@@ -42,8 +42,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 def build_model(args, preset=None, seed=None):
     import jax
     import jax.numpy as jnp
-    from flax import linen as nn
-    from jax.sharding import NamedSharding, PartitionSpec as P
 
     import neuronx_distributed_tpu as nxd
     from neuronx_distributed_tpu.models import (
@@ -53,8 +51,9 @@ def build_model(args, preset=None, seed=None):
         GemmaForCausalLM,
     )
     from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from neuronx_distributed_tpu.parallel.layers import init_sharded_params
     from neuronx_distributed_tpu.parallel.mesh import (
-        get_mesh, model_parallel_is_initialized,
+        model_parallel_is_initialized,
     )
     from neuronx_distributed_tpu.trace import InferenceConfig, ParallelInferenceModel
 
@@ -67,7 +66,9 @@ def build_model(args, preset=None, seed=None):
             raise SystemExit(
                 f"model parallel already initialized with tp="
                 f"{get_tensor_parallel_size()}, but --tp {args.tp} requested")
-    on_tpu = jax.default_backend() == "tpu"
+    # weights, compute and KV cache in the dtype the ARGUMENTS name — never
+    # switched on the backend the run happens to find
+    dtype = jnp.dtype(args.dtype)
     cfg_cls, model_cls = {
         "llama": (LlamaConfig, LlamaForCausalLM),
         "gemma": (GemmaConfig, GemmaForCausalLM),
@@ -77,22 +78,19 @@ def build_model(args, preset=None, seed=None):
         max_seq_len=args.max_total_len,
         sequence_parallel=False,
         remat="none",
-        dtype=jnp.bfloat16 if on_tpu else jnp.float32,
-        param_dtype=jnp.float32,
+        dtype=dtype,
+        param_dtype=dtype,
     )
     module = model_cls(cfg)
     ids0 = jnp.zeros((args.batch_size, args.context_len), jnp.int32)
-    params = module.init(jax.random.PRNGKey(args.seed if seed is None else seed), ids0)
-    specs = nn.get_partition_spec(params)
-    mesh = get_mesh()
-    params = jax.tree.map(
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
-        nn.unbox(params), specs,
-        is_leaf=lambda x: isinstance(x, P) or not isinstance(x, dict))
+    # born sharded over the mesh (as initialize_parallel_model does): the
+    # whole model never sits unsharded on the default device
+    params, _ = init_sharded_params(
+        module, jax.random.PRNGKey(args.seed if seed is None else seed), ids0)
     icfg = InferenceConfig(
         batch_size=args.batch_size, context_len=args.context_len,
         max_total_len=args.max_total_len,
-        kv_cache_dtype=jnp.bfloat16 if on_tpu else jnp.float32,
+        kv_cache_dtype=dtype,
         chunked_prefill=getattr(args, "chunked_prefill", False))
     return cfg, module, params, ParallelInferenceModel(module, params, icfg)
 
@@ -530,6 +528,9 @@ def main():
             sp.add_argument("--family", default="llama",
                             choices=["llama", "gemma", "gemma2"])
             sp.add_argument("--tp", type=int, default=1)
+            sp.add_argument("--dtype", default="bfloat16",
+                            choices=["bfloat16", "float32"],
+                            help="weights, compute and KV-cache dtype")
             sp.add_argument("--batch-size", type=int, default=1)
             sp.add_argument("--context-len", type=int, default=128)
             sp.add_argument("--max-total-len", type=int, default=256)
@@ -653,6 +654,18 @@ def main():
         from neuronx_distributed_tpu.utils.common import ensure_virtual_devices
 
         ensure_virtual_devices(args.virtual_devices)
+    from neuronx_distributed_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
+
+    cache_dir = configure_compile_cache()
+    import jax
+
+    dev = jax.devices()[0]
+    # stderr: stdout carries the commands' JSON lines
+    print(f"devices: {len(jax.devices())} x {dev.device_kind} (platform "
+          f"{dev.platform}); dtype {getattr(args, 'dtype', 'as traced')}; "
+          f"compile cache {cache_dir}", file=sys.stderr, flush=True)
     args.fn(args)
 
 

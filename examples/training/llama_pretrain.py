@@ -11,7 +11,7 @@ Synthetic smoke on the 8-device CPU mesh:
 
   XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
   python examples/training/llama_pretrain.py --preset tiny --tp 2 \
-      --steps 20 --batch-size 8 --seq-len 128
+      --steps 20 --batch-size 8 --seq-len 128 --dtype float32
 
 Real corpus (NXDT token file, see neuronx_distributed_tpu.data):
 
@@ -85,7 +85,10 @@ def parse_args():
     p.add_argument("--timeline", default=None, help="Chrome-trace output path")
     p.add_argument("--scalar-dir", default=None,
                    help="TensorBoard/JSONL scalar stream dir (designated-process only)")
-    p.add_argument("--bf16", action="store_true", help="bf16 compute (default fp32 off-TPU)")
+    p.add_argument("--dtype", default="bfloat16",
+                   choices=["bfloat16", "float32"],
+                   help="compute dtype (master params stay float32); taken "
+                        "from here, never from the backend the run finds")
     p.add_argument("--virtual-devices", type=int, default=None,
                    help="force an N-device virtual CPU mesh (dev/test runs)")
     args = p.parse_args()
@@ -120,10 +123,18 @@ def main():
     )
     from neuronx_distributed_tpu.utils import Timeline, initialize_distributed
     from neuronx_distributed_tpu.utils.common import ensure_virtual_devices
+    from neuronx_distributed_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
 
     if args.virtual_devices:
         ensure_virtual_devices(args.virtual_devices)
+    cache_dir = configure_compile_cache()
     initialize_distributed()
+    dev = jax.devices()[0]
+    print(f"devices: {len(jax.devices())} x {dev.device_kind} "
+          f"(platform {dev.platform}); compute dtype {args.dtype}; "
+          f"compile cache {cache_dir}", flush=True)
     nxd.initialize_model_parallel(
         tensor_parallel_size=args.tp,
         pipeline_parallel_size=args.pp,
@@ -131,7 +142,6 @@ def main():
         kv_size_multiplier=args.kv_multiplier,
     )
 
-    on_tpu = jax.default_backend() == "tpu"
     # one TrainingConfig drives dtypes, mesh, pipeline and optimizer
     config = nxd.training_config(
         tensor_parallel_size=args.tp,
@@ -147,7 +157,7 @@ def main():
         warmup_steps=args.warmup_steps,
         total_steps=max(args.steps, args.warmup_steps + 1),
         zero_one_enabled=not args.no_zero1,
-        compute_dtype="bfloat16" if (args.bf16 or on_tpu) else "float32",
+        compute_dtype=args.dtype,
         param_dtype="float32",
         seed=args.seed,
     )
@@ -256,6 +266,13 @@ def main():
         cfg.num_layers, cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size,
         args.seq_len, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_)
     metrics = TrainingMetrics(args.metrics_file) if args.metrics_file else None
+    # MFU against the device's published peak, from the one table — which
+    # raises on a TPU kind it does not hold.  A CPU run reports no MFU.
+    peak_flops = None
+    if dev.platform == "tpu":
+        from neuronx_distributed_tpu.obs.perf import device_spec
+
+        peak_flops = device_spec(dev).peak_flops
 
     # the whole loop — step/eval/checkpoint/resume/logging — is fit()'s job
     res = fit(
@@ -273,7 +290,7 @@ def main():
         metrics=metrics,
         timeline=Timeline(args.timeline) if args.timeline else None,
         flops_per_token=flops_tok,
-        peak_flops=197e12 if on_tpu else 1e12,
+        peak_flops=peak_flops,
         log_every=10,
     )
     print(f"done: final loss {res.final_loss:.4f}")

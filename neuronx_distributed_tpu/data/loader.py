@@ -13,6 +13,7 @@ round-robin sharded across DP ranks, and prefetched on background threads
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import weakref
@@ -32,23 +33,31 @@ _DTYPE_CODES = {np.dtype(np.uint16): 1, np.dtype(np.int32): 2}
 
 _CSRC = os.path.join(os.path.dirname(__file__), "csrc", "loader.cpp")
 _BUILD_DIR = os.path.join(os.path.dirname(__file__), "_build")
-_LIB_PATH = os.path.join(_BUILD_DIR, "libnxd_data.so")
 
 _lib = None
 _lib_tried = False
 
 
-def _build_native() -> Optional[str]:
+def _lib_path() -> str:
+    """The library is named by the CONTENT of its one source file: a build
+    of another ``loader.cpp`` — a stale ``_build/*.so`` that came along
+    with a copied tree, whatever its mtime — is simply not this file."""
+    with open(_CSRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"libnxd_data.{digest}.so")
+
+
+def _build_native(lib_path: str) -> Optional[str]:
     os.makedirs(_BUILD_DIR, exist_ok=True)
     # build to a per-pid temp name then rename atomically: N DP processes on
     # one host may race to build the same .so
-    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
            _CSRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _LIB_PATH)
-        return _LIB_PATH
+        os.replace(tmp, lib_path)
+        return lib_path
     except (subprocess.SubprocessError, FileNotFoundError, OSError) as e:
         logger.warning("native data loader build failed (%s); using numpy fallback", e)
         if os.path.exists(tmp):
@@ -56,15 +65,22 @@ def _build_native() -> Optional[str]:
         return None
 
 
+def loader_backend() -> str:
+    """``"native"`` when the C++ library serves the loaders of this
+    process, ``"numpy"`` when the bit-identical fallback does."""
+    return "native" if _load_native() is not None else "numpy"
+
+
 def _load_native():
-    """Compile (once) and load the native library; None if unavailable."""
+    """Compile (once per source content) and load the native library; None
+    if unavailable."""
     global _lib, _lib_tried
     if _lib_tried:
         return _lib
     _lib_tried = True
-    path = _LIB_PATH
-    if not os.path.exists(path) or os.path.getmtime(path) < os.path.getmtime(_CSRC):
-        path = _build_native()
+    path = _lib_path()
+    if not os.path.exists(path):
+        path = _build_native(path)
     if path is None:
         return None
     try:
@@ -90,11 +106,10 @@ def _load_native():
     lib.nxd_loader_set_epoch.argtypes = [ctypes.c_void_p, ctypes.c_uint64, ctypes.c_uint64]
     lib.nxd_loader_next.restype = ctypes.c_int64
     lib.nxd_loader_next.argtypes = [ctypes.c_void_p, ctypes.POINTER(ctypes.c_int32)]
-    if hasattr(lib, "nxd_pack_assign"):  # absent only in a stale cached .so
-        lib.nxd_pack_assign.restype = ctypes.c_int64
-        lib.nxd_pack_assign.argtypes = [
-            ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
-            ctypes.c_int32, ctypes.c_int32, ctypes.POINTER(ctypes.c_int32)]
+    lib.nxd_pack_assign.restype = ctypes.c_int64
+    lib.nxd_pack_assign.argtypes = [
+        ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
+        ctypes.c_int32, ctypes.c_int32, ctypes.POINTER(ctypes.c_int32)]
     _lib = lib
     return _lib
 
@@ -109,7 +124,7 @@ def native_pack_assign(lengths: np.ndarray, seq_len: int,
     conflated with unavailability: the fallback must never silently run a
     workload the native path rejected."""
     lib = _load_native()
-    if lib is None or not hasattr(lib, "nxd_pack_assign"):
+    if lib is None:
         return None
     lengths = np.ascontiguousarray(lengths, np.int32)
     out = np.empty(len(lengths), np.int32)

@@ -13,7 +13,7 @@ prefix sharing (Zheng et al., 2024) mapped onto static-shape JAX/pjit:
   prefill logits, so repeated prompts skip prefill compute), with LRU
   eviction of refcount-0 chains;
 - :mod:`.pool` — :class:`PagePool`: the preallocated
-  ``[num_pages, page_size, kv_heads, head_dim]`` device arrays per layer
+  ``[num_pages, kv_heads, page_size, head_dim]`` device arrays per layer
   (kv over tp, page axis a global unsharded pool) plus sizing arithmetic;
 - :mod:`.transfer` — :func:`export_chain` / :func:`import_chain`: move a
   committed page chain between pools (fp and int8 layouts) with

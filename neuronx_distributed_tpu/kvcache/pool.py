@@ -4,7 +4,9 @@ al. SOSP '23, mapped onto static-shape pjit).
 The contiguous serving cache reserves ``[B, max_total_len]`` KV per slot —
 HBM scales with the *worst case* of every slot at once, and that, not
 compute, caps concurrency.  The page pool breaks the coupling: one
-preallocated ``[num_pages, page_size, kv_heads, head_dim]`` pair per layer,
+preallocated HEAD-MAJOR ``[num_pages, kv_heads, page_size, head_dim]`` pair
+per layer (one page of one kv head is a whole ``(page, head_dim)`` trailing
+slab — the block the paged-attention kernel DMAs, ``ops.paged_attention``),
 and requests hold integer *block tables* mapping their logical cache pages
 to physical pages.  Left-padding pages and unwritten decode tail pages
 back onto the shared NULL page (index 0, content never written), and prompt
@@ -54,7 +56,7 @@ def init_page_pool_caches(
     dtype: Any = jnp.bfloat16,
     quant: Optional[str] = None,
 ) -> List[Tuple[jax.Array, ...]]:
-    """Zero page-pool caches ``[NP, page, NKV, D]`` per layer, kv-heads
+    """Zero page-pool caches ``[NP, NKV, page, D]`` per layer, kv-heads
     sharded over tp when divisible (the same policy as the contiguous
     ``init_kv_caches``); the page axis is unsharded — it is a global pool.
 
@@ -63,24 +65,13 @@ def init_page_pool_caches(
     v_scale, v_zero)`` with one fp32 scale/zero per physical page (see
     :mod:`.quant`) — the structural marker the model's block-table
     scatter/gather keys its dequantize-in-the-gather path on."""
-    shape = (num_pages, page_size, num_kv_heads, head_dim)
-    if quant is None:
-        caches: List[Tuple[jax.Array, ...]] = [
-            (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-            for _ in range(num_layers)
-        ]
-    elif quant == "int8":
-        caches = [
-            (jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
-             jnp.zeros((num_pages,), jnp.float32),
-             jnp.zeros((num_pages,), jnp.float32),
-             jnp.zeros((num_pages,), jnp.float32),
-             jnp.zeros((num_pages,), jnp.float32))
-            for _ in range(num_layers)
-        ]
-    else:
+    shape = (num_pages, num_kv_heads, page_size, head_dim)
+    if quant not in (None, "int8"):
         raise ValueError(f"unknown KV quantization {quant!r} "
                          "(supported: 'int8')")
+    # every leaf is BORN with its sharding (never staged whole on the
+    # default device: a pool sized for a tp mesh does not fit one chip)
+    page_sh = scale_sh = None
     if model_parallel_is_initialized():
         mesh = get_mesh()
         kv_axes = (TENSOR_AXIS
@@ -89,13 +80,20 @@ def init_page_pool_caches(
             logger.warning(
                 "page pool kv head dim (%d) not divisible by tp (%d); "
                 "replicating", num_kv_heads, mesh.shape[TENSOR_AXIS])
-        spec = named_sharding(None, None, kv_axes, None)
-        scale_spec = named_sharding(None)  # per-page params: replicated
-        caches = jax.tree.map(
-            lambda x: jax.device_put(
-                x, spec if x.ndim == 4 else scale_spec),
-            caches)
-    return caches
+        page_sh = named_sharding(None, kv_axes, None, None)
+        scale_sh = named_sharding(None)  # per-page params: replicated
+
+    def pages(dt):
+        return jnp.zeros(shape, dt, device=page_sh)
+
+    def params():
+        return jnp.zeros((num_pages,), jnp.float32, device=scale_sh)
+
+    if quant is None:
+        return [(pages(dtype), pages(dtype)) for _ in range(num_layers)]
+    return [(pages(jnp.int8), pages(jnp.int8),
+             params(), params(), params(), params())
+            for _ in range(num_layers)]
 
 
 class PagePool:

@@ -1,7 +1,7 @@
 """Int8 page quantization for the paged KV cache (KIVI, Liu et al. 2024:
 KV tensors tolerate low-bit quantization with bounded logit drift).
 
-A quantized page pool stores each ``[page, NKV, D]`` page as int8 plus ONE
+A quantized page pool stores each ``[NKV, page, D]`` page as int8 plus ONE
 fp32 ``(scale, zero)`` pair per page (asymmetric affine: ``x ≈ (q + 128) *
 scale + zero``), halving the HBM a page costs versus bf16 — the pool holds
 ~2x the pages at a fixed budget, and HBM (not compute) is what caps serving
@@ -37,9 +37,10 @@ _OFFSET = 128.0
 
 
 def quantize_page(x):
-    """Quantize pages over their trailing ``[page, NKV, D]`` axes.
+    """Quantize pages over their trailing three axes (``[NKV, page, D]`` in
+    the pool; the statistics are order-blind).
 
-    ``x`` is ``[..., page, NKV, D]`` float; returns ``(q int8, scale fp32,
+    ``x`` is ``[..., NKV, page, D]`` float; returns ``(q int8, scale fp32,
     zero fp32)`` with ``scale``/``zero`` shaped like the leading axes.
     Asymmetric affine per page: ``zero = min(x)``, ``scale = (max - min) /
     255``; an all-constant page gets ``scale == 0`` and round-trips
@@ -55,7 +56,7 @@ def quantize_page(x):
 
 
 def dequantize_page(q, scale, zero, dtype=jnp.float32):
-    """Invert :func:`quantize_page`: ``q`` is ``[..., page, NKV, D]`` int8,
+    """Invert :func:`quantize_page`: ``q`` is ``[..., NKV, page, D]`` int8,
     ``scale``/``zero`` its leading-axes fp32 params."""
     xf = (q.astype(jnp.float32) + _OFFSET) * scale[..., None, None, None] \
         + zero[..., None, None, None]

@@ -298,46 +298,22 @@ class CoreAttention(nn.Module):
     def __call__(self, q, k, v, q_offset=0, allow_flash=True, kv_valid=None,
                  segment_ids=None):
         cfg = self.config
-        if cfg.attention_impl == "flash" and allow_flash and segment_ids is not None:
-            # packed pretraining on the flash path: the segmented kernel
-            # blocks cross-document attention without materializing [S, S],
-            # and composes with cp > 1 (KV segment ids ride the ring /
-            # all-to-all alongside the KV pair).  Fall through to the dense
-            # core only when the kernel cannot serve the case (odd sequence
-            # lengths, serving-side offsets).
-            from neuronx_distributed_tpu.parallel.mesh import get_context_parallel_size
+        if cfg.attention_impl == "flash" and allow_flash:
+            # allow_flash is the q-aligned, unmasked (training) case — the
+            # only one ring_attention knows.  With segment_ids (packed
+            # pretraining) the segmented kernel blocks cross-document
+            # attention without materializing [S, S], and composes with
+            # cp > 1 (KV segment ids ride the ring / all-to-all alongside
+            # the KV pair).  There is no fall-through to the dense core: a
+            # sequence the kernel cannot tile raises its shape rule (pad to
+            # a multiple of 128 rows per kernel call) where it is fitted,
+            # ops.flash_attention.
             from neuronx_distributed_tpu.ops.ring_attention import ring_attention
 
-            cp = get_context_parallel_size()
-            S = q.shape[1]
-            # The segmented kernel tiles the PER-CHUNK sequence: the rows a
-            # single kernel call sees must be 128-divisible — S/(2cp) for
-            # the zigzag ring (pair chunks), S/cp for the contiguous ring,
-            # the full S for ulysses (post-a2a) and cp==1.
-            if cp <= 1:
-                seg_ok = S % 128 == 0
-            elif cfg.cp_impl == "ulysses":
-                seg_ok = S % cp == 0 and S % 128 == 0
-            elif cfg.cp_zigzag:
-                seg_ok = S % (2 * cp) == 0 and (S // (2 * cp)) % 128 == 0
-            else:
-                seg_ok = S % cp == 0 and (S // cp) % 128 == 0
-            if q_offset == 0 and kv_valid is None and seg_ok:
-                return ring_attention(
-                    q, k, v, causal=True, segment_ids=segment_ids,
-                    layout="zigzag" if cfg.cp_zigzag else "contiguous",
-                    cp_impl=cfg.cp_impl, window=cfg.sliding_window,
-                    sm_scale=cfg.attn_scale, softcap=cfg.attn_softcap,
-                )
-        if cfg.attention_impl == "flash" and allow_flash and segment_ids is None:
-            from neuronx_distributed_tpu.ops.ring_attention import ring_attention
-
-            # ring_attention has no query-offset or padding-mask notion; only
-            # the q-aligned unmasked training case may take this path
             assert q_offset == 0, "flash path requires q_offset == 0"
             assert kv_valid is None, "flash path has no padding-mask support"
             return ring_attention(
-                q, k, v, causal=True,
+                q, k, v, causal=True, segment_ids=segment_ids,
                 layout="zigzag" if cfg.cp_zigzag else "contiguous",
                 cp_impl=cfg.cp_impl, window=cfg.sliding_window,
                 sm_scale=cfg.attn_scale, softcap=cfg.attn_softcap,
@@ -380,33 +356,6 @@ class CoreAttention(nn.Module):
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
         out = jnp.einsum("bkgst,btkd->bskgd", probs, v, preferred_element_type=q.dtype)
         return out.reshape(B, S, NQ, D)
-
-
-def _paged_gather_views(kv_cache, block_table, compute_dtype):
-    """The gather decode path's ``[B, T, NKV, D]`` K/V views from the
-    COMMITTED (post-scatter) page pool — kept in a helper so the O(T)
-    contiguous clones are built only where they are consumed (the attention
-    core call) and never pinned live alongside the returned pool tuple.
-    A quantized pool dequantizes in the gather (page params gather
-    alongside the int8 pages), which is exactly the full-history dequant
-    the block-table-native kernel path exists to avoid."""
-    quantized = len(kv_cache) == 6
-    B, T = block_table.shape[0], block_table.shape[1] * kv_cache[0].shape[1]
-    if quantized:
-        from neuronx_distributed_tpu.kvcache.quant import dequantize_page
-
-        ck, cv, ks, kz, vs, vz = kv_cache
-        k = dequantize_page(
-            ck[block_table], ks[block_table], kz[block_table],
-            dtype=compute_dtype).reshape(B, T, ck.shape[2], ck.shape[3])
-        v = dequantize_page(
-            cv[block_table], vs[block_table], vz[block_table],
-            dtype=compute_dtype).reshape(B, T, cv.shape[2], cv.shape[3])
-    else:
-        ck, cv = kv_cache
-        k = ck[block_table].reshape(B, T, ck.shape[2], ck.shape[3])
-        v = cv[block_table].reshape(B, T, cv.shape[2], cv.shape[3])
-    return k, v
 
 
 class LlamaAttention(nn.Module):
@@ -476,7 +425,8 @@ class LlamaAttention(nn.Module):
                 ck, cv = kv_cache
             if block_table is not None:
                 # paged decode (kvcache/ subsystem): the cache is the global
-                # page pool [NP, page, NKV, D] and block_table [B, PP] maps
+                # head-major page pool [NP, NKV, page, D] (the layout the
+                # paged kernel's blocks need) and block_table [B, PP] maps
                 # each slot's logical pages to physical ones.  Scatter the
                 # S new tokens into their physical (page, in-page) cells —
                 # token s of slot b lands at logical index offset[b] + s —
@@ -490,7 +440,7 @@ class LlamaAttention(nn.Module):
                     raise ValueError(
                         "the block-table decode path needs per-slot offsets "
                         "[B] (continuous-batching decode)")
-                NP, page = ck.shape[0], ck.shape[1]
+                NP, page = ck.shape[0], ck.shape[2]
                 PP = block_table.shape[1]
                 T = PP * page
                 Sn = k.shape[1]
@@ -551,9 +501,12 @@ class LlamaAttention(nn.Module):
                             sel = jnp.clip(s_idx, 0, Sn - 1)
                             ins = jnp.take_along_axis(
                                 new, sel[:, :, None, None], axis=1)
+                            # pages are head-major [B, NKV, page, D]
                             pg = dequantize_page(cq[pc], sc[pc], zp[pc])
-                            pg = jnp.where(hot[:, :, None, None],
-                                           ins.astype(pg.dtype), pg)
+                            pg = jnp.where(
+                                hot[:, None, :, None],
+                                ins.transpose(0, 2, 1, 3).astype(pg.dtype),
+                                pg)
                             q2, s2, z2 = quantize_page(pg)
                             cq = cq.at[pj].set(q2, mode="drop")
                             sc = sc.at[pj].set(s2, mode="drop")
@@ -563,9 +516,12 @@ class LlamaAttention(nn.Module):
                     ck, ks, kz = requant_pages(ck, ks, kz, k)
                     cv, vs, vz = requant_pages(cv, vs, vz, v)
                 else:
-                    ck = ck.at[phys, in_off].set(
+                    # cell (phys, :, in_off) of the head-major pool; the
+                    # split advanced indices lead the update's dims, which
+                    # is k's own [B, Sn, NKV, D]
+                    ck = ck.at[phys, :, in_off].set(
                         k.astype(ck.dtype), mode="drop")
-                    cv = cv.at[phys, in_off].set(
+                    cv = cv.at[phys, :, in_off].set(
                         v.astype(cv.dtype), mode="drop")
             elif jnp.ndim(cache_offset) == 1:
                 # per-example write positions [B] (continuous batching: every
@@ -587,9 +543,14 @@ class LlamaAttention(nn.Module):
             new_cache = (ck, cv, ks, kz, vs, vz) if quantized else (ck, cv)
             if block_table is not None and not paged_kernel:
                 # gather path: attend over the per-row contiguous view of
-                # the COMMITTED pool (the clones are built inside the
-                # helper, layer-local, so XLA frees them with the core)
-                k, v = _paged_gather_views(new_cache, block_table, q.dtype)
+                # the COMMITTED (post-scatter) pool — the O(T) clone (and,
+                # on int8 pools, the full-history dequantize) the
+                # block-table-native kernel path exists to avoid
+                from neuronx_distributed_tpu.ops.paged_attention import (
+                    gather_page_chain,
+                )
+
+                k, v = gather_page_chain(new_cache, block_table, q.dtype)
             elif block_table is None:
                 k, v = ck, cv
 

@@ -5,8 +5,8 @@ die / how many bytes did this program move" from persisted artifacts alone —
 the reference delegates device profiling to external Neuron tools and
 scatters metrics across example code (SURVEY §5.1/§5.5); our earlier port
 reproduced that fragmentation across ``trainer/metrics.py``,
-``trainer/scalar_log.py``, ``utils/timeline.py``, ``utils/profiling.py`` and
-``tools/tpu_watch.py``.  This package correlates them:
+``trainer/scalar_log.py``, ``utils/timeline.py`` and ``utils/profiling.py``.
+This package correlates them:
 
 - :mod:`.registry` — low-overhead counters / gauges / fixed-bucket
   histograms, serialized to the existing ``scalars.jsonl`` schema plus a
@@ -158,7 +158,7 @@ class Observability:
         registry: Optional[MetricRegistry] = None,
         ledgers: bool = False,
         health: Any = False,
-        perf: bool = False,
+        perf: Any = False,
     ):
         self.out_dir = out_dir
         os.makedirs(out_dir, exist_ok=True)
@@ -197,15 +197,19 @@ class Observability:
         # alert edges streamed to alerts.jsonl under out_dir.  Off by
         # default — every consumer guards on `is not None`, so the hot
         # path stays allocation-free (the ALERTS_EVALUATED discipline).
-        # perf attribution (perf=True): per-phase device-time accounting
-        # joined with compile-ledger costs into roofline/MFU records,
-        # dumped to perf_attribution.jsonl on close.  Off by default —
-        # consumers guard on `is not None` (the PERF_RECORDS discipline).
+        # perf attribution (perf=True, or a DeviceSpec cost model for a
+        # run without a known accelerator): per-phase device-time
+        # accounting joined with compile-ledger costs into roofline/MFU
+        # records, dumped to perf_attribution.jsonl on close.  perf=True
+        # reads the device's published peaks and raises on a device the
+        # table does not hold.  Off by default — consumers guard on
+        # `is not None` (the PERF_RECORDS discipline).
         self.perf: Optional[PerfAttribution] = None
         if perf:
             self.perf = PerfAttribution(
                 path=os.path.join(out_dir, PERF_ATTRIBUTION_FILE),
-                registry=self.registry, ledger=self.compile_ledger)
+                registry=self.registry, ledger=self.compile_ledger,
+                spec=perf if isinstance(perf, DeviceSpec) else None)
         self.health_monitor: Optional[HealthMonitor] = None
         if isinstance(health, HealthMonitor):
             self.health_monitor = health

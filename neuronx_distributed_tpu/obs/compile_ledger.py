@@ -1,8 +1,7 @@
 """Compile ledger: every XLA compile the framework triggers, accounted.
 
 The two resources that actually kill runs here are invisible by default:
-a >24-minute cold compile looks exactly like a hang (the round-5 TPU
-window died inside one), and a recompile on the serving hot path is a
+a many-minute cold compile looks exactly like a hang, and a recompile on the serving hot path is a
 silent multi-hundred-ms stall that poisons every latency percentile near
 it.  :class:`CompileLedger` is the one accounting surface:
 
@@ -52,8 +51,7 @@ COMPILE_LEDGER_FILE = "compile_ledger.jsonl"
 COMPILE_LEDGER_SCHEMA = "compile_ledger/1"
 
 # compile wall-time histogram boundaries (ms): compiles span four orders of
-# magnitude — sub-second lazy jits to the >24-minute remote-service cold
-# builds the round-5 window died inside
+# magnitude — sub-second lazy jits to many-minute cold train-step builds
 COMPILE_MS_BUCKETS = (
     1.0, 5.0, 10.0, 50.0, 100.0, 250.0, 500.0, 1000.0, 2500.0, 5000.0,
     10000.0, 30000.0, 60000.0, 300000.0, 900000.0, 1800000.0,

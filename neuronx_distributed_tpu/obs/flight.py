@@ -4,9 +4,8 @@ A ring buffer of the last K step records — loss, grad-norm, step-time
 breakdown (host dispatch vs device wait via ``block_until_ready`` timing,
 data-loader stall) — that dumps to ``flight_record.json`` when the run dies
 (crash or SIGTERM, hooked into ``fit()``'s existing signal path) and at
-clean exit.  The rounds 3-5 bench post-mortems were reconstructed by hand
-from scrollback (docs/BENCH_NOTES_r5.md); this makes the last K steps a
-persisted artifact instead.
+clean exit, so a post-mortem reads the last K steps from a persisted
+artifact instead of reconstructing them from scrollback.
 
 Detectors run synchronously on every record (they are a few float
 comparisons) and emit three-way: a structured warning record (persisted in

@@ -18,11 +18,12 @@ accumulator and attribution record this module allocates, every call site
 guards on ``perf is not None``, and the zero-allocation-when-off test
 asserts the counter never moves over a full run with ``perf=False``.
 
-The device table is a deterministic cost model: known TPU kinds carry
-published peak FLOP/s + HBM bandwidth; on CPU (the test mesh) the spec is
-calibrated once per process from a fixed micro-workload and cached, so
-every record in a run classifies against the same numbers and the CPU
-tunnel is never the blocker for exercising the attribution path.
+The device table (:data:`DEVICE_SPECS`) is the repository's ONE table of
+published peaks, keyed by jax's ``device_kind``; a device that is not in it
+is an error, never a default.  A CPU run has no roofline: tests and CPU
+harness smokes that need a cost model to exercise the attribution path pass
+one explicitly (``spec=`` — e.g. :func:`calibrate_cpu_spec`, which labels
+itself ``cpu``).
 """
 
 from __future__ import annotations
@@ -74,32 +75,64 @@ _MS_BUCKETS = (1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0,
 @dataclasses.dataclass(frozen=True)
 class DeviceSpec:
     """Peak compute + HBM bandwidth for one device kind — the two numbers
-    a roofline needs.  ``kind`` is a lowercase prefix of jax's
-    ``device.device_kind`` (the :func:`~bench.peak_flops_for` idiom)."""
+    a roofline needs.  ``kind`` is jax's ``device.device_kind``."""
 
     kind: str
     peak_flops: float
     hbm_bytes_per_s: float
 
 
-# Published bf16 peak FLOP/s + HBM BW per chip.  Longest prefix wins, so
-# "tpu v5 lite" (v5e) is matched before the bare "tpu v5" (v5p) entry.
-DEVICE_SPECS: Tuple[DeviceSpec, ...] = (
-    DeviceSpec("tpu v6 lite", 918e12, 1640e9),   # v6e / Trillium
-    DeviceSpec("tpu v5 lite", 197e12, 819e9),    # v5e
-    DeviceSpec("tpu v5e", 197e12, 819e9),
-    DeviceSpec("tpu v5", 459e12, 2765e9),        # v5p
-    DeviceSpec("tpu v4", 275e12, 1228e9),
-)
+def _specs(peak_flops: float, hbm_bytes_per_s: float, *kinds: str):
+    return {k: DeviceSpec(k, peak_flops, hbm_bytes_per_s) for k in kinds}
+
+
+# THE peak table: published bf16 peak FLOP/s and HBM bytes/s per chip, keyed
+# by ``jax.devices()[0].device_kind`` exactly as jax reports it (both
+# spellings a kind is known under).  Source: Google Cloud TPU documentation,
+# the "System architecture" page of each version ("TPU v5e": 197 TFLOP/s
+# bf16, 819 GB/s; "TPU v4": 275, 1228; "TPU v5p": 459, 2765; "TPU v6e": 918,
+# 1640).  No entry, no number: :func:`device_spec` raises.
+DEVICE_SPECS: Dict[str, DeviceSpec] = {
+    **_specs(197e12, 819e9, "TPU v5 lite", "TPU v5e"),
+    **_specs(275e12, 1228e9, "TPU v4"),
+    **_specs(459e12, 2765e9, "TPU v5", "TPU v5p"),
+    **_specs(918e12, 1640e9, "TPU v6 lite", "TPU v6e"),
+}
+
+
+class UnknownDeviceError(LookupError):
+    """The device's kind is not in :data:`DEVICE_SPECS`."""
+
+
+def device_spec(device: Any = None) -> DeviceSpec:
+    """The :class:`DeviceSpec` of ``device`` (default: the first jax
+    device) from :data:`DEVICE_SPECS`.  Raises :class:`UnknownDeviceError`
+    for a kind the table does not hold — a CPU included: utilization and
+    roofline figures exist for known accelerators only."""
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    kind = str(device.device_kind)
+    try:
+        return DEVICE_SPECS[kind]
+    except KeyError:
+        raise UnknownDeviceError(
+            f"no published peaks for device kind {kind!r} (known: "
+            f"{sorted(DEVICE_SPECS)}); add it to obs.perf.DEVICE_SPECS with "
+            "its source, or pass an explicit spec= cost model") from None
+
 
 _CPU_SPEC: Optional[DeviceSpec] = None
 
 
 def calibrate_cpu_spec() -> DeviceSpec:
-    """Calibrate-on-first-use CPU spec: one fixed matmul + one fixed copy,
-    measured once per process and cached, so every classification in a
-    run (and every test) sees the same numbers.  The result is a cost
-    MODEL for the test mesh, not a claim about the host."""
+    """An explicit cost MODEL for CPU tests and ``--tiny`` harness smokes
+    that exercise the attribution path without a chip: one fixed matmul +
+    one fixed copy, measured once per process and cached, so every
+    classification in a run sees the same numbers.  Never chosen for a
+    caller — :func:`device_spec` raises on a CPU — and its records carry
+    ``device: "cpu"``, so they cannot pass for a device roofline."""
     global _CPU_SPEC
     if _CPU_SPEC is not None:
         return _CPU_SPEC
@@ -125,27 +158,6 @@ def calibrate_cpu_spec() -> DeviceSpec:
         bw = max(bw, 2.0 * src.nbytes / max(time.perf_counter() - t0, 1e-9))
     _CPU_SPEC = DeviceSpec("cpu", max(peak, 1e9), max(bw, 1e9))
     return _CPU_SPEC
-
-
-def device_spec(device: Any = None) -> DeviceSpec:
-    """Resolve the :class:`DeviceSpec` for ``device`` (default: the first
-    jax device).  Unknown kinds fall back to the calibrated CPU spec."""
-    kind = None
-    if device is None:
-        try:
-            import jax
-
-            device = jax.devices()[0]
-        except Exception:  # noqa: BLE001 — spec lookup must never raise
-            device = None
-    if device is not None:
-        kind = str(getattr(device, "device_kind", None)
-                   or getattr(device, "platform", "cpu")).lower()
-    if kind:
-        for spec in sorted(DEVICE_SPECS, key=lambda s: -len(s.kind)):
-            if kind.startswith(spec.kind):
-                return spec
-    return calibrate_cpu_spec()
 
 
 def roofline_attribution(
@@ -593,6 +605,7 @@ def merge_perf_records(streams: Iterable[Iterable[dict]]) -> List[dict]:
 __all__ = [
     "DeviceSpec",
     "DEVICE_SPECS",
+    "UnknownDeviceError",
     "PERF_ATTRIBUTION_FILE",
     "PERF_ATTRIBUTION_SCHEMA",
     "PERF_FAMILIES",

@@ -1,7 +1,7 @@
 """Checked-in schemas for every JSONL/JSON artifact the framework emits.
 
-Downstream tooling (``tools/obs_report.py``, dashboards, the judge reading
-``docs/tpu_watch_results.jsonl``) parses these files; this module is the
+Downstream tooling (``tools/obs_report.py``, dashboards) parses these
+files; this module is the
 contract that keeps the formats stable.  A schema here is deliberately a
 floor, not a straitjacket: records may carry EXTRA keys (forward-compatible
 growth), but the required keys and their types may never change without a
@@ -37,8 +37,6 @@ SCHEMAS: Dict[str, Dict[str, Any]] = {
         "collective_counts": dict, "collective_bytes": dict,
         "total_collective_count": int, "total_collective_bytes": int,
     },
-    # one line of docs/tpu_watch_results.jsonl (tools/tpu_watch.py append)
-    "tpu_watch": {"ts": str, "kind": str},
     # one line of trace_events.jsonl (obs.tracing.Tracer.export_jsonl) —
     # one record per finished span: the request-lifecycle distributed
     # trace.  request_id is the fleet-global id (-1 for batch-level spans

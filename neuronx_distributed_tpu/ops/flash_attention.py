@@ -27,20 +27,28 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific pallas namespace; absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float(-1e30)  # large-negative instead of -inf: keeps exp/where NaN-free
 LANES = 128
 
 
-def _auto_interpret(interpret: Optional[bool]) -> bool:
+def run_kernel(call, interpret: Optional[bool], *operands):
+    """Run ``call(interpret)(*operands)``, where ``call`` builds the
+    ``pallas_call`` for one mode.
+
+    ``interpret=None`` decides by where the program LOWERS, not by what
+    ``jax.default_backend()`` happens to be when it is traced: a program
+    lowered for a TPU carries the compiled Mosaic kernel, a program lowered
+    for anything else (the CPU test mesh) carries the Pallas interpreter.
+    ``lax.platform_dependent`` stages both and the lowering keeps the one
+    branch of its platform, so an AOT compile for a described TPU from a
+    CPU host gets the kernel, and no TPU program ever comes out
+    interpreted.  An explicit bool is honored as given."""
     if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
+        return call(bool(interpret))(*operands)
+    return jax.lax.platform_dependent(
+        *operands, tpu=call(False), default=call(True))
 
 
 def _compiler_params(dimension_semantics, interpret: bool):
@@ -50,10 +58,12 @@ def _compiler_params(dimension_semantics, interpret: bool):
     parallelize grid steps instead of running the whole grid serially.
     The interpreter ignores compiler params; pass None to keep interpret
     mode permissive."""
-    if interpret or pltpu is None:
+    if interpret:
         return None
     return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
+
+_GRID_SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
 
 _MIN_BLOCK = 128  # below one MXU tile the kernel is pure overhead
 
@@ -250,8 +260,6 @@ def _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     if window is not None and (not causal or window < 1):
         raise ValueError("window requires causal=True and window >= 1")
 
-    if pltpu is None:  # pragma: no cover - CPU builds always ship pltpu today
-        raise RuntimeError("pallas TPU namespace unavailable")
     grid = (B, HQ, nq, nk)
     segmented = q_seg is not None
 
@@ -283,23 +291,25 @@ def _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         in_specs += [qs_spec, ks_spec]
         operands += [qs, ks]
 
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        compiler_params=_compiler_params(("parallel", "parallel", "parallel", "arbitrary"),
-                                         interpret),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, bq, LANES), lambda b, h, qi, ki: (b, h, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, HQ, S, D), q.dtype),
-            jax.ShapeDtypeStruct((B, HQ, S, LANES), jnp.float32),
-        ],
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(*operands)
+    def call(interp):
+        return pl.pallas_call(
+            kernel,
+            grid=grid,
+            compiler_params=_compiler_params(_GRID_SEMANTICS, interp),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
+                pl.BlockSpec((1, 1, bq, LANES), lambda b, h, qi, ki: (b, h, qi, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, HQ, S, D), q.dtype),
+                jax.ShapeDtypeStruct((B, HQ, S, LANES), jnp.float32),
+            ],
+            scratch_shapes=scratch,
+            interpret=interp,
+        )
+
+    o, lse = run_kernel(call, interpret, *operands)
     return o, lse
 
 
@@ -473,17 +483,19 @@ def _bwd_impl(q, k, v, lse, do, delta_rows, causal, sm_scale, block_q, block_k, 
         dq_in_specs += [qs_spec, ks_spec]
         dq_operands += [qs, ks]
 
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(B, HQ, nq, nk),
-        compiler_params=_compiler_params(("parallel", "parallel", "parallel", "arbitrary"),
-                                         interpret),
-        in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, HQ, S, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        interpret=interpret,
-    )(*dq_operands)
+    def dq_call(interp):
+        return pl.pallas_call(
+            dq_kernel,
+            grid=(B, HQ, nq, nk),
+            compiler_params=_compiler_params(_GRID_SEMANTICS, interp),
+            in_specs=dq_in_specs,
+            out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
+            out_shape=jax.ShapeDtypeStruct((B, HQ, S, D), q.dtype),
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+            interpret=interp,
+        )
+
+    dq = run_kernel(dq_call, interpret, *dq_operands)
 
     # dk/dv are accumulated per q-head then group-summed onto kv heads
     def dkv_kernel(*refs):
@@ -513,26 +525,28 @@ def _bwd_impl(q, k, v, lse, do, delta_rows, causal, sm_scale, block_q, block_k, 
         ]
         dkv_operands += [qs, ks]
 
-    dk_q, dv_q = pl.pallas_call(
-        dkv_kernel,
-        grid=(B, HQ, nk, nq),
-        compiler_params=_compiler_params(("parallel", "parallel", "parallel", "arbitrary"),
-                                         interpret),
-        in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, ki, qi: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, ki, qi: (b, h, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, HQ, T, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, HQ, T, D), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*dkv_operands)
+    def dkv_call(interp):
+        return pl.pallas_call(
+            dkv_kernel,
+            grid=(B, HQ, nk, nq),
+            compiler_params=_compiler_params(_GRID_SEMANTICS, interp),
+            in_specs=dkv_in_specs,
+            out_specs=[
+                pl.BlockSpec((1, 1, bk, D), lambda b, h, ki, qi: (b, h, ki, 0)),
+                pl.BlockSpec((1, 1, bk, D), lambda b, h, ki, qi: (b, h, ki, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, HQ, T, D), jnp.float32),
+                jax.ShapeDtypeStruct((B, HQ, T, D), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, D), jnp.float32),
+            ],
+            interpret=interp,
+        )
+
+    dk_q, dv_q = run_kernel(dkv_call, interpret, *dkv_operands)
 
     dk = jnp.sum(dk_q.reshape(B, HKV, G, T, D), axis=2).astype(k.dtype)
     dv = jnp.sum(dv_q.reshape(B, HKV, G, T, D), axis=2).astype(v.dtype)
@@ -562,7 +576,8 @@ def flash_attention(
 
     With ``causal=True`` and ``T > S`` the queries occupy the *last* ``S``
     positions of the kv timeline (the decode/chunked-prefill convention).
-    ``interpret`` defaults to auto: pallas interpreter off-TPU.
+    ``interpret`` defaults to auto: the compiled kernel where the program
+    lowers for a TPU, the pallas interpreter elsewhere (:func:`run_kernel`).
 
     ``window`` (causal only) is Mistral-style sliding-window attention:
     query at position p attends keys in ``[p - window + 1, p]``.  KV blocks
@@ -574,14 +589,14 @@ def flash_attention(
     through ``cap * tanh(s / cap)`` before masking; the backward kernels
     chain through the cap analytically."""
     o, _ = _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                     _auto_interpret(interpret), window=window, softcap=softcap)
+                     interpret, window=window, softcap=softcap)
     return o
 
 
 def _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window,
             softcap):
     o, lse = _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                       _auto_interpret(interpret), window=window, softcap=softcap)
+                       interpret, window=window, softcap=softcap)
     return o, (q, k, v, o, lse)
 
 
@@ -591,7 +606,7 @@ def _fa_bwd(causal, sm_scale, block_q, block_k, interpret, window, softcap,
     delta_rows = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     dq, dk, dv = _bwd_impl(
         q, k, v, lse, do, delta_rows, causal, sm_scale, block_q, block_k,
-        _auto_interpret(interpret), window=window, softcap=softcap,
+        interpret, window=window, softcap=softcap,
     )
     return dq, dk, dv
 
@@ -623,14 +638,14 @@ def flash_attention_with_lse(
     points.
     """
     o, lse = _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                       _auto_interpret(interpret), window=window, softcap=softcap)
+                       interpret, window=window, softcap=softcap)
     return o, lse[..., 0]
 
 
 def _fa_lse_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window,
                 softcap):
     o, lse = _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                       _auto_interpret(interpret), window=window, softcap=softcap)
+                       interpret, window=window, softcap=softcap)
     return (o, lse[..., 0]), (q, k, v, o, lse)
 
 
@@ -642,7 +657,7 @@ def _fa_lse_bwd(causal, sm_scale, block_q, block_k, interpret, window, softcap,
     delta_rows = delta_rows - dlse.astype(jnp.float32)
     dq, dk, dv = _bwd_impl(
         q, k, v, lse, do, delta_rows, causal, sm_scale, block_q, block_k,
-        _auto_interpret(interpret), window=window, softcap=softcap,
+        interpret, window=window, softcap=softcap,
     )
     return dq, dk, dv
 
@@ -692,7 +707,7 @@ def flash_attention_segmented(
     than ``window - 1`` positions back.  ``softcap`` composes too (Gemma-2
     hybrid layers are segmented + banded + capped)."""
     o, _ = _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                     _auto_interpret(interpret), q_segment_ids, kv_segment_ids,
+                     interpret, q_segment_ids, kv_segment_ids,
                      window=window, softcap=softcap)
     return o
 
@@ -700,7 +715,7 @@ def flash_attention_segmented(
 def _fa_seg_fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
                 interpret, window, softcap):
     o, lse = _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                       _auto_interpret(interpret), q_seg, kv_seg, window=window,
+                       interpret, q_seg, kv_seg, window=window,
                        softcap=softcap)
     return o, (q, k, v, q_seg, kv_seg, o, lse)
 
@@ -711,7 +726,7 @@ def _fa_seg_bwd(causal, sm_scale, block_q, block_k, interpret, window, softcap,
     delta_rows = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     dq, dk, dv = _bwd_impl(
         q, k, v, lse, do, delta_rows, causal, sm_scale, block_q, block_k,
-        _auto_interpret(interpret), q_seg, kv_seg, window=window, softcap=softcap,
+        interpret, q_seg, kv_seg, window=window, softcap=softcap,
     )
     return dq, dk, dv, _float0_like(q_seg), _float0_like(kv_seg)
 
@@ -745,7 +760,7 @@ def flash_attention_segmented_with_lse(
     lse cotangent into the delta correction exactly as
     :func:`flash_attention_with_lse` does."""
     o, lse = _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                       _auto_interpret(interpret), q_segment_ids, kv_segment_ids,
+                       interpret, q_segment_ids, kv_segment_ids,
                        window=window, softcap=softcap)
     return o, lse[..., 0]
 
@@ -753,7 +768,7 @@ def flash_attention_segmented_with_lse(
 def _fa_seg_lse_fwd(q, k, v, q_seg, kv_seg, causal, sm_scale, block_q, block_k,
                     interpret, window, softcap):
     o, lse = _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                       _auto_interpret(interpret), q_seg, kv_seg, window=window,
+                       interpret, q_seg, kv_seg, window=window,
                        softcap=softcap)
     return (o, lse[..., 0]), (q, k, v, q_seg, kv_seg, o, lse)
 
@@ -766,7 +781,7 @@ def _fa_seg_lse_bwd(causal, sm_scale, block_q, block_k, interpret, window,
     delta_rows = delta_rows - dlse.astype(jnp.float32)
     dq, dk, dv = _bwd_impl(
         q, k, v, lse, do, delta_rows, causal, sm_scale, block_q, block_k,
-        _auto_interpret(interpret), q_seg, kv_seg, window=window, softcap=softcap,
+        interpret, q_seg, kv_seg, window=window, softcap=softcap,
     )
     return dq, dk, dv, _float0_like(q_seg), _float0_like(kv_seg)
 

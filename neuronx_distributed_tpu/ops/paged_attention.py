@@ -17,7 +17,11 @@ Design (in the style of the in-tree ``ops/flash_attention.py``):
   block covers ``block_pages`` logically-consecutive pages whose PHYSICAL
   page ids come from the scalar-prefetched block table
   (``pltpu.PrefetchScalarGridSpec`` — the index map reads the table, so the
-  pool is addressed in place, never gathered into a per-slot clone);
+  pool is addressed in place, never gathered into a per-slot clone).  The
+  pool is HEAD-MAJOR ``[NP, NKV, page, D]`` (``kvcache.pool``): one page of
+  one kv head is a whole ``(page, D)`` trailing slab, which is the block
+  shape Mosaic accepts (the last two block dims must be tile-aligned or
+  span the array's);
 - online softmax ``(m, l, acc)`` carried in VMEM scratch across the
   page-block grid dim, exactly like the flash forward;
 - GQA by q-head grouping: the ``G = NQ/NKV`` query heads of one kv head are
@@ -34,10 +38,13 @@ Design (in the style of the in-tree ``ops/flash_attention.py``):
   partials that a tiny jnp epilogue merges by logsumexp weighting (the ring
   attention combine) — the decode-latency lever when one slot's chain is
   long but B * NKV underfills the chip;
-- int8 six-tuple pools dequantize IN-KERNEL: each page's fp32
-  ``(scale, zero)`` rides a packed per-page param operand addressed by the
-  same block-table index map, so quantized serving reads 1 byte/element
-  from HBM and never materializes a dequantized history;
+- int8 six-tuple pools dequantize IN-KERNEL: the slot's per-page fp32
+  ``(scale, zero)`` pairs are gathered through the block table (``[B, PP]``
+  floats — tiny) and ride as per-key-column rows of one small operand; the
+  affine code ``x = (q + 128) * scale + zero`` is constant over a page, so
+  it factors out of both matmuls and is applied to the ``[rows, keys]``
+  score/probability tiles — quantized serving reads 1 byte/element from
+  HBM and never materializes a dequantized history;
 - pages past a slot's last needed block keep addressing the slot's LAST
   needed physical page (the index map clamps): consecutive grid steps with
   an unchanged block index skip the re-fetch, so the tail of a short chain
@@ -58,16 +65,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from jax.experimental.pallas import tpu as pltpu
+
 from neuronx_distributed_tpu.ops.flash_attention import (
+    _GRID_SEMANTICS,
     LANES,
     NEG_INF,
-    _auto_interpret,
+    _compiler_params,
+    run_kernel,
 )
-
-try:  # TPU-specific pallas namespace; absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
 
 # int8 affine code offset (kvcache.quant convention: x ~ (q + 128)*scale + zero)
 _INT8_OFFSET = 128.0
@@ -108,22 +114,28 @@ CHUNK_SHAPE_DEFAULTS = {
 }
 
 
-def resolve_paged_kernel(flag, tensor_parallel: int = 1) -> bool:
+def resolve_paged_kernel(flag, platform: Optional[str] = None) -> bool:
     """Resolve the three-state ``paged_kernel`` knob (``"auto"`` | ``True``
-    | ``False``) to a concrete bool: auto picks the kernel on a real TPU
-    backend and the gather path on CPU (interpret runs pay interpreter
-    overhead per grid step).  tp > 1 meshes run the kernel too — it is
-    shard_mapped over the tp-sharded kv-head axis (``tensor_parallel``
-    stays in the signature for callers that recorded it; it no longer
-    forces a fallback).  An explicit ``True`` is honored anywhere — that
-    is how the CPU parity tests drive the interpreter."""
+    | ``False``) to a concrete bool.  Auto picks the kernel when the
+    programs run on a TPU and the gather path elsewhere (interpret runs pay
+    interpreter overhead per grid step).  ``platform`` is the platform of
+    the devices the caller's programs are placed on (its mesh's, or its
+    params') — never ``jax.default_backend()``: a serving wrapper built
+    over TPU devices, attached or described for an AOT compile, gets the
+    kernel whatever the process's default backend is.  tp > 1 meshes run
+    the kernel too (shard_mapped over the tp-sharded kv-head axis).  An
+    explicit ``True`` is honored anywhere — that is how the CPU parity
+    tests drive the interpreter."""
     if flag is True or flag is False:
         return flag
     if flag not in ("auto", None):
         raise ValueError(
             f"paged_kernel must be 'auto', True or False, got {flag!r}")
-    del tensor_parallel
-    return jax.default_backend() == "tpu"
+    if platform is None:
+        raise ValueError(
+            "paged_kernel='auto' resolves against the platform the programs "
+            "run on: pass platform= (the mesh's or the params' devices')")
+    return platform == "tpu"
 
 
 def lookup_defaults(page_size: int, pages_per_slot: int, num_kv_heads: int,
@@ -161,23 +173,34 @@ def lookup_defaults(page_size: int, pages_per_slot: int, num_kv_heads: int,
 # ---------------------------------------------------------------------------
 
 
+def _concat_pages(refs, dtype):
+    """``bp`` single-page ``(1, 1, page, D)`` blocks -> one ``[bp*page, D]``
+    tile in ``dtype``.  Pages whose row count is not a whole sublane tile of
+    their storage dtype (bf16 packs 16 rows, int8 32) are widened to fp32
+    first, where every 8 rows are a tile, so the concatenation stays
+    tile-aligned for Mosaic."""
+    page = refs[0].shape[2]
+    packed_rows = 8 * (4 // jnp.dtype(refs[0].dtype).itemsize)
+    via = refs[0].dtype if page % packed_rows == 0 else jnp.float32
+    return jnp.concatenate(
+        [r[0, 0].astype(via) for r in refs], axis=0).astype(dtype)
+
+
 def _paged_kernel(bt_ref, off_ref, start_ref, q_ref, *rest,
                   sm_scale, page, block_pages, num_blocks, kv_len,
                   group, window, softcap, quantized):
     """One (slot, kv-head, split, page-block) grid step.
 
-    ``rest`` is ``[k_0..k_{bp-1}, v_0.., (kp_0.., vp_0..)?, acc, m, l,
-    m_scr, l_scr, acc_scr]`` — ``bp`` single-page K blocks, the matching V
-    blocks, optionally the packed int8 page params (k then v), the three
-    unnormalized outputs, then the VMEM scratch carried across the
-    page-block dim."""
+    ``rest`` is ``[k_0..k_{bp-1}, v_0.., par?, acc, m, l, m_scr, l_scr,
+    acc_scr]`` — ``bp`` single-page K blocks, the matching V blocks,
+    optionally the int8 page params as four per-key-column rows (k scale,
+    k zero, v scale, v zero), the three unnormalized outputs, then the VMEM
+    scratch carried across the page-block dim."""
     bp = block_pages
-    nk = 2 * bp + (2 * bp if quantized else 0)
-    kv_refs, rest = rest[:nk], rest[nk:]
-    k_refs = kv_refs[:bp]
-    v_refs = kv_refs[bp:2 * bp]
-    kp_refs = kv_refs[2 * bp:3 * bp] if quantized else ()
-    vp_refs = kv_refs[3 * bp:4 * bp] if quantized else ()
+    k_refs, v_refs, rest = rest[:bp], rest[bp:2 * bp], rest[2 * bp:]
+    par_ref = None
+    if quantized:
+        par_ref, rest = rest[0], rest[1:]
     acc_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = rest
 
     b = pl.program_id(0)
@@ -208,27 +231,28 @@ def _paged_kernel(bt_ref, off_ref, start_ref, q_ref, *rest,
     @pl.when(run)
     def _body():
         q = q_ref[0, 0]  # [rows, D], native dtype into the MXU
+        width = bp * page
+        k = _concat_pages(k_refs, q.dtype)
+        v = _concat_pages(v_refs, q.dtype)
         if quantized:
-            parts_k, parts_v = [], []
-            for j in range(bp):
-                kj = k_refs[j][0, :, 0, :].astype(jnp.float32)
-                vj = v_refs[j][0, :, 0, :].astype(jnp.float32)
-                kp = kp_refs[j][0]  # [LANES]: scale in lane 0, zero in lane 1
-                vp = vp_refs[j][0]
-                parts_k.append((kj + _INT8_OFFSET) * kp[0] + kp[1])
-                parts_v.append((vj + _INT8_OFFSET) * vp[0] + vp[1])
-            k = jnp.concatenate(parts_k, axis=0).astype(q.dtype)
-            v = jnp.concatenate(parts_v, axis=0).astype(q.dtype)
-        else:
-            k = jnp.concatenate([r[0, :, 0, :] for r in k_refs], axis=0)
-            v = jnp.concatenate([r[0, :, 0, :] for r in v_refs], axis=0)
+            # x = (code + 128) * scale + zero with (scale, zero) constant
+            # over a page: both matmuls run on the integer-valued codes
+            # (exact in bf16) and the affine map lands on the [rows, width]
+            # tiles through the per-column param rows
+            k = k + _INT8_OFFSET
+            v = v + _INT8_OFFSET
+            par = par_ref[0, 0]  # [4, width] fp32
+            ks, kz, vs, vz = par[0:1], par[1:2], par[2:3], par[3:4]
         # [rows, bp*page] fp32 scores
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
+        )
+        if quantized:
+            qsum = jnp.sum(q.astype(jnp.float32), axis=-1, keepdims=True)
+            s = s * ks + qsum * kz
+        s = s * sm_scale
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
-        width = bp * page
         qpos = off + jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0) // group
         kpos = base_pos + jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
         mask = jnp.logical_and(kpos <= qpos, kpos >= start)
@@ -246,10 +270,13 @@ def _paged_kernel(bt_ref, off_ref, start_ref, q_ref, *rest,
         # is 1, so zero p wherever the mask killed the score
         p = jnp.where(mask, p, 0.0)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        pv = jax.lax.dot_general(
+            (p * vs if quantized else p).astype(v.dtype), v,
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
         )
+        if quantized:
+            pv = pv + jnp.sum(p * vz, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + pv
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
@@ -279,21 +306,25 @@ def _page_index_maps(page, block_pages, num_blocks, kv_len, num_pages_phys,
             p_log = jnp.minimum(p_log, jnp.maximum(last, 0))
             p_log = jnp.minimum(p_log, pages_per_slot - 1)
             phys = bt_ref[b, p_log]
-            return jnp.minimum(phys, num_pages_phys - 1), 0, h, 0
+            return jnp.minimum(phys, num_pages_phys - 1), h, 0, 0
 
         return imap
 
     return for_j
 
 
-def _pack_page_params(scale, zero):
-    """Pack per-page fp32 quant params into a TPU-tileable ``[NP, LANES]``
-    operand: scale in lane 0, zero in lane 1 (the remaining lanes ride
-    along — per-page params are tiny next to the pool)."""
-    npages = scale.shape[0]
-    out = jnp.zeros((npages, LANES), jnp.float32)
-    out = out.at[:, 0].set(scale.astype(jnp.float32))
-    return out.at[:, 1].set(zero.astype(jnp.float32))
+def _page_param_rows(block_table, params, page, block_pages):
+    """The slot's int8 page params as per-key-column rows, one whole
+    ``(4, bp*page)`` trailing slab per page block: ``[B, PP/bp, 4,
+    bp*page]`` fp32 with rows (k scale, k zero, v scale, v zero).  Gathered
+    through the block table OUTSIDE the kernel — ``4 * B * T`` floats, next
+    to a pool of ``NP * page * NKV * D`` bytes."""
+    B, PP = block_table.shape
+    rows = jnp.stack(
+        [p.astype(jnp.float32)[block_table] for p in params], axis=1)
+    rows = jnp.repeat(rows, page, axis=2)  # [B, 4, T]
+    return rows.reshape(B, 4, PP // block_pages, block_pages * page
+                        ).transpose(0, 2, 1, 3)
 
 
 @functools.partial(
@@ -305,18 +336,14 @@ def _paged_attention_impl(q, kv_pages, block_table, cache_offset, kv_start,
                           sm_scale=None, window=None, softcap=None,
                           block_pages=None, split_k=None, interpret=None):
     quantized = len(kv_pages) == 6
-    if quantized:
-        k_pages, v_pages, ks, kz, vs, vz = kv_pages
-    else:
-        k_pages, v_pages = kv_pages
+    k_pages, v_pages = kv_pages[:2]
     B, S, NQ, D = q.shape
-    NP_phys, page, NKV, _ = k_pages.shape
+    NP_phys, NKV, page, _ = k_pages.shape
     PP = block_table.shape[1]
     T = PP * page
     G = NQ // NKV
     rows = G * S
     scale = (D ** -0.5) if sm_scale is None else sm_scale
-    interpret = _auto_interpret(interpret)
     if block_pages is None or split_k is None:
         d_bp, d_sk = lookup_defaults(page, PP, NKV, D,
                                      "int8" if quantized else None,
@@ -342,7 +369,7 @@ def _paged_attention_impl(q, kv_pages, block_table, cache_offset, kv_start,
              else kv_start.astype(jnp.int32))
 
     imap_for = _page_index_maps(page, bp, num_blocks, T, NP_phys, PP, S)
-    kv_spec = lambda j: pl.BlockSpec((1, page, 1, D), imap_for(j))  # noqa: E731
+    kv_spec = lambda j: pl.BlockSpec((1, 1, page, D), imap_for(j))  # noqa: E731
     in_specs = [pl.BlockSpec((1, 1, rows, D),
                              lambda b, h, s_, ki, *_: (b, h, 0, 0))]
     operands = [qg]
@@ -351,18 +378,10 @@ def _paged_attention_impl(q, kv_pages, block_table, cache_offset, kv_start,
     in_specs += [kv_spec(j) for j in range(bp)]
     operands += [v_pages] * bp
     if quantized:
-        kp = _pack_page_params(ks, kz)
-        vp = _pack_page_params(vs, vz)
-
-        def par_spec(j):
-            im = imap_for(j)
-            return pl.BlockSpec(
-                (1, LANES), lambda b, h, s_, ki, *refs: im(b, h, s_, ki, *refs)[:1] + (0,))
-
-        in_specs += [par_spec(j) for j in range(bp)]
-        operands += [kp] * bp
-        in_specs += [par_spec(j) for j in range(bp)]
-        operands += [vp] * bp
+        in_specs.append(pl.BlockSpec(
+            (1, 1, 4, bp * page),
+            lambda b, h, s_, ki, *_: (b, s_ * num_blocks + ki, 0, 0)))
+        operands.append(_page_param_rows(bt, kv_pages[2:], page, bp))
 
     kernel = functools.partial(
         _paged_kernel, sm_scale=scale, page=page, block_pages=bp,
@@ -387,22 +406,22 @@ def _paged_attention_impl(q, kv_pages, block_table, cache_offset, kv_start,
             pltpu.VMEM((rows, D), jnp.float32),
         ],
     )
-    compiler_params = None
-    if not interpret and pltpu is not None:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
-    acc, m, l = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, NKV, sk, rows, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, NKV, sk, rows, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((B, NKV, sk, rows, LANES), jnp.float32),
-        ],
-        compiler_params=compiler_params,
-        interpret=interpret,
-    )(bt, off, start, *operands)
+
+    def call(interp):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((B, NKV, sk, rows, D), jnp.float32),
+                jax.ShapeDtypeStruct((B, NKV, sk, rows, LANES), jnp.float32),
+                jax.ShapeDtypeStruct((B, NKV, sk, rows, LANES), jnp.float32),
+            ],
+            compiler_params=_compiler_params(_GRID_SEMANTICS, interp),
+            interpret=interp,
+            name="paged_attention",
+        )
+
+    acc, m, l = run_kernel(call, interpret, bt, off, start, *operands)
 
     # Flash-Decoding epilogue: merge the split partials by logsumexp weight.
     # An empty split carries (m = NEG_INF, l = 0, acc = 0) and contributes
@@ -439,7 +458,7 @@ def paged_attention(
     ``q [B, S, NQ, D]`` (post-RoPE, model layout; ``S = 1`` is the serving
     decode step, ``S = k+1`` the speculative verification chunk);
     ``kv_pages`` is ONE layer's pool entry — the fp pair
-    ``(k [NP, page, NKV, D], v)`` or the int8 six-tuple ``(k, v, k_scale,
+    ``(k [NP, NKV, page, D], v)`` or the int8 six-tuple ``(k, v, k_scale,
     k_zero, v_scale, v_zero)`` (``kvcache.pool`` layout, dequantized
     in-kernel); ``block_table [B, PP]`` maps each slot's logical pages to
     physical ones; ``cache_offset [B]`` is the cache index of query row 0
@@ -455,7 +474,10 @@ def paged_attention(
     (Mistral SWA, Gemma-2 softcapping and decoupled scale), so every model
     family on the LlamaAttention path is served.  ``block_pages``/
     ``split_k`` default from :func:`lookup_defaults`; ``interpret`` auto
-    (pallas interpreter off-TPU), matching ``ops.flash_attention``.
+    (compiled where the program lowers for a TPU, the pallas interpreter
+    elsewhere), matching ``ops.flash_attention``.  The compiled kernel
+    needs ``page`` to be a multiple of 8 (one fp32 sublane tile) and
+    ``D`` of 128; the interpreter takes any shape.
 
     On a live tp > 1 mesh the kernel runs under a ``shard_map`` over the
     kv-head axis: heads shard naturally (each ``(slot, kv-head)`` grid
@@ -466,20 +488,17 @@ def paged_attention(
 
     Returns ``[B, S, NQ, D]`` in ``q.dtype``.
     """
-    if pltpu is None:  # pragma: no cover - CPU builds ship pltpu today
-        raise RuntimeError("pallas TPU namespace unavailable")
     if len(kv_pages) not in (2, 6):
         raise ValueError(
             f"kv_pages must be a layer's fp pair or int8 six-tuple, got "
             f"{len(kv_pages)} arrays")
-    if q.shape[2] % kv_pages[0].shape[2]:
+    nkv = kv_pages[0].shape[1]
+    if q.shape[2] % nkv:
         raise ValueError(
-            f"q heads ({q.shape[2]}) must group over kv heads "
-            f"({kv_pages[0].shape[2]})")
+            f"q heads ({q.shape[2]}) must group over kv heads ({nkv})")
     kw = dict(sm_scale=sm_scale, window=window, softcap=softcap,
-              block_pages=block_pages, split_k=split_k,
-              interpret=_auto_interpret(interpret))
-    wrap = _tp_shard_mapped(q.shape[2], kv_pages[0].shape[2])
+              block_pages=block_pages, split_k=split_k, interpret=interpret)
+    wrap = _tp_shard_mapped(q.shape[2], nkv)
     if wrap is not None:
         if kv_start is None:
             kv_start = jnp.zeros(cache_offset.shape, jnp.int32)
@@ -510,30 +529,53 @@ def _tp_shard_mapped(nq: int, nkv: int):
         return None
     from jax.sharding import PartitionSpec as P
 
-    from neuronx_distributed_tpu.utils.common import shard_map
-
-    heads = P(None, None, TENSOR_AXIS, None)
+    q_heads = P(None, None, TENSOR_AXIS, None)     # q/out [B, S, NQ, D]
+    pool_heads = P(None, TENSOR_AXIS, None, None)  # pool [NP, NKV, page, D]
 
     def wrap(kw):
         def per_shard(q_, pool_, bt_, off_, start_):
             return _paged_attention_impl(q_, pool_, bt_, off_, start_, **kw)
 
-        pool_spec = tuple(heads if i < 2 else P(None)
-                          for i in range(6))  # trimmed to the pool's arity
-
         def call(q_, pool_, bt_, off_, start_):
-            # full-manual over the whole mesh (the 0.4-era shim refuses
-            # partial-manual): every non-tp axis is explicitly replicated
-            return shard_map(
-                per_shard, mesh,
-                in_specs=(heads, pool_spec[:len(pool_)], P(None, None),
-                          P(None), P(None)),
-                out_specs=heads,
+            # manual over the WHOLE mesh: every non-tp axis is explicitly
+            # replicated, so the Mosaic call never meets an auto axis it
+            # would have to be partitioned over
+            pool_spec = tuple(pool_heads if x.ndim == 4 else P(None)
+                              for x in pool_)
+            return jax.shard_map(
+                per_shard, mesh=mesh,
+                in_specs=(q_heads, pool_spec, P(None, None), P(None),
+                          P(None)),
+                out_specs=q_heads, check_vma=False,
             )(q_, pool_, bt_, off_, start_)
 
         return call
 
     return wrap
+
+
+def gather_page_chain(kv_pages, block_table, dtype):
+    """One layer's pool entry -> the slots' contiguous ``(k, v)`` views
+    ``[B, T, NKV, D]`` through ``block_table [B, PP]`` — the gather path's
+    (and the oracle's) O(T) clone, in the layout the dense attention core
+    attends over.  An int8 six-tuple dequantizes in the gather (page params
+    gather alongside the pages) into ``dtype``."""
+    B, PP = block_table.shape
+
+    def view(pages, scale=None, zero=None):
+        g = pages[block_table]  # [B, PP, NKV, page, D]
+        if scale is not None:
+            from neuronx_distributed_tpu.kvcache.quant import dequantize_page
+
+            g = dequantize_page(g, scale[block_table], zero[block_table],
+                                dtype=dtype)
+        _, _, NKV, page, D = g.shape
+        return g.transpose(0, 1, 3, 2, 4).reshape(B, PP * page, NKV, D)
+
+    if len(kv_pages) == 6:
+        ck, cv, ks, kz, vs, vz = kv_pages
+        return view(ck, ks, kz), view(cv, vs, vz)
+    return view(kv_pages[0]), view(kv_pages[1])
 
 
 def paged_attention_reference(q, kv_pages, block_table, cache_offset,
@@ -543,25 +585,8 @@ def paged_attention_reference(q, kv_pages, block_table, cache_offset,
     dequantize) the chain into the contiguous ``[B, T]`` view, band-mask,
     softmax — except parked rows (``offset >= T``) are zeroed to match the
     kernel's contract.  The parity tests pin the kernel against this."""
-    quantized = len(kv_pages) == 6
-    if quantized:
-        from neuronx_distributed_tpu.kvcache.quant import dequantize_page
-
-        ck, cv, ks, kz, vs, vz = kv_pages
-        B = block_table.shape[0]
-        T = block_table.shape[1] * ck.shape[1]
-        k = dequantize_page(ck[block_table], ks[block_table],
-                            kz[block_table], dtype=q.dtype).reshape(
-                                B, T, ck.shape[2], ck.shape[3])
-        v = dequantize_page(cv[block_table], vs[block_table],
-                            vz[block_table], dtype=q.dtype).reshape(
-                                B, T, cv.shape[2], cv.shape[3])
-    else:
-        ck, cv = kv_pages
-        B = block_table.shape[0]
-        T = block_table.shape[1] * ck.shape[1]
-        k = ck[block_table].reshape(B, T, ck.shape[2], ck.shape[3])
-        v = cv[block_table].reshape(B, T, cv.shape[2], cv.shape[3])
+    k, v = gather_page_chain(kv_pages, block_table, q.dtype)
+    B, T = k.shape[0], k.shape[1]
     S, NQ, D = q.shape[1], q.shape[2], q.shape[3]
     NKV = k.shape[2]
     G = NQ // NKV

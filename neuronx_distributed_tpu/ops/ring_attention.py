@@ -57,7 +57,6 @@ from neuronx_distributed_tpu.parallel.mesh import (
     ambient_manual_axes as _ambient_manual_axes,
     get_mesh,
 )
-from neuronx_distributed_tpu.utils.common import shard_map as _shard_map
 from neuronx_distributed_tpu.utils.logger import get_logger
 
 logger = get_logger(__name__)
@@ -590,14 +589,10 @@ def ring_attention(
             )
 
     # Nested shard_map (inside the PP engine) must receive the current
-    # *abstract* mesh, whose axis_types record the outer manual axes; on
-    # jax < 0.5 (no abstract-mesh tracking) the concrete mesh plus the
-    # compat shim's `auto` complement expresses the same partial-manual.
-    ambient_mesh = ambient and getattr(jax.sharding, "get_abstract_mesh", None)
-    mesh_arg = ambient_mesh() if ambient_mesh else mesh
-    o = _shard_map(
+    # *abstract* mesh, whose axis_types record the outer manual axes.
+    o = jax.shard_map(
         body,
-        mesh=mesh_arg,
+        mesh=jax.sharding.get_abstract_mesh() if ambient else mesh,
         in_specs=(q_spec, kv_spec, kv_spec, *extra_specs),
         out_specs=q_spec,
         axis_names=new_manual,

@@ -50,23 +50,43 @@ Initializer = Callable[..., jax.Array]
 _U = P.UNCONSTRAINED
 
 
+def init_sharded_params(module: nn.Module, rng: jax.Array, *example_inputs,
+                        spec_map: Callable[[Any, Any], Any] | None = None):
+    """``module.init`` with every parameter BORN sharded over the global
+    mesh: shapes and partition specs come from an abstract evaluation, and
+    the init program's ``out_shardings`` place each leaf as it is created —
+    the whole unsharded model never sits on one device (at 7B widths that
+    one device would be chip 0, and it would not fit).  ``example_inputs``
+    are abstract-evaluated only.  ``spec_map(specs, abstract_params)`` may
+    rewrite the specs (FSDP adds dp).  Returns ``(params, param_specs)``,
+    params unboxed."""
+    mesh = get_mesh()
+    abs_params = jax.eval_shape(module.init, rng, *example_inputs)
+    specs = nn.get_partition_spec(abs_params)
+    if spec_map is not None:
+        specs = spec_map(specs, nn.unbox(abs_params))
+    shardings = jax.tree.map(
+        lambda s: NamedSharding(mesh, s), specs,
+        is_leaf=lambda x: isinstance(x, P))
+    init = jax.jit(lambda r, *a: nn.unbox(module.init(r, *a)),
+                   out_shardings=shardings)
+    return init(rng, *example_inputs), specs
+
+
 def shard_activation(x: jax.Array, spec: P) -> jax.Array:
     """Constrain ``x``'s sharding over the global mesh (no-op if no mesh).
 
     Inside a partial-manual ``shard_map`` region (the pipeline engine makes
     ``pp`` manual) the constraint must be expressed against the *abstract*
     context mesh — a NamedSharding over the concrete mesh carries all-Auto
-    axis types and is rejected by jax 0.9's canonicalization when any axis
-    is Manual in context.  On older jax (< 0.5) there is no abstract-mesh
-    tracking; the concrete-mesh constraint is the classic behavior."""
+    axis types and is rejected by jax's canonicalization when any axis is
+    Manual in context."""
     if not model_parallel_is_initialized():
         return x
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        abstract = get_abstract()
-        if abstract.axis_names:  # inside jit/shard_map: use the context mesh
-            return jax.lax.with_sharding_constraint(
-                x, NamedSharding(abstract, spec))
+    abstract = jax.sharding.get_abstract_mesh()
+    if abstract.axis_names:  # inside jit/shard_map: use the context mesh
+        return jax.lax.with_sharding_constraint(
+            x, NamedSharding(abstract, spec))
     return jax.lax.with_sharding_constraint(x, NamedSharding(get_mesh(), spec))
 
 

@@ -138,28 +138,29 @@ def _build_device_array(devices: Sequence[jax.Device], shape: Sequence[int]) -> 
     if math.prod(shape) != len(devices):
         raise ValueError(f"mesh shape {tuple(shape)} does not match device count {len(devices)}")
     if devices and devices[0].platform == "tpu" and len(devices) > 1:
-        n_slices = len({getattr(d, "slice_index", 0) for d in devices})
-        try:
-            from jax.experimental import mesh_utils
+        # No fallback to a plain reshape here: a mesh that ignores the
+        # physical topology still runs, only with its collectives on the
+        # wrong links, and nothing downstream would notice.  What
+        # mesh_utils cannot lay out, it raises.
+        from jax.experimental import mesh_utils
 
-            if n_slices > 1 and shape[0] % n_slices == 0:
-                dcn_shape = (n_slices,) + (1,) * (len(shape) - 1)
-                local_shape = (shape[0] // n_slices, *shape[1:])
-                return mesh_utils.create_hybrid_device_mesh(
-                    local_shape, dcn_shape, devices=devices
-                )
-            if n_slices > 1:
-                # dp cannot absorb the slice boundary (e.g. dp=1, pp across
-                # slices — the reference's 70B topology): a legitimate
-                # layout, just with model-parallel traffic on DCN
-                logger.warning(
-                    "dp=%d not divisible by %d slices; letting "
-                    "create_device_mesh choose the layout (some model-"
-                    "parallel collectives will cross DCN)", shape[0], n_slices,
-                )
-            return mesh_utils.create_device_mesh(tuple(shape), devices=devices)
-        except Exception as e:  # pragma: no cover - topology helpers can be picky
-            logger.warning("mesh_utils device-mesh construction failed (%s); falling back to reshape", e)
+        n_slices = len({getattr(d, "slice_index", 0) for d in devices})
+        if n_slices > 1 and shape[0] % n_slices == 0:
+            dcn_shape = (n_slices,) + (1,) * (len(shape) - 1)
+            local_shape = (shape[0] // n_slices, *shape[1:])
+            return mesh_utils.create_hybrid_device_mesh(
+                local_shape, dcn_shape, devices=devices
+            )
+        if n_slices > 1:
+            # dp cannot absorb the slice boundary (e.g. dp=1, pp across
+            # slices — the reference's 70B topology): a legitimate
+            # layout, just with model-parallel traffic on DCN
+            logger.warning(
+                "dp=%d not divisible by %d slices; letting "
+                "create_device_mesh choose the layout (some model-"
+                "parallel collectives will cross DCN)", shape[0], n_slices,
+            )
+        return mesh_utils.create_device_mesh(tuple(shape), devices=devices)
     return np.asarray(devices).reshape(tuple(shape))
 
 
@@ -297,12 +298,8 @@ def _axis_size(mesh: Optional[Mesh], *axes: str) -> int:
 
 
 def manual_axis_size(axis_name: str) -> int:
-    """Trace-time size of a manual (shard_map) axis, version-portable:
-    jax >= 0.5 has ``lax.axis_size``; older jax folds ``psum(1, axis)`` to
-    the same static constant."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return int(jax.lax.psum(1, axis_name))
+    """Trace-time size of a manual (shard_map) axis."""
+    return jax.lax.axis_size(axis_name)
 
 
 def get_tensor_parallel_size(mesh: Optional[Mesh] = None) -> int:

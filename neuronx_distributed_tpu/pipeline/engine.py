@@ -61,7 +61,6 @@ from neuronx_distributed_tpu.pipeline.partition import (
     padded_layer_layout,
 )
 from neuronx_distributed_tpu.pipeline.scheduler import build_sync_slot_tables
-from neuronx_distributed_tpu.utils.common import shard_map as _shard_map
 
 # Param-tree keys understood by the engine.
 EMBED = "embed"
@@ -417,7 +416,7 @@ def make_pipelined_loss_fn(
         # the shard_map transpose psums parameter cotangents over dp — the
         # explicit form of the reference's bucketed DP grad all-reduce
         # (grads.py:193-246).
-        shmap = _shard_map(
+        shmap = jax.shard_map(
             f,
             mesh=mesh,
             in_specs=(_layer_in_specs(layer_specs), P(), P(),
@@ -737,7 +736,7 @@ def make_1f1b_loss_and_grad_fn(
 
         # dp/ep manual alongside pp — see make_pipelined_loss_fn's note
         lspecs = _layer_in_specs(layer_specs)
-        shmap = _shard_map(
+        shmap = jax.shard_map(
             f,
             mesh=mesh,
             in_specs=(lspecs, P(), P(), P(None, BATCH_AXES), P(None, BATCH_AXES),
@@ -1062,7 +1061,7 @@ def make_interleaved_1f1b_loss_and_grad_fn(
             return (loss_sum, tok_sum), {LAYERS: gl, EMBED: ge, HEAD: gh}
 
         lspecs = _layer_in_specs(layer_specs)
-        shmap = _shard_map(
+        shmap = jax.shard_map(
             f,
             mesh=mesh,
             in_specs=(lspecs, P(), P(), P(None, BATCH_AXES), P(None, BATCH_AXES),
@@ -1205,7 +1204,7 @@ def make_interleaved_fwd_fn(
             aux_sum = lax.psum(aux_sum, (DATA_AXIS, EXPERT_AXIS, PIPELINE_AXIS))
             return outs, aux_sum
 
-        shmap = _shard_map(
+        shmap = jax.shard_map(
             f,
             mesh=mesh,
             in_specs=(_layer_in_specs(layer_specs), P(), P(None, BATCH_AXES),
@@ -1623,7 +1622,7 @@ def make_pipelined_forward_fn(
             return lax.psum(outs, PIPELINE_AXIS)
 
         # dp/ep manual alongside pp — see make_pipelined_loss_fn's note
-        shmap = _shard_map(
+        shmap = jax.shard_map(
             f,
             mesh=mesh,
             in_specs=(_layer_in_specs(layer_specs), P(), P(None, BATCH_AXES),

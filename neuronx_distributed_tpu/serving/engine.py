@@ -617,11 +617,11 @@ class ServingEngine:
                 registry=self.registry, prefix_cache=prefix_cache,
                 spec_overshoot=self._spec_k)
         # block-table-native paged decode (ops.paged_attention): "auto"
-        # follows the model wrapper's resolved default (kernel on TPU at
-        # tp == 1, gather elsewhere); explicit True/False overrides per
-        # engine.  Gather-path steps account their [B, T] K/V
-        # rematerialization into kvcache/gather_bytes_total — the counter
-        # the kernel path keeps at ZERO (the int8 acceptance gate).
+        # follows the model wrapper's resolved default (kernel when its
+        # programs run on a TPU, gather elsewhere); explicit True/False
+        # overrides per engine.  Gather-path steps account their [B, T]
+        # K/V rematerialization into kvcache/gather_bytes_total — the
+        # counter the kernel path keeps at ZERO (the int8 acceptance gate).
         if paged_kernel is True and self._kv is None:
             raise ValueError(
                 "paged_kernel=True needs the paged engine (page_size=/"
@@ -1610,12 +1610,15 @@ class ServingEngine:
         page-aligned prompt KV into the slot's reserved pages through
         ``prefill_chunk_pages``, and the FINAL chunk's last-position logits
         are the prefill logits the shared first-token tail samples from —
-        token-identical to a whole ``prefill_one``.  The start slot rotates
-        step to step so one long prompt cannot hog the budget, and each
-        slot's deadline is re-checked immediately before its dispatch (a
-        dead request never burns a chunk)."""
-        page = self._kv.page_size
-        budget = self._chunk_tokens // page  # pages this step may prefill
+        token-identical to a whole ``prefill_one``.  Every chunk runs the
+        ONE compiled program of the full budget width (a ragged tail is
+        right-padded, see :meth:`_dispatch_chunk`), so a step dispatches
+        ONE chunk, whatever that slot had left to prefill: prompt lengths
+        never multiply compiled programs.  The start slot rotates step to
+        step so one long prompt cannot hog the budget, and each slot's
+        deadline is re-checked immediately before its dispatch (a dead
+        request never burns a chunk)."""
+        width = self._chunk_tokens // self._kv.page_size  # pages a chunk spans
         slots = sorted(self._chunking)
         start = self._chunk_rr % len(slots)
         self._chunk_rr += 1
@@ -1626,8 +1629,6 @@ class ServingEngine:
             key=lambda s: self._chunking[s].req.priority
             != PRIORITY_INTERACTIVE)
         for slot in rotated:
-            if budget <= 0:
-                break
             st = self._chunking.get(slot)
             if st is None:
                 continue
@@ -1639,10 +1640,9 @@ class ServingEngine:
                 self._chunking.pop(slot, None)
                 self._expire_before_prefill(slot, req, outputs, now)
                 continue
-            n = min(budget, st.pages_remaining)
-            budget -= n
             try:
-                self._dispatch_chunk(slot, st, n)
+                self._dispatch_chunk(slot, st,
+                                     min(width, st.pages_remaining))
             except BaseException as e:
                 # transactional like the admission path: the one request
                 # fails, every page is reclaimed, then the fault propagates
@@ -1662,15 +1662,21 @@ class ServingEngine:
                 self._chunking.pop(slot, None)
                 self._finish_prefill(slot, req, st.logits, outputs,
                                      prefilled_fresh=True)
+            break  # the step's budget is one chunk program
 
     def _dispatch_chunk(self, slot: int, st: _ChunkPrefill,
                         n_pages: int) -> None:
         """One ``prefill_chunk_pages`` call covering the slot's next
-        ``n_pages`` fresh prompt pages (page-aligned, contiguous)."""
+        ``n_pages`` fresh prompt pages (page-aligned, contiguous).  The
+        program is always ``prefill_chunk_tokens`` wide: a shorter span (a
+        prompt's tail) is right-padded, and the logits are read at the
+        span's last row.  The pad rows lie past the prompt's end — invalid
+        cells, which the model's scatter never commits."""
         page = self._kv.page_size
         off = st.fresh[st.next_i][0] * page
         width = n_pages * page
-        ids_chunk = st.ids_row[off:off + width][None, :]
+        ids_chunk = np.zeros((1, self._chunk_tokens), np.int32)
+        ids_chunk[0, :width] = st.ids_row[off:off + width]
         tr = self.tracer
         # one shared start stamp: the chunk span and its perf accounting
         # measure the identical interval (attribution sums to the trace)
@@ -1696,7 +1702,7 @@ class ServingEngine:
                 jnp.asarray(ids_chunk), off,
                 self._kv.tables[slot][None, :].copy(), self.caches,
                 st.valid_row[None, :].copy(), apool=ad[0], atables=ad[1],
-                paged_kernel=self._paged_kernel)
+                paged_kernel=self._paged_kernel, last_row=width - 1)
         except BaseException as e:
             if t0 is not None:
                 t1 = self._clock()

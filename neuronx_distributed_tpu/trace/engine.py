@@ -366,6 +366,25 @@ def _serving_batch_axes(batch_size: int):
     return None
 
 
+def _kv_cache_sharding(batch_size: int, num_kv_heads: int):
+    """Sharding of a contiguous ``[B, T, NKV, D]`` KV cache: kv-heads over
+    tp and batch over dp when a mesh is live (None without one)."""
+    if not model_parallel_is_initialized():
+        return None
+    mesh = get_mesh()
+    # shard only the dims the shapes actually divide (small serving
+    # batches are often < dp; few kv heads may be < tp) — and say so,
+    # since replication multiplies per-device cache memory
+    batch_axes = _serving_batch_axes(batch_size)
+    kv_axes = TENSOR_AXIS if num_kv_heads % mesh.shape[TENSOR_AXIS] == 0 else None
+    if kv_axes is None and mesh.shape[TENSOR_AXIS] > 1:
+        logger.warning(
+            "kv cache head dim (%d) not divisible by tp (%d); replicating",
+            num_kv_heads, mesh.shape[TENSOR_AXIS],
+        )
+    return named_sharding(batch_axes, None, kv_axes, None)
+
+
 def init_kv_caches(
     num_layers: int,
     batch_size: int,
@@ -375,26 +394,15 @@ def init_kv_caches(
     dtype: Any = jnp.bfloat16,
 ):
     """Zero KV caches ``[B, T, NKV, D]`` per layer, kv-heads sharded over tp
-    and batch over dp when a mesh is live."""
+    and batch over dp when a mesh is live — born with that sharding, never
+    staged whole on one device."""
     shape = (batch_size, max_total_len, num_kv_heads, head_dim)
-    caches = [
-        (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)) for _ in range(num_layers)
+    sharding = _kv_cache_sharding(batch_size, num_kv_heads)
+    return [
+        (jnp.zeros(shape, dtype, device=sharding),
+         jnp.zeros(shape, dtype, device=sharding))
+        for _ in range(num_layers)
     ]
-    if model_parallel_is_initialized():
-        mesh = get_mesh()
-        # shard only the dims the shapes actually divide (small serving
-        # batches are often < dp; few kv heads may be < tp) — and say so,
-        # since replication multiplies per-device cache memory
-        batch_axes = _serving_batch_axes(batch_size)
-        kv_axes = TENSOR_AXIS if num_kv_heads % mesh.shape[TENSOR_AXIS] == 0 else None
-        if kv_axes is None and mesh.shape[TENSOR_AXIS] > 1:
-            logger.warning(
-                "kv cache head dim (%d) not divisible by tp (%d); replicating",
-                num_kv_heads, mesh.shape[TENSOR_AXIS],
-            )
-        spec = named_sharding(batch_axes, None, kv_axes, None)
-        caches = jax.tree.map(lambda x: jax.device_put(x, spec), caches)
-    return caches
 
 
 class _ServingBase:
@@ -686,7 +694,9 @@ class ParallelInferenceModel(_ServingBase):
         self.num_kv_heads = num_kv_heads if num_kv_heads is not None else mcfg.num_kv_heads
         self.head_dim = head_dim if head_dim is not None else mcfg.head_dim_
         # block-table-native paged decode (ops.paged_attention): "auto"
-        # resolves to the kernel on TPU at tp == 1 and the [B, T] gather
+        # resolves to the kernel when the programs run on a TPU — decided by
+        # the devices they are placed on (the mesh's, else the params'),
+        # not by the process's default backend — and to the [B, T] gather
         # path elsewhere; the per-call `paged_kernel=` kwarg on
         # decode_pages / decode_pages_lora / verify_pages overrides this
         # default (each value compiles its own cached program)
@@ -694,10 +704,18 @@ class ParallelInferenceModel(_ServingBase):
             resolve_paged_kernel,
         )
 
-        tp = (get_mesh().shape[TENSOR_AXIS]
-              if model_parallel_is_initialized() else 1)
-        self.paged_kernel = resolve_paged_kernel(paged_kernel, tp)
+        self.paged_kernel = resolve_paged_kernel(
+            paged_kernel, self._placement_platform())
         self._build()
+
+    def _placement_platform(self) -> str:
+        """Platform of the devices this wrapper's programs are placed on."""
+        if model_parallel_is_initialized():
+            return get_mesh().devices.flat[0].platform
+        sharding = getattr(jax.tree.leaves(self.params)[0], "sharding", None)
+        if sharding is None:  # host arrays: jit places them by default
+            return jax.devices()[0].platform
+        return next(iter(sharding.device_set)).platform
 
     # -- phase functions (pure; also used by the export path) --------------
 
@@ -925,8 +943,8 @@ class ParallelInferenceModel(_ServingBase):
             caches)
 
     def _paged_step_fn(self, params, toks, offsets, block_table, caches,
-                       valid, apool=None, atables=None, paged_kernel=False,
-                       update_valid=True, last_only=True):
+                       valid, apool=None, atables=None, last_row=None,
+                       paged_kernel=False, update_valid=True, last_only=True):
         """THE paged phase fn — one parameterized family serving decode,
         multi-adapter decode, speculative verify and chunked prefill (the
         former ``_decode_pages_fn`` / ``_decode_pages_lora_fn`` /
@@ -953,8 +971,10 @@ class ParallelInferenceModel(_ServingBase):
           chunked prefill pre-writes the FULL prompt's validity at
           admission (keys beyond the chunk are causally masked by the
           q-offset band), so its validity row passes through untouched;
-        - ``last_only`` — decode/prefill sample from the last position
-          only; verify needs the whole ``[B, S, V]`` chunk of logits.
+        - ``last_only`` — decode/prefill sample from one position only
+          (the last, or the traced row ``last_row`` when a prefill chunk is
+          right-padded to its program's fixed width); verify needs the
+          whole ``[B, S, V]`` chunk of logits.
 
         Since every configuration is one parameterization of this single
         fn, the offset/validity/position math — the token-identity
@@ -978,13 +998,16 @@ class ParallelInferenceModel(_ServingBase):
             params, toks, positions.astype(jnp.int32), caches, offsets,
             kv_valid=valid, block_table=block_table, **extra,
         )
-        if last_only:
+        if last_only and last_row is not None:
+            logits = jax.lax.dynamic_index_in_dim(
+                logits, last_row, axis=1, keepdims=False)
+        elif last_only:
             logits = logits[:, -1, :]
         return logits, caches, valid
 
     def _paged_phase(self, toks, offsets, block_table, caches, valid,
                      apool=None, atables=None, paged_kernel=None,
-                     update_valid=True, last_only=True):
+                     update_valid=True, last_only=True, last_row=None):
         """Compile-cache dispatcher for :meth:`_paged_step_fn`: every
         configuration jits the SAME underlying fn, keyed on its static
         parameterization — (chunk width, pool layout, batch rows, kernel
@@ -1007,7 +1030,8 @@ class ParallelInferenceModel(_ServingBase):
                 else "verify_pages" if not last_only
                 else "decode_pages_lora" if lora else "decode_pages")
         key = (name, self._pool_tag(caches), int(toks.shape[1]),
-               int(valid.shape[0]), pk, lora, update_valid, last_only)
+               int(valid.shape[0]), pk, lora, update_valid, last_only,
+               last_row is not None)
         fn = self._serving_cache.get(key)
         if fn is None:
             vout = (self._io_shardings["batch"](None)
@@ -1023,6 +1047,8 @@ class ParallelInferenceModel(_ServingBase):
                 jnp.asarray(block_table, jnp.int32), caches, valid)
         if lora:
             args = args + (apool, jnp.asarray(atables, jnp.int32))
+        if last_row is not None:
+            return fn(*args, last_row=jnp.int32(last_row))
         return fn(*args)
 
     def decode_pages(self, tok, offsets, block_table, caches, valid,
@@ -1132,7 +1158,8 @@ class ParallelInferenceModel(_ServingBase):
                   jnp.asarray(atable, jnp.int32))
 
     def prefill_chunk_pages(self, ids, offset, block_table, caches, valid,
-                            apool=None, atables=None, paged_kernel=None):
+                            apool=None, atables=None, paged_kernel=None,
+                            last_row=None):
         """Compiled paged chunk prefill (pool donated) — the ``S = Cc``,
         ``update_valid=False`` member of the :meth:`_paged_step_fn` family
         (Sarathi-style chunked prefill for the serving engine), lazily
@@ -1149,13 +1176,19 @@ class ParallelInferenceModel(_ServingBase):
         contributes nothing.  ``apool``/``atables`` prefill an adapter
         request's chunks with its LoRA deltas applied (the tenancy
         composition); ``paged_kernel`` walks the pool via the in-kernel
-        chunked-prefill path instead of the O(T) gather.  Returns the
-        chunk's last-position logits (the final chunk's are the prefill
-        logits the first token samples from) and the updated pool."""
+        chunked-prefill path instead of the O(T) gather.  ``last_row`` (a
+        traced scalar) names the chunk row whose logits are wanted when the
+        chunk is RIGHT-PADDED to a fixed program width: rows past it are
+        either later prompt positions (written early, rewritten by their
+        own chunk) or invalid cells past the prompt (never committed), so
+        one compiled program serves every chunk of every prompt length.
+        Returns that row's logits — the chunk's last position by default
+        (the final chunk's are the prefill logits the first token samples
+        from) — and the updated pool."""
         logits, caches, _ = self._paged_phase(
             ids, jnp.asarray([offset], jnp.int32), block_table, caches,
             valid, apool=apool, atables=atables, paged_kernel=paged_kernel,
-            update_valid=False, last_only=True)
+            update_valid=False, last_only=True, last_row=last_row)
         return logits, caches
 
     def verify_pages(self, toks, offsets, block_table, caches, valid,
@@ -1183,10 +1216,12 @@ class ParallelInferenceModel(_ServingBase):
         physical page ``phys`` of the pool (both traced scalars — ONE
         compiled program serves every page of every admission)."""
         def wr(c, r):
-            page = c.shape[1]
+            page = c.shape[2]
             chunk = jax.lax.dynamic_slice_in_dim(r, lp * page, page, axis=1)
+            # row caches are [1, T, NKV, D]; pool pages head-major
             return jax.lax.dynamic_update_slice(
-                c, chunk.astype(c.dtype), (phys, 0, 0, 0))
+                c, chunk.transpose(0, 2, 1, 3).astype(c.dtype),
+                (phys, 0, 0, 0))
 
         return jax.tree.map(wr, caches, row_caches)
 
@@ -1204,7 +1239,7 @@ class ParallelInferenceModel(_ServingBase):
 
         out = []
         for (ck, cv, ks, kz, vs, vz), (rk, rv) in zip(caches, row_caches):
-            page = ck.shape[1]
+            page = ck.shape[2]
 
             def one(cq, sc, zp, r):
                 chunk = jax.lax.dynamic_slice_in_dim(
@@ -1213,7 +1248,8 @@ class ParallelInferenceModel(_ServingBase):
                     v = jax.lax.dynamic_slice_in_dim(
                         row_valid, lp * page, page, axis=0)
                     chunk = chunk * (v > 0)[:, None, None].astype(chunk.dtype)
-                q2, s2, z2 = quantize_page(chunk)
+                # pool pages are head-major [NKV, page, D]
+                q2, s2, z2 = quantize_page(chunk.transpose(1, 0, 2))
                 cq = jax.lax.dynamic_update_slice(
                     cq, q2[None], (phys, 0, 0, 0))
                 sc = jax.lax.dynamic_update_slice(sc, s2[None], (phys,))
@@ -1333,15 +1369,15 @@ class ParallelInferenceModel(_ServingBase):
         tok_spec = bsds((B, 1))
         off_spec = jax.ShapeDtypeStruct((), jnp.int32)
         valid_spec = bsds((B, T))
-        cache_spec = jax.tree.map(
-            sds,
-            init_kv_caches(self.num_layers, B, T, self.num_kv_heads, self.head_dim,
-                           cfg.kv_cache_dtype),
-        )
-        cache_out = jax.tree.map(lambda s: s.sharding, cache_spec)
+        cache_sh = _kv_cache_sharding(B, self.num_kv_heads)
+        cache_leaf = jax.ShapeDtypeStruct(
+            (B, T, self.num_kv_heads, self.head_dim), cfg.kv_cache_dtype,
+            sharding=cache_sh)
+        cache_spec = [(cache_leaf, cache_leaf)] * self.num_layers
+        cache_out = [(cache_sh, cache_sh)] * self.num_layers
         params_spec = jax.tree.map(sds, self.params)
-        # keep the jitted phase fns: lower+compile here, and the export path
-        # reuses them (their lowering cache) instead of re-jitting from scratch
+        # keep the jitted phase fns: the export path reuses them (their
+        # lowering cache) instead of re-jitting from scratch.
         # logits never re-enter an AOT program (they go straight to eager
         # argmax/sampling), so their sharding stays unconstrained — pinning
         # them would force a full-vocab all-gather off the tp-split lm_head
@@ -1352,25 +1388,20 @@ class ParallelInferenceModel(_ServingBase):
             self._decode_fn, donate_argnums=(3,),
             out_shardings=(None, cache_out, bsh(None)),
         )
-        def aot(family, lowered):
-            # AOT phase-fn compile, ledger-timed: these are the programs a
-            # cold serving start pays for up front (the compile ledger's
-            # "aot" rows, with cost/memory stats off the executable)
-            led = self.compile_ledger
-            t0 = time.perf_counter()
-            compiled = lowered.compile()
-            if led is not None:
-                led.record_compile(family, (B, C, T),
-                                   (time.perf_counter() - t0) * 1e3,
-                                   kind="aot", compiled=compiled)
-            return compiled
-
-        self.context = aot(
-            "context", self._context_jit.lower(params_spec, ids_spec, vctx_spec))
-        # donated caches (arg 3) → in-place KV update
-        self.decode = aot("decode", self._decode_jit.lower(
-            params_spec, tok_spec, off_spec, cache_spec, valid_spec
-        ))
+        # The contiguous executables compile on FIRST USE, not here: a paged
+        # serving engine never calls them, and at real widths the batched
+        # [B, C] x [B, T] context program (dense scores over the whole
+        # padded batch) is one the chip's compiler refuses for memory —
+        # compiling it eagerly made every ParallelInferenceModel at such a
+        # size unconstructible for the sake of a program nothing would run.
+        self._aot_lowerings = {
+            "context": lambda: self._context_jit.lower(
+                params_spec, ids_spec, vctx_spec),
+            # donated caches (arg 3) → in-place KV update
+            "decode": lambda: self._decode_jit.lower(
+                params_spec, tok_spec, off_spec, cache_spec, valid_spec),
+        }
+        self._aot_compiled = {}
         self._io_shardings = {
             "batch": bsh, "cache_out": cache_out,
         }
@@ -1379,15 +1410,51 @@ class ParallelInferenceModel(_ServingBase):
                 self._prefill_chunk_fn, donate_argnums=(3,),
                 out_shardings=(None, cache_out),
             )
-            self.prefill_chunk = aot("prefill_chunk", self._prefill_chunk_jit.lower(
-                params_spec, ids_spec, off_spec, cache_spec, valid_spec
-            ))
+            self._aot_lowerings["prefill_chunk"] = (
+                lambda: self._prefill_chunk_jit.lower(
+                    params_spec, ids_spec, off_spec, cache_spec, valid_spec))
         self._loop_cache = _CompiledLRU("decode_loop", owner=self)
         self._serving_lru(reset=True)
         self._arg_specs = (
             params_spec, ids_spec, vctx_spec, tok_spec, off_spec, cache_spec,
             valid_spec,
         )
+
+    def _aot(self, family: str):
+        """The AOT executable of a contiguous phase fn, compiled on first
+        use and ledger-timed (the compile ledger's "aot" rows, with
+        cost/memory stats off the executable)."""
+        compiled = self._aot_compiled.get(family)
+        if compiled is None:
+            cfg = self.config
+            lowered = self._aot_lowerings[family]()
+            t0 = time.perf_counter()
+            compiled = lowered.compile()
+            if self.compile_ledger is not None:
+                self.compile_ledger.record_compile(
+                    family,
+                    (cfg.batch_size, cfg.context_len, cfg.max_total_len),
+                    (time.perf_counter() - t0) * 1e3,
+                    kind="aot", compiled=compiled)
+            self._aot_compiled[family] = compiled
+        return compiled
+
+    @property
+    def context(self):
+        return self._aot("context")
+
+    @property
+    def decode(self):
+        return self._aot("decode")
+
+    @property
+    def prefill_chunk(self):
+        if "prefill_chunk" not in self._aot_lowerings:
+            # hasattr() is how callers ask whether chunking was traced
+            raise AttributeError(
+                "no chunk-prefill executable: built without "
+                "InferenceConfig(chunked_prefill=True)")
+        return self._aot("prefill_chunk")
 
 
 def speculative_generate(

@@ -26,6 +26,7 @@ from neuronx_distributed_tpu.optimizer.adamw_fp32 import adamw_fp32, build_lr_sc
 from neuronx_distributed_tpu.optimizer.zero1 import optimizer_state_specs
 from neuronx_distributed_tpu.parallel.grads import clip_grad_norm
 from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+from neuronx_distributed_tpu.parallel.layers import init_sharded_params
 from neuronx_distributed_tpu.parallel.mesh import BATCH_AXES, get_mesh
 from neuronx_distributed_tpu.utils.logger import get_logger
 
@@ -208,27 +209,20 @@ def initialize_parallel_model(
 
     rng = jax.random.PRNGKey(config.seed if seed is None else seed)
 
-    abs_params = jax.eval_shape(module.init, rng, *example_inputs)
-    param_specs = nn.get_partition_spec(abs_params)
+    spec_map = None
     if config.fsdp:
         # ZeRO-3 placement: dp joins each param's spec on its largest free
         # dim; grads/optimizer states follow, XLA inserts the FSDP
         # all-gather/reduce-scatter pattern (optimizer/zero1.fsdp_spec)
         from neuronx_distributed_tpu.optimizer.zero1 import fsdp_spec
 
-        param_specs = jax.tree.map(
-            lambda s, leaf: fsdp_spec(s, leaf.shape, mesh),
-            param_specs, nn.unbox(abs_params),
-            is_leaf=lambda x: isinstance(x, P),
-        )
-    shardings = jax.tree.map(
-        lambda s: NamedSharding(mesh, s), param_specs, is_leaf=lambda x: isinstance(x, P)
-    )
+        def spec_map(specs, abs_params):
+            return jax.tree.map(
+                lambda s, leaf: fsdp_spec(s, leaf.shape, mesh),
+                specs, abs_params, is_leaf=lambda x: isinstance(x, P))
 
-    init_fn = jax.jit(
-        lambda r, *a: nn.unbox(module.init(r, *a)), out_shardings=shardings
-    )
-    params = init_fn(rng, *example_inputs)
+    params, param_specs = init_sharded_params(
+        module, rng, *example_inputs, spec_map=spec_map)
     model = ParallelModel(module=module, params=params, param_specs=param_specs, mesh=mesh)
     logger.info("initialized model: %.2fM params, sharded over %s", model.num_parameters() / 1e6, dict(mesh.shape))
     return model

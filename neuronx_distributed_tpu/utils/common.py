@@ -3,42 +3,6 @@
 from __future__ import annotations
 
 
-def shard_map(f, mesh, in_specs, out_specs, axis_names=None, check_vma=False):
-    """Version-portable ``jax.shard_map``.
-
-    The codebase targets the jax >= 0.5 surface (``jax.shard_map`` with
-    ``axis_names`` naming the MANUAL axes and ``check_vma``); on older jax
-    the same call maps onto ``jax.experimental.shard_map.shard_map`` with
-    the complementary ``auto`` set and ``check_rep``.  One shim so every
-    call site (engine, ring attention, tests, benches) stays on the new
-    spelling."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        kwargs = {"check_vma": check_vma}
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kwargs = {"check_rep": check_vma}
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            # 0.4-era partial-auto is incomplete in the XLA SPMD partitioner
-            # (PartitionId UNIMPLEMENTED errors, and some interleaved-engine
-            # programs abort the process outright) — refuse cleanly at trace
-            # time instead of letting XLA kill the run
-            raise NotImplementedError(
-                "partial-manual shard_map (manual axes "
-                f"{sorted(axis_names)} with auto axes {sorted(auto)}) "
-                f"requires jax >= 0.5; this environment has jax "
-                "without jax.shard_map")
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
-
-
 def ensure_divisibility(numerator: int, denominator: int) -> None:
     if numerator % denominator != 0:
         raise ValueError(f"{numerator} is not divisible by {denominator}")
@@ -58,30 +22,25 @@ def pad_to_multiple(n: int, multiple: int) -> int:
 def ensure_virtual_devices(n: int) -> None:
     """Force an ``n``-device virtual CPU mesh for dev/test parity runs.
 
-    When the resolved platform is already CPU with >= ``n`` devices this is a
-    no-op; otherwise the backend is reset onto CPU with ``n`` virtual
-    devices — including when a hardware platform is configured (probing a
-    hardware plugin just to count devices can block for minutes in sandboxed
-    environments, so we never initialize one here; a warning is logged
-    instead).  Do not call this on a run that should use the attached
+    When the configured platform is already CPU with >= ``n`` devices this
+    is a no-op; otherwise the backend is reset onto CPU with ``n`` virtual
+    devices.  An attached accelerator is never initialized here (a warning
+    says it is being passed over): a chip belongs to one process at a time,
+    and a dev run that touched it would hold it from the process that
+    measures on it.  Do not call this on a run that should use the attached
     accelerators."""
     import jax
 
     from neuronx_distributed_tpu.utils.logger import get_logger
 
-    # resolved config value, not the env var (the env may be stale relative
-    # to jax.config — see tests/conftest.py)
     platform = jax.config.jax_platforms
     if platform == "cpu":
-        try:
-            if len(jax.devices()) >= n:
-                return
-        except Exception:
-            pass
+        if len(jax.devices()) >= n:
+            return
     else:
         get_logger(__name__).warning(
             "ensure_virtual_devices: forcing a %d-device virtual CPU mesh "
-            "(configured platform %r is NOT probed or used)", n, platform,
+            "(configured platform %r is NOT initialized or used)", n, platform,
         )
     import jax.extend.backend as jeb
 

@@ -10,26 +10,18 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-# v5e-class default; callers pass their chip's numbers for other parts
-DEFAULT_PEAK_FLOPS = 197e12
-DEFAULT_HBM_BYTES_PER_S = 819e9
-
 
 def memory_analysis(compiled: Any) -> Optional[Dict[str, float]]:
     """Executable memory breakdown (argument / output / temp / generated-
-    code bytes) from ``compiled.memory_analysis()``, jax-version-guarded
-    like the ``cost_analysis`` list compat below: some versions return a
-    per-program list, some backends raise Unimplemented — both normalize to
-    a plain dict or None.  Feeds the memory ledger's per-program
-    temp/output accounting (``obs.memory_ledger.MemoryLedger
-    .note_program``): the temp bytes are the transient workspace a step
-    needs on top of the resident pools."""
+    code bytes) from ``compiled.memory_analysis()`` as a plain dict, or
+    None where the backend reports none (some raise Unimplemented).  Feeds
+    the memory ledger's per-program temp/output accounting
+    (``obs.memory_ledger.MemoryLedger.note_program``): the temp bytes are
+    the transient workspace a step needs on top of the resident pools."""
     try:
         ma = compiled.memory_analysis()
     except Exception:  # pragma: no cover - backend-dependent
         return None
-    if isinstance(ma, (list, tuple)):  # per-program list on some versions
-        ma = ma[0] if ma else None
     if ma is None:
         return None
     out: Dict[str, float] = {}
@@ -56,8 +48,6 @@ def cost_report(compiled: Any, collectives: bool = False) -> Dict[str, Any]:
     analysis alone doesn't give."""
     out: Dict[str, Any] = {}
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax < 0.5 returns [per-program dict]
-        ca = ca[0] if ca else {}
     # newer jax backends omit keys entirely instead of reporting 0 — a
     # missing key silently dropped here used to surface downstream as NaN
     # arithmetic intensities in the perf-attribution join.  Default to 0.0
@@ -90,13 +80,14 @@ def cost_report(compiled: Any, collectives: bool = False) -> Dict[str, Any]:
 
 def roofline(
     report: Dict[str, float],
-    peak_flops: float = DEFAULT_PEAK_FLOPS,
-    hbm_bytes_per_s: float = DEFAULT_HBM_BYTES_PER_S,
+    peak_flops: float,
+    hbm_bytes_per_s: float,
 ) -> Dict[str, float]:
     """Roofline lower bound for one execution of the reported program:
-    ``max(flops/peak, bytes/bandwidth)`` — measured step times below this are
-    physically impossible (the round-2 bench failure mode), far above it
-    indicate overhead or serialization to chase."""
+    ``max(flops/peak, bytes/bandwidth)`` — a measured step time below it
+    was not synchronized with the device, one far above it indicates
+    overhead or serialization to chase.  The peaks are the caller's
+    device's, from the one table (``obs.perf.device_spec``)."""
     flops = report.get("flops", 0.0)
     bytes_ = report.get("bytes_accessed", 0.0)
     t_compute = flops / peak_flops if peak_flops else 0.0
@@ -114,16 +105,17 @@ def roofline(
 def jit_cost_report(fn, *example_args, peak_flops: Optional[float] = None,
                     hbm_bytes_per_s: Optional[float] = None) -> Dict[str, Any]:
     """One-call convenience: lower+compile ``fn`` on the example args and
-    return ``{"cost": ..., "roofline": ...}``."""
+    return ``{"cost": ..., "roofline": ...}``.  The roofline runs against
+    the given peaks, else the published peaks of the device the process
+    runs on (an unknown device is an error)."""
     import jax
 
     compiled = jax.jit(fn).lower(*example_args).compile()
     rep = cost_report(compiled)
-    return {
-        "cost": rep,
-        "roofline": roofline(
-            rep,
-            peak_flops or DEFAULT_PEAK_FLOPS,
-            hbm_bytes_per_s or DEFAULT_HBM_BYTES_PER_S,
-        ),
-    }
+    if peak_flops is None or hbm_bytes_per_s is None:
+        from neuronx_distributed_tpu.obs.perf import device_spec
+
+        spec = device_spec()
+        peak_flops = peak_flops or spec.peak_flops
+        hbm_bytes_per_s = hbm_bytes_per_s or spec.hbm_bytes_per_s
+    return {"cost": rep, "roofline": roofline(rep, peak_flops, hbm_bytes_per_s)}

@@ -631,11 +631,10 @@ def test_llama_packed_cp_matches_dense(cp2_mesh):
                                rtol=2e-4, atol=2e-4)
 
 
-def test_packed_zigzag_odd_chunk_falls_back_to_dense(devices8):
-    """cp_zigzag packed gate: S=768 at cp=2 passes S%(128*cp) but the
-    zigzag CHUNK is 192 rows — not kernel-tileable — so the model must fall
-    back to the dense core instead of crashing at trace time."""
-    from conftest import sharded_params
+def test_packed_zigzag_odd_chunk_raises_shape_rule(devices8):
+    """cp_zigzag packed gate: S=768 at cp=2 divides over 128*cp but the
+    zigzag CHUNK is 192 rows — not kernel-tileable — so the kernel raises
+    its shape rule; a flash config is never handed to the dense core."""
     from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
     initialize_model_parallel(tensor_parallel_size=2, context_parallel_size=2,
@@ -649,9 +648,8 @@ def test_packed_zigzag_odd_chunk_falls_back_to_dense(devices8):
                            2 * jnp.ones((2, 368), jnp.int32)], axis=1)
     positions = jnp.broadcast_to(jnp.arange(768), ids.shape)
     model = LlamaForCausalLM(cfg)
-    params = sharded_params(model.init(jax.random.PRNGKey(1), ids))
-    lg = jax.jit(lambda p, i: model.apply(p, i, positions, segment_ids=seg))(params, ids)
-    assert np.isfinite(np.asarray(lg)).all()
+    with pytest.raises(ValueError, match="multiple of 128"):
+        model.init(jax.random.PRNGKey(1), ids, positions, segment_ids=seg)
 
 
 def test_ring_batch_indivisible_raises(devices8):
@@ -671,21 +669,24 @@ def test_ring_batch_indivisible_raises(devices8):
                                np.asarray(ref), rtol=1e-5, atol=1e-5)
 
 
-def test_packed_flash_odd_seq_falls_back_to_dense(devices8):
-    """A packed batch with a non-128-divisible sequence must keep working
-    (dense-core fallback), not crash at trace time."""
+def test_packed_flash_short_odd_seq_runs_the_kernel(devices8):
+    """A packed batch shorter than one 128-row tile is one kernel block:
+    the segmented kernel serves it (matching the dense core), it is not
+    swapped for the dense core behind the caller's back."""
     from conftest import sharded_params
     from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
     initialize_model_parallel(tensor_parallel_size=2, devices=devices8)
-    cfg = LlamaConfig.tiny(attention_impl="flash", sequence_parallel=False,
-                           dtype=jnp.float32, param_dtype=jnp.float32,
-                           max_seq_len=96, remat="none")
-    ids = jax.random.randint(jax.random.PRNGKey(0), (2, 96), 0, cfg.vocab_size)
+    base = dict(sequence_parallel=False, dtype=jnp.float32,
+                param_dtype=jnp.float32, max_seq_len=96, remat="none")
+    model_d = LlamaForCausalLM(LlamaConfig.tiny(attention_impl="dense", **base))
+    model_f = LlamaForCausalLM(LlamaConfig.tiny(attention_impl="flash", **base))
+    ids = jax.random.randint(jax.random.PRNGKey(0), (2, 96), 0, 256)
     seg = jnp.concatenate([jnp.ones((2, 40), jnp.int32),
                            2 * jnp.ones((2, 56), jnp.int32)], axis=1)
     positions = jnp.broadcast_to(jnp.arange(96), ids.shape)
-    model = LlamaForCausalLM(cfg)
-    params = sharded_params(model.init(jax.random.PRNGKey(1), ids))
-    lg = jax.jit(lambda p, i: model.apply(p, i, positions, segment_ids=seg))(params, ids)
-    assert np.isfinite(np.asarray(lg)).all()
+    params = sharded_params(model_d.init(jax.random.PRNGKey(1), ids))
+    lg_f = jax.jit(lambda p, i: model_f.apply(p, i, positions, segment_ids=seg))(params, ids)
+    lg_d = jax.jit(lambda p, i: model_d.apply(p, i, positions, segment_ids=seg))(params, ids)
+    np.testing.assert_allclose(np.asarray(lg_f), np.asarray(lg_d),
+                               rtol=2e-4, atol=2e-4)

@@ -36,6 +36,23 @@ def test_native_library_builds():
     assert _load_native() is not None
 
 
+def test_native_library_is_named_by_source_content(tmp_path, monkeypatch):
+    """Staleness is decided by content, not mtime: a copied tree may bring a
+    ``_build/*.so`` of another ``loader.cpp`` along, newer than the source —
+    it must simply not be the file this source loads."""
+    from neuronx_distributed_tpu.data import loader
+
+    assert loader.loader_backend() == "native"
+    current = loader._lib_path()
+    assert os.path.exists(current)
+    edited = tmp_path / "loader.cpp"
+    with open(loader._CSRC, "rb") as f:
+        edited.write_bytes(f.read() + b"\n// a later edit\n")
+    os.utime(edited, (0, 0))  # older than any build: mtime must not matter
+    monkeypatch.setattr(loader, "_CSRC", str(edited))
+    assert loader._lib_path() != current
+
+
 def _collect(loader):
     return list(loader)
 

@@ -831,7 +831,7 @@ def test_runner_serve_replicas_cli(tmp_path):
     stats = str(tmp_path / "router_stats.jsonl")
     proc = run_cli(
         os.path.join(repo, "examples", "inference", "runner.py"),
-        "serve", "--preset", "tiny", "--batch-size", "2",
+        "serve", "--preset", "tiny", "--dtype", "float32", "--batch-size", "2",
         "--context-len", "16", "--max-total-len", "32",
         "--max-new-tokens", "4", "--num-requests", "6", "--rate", "1000",
         "--page-size", "8", "--replicas", "3",

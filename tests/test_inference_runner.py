@@ -13,7 +13,7 @@ _RUNNER = os.path.join(_REPO, "examples", "inference", "runner.py")
 
 def test_trace_infer_check_accuracy_roundtrip(tmp_path):
     art = str(tmp_path / "traced")
-    run_cli(_RUNNER, "trace", "--preset", "tiny", "--tp", "2",
+    run_cli(_RUNNER, "trace", "--preset", "tiny", "--tp", "2", "--dtype", "float32",
             "--batch-size", "2", "--context-len", "32", "--max-total-len", "64",
             "--out", art, "--virtual-devices", "8")
     assert os.path.isdir(art)
@@ -24,6 +24,7 @@ def test_trace_infer_check_accuracy_roundtrip(tmp_path):
     assert len(gen) == 2 and all(len(row) == 8 for row in gen)
 
     proc = run_cli(_RUNNER, "check-accuracy", "--preset", "tiny", "--tp", "2",
+                   "--dtype", "float32",
                    "--batch-size", "2", "--context-len", "32",
                    "--max-total-len", "64", "--virtual-devices", "8")
     assert last_json_line(proc.stdout) == {"inference_success": 1}
@@ -32,7 +33,7 @@ def test_trace_infer_check_accuracy_roundtrip(tmp_path):
 def test_check_accuracy_gemma2_family():
     """Family dispatch through the serving CLI: Gemma-2 tiny (hybrid
     windows + softcaps) passes the cached-vs-teacher-forced check."""
-    proc = run_cli(_RUNNER, "check-accuracy", "--family", "gemma2",
+    proc = run_cli(_RUNNER, "check-accuracy", "--family", "gemma2", "--dtype", "float32",
                    "--preset", "tiny", "--tp", "2", "--batch-size", "2",
                    "--context-len", "32", "--max-total-len", "64",
                    "--virtual-devices", "8")

@@ -290,7 +290,7 @@ def test_page_pool_shapes_and_budget_math(devices8):
     pool = PagePool(num_layers=2, num_pages=6, page_size=4, num_kv_heads=8,
                     head_dim=8, dtype=jnp.float32)
     assert len(pool.caches) == 2
-    assert pool.caches[0][0].shape == (6, 4, 8, 8)
+    assert pool.caches[0][0].shape == (6, 8, 4, 8)  # [NP, NKV, page, D]
     assert pool.page_bytes == 2 * 2 * 4 * 8 * 8 * 4
     assert pool.total_bytes == 6 * pool.page_bytes
     # a contiguous [B=3, T=8] cache's budget buys exactly B*T/page pages
@@ -622,7 +622,7 @@ def test_runner_serve_paged_cli(tmp_path):
     stats = str(tmp_path / "serving_stats.jsonl")
     proc = run_cli(
         os.path.join(repo, "examples", "inference", "runner.py"), "serve",
-        "--preset", "tiny", "--batch-size", "3", "--context-len", "16",
+        "--preset", "tiny", "--dtype", "float32", "--batch-size", "3", "--context-len", "16",
         "--max-total-len", "32", "--num-requests", "5", "--rate", "100",
         "--max-new-tokens", "4", "--page-size", "8", "--quiet",
         "--stats-out", stats)

@@ -34,6 +34,7 @@ def test_llama_launcher_train_ckpt_resume(tmp_path):
     metrics = tmp_path / "metrics.json"
     common = [
         "--preset", "tiny", "--tp", "2", "--batch-size", "8", "--seq-len", "32",
+        "--dtype", "float32",
         "--lr", "3e-3", "--warmup-steps", "2", "--ckpt-dir", str(tmp_path / "ckpt"),
         "--ckpt-every", "2", "--metrics-file", str(metrics),
         "--scalar-dir", str(tmp_path / "scalars"),
@@ -58,7 +59,7 @@ def test_llama_launcher_pp_flash(tmp_path):
     metrics = tmp_path / "m.json"
     _run(
         "llama_pretrain.py", "--preset", "tiny", "--tp", "2", "--pp", "2",
-        "--microbatches", "2", "--no-sp", "--remat", "none", "--batch-size", "8",
+        "--dtype", "float32", "--microbatches", "2", "--no-sp", "--remat", "none", "--batch-size", "8",
         "--seq-len", "32", "--steps", "3", "--metrics-file", str(metrics),
     )
     assert json.loads(metrics.read_text())["completed_steps"] == 3
@@ -103,7 +104,7 @@ def test_llama_launcher_packed_mode(tmp_path):
 
     proc = _run(
         "llama_pretrain.py", "--preset", "tiny", "--tp", "2", "--batch-size", "4",
-        "--seq-len", "128", "--steps", "4", "--lr", "3e-3", "--attention", "flash",
+        "--dtype", "float32", "--seq-len", "128", "--steps", "4", "--lr", "3e-3", "--attention", "flash",
         "--data", str(data), "--packed", "--packed-eos-id", "255",
     )
     assert "packed" in proc.stdout

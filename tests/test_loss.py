@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from neuronx_distributed_tpu.utils.common import shard_map as _shard_map
 from neuronx_distributed_tpu.parallel.loss import (
     parallel_cross_entropy,
     vocab_parallel_cross_entropy,
@@ -54,7 +53,7 @@ def test_shard_map_path_matches_dense(mesh, smoothing):
 
         return jax.value_and_grad(loss_fn)(logits)
 
-    f = _shard_map(
+    f = jax.shard_map(
         prog,
         mesh=mesh,
         in_specs=(P(None, None, T), P(), P()),
@@ -106,7 +105,7 @@ def test_extreme_logits_stable(mesh):
     def prog(logits, targets):
         return vocab_parallel_cross_entropy(logits, targets)
 
-    f = _shard_map(
+    f = jax.shard_map(
         prog, mesh=mesh, in_specs=(P(None, T), P()), out_specs=P(), check_vma=False
     )
     out = np.asarray(f(logits, targets))

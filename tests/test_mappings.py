@@ -15,7 +15,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from neuronx_distributed_tpu.parallel import mappings as mp
-from neuronx_distributed_tpu.utils.common import shard_map as _shard_map
 from neuronx_distributed_tpu.parallel.mesh import (
     TENSOR_AXES,
     initialize_model_parallel,
@@ -34,7 +33,7 @@ def mesh(request, devices8):
 
 
 def shmap(f, mesh, in_specs, out_specs):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
 
 
 def test_copy_and_reduce_megatron_mlp(mesh):

@@ -168,7 +168,23 @@ def test_multislice_device_layout():
     assert captured["local"] == (2, 1, 1, 1, 1, 2)
     assert arr.shape == (4, 1, 1, 1, 1, 2)
 
-    # dp=1 over 2 slices (pp/tp across DCN) is legitimate: falls through to
-    # create_device_mesh (here: fails on fake devices -> reshape fallback)
-    arr2 = _build_device_array(devs, (1, 1, 1, 1, 1, 8))
-    assert arr2.shape == (1, 1, 1, 1, 1, 8)
+    # dp=1 over 2 slices (pp/tp across DCN) is legitimate: create_device_mesh
+    # chooses the layout
+    def fake_mesh(shape, devices=None):
+        import numpy as np
+
+        captured["single"] = tuple(shape)
+        return np.asarray(devices).reshape(tuple(shape))
+
+    with mock.patch("jax.experimental.mesh_utils.create_device_mesh", fake_mesh):
+        arr2 = _build_device_array(devs, (1, 1, 1, 1, 1, 8))
+    assert captured["single"] == arr2.shape == (1, 1, 1, 1, 1, 8)
+
+    # what mesh_utils cannot lay out is an error: a real TPU mesh never
+    # falls back to a topology-blind reshape behind the caller's back
+    def refuse(shape, devices=None):
+        raise NotImplementedError("no such topology")
+
+    with mock.patch("jax.experimental.mesh_utils.create_device_mesh", refuse):
+        with pytest.raises(NotImplementedError, match="no such topology"):
+            _build_device_array(devs[:4], (1, 1, 1, 1, 1, 4))

@@ -55,8 +55,8 @@ GATHER_BYTES = "kvcache/gather_bytes_total"
 
 
 def _rand_pool(rs, num_pages, page, nkv, d, quant=None):
-    kp = jnp.asarray(rs.standard_normal((num_pages, page, nkv, d)), jnp.float32)
-    vp = jnp.asarray(rs.standard_normal((num_pages, page, nkv, d)), jnp.float32)
+    kp = jnp.asarray(rs.standard_normal((num_pages, nkv, page, d)), jnp.float32)
+    vp = jnp.asarray(rs.standard_normal((num_pages, nkv, page, d)), jnp.float32)
     if quant == "int8":
         qk, ks, kz = quantize_page(kp)
         qv, vs, vz = quantize_page(vp)
@@ -164,7 +164,7 @@ def test_window_and_softcap_gemma2_shape():
 
 def test_defaults_lookup_and_resolution():
     """Table entries win; the heuristic fallback always divides the chain;
-    the auto flag resolves to the gather path off-TPU and explicit values
+    the auto flag resolves by the placement platform and explicit values
     pass through."""
     page, pp, nkv, d = 16, 512, 12, 128
     assert lookup_defaults(page, pp, nkv, d, None) == SHAPE_DEFAULTS[
@@ -175,17 +175,18 @@ def test_defaults_lookup_and_resolution():
         assert args[1] % bp == 0 and (args[1] // bp) % sk == 0
     assert resolve_paged_kernel(True) is True
     assert resolve_paged_kernel(False) is False
-    assert resolve_paged_kernel("auto") is (jax.default_backend() == "tpu")
-    # tp > 1 no longer forces the gather path — the kernel is shard_mapped
-    # over the kv-head axis, so auto resolves on backend alone and an
-    # explicit True is honored on any mesh
-    assert resolve_paged_kernel("auto", tensor_parallel=8) is (
-        jax.default_backend() == "tpu")
-    assert resolve_paged_kernel(True, tensor_parallel=8) is True
+    # auto resolves against the platform the caller's programs are placed
+    # on — never the process's default backend (here: cpu) — and refuses to
+    # guess when it is not told
+    assert resolve_paged_kernel("auto", "tpu") is True
+    assert resolve_paged_kernel("auto", "cpu") is False
+    assert resolve_paged_kernel(True, "cpu") is True
+    with pytest.raises(ValueError, match="platform"):
+        resolve_paged_kernel("auto")
     with pytest.raises(ValueError, match="paged_kernel"):
         resolve_paged_kernel("yes")
     with pytest.raises(ValueError, match="six-tuple"):
-        paged_attention(jnp.zeros((1, 1, 2, 8)), (jnp.zeros((2, 4, 2, 8)),) * 3,
+        paged_attention(jnp.zeros((1, 1, 2, 8)), (jnp.zeros((2, 2, 4, 8)),) * 3,
                         jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32))
 
 

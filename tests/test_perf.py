@@ -8,8 +8,8 @@ Layers:
   / ``attribute``: lower-bound times, compute-/memory-bound
   classification, MFU/MBU, pct_roofline, intensity-null-when-no-bytes,
   and the ``_total`` record whose floor is the SUM of per-family floors;
-- DEVICE SPECS — ``device_kind`` prefix lookup (longest prefix wins) and
-  the calibrate-once-per-process CPU fallback;
+- DEVICE SPECS — the one peak table keyed by ``device_kind``; an unknown
+  kind (the CPU included) raises, a CPU cost model is passed explicitly;
 - COST MODEL — ``utils.profiling.cost_report`` defaults missing cost
   keys to 0.0 and the ledger counts the degradation
   (``perf/cost_model_missing_total``);
@@ -20,7 +20,7 @@ Layers:
   within 1 ms, every family classifies compute- or memory-bound, and the
   ledger join supplies nonzero flops (program families -> phase
   families, weighted by LRU-counted executions);
-- TRAINER — ``fit()`` under ``Observability(perf=True)`` drops a
+- TRAINER — ``fit()`` under ``Observability(perf=<spec>)`` drops a
   schema-valid artifact and the obs report grows a perf section with an
   MFU rollup;
 - SURFACES — fleet merge (``merge_perf_records``), the default health
@@ -128,24 +128,36 @@ def test_total_record_sums_lower_bounds_and_tokens_ceiling(tmp_path):
 
 # -- device specs -------------------------------------------------------------
 
-def test_device_spec_prefix_table():
+def test_device_spec_is_keyed_by_device_kind():
     from types import SimpleNamespace as NS
 
-    assert device_spec(NS(device_kind="TPU v4 chip")).kind == "tpu v4"
-    # longest prefix wins: v5e before the bare v5p entry
+    # exact device_kind strings, both spellings jax knows a chip under
     assert device_spec(NS(device_kind="TPU v5 lite")).peak_flops == 197e12
+    assert device_spec(NS(device_kind="TPU v5e")).hbm_bytes_per_s == 819e9
     assert device_spec(NS(device_kind="TPU v5p")).peak_flops == 459e12
-    assert device_spec(NS(device_kind="TPU v6 lite")).kind == "tpu v6 lite"
+    assert device_spec(NS(device_kind="TPU v6 lite")).kind == "TPU v6 lite"
 
 
-def test_device_spec_cpu_fallback_is_calibrated_once():
+def test_unknown_device_kind_raises():
+    """One peak table, no default: an unknown accelerator — and the CPU
+    the tests run on — has no roofline; a caller that wants a cost model
+    there passes one explicitly."""
     from types import SimpleNamespace as NS
 
-    a = device_spec(NS(device_kind="mystery accelerator"))
-    b = device_spec(None) if not jax.devices()[0].device_kind.lower(
-        ).startswith("tpu") else device_spec(NS(device_kind="mystery"))
-    assert a is b                       # calibrated once, cached
-    assert a.peak_flops >= 1e9 and a.hbm_bytes_per_s >= 1e9
+    from neuronx_distributed_tpu.obs.perf import (
+        UnknownDeviceError,
+        calibrate_cpu_spec,
+    )
+
+    with pytest.raises(UnknownDeviceError, match="mystery accelerator"):
+        device_spec(NS(device_kind="mystery accelerator"))
+    with pytest.raises(UnknownDeviceError):
+        device_spec()  # jax.devices()[0] is the CPU here
+    with pytest.raises(UnknownDeviceError):
+        PerfAttribution()
+    a = calibrate_cpu_spec()
+    assert a is calibrate_cpu_spec()    # calibrated once, cached
+    assert a.kind == "cpu" and a.peak_flops >= 1e9 and a.hbm_bytes_per_s >= 1e9
 
 
 # -- cost model ---------------------------------------------------------------
@@ -290,7 +302,7 @@ def test_attribution_sums_to_traced_wall_time(config, devices8, tmp_path):
 # -- trainer ------------------------------------------------------------------
 
 def test_fit_perf_artifact_and_report_section(devices8, tmp_path):
-    """fit() under Observability(perf=True): the run drops a schema-valid
+    """fit() under Observability(perf=<cost model>): the run drops a schema-valid
     perf_attribution.jsonl whose train_step family carries ledger-joined
     flops, and the obs report grows the perf section + MFU rollup."""
     import neuronx_distributed_tpu as nxd
@@ -302,7 +314,7 @@ def test_fit_perf_artifact_and_report_section(devices8, tmp_path):
 
     config = nxd.training_config(tensor_parallel_size=2, learning_rate=5e-3)
     m, o = _build(config)
-    obs = Observability(str(tmp_path / "obs"), ledgers=True, perf=True)
+    obs = Observability(str(tmp_path / "obs"), ledgers=True, perf=SPEC)
     res = fit(config, m, o, _step_data(), steps=5, **_fit_kwargs(), obs=obs)
     assert res.steps_run == 5
     obs.close()

@@ -290,6 +290,11 @@ def test_interception_completeness_monkeypatched_counter(devices8,
     monkeypatch.setattr(trace_engine._CompiledLRU, "put", counting_put)
 
     cfg, model = _tiny_model(ledger=led)
+    # the contiguous context/decode pair compiles AOT on first use (a paged
+    # engine never calls it): a stepped solo generate is what asks for both
+    prompt = jnp.zeros((model.config.batch_size, model.config.context_len),
+                       jnp.int32)
+    jax.block_until_ready(model.generate(prompt, 2, fused=False))
     engine = ServingEngine(model, page_size=4, num_pages=16,
                            compile_ledger=led)
     rs = np.random.RandomState(0)
@@ -595,3 +600,8 @@ def test_bench_cpu_emits_compile_fields():
     assert rec["compile_warm_ms"] > 0
     # cold includes the trace+compile; warm is a cached dispatch
     assert rec["compile_warm_ms"] <= rec["compile_cold_ms"]
+    # the rehearsal is labelled for what it is: cpu, no device metric name,
+    # no MFU or roofline figure
+    assert rec["platform"] == "cpu" and rec["device_count"] >= 1
+    assert rec["metric"] == "cpu_rehearsal_tokens_per_sec"
+    assert "mfu_model" not in rec and "vs_baseline" not in rec

@@ -695,7 +695,7 @@ def test_fleet_expired_clone_fails_terminally_as_timed_out():
 def test_serve_bench_slo_tiny_cli():
     """`serve_bench --slo --tiny` runs the three rungs end to end and
     emits one structurally-sound JSON line each.  The 2x latency gates are
-    sized for silicon (tpu_watch runs them there); on the CPU tiny model
+    sized for silicon; on the CPU tiny model
     the timing is noise-dominated, so this asserts structure — all three
     modes emitted, every request finished, the SLO engine actually chunked
     — not the rc."""

@@ -409,7 +409,7 @@ def test_runner_serve_spec_cli(tmp_path):
     stats = str(tmp_path / "serving_stats.jsonl")
     proc = run_cli(
         os.path.join(repo, "examples", "inference", "runner.py"), "serve",
-        "--preset", "tiny", "--batch-size", "3", "--context-len", "16",
+        "--preset", "tiny", "--dtype", "float32", "--batch-size", "3", "--context-len", "16",
         "--max-total-len", "64", "--num-requests", "5", "--rate", "100",
         "--max-new-tokens", "4", "--page-size", "8", "--quiet",
         "--draft", "tiny", "--spec-k", "3", "--stats-out", stats)

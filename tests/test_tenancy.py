@@ -836,7 +836,7 @@ def test_serve_bench_kv_quant_tiny_cli():
 def test_runner_serve_adapters_kv_dtype_cli():
     proc = run_cli(
         os.path.join(REPO, "examples", "inference", "runner.py"),
-        "serve", "--preset", "tiny", "--batch-size", "3",
+        "serve", "--preset", "tiny", "--dtype", "float32", "--batch-size", "3",
         "--context-len", "16", "--max-total-len", "32", "--page-size", "8",
         "--adapters", "2", "--kv-dtype", "int8", "--num-requests", "4",
         "--max-new-tokens", "3", "--quiet", timeout=560)

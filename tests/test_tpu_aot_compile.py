@@ -1,0 +1,153 @@
+"""The attention kernels compile for the chip — asked of the chip's compiler,
+without the chip.
+
+libtpu compiles for a TPU that is DESCRIBED, not attached
+(``jax.experimental.topologies``), so each case here is an AOT compile for a
+``v5e:2x2`` host at Mistral-7B / Llama-3 attention geometry (32 q / 8 kv
+heads, head_dim 128, bf16), through the production entry points with the
+``interpret`` argument left at its default.  Interpret-mode parity (the rest
+of the suite) cannot see what these see: a block shape Mosaic refuses, a
+kernel that cannot be partitioned, a program that lowers for a TPU and comes
+out interpreted.  Every case asserts the Mosaic ``tpu_custom_call`` is in the
+compiled program.  Nothing runs, so nothing here says anything about results
+or times; ``chip_smoke.py`` checks the same kernels against their references
+on the chip.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from neuronx_distributed_tpu.ops.paged_attention import paged_attention
+from neuronx_distributed_tpu.ops.ring_attention import ring_attention
+from neuronx_distributed_tpu.parallel.mesh import initialize_model_parallel
+
+NQ, NKV, D = 32, 8, 128
+WINDOW = 4096
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip (the next one warns and compiles
+    again), so the cache is off around these."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _mesh(topo, tp=1):
+    return initialize_model_parallel(
+        tensor_parallel_size=tp, devices=topo.devices[:tp])
+
+
+def _compiled_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# -- flash attention, through ring_attention (what the models call) ---------
+
+
+FLASH_CASES = {
+    # name: (S, window, segmented, backward, custom calls expected)
+    "fwd": (2048, None, False, False, 1),
+    "bwd": (2048, None, False, True, 3),          # fwd + dq + dkv
+    "window4096_s8192_bwd": (8192, WINDOW, False, True, 3),
+    "segmented_bwd": (2048, None, True, True, 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FLASH_CASES))
+def test_flash_kernel_compiles_for_v5e(topo, case):
+    S, window, segmented, backward, n_calls = FLASH_CASES[case]
+    mesh = _mesh(topo)
+    sh = NamedSharding(mesh, P())
+    q = jax.ShapeDtypeStruct((1, S, NQ, D), jnp.bfloat16, sharding=sh)
+    kv = jax.ShapeDtypeStruct((1, S, NKV, D), jnp.bfloat16, sharding=sh)
+    seg = jax.ShapeDtypeStruct((1, S), jnp.int32, sharding=sh)
+
+    def attn(q, k, v, seg):
+        return ring_attention(q, k, v, causal=True, window=window,
+                              segment_ids=seg if segmented else None)
+
+    def loss(q, k, v, seg):
+        return jnp.sum(attn(q, k, v, seg).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else attn
+    text = _compiled_text(fn, q, kv, kv, seg)
+    assert text.count("tpu_custom_call") >= n_calls, (
+        f"{case}: expected {n_calls} Mosaic kernel(s) in the program "
+        f"compiled for the TPU, found {text.count('tpu_custom_call')}")
+
+
+# -- paged attention ---------------------------------------------------------
+
+
+PAGED_CASES = {
+    # name: (S, page, pages_per_slot, int8 pages, window, tp)
+    "decode": (1, 16, 512, False, None, 1),
+    "decode_windowed": (1, 16, 512, False, WINDOW, 1),
+    "verify_s5": (5, 16, 512, False, WINDOW, 1),
+    "chunk_s64": (64, 16, 512, False, WINDOW, 1),
+    "int8_decode": (1, 16, 512, True, WINDOW, 1),
+    "int8_chunk_s64": (64, 16, 512, True, WINDOW, 1),
+    # the serving tools' default page size
+    "page8_decode": (1, 8, 1024, False, WINDOW, 1),
+    "tp4_shard_map_decode": (1, 16, 512, False, WINDOW, 4),
+    "tp4_shard_map_int8_chunk_s64": (64, 16, 512, True, WINDOW, 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAGED_CASES))
+def test_paged_kernel_compiles_for_v5e(topo, case):
+    S, page, pp, quant, window, tp = PAGED_CASES[case]
+    B, num_pages = 8, 2048
+    mesh = _mesh(topo, tp)
+
+    def sds(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*spec)))
+
+    heads = "tp" if tp > 1 else None
+    q = sds((B, S, NQ, D), jnp.bfloat16, None, None, heads, None)
+    pages = sds((num_pages, NKV, page, D),
+                jnp.int8 if quant else jnp.bfloat16, None, heads, None, None)
+    pool = (pages, pages)
+    if quant:
+        pool += (sds((num_pages,), jnp.float32),) * 4
+    table = sds((B, pp), jnp.int32)
+    vec = sds((B,), jnp.int32)
+
+    text = _compiled_text(
+        lambda q, pool, bt, off, start: paged_attention(
+            q, pool, bt, off, start, window=window),
+        q, pool, table, vec, vec)
+    assert "tpu_custom_call" in text, (
+        f"{case}: no Mosaic kernel in the program compiled for the TPU")
+    if tp > 1:
+        # heads shard over tp with the pool: the shard_map'd kernel needs
+        # no collective (the output projection reduces afterwards)
+        for op in ("all-gather", "all-reduce", "all-to-all",
+                   "collective-permute"):
+            assert op not in text, f"{case}: unexpected {op}"

@@ -509,7 +509,7 @@ def test_runner_serve_trace_and_metrics_cli(tmp_path):
     REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out_dir = str(tmp_path / "traces")
     proc = run_cli(os.path.join(REPO, "examples", "inference", "runner.py"),
-                   "serve", "--preset", "tiny", "--batch-size", "2",
+                   "serve", "--preset", "tiny", "--dtype", "float32", "--batch-size", "2",
                    "--num-requests", "3", "--max-new-tokens", "3",
                    "--quiet", "--trace-out", out_dir,
                    "--metrics-port", "0")

@@ -16,9 +16,7 @@ the SAME config and requires <=1% pointwise deviation after warmup):
   at this scale is computationally dishonest — hours per run — so the curve
   itself is committed as the golden for future silicon rounds).
 
-Each mode prints ONE JSON line; ``tools/tpu_watch.py`` runs ``parity`` and
-``scale`` as one-shot jobs in the first healthy TPU window and appends the
-results to the watch log.
+Each mode prints ONE JSON line; ``parity`` and ``scale`` need the chip.
 """
 
 import argparse

@@ -25,8 +25,7 @@ engine owns its KV state):
   requeued in-flight work, and the schema-checked ``router_stats.jsonl``
   agrees record-for-record.
 
-``--disagg`` switches to the disaggregated-fleet acceptance rung (the
-``serving_disagg`` tpu_watch job): a bimodal interactive/batch trace
+``--disagg`` switches to the disaggregated-fleet acceptance rung: a bimodal interactive/batch trace
 through a role-split :class:`DisaggRouter` (prefill + decode replicas)
 vs a homogeneous ``prefix_affinity`` fleet at EQUAL replica count.  Four
 gates, all required: (1) the role-split fleet's interactive TTFT p99
@@ -38,8 +37,7 @@ at the ``kvcache/page_import`` fault point mid-migration still yields
 exactly one finished, token-identical output per request with zero page
 leaks on either side.
 
-``--autopilot`` switches to the autopilot chaos rung (the
-``fleet_autopilot`` tpu_watch job): a deadline-blown load spike plus a
+``--autopilot`` switches to the autopilot chaos rung: a deadline-blown load spike plus a
 mid-run replica kill into a 2-replica fleet running
 :class:`~...serving.fleet.autopilot.Autopilot`, absorbed with zero
 human input.  Gates, all required: the fast-window burn alert fires and
@@ -49,8 +47,8 @@ yields exactly one terminal output (ledger-checked); every action the
 controller took is a schema-valid ``autopilot_actions.jsonl`` record;
 and the post-spike recovery wave finishes to the last request.
 
-``--rolling-update`` switches to the zero-downtime weight-deploy rung
-(the ``fleet_rolling_update`` tpu_watch job): live traffic drips through
+``--rolling-update`` switches to the zero-downtime weight-deploy rung:
+live traffic drips through
 the fleet while ``FleetRouter.rolling_update()`` walks drain → swap →
 rejoin one replica at a time.  Gates, all required: every accepted
 request yields exactly one FINISHED output (zero lost to the roll); the
@@ -61,7 +59,6 @@ reuses every compiled phase program); each replica's
 versions; and every replica describes the new weights_version at the
 end — the mixed-version fleet mid-roll is reported as evidence.
 
-Run by ``tools/tpu_watch.py`` as the ``serving_fleet`` extra job;
 ``--tiny`` smoke-tests the harness on CPU (the same rungs, smaller model).
 """
 
@@ -971,14 +968,11 @@ def main() -> int:
 
     if args.tiny:
         jax.config.update("jax_platforms", "cpu")
-    cache = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
-                         ".jax_cache")
-    try:
-        os.makedirs(cache, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:  # noqa: BLE001
-        pass
+    from neuronx_distributed_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
 
     import jax.numpy as jnp
 

@@ -5,8 +5,8 @@ Three modes:
 - default: static-batch decode latency through the serving engine's
   neuronperf-equivalent harness (`trace.engine.benchmark`: context-encode
   ms, per-token p50/p99 ms, tokens/s — reference
-  `examples/inference/benchmark.py:53-77`).  Run by the TPU watcher in a
-  healthy window (VERDICT r3 #6); `--tiny` smoke-tests the harness on CPU.
+  `examples/inference/benchmark.py:53-77`).  `--tiny` smoke-tests the
+  harness on CPU.
 - `--continuous`: replays a Poisson arrival trace through the
   continuous-batching `serving.ServingEngine` and reports TTFT p50/p99,
   inter-token p50/p99, and goodput against the static lockstep `generate`
@@ -45,7 +45,7 @@ Three modes:
   compile NOTHING.  One JSON line; rc 1 on any refused admission, any
   unfinished request, any post-warmup compile (a compile storm), or
   nonzero `kvcache/gather_bytes_total` (a phase fell off the kernel
-  substrate).  Wired into `tpu_watch` as the `serving_compose` job.
+  substrate).
 
 ``--trace-out DIR`` (engine rungs: `--continuous`, `--slo`) attaches a
 request-lifecycle tracer to every measured engine and drops one
@@ -67,8 +67,7 @@ measured engine under the DEFAULT health-monitor rule pack
 (``obs.health.default_rules``) and drops one schema-checked
 ``<rung>.alerts.jsonl`` per rung; the ``--slo`` rc additionally fails when
 a page-severity alert fires during the compliant rung — a passing bench
-must be QUIET under the production rule pack.  Wired into ``tpu_watch``
-as the ``fleet_health`` extra job.
+must be QUIET under the production rule pack.
 """
 
 from __future__ import annotations
@@ -205,11 +204,19 @@ def _make_perf(args, label: str):
     device time), else None — the zero-allocation default."""
     if not getattr(args, "profile_out", None):
         return None
-    from neuronx_distributed_tpu.obs.perf import PerfAttribution
+    from neuronx_distributed_tpu.obs.perf import (
+        PerfAttribution,
+        calibrate_cpu_spec,
+    )
 
     os.makedirs(args.profile_out, exist_ok=True)
-    return PerfAttribution(path=os.path.join(
-        args.profile_out, f"{label}.perf_attribution.jsonl"))
+    # --tiny is the explicit CPU harness smoke: its attribution records run
+    # against a cost model labelled "cpu"; every other run reads the
+    # device's published peaks (and fails on a device without any)
+    return PerfAttribution(
+        path=os.path.join(args.profile_out,
+                          f"{label}.perf_attribution.jsonl"),
+        spec=calibrate_cpu_spec() if args.tiny else None)
 
 
 def _perf_fields(perf, args, label: str) -> dict:
@@ -1265,8 +1272,7 @@ def run_paged_kernel(args, module, params, cfg, icfg) -> int:
     On a real TPU the metric is measured step wall-time; on the CPU
     interpreter wall time measures the pallas interpreter, not HBM, so the
     rung gates on the bytes-moved model instead (gather: the full clone;
-    kernel: the pages actually read) — the silicon wall-clock confirmation
-    rides ``tpu_watch`` as ``serving_paged_kernel``."""
+    kernel: the pages actually read); the wall-clock gate needs the chip."""
     import dataclasses
     import math
 
@@ -1313,7 +1319,7 @@ def run_paged_kernel(args, module, params, cfg, icfg) -> int:
         for b in range(B):
             tables[b, :need] = 1 + b * need + np.arange(need)
         host_caches = [
-            tuple(rs.standard_normal((num_pages, page, NKV, D)).astype(
+            tuple(rs.standard_normal((num_pages, NKV, page, D)).astype(
                 np.float32) for _ in range(2))
             for _ in range(L)
         ]
@@ -1500,14 +1506,11 @@ def main() -> int:
 
     if args.tiny:
         jax.config.update("jax_platforms", "cpu")
-    # persistent compilation cache (shared with bench.py)
-    cache = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", ".jax_cache")
-    try:
-        os.makedirs(cache, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:  # noqa: BLE001
-        pass
+    from neuronx_distributed_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
 
     import jax.numpy as jnp
 
