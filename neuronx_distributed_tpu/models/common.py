@@ -173,14 +173,18 @@ def make_causal_lm_loss_sum(chunk_size: int = 0):
             ls, tok = chunk_fn(params, h_c, y_c, m_c)
             return (carry[0] + ls, carry[1] + tok), None
 
-        xs = (
-            h.reshape(B, n, c, h.shape[-1]).swapaxes(0, 1),
-            labels.reshape(B, n, c).swapaxes(0, 1),
-            mask.reshape(B, n, c).swapaxes(0, 1),
-        )
-        (loss_sum, tok), _ = jax.lax.scan(
-            body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)), xs
-        )
+        # the chunk scan, the head matmul in it and its recomputation are
+        # one group in the device trace
+        with jax.named_scope("loss_head"):
+            xs = (
+                h.reshape(B, n, c, h.shape[-1]).swapaxes(0, 1),
+                labels.reshape(B, n, c).swapaxes(0, 1),
+                mask.reshape(B, n, c).swapaxes(0, 1),
+            )
+            (loss_sum, tok), _ = jax.lax.scan(
+                body, (jnp.zeros((), jnp.float32),
+                       jnp.zeros((), jnp.float32)), xs
+            )
         aux_terms = jax.tree.leaves(variables.get("losses", {}))
         if aux_terms:
             loss_sum = loss_sum + MOE_AUX_COEF * jnp.mean(jnp.stack(aux_terms)) * tok

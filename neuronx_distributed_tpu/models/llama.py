@@ -440,89 +440,92 @@ class LlamaAttention(nn.Module):
                     raise ValueError(
                         "the block-table decode path needs per-slot offsets "
                         "[B] (continuous-batching decode)")
-                NP, page = ck.shape[0], ck.shape[2]
-                PP = block_table.shape[1]
-                T = PP * page
-                Sn = k.shape[1]
-                idx = cache_offset[:, None] + jnp.arange(Sn)[None, :]  # [B, Sn]
-                page_idx = jnp.clip(idx // page, 0, PP - 1)
-                in_off = idx % page
-                phys = jnp.take_along_axis(block_table, page_idx, axis=1)
-                # a parked slot (offset >= T) writes nothing: route it out of
-                # range and let the scatter drop it
-                phys = jnp.where(idx < T, phys, NP)
-                # never commit an INVALID cell (a chunk's left-pad rows,
-                # whose validity stays 0): their hidden states are
-                # path-dependent garbage (empty-band kernel rows vs
-                # fully-masked gather rows), and on int8 pools a garbage
-                # cell would pollute the whole page's quantization scale
-                live = None
-                if kv_valid is not None:
-                    live = jnp.take_along_axis(
-                        jnp.asarray(kv_valid), jnp.clip(idx, 0, T - 1),
-                        axis=1) > 0                      # [B, Sn]
-                    phys = jnp.where(live, phys, NP)
-                if quantized:
-                    # quantize-on-write, any Sn >= 1: the Sn new cells span
-                    # up to ceil((Sn-1)/page)+1 consecutive logical pages
-                    # (the first may be written mid-page).  Per straddled
-                    # page: gather it, dequantize, insert every new cell
-                    # landing in it, re-quantize the whole page and scatter
-                    # it (and its fresh scale/zero) back.  Sn == 1 reduces
-                    # to the classic single-token decode RMW; Sn > 1 is the
-                    # speculative verify / chunked-prefill commit.  Decode
-                    # pages are exclusively owned per slot (never shared —
-                    # sharing is prompt-page only), so the page-granular
-                    # read-modify-write cannot race another slot; untouched
-                    # and parked rows route to phys == NP and their
-                    # writeback drops.
-                    from neuronx_distributed_tpu.kvcache.quant import (
-                        dequantize_page, quantize_page)
+                # the pool write carries its own name in the device trace: it
+                # sits inside the attention module's scope but is cache traffic
+                with jax.named_scope("kv_write"):
+                    NP, page = ck.shape[0], ck.shape[2]
+                    PP = block_table.shape[1]
+                    T = PP * page
+                    Sn = k.shape[1]
+                    idx = cache_offset[:, None] + jnp.arange(Sn)[None, :]  # [B, Sn]
+                    page_idx = jnp.clip(idx // page, 0, PP - 1)
+                    in_off = idx % page
+                    phys = jnp.take_along_axis(block_table, page_idx, axis=1)
+                    # a parked slot (offset >= T) writes nothing: route it out of
+                    # range and let the scatter drop it
+                    phys = jnp.where(idx < T, phys, NP)
+                    # never commit an INVALID cell (a chunk's left-pad rows,
+                    # whose validity stays 0): their hidden states are
+                    # path-dependent garbage (empty-band kernel rows vs
+                    # fully-masked gather rows), and on int8 pools a garbage
+                    # cell would pollute the whole page's quantization scale
+                    live = None
+                    if kv_valid is not None:
+                        live = jnp.take_along_axis(
+                            jnp.asarray(kv_valid), jnp.clip(idx, 0, T - 1),
+                            axis=1) > 0                      # [B, Sn]
+                        phys = jnp.where(live, phys, NP)
+                    if quantized:
+                        # quantize-on-write, any Sn >= 1: the Sn new cells span
+                        # up to ceil((Sn-1)/page)+1 consecutive logical pages
+                        # (the first may be written mid-page).  Per straddled
+                        # page: gather it, dequantize, insert every new cell
+                        # landing in it, re-quantize the whole page and scatter
+                        # it (and its fresh scale/zero) back.  Sn == 1 reduces
+                        # to the classic single-token decode RMW; Sn > 1 is the
+                        # speculative verify / chunked-prefill commit.  Decode
+                        # pages are exclusively owned per slot (never shared —
+                        # sharing is prompt-page only), so the page-granular
+                        # read-modify-write cannot race another slot; untouched
+                        # and parked rows route to phys == NP and their
+                        # writeback drops.
+                        from neuronx_distributed_tpu.kvcache.quant import (
+                            dequantize_page, quantize_page)
 
-                    base = cache_offset // page          # [B], unclipped
-                    n_pg = (Sn - 1 + page - 1) // page + 1
-                    cell = jnp.arange(page)[None, :]
+                        base = cache_offset // page          # [B], unclipped
+                        n_pg = (Sn - 1 + page - 1) // page + 1
+                        cell = jnp.arange(page)[None, :]
 
-                    def requant_pages(cq, sc, zp, new):
-                        for j in range(n_pg):
-                            lp = base + j                # logical page [B]
-                            lp_c = jnp.clip(lp, 0, PP - 1)
-                            pj = jnp.take_along_axis(
-                                block_table, lp_c[:, None], axis=1)[:, 0]
-                            pos = lp[:, None] * page + cell       # [B, page]
-                            s_idx = pos - cache_offset[:, None]
-                            hot = ((s_idx >= 0) & (s_idx < Sn) & (pos < T))
-                            if kv_valid is not None:
-                                hot &= jnp.take_along_axis(
-                                    jnp.asarray(kv_valid),
-                                    jnp.clip(pos, 0, T - 1), axis=1) > 0
-                            pj = jnp.where(jnp.any(hot, axis=1), pj, NP)
-                            pc = jnp.clip(pj, 0, NP - 1)
-                            sel = jnp.clip(s_idx, 0, Sn - 1)
-                            ins = jnp.take_along_axis(
-                                new, sel[:, :, None, None], axis=1)
-                            # pages are head-major [B, NKV, page, D]
-                            pg = dequantize_page(cq[pc], sc[pc], zp[pc])
-                            pg = jnp.where(
-                                hot[:, None, :, None],
-                                ins.transpose(0, 2, 1, 3).astype(pg.dtype),
-                                pg)
-                            q2, s2, z2 = quantize_page(pg)
-                            cq = cq.at[pj].set(q2, mode="drop")
-                            sc = sc.at[pj].set(s2, mode="drop")
-                            zp = zp.at[pj].set(z2, mode="drop")
-                        return cq, sc, zp
+                        def requant_pages(cq, sc, zp, new):
+                            for j in range(n_pg):
+                                lp = base + j                # logical page [B]
+                                lp_c = jnp.clip(lp, 0, PP - 1)
+                                pj = jnp.take_along_axis(
+                                    block_table, lp_c[:, None], axis=1)[:, 0]
+                                pos = lp[:, None] * page + cell       # [B, page]
+                                s_idx = pos - cache_offset[:, None]
+                                hot = ((s_idx >= 0) & (s_idx < Sn) & (pos < T))
+                                if kv_valid is not None:
+                                    hot &= jnp.take_along_axis(
+                                        jnp.asarray(kv_valid),
+                                        jnp.clip(pos, 0, T - 1), axis=1) > 0
+                                pj = jnp.where(jnp.any(hot, axis=1), pj, NP)
+                                pc = jnp.clip(pj, 0, NP - 1)
+                                sel = jnp.clip(s_idx, 0, Sn - 1)
+                                ins = jnp.take_along_axis(
+                                    new, sel[:, :, None, None], axis=1)
+                                # pages are head-major [B, NKV, page, D]
+                                pg = dequantize_page(cq[pc], sc[pc], zp[pc])
+                                pg = jnp.where(
+                                    hot[:, None, :, None],
+                                    ins.transpose(0, 2, 1, 3).astype(pg.dtype),
+                                    pg)
+                                q2, s2, z2 = quantize_page(pg)
+                                cq = cq.at[pj].set(q2, mode="drop")
+                                sc = sc.at[pj].set(s2, mode="drop")
+                                zp = zp.at[pj].set(z2, mode="drop")
+                            return cq, sc, zp
 
-                    ck, ks, kz = requant_pages(ck, ks, kz, k)
-                    cv, vs, vz = requant_pages(cv, vs, vz, v)
-                else:
-                    # cell (phys, :, in_off) of the head-major pool; the
-                    # split advanced indices lead the update's dims, which
-                    # is k's own [B, Sn, NKV, D]
-                    ck = ck.at[phys, :, in_off].set(
-                        k.astype(ck.dtype), mode="drop")
-                    cv = cv.at[phys, :, in_off].set(
-                        v.astype(cv.dtype), mode="drop")
+                        ck, ks, kz = requant_pages(ck, ks, kz, k)
+                        cv, vs, vz = requant_pages(cv, vs, vz, v)
+                    else:
+                        # cell (phys, :, in_off) of the head-major pool; the
+                        # split advanced indices lead the update's dims, which
+                        # is k's own [B, Sn, NKV, D]
+                        ck = ck.at[phys, :, in_off].set(
+                            k.astype(ck.dtype), mode="drop")
+                        cv = cv.at[phys, :, in_off].set(
+                            v.astype(cv.dtype), mode="drop")
             elif jnp.ndim(cache_offset) == 1:
                 # per-example write positions [B] (continuous batching: every
                 # slot decodes at its own offset).  Single-token steps only —

@@ -22,7 +22,14 @@ it.  :class:`CompileLedger` is the one accounting surface:
   :meth:`declare_warmup_done` is a ``compile_storm`` — counted
   (``trace/compile_storms_total``), surfaced in the flight recorder's
   warnings, and traced as a ``compile`` span so the stall shows up in
-  request waterfalls.
+  request waterfalls;
+- **a recompile inside jit dispatch is seen too**: one process-wide
+  ``jax.monitoring`` listener, installed when the first ledger is built,
+  hears every request JAX makes to its compiler (served from the persistent
+  cache or not) and counts it into ``trace/compile_requests_total``.  After
+  warm-up, a request that no explicit ``record_compile`` accounts for — a
+  cached jit whose argument came back placed otherwise, say — becomes a row
+  of family ``jit_dispatch`` at the next :meth:`reconcile`, and a storm.
 
 Rows stream to a schema-checked ``compile_ledger.jsonl``
 (``obs.schemas`` kind ``compile_ledger``); ``trace/compile_ms`` /
@@ -40,6 +47,7 @@ import json
 import os
 import threading
 import time
+import weakref
 from contextlib import contextmanager
 from typing import Any, Dict, Iterable, List, Optional
 
@@ -66,6 +74,37 @@ LEDGER_ROWS = 0
 # available (AOT sites; lazy jits record wall time only)
 _COST_KEYS = ("flops", "bytes_accessed", "argument_size_in_bytes",
               "output_size_in_bytes", "temp_size_in_bytes")
+
+
+# JAX times every call into its compiler under this event, cache hit or
+# miss, and names the program (jax._src.dispatch.BACKEND_COMPILE_EVENT)
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# a request this long before an explicit row's own timed stretch began is
+# not that row's (clock granularity between the two stamps)
+_COVER_SLACK_S = 0.05
+_live_ledgers: "weakref.WeakSet[CompileLedger]" = weakref.WeakSet()
+_listening = False
+
+
+def _on_compile_request(event: str, duration_secs: float, **kw) -> None:
+    if event == _COMPILE_EVENT:
+        for led in list(_live_ledgers):
+            led._compile_requested(str(kw.get("fun_name", "?")),
+                                   duration_secs * 1e3)
+
+
+def _listen(ledger: "CompileLedger") -> None:
+    """Feed ``ledger`` from the one process-wide listener (JAX offers no
+    public way to take a listener out again, so there is one for good and
+    dead ledgers fall out of the weak set)."""
+    global _listening
+    _live_ledgers.add(ledger)
+    if not _listening:
+        import jax
+
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_request)
+        _listening = True
 
 
 def jit_cache_size(fn: Any) -> Optional[int]:
@@ -138,6 +177,10 @@ class CompileLedger:
         self.cache_hits = 0
         self.cache_misses = 0
         self.cache_evictions = 0
+        # compiler requests heard since warm-up that no row accounts for
+        # yet: (program name, wall ms, clock at the request's end)
+        self._unaccounted: List[tuple] = []
+        _listen(self)
 
     # -- wiring ------------------------------------------------------------
 
@@ -251,6 +294,8 @@ class CompileLedger:
             fam["cold_ms"] += float(wall_ms)
         if self.warmup_done:
             extra["storm"] = True  # stamped BEFORE the row streams to disk
+        if family != "jit_dispatch":
+            self._account(wall_ms)
         row = self._row("compile", family, key, kind, wall_ms, **extra)
         reg = self.registry
         if reg is not None:
@@ -311,6 +356,41 @@ class CompileLedger:
         if self.flight is not None:
             self.flight.warnings.append(warning)
 
+    # -- compiles inside jit dispatch ----------------------------------------
+
+    def _compile_requested(self, name: str, wall_ms: float) -> None:
+        """The process-wide listener's entry: JAX asked its compiler for
+        program ``name``."""
+        if self.registry is not None:
+            self.registry.counter("trace/compile_requests_total").inc()
+        if self.warmup_done:
+            with self._lock:
+                self._unaccounted.append((name, wall_ms, self._clock()))
+
+    def _account(self, wall_ms: Optional[float]) -> None:
+        """An explicit row accounts for the requests heard inside the
+        stretch it timed; one that only noticed a jit cache grow (no wall
+        time) accounts for whatever dispatch compiled before it."""
+        with self._lock:
+            if wall_ms is None:
+                self._unaccounted.clear()
+            elif self._unaccounted:
+                since = self._clock() - wall_ms / 1e3 - _COVER_SLACK_S
+                self._unaccounted = [u for u in self._unaccounted
+                                     if u[2] < since]
+
+    def reconcile(self) -> int:
+        """Book every compiler request heard after warm-up that no explicit
+        row accounted for as a ``jit_dispatch`` compile (keyed by the
+        program's name, with the wall time JAX measured): each is a storm.
+        The engine calls this after every step; the queries call it too.
+        Returns how many rows it added."""
+        with self._lock:
+            pending, self._unaccounted = self._unaccounted, []
+        for name, wall_ms, _ in pending:
+            self.record_compile("jit_dispatch", name, wall_ms, kind="jit")
+        return len(pending)
+
     @contextmanager
     def timed(self, family: str, key: Any, kind: str = "aot"):
         """Time a compile site: ``with ledger.timed("context", key) as rec:
@@ -365,6 +445,7 @@ class CompileLedger:
     # -- queries -----------------------------------------------------------
 
     def compile_count(self, after_warmup_only: bool = False) -> int:
+        self.reconcile()
         with self._lock:
             return sum(1 for r in self.rows if r["event"] == "compile"
                        and (r["after_warmup"] or not after_warmup_only))
@@ -376,16 +457,19 @@ class CompileLedger:
     def mark(self) -> int:
         """Row-count bookmark; pair with :meth:`compiles_since` to count
         the compiles inside a measurement window."""
+        self.reconcile()
         with self._lock:
             return len(self.rows)
 
     def compiles_since(self, mark: int) -> int:
+        self.reconcile()
         with self._lock:
             return sum(1 for r in self.rows[mark:] if r["event"] == "compile")
 
     def summary(self) -> dict:
         """The report-facing rollup (also what ``obs_report --compare``
         diffs between runs)."""
+        self.reconcile()
         with self._lock:
             rows = list(self.rows)
         return summarize_compile_records(rows, cache={

@@ -351,6 +351,7 @@ REGISTRY_METRICS: Dict[str, str] = {
     "trace/compiles_total": "counter",
     "trace/compile_ms": "histogram",
     "trace/compile_storms_total": "counter",
+    "trace/compile_requests_total": "counter",
     "trace/compile_thrash_total": "counter",
     "trace/compiled_cache_hits_total": "counter",
     "trace/compiled_cache_misses_total": "counter",

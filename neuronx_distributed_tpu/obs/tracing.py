@@ -453,3 +453,15 @@ def device_trace(log_dir: str):
         yield
     finally:
         jax.profiler.stop_trace()
+
+
+def phase(name: str, **attrs):
+    """A host span ``nxd/<name>`` in the JAX profiler's own trace, on the
+    clock its device planes use, so a reduction of the trace can set a
+    device gap against the part of the loop that ran then.  It is a
+    ``jax.profiler.TraceAnnotation``: a flag test when no profile is being
+    taken, so call sites are unconditional and nothing turns it on.  Not a
+    :class:`Tracer` span — those are per request, on ``time.monotonic``."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation("nxd/" + name, **attrs)

@@ -307,6 +307,7 @@ def _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
             ],
             scratch_shapes=scratch,
             interpret=interp,
+            name="flash_fwd",
         )
 
     o, lse = run_kernel(call, interpret, *operands)
@@ -493,6 +494,7 @@ def _bwd_impl(q, k, v, lse, do, delta_rows, causal, sm_scale, block_q, block_k, 
             out_shape=jax.ShapeDtypeStruct((B, HQ, S, D), q.dtype),
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
             interpret=interp,
+            name="flash_dq",
         )
 
     dq = run_kernel(dq_call, interpret, *dq_operands)
@@ -544,6 +546,7 @@ def _bwd_impl(q, k, v, lse, do, delta_rows, causal, sm_scale, block_q, block_k, 
                 pltpu.VMEM((bk, D), jnp.float32),
             ],
             interpret=interp,
+            name="flash_dkv",
         )
 
     dk_q, dv_q = run_kernel(dkv_call, interpret, *dkv_operands)

@@ -418,7 +418,10 @@ def _paged_attention_impl(q, kv_pages, block_table, cache_offset, kv_start,
             ],
             compiler_params=_compiler_params(_GRID_SEMANTICS, interp),
             interpret=interp,
-            name="paged_attention",
+            # one query row a slot is the decode step, more is a prefill chunk:
+            # the device trace tells them apart by this name
+            name=("paged_attention_decode" if S == 1
+                  else "paged_attention_chunk"),
         )
 
     acc, m, l = run_kernel(call, interpret, bt, off, start, *operands)

@@ -56,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from neuronx_distributed_tpu.obs import MS_BUCKETS, MetricRegistry
+from neuronx_distributed_tpu.obs.tracing import phase
 from neuronx_distributed_tpu.obs.transfer_audit import TransferAudit
 from neuronx_distributed_tpu.resilience.faults import fault_point, perturb
 from neuronx_distributed_tpu.serving.driver import replay as driver_replay
@@ -142,7 +143,9 @@ def _sample_rows(logits, base_keys, tok_idx, temperature, top_k, top_p):
         tok = _sample_logits(lg, jax.random.fold_in(key, idx), t, k, p)
         return tok, jnp.all(jnp.isfinite(lg.astype(jnp.float32)))
 
-    return jax.vmap(row)(logits, base_keys, tok_idx, temperature, top_k, top_p)
+    with jax.named_scope("sample"):
+        return jax.vmap(row)(logits, base_keys, tok_idx, temperature, top_k,
+                             top_p)
 
 
 @jax.jit
@@ -158,7 +161,9 @@ def _propose_rows(logits, base_keys, tok_idx, temperature, top_k, top_p):
         tok = _sample_logits(lg, jax.random.fold_in(key, idx), t, k, p)
         return tok, qf, jnp.all(jnp.isfinite(lg.astype(jnp.float32)))
 
-    return jax.vmap(row)(logits, base_keys, tok_idx, temperature, top_k, top_p)
+    with jax.named_scope("sample"):
+        return jax.vmap(row)(logits, base_keys, tok_idx, temperature, top_k,
+                             top_p)
 
 
 @jax.jit
@@ -242,7 +247,8 @@ def _pack_tokens(toks, finite):
     separate tiny jit (not fused into :func:`_sample_rows`) so the sampler
     program stays bit-identical to the synchronous engine's — parity by
     construction, not by hoping XLA fuses the same way."""
-    return jnp.stack([toks.astype(jnp.int32), finite.astype(jnp.int32)])
+    with jax.named_scope("pack_tokens"):
+        return jnp.stack([toks.astype(jnp.int32), finite.astype(jnp.int32)])
 
 
 #: module-level jits shared by every engine in the process: their compiles
@@ -1085,6 +1091,10 @@ class ServingEngine:
                 led.record_compile(f"jit:{name}", f"cache_size_{n}", None,
                                    kind="jit")
         self._jit_sizes = sizes
+        # what dispatch compiled this step that neither a cache's first-call
+        # timer nor the poll above saw (a placement-driven recompile of a
+        # cached program) becomes a ``jit_dispatch`` storm
+        led.reconcile()
 
     def step(self) -> List[RequestOutput]:
         """One engine iteration: sweep → admit/prefill → batched decode →
@@ -1096,17 +1106,22 @@ class ServingEngine:
         before re-raising; with a compile ledger attached, the shared
         sampler jits' cache sizes are polled after the step.  Ledgers-off
         is two attribute reads."""
-        if self.compile_ledger is None and self.memory_ledger is None:
-            return self._step_impl()
-        try:
-            out = self._step_impl()
-        except Exception as e:
-            if self.memory_ledger is not None:
-                self.memory_ledger.oom_dump(e)
-            raise
-        if self.compile_ledger is not None:
-            self._poll_module_jits(self.compile_ledger)
-        return out
+        # the loop's phases are spans of the profiler's own trace
+        # (obs.tracing.phase): a flag test each when no profile is taken
+        with phase("serve/step", step=self._steps + 1,
+                   active=self.scheduler.active_count,
+                   queued=self.scheduler.queue_depth):
+            if self.compile_ledger is None and self.memory_ledger is None:
+                return self._step_impl()
+            try:
+                out = self._step_impl()
+            except Exception as e:
+                if self.memory_ledger is not None:
+                    self.memory_ledger.oom_dump(e)
+                raise
+            if self.compile_ledger is not None:
+                self._poll_module_jits(self.compile_ledger)
+            return out
 
     def _step_impl(self) -> List[RequestOutput]:
         outputs: List[RequestOutput] = []
@@ -1114,31 +1129,35 @@ class ServingEngine:
         t_step0 = now
         self._steps += 1
 
-        # 1) cancellation / deadline sweep (frees slots before admission)
-        swept = self.scheduler.sweep(now)
-        if swept:
-            self._park_free_slots()
-            for req in swept:
-                # a swept ACTIVE request still has its compute phase open
-                # (queued ones were closed by the scheduler's sweep)
-                self._trace_end_phase(req, t=now, swept=req.state.value)
-                self.registry.counter(
-                    "serving/cancelled_total"
-                    if req.state is RequestState.CANCELLED
-                    else "serving/timed_out_total").inc()
-                outputs.append(self._emit(req, now))
+        with phase("serve/admit") as span:
+            # 1) cancellation / deadline sweep (frees slots before admission)
+            swept = self.scheduler.sweep(now)
+            if swept:
+                self._park_free_slots()
+                for req in swept:
+                    # a swept ACTIVE request still has its compute phase
+                    # open (queued ones were closed by the scheduler's sweep)
+                    self._trace_end_phase(req, t=now, swept=req.state.value)
+                    self.registry.counter(
+                        "serving/cancelled_total"
+                        if req.state is RequestState.CANCELLED
+                        else "serving/timed_out_total").inc()
+                    outputs.append(self._emit(req, now))
 
-        # 2) priority preemption: when the interactive head is blocked on a
-        # full slot table (or exhausted pages), park batch-tier victims —
-        # pages released transactionally, the request requeued for a later
-        # token-identical re-prefill
-        self._preempt_for_priority(now)
+            # 2) priority preemption: when the interactive head is blocked
+            # on a full slot table (or exhausted pages), park batch-tier
+            # victims — pages released transactionally, the request requeued
+            # for a later token-identical re-prefill
+            self._preempt_for_priority(now)
 
-        # 3) admission: slot-insert prefill per granted request (its device
-        # work queues behind the in-flight decode, keeping the device busy
-        # while the host prepares the batch)
-        for slot, req in self.scheduler.admit(now):
-            self._prefill_into_slot(slot, req, outputs)
+            # 3) admission: slot-insert prefill per granted request (its
+            # device work queues behind the in-flight decode, keeping the
+            # device busy while the host prepares the batch)
+            granted = 0
+            for slot, req in self.scheduler.admit(now):
+                self._prefill_into_slot(slot, req, outputs)
+                granted += 1
+            span.set_metadata(granted=granted)
 
         # 3b) chunked prefill: advance every PREFILLING slot by up to the
         # per-step token budget (Sarathi-style — decodes below keep ticking
@@ -1154,16 +1173,22 @@ class ServingEngine:
             # decode, THEN run the collected step's host-side work (stream
             # callbacks, telemetry, stats) while the device computes
             with self._audit.section("serving/decode"):
-                post = (self._spec_collect() if self._spec_k
-                        else self._collect_decode())
+                with phase("serve/collect"):
+                    post = (self._spec_collect() if self._spec_k
+                            else self._collect_decode())
                 active = [(slot, req) for slot, req in self.scheduler.active()
                           if req.state is RequestState.DECODE]
                 if active:
-                    if self._spec_k:
-                        self._spec_dispatch(active)
-                    else:
-                        self._dispatch_decode(active)
-            self._finish_decode(post, outputs)
+                    with phase("serve/dispatch", active=len(active),
+                               ctx_tokens=self._attended_keys(active)):
+                        if self._spec_k:
+                            self._spec_dispatch(active)
+                        else:
+                            self._dispatch_decode(active)
+            with phase("serve/finish", tokens=sum(
+                    len(p[3]) if p[0] == "tokens" else p[0] == "token"
+                    for p in post)):
+                self._finish_decode(post, outputs)
         else:
             # synchronous reference engine: one fully-processed decode per
             # step (the async path is parity-tested against this)
@@ -1554,8 +1579,9 @@ class ServingEngine:
             jnp.full((1,), s.top_p, jnp.float32))
         # admission is off the steady path, but its fetch is still ONE
         # explicit packed read (first token + finite flag together)
-        first = self._audit.fetch(_pack_tokens(toks, finite),
-                                  label="serving")
+        with phase("serve/fetch"):
+            first = self._audit.fetch(_pack_tokens(toks, finite),
+                                      label="serving")
         now = self._clock()
         self.registry.counter("serving/admitted_total").inc()
         if not bool(first[1][0]):
@@ -1640,9 +1666,17 @@ class ServingEngine:
                 self._chunking.pop(slot, None)
                 self._expire_before_prefill(slot, req, outputs, now)
                 continue
+            n_pages = min(width, st.pages_remaining)
+            page = self._kv.page_size
+            off = st.fresh[st.next_i][0] * page
             try:
-                self._dispatch_chunk(slot, st,
-                                     min(width, st.pages_remaining))
+                # ctx_tokens: the keys the chunk's last row attends — its
+                # end in the left-padded row less the pad
+                with phase("serve/prefill_chunk", request_id=req.request_id,
+                           tok_start=off, width=n_pages * page,
+                           ctx_tokens=off + n_pages * page
+                           - (self.C - req.prompt_len)):
+                    self._dispatch_chunk(slot, st, n_pages)
             except BaseException as e:
                 # transactional like the admission path: the one request
                 # fails, every page is reclaimed, then the fault propagates
@@ -1795,6 +1829,13 @@ class ServingEngine:
         self.registry.counter("serving/timed_out_total").inc()
         outputs.append(self._emit(req, now))
 
+    def _attended_keys(self, active: list) -> int:
+        """Keys the coming decode attends, summed over its slots: each
+        slot's write offset in the left-padded row less its pad (what the
+        paged kernel must read; the ``ctx_tokens`` of the dispatch span)."""
+        return sum(int(self._offsets[slot]) - self.C + req.prompt_len
+                   for slot, req in active)
+
     def _count_gather_step(self) -> None:
         """Account one gather-path paged step's ``[B, T]`` K/V
         rematerialization; the block-table-native kernel path never calls
@@ -1887,7 +1928,8 @@ class ServingEngine:
             return []
         packed_dev, active = self._pending
         self._pending = None
-        packed = self._audit.fetch(packed_dev, label="serving")  # [2, B]
+        with phase("serve/fetch"):
+            packed = self._audit.fetch(packed_dev, label="serving")  # [2, B]
         toks, finite = packed[0], packed[1]
         now = self._clock()
         tr = self.tracer
@@ -2156,7 +2198,9 @@ class ServingEngine:
         packed_dev, active, last_prop = self._pending
         self._pending = None
         k = self._spec_k
-        packed = self._audit.fetch(packed_dev, label="serving")  # [k+3, B]
+        with phase("serve/fetch"):
+            packed = self._audit.fetch(packed_dev,
+                                       label="serving")  # [k+3, B]
         commit, acc, finite = packed[:k + 1], packed[k + 1], packed[k + 2]
         now = self._clock()
         tr = self.tracer

@@ -908,8 +908,18 @@ class ParallelInferenceModel(_ServingBase):
             fn = jax.jit(self._insert_slot_fn, donate_argnums=(0, 2),
                          out_shardings=(io["cache_out"], io["batch"](None)))
             fn = self._serving_cache.put("insert_slot", fn)
-        return fn(caches, row_caches, valid.astype(jnp.int32),
+        return fn(caches, row_caches, self._batch_committed(valid),
                   jnp.asarray(row_valid, jnp.int32), jnp.int32(slot))
+
+    def _batch_committed(self, valid):
+        """``valid`` as int32, committed to the batch sharding.  An engine's
+        first validity array is a fresh, uncommitted ``zeros``; every later
+        one comes out of a program pinned to that sharding.  Committing the
+        argument gives the inserts ONE signature: without it the first
+        admission after a decode compiled them a second time, inside jit
+        dispatch, where only the compile ledger's listener sees it."""
+        return jax.device_put(valid.astype(jnp.int32),
+                              self._io_shardings["batch"](None))
 
     # -- paged-KV phase fns (kvcache/ subsystem; serving paged mode) --------
 
@@ -983,12 +993,15 @@ class ParallelInferenceModel(_ServingBase):
         S = toks.shape[1]
         T = valid.shape[1]
         idx = offsets[:, None] + jnp.arange(S)[None, :]  # [B, S] write indices
-        if update_valid:
-            hot = jnp.any(jnp.arange(T)[None, None, :] == idx[:, :, None],
-                          axis=1)
-            valid = jnp.where(hot, 1, valid)  # the new tokens become keys
-        counts = jnp.cumsum(valid, axis=1) - valid  # valid keys strictly before
-        positions = jnp.take_along_axis(counts, jnp.clip(idx, 0, T - 1), axis=1)
+        with jax.named_scope("kv_valid"):
+            if update_valid:
+                hot = jnp.any(jnp.arange(T)[None, None, :] == idx[:, :, None],
+                              axis=1)
+                valid = jnp.where(hot, 1, valid)  # the new tokens become keys
+            # valid keys strictly before
+            counts = jnp.cumsum(valid, axis=1) - valid
+            positions = jnp.take_along_axis(
+                counts, jnp.clip(idx, 0, T - 1), axis=1)
         extra = {}
         if apool is not None:
             extra["adapters"] = self._gather_adapters(apool, atables)
@@ -1312,8 +1325,9 @@ class ParallelInferenceModel(_ServingBase):
         return fn(caches, jnp.int32(src_page), jnp.int32(dst_page))
 
     def _insert_valid_fn(self, valid, row_valid, slot):
-        return jax.lax.dynamic_update_slice_in_dim(
-            valid, row_valid, slot, axis=0)
+        with jax.named_scope("kv_valid"):
+            return jax.lax.dynamic_update_slice_in_dim(
+                valid, row_valid, slot, axis=0)
 
     def insert_valid(self, valid, row_valid, slot):
         """Compiled validity-row insert (donated) — the paged admission's
@@ -1325,8 +1339,8 @@ class ParallelInferenceModel(_ServingBase):
             fn = jax.jit(self._insert_valid_fn, donate_argnums=(0,),
                          out_shardings=self._io_shardings["batch"](None))
             fn = self._serving_cache.put("insert_valid", fn)
-        return fn(valid.astype(jnp.int32), jnp.asarray(row_valid, jnp.int32),
-                  jnp.int32(slot))
+        return fn(self._batch_committed(valid),
+                  jnp.asarray(row_valid, jnp.int32), jnp.int32(slot))
 
     def _build(self):
         from jax.sharding import NamedSharding

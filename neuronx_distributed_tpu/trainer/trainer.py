@@ -434,18 +434,24 @@ def make_train_step(
 
     def _step(params, opt_state, batch, rng):
         loss, grads = _loss_and_grad(params, batch, rng)
-        if mask is not None:
-            # frozen grads must not shape the clip norm (PEFT correctness)
-            grads = jax.tree.map(
-                lambda m, g: g if m else jnp.zeros_like(g), mask, grads)
-        if oc.grad_clipping:
-            grads, grad_norm = clip_grad_norm(grads, oc.max_grad_norm)
-        else:
-            from neuronx_distributed_tpu.parallel.grads import get_grad_norm
+        # flax names every module's operations in the device trace; the
+        # clip and the update belong to no module
+        with jax.named_scope("optimizer"):
+            if mask is not None:
+                # frozen grads must not shape the clip norm (PEFT
+                # correctness)
+                grads = jax.tree.map(
+                    lambda m, g: g if m else jnp.zeros_like(g), mask, grads)
+            if oc.grad_clipping:
+                grads, grad_norm = clip_grad_norm(grads, oc.max_grad_norm)
+            else:
+                from neuronx_distributed_tpu.parallel.grads import (
+                    get_grad_norm,
+                )
 
-            grad_norm = get_grad_norm(grads)
-        updates, opt_state = optimizer.tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+                grad_norm = get_grad_norm(grads)
+            updates, opt_state = optimizer.tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         metrics = {"loss": loss, "grad_norm": grad_norm}
         return params, opt_state, metrics
 
