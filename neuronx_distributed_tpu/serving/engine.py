@@ -89,6 +89,7 @@ from neuronx_distributed_tpu.trace.engine import (
     SPEC_RESIDUAL_SALT,
     _filtered_logits,
     _sample_logits,
+    _temperature_logits,
     request_rng,
 )
 from neuronx_distributed_tpu.utils.logger import get_logger
@@ -123,29 +124,69 @@ class _ChunkPrefill:
         return len(self.fresh) - self.next_i
 
 
+#: the sampler's paths, in the order of ``_sample_rows``'s ``lax.switch``
+SAMPLER_PATHS = ("greedy", "temperature", "filtered")
+
+
+def _sampler_path(temperature, top_k, top_p):
+    """Index into :data:`SAMPLER_PATHS` of the least work that serves these
+    rows: 0 when no row samples, 1 when some do and none of those filters,
+    2 otherwise.  ONE formula for the device's choice (traced, inside
+    :func:`_sample_rows`) and the host's count of it (the engine's numpy
+    mirrors): array methods only, so numpy stays on the host."""
+    samples = temperature > 0.0
+    filters = samples & ((top_k > 0) | (top_p < 1.0))
+    return samples.any().astype(np.int32) + filters.any().astype(np.int32)
+
+
 @jax.jit
 def _sample_rows(logits, base_keys, tok_idx, temperature, top_k, top_p):
     """Row-wise sampler: every slot draws token ``tok_idx[b]`` from its own
     request stream (``fold_in(base_keys[b], tok_idx[b])`` — the
     per-token fold_in happens INSIDE the jit, so the hot decode loop pays
     zero per-slot host dispatches) with its own sampler params.  One
-    compiled program serves any mix of greedy/sampled slots — greedy rows
-    take the ``where(temperature > 0)`` argmax branch and ignore their key.
-    Module-level jit so every engine over the same shapes shares one
-    compile.
+    compiled program serves any mix of greedy/sampled slots, and does only
+    the work the BATCH asks for: the parameter vectors pick one branch of a
+    ``lax.switch`` on the device (:func:`_sampler_path`; no host read) —
+    argmax alone when no row samples, a categorical draw on the
+    temperature-scaled logits when no sampling row filters, and otherwise
+    the full ``_sample_logits`` (two sorts and a gather over the
+    vocabulary) for every row.  The choice sits outside the ``vmap`` — a
+    ``cond`` under ``vmap`` is a ``select`` that runs both sides — and all
+    three branches return the tokens the full one would, bit for bit.  The
+    rows of slots that are not decoding must carry temperature 0 (the
+    engine resets a slot's row when it parks it), or one finished sampled
+    request holds the batch on the full branch.  Module-level jit so every
+    engine over the same shapes shares one compile.
 
     Returns ``(tokens [B], finite [B])``: ``finite[b]`` is False when row
     ``b``'s logits contain NaN/Inf — computed inside the jit (a cheap
     reduction riding the same dispatch; the full ``[B, V]`` logits never
     cross to the host) so the engine can quarantine a numerically blown-up
     slot without poisoning its co-batch."""
-    def row(lg, key, idx, t, k, p):
-        tok = _sample_logits(lg, jax.random.fold_in(key, idx), t, k, p)
-        return tok, jnp.all(jnp.isfinite(lg.astype(jnp.float32)))
+    def greedy(lg, keys, idx, t, k, p):
+        return jnp.argmax(lg, axis=-1).astype(jnp.int32)
+
+    def temperature_only(lg, keys, idx, t, k, p):
+        def row(lg, key, idx, t):
+            sampled = jax.random.categorical(
+                jax.random.fold_in(key, idx), _temperature_logits(lg, t),
+                axis=-1).astype(jnp.int32)
+            return jnp.where(t > 0.0, sampled,
+                             jnp.argmax(lg, axis=-1).astype(jnp.int32))
+        return jax.vmap(row)(lg, keys, idx, t)
+
+    def filtered(lg, keys, idx, t, k, p):
+        return jax.vmap(lambda lg, key, idx, t, k, p: _sample_logits(
+            lg, jax.random.fold_in(key, idx), t, k, p))(lg, keys, idx, t, k, p)
 
     with jax.named_scope("sample"):
-        return jax.vmap(row)(logits, base_keys, tok_idx, temperature, top_k,
-                             top_p)
+        toks = jax.lax.switch(
+            _sampler_path(temperature, top_k, top_p),
+            (greedy, temperature_only, filtered),
+            logits, base_keys, tok_idx, temperature, top_k, top_p)
+        finite = jnp.all(jnp.isfinite(logits.astype(jnp.float32)), axis=-1)
+        return toks, finite
 
 
 @jax.jit
@@ -863,6 +904,8 @@ class ServingEngine:
                   "rejected", "failed", "slow_steps", "preemptions", "shed",
                   "expired_before_prefill", "prefill_chunks"):
             reg.counter(f"serving/{c}_total")
+        for path in SAMPLER_PATHS:
+            reg.counter(f"serving/sampler_steps_total/{path}")
         # per-priority-class latency histograms: the SLO story is per tier
         # (the whole point of priority scheduling is that the interactive
         # percentiles stay flat while batch absorbs the queueing)
@@ -968,11 +1011,7 @@ class ServingEngine:
         req, slot = self.scheduler.withdraw(request_id, now=now)
         if slot is not None:
             self._chunking.pop(slot, None)
-            self._offsets[slot] = self.T  # park: the slot writes nothing
-            self._last_tok_time[slot] = None
-            if self._kv is not None:
-                self._kv.release_slot(slot)
-            self._release_adapter(slot)
+            self._park_slot(slot)
         if self._kv is not None:
             # a parked victim being migrated drops its local resume pin:
             # the destination resumes from the imported chain instead
@@ -1470,7 +1509,6 @@ class ServingEngine:
                 # each chunk adds a child span under it
                 self._trace_phase_attrs(req, chunked=True,
                                         fresh_pages=len(fresh))
-                self._set_sampling_state(slot, req)
                 if self._spec_k:
                     # the draft's contiguous row prefills whole at
                     # admission (spec × chunked-prefill): the draft is the
@@ -1547,13 +1585,15 @@ class ServingEngine:
                     self._draft_caches, drow_caches, self._draft_valid,
                     row_valid, slot)
 
-        self._set_sampling_state(slot, req)
         self._finish_prefill(slot, req, logits, outputs, prefilled_fresh)
 
     def _set_sampling_state(self, slot: int, req: Request) -> None:
         """Write the slot's per-request sampler state (base key, temp,
-        top-k/p) once at admission, so the decode loop builds no per-slot
-        keys host-side."""
+        top-k/p) once, as its prefill ends, so the decode loop builds no
+        per-slot keys host-side — and not before: while a slot is still
+        chunking its row is not a decoding one, and ``_sample_rows`` picks
+        the batch's work from every row (:meth:`_park_slot` is the other
+        end)."""
         s = req.sampling
         if s.temperature > 0.0 and self._rng is not None:
             self._base_keys[slot] = np.asarray(
@@ -1571,6 +1611,7 @@ class ServingEngine:
         and the chunk loop's final chunk: sample, finite-gate, register the
         prefix chain, transition to DECODE, stream/emit."""
         s = req.sampling
+        self._set_sampling_state(slot, req)
         toks, finite = _sample_rows(
             logits, jnp.asarray(self._base_keys[slot])[None, :],
             jnp.zeros((1,), jnp.int32),
@@ -1790,8 +1831,6 @@ class ServingEngine:
             self.scheduler.requeue(req, now=now)  # frees slot, resets req
             req.parked_at = now
             st = self._chunking.pop(slot, None)
-            self._offsets[slot] = self.T  # park
-            self._last_tok_time[slot] = None
             if self._kv is not None:
                 # pin the victim's COMMITTED leading chain before the
                 # slot's references drop: the re-grant then matches it in
@@ -1801,8 +1840,7 @@ class ServingEngine:
                 self._kv.park_resume(
                     slot, req,
                     fresh_done=st.next_i if st is not None else None)
-                self._kv.release_slot(slot)
-            self._release_adapter(slot)
+            self._park_slot(slot)
             self.registry.counter("serving/preemptions_total").inc()
             logger.info(
                 "serving: preempted batch request %d from slot %d for the "
@@ -1820,11 +1858,7 @@ class ServingEngine:
         req.shed_reason = SHED_EXPIRED_BEFORE_PREFILL
         self._trace_end_phase(req, t=now, expired=True)
         self.scheduler.release(req)
-        self._offsets[slot] = self.T  # park
-        self._last_tok_time[slot] = None
-        if self._kv is not None:
-            self._kv.release_slot(slot)
-        self._release_adapter(slot)
+        self._park_slot(slot)
         self.registry.counter("serving/expired_before_prefill_total").inc()
         self.registry.counter("serving/timed_out_total").inc()
         outputs.append(self._emit(req, now))
@@ -1844,6 +1878,14 @@ class ServingEngine:
         if self._kv is not None and not self._paged_kernel:
             self.registry.counter(GATHER_BYTES_TOTAL).inc(
                 self._gather_bytes_step)
+
+    def _count_sampler_step(self) -> None:
+        """Book which branch of ``_sample_rows`` the coming decode takes,
+        from the host mirrors of the vectors it is handed (the same
+        formula; no device read)."""
+        path = SAMPLER_PATHS[int(_sampler_path(self._temps, self._topks,
+                                               self._topps))]
+        self.registry.counter(f"serving/sampler_steps_total/{path}").inc()
 
     def _decode_step(self, active: list, outputs: list) -> None:
         """One per-slot-offset decode over the whole batch; inactive slots
@@ -1883,6 +1925,7 @@ class ServingEngine:
             self.registry.counter(QUANT_PAGES_TOTAL).inc(len(active))
         logits = perturb("serving/decode_logits", logits,
                          engine_step=self._steps)
+        self._count_sampler_step()
         toks_f = _sample_rows(
             logits, jnp.asarray(self._base_keys), jnp.asarray(tok_idx),
             jnp.asarray(self._temps), jnp.asarray(self._topks),
@@ -2059,6 +2102,7 @@ class ServingEngine:
                     (self._base_keys.copy(), self._temps.copy(),
                      self._topks.copy(), self._topps.copy()))
             self._sampling_dirty = False
+        self._count_sampler_step()
         toks, finite = _sample_rows(
             logits, self._keys_dev, tidx,
             self._temps_dev, self._topks_dev, self._topps_dev)
@@ -2341,11 +2385,7 @@ class ServingEngine:
         req.finish_time = now
         self._trace_end_phase(req, t=now)
         self.scheduler.release(req)
-        self._offsets[slot] = self.T  # park
-        self._last_tok_time[slot] = None
-        if self._kv is not None:
-            self._kv.release_slot(slot)
-        self._release_adapter(slot)
+        self._park_slot(slot)
         self.registry.counter("serving/finished_total").inc()
 
     def _fail_slot_state(self, slot: int, req: Request, now: float,
@@ -2361,11 +2401,7 @@ class ServingEngine:
         self._trace_end_phase(req, t=now, failed=reason)
         self.scheduler.release(req)
         self._chunking.pop(slot, None)
-        self._offsets[slot] = self.T  # park
-        self._last_tok_time[slot] = None
-        if self._kv is not None:
-            self._kv.release_slot(slot)
-        self._release_adapter(slot)
+        self._park_slot(slot)
         self.registry.counter("serving/failed_total").inc()
 
     def _fail_slot(self, slot: int, req: Request, outputs: list,
@@ -2406,19 +2442,30 @@ class ServingEngine:
         self._adapter_tables[slot] = 0
         self._adapter_dirty = True
 
+    def _park_slot(self, slot: int) -> None:
+        """Make a slot that lost its occupant inert until its next insert —
+        every release path ends here, and each piece is idempotent: offset
+        ``T`` writes nothing, the pages and the adapter pin go back, and
+        the sampler row returns to greedy, so a finished sampled request
+        does not hold the batch's sampler on its sort (``_sample_rows``
+        picks its work from every row it is handed)."""
+        self._offsets[slot] = self.T
+        self._last_tok_time[slot] = None
+        if self._kv is not None:
+            self._kv.release_slot(slot)
+        self._release_adapter(slot)
+        if self._temps[slot] > 0.0:
+            self._temps[slot] = 0.0
+            self._sampling_dirty = True
+
     def _park_free_slots(self) -> None:
-        """Reset the device-side state of every slot without a live occupant
-        (after a sweep freed cancelled/timed-out requests): offset ``T``
-        writes nothing, so a freed slot is inert until its next insert."""
+        """Park every slot without a live occupant (after a sweep freed
+        cancelled/timed-out requests)."""
         live = {slot for slot, _ in self.scheduler.active()}
         for slot in range(self.B):
             if slot not in live:
-                self._offsets[slot] = self.T
-                self._last_tok_time[slot] = None
                 self._chunking.pop(slot, None)  # abandon a mid-chunk prefill
-                if self._kv is not None:  # idempotent page reclamation
-                    self._kv.release_slot(slot)
-                self._release_adapter(slot)  # idempotent pin release
+                self._park_slot(slot)
 
     def _emit(self, req: Request, now: float) -> RequestOutput:
         if req.parked_at is not None:

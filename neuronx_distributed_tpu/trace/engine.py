@@ -221,14 +221,22 @@ def request_rng(rng: jax.Array, request_id: int) -> jax.Array:
     return jax.random.fold_in(rng, jnp.uint32(request_id))
 
 
+def _temperature_logits(logits, temperature):
+    """fp32 logits over the temperature (floored at 1e-6): what
+    :func:`_filtered_logits` returns, bit for bit, when ``top_k == 0`` and
+    ``top_p == 1`` — the serving sampler's temperature-only path draws from
+    this and skips the sort."""
+    return logits.astype(jnp.float32) / jnp.maximum(
+        jnp.asarray(temperature, jnp.float32), 1e-6
+    )
+
+
 def _filtered_logits(logits, temperature, top_k=0, top_p=1.0):
     """Temperature/top-k/nucleus-filtered fp32 logits — the distribution the
     sampler actually draws from (dropped tokens at -inf-equivalent).  Shared
     by :func:`_sample_logits` and the sampled speculative-decoding accept
     test, which needs the filtered p/q distributions themselves."""
-    logits = logits.astype(jnp.float32) / jnp.maximum(
-        jnp.asarray(temperature, jnp.float32), 1e-6
-    )
+    logits = _temperature_logits(logits, temperature)
     neg = jnp.finfo(jnp.float32).min
     top_k = jnp.asarray(top_k, jnp.int32)
     top_p = jnp.asarray(top_p, jnp.float32)
@@ -255,8 +263,12 @@ def _sample_logits(logits, rng, temperature, top_k=0, top_p=1.0):
     the smallest prefix of the sorted distribution whose mass reaches p
     (applied after top-k).  All three knobs may be TRACED scalars — one
     compiled program serves every sampler setting (per-request settings must
-    not each pay an XLA compile) — with the pure-greedy Python-float
-    ``temperature == 0.0`` short-circuit kept so greedy callers need no rng.
+    not each pay an XLA compile).  A traced ``temperature`` always pays the
+    whole filter (two sorts over the vocabulary) and selects greedy or
+    sampled afterwards; only a Python-float ``temperature == 0.0`` takes the
+    short-circuit, which serves ``generate()``'s greedy callers (no rng
+    needed).  The serving engine's ``_sample_rows`` chooses the work once a
+    batch instead and calls this only for a batch that filters.
     Serving parity with HF ``generate``'s standard sampler knobs (the
     reference drives its compiled pair through HF generate,
     ``neuron_modeling_llama.py:437-465``).

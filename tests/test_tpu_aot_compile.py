@@ -151,3 +151,47 @@ def test_paged_kernel_compiles_for_v5e(topo, case):
         for op in ("all-gather", "all-reduce", "all-to-all",
                    "collective-permute"):
             assert op not in text, f"{case}: unexpected {op}"
+
+
+# -- the serving sampler -----------------------------------------------------
+
+
+def test_sampler_keeps_its_sort_behind_a_conditional_for_v5e(topo):
+    """The chip's compiler keeps ``_sample_rows``'s batch-level choice a
+    ``conditional`` of three branches (it neither flattens it into a select
+    of both sides nor hoists the sorts out), so an all-greedy batch runs the
+    argmax branch alone.  At the chat cell's shape: 32 slots, Qwen2's
+    vocabulary (one case: the sort alone takes the compiler half a minute)."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from neuronx_distributed_tpu.serving.engine import (
+        SAMPLER_PATHS,
+        _sample_rows,
+    )
+
+    B, V = 32, 152064
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = _sample_rows.lower(
+        sds((B, V), jnp.float32), sds((B, 2), jnp.uint32),
+        sds((B,), jnp.int32), sds((B,), jnp.float32), sds((B,), jnp.int32),
+        sds((B,), jnp.float32)).compile().as_text()
+    [cond] = re.findall(r" conditional\(.*branch_computations=\{([^}]*)\}",
+                        text)
+    branches = [b.strip().lstrip("%") for b in cond.split(",")]
+    assert len(branches) == len(SAMPLER_PATHS)
+    # every sort sits in a computation reachable only from the last branch:
+    # the entry computation and the first two branches name none
+    bodies = dict(re.findall(
+        r"^(?:ENTRY )?%(\S+) \([^\n]*\{\n(.*?)^\}", text, re.S | re.M))
+    assert " sort(" not in bodies[branches[0]]
+    assert " sort(" not in bodies[branches[1]]
+    assert " sort(" in bodies[branches[2]]
+    entry = re.search(r"^ENTRY %\S+ \([^\n]*\{\n(.*?)^\}", text,
+                      re.S | re.M).group(1)
+    assert " sort(" not in entry and " conditional(" in entry
