@@ -172,6 +172,91 @@ def llama_params_to_hf(params: Mapping[str, Any], cfg) -> Dict[str, np.ndarray]:
 
 
 # ---------------------------------------------------------------------------
+# OLMoE (Llama layout + q/k norms + a routed expert block per layer)
+# ---------------------------------------------------------------------------
+
+
+def olmoe_params_from_hf(state_dict: Mapping[str, Any], cfg) -> Dict[str, Any]:
+    """HF ``OlmoeForCausalLM.state_dict()`` -> the param tree of
+    :class:`~..models.llama.LlamaForCausalLM` under an OLMoE config
+    (``qk_norm``, ``num_experts`` > 1): ``mlp.gate.weight [E, H]`` becomes
+    ``moe_mlp/router [H, E]``, the per-expert ``experts.{i}.{gate,up,
+    down}_proj`` the stacked ``gate [E, H, I]``, ``up [E, H, I]`` (the
+    dropless layout, ``parallel/moe.py``) and ``down [E, I, H]``,
+    ``self_attn.{q,k}_norm`` the full-width norms."""
+    sd = {k: _np(v) for k, v in state_dict.items()}
+    H, D, E = cfg.hidden_size, cfg.head_dim_, cfg.num_experts
+    model: Dict[str, Any] = {
+        "embed": {"embedding": sd["model.embed_tokens.weight"]},
+        "final_norm": {"weight": sd["model.norm.weight"]},
+    }
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}."
+        ex = p + "mlp.experts."
+        model[f"layer_{i}"] = {
+            "attn": {
+                "qkv": {
+                    "q_kernel": sd[p + "self_attn.q_proj.weight"].T.reshape(
+                        H, cfg.num_heads, D),
+                    "k_kernel": sd[p + "self_attn.k_proj.weight"].T.reshape(
+                        H, cfg.num_kv_heads, D),
+                    "v_kernel": sd[p + "self_attn.v_proj.weight"].T.reshape(
+                        H, cfg.num_kv_heads, D),
+                },
+                "q_norm": {"weight": sd[p + "self_attn.q_norm.weight"]},
+                "k_norm": {"weight": sd[p + "self_attn.k_norm.weight"]},
+                "o_proj": {"kernel": sd[p + "self_attn.o_proj.weight"].T},
+            },
+            "moe_mlp": {
+                "router": sd[p + "mlp.gate.weight"].T,
+                "gate": np.stack([sd[f"{ex}{e}.gate_proj.weight"].T
+                                  for e in range(E)]),
+                "up": np.stack([sd[f"{ex}{e}.up_proj.weight"].T
+                                for e in range(E)]),
+                "down": np.stack([sd[f"{ex}{e}.down_proj.weight"].T
+                                  for e in range(E)]),
+            },
+            "input_norm": {"weight": sd[p + "input_layernorm.weight"]},
+            "post_attn_norm": {
+                "weight": sd[p + "post_attention_layernorm.weight"]},
+        }
+    return {"params": {"model": model,
+                       "lm_head": {"kernel": sd["lm_head.weight"].T}}}
+
+
+def olmoe_params_to_hf(params: Mapping[str, Any], cfg) -> Dict[str, np.ndarray]:
+    """Inverse of :func:`olmoe_params_from_hf`."""
+    tree = params.get("params", params)
+    model, H = tree["model"], cfg.hidden_size
+    out: Dict[str, np.ndarray] = {
+        "model.embed_tokens.weight": _np(model["embed"]["embedding"]),
+        "model.norm.weight": _np(model["final_norm"]["weight"]),
+        "lm_head.weight": _np(tree["lm_head"]["kernel"]).T,
+    }
+    for i in range(cfg.num_layers):
+        lyr, p = model[f"layer_{i}"], f"model.layers.{i}."
+        attn, moe = lyr["attn"], lyr["moe_mlp"]
+        gate, up, down = (_np(moe[k]) for k in ("gate", "up", "down"))
+        out.update({
+            p + "self_attn.q_proj.weight": _np(attn["qkv"]["q_kernel"]).reshape(H, -1).T,
+            p + "self_attn.k_proj.weight": _np(attn["qkv"]["k_kernel"]).reshape(H, -1).T,
+            p + "self_attn.v_proj.weight": _np(attn["qkv"]["v_kernel"]).reshape(H, -1).T,
+            p + "self_attn.o_proj.weight": _np(attn["o_proj"]["kernel"]).T,
+            p + "self_attn.q_norm.weight": _np(attn["q_norm"]["weight"]),
+            p + "self_attn.k_norm.weight": _np(attn["k_norm"]["weight"]),
+            p + "mlp.gate.weight": _np(moe["router"]).T,
+            p + "input_layernorm.weight": _np(lyr["input_norm"]["weight"]),
+            p + "post_attention_layernorm.weight": _np(lyr["post_attn_norm"]["weight"]),
+        })
+        for e in range(cfg.num_experts):
+            ex = f"{p}mlp.experts.{e}."
+            out[ex + "gate_proj.weight"] = gate[e].T
+            out[ex + "up_proj.weight"] = up[e].T
+            out[ex + "down_proj.weight"] = down[e].T
+    return out
+
+
+# ---------------------------------------------------------------------------
 # GPT-NeoX
 # ---------------------------------------------------------------------------
 

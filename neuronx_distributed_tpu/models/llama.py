@@ -135,6 +135,12 @@ class LlamaConfig:
     # teacher-forced training and incremental decoding — principally an
     # encoder/research router, see parallel/moe.py)
     moe_router: str = "topk"
+    # top-k gates renormalised to sum 1 (Mixtral) or left as the softmax
+    # gave them (OLMoE); HF ``norm_topk_prob``
+    moe_norm_topk_prob: bool = True
+    # RMSNorm over the whole q and k projections (all heads at once), after
+    # any bias, before the head split and RoPE (OLMoE's ``q_norm``/``k_norm``)
+    qk_norm: bool = False
     # internal (set by build_pipelined_llama): experts held per ep rank when
     # the PP engine's manual-ep expert sharding is active; 0 = GSPMD mode
     moe_local_experts: int = 0
@@ -225,6 +231,18 @@ class LlamaConfig:
             vocab_size=32000, hidden_size=4096, intermediate_size=14336,
             num_layers=32, num_heads=32, num_kv_heads=8, rope_theta=1e6,
             num_experts=8, moe_top_k=2, moe_dispatch="scatter"), **overrides})
+
+    @staticmethod
+    def olmoe_1b_7b(**overrides) -> "LlamaConfig":
+        """OLMoE-1B-7B (allenai/OLMoE-1B-7B-0125-Instruct): 16 layers of
+        plain multi-head attention with q/k RMSNorm, every MLP 64 experts
+        of width 1024, 8 a token, gates not renormalised, no token dropped."""
+        return LlamaConfig(**{**dict(
+            vocab_size=50304, hidden_size=2048, intermediate_size=1024,
+            num_layers=16, num_heads=16, num_kv_heads=16, rope_theta=10000.0,
+            rms_eps=1e-5, num_experts=64, moe_top_k=8,
+            moe_norm_topk_prob=False, moe_dispatch="dropless",
+            qk_norm=True), **overrides})
 
     @staticmethod
     def tiny(**overrides) -> "LlamaConfig":
@@ -403,6 +421,17 @@ class LlamaAttention(nn.Module):
             dv = jnp.einsum("bsr,bro->bso", xv, b_v.astype(cfg.dtype),
                             preferred_element_type=cfg.dtype)
             v = v + dv.reshape(B_, S_, cfg.num_kv_heads, D)
+        if cfg.qk_norm:
+            # full-width: the statistic runs over every head of the
+            # projection, which the fused QKV hands over split into heads
+            def full_width_norm(t, name):
+                flat = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
+                               param_dtype=cfg.param_dtype, name=name)(
+                    t.reshape(*t.shape[:-2], -1))
+                return flat.reshape(t.shape)
+
+            q = full_width_norm(q, "q_norm")
+            k = full_width_norm(k, "k_norm")
         sin, cos = rope_sin_cos(positions, D, cfg.rope_theta, cfg.rope_scaling_)
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
@@ -642,6 +671,25 @@ class LlamaMLP(nn.Module):
         )(h)
 
 
+def row_validity(kv_valid, cache_offset, rows: int, cached: bool):
+    """Which of a call's ``[B, rows]`` rows are tokens, from what the serve
+    programs already hold: row ``s`` of example ``b`` sits at key index
+    ``cache_offset[b] + s`` of the validity row ``kv_valid [B, T]`` (a
+    chunk's pad rows are invalid cells there; a parked slot's offset is past
+    ``T``).  Without a cache ``kv_valid`` is the rows' own mask.  ``None``:
+    every row is a token."""
+    if kv_valid is None:
+        return None
+    kv_valid = jnp.asarray(kv_valid)
+    if not cached:
+        return kv_valid > 0
+    T = kv_valid.shape[1]
+    idx = jnp.reshape(cache_offset, (-1, 1)) + jnp.arange(rows)[None, :]
+    idx = jnp.broadcast_to(idx, (kv_valid.shape[0], rows))
+    return (idx < T) & (jnp.take_along_axis(
+        kv_valid, jnp.clip(idx, 0, T - 1), axis=1) > 0)
+
+
 class LlamaBlock(nn.Module):
     config: LlamaConfig
 
@@ -662,18 +710,28 @@ class LlamaBlock(nn.Module):
         if cfg.num_experts > 1:
             from neuronx_distributed_tpu.parallel.moe import ExpertParallelMLP
 
-            h, aux = ExpertParallelMLP(
+            # served (a cache is there) without capacity whatever the model
+            # trains with: a request's logits may not depend on its co-batch
+            dropless = kv_cache is not None or cfg.moe_dispatch == "dropless"
+            moe = ExpertParallelMLP(
                 num_experts=cfg.moe_local_experts or cfg.num_experts,
                 num_experts_global=cfg.num_experts if cfg.moe_local_experts else 0,
                 intermediate_size=cfg.intermediate_size,
                 top_k=cfg.moe_top_k,
                 capacity_factor=cfg.moe_capacity_factor,
-                dispatch=cfg.moe_dispatch,
+                dispatch="dropless" if dropless else cfg.moe_dispatch,
+                norm_topk_prob=cfg.moe_norm_topk_prob,
+                fused_gate_up=cfg.moe_dispatch != "dropless",
                 router_type=cfg.moe_router,
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 name="moe_mlp",
-            )(normed)
+            )
+            # the expert block is the layer's MLP in a device trace too
+            with jax.named_scope("mlp"):
+                h, aux = (moe(normed, row_validity(
+                    kv_valid, cache_offset, normed.shape[1],
+                    kv_cache is not None)) if dropless else moe(normed))
             # collected by losses-mutable apply (causal_lm_loss adds the
             # load-balancing term); silently dropped when not collected
             self.sow("losses", "moe_aux", aux)
@@ -684,6 +742,17 @@ class LlamaBlock(nn.Module):
             # residual stream lives sequence-sharded between blocks
             x = shard_activation(x, trailing_spec(x.ndim, seq=SEQUENCE_AXES, last=None))
         return x, new_cache
+
+
+def moe_layer_stats(variables, num_layers: int) -> dict:
+    """The ``moe_stats`` collection of one apply of a
+    :class:`LlamaForCausalLM`, stacked over layers: ``load [L, E]``, the
+    valid assignments each expert took in that call, and ``choice [L, rows,
+    K]``, each row's experts (``parallel/moe.py``, dropless path)."""
+    layers = variables["moe_stats"]["model"]
+    return {k: jnp.stack([layers[f"layer_{i}"]["moe_mlp"][k][-1]
+                          for i in range(num_layers)])
+            for k in ("load", "choice")}
 
 
 class LlamaModel(nn.Module):

@@ -24,6 +24,16 @@ everything stays static-shaped (capacity-bounded) for jit:
 
 The load-balancing auxiliary loss is the Switch-Transformer form
 ``E * sum_e(frac_tokens_e * mean_prob_e)`` (=1 at perfect balance).
+
+Serving takes none of that: a capacity drop makes a token's output depend on
+who shares its step.  ``dispatch="dropless"`` is the token-choice path with
+no capacity — the ``N * K`` assignments sorted by expert (stable), gate, up
+and down each one grouped matmul over the ragged groups
+(:func:`grouped_matmul`), un-sorted and summed under the gates — so a row's
+result is a function of that row alone.  Rows marked invalid (a chunk's
+padding, a parked slot) are routed nowhere.  It is the path every
+``LlamaConfig`` MoE model is served on, and the path OLMoE (dropless by its
+published definition) takes everywhere.
 """
 
 from __future__ import annotations
@@ -40,6 +50,8 @@ from neuronx_distributed_tpu.parallel.mesh import (
     EXPERT_AXIS,
     TENSOR_AXES,
     ambient_manual_axes,
+    get_tensor_parallel_size,
+    model_parallel_is_initialized,
     strip_axes_from_spec,
 )
 from jax.sharding import PartitionSpec as P
@@ -70,6 +82,46 @@ def load_balancing_loss(probs: jax.Array, expert_mask: jax.Array) -> jax.Array:
     return E * jnp.sum(frac * mean_p)
 
 
+# megablox's (m, k, n) tile: the fastest of those tried at OLMoE-1B-7B's
+# widths on the v5e, a decode's 128 assignment rows (1.60 ms an expert block
+# where ``lax.ragged_dot`` took 1.91) and a chunk's 4096 (2.18 against 3.73):
+# benchmarks/tools/moe_gmm_probe.py, PERF.md (Findings, PR 25)
+GMM_TILING = (128, 2048, 1024)
+
+
+def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
+                   dtype: Dtype) -> jax.Array:
+    """``x [M, K]`` rows sorted by group, ``w [G, K, N]``, ``group_sizes
+    [G]`` -> ``[M, N]``: row ``r`` of group ``g`` is ``x[r] @ w[g]``, fp32
+    accumulation, stored in ``dtype``.  Rows past ``sum(group_sizes)``
+    belong to no group; what they hold is unspecified (callers mask them).
+    A row's result does not depend on the other rows or on the sizes.
+
+    A program lowered for a TPU with the expert width whole on each chip
+    carries the megablox Pallas kernel (scope ``moe_gmm``); any other,
+    ``lax.ragged_dot``.  Both differentiate."""
+    sizes = group_sizes.astype(jnp.int32)
+
+    def ragged(x, w, sizes):
+        return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=dtype)
+
+    def kernel(x, w, sizes):
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        tm, tk, tn = GMM_TILING
+        pad = -x.shape[0] % tm          # whole row tiles; the pad is in no group
+        xp = jnp.pad(x, ((0, pad), (0, 0))) if pad else x
+        with jax.named_scope("moe_gmm"):
+            out = gmm(xp, w, sizes, preferred_element_type=dtype,
+                      tiling=(tm, min(tk, w.shape[1]), min(tn, w.shape[2])))
+        return out[:x.shape[0]]
+
+    if model_parallel_is_initialized() and get_tensor_parallel_size() > 1:
+        # GSPMD cannot split a Pallas call
+        return ragged(x, w, sizes)
+    return jax.lax.platform_dependent(x, w, sizes, tpu=kernel, default=ragged)
+
+
 class ExpertParallelMLP(nn.Module):
     """Top-k routed MoE FFN; experts sharded over ``ep``, each expert's
     hidden dim over the TP axes (the dense MLP's sharding, per expert).
@@ -86,7 +138,21 @@ class ExpertParallelMLP(nn.Module):
     #   (multi-GB at Mixtral scale: N≈32k, E=8, C≈6k — VERDICT r3 weak #3).
     # "scatter": capacity-bucketed segment-sum dispatch + gather combine —
     #   O(N·K·H + E·C·H) memory, the trainable path at preset scale.
+    # "dropless": no capacity — sort by expert + grouped matmuls; a row's
+    #   output depends on that row alone (the served path; see the module
+    #   docstring).  Experts are not split over ``ep`` on this path.
     dispatch: str = "einsum"
+    # top-k gates renormalised to sum 1 (Mixtral; HF ``norm_topk_prob``) or
+    # left as the softmax gave them (OLMoE)
+    norm_topk_prob: bool = True
+    # ``gate_up [E, H, 2, I]`` (the capacity paths' einsum reads it as it
+    # lies) or, False, ``gate [E, H, I]`` and ``up [E, H, I]``: a grouped
+    # matmul kernel takes ``[E, K, N]`` operands, and on the v5e cutting them
+    # out of the fused array relaid 512 MB out a layer a program — 36% of
+    # the device in OLMoE's serving cell (PERF.md, Findings, PR 25).  A model
+    # whose dispatch IS dropless stores them apart; a model trained fused
+    # and served dropless pays that copy.
+    fused_gate_up: bool = True
     # manual expert parallelism (inside the PP engine's shard_map, where
     # ``ep`` is a manual axis): ``num_experts`` is then the LOCAL expert
     # count held by this ep rank and ``num_experts_global`` the routing
@@ -111,7 +177,10 @@ class ExpertParallelMLP(nn.Module):
     kernel_init: Initializer = nn.initializers.lecun_normal()
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    def __call__(self, x: jax.Array, valid=None) -> Tuple[jax.Array, jax.Array]:
+        """``valid [...]`` (the lead dims of ``x``; dropless path only)
+        marks the rows that are tokens: the others are routed nowhere and
+        come out zero."""
         from jax import lax
 
         manual_ep = bool(self.num_experts_global) and \
@@ -125,9 +194,17 @@ class ExpertParallelMLP(nn.Module):
             )
         if self.top_k > Eg:
             raise ValueError(f"top_k={self.top_k} > num_experts={Eg}")
-        if self.dispatch not in ("einsum", "scatter"):
+        if self.dispatch not in ("einsum", "scatter", "dropless"):
             raise ValueError(
-                f"unknown dispatch {self.dispatch!r} (einsum | scatter)")
+                f"unknown dispatch {self.dispatch!r} "
+                "(einsum | scatter | dropless)")
+        dropless = self.dispatch == "dropless"
+        if dropless and (manual_ep or self.router_type != "topk"):
+            raise ValueError(
+                "dispatch='dropless' is token-choice routing with every "
+                "expert in one program (no manual ep, no expert_choice)")
+        if valid is not None and not dropless:
+            raise ValueError("row validity is the dropless path's argument")
         if self.router_type not in ("topk", "expert_choice"):
             raise ValueError(
                 f"unknown router_type {self.router_type!r} "
@@ -148,16 +225,32 @@ class ExpertParallelMLP(nn.Module):
             "router", nn.with_partitioning(self.kernel_init, (None, None)),
             (H, Eg), self.param_dtype,
         )
-        wi = self.param(
-            "gate_up",
-            nn.with_partitioning(self.kernel_init, (EXPERT_AXIS, None, None, TENSOR_AXES)),
-            (E, H, 2, I), self.param_dtype,
-        )
+        if self.fused_gate_up:
+            wi = self.param(
+                "gate_up",
+                nn.with_partitioning(self.kernel_init, (EXPERT_AXIS, None, None, TENSOR_AXES)),
+                (E, H, 2, I), self.param_dtype,
+            )
+        elif not dropless:
+            raise ValueError("fused_gate_up=False is the dropless path's layout")
+        else:
+            wi = tuple(jnp.asarray(self.param(
+                name, nn.with_partitioning(
+                    self.kernel_init, (EXPERT_AXIS, None, TENSOR_AXES)),
+                (E, H, I), self.param_dtype)) for name in ("gate", "up"))
         wo = self.param(
             "down",
             nn.with_partitioning(self.kernel_init, (EXPERT_AXIS, TENSOR_AXES, None)),
             (E, I, H), self.param_dtype,
         )
+
+        if dropless:
+            if self.fused_gate_up:
+                wi = (jnp.asarray(wi)[:, :, 0, :], jnp.asarray(wi)[:, :, 1, :])
+            y, aux = self._dropless(
+                xt, None if valid is None else valid.reshape(-1),
+                jnp.asarray(router), wi, jnp.asarray(wo))
+            return y.reshape(*lead, H), aux
 
         # -- routing (fp32), over the GLOBAL expert space ---------------------
         logits = jnp.einsum(
@@ -212,9 +305,10 @@ class ExpertParallelMLP(nn.Module):
         keep = pos_in_expert < cap  # capacity drop
         gate_vals = gate_vals * keep
 
-        # normalize kept gates per token (Mixtral convention); fp32
-        denom = jnp.maximum(jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
-        gate_vals = gate_vals / denom
+        if self.norm_topk_prob:
+            # normalize kept gates per token (Mixtral convention); fp32
+            denom = jnp.maximum(jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+            gate_vals = gate_vals / denom
 
         # under manual ep this rank computes experts [e0, e0+E) of the
         # global space; elsewhere e0 = 0 and E == Eg
@@ -279,3 +373,59 @@ class ExpertParallelMLP(nn.Module):
             y = lax.psum_scatter(y, EXPERT_AXIS, scatter_dimension=0, tiled=True)
         y = shard_activation(y, _auto_spec(BATCH_AXES, None))
         return y.reshape(*lead, H).astype(self.dtype), aux.astype(jnp.float32)
+
+    def _dropless(self, xt, valid, router, wi, wo):
+        """``xt [N, H]`` -> ``(y [N, H], aux)`` with no capacity; ``wi`` is
+        the pair ``(gate, up)``, each ``[E, H, I]``.  Sown into
+        ``moe_stats`` (kept only by an apply that makes it mutable): ``load
+        [E]``, the valid assignments each expert took, and ``choice [N,
+        K]``, each row's experts in gate order (``E`` for an invalid row)."""
+        N, H = xt.shape
+        E, I, K = self.num_experts, self.intermediate_size, self.top_k
+        with jax.named_scope("moe_router"):
+            logits = jnp.einsum("nh,he->ne", xt.astype(jnp.float32),
+                                router.astype(jnp.float32))
+            probs = jax.nn.softmax(logits, axis=-1)
+            gates, choice = jax.lax.top_k(probs, K)            # [N, K]
+            if self.norm_topk_prob:
+                gates = gates / jnp.maximum(
+                    jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+            live = (jnp.ones((N, 1), bool) if valid is None
+                    else valid.astype(bool)[:, None])
+            # the Switch loss over the live rows (:func:`load_balancing_loss`)
+            took = jnp.where(live, jnp.sum(
+                jax.nn.one_hot(choice, E, dtype=jnp.float32), axis=1), 0.0)
+            n_live = jnp.maximum(jnp.sum(live), 1)
+            aux = E * jnp.sum(jnp.sum(took, 0) / n_live
+                              * jnp.sum(jnp.where(live, probs, 0.0), 0)
+                              / n_live)
+            # an invalid row's assignments go to group E: past every
+            # expert's rows once sorted, in no count, under a zero gate
+            choice = jnp.where(live, choice, E)
+            gates = jnp.where(live, gates, 0.0)
+        with jax.named_scope("moe_dispatch"):
+            flat = choice.reshape(-1)                          # token-major
+            # stable: within an expert the rows keep their token order, so
+            # nothing about a row's place depends on its neighbours' values
+            order = jnp.argsort(flat, stable=True)
+            load = jnp.sum(flat[:, None] == jnp.arange(E)[None, :], axis=0,
+                           dtype=jnp.int32)                    # [E]
+            xs = xt.astype(self.dtype)[order // K]             # [N*K, H]
+        with jax.named_scope("moe_experts"):
+            gate, up = (grouped_matmul(xs, w.astype(self.dtype), load,
+                                       self.dtype) for w in wi)
+            h = jax.nn.silu(gate) * up
+            h = shard_activation(h, _auto_spec(None, TENSOR_AXES))
+            ys = grouped_matmul(h, wo.astype(self.dtype), load, self.dtype)
+        with jax.named_scope("moe_combine"):
+            in_group = jnp.arange(N * K) < jnp.sum(load)
+            ys = jnp.where(in_group[:, None], ys, 0)
+            back = jnp.zeros((N * K,), jnp.int32).at[order].set(
+                jnp.arange(N * K, dtype=jnp.int32))
+            y = jnp.sum(ys[back].reshape(N, K, H).astype(jnp.float32)
+                        * gates[:, :, None], axis=1).astype(self.dtype)
+            y = shard_activation(y, _auto_spec(BATCH_AXES, None))
+        if not self.is_initializing():  # never part of a parameter tree
+            self.sow("moe_stats", "load", load)
+            self.sow("moe_stats", "choice", choice)
+        return y, aux.astype(jnp.float32)

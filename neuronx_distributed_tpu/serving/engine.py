@@ -613,6 +613,11 @@ class ServingEngine:
         if registry is None and obs is not None:
             registry = obs.registry
         self.registry = registry if registry is not None else MetricRegistry()
+        # expert loads summed since this engine began, [L, E]; what the
+        # model ran before (another engine, a check) is not this engine's
+        self._moe_load = None
+        self._pending_moe_seq = None   # the in-flight decode's moe_seq
+        self._take_moe_loads()
         # resource ledgers (obs.compile_ledger / obs.memory_ledger).  An
         # explicit compile ledger is attached to the MODEL (and the draft)
         # so the AOT phase-fn wrappers and every _CompiledLRU family report
@@ -1620,9 +1625,7 @@ class ServingEngine:
             jnp.full((1,), s.top_p, jnp.float32))
         # admission is off the steady path, but its fetch is still ONE
         # explicit packed read (first token + finite flag together)
-        with phase("serve/fetch"):
-            first = self._audit.fetch(_pack_tokens(toks, finite),
-                                      label="serving")
+        first = self._fetch_tokens(_pack_tokens(toks, finite))
         now = self._clock()
         self.registry.counter("serving/admitted_total").inc()
         if not bool(first[1][0]):
@@ -1879,6 +1882,57 @@ class ServingEngine:
             self.registry.counter(GATHER_BYTES_TOTAL).inc(
                 self._gather_bytes_step)
 
+    def _fetch_tokens(self, packed_dev, upto=None):
+        """The step's ONE device->host read.  A routed model's expert loads
+        (``[L, E]`` a paged program, waiting on the device since their
+        program ran) ride it, so counting them costs no sync of its own:
+        ``upto`` (a decode's ``moe_seq``) leaves the loads of programs
+        launched AFTER the one whose tokens are read — this step's prefill
+        chunk — for the next fetch, which would otherwise wait for them."""
+        programs, loads = self._take_moe_loads(upto)
+        with phase("serve/fetch"):
+            if not loads:
+                return self._audit.fetch(packed_dev, label="serving")
+            packed, loads = self._audit.fetch((packed_dev, loads),
+                                              label="serving")
+        self._count_moe(programs, loads)
+        return packed
+
+    def _take_moe_loads(self, upto=None):
+        """``(program families, device loads [L, E])`` of the paged
+        programs a routed model ran since the last call."""
+        take = getattr(self.model, "take_moe_stats", None)
+        stats = take(upto) if take is not None else []
+        return [s["program"] for s in stats], [s["load"] for s in stats]
+
+    def _count_moe(self, programs, loads) -> None:
+        """Book the expert loads of the paged programs just fetched:
+        ``moe/assignments_total`` (valid rows x experts a token x layers),
+        ``moe/layer_calls_total`` (expert blocks that ran with a token),
+        ``moe/experts_hit_total`` (experts with a row, summed over those
+        calls) — the last two also by program family, ``.../decode_pages``
+        and ``.../prefill_chunk_pages``: a decode's few rows leave experts
+        unread, a chunk's hundreds do not — and the gauge
+        ``moe/expert_load_max_over_mean`` (per layer, the busiest expert's
+        assignments over the mean expert's since the engine began; the mean
+        over layers)."""
+        if not loads:
+            return
+        reg = self.registry
+        for program, load in zip(programs, loads):
+            load = np.asarray(load, np.int64)
+            calls, hit = int((load.sum(axis=1) > 0).sum()), int((load > 0).sum())
+            reg.counter("moe/assignments_total").inc(int(load.sum()))
+            for suffix in ("", "/" + program):
+                reg.counter("moe/layer_calls_total" + suffix).inc(calls)
+                reg.counter("moe/experts_hit_total" + suffix).inc(hit)
+            self._moe_load = load + (0 if self._moe_load is None
+                                     else self._moe_load)
+        mean = self._moe_load.mean(axis=1)
+        if (mean > 0).any():
+            reg.gauge("moe/expert_load_max_over_mean").set(float(np.mean(
+                self._moe_load.max(axis=1)[mean > 0] / mean[mean > 0])))
+
     def _count_sampler_step(self) -> None:
         """Book which branch of ``_sample_rows`` the coming decode takes,
         from the host mirrors of the vectors it is handed (the same
@@ -1931,6 +1985,8 @@ class ServingEngine:
             jnp.asarray(self._temps), jnp.asarray(self._topks),
             jnp.asarray(self._topps))
         toks, finite = np.asarray(toks_f[0]), np.asarray(toks_f[1])
+        programs, loads = self._take_moe_loads()
+        self._count_moe(programs, jax.device_get(loads))
         now = self._clock()
         for slot, req in active:
             self._offsets[slot] += 1  # the step wrote req's previous token
@@ -1971,8 +2027,7 @@ class ServingEngine:
             return []
         packed_dev, active = self._pending
         self._pending = None
-        with phase("serve/fetch"):
-            packed = self._audit.fetch(packed_dev, label="serving")  # [2, B]
+        packed = self._fetch_tokens(packed_dev, self._pending_moe_seq)  # [2, B]
         toks, finite = packed[0], packed[1]
         now = self._clock()
         tr = self.tracer
@@ -2110,6 +2165,7 @@ class ServingEngine:
                          [(slot, req, int(self._slot_gen[slot]))
                           for slot, req in active])
         self._pending_version = self.weights_version
+        self._pending_moe_seq = getattr(self.model, "moe_seq", None)
 
     def _spec_dispatch(self, active: list) -> None:
         """Dispatch one speculative draft-k-verify round for the current
@@ -2221,6 +2277,7 @@ class ServingEngine:
                          [(slot, req, int(self._slot_gen[slot]))
                           for slot, req in active], props[-1])
         self._pending_version = self.weights_version
+        self._pending_moe_seq = getattr(self.model, "moe_seq", None)
 
     def _spec_collect(self) -> list:
         """Collect the in-flight speculative round: ONE explicit packed
@@ -2242,9 +2299,7 @@ class ServingEngine:
         packed_dev, active, last_prop = self._pending
         self._pending = None
         k = self._spec_k
-        with phase("serve/fetch"):
-            packed = self._audit.fetch(packed_dev,
-                                       label="serving")  # [k+3, B]
+        packed = self._fetch_tokens(packed_dev, self._pending_moe_seq)  # [k+3, B]
         commit, acc, finite = packed[:k + 1], packed[k + 1], packed[k + 2]
         now = self._clock()
         tr = self.tracer

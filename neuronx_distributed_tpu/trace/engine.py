@@ -36,6 +36,7 @@ export path.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import numbers
 import time
@@ -718,7 +719,28 @@ class ParallelInferenceModel(_ServingBase):
 
         self.paged_kernel = resolve_paged_kernel(
             paged_kernel, self._placement_platform())
+        # a routed model's paged programs also return their routing
+        # (models.llama.moe_layer_stats); it waits here, still on the
+        # device, for whoever reads the step's tokens to take it along
+        self._moe = getattr(mcfg, "num_experts", 1) > 1
+        self._moe_stats: collections.deque = collections.deque(maxlen=256)
+        self.moe_seq = 0      # paged programs of a routed model run so far
         self._build()
+
+    def take_moe_stats(self, upto: Optional[int] = None) -> list:
+        """One ``{"load": [L, E], "choice": [L, rows, K]}`` of device
+        arrays, with the ``"program"`` family that ran (``decode_pages``,
+        ``prefill_chunk_pages``, ...) and its ``"seq"`` (the value of
+        ``moe_seq`` when it was launched), for each paged program run since
+        the last call, oldest first; empty for a dense model.  ``upto``
+        leaves the programs launched after that ``seq`` for a later call:
+        a reader that waits for one program's result takes what the device
+        has finished by then and nothing it would have to wait longer for."""
+        out = []
+        while self._moe_stats and (upto is None
+                                   or self._moe_stats[0]["seq"] <= upto):
+            out.append(self._moe_stats.popleft())
+        return out
 
     def _placement_platform(self) -> str:
         """Platform of the devices this wrapper's programs are placed on."""
@@ -1019,15 +1041,22 @@ class ParallelInferenceModel(_ServingBase):
             extra["adapters"] = self._gather_adapters(apool, atables)
         if paged_kernel:
             extra["paged_kernel"] = True
-        logits, caches = self.module.apply(
+        out = self.module.apply(
             params, toks, positions.astype(jnp.int32), caches, offsets,
-            kv_valid=valid, block_table=block_table, **extra,
+            kv_valid=valid, block_table=block_table,
+            mutable=["moe_stats"] if self._moe else False, **extra,
         )
+        (logits, caches), stats = out if self._moe else (out, None)
         if last_only and last_row is not None:
             logits = jax.lax.dynamic_index_in_dim(
                 logits, last_row, axis=1, keepdims=False)
         elif last_only:
             logits = logits[:, -1, :]
+        if self._moe:
+            from neuronx_distributed_tpu.models.llama import moe_layer_stats
+
+            return logits, caches, valid, moe_layer_stats(
+                stats, self.num_layers)
         return logits, caches, valid
 
     def _paged_phase(self, toks, offsets, block_table, caches, valid,
@@ -1066,15 +1095,20 @@ class ParallelInferenceModel(_ServingBase):
                 _ft.partial(self._paged_step_fn, paged_kernel=pk,
                             update_valid=update_valid, last_only=last_only),
                 donate_argnums=(4,),
-                out_shardings=(None, self._pool_out_shardings(caches), vout))
+                out_shardings=(None, self._pool_out_shardings(caches), vout)
+                + ((None,) if self._moe else ()))
             fn = self._serving_cache.put(key, fn)
         args = (self.params, toks, jnp.asarray(offsets, jnp.int32),
                 jnp.asarray(block_table, jnp.int32), caches, valid)
         if lora:
             args = args + (apool, jnp.asarray(atables, jnp.int32))
-        if last_row is not None:
-            return fn(*args, last_row=jnp.int32(last_row))
-        return fn(*args)
+        out = (fn(*args, last_row=jnp.int32(last_row))
+               if last_row is not None else fn(*args))
+        if self._moe:
+            self.moe_seq += 1
+            self._moe_stats.append({**out[3], "program": name,
+                                    "seq": self.moe_seq})
+        return out[:3]
 
     def decode_pages(self, tok, offsets, block_table, caches, valid,
                      paged_kernel=None):

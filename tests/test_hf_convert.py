@@ -21,6 +21,8 @@ from neuronx_distributed_tpu.convert import (  # noqa: E402
     gpt_neox_params_to_hf,
     llama_params_from_hf,
     llama_params_to_hf,
+    olmoe_params_from_hf,
+    olmoe_params_to_hf,
 )
 
 
@@ -278,3 +280,41 @@ def test_qwen2_bias_checkpoint_requires_flag(devices8):
                       dtype=jnp.float32, param_dtype=jnp.float32)
     with pytest.raises(ValueError, match="qkv_bias"):
         llama_params_from_hf(hf.state_dict(), cfg)
+
+
+def test_olmoe_logits_parity_and_roundtrip(devices8):
+    """OLMoE = the Llama layout + full-width q/k RMSNorm + a dropless
+    top-k-of-E expert block with gates NOT renormalised: HF
+    ``OlmoeForCausalLM`` logits through ``olmoe_params_from_hf``, and the
+    state dict back bit for bit."""
+    from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+
+    hf_cfg = transformers.OlmoeConfig(
+        vocab_size=128, hidden_size=64, intermediate_size=32,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+        max_position_embeddings=64, rms_norm_eps=1e-5, rope_theta=10000.0,
+        num_experts=8, num_experts_per_tok=3, norm_topk_prob=False,
+        tie_word_embeddings=False, attention_dropout=0.0, clip_qkv=None,
+    )
+    torch.manual_seed(0)
+    hf = transformers.OlmoeForCausalLM(hf_cfg).eval().float()
+    with torch.no_grad():  # norm weights of ones would hide a missing norm
+        for name, w in hf.named_parameters():
+            if name.endswith("norm.weight"):
+                w.add_(0.3 * torch.randn_like(w))
+    ids = torch.randint(0, 128, (2, 16))
+    with torch.no_grad():
+        want = hf(ids).logits.numpy()
+
+    nxd.initialize_model_parallel(tensor_parallel_size=1)
+    cfg = LlamaConfig.olmoe_1b_7b(
+        vocab_size=128, hidden_size=64, intermediate_size=32, num_layers=2,
+        num_heads=4, num_kv_heads=4, max_seq_len=64, num_experts=8,
+        moe_top_k=3, sequence_parallel=False, remat="none",
+        dtype=jnp.float32, param_dtype=jnp.float32)
+    params = jax.tree.map(jnp.asarray, olmoe_params_from_hf(hf.state_dict(), cfg))
+    model = LlamaForCausalLM(cfg)
+    got = jax.jit(lambda p, i: model.apply(p, i))(params, jnp.asarray(ids.numpy()))
+    _assert_logits_close(got, want)
+
+    _roundtrip(hf.state_dict(), olmoe_params_from_hf, olmoe_params_to_hf, cfg)

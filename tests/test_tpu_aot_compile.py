@@ -195,3 +195,54 @@ def test_sampler_keeps_its_sort_behind_a_conditional_for_v5e(topo):
     entry = re.search(r"^ENTRY %\S+ \([^\n]*\{\n(.*?)^\}", text,
                       re.S | re.M).group(1)
     assert " sort(" not in entry and " conditional(" in entry
+
+
+# -- OLMoE: a group of ONE query head, and the routed expert block ------------
+
+
+@pytest.mark.parametrize("S", [1, 512], ids=["decode", "chunk_s512"])
+def test_paged_kernel_compiles_at_a_group_of_one(topo, S):
+    """OLMoE-1B-7B's attention geometry — 16 query heads = 16 kv heads, so
+    ONE query row a kv head in a decode — at the serving cell's pool (16
+    slots x 64 pages of 16): the first model to ask this of the kernel."""
+    mesh = _mesh(topo)
+    B = 16 if S == 1 else 1
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P()))
+
+    pages = sds((1153, 16, 16, D), jnp.bfloat16)
+    text = _compiled_text(
+        lambda q, pool, bt, off, start: paged_attention(q, pool, bt, off,
+                                                        start),
+        sds((B, S, 16, D), jnp.bfloat16), (pages, pages),
+        sds((B, 64), jnp.int32), sds((B,), jnp.int32), sds((B,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_routed_expert_block_compiles_for_v5e_without_relaying_out_its_weights(topo):
+    """The dropless expert block at OLMoE-1B-7B's widths (64 experts x 1024,
+    8 a token, hidden 2048) on a decode's 16 rows: three megablox calls
+    (gate, up, down) and no temporary anywhere near an expert matrix —
+    stored FUSED as ``[E, H, 2, I]`` the same block copied 512 MiB out a
+    call on the v5e (PR 25)."""
+    from neuronx_distributed_tpu.parallel.moe import ExpertParallelMLP
+
+    mesh = _mesh(topo)
+    moe = ExpertParallelMLP(
+        num_experts=64, intermediate_size=1024, top_k=8, dispatch="dropless",
+        norm_topk_prob=False, fused_gate_up=False, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((16, 1, 2048), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P()))
+    from flax import linen as nn
+
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=NamedSharding(mesh, P())),
+        nn.unbox(jax.eval_shape(moe.init, jax.random.PRNGKey(0), x)))
+    compiled = jax.jit(lambda p, x: moe.apply(p, x)[0]).lower(
+        params, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
