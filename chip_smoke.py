@@ -13,8 +13,10 @@ window 4096 — only depth is cut, and the cut is printed):
 - **kernels** — the flash kernel (forward + backward, through
   ``ring_attention``; causal, and window 4096 at sequence 8192) against
   ``mha_reference``, and ``paged_attention`` (decode, verify, chunk; fp and
-  int8 pages; window on) against ``paged_attention_reference`` — compiled,
-  with the Mosaic call asserted in each program;
+  int8 pages; window on; then the three serving cells' head layouts — 7, 4
+  and 1 query heads a kv head — with two thirds of the slots parked and the
+  rest left-padded) against ``paged_attention_reference`` — compiled, with
+  the Mosaic call asserted in each program;
 - **train**   — ``initialize_model_parallel`` → ``training_config`` →
   ``initialize_parallel_model`` → ``initialize_parallel_optimizer`` →
   ``make_train_step``, driven by ``trainer.fit()`` with flash attention,
@@ -74,6 +76,10 @@ REAL = dict(
     flash_seq=2048, flash_window_seq=8192, flash_ref_heads=(8, 2),
     flash_tail=512,
     paged=dict(batch=8, page=16, pages_per_slot=512, num_pages=2048),
+    # (q heads, kv heads, slots, pages a slot, window) of the three serving
+    # cells' decode: Qwen2-7B chat, Mistral-7B docs, OLMoE-1B-7B backlog
+    paged_cells=((28, 4, 32, 128, None), (32, 8, 8, 512, WINDOW),
+                 (16, 16, 16, 64, None)),
     train=dict(layers=2, batch=2, seq=8192, steps=6, loss_chunk=512),
     serve=dict(layers=16, slots=8, context=6144, total=8192, page=16,
                chunk=512, new=64,
@@ -91,6 +97,7 @@ TINY = dict(
     flash_seq=128, flash_window_seq=256, flash_ref_heads=(4, 2),
     flash_tail=64,
     paged=dict(batch=4, page=8, pages_per_slot=16, num_pages=40),
+    paged_cells=((14, 2, 6, 16, None), (8, 2, 3, 16, 96), (4, 4, 6, 8, None)),
     train=dict(layers=2, batch=2, seq=128, steps=6, loss_chunk=64),
     serve=dict(layers=2, slots=4, context=64, total=96, page=8,
                chunk=16, new=4,
@@ -333,6 +340,48 @@ def phase_kernels(size, seed, on_tpu):
                 f"T {T} window {window}")
             check_close("out", out, ref, KERNEL_TOL)
             if np.any(np.asarray(out[-1], np.float32) != 0.0):
+                raise AssertionError("parked slot rows are not exact zeros")
+
+    # the serving cells' head layouts, as the engine presents them: two
+    # slots in three parked, the live ones behind a left pad, each holding
+    # pages of its own — every other page of the pool is NaN, so a walk
+    # that strays off a slot's band cannot pass
+    for nq_c, nkv_c, B, PP, win in size["paged_cells"]:
+        T = PP * page
+        live = np.arange(B) % 3 == 0
+        for S in (1, 5):
+            offs = np.where(live, rs.randint(T // 2, T - S, size=B), T)
+            starts = np.where(live, offs - rs.randint(1, T // 2, size=B), 0)
+            table = rs.permutation(np.arange(1, B * PP + 1)).reshape(B, PP)
+            held = np.zeros((B, PP), bool)
+            for b in np.flatnonzero(live):
+                lo = starts[b] if win is None else max(starts[b],
+                                                       offs[b] - win + 1)
+                held[b, lo // page:(offs[b] + S - 1) // page + 1] = True
+            kk = jax.random.split(jax.random.fold_in(keys[5], nq_c * 8 + S), 3)
+            clean = tuple(
+                jax.random.normal(k_, (B * PP + 1, nkv_c, page, D),
+                                  jnp.bfloat16) for k_ in kk[:2])
+            dead = np.setdiff1d(np.arange(B * PP + 1), table[held])
+            pool = tuple(c.at[dead].set(jnp.nan) for c in clean)
+            q = jax.random.normal(kk[2], (B, S, nq_c, D), jnp.bfloat16)
+            args = (jnp.asarray(np.where(held, table, 0), jnp.int32),
+                    jnp.asarray(offs, jnp.int32),
+                    jnp.asarray(starts, jnp.int32))
+            kern = compiled_with_kernel(
+                lambda q, pool, bt, off, start, win=win: paged_attention(
+                    q, pool, bt, off, start, window=win),
+                q, pool, *args, on_tpu=on_tpu)
+            out = kern(q, pool, *args)
+            with jax.default_matmul_precision("highest"):
+                ref = jax.jit(
+                    lambda q, pool, bt, off, start, win=win:
+                    paged_attention_reference(q, pool, bt, off, start,
+                                              window=win))(q, clean, *args)
+            log(f" paged S={S} cell layout {nq_c}q/{nkv_c}kv: B{B} "
+                f"({int(live.sum())} live) page {page} T {T} window {win}")
+            check_close("out", out, ref, KERNEL_TOL)
+            if np.any(np.asarray(out, np.float32)[~live] != 0.0):
                 raise AssertionError("parked slot rows are not exact zeros")
 
 

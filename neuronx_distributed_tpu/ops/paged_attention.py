@@ -1,59 +1,53 @@
-"""Block-table-native paged-attention decode kernel (pallas TPU).
+"""Block-table-native paged-attention kernel (pallas TPU): a walk over the
+pages a slot HOLDS.
 
 The gather decode path rematerializes every slot's whole page chain into a
 contiguous ``[B, T, NKV, D]`` view before the band-mask core attends over it
 (``models/llama.py`` "gather ck[block_table]") — an O(T) materialized copy
 per step that grows with context length and, under ``kv_quant="int8"``,
 dequantizes the *entire* history every step.  This kernel is the
-vLLM-PagedAttention / Flash-Decoding answer (Kwon et al. SOSP '23; Dao et
-al. 2023): walk the block table directly in device memory with an
-online-softmax reduction over page blocks, so decode-step bytes are the
-pages actually attended — flat in ``T`` at a fixed context — and int8 pages
-dequantize per page block *inside* the kernel.
+vLLM-PagedAttention answer (Kwon et al. SOSP '23): attend straight over the
+pool in device memory, so that a call's time and bytes follow the pages its
+slots attend — not the pages their block tables could hold.
 
-Design (in the style of the in-tree ``ops/flash_attention.py``):
+Design (the pattern of ``jax.experimental.pallas.ops.tpu.paged_attention``,
+with this pool's layout and the band, softcap and int8 it lacks):
 
-- one grid program per ``(slot, kv-head, split, page-block)``; the page
-  block covers ``block_pages`` logically-consecutive pages whose PHYSICAL
-  page ids come from the scalar-prefetched block table
-  (``pltpu.PrefetchScalarGridSpec`` — the index map reads the table, so the
-  pool is addressed in place, never gathered into a per-slot clone).  The
-  pool is HEAD-MAJOR ``[NP, NKV, page, D]`` (``kvcache.pool``): one page of
-  one kv head is a whole ``(page, D)`` trailing slab, which is the block
-  shape Mosaic accepts (the last two block dims must be tile-aligned or
-  span the array's);
-- online softmax ``(m, l, acc)`` carried in VMEM scratch across the
-  page-block grid dim, exactly like the flash forward;
+- the K and V pools stay in HBM (``memory_space=pl.ANY``); a grid program
+  is ONE slot and a block of its kv heads.  It reads its slot's offset and
+  first valid key from the scalar-prefetched vectors, works out the first
+  and last page of the band it attends (``[max(kv_start, offset - window +
+  1), offset + S - 1]``), and loops over THOSE pages only — a data-dependent
+  ``fori_loop``, never unrolled.  A parked slot (``offset >= T``) and an
+  empty band run no trip and write EXACT ZEROS; block-table entries outside
+  the band are never read, and neither are the pages they name;
+- one ``pltpu.make_async_copy`` a page moves ALL the program's kv heads:
+  the pool is HEAD-MAJOR ``[NP, NKV, page, D]`` (``kvcache.pool``), so the
+  heads of one page are contiguous (16-64 KiB a copy at the serving
+  geometries).  ``block_pages`` pages make one compute step; the copies of
+  step ``i + 1`` are in flight while step ``i`` is attended (two buffers,
+  DMA semaphores);
+- online softmax ``(m, l, acc)`` in VMEM scratch across the steps, exactly
+  like the flash forward, normalized in the kernel at the end;
 - GQA by q-head grouping: the ``G = NQ/NKV`` query heads of one kv head are
-  the kernel's query rows (``G * S`` rows per program — S > 1 is the
-  speculative verification chunk), so grouped queries cost no extra KV
-  traffic;
-- per-slot masking from the scalar-prefetched ``cache_offset`` (query row
-  ``s`` attends cache positions ``<= offset + s``) and ``kv_start`` (the
-  left-pad count — serving validity is a contiguous band, see
-  :func:`paged_attention`); a parked slot (``offset >= T``) produces
-  EXACT ZEROS;
-- Flash-Decoding split-K: ``split_k > 1`` partitions the page chain across
-  parallel grid programs, each emitting unnormalized ``(acc, m, l)``
-  partials that a tiny jnp epilogue merges by logsumexp weighting (the ring
-  attention combine) — the decode-latency lever when one slot's chain is
-  long but B * NKV underfills the chip;
+  that head's query rows (``G * S`` rows — S > 1 is a prefill chunk or the
+  speculative verification chunk), padded to a sublane tile in the wrapper,
+  so grouped queries cost no extra KV traffic.  The heads of a program are
+  the batch dim of its two matmuls;
 - int8 six-tuple pools dequantize IN-KERNEL: the slot's per-page fp32
-  ``(scale, zero)`` pairs are gathered through the block table (``[B, PP]``
-  floats — tiny) and ride as per-key-column rows of one small operand; the
-  affine code ``x = (q + 128) * scale + zero`` is constant over a page, so
-  it factors out of both matmuls and is applied to the ``[rows, keys]``
-  score/probability tiles — quantized serving reads 1 byte/element from
-  HBM and never materializes a dequantized history;
-- pages past a slot's last needed block keep addressing the slot's LAST
-  needed physical page (the index map clamps): consecutive grid steps with
-  an unchanged block index skip the re-fetch, so the tail of a short chain
-  in a long table costs (almost) no HBM traffic — the "attend in HBM, move
-  only the pages you read" contract the serve_bench rung gates on.
+  ``(scale, zero)`` pairs are gathered through the block table OUTSIDE
+  (``[B, PP]`` floats, zeroed outside the band) into per-key-column rows,
+  one small slab a step that rides the step's copies; the affine code ``x =
+  (q + 128) * scale + zero`` is constant over a page, so it factors out of
+  both matmuls and is applied to the ``[rows, keys]`` score/probability
+  tiles — quantized serving reads 1 byte/element from HBM and never
+  materializes a dequantized history.
 
-Block sizes consult a shape-keyed defaults table
-(:data:`SHAPE_DEFAULTS`, grown by ``tools/flash_autotune.py --paged``) the
-same way the flash kernel's 512x512 default is autotune-justified.
+What is left to choose is a rule on the shapes (:func:`walk_shape`): how
+many kv heads a program takes (all of them at a decode's or a verify's few
+rows, fewer at a prefill chunk's hundreds) and how many pages a step
+attends (up to four MXU tiles of keys, within a VMEM budget).
+``tools/flash_autotune.py --paged`` sweeps the second.
 """
 
 from __future__ import annotations
@@ -68,7 +62,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from neuronx_distributed_tpu.ops.flash_attention import (
-    _GRID_SEMANTICS,
     LANES,
     NEG_INF,
     _compiler_params,
@@ -78,40 +71,14 @@ from neuronx_distributed_tpu.ops.flash_attention import (
 # int8 affine code offset (kvcache.quant convention: x ~ (q + 128)*scale + zero)
 _INT8_OFFSET = 128.0
 
-# ---------------------------------------------------------------------------
-# shape-keyed kernel defaults (tools/flash_autotune.py --paged writes these)
-# ---------------------------------------------------------------------------
-
-# (page_size, pages_per_slot, num_kv_heads, head_dim, quant) ->
-#     (block_pages, split_k)
-# Committed from `flash_autotune --paged` sweeps; unlisted shapes fall back
-# to the heuristic in `lookup_defaults`.  The serving shapes here are the
-# serve_bench ladder (page 8/16, T in {512, 2k, 8k}) at the bench model's
-# kv geometry.
-SHAPE_DEFAULTS = {
-    # page, PP, NKV, D, quant  : bp, split_k
-    (16, 32, 12, 128, None): (8, 1),      # T=512 bench shape
-    (16, 128, 12, 128, None): (8, 2),     # T=2k
-    (16, 512, 12, 128, None): (8, 4),     # T=8k: long chains want split-K
-    (16, 512, 12, 128, "int8"): (8, 4),
-    (16, 128, 8, 128, None): (8, 2),      # llama3-8b kv8 geometry
-    (16, 512, 8, 128, None): (8, 4),
-}
-
-# (page_size, pages_per_slot, num_kv_heads, head_dim, quant, chunk_width) ->
-#     (block_pages, split_k)
-# Wide-chunk entries (S > 1): the in-kernel chunked-prefill and speculative
-# verify shapes, committed from `flash_autotune --paged --chunk-width S`
-# sweeps.  A wide chunk amortizes grid overhead across S query rows, so the
-# winning (bp, split_k) generally differs from the S = 1 decode entry at the
-# same pool geometry — wider blocks, less split-K.
-CHUNK_SHAPE_DEFAULTS = {
-    # page, PP, NKV, D, quant, S  : bp, split_k
-    (16, 128, 12, 128, None, 64): (16, 1),   # T=2k bench, 64-token chunks
-    (16, 512, 12, 128, None, 64): (16, 2),   # T=8k
-    (16, 512, 12, 128, "int8", 64): (16, 2),
-    (16, 128, 8, 128, None, 64): (16, 1),    # llama3-8b kv8 geometry
-}
+# keys of one MXU tile of scores, and the most tiles one compute step attends
+_TILE_KEYS = LANES
+_MAX_STEP_TILES = 4
+# VMEM one program may spend on its queries, their fp32 accumulator and one
+# tile of scores (what grows with the kv heads it takes); on a step's whole
+# score tile; and on its double-buffered page blocks
+_VMEM_BUDGET = 4 * 2 ** 20
+_SUBLANES = 8
 
 
 def resolve_paged_kernel(flag, platform: Optional[str] = None) -> bool:
@@ -138,34 +105,32 @@ def resolve_paged_kernel(flag, platform: Optional[str] = None) -> bool:
     return platform == "tpu"
 
 
-def lookup_defaults(page_size: int, pages_per_slot: int, num_kv_heads: int,
-                    head_dim: int, quant: Optional[str] = None,
-                    chunk_width: int = 1) -> Tuple[int, int]:
-    """``(block_pages, split_k)`` for the given paged-decode shape: the
-    autotuned table entry when one exists, else a heuristic — enough pages
-    per block to fill ~128 kv lanes (one MXU tile of scores), split-K only
-    once the chain is long enough that a single sequential walk leaves the
-    chip idle.  ``chunk_width > 1`` (prefill chunks, speculative verify)
-    consults :data:`CHUNK_SHAPE_DEFAULTS` first and falls back to the
-    decode entry at the same pool geometry."""
-    if chunk_width > 1:
-        ckey = (page_size, pages_per_slot, num_kv_heads, head_dim, quant,
-                chunk_width)
-        if ckey in CHUNK_SHAPE_DEFAULTS:
-            return CHUNK_SHAPE_DEFAULTS[ckey]
-    key = (page_size, pages_per_slot, num_kv_heads, head_dim, quant)
-    if key in SHAPE_DEFAULTS:
-        return SHAPE_DEFAULTS[key]
-    bp = max(1, min(pages_per_slot, LANES // max(page_size, 1)))
-    while pages_per_slot % bp:
-        bp -= 1
-    blocks = pages_per_slot // bp
-    split_k = 1
-    for cand in (4, 2):
-        if blocks >= 8 * cand and blocks % cand == 0:
-            split_k = cand
-            break
-    return bp, split_k
+def walk_shape(page_size: int, num_kv_heads: int, head_dim: int, rows: int,
+               pages_per_slot: int, q_itemsize: int = 2,
+               kv_itemsize: int = 2) -> Tuple[int, int]:
+    """``(kv heads a program, pages a step)`` of the walk, from shapes alone.
+
+    ``rows`` is the query rows of ONE kv head (``G * S``).  A program takes
+    the largest divisor of the (local) kv heads whose queries, fp32
+    accumulator and one MXU tile of scores fit :data:`_VMEM_BUDGET` — every
+    head at a decode's or a verify's few rows, so one copy a page feeds them
+    all; one head (or a few) at a prefill chunk's hundreds.  A step attends
+    as many MXU tiles of keys (:data:`_TILE_KEYS` each, at most
+    :data:`_MAX_STEP_TILES`: a longer step pays the per-step rescale of the
+    accumulator less often) as keep its score tile and its two
+    double-buffered K and V page blocks within the same budget each, never
+    more pages than the table has."""
+    rows = -(-rows // _SUBLANES) * _SUBLANES
+    per_head = rows * (head_dim * q_itemsize + head_dim * 4 + _TILE_KEYS * 4)
+    heads = num_kv_heads
+    while heads > 1 and (num_kv_heads % heads
+                         or heads * per_head > _VMEM_BUDGET):
+        heads -= 1
+    tile_pages = max(1, _TILE_KEYS // max(page_size, 1))
+    tile_bytes = tile_pages * page_size * max(
+        heads * rows * 4, 4 * heads * head_dim * kv_itemsize)
+    tiles = max(1, min(_MAX_STEP_TILES, _VMEM_BUDGET // tile_bytes))
+    return heads, min(tiles * tile_pages, pages_per_slot)
 
 
 # ---------------------------------------------------------------------------
@@ -173,67 +138,115 @@ def lookup_defaults(page_size: int, pages_per_slot: int, num_kv_heads: int,
 # ---------------------------------------------------------------------------
 
 
-def _concat_pages(refs, dtype):
-    """``bp`` single-page ``(1, 1, page, D)`` blocks -> one ``[bp*page, D]``
-    tile in ``dtype``.  Pages whose row count is not a whole sublane tile of
-    their storage dtype (bf16 packs 16 rows, int8 32) are widened to fp32
-    first, where every 8 rows are a tile, so the concatenation stays
+def _band_pages(off, start, chunk, page, kv_len, window):
+    """``(first page, last page, attends anything)`` of the band a slot's
+    chunk attends — ``[max(start, off - window + 1), off + chunk - 1]``; a
+    parked slot (``off >= T``) attends nothing.  The kernel (scalars) and
+    the int8 params' gather (vectors) share it."""
+    last_pos = jnp.minimum(off + (chunk - 1), kv_len - 1)
+    lo_pos = jnp.maximum(start, 0)
+    if window is not None:
+        # the lowest key any row sees is row 0's: off - window + 1
+        lo_pos = jnp.maximum(lo_pos, off - (window - 1))
+    live = jnp.logical_and(off < kv_len, lo_pos <= last_pos)
+    return jax.lax.div(lo_pos, page), jax.lax.div(last_pos, page), live
+
+
+def _keys_tile(buf, dtype):
+    """A step's ``[heads, bp, page, D]`` page block -> ``[heads, bp * page,
+    D]`` keys in ``dtype``.  Pages whose row count is not a whole sublane
+    tile of their storage dtype (bf16 packs 16 rows, int8 32) are widened to
+    fp32 first, where every 8 rows are a tile, so the merge stays
     tile-aligned for Mosaic."""
-    page = refs[0].shape[2]
-    packed_rows = 8 * (4 // jnp.dtype(refs[0].dtype).itemsize)
-    via = refs[0].dtype if page % packed_rows == 0 else jnp.float32
-    return jnp.concatenate(
-        [r[0, 0].astype(via) for r in refs], axis=0).astype(dtype)
+    heads, bp, page, d = buf.shape
+    packed_rows = _SUBLANES * (4 // jnp.dtype(buf.dtype).itemsize)
+    via = buf.dtype if page % packed_rows == 0 else jnp.float32
+    return buf.astype(via).reshape(heads, bp * page, d).astype(dtype)
 
 
-def _paged_kernel(bt_ref, off_ref, start_ref, q_ref, *rest,
-                  sm_scale, page, block_pages, num_blocks, kv_len,
-                  group, window, softcap, quantized):
-    """One (slot, kv-head, split, page-block) grid step.
+def _walk_kernel(bt_ref, off_ref, start_ref, q_ref, k_hbm, v_hbm, *rest,
+                 sm_scale, page, block_pages, kv_len, group, chunk, window,
+                 softcap, quantized):
+    """One (slot, kv-head block) program: the walk over the slot's band.
 
-    ``rest`` is ``[k_0..k_{bp-1}, v_0.., par?, acc, m, l, m_scr, l_scr,
-    acc_scr]`` — ``bp`` single-page K blocks, the matching V blocks,
-    optionally the int8 page params as four per-key-column rows (k scale,
-    k zero, v scale, v zero), the three unnormalized outputs, then the VMEM
-    scratch carried across the page-block dim."""
+    ``rest`` is ``[par_hbm?, o, k_buf, v_buf, par_buf?, sem, m_scr, l_scr,
+    acc_scr]`` — optionally the int8 page params as per-key-column rows in
+    HBM (one slab a step: k scale, k zero, v scale, v zero, padded to a
+    sublane tile), the output block, the two double-buffered page blocks ``[2, heads, bp, page,
+    D]``, the params' buffer, the DMA semaphores ``[2, 3]`` (buffer x K / V /
+    params) and the online-softmax scratch."""
     bp = block_pages
-    k_refs, v_refs, rest = rest[:bp], rest[bp:2 * bp], rest[2 * bp:]
-    par_ref = None
+    par_hbm = par_buf = None
     if quantized:
-        par_ref, rest = rest[0], rest[1:]
-    acc_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = rest
+        par_hbm, o_ref, k_buf, v_buf, par_buf, sem, m_scr, l_scr, acc_scr = rest
+    else:
+        o_ref, k_buf, v_buf, sem, m_scr, l_scr, acc_scr = rest
+    heads, rows = q_ref.shape[1], q_ref.shape[2]
+    num_pages_phys, nkv = k_hbm.shape[0], k_hbm.shape[1]
 
     b = pl.program_id(0)
-    sk = pl.program_id(2)
-    ki = pl.program_id(3)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
+    h0 = pl.program_id(1) * heads
     off = off_ref[b]
     start = start_ref[b]
-    rows = q_ref.shape[2]  # G * S query rows
-    # logical page-block index along the slot's chain, and its kv positions
-    blk = sk * num_blocks + ki
-    base_pos = blk * bp * page
-    # the chain's last position any query row may attend
-    last_pos = off + (rows // group) - 1
-    live = off < kv_len  # parked slots (offset >= T) contribute nothing
-    run = jnp.logical_and(live, base_pos <= last_pos)
-    if window is not None:
-        # with a sliding window, blocks entirely left of the band are dead:
-        # the lowest key any row sees is (off + s) - window + 1 >= off - w + 1
-        run = jnp.logical_and(run, base_pos + bp * page - 1 >= off - (window - 1))
+    first, last, live = _band_pages(off, start, chunk, page, kv_len, window)
+    # step i attends logical pages first + i * bp + [0, bp); those past
+    # ``last`` re-address ``last`` and are masked, never a page the slot
+    # does not hold
+    steps = jnp.where(live, jax.lax.div(last - first, bp) + 1, 0)
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0, 0]  # [rows, D], native dtype into the MXU
-        width = bp * page
-        k = _concat_pages(k_refs, q.dtype)
-        v = _concat_pages(v_refs, q.dtype)
+    def heads_of(pool, phys):
+        return pool.at[phys] if heads == nkv else pool.at[phys, pl.ds(h0, heads)]
+
+    def start_step(i, slot):
+        for j in range(bp):
+            p_log = jnp.minimum(first + i * bp + j, last)
+            phys = jnp.clip(bt_ref[b, p_log], 0, num_pages_phys - 1)
+            pltpu.make_async_copy(heads_of(k_hbm, phys), k_buf.at[slot, :, j],
+                                  sem.at[slot, 0]).start()
+            pltpu.make_async_copy(heads_of(v_hbm, phys), v_buf.at[slot, :, j],
+                                  sem.at[slot, 1]).start()
+        if quantized:
+            pltpu.make_async_copy(par_hbm.at[b, i], par_buf.at[slot],
+                                  sem.at[slot, 2]).start()
+
+    def wait_step(slot):
+        # a wait needs the copy's shape and semaphore, not its source
+        for j in range(bp):
+            pltpu.make_async_copy(heads_of(k_hbm, 0), k_buf.at[slot, :, j],
+                                  sem.at[slot, 0]).wait()
+            pltpu.make_async_copy(heads_of(v_hbm, 0), v_buf.at[slot, :, j],
+                                  sem.at[slot, 1]).wait()
+        if quantized:
+            pltpu.make_async_copy(par_hbm.at[0, 0], par_buf.at[slot],
+                                  sem.at[slot, 2]).wait()
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(steps > 0)
+    def _first():
+        start_step(0, 0)
+
+    width = bp * page
+    # the last position any row may attend; rows the wrapper padded on
+    # (r >= G * S) attend what the last real row does
+    last_pos = jnp.minimum(off + (chunk - 1), kv_len - 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
+    qpos = jnp.minimum(off + row // group, last_pos)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+
+    def step(i, carry):
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < steps)
+        def _next():
+            start_step(i + 1, 1 - slot)
+
+        wait_step(slot)
+        q = q_ref[0]  # [heads, rows, D], native dtype into the MXU
+        k = _keys_tile(k_buf[slot], q.dtype)
+        v = _keys_tile(v_buf[slot], q.dtype)
         if quantized:
             # x = (code + 128) * scale + zero with (scale, zero) constant
             # over a page: both matmuls run on the integer-valued codes
@@ -241,182 +254,151 @@ def _paged_kernel(bt_ref, off_ref, start_ref, q_ref, *rest,
             # tiles through the per-column param rows
             k = k + _INT8_OFFSET
             v = v + _INT8_OFFSET
-            par = par_ref[0, 0]  # [4, width] fp32
+            par = par_buf[slot]  # [8, width] fp32, rows 4.. are padding
             ks, kz, vs, vz = par[0:1], par[1:2], par[2:3], par[3:4]
-        # [rows, bp*page] fp32 scores
+        # [heads, rows, bp*page] fp32 scores, the heads as the batch dim
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
         if quantized:
             qsum = jnp.sum(q.astype(jnp.float32), axis=-1, keepdims=True)
             s = s * ks + qsum * kz
         s = s * sm_scale
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
-        qpos = off + jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0) // group
-        kpos = base_pos + jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+        kpos = (first + i * bp) * page + col
         mask = jnp.logical_and(kpos <= qpos, kpos >= start)
         if window is not None:
             mask = jnp.logical_and(mask, kpos > qpos - window)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+        m_prev = m_scr[:, :, :1]
+        l_prev = l_scr[:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        # fully-masked blocks must contribute nothing: exp(NEG_INF - NEG_INF)
+        # a fully-masked row must contribute nothing: exp(NEG_INF - NEG_INF)
         # is 1, so zero p wherever the mask killed the score
-        p = jnp.where(mask, p, 0.0)
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
             (p * vs if quantized else p).astype(v.dtype), v,
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )
+            (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
         if quantized:
             pv = pv + jnp.sum(p * vz, axis=-1, keepdims=True)
         acc_scr[...] = acc_scr[...] * alpha + pv
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        return carry
 
-    @pl.when(ki == num_blocks - 1)
-    def _finish():
-        # UNNORMALIZED partials out — the split-K epilogue merges them
-        acc_ref[0, 0, 0] = acc_scr[...]
-        m_ref[0, 0, 0] = m_scr[...]
-        l_ref[0, 0, 0] = l_scr[...]
+    jax.lax.fori_loop(0, steps, step, 0)
 
-
-def _page_index_maps(page, block_pages, num_blocks, kv_len, num_pages_phys,
-                     pages_per_slot, s_rows):
-    """Index maps for the ``bp`` single-page K/V operands: logical page
-    ``blk * bp + j`` of slot ``b``'s chain, clamped to the slot's LAST
-    needed page — tail grid steps then re-address an unchanged block, and
-    the pipeline skips the re-fetch (the DMA-skip half of flat-in-T)."""
-
-    def for_j(j):
-        def imap(b, h, sk, ki, bt_ref, off_ref, start_ref):
-            blk = sk * num_blocks + ki
-            p_log = blk * block_pages + j
-            # last logical page the slot actually needs: the chunk's final
-            # query row attends (and wrote) position offset + S - 1
-            # (clamped so a parked slot at off >= T stays in range)
-            last = jnp.minimum(off_ref[b] + s_rows - 1, kv_len - 1) // page
-            p_log = jnp.minimum(p_log, jnp.maximum(last, 0))
-            p_log = jnp.minimum(p_log, pages_per_slot - 1)
-            phys = bt_ref[b, p_log]
-            return jnp.minimum(phys, num_pages_phys - 1), h, 0, 0
-
-        return imap
-
-    return for_j
+    # no trip (a parked slot, an empty band) leaves l = 0: exact zeros
+    l_fin = l_scr[:, :, :1]
+    o_ref[0] = (acc_scr[...] / jnp.where(l_fin == 0.0, 1.0, l_fin)
+                ).astype(o_ref.dtype)
 
 
-def _page_param_rows(block_table, params, page, block_pages):
-    """The slot's int8 page params as per-key-column rows, one whole
-    ``(4, bp*page)`` trailing slab per page block: ``[B, PP/bp, 4,
-    bp*page]`` fp32 with rows (k scale, k zero, v scale, v zero).  Gathered
-    through the block table OUTSIDE the kernel — ``4 * B * T`` floats, next
-    to a pool of ``NP * page * NKV * D`` bytes."""
+def _page_param_rows(block_table, params, first, last, live, page,
+                     block_pages):
+    """The slots' int8 page params as per-key-column rows, one ``(8, bp *
+    page)`` slab a step of the walk: ``[B, steps, 8, bp * page]`` fp32 with
+    rows (k scale, k zero, v scale, v zero, four of padding), slot ``b``'s
+    step ``i`` holding its logical pages ``first[b] + i * bp + [0, bp)``.
+    Gathered through the block table OUTSIDE the kernel — ``B * T`` floats
+    a row, next to a pool of ``NP * page * NKV * D`` bytes — and zeroed
+    past the band: a table entry the slot does not attend may name any
+    page, or none."""
     B, PP = block_table.shape
+    nsteps = -(-PP // block_pages)
+    p_log = first[:, None] + jnp.arange(nsteps * block_pages,
+                                        dtype=jnp.int32)[None, :]
+    held = jnp.logical_and(live[:, None], p_log <= last[:, None])
+    phys = jnp.take_along_axis(block_table, jnp.minimum(p_log, PP - 1), axis=1)
     rows = jnp.stack(
-        [p.astype(jnp.float32)[block_table] for p in params], axis=1)
-    rows = jnp.repeat(rows, page, axis=2)  # [B, 4, T]
-    return rows.reshape(B, 4, PP // block_pages, block_pages * page
+        [jnp.where(held, p.astype(jnp.float32)[phys], 0.0) for p in params],
+        axis=1)  # [B, 4, steps * bp]
+    rows = jnp.pad(rows, ((0, 0), (0, _SUBLANES - len(params)), (0, 0)))
+    rows = jnp.repeat(rows, page, axis=2)
+    return rows.reshape(B, _SUBLANES, nsteps, block_pages * page
                         ).transpose(0, 2, 1, 3)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("sm_scale", "window", "softcap", "block_pages",
-                     "split_k", "interpret"),
+                     "interpret"),
 )
 def _paged_attention_impl(q, kv_pages, block_table, cache_offset, kv_start,
                           sm_scale=None, window=None, softcap=None,
-                          block_pages=None, split_k=None, interpret=None):
+                          block_pages=None, interpret=None):
     quantized = len(kv_pages) == 6
     k_pages, v_pages = kv_pages[:2]
     B, S, NQ, D = q.shape
-    NP_phys, NKV, page, _ = k_pages.shape
+    _, NKV, page, _ = k_pages.shape
     PP = block_table.shape[1]
     T = PP * page
     G = NQ // NKV
     rows = G * S
+    rows_p = -(-rows // _SUBLANES) * _SUBLANES
     scale = (D ** -0.5) if sm_scale is None else sm_scale
-    if block_pages is None or split_k is None:
-        d_bp, d_sk = lookup_defaults(page, PP, NKV, D,
-                                     "int8" if quantized else None,
-                                     chunk_width=S)
-        block_pages = d_bp if block_pages is None else block_pages
-        split_k = d_sk if split_k is None else split_k
-    bp = max(1, min(int(block_pages), PP))
-    while PP % bp:
-        bp -= 1
-    sk = max(1, min(int(split_k), PP // bp))
-    while (PP // bp) % sk:
-        sk -= 1
-    num_blocks = PP // bp // sk
+    heads, bp = walk_shape(page, NKV, D, rows, PP, q.dtype.itemsize,
+                           k_pages.dtype.itemsize)
+    if block_pages is not None:
+        bp = max(1, int(block_pages))
 
     # q rows grouped per kv head: [B, NKV, G*S, D] with row r -> s = r // G
-    # matching the dense core's reshape(B, S, NKV, G, D) head mapping
+    # matching the dense core's reshape(B, S, NKV, G, D) head mapping,
+    # padded to a sublane tile (the pad rows are dropped below)
     qg = q.reshape(B, S, NKV, G, D).transpose(0, 2, 1, 3, 4).reshape(
         B, NKV, rows, D)
+    if rows_p != rows:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows_p - rows), (0, 0)))
 
     bt = block_table.astype(jnp.int32)
     off = cache_offset.astype(jnp.int32)
     start = (jnp.zeros((B,), jnp.int32) if kv_start is None
              else kv_start.astype(jnp.int32))
 
-    imap_for = _page_index_maps(page, bp, num_blocks, T, NP_phys, PP, S)
-    kv_spec = lambda j: pl.BlockSpec((1, 1, page, D), imap_for(j))  # noqa: E731
-    in_specs = [pl.BlockSpec((1, 1, rows, D),
-                             lambda b, h, s_, ki, *_: (b, h, 0, 0))]
-    operands = [qg]
-    in_specs += [kv_spec(j) for j in range(bp)]
-    operands += [k_pages] * bp
-    in_specs += [kv_spec(j) for j in range(bp)]
-    operands += [v_pages] * bp
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, heads, rows_p, D),
+                             lambda b, h, *_: (b, h, 0, 0)),
+                any_space, any_space]
+    operands = [qg, k_pages, v_pages]
+    page_buf = pltpu.VMEM((2, heads, bp, page, D), k_pages.dtype)
+    scratch = [page_buf, page_buf]
     if quantized:
-        in_specs.append(pl.BlockSpec(
-            (1, 1, 4, bp * page),
-            lambda b, h, s_, ki, *_: (b, s_ * num_blocks + ki, 0, 0)))
-        operands.append(_page_param_rows(bt, kv_pages[2:], page, bp))
+        in_specs.append(any_space)
+        operands.append(_page_param_rows(
+            bt, kv_pages[2:], *_band_pages(off, start, S, page, T, window),
+            page, bp))
+        scratch.append(pltpu.VMEM((2, _SUBLANES, bp * page), jnp.float32))
+    scratch += [
+        pltpu.SemaphoreType.DMA((2, 3)),
+        pltpu.VMEM((heads, rows_p, LANES), jnp.float32),
+        pltpu.VMEM((heads, rows_p, LANES), jnp.float32),
+        pltpu.VMEM((heads, rows_p, D), jnp.float32),
+    ]
 
     kernel = functools.partial(
-        _paged_kernel, sm_scale=scale, page=page, block_pages=bp,
-        num_blocks=num_blocks, kv_len=T, group=G, window=window,
-        softcap=softcap, quantized=quantized)
+        _walk_kernel, sm_scale=scale, page=page, block_pages=bp, kv_len=T,
+        group=G, chunk=S, window=window, softcap=softcap, quantized=quantized)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, NKV, sk, num_blocks),
+        grid=(B, NKV // heads),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, rows, D),
-                         lambda b, h, s_, ki, *_: (b, h, s_, 0, 0)),
-            pl.BlockSpec((1, 1, 1, rows, LANES),
-                         lambda b, h, s_, ki, *_: (b, h, s_, 0, 0)),
-            pl.BlockSpec((1, 1, 1, rows, LANES),
-                         lambda b, h, s_, ki, *_: (b, h, s_, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((rows, LANES), jnp.float32),
-            pltpu.VMEM((rows, LANES), jnp.float32),
-            pltpu.VMEM((rows, D), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, heads, rows_p, D),
+                               lambda b, h, *_: (b, h, 0, 0)),
+        scratch_shapes=scratch,
     )
 
     def call(interp):
         return pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=[
-                jax.ShapeDtypeStruct((B, NKV, sk, rows, D), jnp.float32),
-                jax.ShapeDtypeStruct((B, NKV, sk, rows, LANES), jnp.float32),
-                jax.ShapeDtypeStruct((B, NKV, sk, rows, LANES), jnp.float32),
-            ],
-            compiler_params=_compiler_params(_GRID_SEMANTICS, interp),
+            out_shape=jax.ShapeDtypeStruct((B, NKV, rows_p, D), q.dtype),
+            compiler_params=_compiler_params(("parallel", "parallel"), interp),
             interpret=interp,
             # one query row a slot is the decode step, more is a prefill chunk:
             # the device trace tells them apart by this name
@@ -424,22 +406,9 @@ def _paged_attention_impl(q, kv_pages, block_table, cache_offset, kv_start,
                   else "paged_attention_chunk"),
         )
 
-    acc, m, l = run_kernel(call, interpret, bt, off, start, *operands)
-
-    # Flash-Decoding epilogue: merge the split partials by logsumexp weight.
-    # An empty split carries (m = NEG_INF, l = 0, acc = 0) and contributes
-    # nothing; a fully-parked slot ends with l* = 0 and emits exact zeros.
-    m = m[..., 0]  # [B, NKV, sk, rows]
-    l = l[..., 0]
-    m_star = jnp.max(m, axis=2, keepdims=True)
-    w = jnp.exp(m - m_star)
-    l_star = jnp.sum(l * w, axis=2)  # [B, NKV, rows]
-    o = jnp.sum(acc * w[..., None], axis=2)  # [B, NKV, rows, D]
-    safe_l = jnp.where(l_star == 0.0, 1.0, l_star)
-    o = o / safe_l[..., None]
-    out = o.reshape(B, NKV, S, G, D).transpose(0, 2, 1, 3, 4).reshape(
-        B, S, NQ, D)
-    return out.astype(q.dtype)
+    o = run_kernel(call, interpret, bt, off, start, *operands)
+    return o[:, :, :rows].reshape(B, NKV, S, G, D).transpose(
+        0, 2, 1, 3, 4).reshape(B, S, NQ, D)
 
 
 def paged_attention(
@@ -453,7 +422,6 @@ def paged_attention(
     window: Optional[int] = None,
     softcap: Optional[float] = None,
     block_pages: Optional[int] = None,
-    split_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Decode attention straight over the page pool.
@@ -475,15 +443,17 @@ def paged_attention(
 
     ``window``/``softcap``/``sm_scale`` mirror the flash kernel's knobs
     (Mistral SWA, Gemma-2 softcapping and decoupled scale), so every model
-    family on the LlamaAttention path is served.  ``block_pages``/
-    ``split_k`` default from :func:`lookup_defaults`; ``interpret`` auto
-    (compiled where the program lowers for a TPU, the pallas interpreter
-    elsewhere), matching ``ops.flash_attention``.  The compiled kernel
-    needs ``page`` to be a multiple of 8 (one fp32 sublane tile) and
-    ``D`` of 128; the interpreter takes any shape.
+    family on the LlamaAttention path is served.  How many kv heads a
+    program takes and how many pages a step attends follow from the shapes
+    (:func:`walk_shape`); ``block_pages`` overrides the second — the tests'
+    and the sweep's handle, not a model's.  ``interpret`` auto (compiled
+    where the program lowers for a TPU, the pallas interpreter elsewhere),
+    matching ``ops.flash_attention``.  The compiled kernel needs ``page``
+    to be a multiple of 8 (one fp32 sublane tile) and ``D`` of 128; the
+    interpreter takes any shape.
 
     On a live tp > 1 mesh the kernel runs under a ``shard_map`` over the
-    kv-head axis: heads shard naturally (each ``(slot, kv-head)`` grid
+    kv-head axis: heads shard naturally (each ``(slot, kv-head block)``
     program is independent), the pool's kv-head axis is already tp-sharded
     by ``kvcache.pool``, and the block table / offsets / per-page quant
     params are replicated — no collectives, the row-parallel output
@@ -500,7 +470,7 @@ def paged_attention(
         raise ValueError(
             f"q heads ({q.shape[2]}) must group over kv heads ({nkv})")
     kw = dict(sm_scale=sm_scale, window=window, softcap=softcap,
-              block_pages=block_pages, split_k=split_k, interpret=interpret)
+              block_pages=block_pages, interpret=interpret)
     wrap = _tp_shard_mapped(q.shape[2], nkv)
     if wrap is not None:
         if kv_start is None:

@@ -688,6 +688,10 @@ class ServingEngine:
             )
 
             self._paged_kernel = resolve_paged_kernel(paged_kernel)
+        # the model's sliding window, for the count of pages a decode walks
+        self._attn_window = getattr(
+            getattr(getattr(model, "module", None), "config", None),
+            "sliding_window", None)
         # bytes ONE gather-path step spends on the contiguous clone: k + v,
         # every layer, the full padded [B, T] view in the compute dtype
         # (an int8 pool dequantizes into the same-sized fp clone)
@@ -1223,6 +1227,7 @@ class ServingEngine:
                 active = [(slot, req) for slot, req in self.scheduler.active()
                           if req.state is RequestState.DECODE]
                 if active:
+                    self._count_paged_walk(active)
                     with phase("serve/dispatch", active=len(active),
                                ctx_tokens=self._attended_keys(active)):
                         if self._spec_k:
@@ -1239,6 +1244,7 @@ class ServingEngine:
             active = [(slot, req) for slot, req in self.scheduler.active()
                       if req.state is RequestState.DECODE]
             if active:
+                self._count_paged_walk(active)
                 if self._spec_k:
                     self._spec_dispatch(active)
                     self._finish_decode(self._spec_collect(), outputs)
@@ -1872,6 +1878,29 @@ class ServingEngine:
         paged kernel must read; the ``ctx_tokens`` of the dispatch span)."""
         return sum(int(self._offsets[slot]) - self.C + req.prompt_len
                    for slot, req in active)
+
+    def _count_paged_walk(self, active: list) -> None:
+        """What the traffic lets the paged kernel skip, from the host
+        offsets ``_attended_keys`` sums: the pages the coming decode's live
+        slots attend (``serving/paged_pages_walked_total`` — each slot's
+        band ``[max(pad, offset - window + 1), offset + rows - 1]`` in
+        pages; a hybrid model's global layers walk the whole band, this is
+        its windowed layers') beside the pages its block tables could hold
+        (``serving/paged_pages_tabled_total``: slots x pages a slot)."""
+        if not self._paged_kernel:
+            return
+        page = self._kv.page_size
+        rows = self._spec_k + 1 if self._spec_k else 1
+        walked = 0
+        for slot, req in active:
+            off = int(self._offsets[slot])
+            low = self.C - req.prompt_len
+            if self._attn_window is not None:
+                low = max(low, off - self._attn_window + 1)
+            walked += (off + rows - 1) // page - low // page + 1
+        self.registry.counter("serving/paged_pages_walked_total").inc(walked)
+        self.registry.counter("serving/paged_pages_tabled_total").inc(
+            self.B * self._kv.pages_per_slot)
 
     def _count_gather_step(self) -> None:
         """Account one gather-path paged step's ``[B, T]`` K/V

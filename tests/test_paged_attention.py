@@ -8,8 +8,10 @@ mode on CPU, like the flash-attention interpret tests):
   contiguous ``[B, T]`` view, band-mask, softmax) across fp and int8
   pools, GQA and MHA, parked slots, ragged per-slot offsets and left-pad
   starts, ``S = 1`` decode and ``S = k+1`` verify chunks, sliding windows
-  and softcaps (the Gemma-2 shape), and every (block_pages, split_k)
-  decomposition — the online-softmax/split-K machinery must be invisible;
+  and softcaps (the Gemma-2 shape), the edges of the walk over a slot's
+  band (a page's first and last row, one live key, bands that start
+  mid-page, every slot parked, pages a step that do not divide the band),
+  and dead pages and table entries that are never read;
 - ENGINE parity — the acceptance bar: ``ServingEngine`` outputs
   token-identical with ``paged_kernel=True`` vs ``False`` (greedy AND
   sampled, sync AND async, staggered arrivals + slot reuse) across
@@ -36,11 +38,10 @@ from conftest import sharded_params
 from neuronx_distributed_tpu.kvcache.quant import quantize_page
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from neuronx_distributed_tpu.ops.paged_attention import (
-    SHAPE_DEFAULTS,
-    lookup_defaults,
     paged_attention,
     paged_attention_reference,
     resolve_paged_kernel,
+    walk_shape,
 )
 from neuronx_distributed_tpu.parallel.mesh import initialize_model_parallel
 from neuronx_distributed_tpu.serving import Request, SamplingParams, ServingEngine
@@ -80,21 +81,93 @@ def test_kernel_matches_gather_math(quant, nq, nkv):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
-@pytest.mark.parametrize("bp,sk", [(1, 1), (1, 2), (2, 1), (2, 2), (4, 1),
-                                   (8, 1), (4, 2)])
-def test_kernel_block_split_decompositions_identical(bp, sk):
-    """Every (block_pages, split_k) decomposition of the chain — including
-    non-dividing requests the kernel must clamp — produces the same
-    attention up to fp tolerance (the online-softmax merge is exact)."""
+# the walk's edges: (offsets, kv_start, window, block_pages, nq, nkv) at
+# page 4 x 8 pages a slot (T = 32), three slots
+WALK_EDGES = {
+    # the band ends on a page's first row, and on a page's last row
+    "offset_at_page_first_and_last_row": ([8, 11, 20], None, None, None, 4, 2),
+    # one live key: offset 0, and a band that kv_start cuts to its last key
+    "one_live_key": ([0, 13, 5], [0, 13, 5], None, None, 4, 2),
+    # kv_start in the middle of a page: its page's first rows are masked
+    "band_starts_mid_page_from_kv_start": ([14, 27, 9], [6, 17, 1], None,
+                                           None, 4, 2),
+    # the window's left edge two pages in (and mid-page): pages 0-1 are dead
+    "band_starts_at_window_left_edge": ([21, 30, 18], None, 11, None, 4, 2),
+    # every slot parked: no trip anywhere, all zeros
+    "every_slot_parked": ([32, 40, 32], None, None, None, 4, 2),
+    # three pages a step over bands of 7, 2 and 5 pages
+    "pages_per_step_not_dividing_band": ([27, 7, 19], [0, 0, 2], None, 3, 4,
+                                         2),
+    # the serving cells' groups: 7 query heads a kv head (Qwen2), and 1
+    "group_of_7": ([9, 30, 17], [0, 5, 0], None, None, 14, 2),
+    "group_of_1": ([9, 30, 17], [0, 5, 0], None, None, 4, 4),
+}
+
+
+@pytest.mark.parametrize("edge", sorted(WALK_EDGES))
+def test_walk_edges_match_reference(edge):
+    """The walk over ``[first page, last page]`` of each slot's band against
+    the dense oracle, where a band begins or ends on a page's edge, holds one
+    key, or is empty; a parked slot comes back exact zeros."""
+    offs, starts, window, bp, nq, nkv = WALK_EDGES[edge]
     rs = np.random.RandomState(1)
-    B, S, NQ, NKV, D, page, PP, NP_ = 2, 1, 4, 2, 8, 4, 8, 40
-    q = jnp.asarray(rs.standard_normal((B, S, NQ, D)), jnp.float32)
-    pool = _rand_pool(rs, NP_, page, NKV, D)
+    B, S, D, page, PP, NP_ = 3, 1, 8, 4, 8, 40
+    T = PP * page
+    q = jnp.asarray(rs.standard_normal((B, S, nq, D)), jnp.float32)
+    pool = _rand_pool(rs, NP_, page, nkv, D)
     bt = jnp.asarray(rs.randint(1, NP_, size=(B, PP)), jnp.int32)
-    off = jnp.asarray([9, 30], jnp.int32)
-    ref = paged_attention_reference(q, pool, bt, off)
-    out = paged_attention(q, pool, bt, off, block_pages=bp, split_k=sk)
+    off = jnp.asarray(offs, jnp.int32)
+    start = None if starts is None else jnp.asarray(starts, jnp.int32)
+    ref = paged_attention_reference(q, pool, bt, off, start, window=window)
+    out = paged_attention(q, pool, bt, off, start, window=window,
+                          block_pages=bp)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+    for slot, o in enumerate(offs):
+        if o >= T:
+            assert np.all(np.asarray(out)[slot] == 0.0)
+
+
+@pytest.mark.parametrize("window", [None, 9])
+@pytest.mark.parametrize("quant", [None, "int8"])
+@pytest.mark.parametrize("S", [1, 3])
+def test_dead_pages_are_never_read(quant, window, S):
+    """Every physical page outside all live bands is NaN (an int8 pool: its
+    scale and zero are) and every block-table entry outside a slot's band
+    is out of range: the output is finite and equal to the oracle's over
+    the clean pool, so the kernel read neither."""
+    rs = np.random.RandomState(6)
+    B, NQ, NKV, D, page, PP, NP_ = 4, 4, 2, 8, 4, 8, 40
+    T = PP * page
+    q = jnp.asarray(rs.standard_normal((B, S, NQ, D)), jnp.float32)
+    pool = _rand_pool(rs, NP_, page, NKV, D, quant)
+    bt = rs.permutation(np.arange(1, NP_))[:B * PP].reshape(B, PP)
+    offs = np.asarray([5, 18, T, 29 - S], np.int32)      # slot 2 parked
+    starts = np.asarray([0, 6, 0, 13], np.int32)
+    ref = paged_attention_reference(
+        q, pool, jnp.asarray(bt, jnp.int32), jnp.asarray(offs),
+        jnp.asarray(starts), window=window)
+
+    held = np.zeros((B, PP), bool)
+    for b in range(B):
+        if offs[b] < T:
+            lo = starts[b] if window is None else max(
+                starts[b], offs[b] - window + 1)
+            held[b, lo // page:(offs[b] + S - 1) // page + 1] = True
+    dead_pages = np.setdiff1d(np.arange(NP_), bt[held])
+    assert len(dead_pages) > NP_ // 2
+    poison = lambda a: jnp.asarray(a).at[dead_pages].set(jnp.nan)  # noqa: E731
+    if quant == "int8":
+        poisoned = pool[:2] + tuple(poison(p) for p in pool[2:])
+    else:
+        poisoned = tuple(poison(p) for p in pool)
+    bad_table = jnp.asarray(np.where(held, bt, 2 ** 30), jnp.int32)
+
+    out = np.asarray(paged_attention(
+        q, poisoned, bad_table, jnp.asarray(offs), jnp.asarray(starts),
+        window=window))
+    assert np.all(np.isfinite(out))
+    np.testing.assert_allclose(out, np.asarray(ref), atol=1e-5)
+    assert np.all(out[2] == 0.0)
 
 
 def test_parked_slots_emit_exact_zeros():
@@ -162,17 +235,34 @@ def test_window_and_softcap_gemma2_shape():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
-def test_defaults_lookup_and_resolution():
-    """Table entries win; the heuristic fallback always divides the chain;
-    the auto flag resolves by the placement platform and explicit values
-    pass through."""
-    page, pp, nkv, d = 16, 512, 12, 128
-    assert lookup_defaults(page, pp, nkv, d, None) == SHAPE_DEFAULTS[
-        (page, pp, nkv, d, None)]
-    for args in [(4, 8, 2, 16, None), (16, 7, 8, 64, "int8"),
-                 (1, 1, 1, 8, None), (128, 64, 4, 128, None)]:
-        bp, sk = lookup_defaults(*args)
-        assert args[1] % bp == 0 and (args[1] // bp) % sk == 0
+# (cell, page, NKV, G, D, pages a slot) of the three serving cells
+CELL_GEOMETRIES = {
+    "qwen2-7b.serve-chat": (16, 4, 7, 128, 128),
+    "mistral-7b.serve-docs": (16, 8, 4, 128, 512),
+    "olmoe-1b-7b.serve-backlog": (16, 16, 1, 128, 64),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_GEOMETRIES))
+def test_walk_shape_rule_and_resolution(cell):
+    """How many kv heads a program takes and how many pages a step attends,
+    from shapes alone: every head at a decode's and a verify's few rows (one
+    copy a page feeds them all), fewer at the 512-row chunk, whose fp32
+    accumulator grows with them; up to four MXU tiles of keys a step, fewer
+    where the score tile (Qwen2's 3584-row chunk) or the page buffers
+    (OLMoE's 64 KiB pages) would pass the budget.  And the auto flag
+    resolves by the placement platform, explicit values pass through."""
+    page, nkv, g, d, pp = CELL_GEOMETRIES[cell]
+    decode_pages = {7: 32, 4: 32, 1: 16}[g]
+    for S in (1, 5):
+        assert walk_shape(page, nkv, d, g * S, pp) == (nkv, decode_pages)
+    assert walk_shape(page, nkv, d, g * 512, pp) == {
+        7: (1, 16), 4: (1, 32), 1: (4, 32)}[g]
+    # a table shorter than a step, pages wider than a tile, heads that a
+    # budget cannot split evenly: still a divisor, still at least one page
+    assert walk_shape(4, 2, 16, 1, 6) == (2, 6)
+    assert walk_shape(256, 3, 128, 4096, 64) == (1, 1)
+    assert walk_shape(16, 6, 128, 1024, 64, 4, 4)[0] in (1, 2, 3)
     assert resolve_paged_kernel(True) is True
     assert resolve_paged_kernel(False) is False
     # auto resolves against the platform the caller's programs are placed
@@ -424,6 +514,36 @@ def test_kernel_churn_leaks_zero_pages(llama_pool):
     assert engine.registry.snapshot().get(GATHER_BYTES, 0) == 0
 
 
+@pytest.mark.parametrize("async_decode", [True, False])
+def test_walk_counters_count_from_host_offsets(llama_pool, async_decode):
+    """``serving/paged_pages_walked_total`` / ``_tabled_total``: one request
+    of 6 prompt tokens in a row of C = 8 (2 pad keys), pages of 4, 3 slots x
+    4 pages a slot.  Decode i runs at offset 8 + i over keys [2, 8 + i]:
+    pages 0..2 while the offset is under 12, 0..3 after — host integers,
+    counted whether the kernel or the interpreter runs, never on the gather
+    path."""
+    cfg, pool = llama_pool
+
+    def run(pk):
+        engine = ServingEngine(pool, page_size=4, num_pages=16,
+                               async_decode=async_decode, paged_kernel=pk)
+        engine.submit(Request(request_id=0, prompt_ids=[3, 1, 4, 1, 5, 9],
+                              max_new_tokens=7))
+        [out] = engine.run_until_complete(max_steps=100)
+        assert len(out.token_ids) == 7
+        return engine.registry.snapshot()
+
+    snap = run(True)
+    walked = snap["serving/paged_pages_walked_total"]
+    tabled = snap["serving/paged_pages_tabled_total"]
+    decodes = int(tabled) // (3 * 4)
+    assert tabled == decodes * 3 * 4 and decodes >= 6
+    # the first token comes from the prefill; decode i is at offset 8 + i
+    assert walked == sum((8 + i) // 4 - 2 // 4 + 1 for i in range(decodes))
+    off = run(False)
+    assert "serving/paged_pages_walked_total" not in off
+
+
 def test_paged_kernel_requires_paged_mode(llama_pool):
     """paged_kernel=True without page_size/num_pages is a loud error — the
     kernel walks block tables."""
@@ -461,22 +581,27 @@ def test_serve_bench_paged_kernel_tiny_cli():
 
 @pytest.mark.slow
 def test_flash_autotune_paged_tiny_cli():
-    """`flash_autotune --paged --cpu --tiny` sweeps (block_pages, split_k)
-    and emits a defaults_entry in the SHAPE_DEFAULTS table format."""
-    proc = subprocess.run(
-        [sys.executable, "tools/flash_autotune.py", "--paged", "--cpu",
-         "--tiny"],
-        capture_output=True, text=True, timeout=900, cwd=".",
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [json.loads(ln) for ln in proc.stdout.splitlines()
-             if ln.strip().startswith("{")]
-    sweeps = [r for r in lines if "decode_ms" in r and "shape_key" in r]
-    entry = [r for r in lines if "defaults_entry" in r]
+    """`flash_autotune --paged --cpu --tiny` times the pages a step attends
+    and prints the fastest beside the shape rule's pick; `--walk` times a
+    call against the table's width and the live slots."""
+    def run(*extra):
+        proc = subprocess.run(
+            [sys.executable, "tools/flash_autotune.py", "--paged", "--cpu",
+             "--tiny", *extra],
+            capture_output=True, text=True, timeout=900, cwd=".",
+        )
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        return [json.loads(ln) for ln in proc.stdout.splitlines()
+                if ln.strip().startswith("{")]
+
+    lines = run()
+    sweeps = [r for r in lines if "call_us" in r and "block_pages" in r]
+    [last] = [r for r in lines if "best" in r]
     assert len(sweeps) >= 4
-    assert entry, "missing the defaults_entry line"
-    e = entry[0]["defaults_entry"]
-    key = tuple(e["key"][:4]) + (e["key"][4],)
-    page, pp = key[0], key[1]
-    assert pp % e["block_pages"] == 0
-    assert (pp // e["block_pages"]) % e["split_k"] == 0
+    assert last["best"]["block_pages"] in {r["block_pages"] for r in sweeps}
+    assert last["rule"] == {"kv_heads_per_program": 2, "block_pages": 8,
+                            "call_us": last["rule"]["call_us"]}
+    walk = run("--walk")
+    assert len({r["pages_per_slot"] for r in walk}) >= 3
+    assert len({r["live_slots"] for r in walk}) >= 2
+    assert all(r["walk"] and r["call_us"] > 0 for r in walk)

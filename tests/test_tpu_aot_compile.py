@@ -197,28 +197,46 @@ def test_sampler_keeps_its_sort_behind_a_conditional_for_v5e(topo):
     assert " sort(" not in entry and " conditional(" in entry
 
 
-# -- OLMoE: a group of ONE query head, and the routed expert block ------------
+# -- the three serving cells' paged geometries; OLMoE's routed expert block ---
+
+
+# cell: (slots, kv heads, group, pages a slot, pool pages, window); page 16
+CELL_PAGED = {
+    "qwen2-7b.serve-chat": (32, 4, 7, 128, 4353, None),
+    "mistral-7b.serve-docs": (8, 8, 4, 512, 4161, WINDOW),
+    # 16 query heads = 16 kv heads: ONE query row a kv head in a decode
+    "olmoe-1b-7b.serve-backlog": (16, 16, 1, 64, 1153, None),
+}
 
 
 @pytest.mark.parametrize("S", [1, 512], ids=["decode", "chunk_s512"])
-def test_paged_kernel_compiles_at_a_group_of_one(topo, S):
-    """OLMoE-1B-7B's attention geometry — 16 query heads = 16 kv heads, so
-    ONE query row a kv head in a decode — at the serving cell's pool (16
-    slots x 64 pages of 16): the first model to ask this of the kernel."""
+@pytest.mark.parametrize("cell", sorted(CELL_PAGED))
+def test_paged_kernel_compiles_at_the_cells_geometries(topo, cell, S):
+    """Each serving cell's decode call and its 512-row prefill chunk, at the
+    cell's pool: one Mosaic call, which takes the K and the V pool ONCE each
+    — whole, in HBM, for the walk's own copies — not a page of them per
+    operand."""
+    import re
+
+    B, nkv, group, pp, num_pages, window = CELL_PAGED[cell]
     mesh = _mesh(topo)
-    B = 16 if S == 1 else 1
+    B = B if S == 1 else 1
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype,
                                     sharding=NamedSharding(mesh, P()))
 
-    pages = sds((1153, 16, 16, D), jnp.bfloat16)
+    pages = sds((num_pages, nkv, 16, D), jnp.bfloat16)
     text = _compiled_text(
-        lambda q, pool, bt, off, start: paged_attention(q, pool, bt, off,
-                                                        start),
-        sds((B, S, 16, D), jnp.bfloat16), (pages, pages),
-        sds((B, 64), jnp.int32), sds((B,), jnp.int32), sds((B,), jnp.int32))
-    assert "tpu_custom_call" in text
+        lambda q, pool, bt, off, start: paged_attention(
+            q, pool, bt, off, start, window=window),
+        sds((B, S, nkv * group, D), jnp.bfloat16), (pages, pages),
+        sds((B, pp), jnp.int32), sds((B,), jnp.int32), sds((B,), jnp.int32))
+    [call] = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    name = "paged_attention_decode" if S == 1 else "paged_attention_chunk"
+    assert f"%{name}" in call
+    [operands] = re.findall(r"operand_layout_constraints=\{(.*?)\}\}, ", call)
+    assert operands.count(f"bf16[{num_pages},{nkv},16,{D}]") == 2
 
 
 def test_routed_expert_block_compiles_for_v5e_without_relaying_out_its_weights(topo):

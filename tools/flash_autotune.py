@@ -7,21 +7,24 @@ compile orders of magnitude faster than the full train step, so a sweep is
 cheap in chip time, and its numbers justify (or refute) the
 512x512 default the models use (`ops/flash_attention.py` block_q/block_k).
 
-``--paged`` instead sweeps the paged-attention DECODE kernel
-(`ops/paged_attention.py`) across (block_pages, split_k) candidates for one
-(page, pages_per_slot, kv_heads, head_dim, quant) shape key and prints a
-``defaults_entry`` line in exactly the `SHAPE_DEFAULTS` table format the
-kernel consults — run it per serving shape on silicon and commit the
-winning entries.  With ``--chunk-width S`` (S > 1: in-kernel chunked
-prefill and speculative verify) the key grows a sixth element and the
-``defaults_entry`` targets the `CHUNK_SHAPE_DEFAULTS` table instead —
-wide chunks amortize grid overhead differently, so they get their own
-committed entries rather than reusing the S = 1 decode winner.
+``--paged`` instead times the paged-attention kernel
+(`ops/paged_attention.py`) at one serving shape with every live slot at
+``--live-len`` keys, across what is left to choose: the pages one compute
+step attends (``block_pages``; the kernel's own choice is a rule on the
+shapes, ``walk_shape``, printed beside the fastest).  ``--chunk-width S``
+(S > 1: a prefill chunk, the speculative verify) times the chunk form.
+``--paged --walk`` asks what a call's time follows: the table's width is
+swept at all slots live, then the live slots at one width — a walk over the
+pages the slots hold is flat in the first and falls with the second.  Each
+line gives ``call_us`` (host clock over chained calls, what surrounds the
+kernel included) and, on a TPU, ``kernel_us`` (the kernel's own events in a
+profiler trace).
 
 Usage:
     python tools/flash_autotune.py                 # flash bench shape, TPU
     python tools/flash_autotune.py --cpu --tiny    # flash smoke (interpret)
-    python tools/flash_autotune.py --paged         # paged decode sweep, TPU
+    python tools/flash_autotune.py --paged         # pages a step, TPU
+    python tools/flash_autotune.py --paged --walk  # table width / live slots
     python tools/flash_autotune.py --paged --cpu --tiny   # paged smoke
 """
 
@@ -69,97 +72,208 @@ def _time_fn(f, steps, *xs):
     return statistics.median(ts)
 
 
-def run_paged(args) -> int:
-    """Sweep (block_pages, split_k) for the paged decode kernel at one
-    serving shape key; print one JSON line per candidate plus the winning
-    ``defaults_entry`` in `ops.paged_attention.SHAPE_DEFAULTS` format."""
-    import jax
+def _paged_inputs(rs, B, NQ, NKV, D, page, PP, S, quant, dtype, live_len,
+                  live_slots, pad_len=0):
+    """One paged call's operands, as the serving engine presents them:
+    ``live_slots`` slots hold ``live_len`` keys before the chunk behind a
+    left pad of ``pad_len`` keys and a few more (a slot's own, so that bands
+    start anywhere in a page), each on pages of its own; the rest are
+    parked."""
     import jax.numpy as jnp
     import numpy as np
 
     from neuronx_distributed_tpu.kvcache.quant import quantize_page
-    from neuronx_distributed_tpu.ops.paged_attention import paged_attention
 
-    if args.tiny:
-        args.batch, args.heads, args.kv_heads = 4, 8, 2
-        args.head_dim, args.steps = 16, 2
-        args.page_size, args.pages_per_slot = 4, 8
-        args.num_pages = 64
-
-    B, NQ, NKV, D = args.batch, args.heads, args.kv_heads, args.head_dim
-    page, PP = args.page_size, args.pages_per_slot
-    S = args.chunk_width
-    NP_ = args.num_pages or (B * PP + 1)
-    quant = args.quant if args.quant != "none" else None
     T = PP * page
-
-    rs = np.random.RandomState(args.seed)
-    dtype = jnp.float32 if args.cpu else jnp.bfloat16
+    spread = 3 * np.arange(B) if pad_len else np.zeros(B, np.int64)
+    start = np.clip(pad_len + spread, 0, max(T - S - live_len, 0))
+    off = np.where(np.arange(B) < live_slots, start + live_len, T)
+    live_pages = -(-(live_len + S) // page) + 1
+    NP_ = B * live_pages + 1
     q = jnp.asarray(rs.randn(B, S, NQ, D), dtype)
     kp = jnp.asarray(rs.randn(NP_, NKV, page, D), dtype)
     vp = jnp.asarray(rs.randn(NP_, NKV, page, D), dtype)
+    pool = (kp, vp)
     if quant == "int8":
         qk, sk_, zk = quantize_page(kp)
         qv, sv, zv = quantize_page(vp)
         pool = (qk, qv, sk_, zk, sv, zv)
-    else:
-        pool = (kp, vp)
-    bt = jnp.asarray(rs.randint(1, NP_, size=(B, PP)), jnp.int32)
-    # decode at a full chain — the worst case the defaults must win at
-    off = jnp.full((B,), T - S, jnp.int32)
-    start = jnp.zeros((B,), jnp.int32)
+    table = np.zeros((B, PP), np.int32)
+    chains = 1 + rs.permutation(NP_ - 1).reshape(B, live_pages)
+    for b in range(B):
+        lo = start[b] // page
+        held = min(live_pages, PP - lo)
+        table[b, lo:lo + held] = chains[b, :held]
+    return (q, pool, jnp.asarray(table), jnp.asarray(off, jnp.int32),
+            jnp.asarray(start, jnp.int32))
 
-    def divisors(n, cands):
-        return [c for c in cands if c <= n and n % c == 0]
 
-    # decode attention cost at the swept shape (identical for every
-    # candidate — only the achieved time varies): QK^T + PV over the full
-    # chain per query row, and the kernel must stream every mapped page
-    kv_bytes = 1 if quant == "int8" else q.dtype.itemsize
-    dec_flops = 2 * 2 * B * S * NQ * T * D
-    dec_bytes = (B * PP * page * NKV * D * 2 * kv_bytes
+def _time_chain(paged_call, chain, steps, q, *rest):
+    """Host-clock seconds for ONE call: ``chain`` calls in one program, each
+    taking the previous one's output into its queries (times zero), so the
+    host's launch is paid once per ``chain`` calls.  What sits around the
+    kernel in a call (the query pad, the output slice) and the chain's own
+    add are in it: tens of microseconds."""
+    import jax
+
+    def many(q_, *xs):
+        out = paged_call(q_, *xs)
+        for _ in range(chain - 1):
+            out = paged_call(q_ + 0 * out, *xs)
+        return out
+
+    return _time_fn(jax.jit(many), steps, q, *rest) / chain
+
+
+def _kernel_us(paged_call, steps, *xs):
+    """Device-clock microseconds of the paged kernel ALONE: the median
+    duration of its events (named ``paged_attention_*``) in a profiler
+    trace of ``steps`` calls.  None where there is no TPU to trace."""
+    import glob
+    import statistics
+    import tempfile
+
+    import jax
+
+    if jax.devices()[0].platform != "tpu":
+        return None
+    fn = jax.jit(paged_call)
+    jax.block_until_ready(fn(*xs))
+    with tempfile.TemporaryDirectory() as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(steps):
+                out = fn(*xs)
+            jax.block_until_ready(out)
+        [path] = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                           recursive=True)
+        data = jax.profiler.ProfileData.from_file(path)
+    durations = [e.duration_ns
+                 for plane in data.planes
+                 if plane.name.startswith("/device:TPU:")
+                 for line in plane.lines if line.name == "XLA Ops"
+                 for e in line.events if "paged_attention" in e.name]
+    return (round(statistics.median(durations) / 1e3, 1) if durations
+            else None)
+
+
+def _paged_shape(args):
+    """``(B, NQ, NKV, D, page, S, quant, dtype)`` of a ``--paged`` run;
+    ``--tiny`` swaps in shapes the interpreter can carry."""
+    import jax.numpy as jnp
+
+    if args.tiny:
+        args.batch, args.heads, args.kv_heads = 4, 8, 2
+        args.head_dim, args.steps, args.chain = 16, 2, 2
+        args.page_size, args.pages_per_slot, args.live_len = 4, 8, 9
+    return (args.batch, args.heads, args.kv_heads, args.head_dim,
+            args.page_size, args.chunk_width,
+            args.quant if args.quant != "none" else None,
+            jnp.float32 if args.cpu else jnp.bfloat16)
+
+
+def run_paged(args) -> int:
+    """Sweep the pages a compute step attends (``block_pages``) for the
+    paged kernel at one serving shape, every live slot at ``--live-len``
+    keys; print one JSON line a candidate, then the fastest beside what the
+    kernel's shape rule (``ops.paged_attention.walk_shape``) picks."""
+    import jax
+    import numpy as np
+
+    from neuronx_distributed_tpu.ops.paged_attention import (
+        paged_attention,
+        walk_shape,
+    )
+
+    B, NQ, NKV, D, page, S, quant, dtype = _paged_shape(args)
+    PP = args.pages_per_slot
+    live = args.live_slots or B
+    xs = _paged_inputs(np.random.RandomState(args.seed), B, NQ, NKV, D, page,
+                       PP, S, quant, dtype, args.live_len, live, args.pad_len)
+    q, pool = xs[0], xs[1]
+    keys = live * min(args.live_len + S, args.window or PP * page)
+
+    # what the call must do at least: QK^T + PV over the keys its live
+    # slots attend, and those keys' pages (and its queries) through HBM once
+    flops = 2 * 2 * S * NQ * keys * D
+    hbm_bytes = (keys * NKV * D * 2 * pool[0].dtype.itemsize
                  + B * S * NQ * D * 2 * q.dtype.itemsize)
 
-    bps = divisors(PP, [1, 2, 4, 8, 16])
+    heads, rule_bp = walk_shape(page, NKV, D, (NQ // NKV) * S, PP,
+                                q.dtype.itemsize, pool[0].dtype.itemsize)
+    shape = {"page": page, "pages_per_slot": PP, "kv_heads": NKV,
+             "group": NQ // NKV, "head_dim": D, "quant": quant,
+             "chunk_width": S, "slots": B, "live_slots": live,
+             "live_len": args.live_len, "pad_len": args.pad_len,
+             "window": args.window}
     results = []
-    # S = 1 tunes the decode table; S > 1 (chunked prefill / spec verify)
-    # tunes the six-tuple CHUNK_SHAPE_DEFAULTS key at this pool geometry
-    key = [page, PP, NKV, D, quant] + ([S] if S > 1 else [])
-    table = "CHUNK_SHAPE_DEFAULTS" if S > 1 else "SHAPE_DEFAULTS"
-    for bp in bps:
-        for sk in divisors(PP // bp, [1, 2, 4, 8]):
-            fn = jax.jit(lambda q_, bp=bp, sk=sk: paged_attention(
-                q_, pool, bt, off, start, block_pages=bp, split_k=sk))
-            try:
-                t = _time_fn(fn, args.steps, q)
-            except Exception as e:  # noqa: BLE001 — report and keep sweeping
-                rec = {"shape_key": key, "block_pages": bp, "split_k": sk,
-                       "error": str(e)[:200]}
-                results.append(rec)
-                print(json.dumps(rec), flush=True)
-                continue
-            rec = {"shape_key": key, "block_pages": bp, "split_k": sk,
-                   "decode_ms": round(t * 1e3, 3),
-                   "pct_roofline": _pct_roofline(dec_flops, dec_bytes, t)}
+    for bp in [c for c in (1, 2, 4, 8, 16, 32, 64) if c <= PP]:
+        call = lambda q_, *r, bp=bp: paged_attention(  # noqa: E731
+            q_, *r, window=args.window, block_pages=bp)
+        try:
+            t = _time_chain(call, args.chain, args.steps, *xs)
+        except Exception as e:  # noqa: BLE001 — report and keep sweeping
+            rec = {"shape": shape, "block_pages": bp, "error": str(e)[:200]}
             results.append(rec)
             print(json.dumps(rec), flush=True)
+            continue
+        rec = {"shape": shape, "block_pages": bp,
+               "call_us": round(t * 1e6, 1),
+               "kernel_us": _kernel_us(call, args.steps, *xs),
+               "pct_roofline": _pct_roofline(flops, hbm_bytes, t)}
+        results.append(rec)
+        print(json.dumps(rec), flush=True)
 
     ok = [r for r in results if "error" not in r]
     if ok:
-        best = min(ok, key=lambda r: r["decode_ms"])
-        # the defaults-table entry to commit (ops/paged_attention.py)
+        best = min(ok, key=lambda r: r["call_us"])
         print(json.dumps({
-            "defaults_entry": {
-                "table": table,
-                "key": key,
-                "block_pages": best["block_pages"],
-                "split_k": best["split_k"],
-            },
-            "decode_ms": best["decode_ms"],
-            "pct_roofline": best["pct_roofline"],
+            "best": {"block_pages": best["block_pages"],
+                     "call_us": best["call_us"],
+                     "pct_roofline": best["pct_roofline"]},
+            "rule": {"kv_heads_per_program": heads, "block_pages": rule_bp,
+                     "call_us": next((r["call_us"] for r in ok
+                                      if r["block_pages"] == rule_bp), None)},
             "device": jax.devices()[0].device_kind,
         }), flush=True)
     return 0 if ok else 1
+
+
+def run_paged_walk(args) -> int:
+    """Does a call's time follow the pages its slots hold, or the pages its
+    table could hold?  At the shape given (default: the chat cell's decode —
+    32 slots, 28q / 4kv x 128, page 16), every live slot at ``--live-len``
+    keys: the table's width swept at all slots live, then the live slots
+    swept at ``--pages-per-slot``.  One JSON line a point."""
+    import jax
+    import numpy as np
+
+    from neuronx_distributed_tpu.ops.paged_attention import paged_attention
+
+    B, NQ, NKV, D, page, S, quant, dtype = _paged_shape(args)
+    pad = args.pad_len + 3 * B if args.pad_len else 0  # as _paged_inputs
+    need = -(-(pad + args.live_len + S) // page)
+    widths = sorted({w for w in (args.pages_per_slot // 4,
+                                 args.pages_per_slot // 2,
+                                 args.pages_per_slot,
+                                 args.pages_per_slot * 2) if w >= need})
+    slots = sorted({max(1, B // 8), max(1, (B * 13) // 32), B})
+    points = [(pp, B) for pp in widths] + [
+        (args.pages_per_slot, n) for n in slots if n != B]
+    call = lambda q_, pool, bt, off, start: paged_attention(  # noqa: E731
+        q_, pool, bt, off, start, window=args.window)
+    for pp, live in points:
+        xs = _paged_inputs(np.random.RandomState(args.seed), B, NQ, NKV, D,
+                           page, pp, S, quant, dtype, args.live_len, live,
+                           args.pad_len)
+        t = _time_chain(call, args.chain, args.steps, *xs)
+        print(json.dumps({
+            "walk": True, "pages_per_slot": pp, "live_slots": live,
+            "slots": B, "live_len": args.live_len, "pad_len": args.pad_len,
+            "chunk_width": S,
+            "call_us": round(t * 1e6, 1),
+            "kernel_us": _kernel_us(call, args.steps, *xs),
+            "device": jax.devices()[0].device_kind}), flush=True)
+    return 0
 
 
 def main() -> int:
@@ -174,19 +288,31 @@ def main() -> int:
     p.add_argument("--cpu", action="store_true")
     p.add_argument("--tiny", action="store_true", help="smoke shapes")
     p.add_argument("--paged", action="store_true",
-                   help="sweep the paged decode kernel (block_pages x "
-                        "split_k) instead of the flash fwd/bwd blocks")
+                   help="time the paged kernel across the pages a step "
+                        "attends instead of the flash fwd/bwd blocks")
     p.add_argument("--page-size", type=int, default=16,
                    help="paged mode: tokens per KV page")
     p.add_argument("--pages-per-slot", type=int, default=128,
                    help="paged mode: block-table width PP (T = PP * page)")
-    p.add_argument("--num-pages", type=int, default=None,
-                   help="paged mode: physical pool pages (default B*PP+1)")
+    p.add_argument("--live-slots", type=int, default=None,
+                   help="paged mode: slots not parked (default: all)")
     p.add_argument("--chunk-width", type=int, default=1,
                    help="paged mode: query rows S (1 = decode, k+1 = "
                         "speculative verify)")
     p.add_argument("--quant", default="none", choices=("none", "int8"),
                    help="paged mode: pool layout to tune")
+    p.add_argument("--walk", action="store_true",
+                   help="paged mode: time a call against the table's width "
+                        "and against the live slots, at one live length")
+    p.add_argument("--live-len", type=int, default=352,
+                   help="paged mode: keys a live slot holds before the chunk")
+    p.add_argument("--pad-len", type=int, default=0,
+                   help="paged mode: left pad before a live slot's keys (a "
+                        "few more a slot, so bands start anywhere in a page)")
+    p.add_argument("--window", type=int, default=None,
+                   help="paged mode: sliding window")
+    p.add_argument("--chain", type=int, default=16,
+                   help="paged mode: calls timed in one program")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args()
 
@@ -199,7 +325,7 @@ def main() -> int:
     from neuronx_distributed_tpu.ops.flash_attention import flash_attention
 
     if args.paged:
-        return run_paged(args)
+        return run_paged_walk(args) if args.walk else run_paged(args)
 
     if args.tiny:
         args.batch, args.heads, args.kv_heads = 1, 2, 2
