@@ -24,7 +24,7 @@ framework-native analogue of the reference's
   python examples/inference/runner.py serve --preset tiny --batch-size 3 \
       --context-len 16 --max-total-len 32 --num-requests 6 --rate 50
 
-  # batched speculative serving over paged KV (--draft equal to --preset
+  # batched speculative serving (--draft equal to --preset
   # is the draft == target control: acceptance 1.0, tokens/step ~ k+1)
   python examples/inference/runner.py serve --preset tiny --batch-size 3 \
       --context-len 16 --max-total-len 64 --page-size 8 \
@@ -212,39 +212,23 @@ def cmd_serve(args):
             print(json.dumps({"event": "token", "request_id": req.request_id,
                               "token": int(tok)}), flush=True)
 
-    paged_kw = {}
-    if args.paged_kernel != "auto" and not args.page_size:
-        raise SystemExit("--paged-kernel on|off needs --page-size: the "
-                         "kernel walks block tables")
-    if args.page_size:
-        # paged KV: pool HBM is num_pages * page_bytes instead of B * T.
-        # Default pool = the contiguous engine's footprint in pages PLUS the
-        # reserved NULL page, so plain `--page-size N` is a true drop-in
-        # (every workload the contiguous engine admits still fits) with
-        # prefix reuse on top; shrink --num-pages to trade HBM for
-        # admission backpressure.
-        num_pages = args.num_pages or (
-            args.batch_size * (args.max_total_len // args.page_size) + 1)
-        paged_kw = dict(page_size=args.page_size, num_pages=num_pages,
-                        paged_kernel={"auto": "auto", "on": True,
-                                      "off": False}[args.paged_kernel])
+    # the KV cache is a page pool: its HBM is num_pages * page_bytes, not
+    # B * T.  Left unset, --num-pages is the engine's own default — the pool
+    # in which every slot can hold --max-total-len — and a smaller pool
+    # trades HBM for admission backpressure.
+    paged_kw = dict(page_size=args.page_size, num_pages=args.num_pages,
+                    paged_kernel={"auto": "auto", "on": True,
+                                  "off": False}[args.paged_kernel])
     if args.kv_dtype == "int8":
         # int8 KV pages: same page count by default, half the HBM — or
         # shrink --num-pages less aggressively for ~2x the in-flight
         # requests at the fp pool's byte budget
-        if not args.page_size:
-            raise SystemExit("--kv-dtype int8 quantizes KV pages: pass "
-                             "--page-size")
         paged_kw["kv_quant"] = "int8"
     n_adapters = args.adapters or 0
     if n_adapters:
         # multi-tenant demo: N random rank-4 LoRA adapters registered on
         # every engine, requests round-robined across them (JSONL prompt
         # specs may instead pin one explicitly via "adapter_id")
-        if not args.page_size:
-            raise SystemExit("--adapters needs --page-size: adapter paging "
-                             "rides the paged engine")
-
         def make_store():
             import numpy as np
 
@@ -272,9 +256,6 @@ def cmd_serve(args):
         # per slot per step, the target verifies them in one batched chunk.
         # The draft preset shares the target's seed, so `--draft` equal to
         # `--preset` is the draft == target control (acceptance 1.0).
-        if not args.page_size:
-            raise SystemExit("--draft needs --page-size: speculative "
-                             "serving runs over the paged KV cache")
         _, _, _, draft = build_model(args, preset=args.draft)
         paged_kw.update(draft=draft, spec_k=args.spec_k)
     tracer = None
@@ -438,10 +419,9 @@ def cmd_serve(args):
             "failovers": int(snap.get("router/failovers_total", 0)),
             "affinity_hit_rate": (round(hits / (hits + misses), 4)
                                   if hits + misses else None),
+            "fleet_prefix_hit_rate": prefix["prefix_hit_rate"],
+            "prefills_skipped": prefix["prefills_skipped"],
         })
-        if args.page_size:
-            summary["fleet_prefix_hit_rate"] = prefix["prefix_hit_rate"]
-            summary["prefills_skipped"] = prefix["prefills_skipped"]
         if n_adapters:
             summary["adapters"] = n_adapters
         print(json.dumps(summary))
@@ -457,12 +437,11 @@ def cmd_serve(args):
         "wall_s": round(wall, 4),
         "tokens_per_s": (int(snap.get("serving/tokens_total", 0)) /
                          max(wall, 1e-9)),
+        "kv_pages_in_use": int(snap.get("kvcache/pages_in_use", 0)),
+        "prefix_hits": int(snap.get("kvcache/prefix_hits_total", 0)),
+        "prefills_skipped": int(
+            snap.get("kvcache/prefill_skipped_total", 0)),
     }
-    if args.page_size:
-        summary["kv_pages_in_use"] = int(snap.get("kvcache/pages_in_use", 0))
-        summary["prefix_hits"] = int(snap.get("kvcache/prefix_hits_total", 0))
-        summary["prefills_skipped"] = int(
-            snap.get("kvcache/prefill_skipped_total", 0))
     if args.kv_dtype == "int8":
         summary["quant_page_writes"] = int(
             snap.get("kvcache/quant_pages_total", 0))
@@ -573,35 +552,33 @@ def main():
                     help="serving_stats.jsonl output path")
     sp.add_argument("--quiet", action="store_true",
                     help="suppress per-token stream events")
-    sp.add_argument("--page-size", type=int, default=None,
-                    help="enable the paged KV cache with this page size in "
-                         "tokens (must divide --context-len and "
-                         "--max-total-len); repeated prompts then share "
-                         "prefix pages and skip prefill")
+    sp.add_argument("--page-size", type=int, default=8,
+                    help="tokens a page of the KV pool holds (must divide "
+                         "--context-len and --max-total-len); repeated "
+                         "prompts share prefix pages and skip prefill")
     sp.add_argument("--num-pages", type=int, default=None,
-                    help="paged KV pool size in pages (default: the "
-                         "contiguous engine's batch*total footprint + the "
+                    help="KV pool size in pages (default: every slot can "
+                         "hold --max-total-len, batch*total/page + the "
                          "reserved NULL page; smaller pools trade HBM for "
                          "admission backpressure)")
     sp.add_argument("--adapters", type=int, default=0,
                     help="multi-tenant demo: register this many random "
                          "rank-4 LoRA adapters and round-robin requests "
-                         "across them (JSONL specs may pin 'adapter_id'); "
-                         "needs --page-size")
+                         "across them (JSONL specs may pin 'adapter_id')")
     sp.add_argument("--kv-dtype", default="fp", choices=["fp", "int8"],
                     help="KV page dtype: int8 stores pages quantized with "
                          "per-page scale/zero (~2x pages per HBM byte at a "
-                         "bounded logit drift); needs --page-size")
+                         "bounded logit drift)")
     sp.add_argument("--paged-kernel", default="auto",
                     choices=["auto", "on", "off"],
                     help="block-table-native decode kernel "
-                         "(ops.paged_attention): auto = kernel on TPU at "
-                         "tp 1, gather path elsewhere; needs --page-size")
+                         "(ops.paged_attention): auto = kernel on TPU, "
+                         "gather path elsewhere")
     sp.add_argument("--draft", default=None,
                     help="enable speculative serving with this draft-model "
                          "preset (same family/seed as the target, so a "
                          "preset equal to --preset is the draft == target "
-                         "control); needs --page-size")
+                         "control)")
     sp.add_argument("--spec-k", type=int, default=4,
                     help="draft tokens proposed per slot per round "
                          "(speculative serving; requires --draft)")
@@ -631,10 +608,7 @@ def main():
     sp.add_argument("--routing", default="prefix_affinity",
                     choices=["round_robin", "random", "least_loaded",
                              "prefix_affinity"],
-                    help="fleet dispatch policy (with --replicas > 1); "
-                         "prefix_affinity needs --page-size to have "
-                         "fingerprints to steer by, else it degrades to "
-                         "least-loaded")
+                    help="fleet dispatch policy (with --replicas > 1)")
     sp.set_defaults(fn=cmd_serve)
 
     sp = sub.add_parser("spec-decode", help="speculative decoding: verify + time vs plain greedy")

@@ -34,6 +34,7 @@ boundary).
 
 import argparse
 import json
+import math
 import os
 import sys
 
@@ -135,8 +136,10 @@ def main():
         InferenceConfig(batch_size=args.serve_slots, context_len=P,
                         max_total_len=S, kv_cache_dtype=jnp.float32))
     ledger = CompileLedger()
+    # a page must divide the prompt and the row; the pool defaults to every
+    # slot holding a whole row
     engine = ServingEngine(infer, registry=MetricRegistry(),
-                           compile_ledger=ledger)
+                           page_size=math.gcd(P, M), compile_ledger=ledger)
     swapper = WeightSwapper(engine, path=args.swaps_out)
 
     rs = np.random.RandomState(args.seed)

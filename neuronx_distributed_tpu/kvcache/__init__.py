@@ -23,8 +23,9 @@ prefix sharing (Zheng et al., 2024) mapped onto static-shape JAX/pjit:
 The serving integration lives one layer up:
 ``serving.paged.PagedKVManager`` glues these onto the engine's slot table,
 ``trace.ParallelInferenceModel`` compiles the paged phase programs
-(``decode_pages`` / ``write_page`` / ``copy_page``), and ``models.llama``
-carries the block-table gather/scatter decode path.
+(``decode_pages`` / ``prefill_chunk_pages`` / ``copy_page``), and
+``models.llama`` carries the block-table scatter — the pool's one writer
+of K/V rows — and the gather decode path.
 """
 
 from neuronx_distributed_tpu.kvcache.allocator import (

@@ -104,7 +104,7 @@ class PagePool:
     initial value, not a persistent view).  The class is deliberately thin:
     page *accounting* lives in the host-side
     :class:`~.allocator.BlockAllocator`, device *programs* on the serving
-    wrapper (``decode_pages`` / ``write_page`` / ``copy_page``)."""
+    wrapper (``decode_pages`` / ``prefill_chunk_pages`` / ``copy_page``)."""
 
     def __init__(
         self,

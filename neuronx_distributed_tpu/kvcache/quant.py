@@ -7,8 +7,8 @@ scale + zero``), halving the HBM a page costs versus bf16 — the pool holds
 ~2x the pages at a fixed budget, and HBM (not compute) is what caps serving
 concurrency (PR 5's measured result).  The quantization granularity is the
 PAGE — the same unit the allocator refcounts — so quantize-on-write happens
-exactly where page writes already happen (``write_page`` prefill writes,
-the single-token decode scatter) and dequantize-in-the-gather reproduces
+exactly where page writes already happen (the block-table scatter of a
+prefill chunk, a decode token or a verify chunk) and dequantize-in-the-gather reproduces
 the same ``[B, T]`` view the band-mask attention core consumes, leaving
 the attention math untouched.
 
@@ -20,8 +20,8 @@ one).  Two exactness cases fall out of the affine form: an all-constant
 page round-trips exactly (``scale == 0``, ``zero`` carries the value — the
 zero decode tail never drifts), and so does any two-valued page.
 
-Pure jnp helpers, shared by the model's scatter/gather path and the
-serving wrapper's page-write programs; no engine state lives here.
+Pure jnp helpers for the model's scatter/gather path; no engine state
+lives here.
 """
 
 from __future__ import annotations

@@ -556,8 +556,10 @@ class LlamaAttention(nn.Module):
                         cv = cv.at[phys, :, in_off].set(
                             v.astype(cv.dtype), mode="drop")
             elif jnp.ndim(cache_offset) == 1:
-                # per-example write positions [B] (continuous batching: every
-                # slot decodes at its own offset).  Single-token steps only —
+                # per-example write positions [B] over a contiguous [B, T]
+                # cache: every slot decodes at its own offset.  One caller,
+                # the speculative DRAFT's decode_slots (the serving target
+                # takes the block-table path above).  Single-token steps only —
                 # a masked select over the time axis instead of a slice
                 # update; an out-of-range offset (>= T) writes nothing, which
                 # lets idle slots park harmlessly at T.

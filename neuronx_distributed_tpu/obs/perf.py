@@ -42,15 +42,15 @@ PERF_FAMILIES = ("prefill", "prefill_chunk", "decode_step", "spec_round",
                  "train_step")
 
 # compiled-program family -> phase family: the ledger books costs per
-# PROGRAM (``prefill_one``, ``write_page``, ...) while device time is
+# PROGRAM (``prefill_one``, ``insert_valid``, ...) while device time is
 # accounted per PHASE — this map is the join.  A phase executes several
-# programs (a paged prefill runs prefill_one once and write_page per
-# page), so phase flops are the sum over its programs of per-call cost x
-# executions (the _CompiledLRU feeds executions via note_program_call).
+# programs (an admission inserts a validity row, and under speculative
+# serving runs the draft's prefill_one and insert_slot), so phase flops are
+# the sum over its programs of per-call cost x executions (the
+# _CompiledLRU feeds executions via note_program_call).
 PHASE_PROGRAMS: Dict[str, Tuple[str, ...]] = {
     "prefill": ("prefill_one", "prefill_one_lora", "insert_slot",
-                "insert_valid", "write_page", "copy_page",
-                "write_adapter_page"),
+                "insert_valid", "copy_page", "write_adapter_page"),
     "prefill_chunk": ("prefill_chunk_pages",),
     "decode_step": ("decode_slots", "decode_pages", "decode_pages_lora",
                     "jit:sample_rows", "jit:pack_tokens"),
@@ -339,7 +339,7 @@ class PerfAttribution:
     def ingest_ledger(self, ledger: Any = None) -> int:
         """Join compile-ledger cost extras onto phase families.  Ledger
         rows carry costs per compiled PROGRAM (``prefill_one``,
-        ``write_page``, ...); a phase executes several programs, so per
+        ``insert_valid``, ...); a phase executes several programs, so per
         phase the total is the sum over its programs of per-call cost
         (mean across that program's compile rows — keys differ by shape)
         times executions counted by :meth:`note_program_call`.  Rebuilt

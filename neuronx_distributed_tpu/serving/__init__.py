@@ -1,8 +1,8 @@
 """Continuous-batching serving subsystem (ISSUE 2 tentpole).
 
-Iteration-level scheduling over the AOT decode executables: requests enter
-and leave the fixed-``B`` batch independently (per-slot KV offsets +
-slot-insert prefill), with per-request sampler params, rng streams, stop
+Iteration-level scheduling over the paged phase programs: requests enter
+and leave the fixed-``B`` batch independently (per-slot KV offsets, block
+tables over one page pool, chunked prefill), with per-request sampler params, rng streams, stop
 conditions, streaming callbacks, FCFS admission control, cancellation and
 deadlines — the serving layer the ROADMAP's "heavy traffic from millions of
 users" north star points at.
@@ -21,13 +21,13 @@ freed, co-batch untouched), ``max_queue`` bounds the admission backlog
 (``BackpressureError``), ``step_timeout_s`` arms a step watchdog, and an
 attached ``obs`` hub gives ``replay_trace`` a crash flight dump.
 
-Paged KV mode (kvcache PR): ``ServingEngine(page_size=, num_pages=)`` swaps
-the per-slot contiguous KV reservation for the :mod:`~..kvcache` page pool —
-:mod:`.paged`'s :class:`PagedKVManager` owns block tables, page budgeting,
-prefix-cache reuse, and terminal-state reclamation.
+The KV cache (kvcache PR): ``ServingEngine(page_size=, num_pages=)`` keeps
+K and V in the :mod:`~..kvcache` page pool, the engine's one KV
+representation — :mod:`.paged`'s :class:`PagedKVManager` owns block tables,
+page budgeting, prefix-cache reuse, and terminal-state reclamation.
 
-Speculative decoding (spec PR): ``ServingEngine(draft=, spec_k=)`` (paged
-mode only) turns every decode step into a batched per-slot draft-k-verify
+Speculative decoding (spec PR): ``ServingEngine(draft=, spec_k=)`` turns
+every decode step into a batched per-slot draft-k-verify
 round — multi-token commit through one target verification forward,
 rejected tails rolled back by page accounting, greedy output
 token-identical to the plain engine, sampled output exactly distributed as
@@ -49,9 +49,9 @@ across replicas by the fleet-global id, exported as schema-checked
 from ``serving_stats`` v5 via ``trace_id``.  Zero overhead when off.
 
 Stall-free SLO serving (SLO PR): ``ServingEngine(prefill_chunk_tokens=)``
-interleaves page-aligned prefill chunks with decode steps (Sarathi-style —
-long prompts stop stalling co-batched decodes, token-identical to
-whole-prefill), ``Request.priority`` + deadlines turn the scheduler into a
+sizes the page-aligned prefill chunks that interleave with decode steps
+(Sarathi-style — long prompts stop stalling co-batched decodes,
+token-identical whatever the width), ``Request.priority`` + deadlines turn the scheduler into a
 two-tier EDF with slot preemption and bounded-wait anti-starvation, and
 ``shed_infeasible=True`` sheds dead-on-arrival deadlines at admission with
 the distinct :class:`SLOInfeasible` signal.

@@ -1,7 +1,7 @@
 """Workload drive loop shared by every serving front end.
 
-One Poisson-arrival replay implementation serves the benchmarks
-(``tools/serve_bench.py``, ``tools/fleet_bench.py``), the demo CLI
+One Poisson-arrival replay implementation serves the fleet bench
+(``tools/fleet_bench.py``), the demo CLI
 (``examples/inference/runner.py serve``) and the tests — against EITHER a
 single :class:`~.engine.ServingEngine` or a
 :class:`~.fleet.FleetRouter` front door over N of them.  The target only

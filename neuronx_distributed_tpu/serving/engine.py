@@ -1,48 +1,58 @@
-"""Continuous-batching serving engine over the AOT decode executables.
+"""Continuous-batching serving engine over the paged phase programs.
 
 ``ServingEngine.step()`` is the iteration-level scheduling loop (Orca,
 OSDI '22): sweep cancellations/deadlines, admit queued requests into free
-slots (single-request prefill + KV slot-insert into the live donated
-caches), run ONE batched decode step with per-slot cache offsets, sample
-each slot from its own request's rng stream and sampler params, stream the
-tokens, and free the slots of finished requests — so requests enter and
-leave the batch independently instead of in lockstep, closing the
-utilization gap of the static ``generate`` batch (slots no longer idle
-until the longest request finishes).
+slots (block table + pages; the prompt's fresh pages are then computed by
+the chunk loop, one chunk program a step), run ONE batched decode step with
+per-slot cache offsets, sample each slot from its own request's rng stream
+and sampler params, stream the tokens, and free the slots of finished
+requests — so requests enter and leave the batch independently instead of
+in lockstep, closing the utilization gap of the static ``generate`` batch
+(slots no longer idle until the longest request finishes).
 
-The compiled-program contract: the engine owns the live batch state
-(``caches [B, T, ...]``, ``valid [B, T]``, per-slot offsets) and threads it
-through three phase executables on the serving wrapper —
-``prefill_one`` (the batched context fn at B=1, numerically identical to a
-solo prefill), ``insert_slot`` (donated batch-axis scatter), and
-``decode_slots`` (the per-slot-offset generalization of ``decode``).  Greedy
-outputs are token-identical to a solo ``generate`` of the same prompt: the
-per-row mask/position machinery reproduces the scalar-offset math row by
-row, and masked lanes contribute exactly zero probability.
+There is ONE path through the loop, the one every benchmark cell measures:
 
-Telemetry goes through the PR-1 ``obs.MetricRegistry`` (queue-depth /
+- **one KV representation** — a global page pool ``[NP, NKV, page, D]`` a
+  layer plus per-slot block tables (``kvcache/``, ``serving/paged.py``).
+  The pool's K/V rows have one writer, the block-table scatter inside the
+  paged phase programs (scope ``kv_write`` in ``models/llama.py``), and
+  ``copy_page`` for copy-on-write;
+- **one prefill** — every fresh prompt rides the chunk loop
+  (``prefill_chunk_pages``, at most ``prefill_chunk_tokens`` prompt tokens a
+  step; one chunk of ``context_len`` when the caller names no size).  An
+  exact repeated prompt skips compute: the prefix index hands back its
+  pages and its prefill logits;
+- **one decode loop, pipelined** — ``step()`` dispatches decode step N+1
+  *before* running step N's deferred host work (stream callbacks,
+  inter-token telemetry, stats serialization), and the whole per-step
+  device→host traffic — sampled tokens and per-slot finite flags — is
+  packed into ONE ``[2, B]`` array fetched with a single explicit
+  ``device_get`` per step (counted by the
+  :class:`~..obs.transfer_audit.TransferAudit`; host wait exported as
+  ``serving/host_blocked_ms``).  The host→device direction is symmetric:
+  the next-token feed, per-slot write offsets and token indices stage as
+  one packed explicit ``device_put``, and the per-slot sampling state (keys
+  / temperature / top-k / top-p) lives in device mirrors refreshed only
+  when admission changes them.  Stop *detection* stays pre-dispatch — it is
+  a few integer compares and the next step's active set depends on it — so
+  the pipeline never decodes speculatively for a finished slot.  A token's
+  stream callback fires after the next step's dispatch, and the final
+  token's callback sees its request already in a terminal state.
+
+The reference the loop is held to is the solo ``generate`` of the same
+weights: greedy outputs are token-identical to it (the per-row
+mask/position machinery reproduces the scalar-offset math row by row, and
+masked lanes contribute exactly zero probability), and sampled outputs
+equal ``generate(..., rng=rng, request_ids=[rid])``.
+
+The DRAFT model of speculative serving is the one user of the contiguous
+phase functions (``prefill_one`` / ``insert_slot`` / ``decode_slots`` over
+``empty_caches()``): its ``[B, T]`` row a slot makes rollback free.
+
+Telemetry goes through the ``obs.MetricRegistry`` (queue-depth /
 slot-occupancy gauges, TTFT and inter-token histograms, admission /
 finish / cancel counters) and per-request ``serving_stats.jsonl`` records
 validated by ``obs.schemas``.
-
-**The decode hot path is asynchronous** (``async_decode=True``, the
-default): ``step()`` dispatches decode step N+1 *before* running step N's
-deferred host work (stream callbacks, inter-token telemetry, stats
-serialization), and the whole per-step device→host traffic — sampled
-tokens and per-slot finite flags — is packed into ONE ``[2, B]`` array
-fetched with a single explicit ``device_get`` per step (counted by the
-:class:`~..obs.transfer_audit.TransferAudit`; host wait exported as
-``serving/host_blocked_ms``).  The host→device direction is symmetric: the
-next-token feed, per-slot write offsets and token indices stage as one
-packed explicit ``device_put``, and the per-slot sampling state (keys /
-temperature / top-k / top-p) lives in device mirrors refreshed only when
-admission changes them.  Stop *detection* stays pre-dispatch — it is a few
-integer compares and the next step's active set depends on it — so the
-pipeline never decodes speculatively for a finished slot and async outputs
-remain token-identical to the synchronous engine (parity-tested).  The one
-observable shift: a token's stream callback fires after the next step's
-dispatch, and the final token's callback sees its request already in a
-terminal state.
 """
 
 from __future__ import annotations
@@ -286,8 +296,7 @@ def _pack_tokens(toks, finite):
     """Pack the decode step's whole device→host payload into one ``[2, B]``
     int32 array so the engine pays exactly ONE host fetch per step.  A
     separate tiny jit (not fused into :func:`_sample_rows`) so the sampler
-    program stays bit-identical to the synchronous engine's — parity by
-    construction, not by hoping XLA fuses the same way."""
+    program is the same one the prefill's first token runs."""
     with jax.named_scope("pack_tokens"):
         return jnp.stack([toks.astype(jnp.int32), finite.astype(jnp.int32)])
 
@@ -330,10 +339,11 @@ def replay_trace(engine: "ServingEngine", arrivals, requests,
 class ServingEngine:
     """Continuous-batching engine over a :class:`~..trace.ParallelInferenceModel`.
 
-    ``model`` must expose the per-slot serving surface (``prefill_one`` /
-    ``insert_slot`` / ``decode_slots``) — ``ParallelInferenceModel`` does;
-    exported ``LoadedInferenceModel`` artifacts carry only the scalar-offset
-    context/decode pair and are rejected up front.
+    ``model`` must expose the paged serving surface (``make_page_pool`` /
+    ``prefill_chunk_pages`` / ``decode_pages`` / ``insert_valid``) —
+    ``ParallelInferenceModel`` does; exported ``LoadedInferenceModel``
+    artifacts carry only the scalar-offset context/decode pair and are
+    rejected up front.
 
     ``rng`` seeds the per-request sampling streams
     (``fold_in(fold_in(rng, request_id), token_index)`` — the same streams
@@ -363,34 +373,31 @@ class ServingEngine:
       ``replay_trace`` dumps it on an unhandled exception, and the engine's
       metrics then ride the hub's registry unless one was passed explicitly.
 
-    Async hot path (perf PR):
+    The decode loop is pipelined (see the module docstring): step N+1 is
+    dispatched before step N's stream callbacks / stats run, and all
+    per-step host↔device traffic packs into one explicit fetch + one
+    explicit put.  ``transfer_guard="forbid"`` wraps the steady decode
+    section in ``jax.transfer_guard("disallow")``: an implicit transfer in
+    the hot path raises instead of silently draining the device.  Fetch/put
+    counts and ``serving/host_blocked_ms`` export in every mode.
 
-    - ``async_decode`` (default True) pipelines the decode loop: step N+1
-      is dispatched before step N's stream callbacks / stats run, and all
-      per-step host↔device traffic packs into one explicit fetch + one
-      explicit put (see the module docstring).  ``False`` restores the
-      fully synchronous per-step engine (the parity reference);
-    - ``transfer_guard="forbid"`` wraps the steady decode section in
-      ``jax.transfer_guard("disallow")``: an implicit transfer in the hot
-      path raises instead of silently draining the device.  Fetch/put
-      counts and ``serving/host_blocked_ms`` export in every mode.
-
-    Paged KV mode (kvcache PR): ``page_size``/``num_pages`` replace the
-    contiguous ``[B, max_total_len]`` per-slot KV reservation with a global
-    page pool plus per-slot block tables — HBM is sized by ``num_pages``
-    (not ``B * T``), admission gates on *pages free*, every terminal state
-    reclaims its pages, and ``prefix_cache`` (default True) shares
-    page-aligned prompt prefixes across requests (an exact repeated prompt
-    skips prefill compute entirely).  Greedy paged decode is token-identical
-    to the contiguous engine (same band-mask attention over the gathered
-    page view — parity-tested); ``kvcache/*`` metrics (pool occupancy,
-    prefix hit/miss, evictions) export through the registry.
+    The KV cache is a global page pool plus per-slot block tables:
+    ``page_size`` (required; it must divide ``context_len`` and
+    ``max_total_len``) is the tokens a page holds and ``num_pages`` the
+    pool's size — HBM is sized by ``num_pages``, not ``B * T``.  Left
+    unset, ``num_pages`` is the pool in which every slot can hold
+    ``max_total_len`` (``B * T / page_size`` pages and the NULL page).
+    Admission gates on *pages free*, every terminal state reclaims its
+    pages, and ``prefix_cache`` (default True) shares page-aligned prompt
+    prefixes across requests (an exact repeated prompt skips prefill
+    compute entirely).  ``kvcache/*`` metrics (pool occupancy, prefix
+    hit/miss, evictions) export through the registry.
 
     Speculative decoding (spec PR): ``draft=`` (a second
     ``ParallelInferenceModel`` sharing the target's tokenizer and serving
     shapes) + ``spec_k=`` turn every decode step into a batched per-slot
     draft-k-verify round — the serving generalization of the solo
-    ``trace.speculative_generate``.  Paged mode only: accepted tokens
+    ``trace.speculative_generate``.  Accepted tokens
     scatter into block-table pages through the verify step itself, rejected
     tails roll back by host-side offset rewind against the worst-case
     ``spec_k``-token page reservation made at admission (no device copy),
@@ -401,7 +408,7 @@ class ServingEngine:
     land in ``serving_stats.jsonl`` and the ``serving/spec_*_total``
     counters (committed/rounds is the tokens-per-step headline).
 
-    Multi-tenant serving (tenancy PR; paged mode only):
+    Multi-tenant serving (tenancy PR):
 
     - ``adapter_store=`` (a :class:`~..tenancy.AdapterStore`) serves many
       LoRA adapters from ONE compiled envelope: ``Request.adapter_id``
@@ -420,18 +427,21 @@ class ServingEngine:
       bounded, parity-tested logit drift.  ``kvcache/quant_pages_total``
       counts quantized page writes.
 
-    Stall-free SLO serving (this PR; paged mode):
+    Stall-free SLO serving:
 
-    - ``prefill_chunk_tokens=N`` (a multiple of ``page_size``) turns long
-      prompts into Sarathi-style chunked prefills: at most ``N`` prompt
-      tokens are prefilled per engine step (page-aligned
-      ``prefill_chunk_pages`` scatters at the slot's offset), a PREFILLING
-      slot co-exists with decoding slots inside one ``step()``, and the
-      outputs stay token-identical to whole-prefill (prefix-cache hits
-      still skip resident chunks).  Co-batched decodes tick every step, so
-      inter-token latency no longer spikes with a neighbor's prompt
-      length.  Composes with ``spec_k`` (the draft row prefills whole at
-      admission), ``kv_quant`` (chunk writes quantize-on-scatter) and
+    - ``prefill_chunk_tokens=N`` (a multiple of ``page_size``; unset, one
+      chunk of ``context_len``) is the width of the ONE chunk program and
+      the prefill budget of a step: every fresh prompt is a
+      Sarathi-style chunked prefill, at most ``N`` prompt tokens per
+      engine step (page-aligned ``prefill_chunk_pages`` scatters at the
+      slot's offset; a shorter span is right-padded), a PREFILLING slot
+      co-exists with decoding slots inside one ``step()``, and the outputs
+      are token-identical whatever the width (prefix-cache hits still
+      skip resident chunks).  Co-batched decodes tick every step, so a
+      smaller ``N`` keeps inter-token latency from spiking with a
+      neighbor's prompt length.  Composes with ``spec_k`` (the draft row
+      prefills whole at admission), ``kv_quant`` (chunk writes
+      quantize-on-scatter) and
       ``adapter_store`` (chunks prefill under the request's adapter) —
       every pair is one parameterization of the same paged phase-fn
       family;
@@ -487,9 +497,8 @@ class ServingEngine:
         max_queue: Optional[int] = None,
         step_timeout_s: Optional[float] = None,
         obs: Any = None,
-        async_decode: bool = True,
         transfer_guard: str = "off",
-        page_size: Optional[int] = None,
+        page_size: int,
         num_pages: Optional[int] = None,
         prefix_cache: bool = True,
         draft: Any = None,
@@ -506,22 +515,18 @@ class ServingEngine:
         health: Any = None,
         perf: Any = None,
     ):
-        attrs = ("prefill_one", "insert_slot", "decode_slots")
-        if page_size is not None:
-            attrs += ("decode_pages", "write_page", "insert_valid",
-                      "make_page_pool")
-        if prefill_chunk_tokens is not None:
-            attrs += ("prefill_chunk_pages",)
+        attrs = ("make_page_pool", "prefill_chunk_pages", "decode_pages",
+                 "insert_valid")
         if spec_k:
             attrs += ("verify_pages",)
         if adapter_store is not None:
-            attrs += ("decode_pages_lora", "prefill_one_lora",
-                      "make_adapter_pool", "write_adapter_page")
+            attrs += ("decode_pages_lora", "make_adapter_pool",
+                      "write_adapter_page")
         for attr in attrs:
             if not hasattr(model, attr):
                 raise TypeError(
                     f"model {type(model).__name__} has no {attr!r}: the "
-                    "continuous-batching engine needs the per-slot serving "
+                    "continuous-batching engine needs the paged serving "
                     "surface of ParallelInferenceModel (exported artifacts "
                     "carry only the scalar-offset context/decode pair)")
         self.model = model
@@ -543,57 +548,24 @@ class ServingEngine:
         self._draft_model = draft
         # multi-tenant serving (tenancy/): per-request LoRA adapters paged
         # through the adapter store; int8 KV pages double the pool at a
-        # measured, bounded logit drift.  Both live on the paged machinery
-        # and compose with speculative decoding — the verify chunk is the
-        # same parameterized phase fn, adapter-aware and requantizing.
-        if adapter_store is not None and page_size is None:
+        # measured, bounded logit drift.  Both compose with speculative
+        # decoding — the verify chunk is the same parameterized phase fn,
+        # adapter-aware and requantizing.
+        if kv_quant not in (None, "int8"):
             raise ValueError(
-                "adapter_store needs the paged engine (page_size=/"
-                "num_pages=): adapter pages ride the same machinery as KV "
-                "pages")
-        if kv_quant is not None:
-            if kv_quant != "int8":
-                raise ValueError(
-                    f"kv_quant must be 'int8' or None, got {kv_quant!r}")
-            if page_size is None:
-                raise ValueError(
-                    "kv_quant quantizes KV pages: pass page_size=/"
-                    "num_pages= alongside it")
-        # paged chunked prefill (Sarathi-style stall-free batching): long
-        # prompts trickle into the page pool across steps — a PREFILLING
-        # slot co-exists with decoding slots, and the per-step token budget
-        # bounds how much prefill work any one step may do
-        if prefill_chunk_tokens is not None:
-            if page_size is None:
-                raise ValueError(
-                    "prefill_chunk_tokens needs the paged engine "
-                    "(page_size=/num_pages=): chunks write page-aligned "
-                    "block-table scatters")
-            if prefill_chunk_tokens < page_size \
-                    or prefill_chunk_tokens % page_size != 0:
-                raise ValueError(
-                    f"prefill_chunk_tokens ({prefill_chunk_tokens}) must be "
-                    f"a positive multiple of page_size ({page_size}) — "
-                    "chunks are page-aligned so cached prefix pages can be "
-                    "skipped whole")
-        self._chunk_tokens = prefill_chunk_tokens
-        self._chunking: dict = {}   # slot -> _ChunkPrefill in progress
-        self._chunk_rr = 0          # budget-rotation cursor (fairness)
+                f"kv_quant must be 'int8' or None, got {kv_quant!r}")
         self._adapters = adapter_store
         self._kv_quant = kv_quant
         if spec_k:
-            if page_size is None:
-                raise ValueError(
-                    "speculative serving runs over the paged KV cache "
-                    "(rejected tails roll back by page accounting): pass "
-                    "page_size=/num_pages= alongside draft=/spec_k=")
+            # the draft keeps a contiguous [B, T] row a slot (see
+            # _prefill_draft_row): the one user of these phase functions
             for attr in ("prefill_one", "insert_slot", "decode_slots",
                          "empty_caches"):
                 if not hasattr(draft, attr):
                     raise TypeError(
                         f"draft {type(draft).__name__} has no {attr!r}: the "
-                        "draft needs the same per-slot serving surface as "
-                        "the target")
+                        "draft needs the contiguous per-slot serving "
+                        "surface of ParallelInferenceModel")
             dcfg = draft.config
             for f in ("batch_size", "context_len", "max_total_len"):
                 if getattr(dcfg, f) != getattr(cfg, f):
@@ -649,39 +621,41 @@ class ServingEngine:
         # reads) and books any growth as a compile event.
         self._jit_sizes = (_module_jit_sizes()
                           if compile_ledger is not None else None)
-        # paged KV mode (kvcache/ subsystem): KV lives in a global page pool
-        # sized by `num_pages`, slots carry int32 block tables, admission
-        # gates on pages free, and repeated prompts share prefix pages
-        self._kv: Optional[PagedKVManager] = None
-        if page_size is None and num_pages is not None:
+        # the KV cache (kvcache/ subsystem): a global page pool sized by
+        # `num_pages` — left unset, the pool in which every slot can hold
+        # max_total_len, and the NULL page — slots carry int32 block tables,
+        # admission gates on pages free, repeated prompts share prefix pages
+        num_pages = (self.B * self.T // page_size + 1 if num_pages is None
+                     else num_pages)
+        self._kv = PagedKVManager(
+            num_slots=self.B, context_len=self.C, max_total_len=self.T,
+            page_size=page_size, num_pages=num_pages,
+            registry=self.registry, prefix_cache=prefix_cache,
+            spec_overshoot=self._spec_k)
+        # chunked prefill (Sarathi-style stall-free batching): a prompt's
+        # fresh pages trickle into the pool a chunk a step — a PREFILLING
+        # slot co-exists with decoding slots, and the chunk width bounds how
+        # much prefill work any one step may do.  Left unset, one chunk
+        # spans the context: the width a whole-prompt program compiles at
+        self._chunk_tokens = (self.C if prefill_chunk_tokens is None
+                              else prefill_chunk_tokens)
+        if self._chunk_tokens < page_size \
+                or self._chunk_tokens % page_size != 0:
             raise ValueError(
-                "num_pages without page_size: paged mode is keyed on "
-                "page_size — pass both, or neither for the contiguous "
-                "engine")
-        if page_size is not None:
-            if num_pages is None:
-                raise ValueError(
-                    "paged mode needs num_pages (the pool size; size it "
-                    "with kvcache.PagePool.pages_for_budget)")
-            self._kv = PagedKVManager(
-                num_slots=self.B, context_len=self.C, max_total_len=self.T,
-                page_size=page_size, num_pages=num_pages,
-                registry=self.registry, prefix_cache=prefix_cache,
-                spec_overshoot=self._spec_k)
+                f"prefill_chunk_tokens ({self._chunk_tokens}) must be a "
+                f"positive multiple of page_size ({page_size}) — chunks "
+                "are page-aligned so cached prefix pages can be skipped "
+                "whole")
+        self._chunking: dict = {}   # slot -> _ChunkPrefill in progress
+        self._chunk_rr = 0          # budget-rotation cursor (fairness)
         # block-table-native paged decode (ops.paged_attention): "auto"
         # follows the model wrapper's resolved default (kernel when its
         # programs run on a TPU, gather elsewhere); explicit True/False
         # overrides per engine.  Gather-path steps account their [B, T]
         # K/V rematerialization into kvcache/gather_bytes_total — the
         # counter the kernel path keeps at ZERO (the int8 acceptance gate).
-        if paged_kernel is True and self._kv is None:
-            raise ValueError(
-                "paged_kernel=True needs the paged engine (page_size=/"
-                "num_pages=): the kernel walks block tables")
         if paged_kernel in ("auto", None):
-            self._paged_kernel = (self._kv is not None
-                                  and bool(getattr(model, "paged_kernel",
-                                                   False)))
+            self._paged_kernel = bool(getattr(model, "paged_kernel", False))
         else:
             from neuronx_distributed_tpu.ops.paged_attention import (
                 resolve_paged_kernel,
@@ -751,7 +725,6 @@ class ServingEngine:
             raise ValueError(
                 f"transfer_guard must be 'off' or 'forbid', "
                 f"got {transfer_guard!r}")
-        self.async_decode = async_decode
         self._audit = TransferAudit(
             self.registry,
             mode="forbid" if transfer_guard == "forbid" else "observe")
@@ -787,27 +760,22 @@ class ServingEngine:
         self._stats_path = stats_path
         self._stats_f = None
 
-        # live device state: the batch as a resource pool — contiguous
-        # [B, T] rows, or the global page pool in paged mode (the paged
-        # pool's HBM is num_pages * page_bytes, decoupled from B * T)
-        self._page_bytes: Optional[int] = None
-        if self._kv is not None:
-            pool = model.make_page_pool(num_pages, page_size,
-                                        quant=self._kv_quant)
-            self.caches = pool.caches
-            # the pool's page_bytes-derived logical size: what the memory
-            # ledger accounts and what the fleet's headroom view is sized
-            # from (pages_free * page_bytes)
-            self._page_bytes = pool.page_bytes
-            logger.info(
-                "serving: paged KV pool: %d pages x %d tokens%s "
-                "(%.1f MiB; contiguous [B=%d, T=%d] would be %.1f MiB)",
-                num_pages, page_size,
-                f" ({self._kv_quant} quantized)" if self._kv_quant else "",
-                num_pages * pool.page_bytes / 2**20, self.B,
-                self.T, pool.page_bytes * self.B * self.T / page_size / 2**20)
-        else:
-            self.caches = model.empty_caches()
+        # live device state: the global page pool (its HBM is num_pages *
+        # page_bytes, decoupled from B * T) and the [B, T] key validity
+        pool = model.make_page_pool(num_pages, page_size,
+                                    quant=self._kv_quant)
+        self.caches = pool.caches
+        # the pool's page_bytes-derived logical size: what the memory
+        # ledger accounts and what the fleet's headroom view is sized
+        # from (pages_free * page_bytes)
+        self._page_bytes = pool.page_bytes
+        logger.info(
+            "serving: paged KV pool: %d pages x %d tokens%s "
+            "(%.1f MiB; [B=%d, T=%d] rows would be %.1f MiB)",
+            num_pages, page_size,
+            f" ({self._kv_quant} quantized)" if self._kv_quant else "",
+            num_pages * pool.page_bytes / 2**20, self.B,
+            self.T, pool.page_bytes * self.B * self.T / page_size / 2**20)
         self.valid = jnp.zeros((self.B, self.T), jnp.int32)
         # the draft's KV state stays CONTIGUOUS [B, T]: its rollback is free
         # (rejected slots sit past the rewound offset, index-based causal
@@ -880,14 +848,7 @@ class ServingEngine:
         ml = self.memory_ledger
         if ml is not None:
             ml.account_tree("params", model.params)
-            if self._kv is not None:
-                ml.set("kv_pool", num_pages * self._page_bytes)
-            else:
-                from neuronx_distributed_tpu.obs.memory_ledger import (
-                    tree_bytes,
-                )
-
-                ml.set("kv_cache", tree_bytes(self.caches))
+            ml.set("kv_pool", num_pages * self._page_bytes)
             if self._spec_k:
                 from neuronx_distributed_tpu.obs.memory_ledger import (
                     tree_bytes,
@@ -1021,10 +982,9 @@ class ServingEngine:
         if slot is not None:
             self._chunking.pop(slot, None)
             self._park_slot(slot)
-        if self._kv is not None:
-            # a parked victim being migrated drops its local resume pin:
-            # the destination resumes from the imported chain instead
-            self._kv.release_resume(req)
+        # a parked victim being migrated drops its local resume pin: the
+        # destination resumes from the imported chain instead
+        self._kv.release_resume(req)
         if self.tracer is not None:
             rt = self._rt.pop(request_id, None)
             if rt is not None:
@@ -1040,7 +1000,7 @@ class ServingEngine:
         of both KV migration and the fleet-global prefix cache.  Returns
         None when the index does not hold the chain (evicted since the
         directory last synced, or prefix caching off)."""
-        if self._kv is None or self._kv.index is None:
+        if self._kv.index is None:
             return None
         hit = self._kv.index.find_fingerprint(fingerprint)
         if hit is None:
@@ -1057,7 +1017,7 @@ class ServingEngine:
         chaos kill at ``kvcache/page_import``, leaks nothing).  Returns
         the number of pages actually copied in (0 = already fully cached
         here)."""
-        if self._kv is None or self._kv.index is None:
+        if self._kv.index is None:
             raise TransferError(
                 "engine has no prefix index; cannot import a chain")
         matched, _ = self._kv.index.peek(export.keys)
@@ -1113,14 +1073,13 @@ class ServingEngine:
             self.model = model = view
         model.params = params
         self.weights_version = int(version)
-        if self._kv is not None:
-            # cached prefix KV (and full-hit prefill logits) embody the
-            # OUTGOING params — a post-swap admission must never hit them,
-            # or old-version output leaks past the version boundary
-            dropped = self._kv.flush_prefix_cache()
-            if dropped:
-                logger.info("serving: weight swap flushed %d cached prefix "
-                            "chain node(s)", dropped)
+        # cached prefix KV (and full-hit prefill logits) embody the
+        # OUTGOING params — a post-swap admission must never hit them, or
+        # old-version output leaks past the version boundary
+        dropped = self._kv.flush_prefix_cache()
+        if dropped:
+            logger.info("serving: weight swap flushed %d cached prefix "
+                        "chain node(s)", dropped)
         ml = self.memory_ledger
         if ml is not None:
             # mem/params_bytes tracks the LIVE generation (the logical
@@ -1207,54 +1166,40 @@ class ServingEngine:
                 granted += 1
             span.set_metadata(granted=granted)
 
-        # 3b) chunked prefill: advance every PREFILLING slot by up to the
-        # per-step token budget (Sarathi-style — decodes below keep ticking
+        # 3b) prefill: advance the PREFILLING slots by the step's budget,
+        # one chunk program (Sarathi-style — decodes below keep ticking
         # every step while long prompts trickle in)
         if self._chunking:
             self._run_prefill_chunks(outputs)
 
-        # 4) decode: one single-token batched step, or — speculative mode —
-        # one draft-k-verify round committing up to k+1 tokens per slot
-        if self.async_decode:
-            # pipelined: collect the in-flight step's packed results (one
-            # explicit fetch + cheap stop detection), dispatch the next
-            # decode, THEN run the collected step's host-side work (stream
-            # callbacks, telemetry, stats) while the device computes
-            with self._audit.section("serving/decode"):
-                with phase("serve/collect"):
-                    post = (self._spec_collect() if self._spec_k
-                            else self._collect_decode())
-                active = [(slot, req) for slot, req in self.scheduler.active()
-                          if req.state is RequestState.DECODE]
-                if active:
-                    self._count_paged_walk(active)
-                    with phase("serve/dispatch", active=len(active),
-                               ctx_tokens=self._attended_keys(active)):
-                        if self._spec_k:
-                            self._spec_dispatch(active)
-                        else:
-                            self._dispatch_decode(active)
-            with phase("serve/finish", tokens=sum(
-                    len(p[3]) if p[0] == "tokens" else p[0] == "token"
-                    for p in post)):
-                self._finish_decode(post, outputs)
-        else:
-            # synchronous reference engine: one fully-processed decode per
-            # step (the async path is parity-tested against this)
+        # 4) decode, pipelined: one single-token batched step, or —
+        # speculative mode — one draft-k-verify round committing up to k+1
+        # tokens per slot.  Collect the in-flight step's packed results (one
+        # explicit fetch + cheap stop detection), dispatch the next decode,
+        # THEN run the collected step's host-side work (stream callbacks,
+        # telemetry, stats) while the device computes
+        with self._audit.section("serving/decode"):
+            with phase("serve/collect"):
+                post = (self._spec_collect() if self._spec_k
+                        else self._collect_decode())
             active = [(slot, req) for slot, req in self.scheduler.active()
                       if req.state is RequestState.DECODE]
             if active:
                 self._count_paged_walk(active)
-                if self._spec_k:
-                    self._spec_dispatch(active)
-                    self._finish_decode(self._spec_collect(), outputs)
-                else:
-                    self._decode_step(active, outputs)
+                with phase("serve/dispatch", active=len(active),
+                           ctx_tokens=self._attended_keys(active)):
+                    if self._spec_k:
+                        self._spec_dispatch(active)
+                    else:
+                        self._dispatch_decode(active)
+        with phase("serve/finish", tokens=sum(
+                len(p[3]) if p[0] == "tokens" else p[0] == "token"
+                for p in post)):
+            self._finish_decode(post, outputs)
 
         self.registry.gauge("serving/queue_depth").set(self.scheduler.queue_depth)
         self.registry.gauge("serving/slots_active").set(self.scheduler.active_count)
-        if self._kv is not None:
-            self._kv.export_gauges()
+        self._kv.export_gauges()
         if self._adapters is not None:
             self._adapters.export_gauges()
 
@@ -1384,38 +1329,29 @@ class ServingEngine:
         return rt.get("phase") if rt is not None else None
 
     def _prefill_into_slot(self, slot: int, req: Request, outputs: list) -> None:
-        """Single-request prefill, KV/validity slot-insert, first token.
-
-        Paged mode replaces the contiguous row insert with block-table
-        assembly: prefix-cache lookup (an exact full-prompt hit returns the
-        cached prefill logits and skips ``prefill_one`` entirely), atomic
-        page allocation, page-aligned writes of only the UNCACHED prompt
-        pages, and prefix-index registration.  A failure mid-admission
-        reclaims every page, fails the one request, and re-raises.
-
-        Chunked mode (``prefill_chunk_tokens``) stops after the block-table
-        assembly: the fresh prompt pages are computed by the per-step
-        budgeted chunk loop instead, and the request stays PREFILLING
-        across steps while decodes keep ticking."""
+        """Admit one granted request into its slot: the adapter pin, the
+        block table and its pages, the validity row, the draft's row.  Then
+        either the first token at once — an exact full-prompt prefix hit
+        hands back the cached prefill logits, no compute at all — or the
+        hand-off to the chunk loop, which computes the fresh prompt pages a
+        chunk a step while decodes keep ticking (the request stays
+        PREFILLING until its last chunk lands).  A failure mid-admission
+        reclaims every page and pin, fails the one request, and re-raises
+        unless it was a transient pool exhaustion."""
         now = self._clock()
+        # the prefill phase starts at the GRANT instant (where the queue /
+        # preempted span ended), so the trace phases tile without gaps
+        t_grant = req.prefill_time if req.prefill_time is not None else now
         # a preemption park ends at the grant: bank the parked wall time
         # (the serving_stats `preempted_ms` decomposition field)
         if req.parked_at is not None:
-            t_grant = (req.prefill_time if req.prefill_time is not None
-                       else now)
             req.preempted_ms += max(t_grant - req.parked_at, 0.0) * 1e3
             req.parked_at = None
-        # the prefill phase starts at the GRANT instant (where the queue /
-        # preempted span ended), so the trace phases tile without gaps
-        self._trace_begin_phase(
-            req, "prefill",
-            t=req.prefill_time if req.prefill_time is not None else now,
-            slot=slot)
+        self._trace_begin_phase(req, "prefill", t=t_grant, slot=slot)
         if self._perf is not None:
             # the same grant instant the span starts at — per-family sums
             # match the traced prefill wall-time exactly
-            self._perf_t0[req.request_id] = (
-                req.prefill_time if req.prefill_time is not None else now)
+            self._perf_t0[req.request_id] = t_grant
         # pre-dispatch expiry: the sweep ran at step start, but a request
         # can expire between sweep and prefill — never burn a prefill (or
         # its first chunk) on a deadline that is already dead
@@ -1424,179 +1360,133 @@ class ServingEngine:
             return
         self._slot_gen[slot] += 1  # a fresh occupancy generation begins
         L = req.prompt_len
-        ids = np.zeros((1, self.C), np.int32)
-        ids[0, self.C - L:] = req.prompt_ids  # LEFT-padded to the traced width
-        valid_np = (np.arange(self.C) >= self.C - L).astype(np.int32)
-        valid_ctx = jnp.asarray(valid_np)[None, :]
-        row_valid = jnp.concatenate(
-            [valid_ctx, jnp.zeros((1, self.T - self.C), jnp.int32)], axis=1)
-        prefilled_fresh = False  # paged: freshly prefilled chain to register
-        aid = getattr(req, "adapter_id", 0)
-        if aid:
-            # pin-at-admission: the adapter's pages are taken (and device-
-            # loaded on a cold start) BEFORE any KV allocation, so the KV
-            # failure path below has exactly one extra thing to undo.  A
-            # transient adapter-pool exhaustion fails THIS request cleanly
-            # (the engine keeps serving); injected faults re-raise after
-            # the same cleanup, like the KV path.
-            tr = self.tracer
-            aspan = (tr.begin("adapter_acquire", request_id=req.request_id,
-                              parent=self._trace_phase_of(req),
-                              t=self._clock(), adapter_id=aid)
-                     if tr is not None else None)
-            try:
-                loads = self._adapters.acquire(aid, engine_step=self._steps)
-                if aspan is not None:
-                    tr.end(aspan, t=self._clock(), loads=len(loads))
-            except BaseException as e:
-                now = self._clock()
-                if aspan is not None:
-                    tr.end(aspan, t=now, failed=type(e).__name__)
-                self._fail_slot_state(
-                    slot, req, now, reason=f"adapter:{type(e).__name__}")
-                logger.warning(
-                    "serving: request %d failed acquiring adapter %d (%s) — "
-                    "slot %d freed", req.request_id, aid, e, slot)
-                outputs.append(self._emit(req, now))
-                if isinstance(e, PoolExhausted):
-                    return
-                raise
-            for phys, block in loads:
-                self._adapter_pool = self.model.write_adapter_page(
-                    self._adapter_pool, block, phys)
-        if self._kv is not None:
-            try:
-                cached = self._kv.admit_slot(slot, req, ids[0], valid_np,
-                                             engine_step=self._steps)
-            except BaseException as e:
-                now = self._clock()
-                if aid:
-                    self._adapters.release(aid)  # undo the admission pin
-                self._fail_slot_state(slot, req, now,
-                                      reason=f"page_alloc:{type(e).__name__}")
-                logger.warning(
-                    "serving: request %d failed mid-page-allocation (%s) — "
-                    "every page reclaimed, slot %d freed", req.request_id,
-                    e, slot)
-                outputs.append(self._emit(req, now))
-                raise
-            # the slot's lookup references now cover the resumable chain a
-            # preemption park pinned (if any) — drop the park's pin so the
-            # accounting returns to the one-holder-per-chain norm
-            self._kv.release_resume(req)
-            # from here the slot owns the pin: every terminal path releases
-            # it through _release_adapter
-            if self._adapters is not None:
-                self._slot_adapter[slot] = aid
-                self._adapter_tables[slot] = self._adapters.table(aid)
-                self._adapter_dirty = True
-            fresh = (self._kv.fresh_pages(slot)
-                     if self._chunk_tokens is not None and cached is None
-                     else [])
-            if fresh:
-                # chunked prefill — EVERY fresh prefill rides the chunk
-                # path in chunked mode, not just long prompts: the whole
-                # ``prefill_one`` program is compiled at the full context
-                # width, so even a short prompt's admission stalls
-                # co-batched decodes for a full-width forward, while a
-                # chunk costs only its own span.  The block table is
-                # assembled and the fresh prompt pages reserved here; the
-                # compute is deferred to the per-step budgeted chunk loop
-                # (a span that fits the budget completes in this same
-                # step — same TTFT step count as the whole path).  Fresh
-                # pages are always one contiguous logical run (padding
-                # pages lead and ride the NULL page; the matched prefix is
-                # a leading chain), so chunks walk it left to right.
-                lps = [lp for lp, _ in fresh]
-                assert lps == list(range(lps[0], lps[0] + len(lps))), (
-                    f"fresh prompt pages not contiguous: {lps}")
-                self.valid = self.model.insert_valid(self.valid, row_valid,
-                                                     slot)
-                valid_full_np = np.concatenate(
-                    [valid_np, np.zeros((self.T - self.C,), np.int32)])
-                self._chunking[slot] = _ChunkPrefill(
-                    req, ids[0].copy(), valid_full_np, fresh)
-                # the prefill phase span stays OPEN across chunked steps;
-                # each chunk adds a child span under it
-                self._trace_phase_attrs(req, chunked=True,
-                                        fresh_pages=len(fresh))
-                if self._spec_k:
-                    # the draft's contiguous row prefills whole at
-                    # admission (spec × chunked-prefill): the draft is the
-                    # small model — its full-width forward is the cheap
-                    # half — and its row sits parked (offset = T) until
-                    # the target's final chunk lands
-                    if self._draft_lora and aid:
-                        _, drow_caches = self._draft_model.prefill_one_lora(
-                            jnp.asarray(ids), valid_ctx, self._adapter_pool,
-                            self._adapter_tables[slot][None, :])
-                    else:
-                        _, drow_caches = self._draft_model.prefill_one(
-                            jnp.asarray(ids), valid_ctx)
-                    self._draft_caches, self._draft_valid = \
-                        self._draft_model.insert_slot(
-                            self._draft_caches, drow_caches,
-                            self._draft_valid, row_valid, slot)
-                return
-            if cached is not None:
-                # exact full-prompt prefix hit: the chain's pages already
-                # hold this prompt's KV and the payload is the prefill's
-                # last-position logits — no prefill compute at all (keys
-                # are adapter-salted, so the cached KV/logits were computed
-                # under this same adapter)
-                self._trace_phase_attrs(req, prefix_hit=True)
-                logits = jnp.asarray(cached)
-            else:
-                if aid:
-                    logits, row_caches = self.model.prefill_one_lora(
-                        jnp.asarray(ids), valid_ctx, self._adapter_pool,
-                        self._adapter_tables[slot][None, :])
-                else:
-                    logits, row_caches = self.model.prefill_one(
-                        jnp.asarray(ids), valid_ctx)
-                logits = perturb("serving/prefill_logits", logits,
-                                 request_id=req.request_id,
-                                 engine_step=self._steps)
-                fresh = self._kv.fresh_pages(slot)
-                self._trace_phase_attrs(req, fresh_pages=len(fresh))
-                for lp, phys in fresh:
-                    self.caches = self.model.write_page(
-                        self.caches, row_caches, lp, phys,
-                        row_valid=valid_np)
-                if self._kv_quant is not None and fresh:
-                    self.registry.counter(QUANT_PAGES_TOTAL).inc(len(fresh))
-                # prefix-index registration waits for the finite-logits
-                # gate below: a poisoned prefill must fail ITS request
-                # only, never become a cached payload every future
-                # identical prompt replays
-                prefilled_fresh = True
-            self.valid = self.model.insert_valid(self.valid, row_valid, slot)
-        else:
-            logits, row_caches = self.model.prefill_one(
-                jnp.asarray(ids), valid_ctx)
-            logits = perturb("serving/prefill_logits", logits,
-                             request_id=req.request_id, engine_step=self._steps)
-            self.caches, self.valid = self.model.insert_slot(
-                self.caches, row_caches, self.valid, row_valid, slot)
-
+        ids = np.zeros((self.C,), np.int32)
+        ids[self.C - L:] = req.prompt_ids  # LEFT-padded to the traced width
+        valid = np.zeros((self.T,), np.int32)
+        valid[self.C - L:self.C] = 1       # the prompt's keys; decode adds its own
+        if not self._admit_adapter(slot, req, outputs):
+            return
+        cached = self._admit_pages(slot, req, ids, valid[:self.C], outputs)
+        self.valid = self.model.insert_valid(self.valid, valid[None, :], slot)
         if self._spec_k:
-            # the draft prefills the same prompt into its own contiguous
-            # slot row — it runs even on a target prefix-cache hit (the
-            # draft's KV is not paged/shared), and its row is simply
-            # overwritten at the next insert if this admission fails
-            if self._draft_lora and aid:
-                _, drow_caches = self._draft_model.prefill_one_lora(
-                    jnp.asarray(ids), valid_ctx, self._adapter_pool,
-                    self._adapter_tables[slot][None, :])
-            else:
-                _, drow_caches = self._draft_model.prefill_one(
-                    jnp.asarray(ids), valid_ctx)
-            self._draft_caches, self._draft_valid = \
-                self._draft_model.insert_slot(
-                    self._draft_caches, drow_caches, self._draft_valid,
-                    row_valid, slot)
+            self._prefill_draft_row(slot, req, ids, valid)
+        if cached is not None:
+            # exact full-prompt prefix hit: the chain's pages already hold
+            # this prompt's KV and the payload is the prefill's
+            # last-position logits (keys are adapter-salted, so both were
+            # computed under this same adapter)
+            self._trace_phase_attrs(req, prefix_hit=True)
+            self._finish_prefill(slot, req, jnp.asarray(cached), outputs,
+                                 prefilled_fresh=False)
+            return
+        # EVERY fresh prompt rides the chunk loop: the block table is
+        # assembled and the fresh pages reserved; the compute is the loop's,
+        # one chunk program a step (a span that fits the budget completes
+        # in this same step).  Fresh pages are always one contiguous
+        # logical run (padding pages lead and ride the NULL page; the
+        # matched prefix is a leading chain), so chunks walk it left to
+        # right.
+        fresh = self._kv.fresh_pages(slot)
+        lps = [lp for lp, _ in fresh]
+        assert lps == list(range(lps[0], lps[0] + len(lps))), (
+            f"fresh prompt pages not contiguous: {lps}")
+        self._chunking[slot] = _ChunkPrefill(req, ids, valid, fresh)
+        # the prefill phase span stays OPEN across chunked steps; each
+        # chunk adds a child span under it
+        self._trace_phase_attrs(req, chunked=True, fresh_pages=len(fresh))
 
-        self._finish_prefill(slot, req, logits, outputs, prefilled_fresh)
+    def _admit_adapter(self, slot: int, req: Request, outputs: list) -> bool:
+        """Pin the request's adapter at admission: its pages are taken (and
+        device-loaded on a cold start) BEFORE any KV allocation, so the KV
+        failure path has exactly one extra thing to undo.  False when the
+        request failed here (emitted, slot freed): a transient adapter-pool
+        exhaustion fails THIS request cleanly and the engine keeps serving;
+        anything else re-raises after the same cleanup."""
+        aid = req.adapter_id
+        if not aid:
+            return True
+        tr = self.tracer
+        aspan = (tr.begin("adapter_acquire", request_id=req.request_id,
+                          parent=self._trace_phase_of(req),
+                          t=self._clock(), adapter_id=aid)
+                 if tr is not None else None)
+        try:
+            loads = self._adapters.acquire(aid, engine_step=self._steps)
+            if aspan is not None:
+                tr.end(aspan, t=self._clock(), loads=len(loads))
+        except BaseException as e:
+            now = self._clock()
+            if aspan is not None:
+                tr.end(aspan, t=now, failed=type(e).__name__)
+            self._fail_slot_state(
+                slot, req, now, reason=f"adapter:{type(e).__name__}")
+            logger.warning(
+                "serving: request %d failed acquiring adapter %d (%s) — "
+                "slot %d freed", req.request_id, aid, e, slot)
+            outputs.append(self._emit(req, now))
+            if isinstance(e, PoolExhausted):
+                return False
+            raise
+        for phys, block in loads:
+            self._adapter_pool = self.model.write_adapter_page(
+                self._adapter_pool, block, phys)
+        return True
+
+    def _admit_pages(self, slot: int, req: Request, ids, valid_ctx,
+                     outputs: list):
+        """Build the slot's block table: prefix lookup, then every page the
+        request can need, atomically.  Returns the cached prefill logits on
+        an exact full-prompt hit, else None.  A failure reclaims every page
+        (and the admission's adapter pin), fails the one request, and
+        re-raises."""
+        aid = req.adapter_id
+        try:
+            cached = self._kv.admit_slot(slot, req, ids, valid_ctx,
+                                         engine_step=self._steps)
+        except BaseException as e:
+            now = self._clock()
+            if aid:
+                self._adapters.release(aid)  # undo the admission pin
+            self._fail_slot_state(slot, req, now,
+                                  reason=f"page_alloc:{type(e).__name__}")
+            logger.warning(
+                "serving: request %d failed mid-page-allocation (%s) — "
+                "every page reclaimed, slot %d freed", req.request_id,
+                e, slot)
+            outputs.append(self._emit(req, now))
+            raise
+        # the slot's lookup references now cover the resumable chain a
+        # preemption park pinned (if any) — drop the park's pin so the
+        # accounting returns to the one-holder-per-chain norm
+        self._kv.release_resume(req)
+        # from here the slot owns the adapter pin: every terminal path
+        # releases it through _release_adapter
+        if self._adapters is not None:
+            self._slot_adapter[slot] = aid
+            self._adapter_tables[slot] = self._adapters.table(aid)
+            self._adapter_dirty = True
+        return cached
+
+    def _prefill_draft_row(self, slot: int, req: Request, ids, valid) -> None:
+        """The DRAFT's prompt row (speculative serving).  The draft keeps a
+        contiguous ``[B, T]`` cache row a slot — a rejected tail rolls back
+        there for free — so its prompt prefills whole at admission
+        (``prefill_one``: the small model's full-width forward is the cheap
+        half) and lands by ``insert_slot``: the one caller of either.  It
+        runs even on a target prefix hit (the draft's KV is not shared),
+        sits parked (offset ``T``) until the target's last chunk lands, and
+        is simply overwritten by the next insert if this admission fails."""
+        ids_row = jnp.asarray(ids[None, :])
+        valid_ctx = jnp.asarray(valid[None, :self.C])
+        if self._draft_lora and req.adapter_id:
+            _, row_caches = self._draft_model.prefill_one_lora(
+                ids_row, valid_ctx, self._adapter_pool,
+                self._adapter_tables[slot][None, :])
+        else:
+            _, row_caches = self._draft_model.prefill_one(ids_row, valid_ctx)
+        self._draft_caches, self._draft_valid = self._draft_model.insert_slot(
+            self._draft_caches, row_caches, self._draft_valid,
+            valid[None, :], slot)
 
     def _set_sampling_state(self, slot: int, req: Request) -> None:
         """Write the slot's per-request sampler state (base key, temp,
@@ -1618,9 +1508,9 @@ class ServingEngine:
 
     def _finish_prefill(self, slot: int, req: Request, logits,
                         outputs: list, prefilled_fresh: bool) -> None:
-        """The prefill's first-token tail, shared by the whole-prefill path
-        and the chunk loop's final chunk: sample, finite-gate, register the
-        prefix chain, transition to DECODE, stream/emit."""
+        """The prefill's first-token tail, shared by the exact-prefix-hit
+        admission and the chunk loop's final chunk: sample, finite-gate,
+        register the prefix chain, transition to DECODE, stream/emit."""
         s = req.sampling
         self._set_sampling_state(slot, req)
         toks, finite = _sample_rows(
@@ -1686,7 +1576,7 @@ class ServingEngine:
         page-aligned prompt KV into the slot's reserved pages through
         ``prefill_chunk_pages``, and the FINAL chunk's last-position logits
         are the prefill logits the shared first-token tail samples from —
-        token-identical to a whole ``prefill_one``.  Every chunk runs the
+        token-identical whatever the chunk width.  Every chunk runs the
         ONE compiled program of the full budget width (a ragged tail is
         right-padded, see :meth:`_dispatch_chunk`), so a step dispatches
         ONE chunk, whatever that slot had left to prefill: prompt lengths
@@ -1815,7 +1705,6 @@ class ServingEngine:
             # the chunk's page-aligned writes each requantized their page
             self.registry.counter(QUANT_PAGES_TOTAL).inc(n_pages)
         if st.pages_remaining == 0:
-            # same fault point the whole-prefill path perturbs, applied to
             # the prefill logits the first token will sample from
             logits = perturb("serving/prefill_logits", logits,
                              request_id=st.req.request_id,
@@ -1840,15 +1729,13 @@ class ServingEngine:
             self.scheduler.requeue(req, now=now)  # frees slot, resets req
             req.parked_at = now
             st = self._chunking.pop(slot, None)
-            if self._kv is not None:
-                # pin the victim's COMMITTED leading chain before the
-                # slot's references drop: the re-grant then matches it in
-                # the prefix index and re-prefills only the uncommitted
-                # tail (a DECODE victim skips prefill entirely).  A
-                # mid-chunk victim's committed depth is its chunk progress.
-                self._kv.park_resume(
-                    slot, req,
-                    fresh_done=st.next_i if st is not None else None)
+            # pin the victim's COMMITTED leading chain before the slot's
+            # references drop: the re-grant then matches it in the prefix
+            # index and re-prefills only the uncommitted tail (a DECODE
+            # victim skips prefill entirely).  A mid-chunk victim's
+            # committed depth is its chunk progress.
+            self._kv.park_resume(
+                slot, req, fresh_done=st.next_i if st is not None else None)
             self._park_slot(slot)
             self.registry.counter("serving/preemptions_total").inc()
             logger.info(
@@ -1907,7 +1794,7 @@ class ServingEngine:
         rematerialization; the block-table-native kernel path never calls
         this, so ``kvcache/gather_bytes_total`` staying flat IS the
         "attend in HBM" evidence the report's kv-cache line shows."""
-        if self._kv is not None and not self._paged_kernel:
+        if not self._paged_kernel:
             self.registry.counter(GATHER_BYTES_TOTAL).inc(
                 self._gather_bytes_step)
 
@@ -1969,80 +1856,6 @@ class ServingEngine:
         path = SAMPLER_PATHS[int(_sampler_path(self._temps, self._topks,
                                                self._topps))]
         self.registry.counter(f"serving/sampler_steps_total/{path}").inc()
-
-    def _decode_step(self, active: list, outputs: list) -> None:
-        """One per-slot-offset decode over the whole batch; inactive slots
-        are parked at offset ``T`` (write nothing, logits ignored).  The
-        per-token sampling keys are derived INSIDE the jitted sampler from
-        the admission-time per-slot base keys — no per-slot host work here."""
-        tok_idx = np.zeros((self.B,), np.int32)
-        for slot, req in active:
-            tok_idx[slot] = len(req.generated)
-        tr = self.tracer
-        t0 = (self._clock() if tr is not None or self._perf is not None
-              else None)
-        bspan = (tr.begin("decode_step", t=t0, step=self._steps,
-                          active=len(active),
-                          weights_version=self.weights_version)
-                 if tr is not None else None)
-
-        if self._adapters is not None:
-            logits, self.caches, self.valid = self.model.decode_pages_lora(
-                jnp.asarray(self._next_tok)[:, None], self._offsets,
-                self._kv.tables, self.caches, self.valid,
-                self._adapter_pool, self._adapter_tables,
-                paged_kernel=self._paged_kernel)
-            self._count_gather_step()
-        elif self._kv is not None:
-            logits, self.caches, self.valid = self.model.decode_pages(
-                jnp.asarray(self._next_tok)[:, None], self._offsets,
-                self._kv.tables, self.caches, self.valid,
-                paged_kernel=self._paged_kernel)
-            self._count_gather_step()
-        else:
-            logits, self.caches, self.valid = self.model.decode_slots(
-                jnp.asarray(self._next_tok)[:, None], self._offsets,
-                self.caches, self.valid)
-        if self._kv_quant is not None:
-            # every active slot's decode write requantized its page
-            self.registry.counter(QUANT_PAGES_TOTAL).inc(len(active))
-        logits = perturb("serving/decode_logits", logits,
-                         engine_step=self._steps)
-        self._count_sampler_step()
-        toks_f = _sample_rows(
-            logits, jnp.asarray(self._base_keys), jnp.asarray(tok_idx),
-            jnp.asarray(self._temps), jnp.asarray(self._topks),
-            jnp.asarray(self._topps))
-        toks, finite = np.asarray(toks_f[0]), np.asarray(toks_f[1])
-        programs, loads = self._take_moe_loads()
-        self._count_moe(programs, jax.device_get(loads))
-        now = self._clock()
-        for slot, req in active:
-            self._offsets[slot] += 1  # the step wrote req's previous token
-            if not bool(finite[slot]):
-                # quarantine: fail THIS request only — its logits blew up;
-                # co-batched rows never mixed with them (attention is
-                # per-row) and keep decoding untouched
-                self._fail_slot(slot, req, outputs, now)
-                continue
-            tok = int(toks[slot])
-            req.decode_steps += 1
-            if bspan is not None:
-                tr.instant("decode_slot", request_id=req.request_id,
-                           parent=bspan, t=now, slot=slot,
-                           tok_idx=int(tok_idx[slot]))
-            last = self._last_tok_time[slot]
-            if last is not None:
-                self._observe_intertoken(req, (now - last) * 1e3)
-            self._append_token(slot, req, tok, now)
-            if not req.done:
-                self._next_tok[slot] = tok
-            else:
-                outputs.append(self._emit(req, now))
-        if bspan is not None:
-            tr.end(bspan, t=now)
-        if self._perf is not None:
-            self._perf.note_phase("decode_step", (now - t0) * 1e3)
 
     def _collect_decode(self) -> list:
         """Collect the in-flight decode step: ONE explicit packed fetch
@@ -2135,13 +1948,12 @@ class ServingEngine:
                     weights_version=self.weights_version)
         # eager slicing of a stacked [3, B] array would bind scalar start
         # indices host-side (an implicit transfer the guard rejects), so the
-        # per-step inputs stage as one explicit pytree put instead; in paged
-        # mode a dirty block table rides the SAME put (still one explicit
-        # host→device crossing per step) and a clean one reuses its mirror
+        # per-step inputs stage as one explicit pytree put instead; a dirty
+        # block table rides the SAME put (still one explicit host→device
+        # crossing per step) and a clean one reuses its mirror
         staged = [self._next_tok[:, None].copy(), self._offsets.copy(),
                   tok_idx]
-        stage_kv = self._kv is not None and (self._kv.tables_dirty
-                                             or self._tables_dev is None)
+        stage_kv = self._kv.tables_dirty or self._tables_dev is None
         stage_ad = self._adapters is not None and (
             self._adapter_dirty or self._atables_dev is None)
         if stage_kv:
@@ -2166,15 +1978,11 @@ class ServingEngine:
                 tok, offs, self._tables_dev, self.caches, self.valid,
                 self._adapter_pool, self._atables_dev,
                 paged_kernel=self._paged_kernel)
-            self._count_gather_step()
-        elif self._kv is not None:
+        else:
             logits, self.caches, self.valid = self.model.decode_pages(
                 tok, offs, self._tables_dev, self.caches, self.valid,
                 paged_kernel=self._paged_kernel)
-            self._count_gather_step()
-        else:
-            logits, self.caches, self.valid = self.model.decode_slots(
-                tok, offs, self.caches, self.valid)
+        self._count_gather_step()
         if self._kv_quant is not None:
             # every active slot's decode write requantized its page
             self.registry.counter(QUANT_PAGES_TOTAL).inc(len(active))
@@ -2453,7 +2261,8 @@ class ServingEngine:
 
     def _stop_reason(self, req: Request, tok: int) -> Optional[str]:
         """Finish reason for ``tok`` (already appended), engine-level EOS
-        included — the ONE stop predicate both engines share."""
+        included — the ONE stop predicate the prefill's first token, the
+        decode collect and the speculative collect share."""
         reason = req.check_stop(tok)
         if (reason is None and self.eos_token_id is not None
                 and tok == self.eos_token_id):
@@ -2477,8 +2286,7 @@ class ServingEngine:
         """Quarantine bookkeeping for one failed request: terminal
         ``FAILED`` state, slot freed and parked (the next insert overwrites
         the poisoned KV; a parked row's logits are ignored meanwhile), its
-        KV pages reclaimed in paged mode, the rest of the batch
-        untouched."""
+        KV pages reclaimed, the rest of the batch untouched."""
         req.transition(RequestState.FAILED)
         req.finish_reason = reason
         req.finish_time = now
@@ -2490,8 +2298,9 @@ class ServingEngine:
 
     def _fail_slot(self, slot: int, req: Request, outputs: list,
                    now: float) -> None:
-        """Synchronous quarantine: bookkeeping + log + emit in one go (the
-        prefill path and the synchronous engine)."""
+        """Quarantine at the prefill's first token: bookkeeping + log +
+        emit in one go (the decode loop defers log and emit to
+        :meth:`_finish_decode`)."""
         self._fail_slot_state(slot, req, now)
         logger.warning(
             "serving: request %d failed (%s) after %d tokens — slot %d "
@@ -2535,8 +2344,7 @@ class ServingEngine:
         picks its work from every row it is handed)."""
         self._offsets[slot] = self.T
         self._last_tok_time[slot] = None
-        if self._kv is not None:
-            self._kv.release_slot(slot)
+        self._kv.release_slot(slot)
         self._release_adapter(slot)
         if self._temps[slot] > 0.0:
             self._temps[slot] = 0.0
@@ -2557,11 +2365,10 @@ class ServingEngine:
             # its re-grant): the open park still counts as preempted time
             req.preempted_ms += max(now - req.parked_at, 0.0) * 1e3
             req.parked_at = None
-        if self._kv is not None:
-            # terminal while holding a resume pin (swept/cancelled parked
-            # victim): the pin drops here, the one choke point every
-            # terminal path funnels through — zero page leak
-            self._kv.release_resume(req)
+        # terminal while holding a resume pin (swept/cancelled parked
+        # victim): the pin drops here, the one choke point every terminal
+        # path funnels through — zero page leak
+        self._kv.release_resume(req)
         tr = self.tracer
         if tr is not None:
             rt = self._rt.pop(req.request_id, None)

@@ -29,7 +29,7 @@ the admission layer over the pool:
 
 Drive a fleet exactly like an engine: it has ``submit`` / ``step`` /
 ``has_work``, so :func:`~..serving.driver.replay` (and everything built on
-it — ``serve_bench``, ``fleet_bench``, ``runner.py serve --replicas N``)
+it — ``fleet_bench``, ``runner.py serve --replicas N``)
 takes either.
 """
 

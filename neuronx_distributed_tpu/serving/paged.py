@@ -58,7 +58,7 @@ class PagedKVManager:
     Implements the scheduler's ``page_gate`` protocol
     (:meth:`pages_needed` / :meth:`pages_free` / :meth:`pages_capacity`)
     and the engine's slot lifecycle (:meth:`admit_slot` →
-    :meth:`fresh_pages` writes → :meth:`finish_insert`;
+    :meth:`fresh_pages` chunk writes → :meth:`finish_insert`;
     :meth:`release_slot` on any terminal state).
     """
 
@@ -89,7 +89,7 @@ class PagedKVManager:
         # per-slot logical→physical page map; NULL_PAGE backs every hole
         self.tables = np.full((num_slots, self.pages_per_slot), NULL_PAGE,
                               np.int32)
-        self.tables_dirty = True  # device mirror refresh flag (async engine)
+        self.tables_dirty = True  # device mirror refresh flag (decode dispatch)
         self._slot_pages: List[List[int]] = [[] for _ in range(num_slots)]
         self._slot_fresh: List[List[tuple]] = [[] for _ in range(num_slots)]
         self._slot_keys: List[Optional[list]] = [None] * num_slots
@@ -160,7 +160,7 @@ class PagedKVManager:
         allocation of the remaining prompt pages and all decode pages
         (evicting LRU cached chains first when the free list is short).
         Returns the cached prefill logits on an exact full-prompt hit (the
-        engine skips ``prefill_one`` entirely), else None.
+        engine skips prefill compute entirely), else None.
 
         Transactional: on ANY failure every page/reference taken so far is
         released before the exception propagates."""
@@ -204,6 +204,14 @@ class PagedKVManager:
             for p in taken:
                 self.alloc.free(p)
             raise
+        full_hit = payload is not None and len(matched) == self.ctx_pages
+        if not fresh and not full_hit:
+            # the whole chain is resident but carries no prefill logits (a
+            # decoding victim's chain, re-registered by park_resume after a
+            # weight swap flushed the index): the last prompt page is
+            # computed again, in place — the same tokens at the same
+            # positions — for its last row's logits
+            fresh = [(self.ctx_pages - 1, int(table[self.ctx_pages - 1]))]
         self._slot_pages[slot] = taken
         self._slot_fresh[slot] = fresh
         self._slot_keys[slot] = keys
@@ -211,7 +219,6 @@ class PagedKVManager:
         self.tables_dirty = True
         n_hit = sum(1 for lp, p in enumerate(matched)
                     if not is_padding_key(keys[lp]))
-        full_hit = payload is not None and len(matched) == self.ctx_pages
         if self.registry is not None:
             self.registry.counter(PREFIX_HITS_TOTAL).inc(n_hit)
             self.registry.counter(PREFIX_MISSES_TOTAL).inc(len(todo))
@@ -220,16 +227,16 @@ class PagedKVManager:
         return payload if full_hit else None
 
     def fresh_pages(self, slot: int) -> List[tuple]:
-        """``[(logical_page, phys_page), ...]`` the engine must fill from
-        the prefill row caches — cached-prefix (and padding) pages are
-        absent, so their writes are skipped entirely.
+        """``[(logical_page, phys_page), ...]`` the engine's chunk loop
+        must compute — cached-prefix (and padding) pages are absent, so
+        their writes are skipped entirely.
 
         The logical pages are always ONE CONTIGUOUS ascending run: padding
         pages lead (left-padded prompts) and ride the NULL page, and the
         matched prefix is a leading chain, so everything between the first
-        fresh page and ``ctx_pages`` is fresh.  The chunked-prefill loop
-        (``ServingEngine(prefill_chunk_tokens=)``) walks this run left to
-        right, one budgeted chunk per step."""
+        fresh page and ``ctx_pages`` is fresh.  The engine's chunk loop
+        walks this run left to right, one chunk of ``prefill_chunk_tokens``
+        per step."""
         return list(self._slot_fresh[slot])
 
     def finish_insert(self, slot: int, payload: Any) -> None:
@@ -239,8 +246,21 @@ class PagedKVManager:
         if self.index is None or self._slot_keys[slot] is None:
             return
         keys = self._slot_keys[slot]
-        pages = [int(p) for p in self.tables[slot][:self.ctx_pages]]
+        self._register_chain(keys, self.tables[slot][:self.ctx_pages],
+                             payload=payload)
+
+    def _register_chain(self, keys, pages, payload: Any = None) -> List[int]:
+        """Insert a slot's chain into the prefix index; returns the pages
+        the index holds for it.  They are the slot's own, except where the
+        index already holds a leading part of the chain under OTHER pages:
+        a slot that prefilled the same prefix while this one was chunking
+        registered first.  Equal keys hold equal content, so that copy
+        stays, the rest of the chain hangs off it, and this slot's copy
+        stays private until its release."""
+        held, _ = self.index.peek(keys)
+        pages = [int(p) for p in held] + [int(p) for p in pages[len(held):]]
         self.index.insert(keys, pages, payload=payload)
+        return pages
 
     def release_slot(self, slot: int) -> None:
         """Drop every page reference the slot holds (exclusive pages return
@@ -295,11 +315,11 @@ class PagedKVManager:
         if depth <= 0:
             return
         ckeys = list(keys[:depth])
-        pages = [int(p) for p in self.tables[slot][:depth]]
         # register first (a DECODE victim's chain is already indexed — the
         # re-insert is a touch; a mid-chunk victim's partial chain is new
-        # and the index takes its own references), then pin
-        self.index.insert(ckeys, pages)
+        # and the index takes its own references), then pin what the
+        # index holds
+        pages = self._register_chain(ckeys, self.tables[slot][:depth])
         for p in pages:
             self.alloc.retain(p)  # no-op on NULL padding holes
         req.resume_pages = pages

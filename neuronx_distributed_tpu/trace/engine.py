@@ -779,7 +779,9 @@ class ParallelInferenceModel(_ServingBase):
         return self._decode_fn(params, tok, offset, caches, valid)
 
     def empty_caches(self):
-        """Fresh zero KV caches shaped/sharded like the traced ones."""
+        """Fresh zero contiguous ``[B, T]`` KV caches shaped/sharded like
+        the traced ones: the solo ``generate``'s, and in the serving engine
+        the speculative DRAFT's only (the target lives in a page pool)."""
         return init_kv_caches(
             self.num_layers, self.config.batch_size, self.config.max_total_len,
             self.num_kv_heads, self.head_dim, self.config.kv_cache_dtype,
@@ -878,8 +880,9 @@ class ParallelInferenceModel(_ServingBase):
 
     def _serving_lru(self, reset=False):
         """Get-or-create the shared serving phase-fn cache — the ONE place
-        that owns its capacity (the paged + contiguous + per-chunk-width
-        verify programs must coexist without evictions)."""
+        that owns its capacity (the paged programs, the draft's contiguous
+        ones and the per-chunk-width verify programs must coexist without
+        evictions)."""
         if reset or not hasattr(self, "_serving_cache"):
             self._serving_cache = _CompiledLRU(
                 "serving_phase", capacity=SERVING_CACHE_SIZE, owner=self)
@@ -887,7 +890,10 @@ class ParallelInferenceModel(_ServingBase):
 
     def decode_slots(self, tok, offsets, caches, valid, apool=None,
                      atables=None):
-        """Compiled per-slot decode step (lazily jitted, cache donated);
+        """Compiled per-slot decode step over contiguous ``[B, T]`` caches
+        (lazily jitted, cache donated) — in the serving engine the
+        speculative DRAFT's step and nothing else's: the target decodes
+        through :meth:`decode_pages`.
         ``offsets`` is the per-slot next-write index ``[B]`` (``T`` = idle).
         ``apool``/``atables`` select the adapter-aware variant (its own
         cached program).  Outputs pinned to the AOT executables'
@@ -912,7 +918,9 @@ class ParallelInferenceModel(_ServingBase):
         — the same pure phase fn as the batched ``context`` executable, so a
         slot-inserted request's prefill is numerically identical to a solo
         ``generate``'s.  The returned one-row caches feed
-        :meth:`insert_slot`."""
+        :meth:`insert_slot`.  In the serving engine the speculative DRAFT's
+        prefill only: the target's prompts go through
+        :meth:`prefill_chunk_pages`."""
         self._serving_lru()
         fn = self._serving_cache.get("prefill_one")
         if fn is None:
@@ -933,8 +941,11 @@ class ParallelInferenceModel(_ServingBase):
         return caches, valid
 
     def insert_slot(self, caches, row_caches, valid, row_valid, slot):
-        """Compiled slot insert (live caches + validity donated — requests
-        enter the batch without copying the other slots)."""
+        """Compiled slot insert into contiguous ``[B, T]`` caches (live
+        caches + validity donated — requests enter the batch without
+        copying the other slots).  In the serving engine the speculative
+        DRAFT's only; the target inserts a validity row
+        (:meth:`insert_valid`) and writes K/V through its block table."""
         self._serving_lru()
         fn = self._serving_cache.get("insert_slot")
         if fn is None:
@@ -1207,7 +1218,8 @@ class ParallelInferenceModel(_ServingBase):
     def prefill_one_lora(self, ids, valid, apool, atable):
         """Compiled adapter-aware single-request prefill — the tenancy
         counterpart of :meth:`prefill_one` (returns the same
-        ``(logits [1, V], B=1 row caches)``)."""
+        ``(logits [1, V], B=1 row caches)``), and like it the speculative
+        DRAFT's only in the serving engine."""
         self._serving_lru()
         fn = self._serving_cache.get("prefill_one_lora")
         if fn is None:
@@ -1229,8 +1241,8 @@ class ParallelInferenceModel(_ServingBase):
         logical→physical page map, ``valid [1, T]`` the slot's whole-cache
         key-validity row with the FULL prompt's (left-padded) validity
         pre-written and zeros beyond it: chunk token positions are global
-        prefix counts of that mask, so RoPE phases match the one-shot
-        ``prefill_one`` exactly, and keys beyond the chunk are causally
+        prefix counts of that mask, so RoPE phases match a one-shot
+        context prefill exactly, and keys beyond the chunk are causally
         masked (q offset = cache offset) so the not-yet-written tail
         contributes nothing.  ``apool``/``atables`` prefill an adapter
         request's chunks with its LoRA deltas applied (the tenancy
@@ -1270,83 +1282,6 @@ class ParallelInferenceModel(_ServingBase):
                                  apool=apool, atables=atables,
                                  paged_kernel=paged_kernel, last_only=False)
 
-    def _write_page_fn(self, caches, row_caches, lp, phys):
-        """Write logical page ``lp`` of a prefilled one-row cache into
-        physical page ``phys`` of the pool (both traced scalars — ONE
-        compiled program serves every page of every admission)."""
-        def wr(c, r):
-            page = c.shape[2]
-            chunk = jax.lax.dynamic_slice_in_dim(r, lp * page, page, axis=1)
-            # row caches are [1, T, NKV, D]; pool pages head-major
-            return jax.lax.dynamic_update_slice(
-                c, chunk.transpose(0, 2, 1, 3).astype(c.dtype),
-                (phys, 0, 0, 0))
-
-        return jax.tree.map(wr, caches, row_caches)
-
-    def _write_page_quant_fn(self, caches, row_caches, lp, phys,
-                             row_valid=None):
-        """Quantize-on-write prefill page write: the fp row-cache chunk is
-        quantized per page (scale/zero computed from the page content) and
-        the int8 payload + page params land at ``phys``.  ``row_valid``
-        (the request's ``[C]`` validity row) zeroes INVALID cells — a
-        left-pad row's hidden states are masked-attention garbage, and
-        letting them into the page would pollute its quantization scale;
-        zeroing matches the chunk scatter's valid-masked commit exactly,
-        so chunked and whole int8 prefills quantize identical pages."""
-        from neuronx_distributed_tpu.kvcache.quant import quantize_page
-
-        out = []
-        for (ck, cv, ks, kz, vs, vz), (rk, rv) in zip(caches, row_caches):
-            page = ck.shape[2]
-
-            def one(cq, sc, zp, r):
-                chunk = jax.lax.dynamic_slice_in_dim(
-                    r, lp * page, page, axis=1)[0]  # [page, NKV, D]
-                if row_valid is not None:
-                    v = jax.lax.dynamic_slice_in_dim(
-                        row_valid, lp * page, page, axis=0)
-                    chunk = chunk * (v > 0)[:, None, None].astype(chunk.dtype)
-                # pool pages are head-major [NKV, page, D]
-                q2, s2, z2 = quantize_page(chunk.transpose(1, 0, 2))
-                cq = jax.lax.dynamic_update_slice(
-                    cq, q2[None], (phys, 0, 0, 0))
-                sc = jax.lax.dynamic_update_slice(sc, s2[None], (phys,))
-                zp = jax.lax.dynamic_update_slice(zp, z2[None], (phys,))
-                return cq, sc, zp
-
-            ck, ks, kz = one(ck, ks, kz, rk)
-            cv, vs, vz = one(cv, vs, vz, rv)
-            out.append((ck, cv, ks, kz, vs, vz))
-        return out
-
-    def write_page(self, caches, row_caches, logical_page, phys_page,
-                   row_valid=None):
-        """Compiled page-aligned prefill write (pool donated): page
-        ``logical_page`` of the ``prefill_one`` row caches lands in pool
-        page ``phys_page``.  Cached-prefix pages are simply never written —
-        the caller skips them entirely.  A quantized pool quantizes on
-        write (per-page scale/zero from the page content), with
-        ``row_valid`` zero-masking invalid (left-pad) cells out of the
-        scale; the fp pool ignores ``row_valid`` (garbage cells are never
-        attended and couple to nothing)."""
-        self._serving_lru()
-        quant = self._pool_tag(caches) == "int8"
-        masked = quant and row_valid is not None
-        key = ("write_page", self._pool_tag(caches), masked)
-        fn = self._serving_cache.get(key)
-        if fn is None:
-            impl = self._write_page_quant_fn if quant else self._write_page_fn
-            fn = jax.jit(impl, donate_argnums=(0,),
-                         out_shardings=self._pool_out_shardings(caches))
-            fn = self._serving_cache.put(key, fn)
-        if masked:
-            return fn(caches, row_caches, jnp.int32(logical_page),
-                      jnp.int32(phys_page),
-                      jnp.asarray(row_valid, jnp.int32))
-        return fn(caches, row_caches, jnp.int32(logical_page),
-                  jnp.int32(phys_page))
-
     def _copy_page_fn(self, caches, src, dst):
         def cp(c):
             # 4-D page payloads and 1-D per-page quant params alike: copy
@@ -1376,8 +1311,8 @@ class ParallelInferenceModel(_ServingBase):
                 valid, row_valid, slot, axis=0)
 
     def insert_valid(self, valid, row_valid, slot):
-        """Compiled validity-row insert (donated) — the paged admission's
-        slice of :meth:`insert_slot`: block tables carry the KV, so only the
+        """Compiled validity-row insert (donated) — all an admission writes
+        outside the page pool: block tables carry the KV, so only the
         validity row needs writing."""
         self._serving_lru()
         fn = self._serving_cache.get("insert_valid")

@@ -6,7 +6,7 @@ cache directory is part of the cache key, so it must be the same path on
 every run: never a temp name, a pid or a time.
 
 Every entry point that compiles (``chip_smoke.py``, ``bench.py``,
-``tools/serve_bench.py``, ``tools/fleet_bench.py`` and the two launchers)
+``tools/fleet_bench.py`` and the two launchers)
 calls :func:`configure_compile_cache` before its first compile, and nothing
 else in the tree names the cache-directory option.
 """

@@ -19,10 +19,11 @@ wall-clock, so they stay CI-safe):
   cleanly on early stop, on a real in-process SIGTERM checkpoint, and
   through a policy rollback (the staged pipeline rewinds to the
   rolled-back step, parity-tested against the unprefetched run);
-- **serving pipelining** — async decode outputs token-identical to the
-  synchronous engine (greedy under staggered arrivals + slot reuse, and
-  sampled per-request rng streams), with ONE packed fetch + ONE packed put
-  per steady engine step, counted by the transfer audit.
+- **serving pipelining** — the pipelined decode loop's outputs
+  token-identical to the solo ``generate`` (greedy under staggered arrivals
+  + slot reuse, and a sampled per-request rng stream), with ONE packed
+  fetch + ONE packed put per steady engine step, counted by the transfer
+  audit.
 """
 
 import os
@@ -35,7 +36,7 @@ import numpy as np
 import pytest
 
 import neuronx_distributed_tpu as nxd
-from conftest import sharded_params
+from conftest import sharded_params, solo_generate
 from neuronx_distributed_tpu.data.prefetch import DevicePrefetcher
 from neuronx_distributed_tpu.obs import MetricRegistry, Observability, TransferAudit
 from neuronx_distributed_tpu.resilience import AnomalyPolicy, clear_plan, install_plan
@@ -325,21 +326,22 @@ def pool_factory(devices8):
     params = sharded_params(module.init(jax.random.PRNGKey(0),
                                         jnp.zeros((3, 8), jnp.int32)))
 
-    def make():
+    def make(batch_size=3):
         return ParallelInferenceModel(
             module, params,
-            InferenceConfig(batch_size=3, context_len=8, max_total_len=16,
-                            kv_cache_dtype=jnp.float32))
+            InferenceConfig(batch_size=batch_size, context_len=8,
+                            max_total_len=16, kv_cache_dtype=jnp.float32))
 
     return cfg, make
 
 
 @pytest.mark.perf
-def test_serving_async_token_identical_to_sync_engine(pool_factory):
+def test_serving_pipelined_token_identical_to_solo_generate(pool_factory):
     """Acceptance bar: the pipelined engine's outputs are token-identical
-    to the PR-2 synchronous engine — greedy under staggered arrivals with
-    slot reuse (5 requests over 3 slots), and sampled per-request rng
-    streams — and streaming callbacks still see every token in order."""
+    to the solo ``generate`` of each prompt on the same weights — greedy
+    under staggered arrivals with slot reuse (5 requests over 3 slots), and
+    a sampled request on its per-request rng stream — and streaming
+    callbacks still see every token in order."""
     from neuronx_distributed_tpu.serving import Request, SamplingParams, ServingEngine
 
     cfg, make = pool_factory
@@ -347,35 +349,37 @@ def test_serving_async_token_identical_to_sync_engine(pool_factory):
     prompts = [rs.randint(1, cfg.vocab_size, size=rs.randint(3, 8)).tolist()
                for _ in range(5)]
     rng = jax.random.PRNGKey(42)
+    temps = {i: 0.8 if i == 2 else 0.0 for i in range(5)}
 
-    def run(async_decode):
-        streamed = {}
-        engine = ServingEngine(make(), rng=rng, async_decode=async_decode)
-        outs = {}
-        for i in range(3):
-            engine.submit(Request(
-                request_id=i, prompt_ids=prompts[i], max_new_tokens=4 + i,
-                sampling=SamplingParams(temperature=0.8 if i == 2 else 0.0),
-                stream_cb=lambda r, t: streamed.setdefault(
-                    r.request_id, []).append(t)))
-        for out in engine.step():
-            outs[out.request_id] = out
-        for i in range(3, 5):  # late joiners: slot reuse mid-decode
-            engine.submit(Request(
-                request_id=i, prompt_ids=prompts[i], max_new_tokens=4 + i,
-                stream_cb=lambda r, t: streamed.setdefault(
-                    r.request_id, []).append(t)))
-        for out in engine.run_until_complete(max_steps=200):
-            outs[out.request_id] = out
-        return ({rid: list(o.token_ids) for rid, o in outs.items()},
-                {rid: o.finish_reason for rid, o in outs.items()}, streamed)
+    streamed = {}
+    engine = ServingEngine(make(), page_size=4, rng=rng)
+    outs = {}
 
-    async_toks, async_reasons, async_streamed = run(True)
-    sync_toks, sync_reasons, _ = run(False)
-    assert async_toks == sync_toks
-    assert async_reasons == sync_reasons
-    for rid, toks in async_toks.items():
-        assert async_streamed[rid] == toks  # every token streamed, in order
+    def submit(i):
+        engine.submit(Request(
+            request_id=i, prompt_ids=prompts[i], max_new_tokens=4 + i,
+            sampling=SamplingParams(temperature=temps[i]),
+            stream_cb=lambda r, t: streamed.setdefault(
+                r.request_id, []).append(t)))
+
+    for i in range(3):
+        submit(i)
+    for out in engine.step():
+        outs[out.request_id] = out
+    for i in range(3, 5):  # late joiners: slot reuse mid-decode
+        submit(i)
+    for out in engine.run_until_complete(max_steps=200):
+        outs[out.request_id] = out
+
+    solo = make(batch_size=1)
+    assert set(outs) == set(range(5))
+    for i, out in outs.items():
+        kw = (dict(temperature=temps[i], rng=rng, request_ids=[i])
+              if temps[i] else {})
+        want = solo_generate(solo, prompts[i], 4 + i, **kw)
+        assert list(out.token_ids) == want, f"request {i} diverged"
+        assert out.finish_reason == "length"
+        assert streamed[i] == want  # every token streamed, in order
 
 
 @pytest.mark.perf
@@ -387,7 +391,7 @@ def test_serving_one_packed_fetch_and_put_per_steady_step(pool_factory):
     from neuronx_distributed_tpu.serving import Request, ServingEngine, replay_trace
 
     _, make = pool_factory
-    engine = ServingEngine(make(), transfer_guard="forbid")
+    engine = ServingEngine(make(), page_size=4, transfer_guard="forbid")
     engine.submit(Request(request_id=0, prompt_ids=[1, 2, 3],
                           max_new_tokens=8))
     engine.step()  # admission step (prefill fetch happens here)
@@ -404,7 +408,7 @@ def test_serving_one_packed_fetch_and_put_per_steady_step(pool_factory):
 
     # replay_trace over a fresh engine: every fetch the drive loop causes
     # is a packed, audited one (fetch count == host_blocked observations)
-    engine2 = ServingEngine(make(), transfer_guard="forbid")
+    engine2 = ServingEngine(make(), page_size=4, transfer_guard="forbid")
     reqs = [Request(request_id=i, prompt_ids=[1, 2, 3], max_new_tokens=4)
             for i in range(4)]
     outs = replay_trace(engine2, [0.0, 0.0, 0.0, 0.01], reqs)

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import sharded_params
+from conftest import sharded_params, solo_generate
 from neuronx_distributed_tpu.kvcache.allocator import NULL_PAGE, BlockAllocator
 from neuronx_distributed_tpu.kvcache.pool import init_page_pool_caches
 from neuronx_distributed_tpu.kvcache.prefix import (
@@ -312,16 +312,6 @@ def _paged_factory(pool, num_pages=9):
     return factory
 
 
-def _solo_generate(solo, prompt_ids, max_new):
-    C = solo.config.context_len
-    L = len(prompt_ids)
-    ids = np.zeros((1, C), np.int32)
-    ids[0, C - L:] = prompt_ids
-    out = solo.generate(jnp.asarray(ids), max_new,
-                        prompt_lens=jnp.asarray([L]))
-    return [int(t) for t in np.asarray(out)[0, C:]]
-
-
 def _bimodal(cfg, n, rs):
     """Alternating interactive/batch requests over 6-8 token prompts (two
     real pages at page_size=4) — what disaggregation exists for."""
@@ -354,7 +344,7 @@ def test_disagg_fleet_migrates_and_stays_token_identical(disagg_pool,
     for gid, out in outs.items():
         cid = router.client_id(gid)
         assert out.state == "finished"
-        assert list(out.token_ids) == _solo_generate(solo, prompts[cid], 4), (
+        assert list(out.token_ids) == solo_generate(solo, prompts[cid], 4), (
             f"request {cid} diverged after migration")
     snap = router.registry.snapshot()
     assert snap["router/migrations_total"] >= 1.0
@@ -398,7 +388,7 @@ def test_disagg_fleet_prefix_fill_prefills_once_fleet_wide(disagg_pool):
     snap = router.registry.snapshot()
     assert snap["kvcache/fleet_prefix_hits_total"] >= 1.0
     assert outs[g1].state == "finished"
-    assert list(outs[g1].token_ids) == _solo_generate(solo, popular, 4)
+    assert list(outs[g1].token_ids) == solo_generate(solo, popular, 4)
     # the decode replica really did skip the prefill work: its own index
     # served the imported chain
     dec = router.replicas[1].engine.registry.snapshot()
@@ -430,7 +420,7 @@ def test_disagg_chaos_kill_mid_migration_aborts_cleanly(disagg_pool):
     for gid, out in outs.items():
         cid = router.client_id(gid)
         assert out.state == "finished"
-        assert list(out.token_ids) == _solo_generate(solo, prompts[cid], 4)
+        assert list(out.token_ids) == solo_generate(solo, prompts[cid], 4)
     for r in router.replicas.values():
         r.engine._kv.assert_invariants()                  # no page leaks
     router.close()
@@ -465,8 +455,8 @@ def test_preempted_request_resumes_without_reprefill(disagg_pool):
     assert len(by) == 3
     assert all(o.state == "finished" for o in by.values())
     for rid in range(3):
-        want = _solo_generate(solo, prompts[rid],
-                              6 if rid < 2 else 4)
+        want = solo_generate(solo, prompts[rid],
+                             6 if rid < 2 else 4)
         assert list(by[rid].token_ids) == want, f"request {rid} diverged"
     snap = eng.registry.snapshot()
     assert snap["serving/preemptions_total"] >= 1.0

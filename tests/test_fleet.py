@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import last_json_line, run_cli, sharded_params
+from conftest import last_json_line, run_cli, sharded_params, solo_generate
 from neuronx_distributed_tpu.kvcache.prefix import (
     PAD,
     PrefixIndex,
@@ -676,16 +676,6 @@ def _paged_factory(pool, seed=0):
     return factory
 
 
-def _solo_generate(solo, prompt_ids, max_new):
-    C = solo.config.context_len
-    L = len(prompt_ids)
-    ids = np.zeros((1, C), np.int32)
-    ids[0, C - L:] = prompt_ids
-    out = solo.generate(jnp.asarray(ids), max_new,
-                        prompt_lens=jnp.asarray([L]))
-    return [int(t) for t in np.asarray(out)[0, C:]]
-
-
 def _shared_prompts(cfg, n, rs):
     """Half share one system preamble (page-aligned length 4), half are
     unrelated — the trace affinity exists for."""
@@ -717,7 +707,7 @@ def test_fleet_greedy_identical_to_solo_under_every_policy(fleet_pool, policy):
     for gid, out in outs.items():
         cid = router.client_id(gid)
         assert out.state == "finished"
-        want = _solo_generate(solo, prompts[cid], 4)
+        want = solo_generate(solo, prompts[cid], 4)
         assert list(out.token_ids) == want, (
             f"request {cid} diverged under {policy}")
     router.assert_invariants()
@@ -779,7 +769,7 @@ def test_fleet_kill_zero_loss_and_token_identical(fleet_pool, tmp_path):
     assert all(o.state == "finished" for o in outs.values())
     for gid, out in outs.items():
         cid = router.client_id(gid)
-        assert list(out.token_ids) == _solo_generate(solo, prompts[cid], 4)
+        assert list(out.token_ids) == solo_generate(solo, prompts[cid], 4)
     snap = router.registry.snapshot()
     assert snap["router/failovers_total"] == 1.0
     assert snap["router/requeued_total"] >= 1.0

@@ -22,8 +22,7 @@ Five layers:
 - E2E + CLI — the PR-7 replica-kill chaos scenario firing→resolving
   ``replica_down`` through the router's ``FleetHealth``, the obs_report
   fleet-layout merge + alerts section, the ``--compare`` alerts
-  regression, and the ``fleet_watch`` / ``serve_bench --alerts-out``
-  rungs.
+  regression, and the ``fleet_watch`` rungs.
 """
 
 import json
@@ -63,7 +62,6 @@ from neuronx_distributed_tpu.obs.report import (
     build_report,
     compare_resources,
     render_markdown,
-    summarize_alerts,
 )
 from neuronx_distributed_tpu.obs.schemas import validate_jsonl, validate_record
 from neuronx_distributed_tpu.parallel.mesh import initialize_model_parallel
@@ -772,20 +770,3 @@ def test_fleet_watch_once_renders_run_dir(tmp_path):
     assert "kv_headroom" in out and "warn" in out
     assert "replica0" in out and "8/16" in out and "50%" in out
     assert "tokens" in out
-
-
-@pytest.mark.slow
-def test_serve_bench_alerts_out_cli(tmp_path):
-    out_dir = str(tmp_path / "alerts")
-    proc = run_cli(os.path.join(REPO, "tools", "serve_bench.py"),
-                   "--tiny", "--continuous", "--num-requests", "4",
-                   "--max-new-tokens", "4", "--alerts-out", out_dir)
-    rec = [json.loads(l) for l in proc.stdout.strip().splitlines()
-           if l.startswith("{")][-1]
-    assert rec["alerts"].endswith("continuous.alerts.jsonl")
-    assert os.path.exists(rec["alerts"])
-    validate_jsonl("alert", rec["alerts"])
-    assert rec["page_alerts"] == 0, "a passing tiny rung must be quiet"
-    # the dropped artifact feeds the report's alerts section
-    alerts = summarize_alerts([rec["alerts"]])
-    assert alerts is not None and alerts["firing"] == 0

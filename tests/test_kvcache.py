@@ -9,7 +9,7 @@ Three layers, mirroring the subsystem's split:
   full-hit payloads;
 - PAGED ENGINE parity — the acceptance bar: paged greedy AND sampled
   continuous-batching outputs under staggered arrivals + slot reuse are
-  token-identical to the contiguous engine / solo ``generate``; prefix-hit
+  token-identical to the solo ``generate``; prefix-hit
   admissions skip prefill work (counted via the fault-point plane and the
   ``kvcache/prefill_skipped_total`` metric); eviction under pool pressure
   reclaims cached chains without corrupting live requests;
@@ -18,14 +18,12 @@ Three layers, mirroring the subsystem's split:
   crashed request's pages are reclaimed and the engine keeps serving.
 """
 
-import json
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import sharded_params
+from conftest import sharded_params, solo_generate
 from neuronx_distributed_tpu.kvcache import (
     NULL_PAGE,
     PAD,
@@ -304,8 +302,8 @@ def test_page_pool_shapes_and_budget_math(devices8):
 
 @pytest.fixture
 def paged_pool(devices8):
-    """B=3 paged + contiguous pool models and a B=1 solo reference over the
-    SAME params (page 4 divides C=8 and T=16)."""
+    """B=3 pool model and a B=1 solo reference over the SAME params (page
+    4 divides C=8 and T=16)."""
     initialize_model_parallel(tensor_parallel_size=1,
                               devices=jax.devices()[:1])
     cfg = LlamaConfig.tiny(
@@ -326,26 +324,15 @@ def paged_pool(devices8):
     return cfg, pool, solo
 
 
-def _solo_generate(solo, prompt_ids, max_new, **kw):
-    C = solo.config.context_len
-    L = len(prompt_ids)
-    ids = np.zeros((1, C), np.int32)
-    ids[0, C - L:] = prompt_ids
-    out = solo.generate(jnp.asarray(ids), max_new,
-                        prompt_lens=jnp.asarray([L]), **kw)
-    return [int(t) for t in np.asarray(out)[0, C:]]
-
-
 def _paged_engine(pool, num_pages=16, **kw):
     return ServingEngine(pool, page_size=4, num_pages=num_pages, **kw)
 
 
-@pytest.mark.parametrize("async_decode", [True, False])
-def test_paged_greedy_token_identical_to_contiguous(paged_pool, async_decode):
+@pytest.mark.parametrize("chunk", [4, 8])
+def test_paged_greedy_token_identical_to_solo_generate(paged_pool, chunk):
     """Acceptance bar: staggered arrivals, slot reuse (5 requests over 3
-    slots), every request's paged greedy tokens identical to BOTH the
-    contiguous engine's and its solo generate — in the pipelined async
-    engine and the synchronous reference."""
+    slots), every request's greedy tokens identical to its solo generate —
+    with prompts prefilled a page a step and in one chunk of the context."""
     cfg, pool, solo = paged_pool
     rs = np.random.RandomState(7)
     prompts = [rs.randint(1, cfg.vocab_size, size=rs.randint(3, 9)).tolist()
@@ -365,12 +352,10 @@ def test_paged_greedy_token_identical_to_contiguous(paged_pool, async_decode):
             outs[o.request_id] = o
         return outs
 
-    paged = run(_paged_engine(pool, async_decode=async_decode))
-    contiguous = run(ServingEngine(pool, async_decode=async_decode))
-    assert set(paged) == set(contiguous) == set(range(5))
+    paged = run(_paged_engine(pool, prefill_chunk_tokens=chunk))
+    assert set(paged) == set(range(5))
     for i, p in enumerate(prompts):
-        want = _solo_generate(solo, p, 4 + i)
-        assert list(contiguous[i].token_ids) == want
+        want = solo_generate(solo, p, 4 + i)
         assert list(paged[i].token_ids) == want, (
             f"request {i} diverged on the paged engine")
         assert paged[i].finish_reason == "length"
@@ -378,8 +363,7 @@ def test_paged_greedy_token_identical_to_contiguous(paged_pool, async_decode):
 
 def test_paged_sampled_parity_and_cobatch_independence(paged_pool):
     """Sampled paged decode draws the same per-request rng streams as
-    ``generate(request_ids=...)`` and the contiguous engine, independent of
-    co-batching."""
+    ``generate(request_ids=...)``, independent of co-batching."""
     cfg, pool, solo = paged_pool
     rs = np.random.RandomState(11)
     prompts = {rid: rs.randint(1, cfg.vocab_size, size=6).tolist()
@@ -398,21 +382,21 @@ def test_paged_sampled_parity_and_cobatch_independence(paged_pool):
     together = run([0, 1, 2])
     alone = run([1])
     assert together[1] == alone[1]
-    want = _solo_generate(solo, prompts[1], 5, temperature=0.9, rng=rng,
-                          request_ids=[1])
+    want = solo_generate(solo, prompts[1], 5, temperature=0.9, rng=rng,
+                         request_ids=[1])
     assert together[1] == want
 
 
 def test_prefix_hit_skips_prefill_work(paged_pool):
     """A repeated prompt's admission reuses the cached chain: no
-    ``prefill_one`` call (counted on the fault-point plane — the
+    prefill chunk (counted on the fault-point plane — the
     serving/prefill_logits perturb point never fires for it), the
     prefill-skipped counter ticks, and the output stays token-identical."""
     cfg, pool, solo = paged_pool
     prompt = [3, 1, 4, 1, 5, 9]
     engine = _paged_engine(pool)
     # count every prefill through the fault plane: an unlimited zero-sleep
-    # spec fires (and records) once per prefill_one perturb call
+    # spec fires (and records) once per prefill's perturb call
     install_plan({"faults": [{"point": "serving/prefill_logits",
                               "action": "sleep", "seconds": 0, "count": 0}]})
     try:
@@ -427,7 +411,7 @@ def test_prefix_hit_skips_prefill_work(paged_pool):
             "cached-prefix admission still ran prefill")
     finally:
         clear_plan()
-    want = _solo_generate(solo, prompt, 4)
+    want = solo_generate(solo, prompt, 4)
     assert list(o1.token_ids) == list(o2.token_ids) == want
     snap = engine.registry.snapshot()
     assert snap["kvcache/prefill_skipped_total"] == 1.0
@@ -452,7 +436,7 @@ def test_paged_eviction_under_pool_pressure(paged_pool):
             for o in engine.run_until_complete(max_steps=400)}
     assert set(outs) == set(range(4))
     for i, p in enumerate(prompts):
-        assert list(outs[i].token_ids) == _solo_generate(solo, p, 3)
+        assert list(outs[i].token_ids) == solo_generate(solo, p, 3)
     snap = engine.registry.snapshot()
     assert snap["kvcache/evictions_total"] >= 1.0
     engine._kv.assert_invariants()
@@ -503,10 +487,83 @@ def test_poisoned_prefill_never_enters_prefix_cache(paged_pool):
     engine.submit(Request(request_id=1, prompt_ids=prompt, max_new_tokens=4))
     [o1] = engine.run_until_complete(max_steps=100)
     assert o1.state == "finished"
-    assert list(o1.token_ids) == _solo_generate(solo, prompt, 4)
+    assert list(o1.token_ids) == solo_generate(solo, prompt, 4)
     snap = engine.registry.snapshot()
     assert snap["kvcache/prefill_skipped_total"] == 0.0, (
         "the poisoned chain was cached and replayed")
+
+
+def test_default_pool_holds_every_slot_at_max_total_len(paged_pool):
+    """``num_pages`` left unset is a size worked out from the inputs, not a
+    mode: B * T / page pages and the NULL page, so every slot can hold
+    ``max_total_len`` at once and admission never waits for a page."""
+    cfg, pool, solo = paged_pool
+    engine = ServingEngine(pool, page_size=4)
+    assert engine._kv.pages_capacity() == 3 * 16 // 4
+    rs = np.random.RandomState(5)
+    prompts = [rs.randint(1, cfg.vocab_size, size=8).tolist()
+               for _ in range(3)]  # full context, distinct
+    for i, p in enumerate(prompts):  # each asks for all of max_total_len
+        engine.submit(Request(request_id=i, prompt_ids=p, max_new_tokens=8))
+    engine.step()
+    assert engine.scheduler.active_count == 3
+    assert engine._kv.alloc.free_count == 0
+    outs = {o.request_id: o
+            for o in engine.run_until_complete(max_steps=200)}
+    for i, p in enumerate(prompts):
+        assert list(outs[i].token_ids) == solo_generate(solo, p, 8)
+    engine._kv.assert_invariants()
+
+
+def test_identical_prompts_prefilling_at_once_share_one_registration(
+        paged_pool):
+    """Identical prompts admitted in one step each compute their own pages
+    (nothing is cached until a first prefill lands); the first to finish
+    registers its chain, the others keep their copies private — no chain
+    divergence, tokens identical, a later arrival hits the cache."""
+    cfg, pool, solo = paged_pool
+    prompt = [3, 1, 4, 1, 5, 9, 2]
+    engine = _paged_engine(pool, prefill_chunk_tokens=4)
+    for i in range(3):
+        engine.submit(Request(request_id=i, prompt_ids=prompt,
+                              max_new_tokens=3))
+    outs = {o.request_id: o
+            for o in engine.run_until_complete(max_steps=200)}
+    want = solo_generate(solo, prompt, 3)
+    assert all(list(outs[i].token_ids) == want for i in range(3))
+    engine._kv.assert_invariants()
+    assert engine._kv.alloc.in_use == 2  # ONE cached copy of two pages
+    engine.submit(Request(request_id=3, prompt_ids=prompt, max_new_tokens=3))
+    [late] = engine.run_until_complete(max_steps=100)
+    assert list(late.token_ids) == want
+    assert engine.registry.snapshot()["kvcache/prefill_skipped_total"] == 1.0
+
+
+def test_resident_chain_without_logits_recomputes_its_last_page(paged_pool):
+    """A chain that is wholly resident but carries no prefill logits (what a
+    preempted decode's ``park_resume`` registers after a weight swap flushed
+    the index) is not a prefill skip: the last prompt page is computed
+    again, in place, for its last row's logits — one chunk, same tokens."""
+    cfg, pool, solo = paged_pool
+    prompt = [3, 1, 4, 1, 5, 9]
+    engine = _paged_engine(pool)
+    engine.submit(Request(request_id=0, prompt_ids=prompt, max_new_tokens=4))
+    [first] = engine.run_until_complete(max_steps=100)
+    node = engine._kv.index._root
+    while node.children:
+        [node] = node.children.values()
+    assert node.payload is not None
+    node.payload = None
+    chunks = engine.registry.snapshot()["serving/prefill_chunks_total"]
+    engine.submit(Request(request_id=1, prompt_ids=prompt, max_new_tokens=4))
+    [second] = engine.run_until_complete(max_steps=100)
+    snap = engine.registry.snapshot()
+    assert snap["serving/prefill_chunks_total"] == chunks + 1
+    assert snap["kvcache/prefill_skipped_total"] == 0.0
+    assert list(first.token_ids) == list(second.token_ids) \
+        == solo_generate(solo, prompt, 4)
+    assert node.payload is not None  # the chain carries its logits again
+    engine._kv.assert_invariants()
 
 
 # -- chaos: exhaustion + mid-allocation crash -------------------------------
@@ -542,7 +599,7 @@ def test_pool_exhaustion_is_retryable_backpressure(paged_pool):
     engine.submit(req(2))  # the rejection was transient
     [out2] = engine.run_until_complete(max_steps=300)
     assert out2.state == "finished"
-    assert list(out2.token_ids) == _solo_generate(solo, list(range(1, 9)), 4)
+    assert list(out2.token_ids) == solo_generate(solo, list(range(1, 9)), 4)
     engine._kv.assert_invariants()
     engine.scheduler.assert_invariants()
 
@@ -576,39 +633,9 @@ def test_paged_mid_allocation_crash_reclaims_pages(paged_pool):
     engine.submit(Request(request_id=1, prompt_ids=prompt, max_new_tokens=3))
     [out] = engine.run_until_complete(max_steps=100)
     assert out.state == "finished"
-    assert list(out.token_ids) == _solo_generate(solo, prompt, 3)
+    assert list(out.token_ids) == solo_generate(solo, prompt, 3)
     kv.assert_invariants()
     engine.scheduler.assert_invariants()
-
-
-# -- CLI: serve_bench --paged ----------------------------------------------
-
-def test_serve_bench_paged_tiny_cli():
-    """Acceptance bar: the paged rung sustains strictly more concurrent
-    requests than contiguous at the same simulated HBM budget, and reports
-    a prefix-hit rate."""
-    import os
-
-    from conftest import run_cli
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = run_cli(
-        os.path.join(repo, "tools", "serve_bench.py"),
-        "--tiny", "--paged", "--batch-size", "2", "--context-len", "32",
-        "--max-total-len", "64", "--page-size", "8", "--num-requests", "8",
-        "--max-new-tokens", "4")
-    recs = [json.loads(line) for line in proc.stdout.strip().splitlines()
-            if line.strip().startswith("{")]
-    by_mode = {r["mode"]: r for r in recs if r.get("metric") == "serving_paged"}
-    assert set(by_mode) == {"contiguous", "paged"}
-    cont, paged = by_mode["contiguous"], by_mode["paged"]
-    assert cont["hbm_budget_pages"] == paged["hbm_budget_pages"]
-    assert paged["max_concurrent"] > cont["max_concurrent"], (
-        "paged must sustain strictly more concurrency at the same budget")
-    assert paged["finished"] == cont["finished"] == 8
-    assert paged["prefix_hit_rate"] and paged["prefix_hit_rate"] > 0
-    assert paged["ttft_ms"]["p50"] is not None
-    assert paged["goodput_tok_s"] > 0
 
 
 # -- runner serve --page-size ----------------------------------------------

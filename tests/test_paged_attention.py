@@ -20,7 +20,7 @@ mode on CPU, like the flash-attention interpret tests):
   the speculative verify chunk rides the same kernel, and a churn run
   leaks zero pages.
 
-The serve_bench --paged-kernel / flash_autotune --paged CLI rungs are
+The flash_autotune --paged CLI rung is
 marked slow to stay out of tier-1; everything here also carries the
 ``paged_kernel`` marker.
 """
@@ -338,11 +338,12 @@ def _run_staggered(engine, prompts, max_new=4):
     return {i: list(o.token_ids) for i, o in outs.items()}
 
 
-@pytest.mark.parametrize("async_decode", [True, False])
-def test_llama_engine_token_identical_kernel_on_off(llama_pool, async_decode):
+@pytest.mark.parametrize("chunk", [4, 8])
+def test_llama_engine_token_identical_kernel_on_off(llama_pool, chunk):
     """Acceptance bar: staggered arrivals + slot reuse (5 requests over 3
-    slots), kernel-on outputs token-identical to kernel-off, async and
-    sync — and the gather-bytes counter separates the two paths."""
+    slots), kernel-on outputs token-identical to kernel-off, with prompts
+    prefilled a page a step and in one chunk of the context — and the
+    gather-bytes counter separates the two paths."""
     cfg, pool = llama_pool
     rs = np.random.RandomState(7)
     prompts = [rs.randint(1, cfg.vocab_size, size=rs.randint(3, 9)).tolist()
@@ -351,7 +352,7 @@ def test_llama_engine_token_identical_kernel_on_off(llama_pool, async_decode):
     engines = {}
     for pk in (False, True):
         engines[pk] = ServingEngine(pool, page_size=4, num_pages=16,
-                                    async_decode=async_decode,
+                                    prefill_chunk_tokens=chunk,
                                     paged_kernel=pk)
     off = _run_staggered(engines[False], prompts)
     on = _run_staggered(engines[True], prompts)
@@ -514,8 +515,8 @@ def test_kernel_churn_leaks_zero_pages(llama_pool):
     assert engine.registry.snapshot().get(GATHER_BYTES, 0) == 0
 
 
-@pytest.mark.parametrize("async_decode", [True, False])
-def test_walk_counters_count_from_host_offsets(llama_pool, async_decode):
+@pytest.mark.parametrize("chunk", [4, 8])
+def test_walk_counters_count_from_host_offsets(llama_pool, chunk):
     """``serving/paged_pages_walked_total`` / ``_tabled_total``: one request
     of 6 prompt tokens in a row of C = 8 (2 pad keys), pages of 4, 3 slots x
     4 pages a slot.  Decode i runs at offset 8 + i over keys [2, 8 + i]:
@@ -526,7 +527,7 @@ def test_walk_counters_count_from_host_offsets(llama_pool, async_decode):
 
     def run(pk):
         engine = ServingEngine(pool, page_size=4, num_pages=16,
-                               async_decode=async_decode, paged_kernel=pk)
+                               prefill_chunk_tokens=chunk, paged_kernel=pk)
         engine.submit(Request(request_id=0, prompt_ids=[3, 1, 4, 1, 5, 9],
                               max_new_tokens=7))
         [out] = engine.run_until_complete(max_steps=100)
@@ -544,39 +545,15 @@ def test_walk_counters_count_from_host_offsets(llama_pool, async_decode):
     assert "serving/paged_pages_walked_total" not in off
 
 
-def test_paged_kernel_requires_paged_mode(llama_pool):
-    """paged_kernel=True without page_size/num_pages is a loud error — the
-    kernel walks block tables."""
+def test_engine_requires_page_size(llama_pool):
+    """There is no engine without a page pool: leaving ``page_size`` out is
+    a loud error, whatever else is asked for."""
     _, pool = llama_pool
-    with pytest.raises(ValueError, match="paged_kernel"):
+    with pytest.raises(TypeError, match="page_size"):
         ServingEngine(pool, paged_kernel=True)
 
 
 # -- CLI rungs (slow tier) --------------------------------------------------
-
-
-@pytest.mark.slow
-def test_serve_bench_paged_kernel_tiny_cli():
-    """`serve_bench --paged-kernel --tiny` emits one JSON line per
-    (T, mode) plus the gate line, and the flat-in-T rc gate passes on the
-    bytes-moved model."""
-    proc = subprocess.run(
-        [sys.executable, "tools/serve_bench.py", "--tiny", "--paged-kernel",
-         "--kernel-steps", "2"],
-        capture_output=True, text=True, timeout=900, cwd=".",
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [json.loads(ln) for ln in proc.stdout.splitlines()
-             if ln.strip().startswith("{")]
-    rungs = [r for r in lines if r.get("metric") == "serving_paged_kernel"]
-    gate = [r for r in lines if r.get("metric") == "serving_paged_kernel_gate"]
-    assert len(rungs) == 6  # 3 lengths x {gather, kernel}
-    assert {r["mode"] for r in rungs} == {"gather", "kernel"}
-    assert gate and gate[0]["rc"] == 0
-    kernel_bytes = {r["step_bytes"] for r in rungs if r["mode"] == "kernel"}
-    assert len(kernel_bytes) == 1, "kernel bytes must be flat in T"
-    gather_bytes = [r["step_bytes"] for r in rungs if r["mode"] == "gather"]
-    assert sorted(gather_bytes) == gather_bytes and len(set(gather_bytes)) == 3
 
 
 @pytest.mark.slow

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import sharded_params
+from conftest import sharded_params, solo_generate, step_until_decoding
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from neuronx_distributed_tpu.parallel.mesh import initialize_model_parallel
 from neuronx_distributed_tpu.resilience import clear_plan, install_plan
@@ -203,17 +203,7 @@ def served_pool(devices8):
     return cfg, pool, solo
 
 
-def _solo_generate(solo, prompt_ids, max_new, **kw):
-    C = solo.config.context_len
-    L = len(prompt_ids)
-    ids = np.zeros((1, C), np.int32)
-    ids[0, C - L:] = prompt_ids
-    out = solo.generate(jnp.asarray(ids), max_new,
-                        prompt_lens=jnp.asarray([L]), **kw)
-    return [int(t) for t in np.asarray(out)[0, C:]]
-
-
-def test_continuous_greedy_matches_solo_generate(served_pool, tmp_path):
+def test_continuous_greedy_matchessolo_generate(served_pool, tmp_path):
     """Acceptance bar: staggered arrivals, slot reuse (5 requests over 3
     slots), every request's greedy tokens identical to its solo generate."""
     cfg, pool, solo = served_pool
@@ -221,7 +211,7 @@ def test_continuous_greedy_matches_solo_generate(served_pool, tmp_path):
     prompts = [rs.randint(1, cfg.vocab_size, size=rs.randint(3, 9)).tolist()
                for _ in range(5)]
     stats_path = str(tmp_path / "serving_stats.jsonl")
-    engine = ServingEngine(pool, stats_path=stats_path)
+    engine = ServingEngine(pool, page_size=4, stats_path=stats_path)
 
     streamed = {}
     outs = {}
@@ -243,7 +233,7 @@ def test_continuous_greedy_matches_solo_generate(served_pool, tmp_path):
 
     assert set(outs) == set(range(5))
     for i, p in enumerate(prompts):
-        want = _solo_generate(solo, p, 4 + i)
+        want = solo_generate(solo, p, 4 + i)
         got = list(outs[i].token_ids)
         assert got == want, f"request {i} diverged: {got} vs solo {want}"
         assert streamed[i] == want  # streaming callback saw every token
@@ -277,7 +267,7 @@ def test_continuous_sampled_reproducible_across_cobatching(served_pool):
     sampling = SamplingParams(temperature=0.9, top_k=0, top_p=1.0)
 
     def run(rids):
-        engine = ServingEngine(pool, rng=rng)
+        engine = ServingEngine(pool, page_size=4, rng=rng)
         for rid in rids:
             engine.submit(Request(request_id=rid, prompt_ids=prompts[rid],
                                   max_new_tokens=5, sampling=sampling))
@@ -290,7 +280,7 @@ def test_continuous_sampled_reproducible_across_cobatching(served_pool):
         "request 1's sampled tokens changed with its co-batch")
 
     # and the engine's stream equals generate(request_ids=...)'s
-    want = _solo_generate(
+    want = solo_generate(
         solo, prompts[1], 5, temperature=0.9, rng=rng, request_ids=[1])
     assert together[1] == want
 
@@ -298,7 +288,7 @@ def test_continuous_sampled_reproducible_across_cobatching(served_pool):
 def test_engine_cancellation_and_timeout(served_pool):
     cfg, pool, _ = served_pool
     t = [0.0]
-    engine = ServingEngine(pool, clock=lambda: t[0])
+    engine = ServingEngine(pool, page_size=4, clock=lambda: t[0])
     # 3 slots: r0 decodes, r1 will be cancelled mid-decode, r2 times out
     # in the queue (deadline passes before any slot frees... force by
     # filling slots first)
@@ -335,42 +325,19 @@ def test_stop_token_ends_request_early(served_pool):
     slot with finish_reason 'stop_token'."""
     cfg, pool, solo = served_pool
     prompt = [3, 1, 4, 1, 5]
-    first = _solo_generate(solo, prompt, 1)[0]
-    engine = ServingEngine(pool)
+    first = solo_generate(solo, prompt, 1)[0]
+    engine = ServingEngine(pool, page_size=4)
     engine.submit(Request(request_id=0, prompt_ids=prompt, max_new_tokens=8,
                           stop_token_ids=(first,)))
     [out] = engine.run_until_complete(max_steps=50)
     assert out.finish_reason == "stop_token"
     assert list(out.token_ids) == [first]
     # engine-level eos_token_id behaves the same without per-request config
-    engine2 = ServingEngine(pool, eos_token_id=first)
+    engine2 = ServingEngine(pool, page_size=4, eos_token_id=first)
     engine2.submit(Request(request_id=1, prompt_ids=prompt, max_new_tokens=8))
     [out2] = engine2.run_until_complete(max_steps=50)
     assert out2.finish_reason == "stop_token"
     assert list(out2.token_ids) == [first]
-
-
-def test_serve_bench_continuous_tiny_cli(tmp_path):
-    """Acceptance bar: `tools/serve_bench.py --continuous --tiny` runs clean
-    on CPU and leaves a schema-valid serving_stats.jsonl."""
-    import os
-
-    from conftest import last_json_line, run_cli
-    from neuronx_distributed_tpu.obs.schemas import validate_jsonl
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    stats = str(tmp_path / "serving_stats.jsonl")
-    proc = run_cli(
-        os.path.join(repo, "tools", "serve_bench.py"),
-        "--tiny", "--continuous", "--context-len", "16",
-        "--max-total-len", "32", "--num-requests", "4",
-        "--max-new-tokens", "4", "--stats-out", stats)
-    rec = last_json_line(proc.stdout)
-    assert rec["metric"] == "serving_continuous"
-    assert rec["finished"] == 4 and rec["stats_records"] == 4
-    assert rec["goodput_tok_s"] > 0 and rec["static_tok_s"] > 0
-    assert rec["ttft_ms"]["p50"] is not None
-    assert validate_jsonl("serving_stats", stats) == 4
 
 
 # -- hardening (resilience PR) ----------------------------------------------
@@ -410,7 +377,7 @@ def test_scheduler_backpressure_bounds_excess_backlog():
 
 def test_engine_backpressure_counts_rejections(served_pool):
     cfg, pool, _ = served_pool
-    engine = ServingEngine(pool, max_queue=1)
+    engine = ServingEngine(pool, page_size=4, max_queue=1)
     for rid in range(4):  # B=3 slots + 1 backlog
         engine.submit(Request(request_id=rid, prompt_ids=[1, 2],
                               max_new_tokens=2))
@@ -429,11 +396,11 @@ def test_non_finite_logit_quarantine_decode(served_pool):
     rs = np.random.RandomState(3)
     prompts = [rs.randint(1, cfg.vocab_size, size=5).tolist()
                for _ in range(3)]
-    engine = ServingEngine(pool)
+    engine = ServingEngine(pool, page_size=4)
     for rid in range(3):
         engine.submit(Request(request_id=rid, prompt_ids=prompts[rid],
                               max_new_tokens=6))
-    engine.step()  # prefill all three; find request 1's slot
+    step_until_decoding(engine)  # all three; then find request 1's slot
     slot_of = {req.request_id: slot for slot, req in engine.scheduler.active()}
     install_plan({"faults": [{"point": "serving/decode_logits",
                               "action": "nan", "slot": slot_of[1]}]})
@@ -446,7 +413,7 @@ def test_non_finite_logit_quarantine_decode(served_pool):
     assert outs[1].finish_reason == "non_finite_logits"
     for rid in (0, 2):  # co-batch never saw the poison
         assert outs[rid].state == "finished"
-        assert list(outs[rid].token_ids) == _solo_generate(
+        assert list(outs[rid].token_ids) == solo_generate(
             solo, prompts[rid], 6)
     assert engine.registry.snapshot()["serving/failed_total"] == 1.0
     # the quarantined slot is reusable
@@ -454,7 +421,7 @@ def test_non_finite_logit_quarantine_decode(served_pool):
                           max_new_tokens=3))
     [out7] = engine.run_until_complete(max_steps=100)
     assert out7.state == "finished"
-    assert list(out7.token_ids) == _solo_generate(solo, prompts[0], 3)
+    assert list(out7.token_ids) == solo_generate(solo, prompts[0], 3)
     engine.scheduler.assert_invariants()
 
 
@@ -465,7 +432,7 @@ def test_non_finite_logit_quarantine_prefill(served_pool, tmp_path):
 
     cfg, pool, _ = served_pool
     stats = str(tmp_path / "serving_stats.jsonl")
-    engine = ServingEngine(pool, stats_path=stats)
+    engine = ServingEngine(pool, page_size=4, stats_path=stats)
     install_plan({"faults": [{"point": "serving/prefill_logits",
                               "action": "nan", "match": {"request_id": 0}}]})
     try:
@@ -494,7 +461,7 @@ def test_engine_step_watchdog_counts_slow_steps(served_pool):
         t[0] += 10.0
         return t[0]
 
-    engine = ServingEngine(pool, clock=clock, step_timeout_s=1.0)
+    engine = ServingEngine(pool, page_size=4, clock=clock, step_timeout_s=1.0)
     engine.submit(Request(request_id=0, prompt_ids=[1, 2], max_new_tokens=2))
     engine.run_until_complete(max_steps=50)
     snap = engine.registry.snapshot()
@@ -511,7 +478,7 @@ def test_replay_trace_dumps_flight_on_crash(served_pool, tmp_path):
 
     cfg, pool, _ = served_pool
     obs = Observability(str(tmp_path / "obs"))
-    engine = ServingEngine(pool, obs=obs)
+    engine = ServingEngine(pool, page_size=4, obs=obs)
 
     reqs = [
         Request(request_id=0, prompt_ids=[1, 2], max_new_tokens=3),
@@ -525,7 +492,7 @@ def test_replay_trace_dumps_flight_on_crash(served_pool, tmp_path):
     validate_flight_document(doc)
     assert doc["reason"] == "crash:RuntimeError"
     # engine steps record into the flight ring (queue/slots/step time)
-    engine2 = ServingEngine(pool, obs=obs)
+    engine2 = ServingEngine(pool, page_size=4, obs=obs)
     engine2.submit(Request(request_id=5, prompt_ids=[1], max_new_tokens=2))
     engine2.run_until_complete(max_steps=50)
     assert any("queue_depth" in r for r in obs.flight.records)

@@ -11,21 +11,17 @@ Three layers, mirroring the subsystem's split:
   and a randomized-churn run over a REAL ``PagedKVManager`` page gate
   asserting invariants after every op and zero page leaks;
 - PAGED CHUNKED PREFILL + engine e2e on the CPU tiny Llama — the
-  acceptance bar: chunked outputs token-identical to the whole-prefill
-  paged engine (greedy + sampled, sync + async, staggered arrivals,
-  prefix-cache hit and miss), preemption round-trips token-identical, the
+  acceptance bar: chunked outputs token-identical to solo ``generate``
+  at a one-page chunk and at the context's width (greedy + sampled,
+  staggered arrivals, prefix-cache hit and miss), preemption round-trips token-identical, the
   pre-dispatch expiry check (``serving/expired_before_prefill_total``)
-  firing for whole prefills AND mid-chunk, and a chaos rung: an
+  firing before a first chunk AND mid-chunk, and a chaos rung: an
   ``NXD_FAULT_PLAN`` kill mid-chunked-prefill reclaims every page and the
   request requeues cleanly;
 - the fleet requeue-deadline satellite: a crashed replica's requeued clone
   carries the ORIGINAL submission instant (absolute deadline through the
   crash) and an already-expired clone fails terminally as TIMED_OUT
   instead of burning a sibling's prefill.
-
-The ``serve_bench --slo`` CLI rung is ``slo`` + ``slow`` marked (out of
-tier-1); its latency gates are meaningful on silicon, so the CPU test
-asserts the rung's structure, not its timing.
 """
 
 import jax
@@ -33,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import last_json_line, run_cli, sharded_params
+from conftest import sharded_params, solo_generate
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from neuronx_distributed_tpu.obs import MetricRegistry
 from neuronx_distributed_tpu.parallel.mesh import initialize_model_parallel
@@ -342,16 +338,6 @@ def paged_pool(devices8):
     return cfg, pool, solo
 
 
-def _solo_generate(solo, prompt_ids, max_new, **kw):
-    C = solo.config.context_len
-    L = len(prompt_ids)
-    ids = np.zeros((1, C), np.int32)
-    ids[0, C - L:] = prompt_ids
-    out = solo.generate(jnp.asarray(ids), max_new,
-                        prompt_lens=jnp.asarray([L]), **kw)
-    return [int(t) for t in np.asarray(out)[0, C:]]
-
-
 def _run_staggered(engine, prompts, max_new=None, sampling=None, n_front=3):
     outs = {}
     for i in range(n_front):
@@ -373,41 +359,30 @@ def _run_staggered(engine, prompts, max_new=None, sampling=None, n_front=3):
     return {k: list(v.token_ids) for k, v in outs.items()}
 
 
-@pytest.mark.parametrize("async_decode,chunk", [
-    (True, 4),
-    # the remaining combinations stay out of tier-1 (each pair compiles
-    # and drives two engines); the full suite remains the gate
-    pytest.param(False, 4, marks=pytest.mark.slow),
-    pytest.param(True, 8, marks=pytest.mark.slow),
-    pytest.param(False, 8, marks=pytest.mark.slow),
-])
-def test_chunked_prefill_token_identical_to_whole(paged_pool, async_decode,
-                                                  chunk):
-    """Acceptance bar: paged chunked-prefill greedy outputs under staggered
-    arrivals + slot reuse are token-identical to the whole-prefill paged
-    engine and to solo generate, in the async and sync engines, at 1- and
-    2-page chunk budgets."""
+@pytest.mark.parametrize("chunk", [4, 8])
+def test_chunked_prefill_token_identical_to_solo_generate(paged_pool, chunk):
+    """Acceptance bar: chunked-prefill greedy outputs under staggered
+    arrivals + slot reuse are token-identical to solo generate, at a
+    1-page chunk budget and at the context's width (2 pages: a prompt in
+    ONE chunk, what the engine does when no width is named)."""
     cfg, pool, solo = paged_pool
     rs = np.random.RandomState(7)
     prompts = [rs.randint(1, cfg.vocab_size, size=rs.randint(3, 9)).tolist()
                for _ in range(5)]
-    whole = _run_staggered(
-        ServingEngine(pool, page_size=4, num_pages=16,
-                      async_decode=async_decode), prompts)
-    chunked = _run_staggered(
-        ServingEngine(pool, page_size=4, num_pages=16,
-                      async_decode=async_decode,
-                      prefill_chunk_tokens=chunk), prompts)
-    assert chunked == whole
+    engine = ServingEngine(pool, page_size=4, num_pages=16,
+                           prefill_chunk_tokens=chunk)
+    chunked = _run_staggered(engine, prompts)
     for i, p in enumerate(prompts):
-        assert chunked[i] == _solo_generate(solo, p, 4 + i)
+        assert chunked[i] == solo_generate(solo, p, 4 + i)
+    # no width named is one chunk of the context: same programs, same count
+    assert ServingEngine(pool, page_size=4)._chunk_tokens == 8
 
 
 @pytest.mark.slow
 def test_chunked_prefill_sampled_token_identical(paged_pool):
-    """Sampled chunked outputs equal the whole-prefill engine's (the
-    per-request rng streams are keyed on (rng, id, token index) — chunking
-    must not shift them)."""
+    """Sampled outputs at a 1-page chunk equal those at one chunk of the
+    context (the per-request rng streams are keyed on (rng, id, token
+    index) — chunking must not shift them)."""
     cfg, pool, _ = paged_pool
     rs = np.random.RandomState(11)
     prompts = [rs.randint(1, cfg.vocab_size, size=6).tolist()
@@ -442,7 +417,7 @@ def test_chunked_prefill_prefix_hit_skips_resident_chunks(paged_pool):
     assert snap["serving/prefill_chunks_total"] == chunks_before, (
         "a full prefix hit must not burn prefill chunks")
     assert snap["kvcache/prefill_skipped_total"] == 1.0
-    want = _solo_generate(solo, prompt, 3)
+    want = solo_generate(solo, prompt, 3)
     assert list(first.token_ids) == list(second.token_ids) == want
 
 
@@ -455,7 +430,7 @@ def test_decodes_tick_while_long_prompt_chunks(paged_pool):
     short = rs.randint(1, cfg.vocab_size, size=3).tolist()
     long_p = rs.randint(1, cfg.vocab_size, size=8).tolist()  # full width
     engine = ServingEngine(pool, page_size=4, num_pages=16,
-                           prefill_chunk_tokens=4, async_decode=False)
+                           prefill_chunk_tokens=4)
     engine.submit(Request(request_id=0, prompt_ids=short, max_new_tokens=8))
     engine.step()  # short decodes from here on
     engine.submit(Request(request_id=1, prompt_ids=long_p, max_new_tokens=2,
@@ -472,8 +447,8 @@ def test_decodes_tick_while_long_prompt_chunks(paged_pool):
         "co-batched decode stalled during a chunked prefill")
     for o in engine.run_until_complete(max_steps=200):
         outs[o.request_id] = o
-    assert list(outs[0].token_ids) == _solo_generate(solo, short, 8)
-    assert list(outs[1].token_ids) == _solo_generate(solo, long_p, 2)
+    assert list(outs[0].token_ids) == solo_generate(solo, short, 8)
+    assert list(outs[1].token_ids) == solo_generate(solo, long_p, 2)
 
 
 def test_preemption_e2e_token_identical_and_no_leak(paged_pool):
@@ -502,7 +477,7 @@ def test_preemption_e2e_token_identical_and_no_leak(paged_pool):
     assert preempted and all(o.priority == "batch" for o in preempted)
     for i in range(4):
         n = 3 if i == 3 else 8
-        assert list(outs[i].token_ids) == _solo_generate(
+        assert list(outs[i].token_ids) == solo_generate(
             solo, prompts[i], n), f"request {i} diverged after preemption"
     engine._kv.assert_invariants()
     evictable = (engine._kv.index.evictable_pages()
@@ -597,7 +572,7 @@ def test_chaos_kill_mid_chunked_prefill_reclaims_and_requeues(paged_pool):
     engine.submit(Request(request_id=1, prompt_ids=prompt, max_new_tokens=3))
     [out] = engine.run_until_complete(max_steps=100)
     assert out.state == "finished"
-    assert list(out.token_ids) == _solo_generate(solo, prompt, 3)
+    assert list(out.token_ids) == solo_generate(solo, prompt, 3)
 
 
 def test_serving_stats_v4_fields_emitted(paged_pool, tmp_path):
@@ -623,8 +598,8 @@ def test_serving_stats_v4_fields_emitted(paged_pool, tmp_path):
     assert rec["preemptions"] == 0 and rec["shed_reason"] is None
     assert rec["queue_wait_ms"] == rec["queue_ms"]
     # knob validation (same fixture, no extra AOT compile): chunking needs
-    # the paged engine, page-aligned budgets, and a known priority class
-    with pytest.raises(ValueError, match="paged engine"):
+    # a page size, page-aligned budgets, and a known priority class
+    with pytest.raises(TypeError, match="page_size"):
         ServingEngine(pool, prefill_chunk_tokens=4)
     with pytest.raises(ValueError, match="multiple of page_size"):
         ServingEngine(pool, page_size=4, num_pages=16,
@@ -688,41 +663,3 @@ def test_fleet_expired_clone_fails_terminally_as_timed_out():
     router.assert_invariants()
     assert router.inflight == 0
 
-
-# -- CLI rung (out of tier-1) ------------------------------------------------
-
-@pytest.mark.slow
-def test_serve_bench_slo_tiny_cli():
-    """`serve_bench --slo --tiny` runs the three rungs end to end and
-    emits one structurally-sound JSON line each.  The 2x latency gates are
-    sized for silicon; on the CPU tiny model
-    the timing is noise-dominated, so this asserts structure — all three
-    modes emitted, every request finished, the SLO engine actually chunked
-    — not the rc."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "serve_bench.py"),
-         "--tiny", "--slo", "--context-len", "64", "--max-total-len", "96",
-         "--page-size", "8", "--slo-chunk", "8", "--num-requests", "8",
-         "--slo-long", "2", "--max-new-tokens", "4", "--arrival-rate", "40"],
-        capture_output=True, text=True, timeout=590, env=env)
-    assert proc.returncode in (0, 1), proc.stderr[-2000:]
-    recs = [json.loads(l) for l in proc.stdout.splitlines()
-            if l.startswith("{")]
-    by_mode = {r["mode"]: r for r in recs}
-    assert set(by_mode) == {"baseline", "control", "slo"}
-    assert all(r["metric"] == "serving_slo" for r in recs)
-    assert by_mode["baseline"]["finished"] == 8
-    assert by_mode["control"]["finished"] == 10
-    assert by_mode["slo"]["finished"] == 10
-    assert by_mode["slo"]["prefill_chunks"] > 0
-    assert by_mode["control"]["prefill_chunks"] == 0
-    for r in recs:
-        assert r["interactive_intertoken_ms"]["p99"] is not None

@@ -21,8 +21,7 @@ Three layers, mirroring the subsystem's guarantees:
   widened serving phase-fn cache absorbs the draft/verify programs with
   ZERO ``trace/compiled_cache_evictions_total``.
 
-The heavier k-sweep CLI rung (``serve_bench --spec``) is marked slow to
-stay out of tier-1; everything here also carries the ``spec`` marker.
+Everything here carries the ``spec`` marker.
 """
 
 import numpy as np
@@ -31,7 +30,13 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from conftest import last_json_line, run_cli, sharded_params
+from conftest import (
+    last_json_line,
+    run_cli,
+    sharded_params,
+    solo_generate,
+    step_until_decoding,
+)
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from neuronx_distributed_tpu.parallel.mesh import initialize_model_parallel
 from neuronx_distributed_tpu.resilience import clear_plan, install_plan
@@ -172,16 +177,6 @@ def spec_pool(devices8):
 PAGED_KW = dict(page_size=4, num_pages=40)
 
 
-def _solo_generate(solo, prompt_ids, max_new, **kw):
-    C = solo.config.context_len
-    L = len(prompt_ids)
-    ids = np.zeros((1, C), np.int32)
-    ids[0, C - L:] = prompt_ids
-    out = solo.generate(jnp.asarray(ids), max_new,
-                        prompt_lens=jnp.asarray([L]), **kw)
-    return [int(t) for t in np.asarray(out)[0, C:]]
-
-
 def _run_staggered(engine, prompts, temps=None, max_new=None, streamed=None):
     """3 requests up front, 2 more after the first step (slot reuse)."""
     outs = {}
@@ -217,9 +212,10 @@ def _assert_no_page_state(engine):
 
 def test_spec_greedy_matches_nonspec_engine(spec_pool, tmp_path):
     """Acceptance bar: greedy speculative output token-identical to the
-    non-speculative paged engine AND solo generate — staggered arrivals,
-    slot reuse, self AND adversarial drafts, async and sync — with zero
-    compiled-cache evictions and zero page leaks."""
+    non-speculative engine AND solo generate — staggered arrivals, slot
+    reuse, self AND adversarial drafts, prompts prefilled a page a step and
+    in one chunk of the context — with zero compiled-cache evictions and
+    zero page leaks."""
     cfg, pool, solo, draft_other = spec_pool
     rs = np.random.RandomState(7)
     prompts = [rs.randint(1, cfg.vocab_size, size=rs.randint(3, 9)).tolist()
@@ -229,16 +225,16 @@ def test_spec_greedy_matches_nonspec_engine(spec_pool, tmp_path):
     base = _run_staggered(base_engine, prompts)
 
     for draft, exp_full_accept in ((pool, True), (draft_other, False)):
-        for async_decode in (True, False):
+        for chunk in (4, 8):
             streamed = {}
-            stats = str(tmp_path / f"stats_{exp_full_accept}_{async_decode}.jsonl")
+            stats = str(tmp_path / f"stats_{exp_full_accept}_{chunk}.jsonl")
             engine = ServingEngine(pool, draft=draft, spec_k=3,
-                                   async_decode=async_decode,
+                                   prefill_chunk_tokens=chunk,
                                    stats_path=stats, **PAGED_KW)
             outs = _run_staggered(engine, prompts, streamed=streamed)
             engine.close()
             for i, p in enumerate(prompts):
-                want = _solo_generate(solo, p, 4 + i)
+                want = solo_generate(solo, p, 4 + i)
                 assert list(outs[i].token_ids) == want \
                     == list(base[i].token_ids), f"request {i} diverged"
                 assert streamed[i] == want  # streaming saw every token once
@@ -319,7 +315,7 @@ def test_spec_stop_token_inside_accepted_run(spec_pool):
     engine — and reclaims its pages immediately."""
     cfg, pool, solo, _ = spec_pool
     prompt = [3, 1, 4, 1, 5]
-    full = _solo_generate(solo, prompt, 8)
+    full = solo_generate(solo, prompt, 8)
     eos = full[2]  # stop mid-run: spec commits 3+ tokens per round here
 
     def run(**kw):
@@ -347,12 +343,13 @@ def test_spec_mid_verify_fault_quarantines_without_leaks(spec_pool):
     prompts = [rs.randint(1, cfg.vocab_size, size=5).tolist()
                for _ in range(3)]
     engine = ServingEngine(pool, draft=pool, spec_k=3, **PAGED_KW)
+    for rid in range(2):
+        engine.submit(Request(request_id=rid, prompt_ids=prompts[rid],
+                              max_new_tokens=12))
+    step_until_decoding(engine)  # both, before the ONE poisoned round
     install_plan({"faults": [{"point": "serving/verify_logits",
                               "action": "nan"}]})
     try:
-        for rid in range(2):
-            engine.submit(Request(request_id=rid, prompt_ids=prompts[rid],
-                                  max_new_tokens=6))
         outs = {o.request_id: o
                 for o in engine.run_until_complete(max_steps=200)}
     finally:
@@ -365,7 +362,7 @@ def test_spec_mid_verify_fault_quarantines_without_leaks(spec_pool):
     engine.submit(Request(request_id=7, prompt_ids=prompts[2],
                           max_new_tokens=4))
     [out] = engine.run_until_complete(max_steps=100)
-    assert list(out.token_ids) == _solo_generate(solo, prompts[2], 4)
+    assert list(out.token_ids) == solo_generate(solo, prompts[2], 4)
     _assert_no_page_state(engine)
 
 
@@ -390,7 +387,7 @@ def test_spec_envelope_and_constructor_validation(spec_pool):
         ServingEngine(pool, draft=pool, **PAGED_KW)
     with pytest.raises(ValueError, match="BOTH draft= and spec_k="):
         ServingEngine(pool, spec_k=2, **PAGED_KW)
-    with pytest.raises(ValueError, match="paged KV cache"):
+    with pytest.raises(TypeError, match="page_size"):
         ServingEngine(pool, draft=pool, spec_k=2)
     with pytest.raises(ValueError, match="serving shapes differ"):
         ServingEngine(pool, draft=solo, spec_k=2, **PAGED_KW)
@@ -421,28 +418,3 @@ def test_runner_serve_spec_cli(tmp_path):
     recs = [_json.loads(l) for l in open(stats)]
     assert all(r["acceptance_rate"] == 1.0 for r in recs)
 
-
-# -- CLI rung (slow: compiles its own models, sweeps k) ---------------------
-
-@pytest.mark.slow
-def test_serve_bench_spec_tiny_cli():
-    """`serve_bench --spec --tiny`: one JSON line per rung; every spec rung
-    must be token-identical to the paged baseline and (k >= 2, draft ==
-    target) commit > 1 token/step — rc 1 otherwise, which run_cli asserts
-    against."""
-    import json as _json
-    import os
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = run_cli(os.path.join(repo, "tools", "serve_bench.py"),
-                   "--tiny", "--spec", "--spec-ks", "2,3",
-                   "--batch-size", "2", "--context-len", "16",
-                   "--max-total-len", "64", "--max-new-tokens", "6",
-                   "--num-requests", "4", "--page-size", "8")
-    lines = [_json.loads(l) for l in proc.stdout.strip().splitlines()
-             if l.startswith("{")]
-    assert [r["mode"] for r in lines] == ["baseline", "spec", "spec"]
-    for rec in lines[1:]:
-        assert rec["identical_to_baseline"] is True
-        assert rec["acceptance_rate"] == 1.0
-        assert rec["tokens_per_step"] > 1.0

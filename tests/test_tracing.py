@@ -479,30 +479,6 @@ def test_fleet_failover_clone_stitches_one_trace(paged_pool, tmp_path):
 # -- CLI rungs (out of tier-1) -----------------------------------------------
 
 @pytest.mark.slow
-def test_serve_bench_trace_out_cli(tmp_path):
-    import os
-    import sys
-
-    REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out_dir = str(tmp_path / "traces")
-    proc = run_cli(os.path.join(REPO, "tools", "serve_bench.py"),
-                   "--tiny", "--continuous", "--num-requests", "4",
-                   "--max-new-tokens", "4", "--trace-out", out_dir)
-    rec = [json.loads(l) for l in proc.stdout.strip().splitlines()
-           if l.startswith("{")][-1]
-    assert rec["trace_events"].endswith("continuous.trace_events.jsonl")
-    assert validate_jsonl("trace_event", rec["trace_events"]) > 0
-    assert os.path.exists(rec["trace_perfetto"])
-    # the waterfall section renders from the dropped artifacts
-    trace = summarize_trace([rec["trace_events"]],
-                            read_serving_stats(rec["stats_path"]))
-    assert trace is not None and trace["requests"] == 4
-    assert all(e.get("stats_total_ms") is not None
-               for e in trace["slowest"])
-    sys.stdout.write(f"trace rung ok: {trace['spans']} spans\n")
-
-
-@pytest.mark.slow
 def test_runner_serve_trace_and_metrics_cli(tmp_path):
     import os
 

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import sharded_params
+from conftest import sharded_params, solo_generate
 from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from neuronx_distributed_tpu.obs import CompileLedger, MetricRegistry
 from neuronx_distributed_tpu.obs.schemas import validate_jsonl, validate_record
@@ -81,16 +81,6 @@ def swap_rig():
     return cfg, pool, params1, solo0, solo1
 
 
-def _solo_generate(solo, prompt_ids, max_new):
-    C = solo.config.context_len
-    L = len(prompt_ids)
-    ids = np.zeros((1, C), np.int32)
-    ids[0, C - L:] = prompt_ids
-    out = solo.generate(jnp.asarray(ids), max_new,
-                        prompt_lens=jnp.asarray([L]))
-    return [int(t) for t in np.asarray(out)[0, C:]]
-
-
 def _serve_one(engine, rid, prompt_ids, max_new=4):
     engine.submit(Request(request_id=rid, prompt_ids=prompt_ids,
                           max_new_tokens=max_new))
@@ -120,7 +110,7 @@ def test_live_swap_zero_compiles_and_exact_version_boundary(swap_rig, tmp_path):
     swapper = WeightSwapper(engine, path=swaps_path)
 
     before = _serve_one(engine, 0, prompt)
-    assert list(before.token_ids) == _solo_generate(solo0, prompt, 4)
+    assert list(before.token_ids) == solo_generate(solo0, prompt, 4)
     assert before.weights_version == 0
     engine.declare_warmup_done()
 
@@ -133,7 +123,7 @@ def test_live_swap_zero_compiles_and_exact_version_boundary(swap_rig, tmp_path):
     after = _serve_one(engine, 1, prompt)
     assert ledger.compile_count(after_warmup_only=True) == 0
     assert after.weights_version == 1
-    assert list(after.token_ids) == _solo_generate(solo1, prompt, 4), (
+    assert list(after.token_ids) == solo_generate(solo1, prompt, 4), (
         "post-swap output must come from the NEW weights")
     assert list(after.token_ids) != list(before.token_ids), (
         "the rig's two param sets must disagree for the boundary to mean "
@@ -185,7 +175,7 @@ def test_envelope_mismatches_refused_with_old_weights_serving(swap_rig):
     assert engine.weights_version == 0
     out = _serve_one(engine, 0, prompt)
     assert out.weights_version == 0
-    assert list(out.token_ids) == _solo_generate(solo0, prompt, 4)
+    assert list(out.token_ids) == solo_generate(solo0, prompt, 4)
     assert engine.registry.snapshot()["weights/swap_failures_total"] == 3.0
     engine.close()
 
@@ -213,11 +203,11 @@ def test_pre_swap_chaos_fault_is_transactional(swap_rig, tmp_path):
         clear_plan()
     assert engine.weights_version == 0
     assert list(_serve_one(engine, 0, prompt).token_ids) == \
-        _solo_generate(solo0, prompt, 4)
+        solo_generate(solo0, prompt, 4)
 
     assert swapper.swap(params1, source="memory") == 1
     assert list(_serve_one(engine, 1, prompt).token_ids) == \
-        _solo_generate(solo1, prompt, 4)
+        solo_generate(solo1, prompt, 4)
     engine.close()
     swapper.close()
 
@@ -246,7 +236,7 @@ def test_memory_swap_survives_donated_source_buffers(swap_rig):
         leaf.delete()  # what donation does to the trainer's old pytree
     out = _serve_one(engine, 0, prompt)
     assert out.weights_version == 1
-    assert list(out.token_ids) == _solo_generate(solo1, prompt, 4)
+    assert list(out.token_ids) == solo_generate(solo1, prompt, 4)
     engine.close()
 
 

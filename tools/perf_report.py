@@ -2,7 +2,7 @@
 
 Reads one or more ``perf_attribution.jsonl`` streams (written by a run
 with the perf profiler on: ``Observability(perf=True)`` for ``fit()``,
-``serve_bench --profile-out`` for the serving rungs) and answers the
+``ServingEngine(perf=...)`` for a serving run) and answers the
 three bottleneck questions from the artifact alone:
 
 - **top time-eaters** — families ranked by accounted device time;
