@@ -15,8 +15,10 @@ window 4096 — only depth is cut, and the cut is printed):
   ``mha_reference``, and ``paged_attention`` (decode, verify, chunk; fp and
   int8 pages; window on; then the three serving cells' head layouts — 7, 4
   and 1 query heads a kv head — with two thirds of the slots parked and the
-  rest left-padded) against ``paged_attention_reference`` — compiled, with
-  the Mosaic call asserted in each program;
+  rest left-padded, the step's K / V rows first written into the pools by
+  ``ops.kv_pool_write``'s kernel and the fetched pools held to a numpy
+  write, bit for bit) against ``paged_attention_reference`` — compiled,
+  with the Mosaic call asserted in each program;
 - **train**   — ``initialize_model_parallel`` → ``training_config`` →
   ``initialize_parallel_model`` → ``initialize_parallel_optimizer`` →
   ``make_train_step``, driven by ``trainer.fit()`` with flash attention,
@@ -29,7 +31,9 @@ window 4096 — only depth is cut, and the cut is printed):
 
 ``--four-chips`` runs ONLY the path that exists across chips and what it is
 compared with: a tp=4 mesh (sequence parallel on) over four real devices —
-train steps and paged requests through the ``shard_map``'d kernel — then a
+train steps, the pool write at the cells' head layouts with the kv heads
+over tp (bit for bit against numpy) and paged requests through the
+``shard_map``'d kernels — then a
 dp=2 x tp=2 ZeRO-1 step, then the same seeded model on a one-device mesh in
 the same process, whose step-0 loss and first-decode logits must agree.
 
@@ -170,6 +174,86 @@ def compiled_with_kernel(fn, *args, on_tpu):
         raise AssertionError("no Mosaic tpu_custom_call in the compiled "
                              "program: the kernel was interpreted or replaced")
     return compiled
+
+
+def paged_cell_cases(size, seed):
+    """The serving cells' head layouts as the engine presents them, for a
+    decode (S = 1) and a verify chunk (S = 5): two slots in three parked,
+    the live ones behind a left pad, each holding pages of its own — every
+    other page of the pool is NaN, so a walk or a write that strays off a
+    slot's band cannot pass.  Yields ``(layout, S, win, q, new (k, v), clean
+    pools, pools, table, offs, starts)``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    D, page = size["head_dim"], size["paged"]["page"]
+    rs = np.random.RandomState(seed)
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), 5)
+    for nq_c, nkv_c, B, PP, win in size["paged_cells"]:
+        T = PP * page
+        live = np.arange(B) % 3 == 0
+        for S in (1, 5):
+            offs = np.where(live, rs.randint(T // 2, T - S, size=B), T)
+            starts = np.where(live, offs - rs.randint(1, T // 2, size=B), 0)
+            table = rs.permutation(np.arange(1, B * PP + 1)).reshape(B, PP)
+            held = np.zeros((B, PP), bool)
+            for b in np.flatnonzero(live):
+                lo = starts[b] if win is None else max(starts[b],
+                                                       offs[b] - win + 1)
+                held[b, lo // page:(offs[b] + S - 1) // page + 1] = True
+            kk = jax.random.split(jax.random.fold_in(key, nq_c * 8 + S), 5)
+            clean = tuple(
+                jax.random.normal(k_, (B * PP + 1, nkv_c, page, D),
+                                  jnp.bfloat16) for k_ in kk[:2])
+            dead = np.setdiff1d(np.arange(B * PP + 1), table[held])
+            pool = tuple(c.at[dead].set(jnp.nan) for c in clean)
+            q = jax.random.normal(kk[2], (B, S, nq_c, D), jnp.bfloat16)
+            new = tuple(jax.random.normal(k_, (B, S, nkv_c, D), jnp.bfloat16)
+                        for k_ in kk[3:])
+            layout = (f"{nq_c}q/{nkv_c}kv: B{B} ({int(live.sum())} live) "
+                      f"page {page} T {T} window {win}")
+            yield (layout, S, win, q, new, clean, pool,
+                   np.where(held, table, 0), offs, starts)
+
+
+def write_rows_checked(pool, new, table, offs, starts, on_tpu, place=None):
+    """Commit a step's ``new`` (k, v) rows ``[B, S, NKV, D]`` to the pool
+    pair through ``ops.kv_pool_write``'s kernel, addressed as
+    ``models/llama.py`` addresses them (row ``s`` of slot ``b`` is cell
+    ``offs[b] + s`` of its chain; a parked slot and a cell before its
+    ``start`` write nothing), fetch the pools and hold them to a numpy
+    write, bit for bit.  Returns the written pools."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from neuronx_distributed_tpu.ops.kv_pool_write import write_pool_rows
+
+    num_pages, _, page, _ = pool[0].shape
+    S = new[0].shape[1]
+    idx = offs[:, None] + np.arange(S)[None, :]
+    keep = (idx < table.shape[1] * page) & (idx >= starts[:, None])
+    phys = np.where(keep, np.take_along_axis(
+        table, np.clip(idx // page, 0, table.shape[1] - 1), axis=1), num_pages)
+    cells = (jnp.asarray(phys, jnp.int32), jnp.asarray(idx % page, jnp.int32))
+    if place is not None:
+        pool, new = place(pool, new)
+    write = compiled_with_kernel(
+        lambda pool, new, phys, in_off: tuple(
+            write_pool_rows(c, x, phys, in_off, kernel=True)
+            for c, x in zip(pool, new)),
+        pool, new, *cells, on_tpu=on_tpu)
+    got = write(pool, new, *cells)
+    for name, g, c, x in zip("kv", got, pool, new):
+        want, rows = np.array(c), np.asarray(x)
+        for b, s in zip(*np.nonzero(keep)):
+            want[phys[b, s], :, idx[b, s] % page] = rows[b, s]
+        if not np.array_equal(np.asarray(g).view(np.uint16),
+                              want.view(np.uint16)):
+            raise AssertionError(
+                f"pool write S={S}: the {name} pool differs from the numpy "
+                "write")
+    return got
 
 
 def model_config(size, **overrides):
@@ -342,47 +426,29 @@ def phase_kernels(size, seed, on_tpu):
             if np.any(np.asarray(out[-1], np.float32) != 0.0):
                 raise AssertionError("parked slot rows are not exact zeros")
 
-    # the serving cells' head layouts, as the engine presents them: two
-    # slots in three parked, the live ones behind a left pad, each holding
-    # pages of its own — every other page of the pool is NaN, so a walk
-    # that strays off a slot's band cannot pass
-    for nq_c, nkv_c, B, PP, win in size["paged_cells"]:
-        T = PP * page
-        live = np.arange(B) % 3 == 0
-        for S in (1, 5):
-            offs = np.where(live, rs.randint(T // 2, T - S, size=B), T)
-            starts = np.where(live, offs - rs.randint(1, T // 2, size=B), 0)
-            table = rs.permutation(np.arange(1, B * PP + 1)).reshape(B, PP)
-            held = np.zeros((B, PP), bool)
-            for b in np.flatnonzero(live):
-                lo = starts[b] if win is None else max(starts[b],
-                                                       offs[b] - win + 1)
-                held[b, lo // page:(offs[b] + S - 1) // page + 1] = True
-            kk = jax.random.split(jax.random.fold_in(keys[5], nq_c * 8 + S), 3)
-            clean = tuple(
-                jax.random.normal(k_, (B * PP + 1, nkv_c, page, D),
-                                  jnp.bfloat16) for k_ in kk[:2])
-            dead = np.setdiff1d(np.arange(B * PP + 1), table[held])
-            pool = tuple(c.at[dead].set(jnp.nan) for c in clean)
-            q = jax.random.normal(kk[2], (B, S, nq_c, D), jnp.bfloat16)
-            args = (jnp.asarray(np.where(held, table, 0), jnp.int32),
-                    jnp.asarray(offs, jnp.int32),
-                    jnp.asarray(starts, jnp.int32))
-            kern = compiled_with_kernel(
-                lambda q, pool, bt, off, start, win=win: paged_attention(
-                    q, pool, bt, off, start, window=win),
-                q, pool, *args, on_tpu=on_tpu)
-            out = kern(q, pool, *args)
-            with jax.default_matmul_precision("highest"):
-                ref = jax.jit(
-                    lambda q, pool, bt, off, start, win=win:
-                    paged_attention_reference(q, pool, bt, off, start,
-                                              window=win))(q, clean, *args)
-            log(f" paged S={S} cell layout {nq_c}q/{nkv_c}kv: B{B} "
-                f"({int(live.sum())} live) page {page} T {T} window {win}")
-            check_close("out", out, ref, KERNEL_TOL)
-            if np.any(np.asarray(out, np.float32)[~live] != 0.0):
-                raise AssertionError("parked slot rows are not exact zeros")
+    # the serving cells' head layouts: the step's rows are written into the
+    # pool (and held to a numpy write) before the kernel reads them
+    for layout, S, win, q, new, clean, pool, table, offs, starts in \
+            paged_cell_cases(size, seed):
+        pool = write_rows_checked(pool, new, table, offs, starts, on_tpu)
+        clean = write_rows_checked(clean, new, table, offs, starts, on_tpu)
+        args = (jnp.asarray(table, jnp.int32), jnp.asarray(offs, jnp.int32),
+                jnp.asarray(starts, jnp.int32))
+        kern = compiled_with_kernel(
+            lambda q, pool, bt, off, start, win=win: paged_attention(
+                q, pool, bt, off, start, window=win),
+            q, pool, *args, on_tpu=on_tpu)
+        out = kern(q, pool, *args)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(
+                lambda q, pool, bt, off, start, win=win:
+                paged_attention_reference(q, pool, bt, off, start,
+                                          window=win))(q, clean, *args)
+        log(f" paged S={S} cell layout {layout}: pools written bit for bit")
+        check_close("out", out, ref, KERNEL_TOL)
+        if np.any(np.asarray(out, np.float32)[offs >= table.shape[1] * page]
+                  != 0.0):
+            raise AssertionError("parked slot rows are not exact zeros")
 
 
 # -- phase: train -------------------------------------------------------------
@@ -762,6 +828,8 @@ def phase_serve(size, seed, devices, on_tpu):
 
 
 def phase_four_chips(size, seed, devices, on_tpu):
+    import jax
+
     import neuronx_distributed_tpu as nxd
     from neuronx_distributed_tpu.obs.compile_ledger import CompileLedger
     from neuronx_distributed_tpu.parallel.mesh import destroy_model_parallel
@@ -790,6 +858,20 @@ def phase_four_chips(size, seed, devices, on_tpu):
     log("[four-chips] serve, tp=4: paged requests through the shard_map'd "
         "kernel")
     nxd.initialize_model_parallel(tensor_parallel_size=4, devices=four)
+
+    def over_heads(pool, new):
+        # the pool's kv heads over tp where tp divides them (kvcache.pool)
+        from neuronx_distributed_tpu.parallel.mesh import named_sharding
+
+        tp = "tp" if pool[0].shape[1] % 4 == 0 else None
+        return (jax.device_put(pool, named_sharding(None, tp, None, None)),
+                jax.device_put(new, named_sharding(None, None, tp, None)))
+
+    for layout, S, _, _, new, _, pool, table, offs, starts in \
+            paged_cell_cases(size, seed):
+        write_rows_checked(pool, new, table, offs, starts, on_tpu,
+                           place=over_heads)
+        log(f" pool write S={S} cell layout {layout}: bit for bit at tp=4")
     ledger = CompileLedger()
     cfg, module, params, model = build_server(f, size, seed, ledger)
     serve_requests(f, cfg, model, ledger, seed, on_tpu)

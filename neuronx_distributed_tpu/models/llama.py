@@ -455,16 +455,18 @@ class LlamaAttention(nn.Module):
             if block_table is not None:
                 # paged decode (kvcache/ subsystem): the cache is the global
                 # head-major page pool [NP, NKV, page, D] (the layout the
-                # paged kernel's blocks need) and block_table [B, PP] maps
-                # each slot's logical pages to physical ones.  Scatter the
+                # paged kernel's copies read) and block_table [B, PP] maps
+                # each slot's logical pages to physical ones.  Write the
                 # S new tokens into their physical (page, in-page) cells —
                 # token s of slot b lands at logical index offset[b] + s —
-                # then gather the row's chain back into the same
-                # [B, T, NKV, D] view the contiguous path attends over; the
-                # band-mask core below is untouched, so paged decode is
-                # value-identical to the per-slot contiguous decode.  S == 1
-                # is the serving decode step; S == k+1 is the speculative
-                # verification chunk.
+                # in place (ops.kv_pool_write: the pages they touch, on the
+                # pool's leading axis), then attend: with paged_kernel
+                # straight over the pool, else over the row's chain gathered
+                # back into the [B, T, NKV, D] view the contiguous path
+                # attends over (the band-mask core below is untouched, so
+                # paged decode is value-identical to the per-slot contiguous
+                # decode).  S == 1 is the serving decode step, S == k+1 the
+                # speculative verification chunk, S == Cc a prefill chunk.
                 if jnp.ndim(cache_offset) != 1:
                     raise ValueError(
                         "the block-table decode path needs per-slot offsets "
@@ -548,13 +550,15 @@ class LlamaAttention(nn.Module):
                         ck, ks, kz = requant_pages(ck, ks, kz, k)
                         cv, vs, vz = requant_pages(cv, vs, vz, v)
                     else:
-                        # cell (phys, :, in_off) of the head-major pool; the
-                        # split advanced indices lead the update's dims, which
-                        # is k's own [B, Sn, NKV, D]
-                        ck = ck.at[phys, :, in_off].set(
-                            k.astype(ck.dtype), mode="drop")
-                        cv = cv.at[phys, :, in_off].set(
-                            v.astype(cv.dtype), mode="drop")
+                        # cell (phys, :, in_off) of the head-major pool, in
+                        # place; a kernel where the paged kernel is one
+                        from neuronx_distributed_tpu.ops.kv_pool_write import (
+                            write_pool_rows)
+
+                        ck = write_pool_rows(ck, k, phys, in_off,
+                                             kernel=paged_kernel)
+                        cv = write_pool_rows(cv, v, phys, in_off,
+                                             kernel=paged_kernel)
             elif jnp.ndim(cache_offset) == 1:
                 # per-example write positions [B] over a contiguous [B, T]
                 # cache: every slot decodes at its own offset.  One caller,

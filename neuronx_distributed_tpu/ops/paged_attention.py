@@ -482,12 +482,12 @@ def paged_attention(
         q, tuple(kv_pages), block_table, cache_offset, kv_start, **kw)
 
 
-def _tp_shard_mapped(nq: int, nkv: int):
-    """The tp > 1 dispatch decision: returns a ``wrap`` closure when a live
-    mesh shards the kv-head axis (``wrap(kw)`` is the shard_mapped kernel),
-    else None (single-device meshes, and head counts the mesh does not
-    divide — those stay on the global-kernel path, matching the pool's own
-    replicate-when-indivisible policy)."""
+def kv_head_tp_mesh(nkv: int):
+    """The live mesh when its tp axis (> 1) divides the pool's ``nkv`` kv
+    heads — ``kvcache.pool`` then shards the pool's head axis over it, and a
+    Pallas call over the pool runs under a ``shard_map`` (GSPMD cannot split
+    one) — else None: no mesh, tp = 1, or heads the mesh does not divide
+    (the pool's own replicate-when-indivisible policy)."""
     from neuronx_distributed_tpu.parallel.mesh import (
         TENSOR_AXIS,
         get_mesh,
@@ -498,7 +498,18 @@ def _tp_shard_mapped(nq: int, nkv: int):
         return None
     mesh = get_mesh()
     tp = mesh.shape[TENSOR_AXIS]
-    if tp == 1 or nkv % tp or nq % tp or (nq // tp) % (nkv // tp):
+    return None if tp == 1 or nkv % tp else mesh
+
+
+def _tp_shard_mapped(nq: int, nkv: int):
+    """The tp > 1 dispatch decision: returns a ``wrap`` closure when a live
+    mesh shards the kv-head axis (``wrap(kw)`` is the shard_mapped kernel),
+    else None (:func:`kv_head_tp_mesh`; the query heads group over the kv
+    heads, so the mesh divides them too)."""
+    from neuronx_distributed_tpu.parallel.mesh import TENSOR_AXIS
+
+    mesh = kv_head_tp_mesh(nkv)
+    if mesh is None:
         return None
     from jax.sharding import PartitionSpec as P
 

@@ -1186,6 +1186,7 @@ class ServingEngine:
                       if req.state is RequestState.DECODE]
             if active:
                 self._count_paged_walk(active)
+                self._count_decode_write(active)
                 with phase("serve/dispatch", active=len(active),
                            ctx_tokens=self._attended_keys(active)):
                     if self._spec_k:
@@ -1651,6 +1652,9 @@ class ServingEngine:
         width = n_pages * page
         ids_chunk = np.zeros((1, self._chunk_tokens), np.int32)
         ids_chunk[0, :width] = st.ids_row[off:off + width]
+        # the chunk commits its valid cells (a first page may lead with pads)
+        cells = st.valid_row[off:off + width].reshape(n_pages, page) > 0
+        self._count_kv_write(int(cells.sum()), int(cells.any(axis=1).sum()))
         tr = self.tracer
         # one shared start stamp: the chunk span and its perf accounting
         # measure the identical interval (attribution sums to the trace)
@@ -1788,6 +1792,28 @@ class ServingEngine:
         self.registry.counter("serving/paged_pages_walked_total").inc(walked)
         self.registry.counter("serving/paged_pages_tabled_total").inc(
             self.B * self._kv.pages_per_slot)
+
+    def _count_kv_write(self, rows: int, pages: int) -> None:
+        """What the coming program commits to the page pool, a layer: the
+        K/V rows (``serving/kv_rows_written_total``) and the pool pages they
+        land in (``serving/kv_pages_touched_total`` — what the page-granular
+        writer, ``ops.kv_pool_write``, reads and writes back)."""
+        self.registry.counter("serving/kv_rows_written_total").inc(rows)
+        self.registry.counter("serving/kv_pages_touched_total").inc(pages)
+
+    def _count_decode_write(self, active: list) -> None:
+        """The coming decode's (or verify round's) pool write, from the host
+        offsets: every live slot's row(s) from its offset on — a verify
+        chunk's rows past the table's end are dropped."""
+        page = self._kv.page_size
+        rows = pages = 0
+        for slot, _ in active:
+            off = int(self._offsets[slot])
+            last = min(off + self._spec_k + 1, self.T) - 1
+            if last >= off:
+                rows += last - off + 1
+                pages += last // page - off // page + 1
+        self._count_kv_write(rows, pages)
 
     def _count_gather_step(self) -> None:
         """Account one gather-path paged step's ``[B, T]`` K/V

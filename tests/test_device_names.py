@@ -181,7 +181,8 @@ def test_paged_programs_name_their_kernel_and_their_cache_writes(
         fn, pool.params, jnp.zeros((B, width), jnp.int32),
         jnp.full((B,), 8, jnp.int32), jnp.zeros((B, 4), jnp.int32), caches,
         jnp.zeros((B, 16), jnp.int32))
-    assert kernels == {kernel}
+    # the paged kernel, and the pool's writer beside it
+    assert kernels == {kernel, "kv_pool_write"}
     assert _has(stacks, "kv_write") and _has(stacks, "kv_valid")
     # the pool write sits inside the attention module's scope
     assert any("kv_write" in s and "attn" in tracing_components(s)

@@ -239,6 +239,65 @@ def test_paged_kernel_compiles_at_the_cells_geometries(topo, cell, S):
     assert operands.count(f"bf16[{num_pages},{nkv},16,{D}]") == 2
 
 
+WRITE_CASES = [(cell, S, 1) for cell in sorted(CELL_PAGED) for S in (1, 512)]
+WRITE_CASES += [("mistral-7b.serve-docs", 1, 4), ("mistral-7b.serve-docs", 512, 4)]
+
+
+@pytest.mark.parametrize(
+    "cell,S,tp", WRITE_CASES,
+    ids=[f"{c}-{'decode' if S == 1 else 'chunk_s512'}-tp{tp}"
+         for c, S, tp in WRITE_CASES])
+def test_pool_write_then_attend_copies_no_pool_for_v5e(topo, cell, S, tp):
+    """A serve program's K / V write and its paged call, both pools donated:
+    the write is in place.  No ``copy`` or ``transpose`` gives a pool-shaped
+    result, the program's temporaries stay far under a pool (the row scatter
+    that split page and cell round the head axis had the compiler relay
+    both pools out and back: 130 MiB of temporaries at the docs geometry,
+    PR 28), both pools are aliased to their outputs, and under tp = 4 no
+    collective moves one."""
+    import re
+
+    from neuronx_distributed_tpu.ops.kv_pool_write import write_pool_rows
+
+    B, nkv, group, pp, num_pages, window = CELL_PAGED[cell]
+    mesh = _mesh(topo, tp)
+    B = B if S == 1 else 1
+    heads = "tp" if tp > 1 else None
+
+    def sds(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*spec)))
+
+    def step(q, k, v, pool, bt, off, start):
+        # models/llama.py's index arithmetic, without the validity mask
+        idx = off[:, None] + jnp.arange(S)[None, :]
+        phys = jnp.take_along_axis(bt, jnp.clip(idx // 16, 0, pp - 1), axis=1)
+        phys = jnp.where(idx < pp * 16, phys, num_pages)
+        with jax.named_scope("kv_write"):
+            pool = tuple(write_pool_rows(c, x, phys, idx % 16, kernel=True)
+                         for c, x in zip(pool, (k, v)))
+        return paged_attention(q, pool, bt, off, start, window=window), pool
+
+    pages = sds((num_pages, nkv, 16, D), jnp.bfloat16, None, heads, None, None)
+    new = sds((B, S, nkv, D), jnp.bfloat16, None, None, heads, None)
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        sds((B, S, nkv * group, D), jnp.bfloat16, None, None, heads, None),
+        new, new, (pages, pages), sds((B, pp), jnp.int32),
+        sds((B,), jnp.int32), sds((B,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("%kv_pool_write") >= 2 and "%paged_attention" in text
+    pool_shape = re.escape(f"bf16[{num_pages},{nkv // tp},16,{D}]")
+    relaid = [ln.strip()[:120] for ln in text.splitlines()
+              if re.search(rf"= {pool_shape}\S* (copy|transpose)\(", ln)]
+    assert not relaid, f"a pool is copied: {relaid}"
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 8 * 2 ** 20
+    pool_bytes = num_pages * (nkv // tp) * 16 * D * 2
+    assert memory.alias_size_in_bytes == 2 * pool_bytes
+    for op in ("all-gather", "all-reduce", "all-to-all", "collective-permute"):
+        assert op not in text, f"unexpected {op}"
+
+
 def test_routed_expert_block_compiles_for_v5e_without_relaying_out_its_weights(topo):
     """The dropless expert block at OLMoE-1B-7B's widths (64 experts x 1024,
     8 a token, hidden 2048) on a decode's 16 rows: three megablox calls
