@@ -27,7 +27,13 @@ window 4096 — only depth is cut, and the cut is printed):
   kernel left at ``"auto"`` and chunked prefill on, answering staggered
   requests of 512–6000 prompt tokens; logits of the kernel path against the
   gather path, and of prefill-then-decode through the cache against a full
-  forward of the same sequence.
+  forward of the same sequence;
+- **hybrid**  — a thin model with a layer LIST (``mixer_types``: block-sparse
+  softmax layers and lightning linear-attention layers, at MiniCPM-SALA's
+  head geometry) through the same engine: requests finish, the state rows
+  and the pages come back, no prefix is shared; logits of the kernel path
+  (the chosen-table decode walk, the masked chunk walk) against the gather
+  path.
 
 ``--four-chips`` runs ONLY the path that exists across chips and what it is
 compared with: a tp=4 mesh (sequence parallel on) over four real devices —
@@ -92,6 +98,14 @@ REAL = dict(
     four=dict(layers=2, batch=2, seq=8192, steps=3, loss_chunk=512,
               slots=4, context=2048, total=4096, page=16, chunk=512, new=16,
               prompts=(600, 1297, 2000), probe=(1297,), probe_decodes=2),
+    # MiniCPM-SALA's mixers at their published head geometry, thin elsewhere;
+    # dense_len low enough that 2300 and 3900 tokens take the sparse rule
+    hybrid=dict(heads=(32, 2), head_dim=128, hidden=512, mlp=1024, vocab=1024,
+                slots=4, context=4096, total=4608, page=64, chunk=512, new=8,
+                prompts=(700, 2300, 3900), probe_decodes=2,
+                sparse=dict(sparse_block_size=64, sparse_kernel_size=32,
+                            sparse_kernel_stride=16, sparse_window_size=512,
+                            sparse_topk=24, sparse_dense_len=2048)),
 )
 
 # the same control flow at sizes the CPU and the Pallas interpreter can carry
@@ -110,6 +124,12 @@ TINY = dict(
     four=dict(layers=2, batch=2, seq=128, steps=3, loss_chunk=64,
               slots=4, context=64, total=96, page=8, chunk=16, new=4,
               prompts=(9, 33, 50), probe=(33,), probe_decodes=2),
+    hybrid=dict(heads=(4, 2), head_dim=16, hidden=64, mlp=96, vocab=256,
+                slots=3, context=48, total=64, page=4, chunk=8, new=3,
+                prompts=(7, 14, 45), probe_decodes=2,
+                sparse=dict(sparse_block_size=4, sparse_kernel_size=2,
+                            sparse_kernel_stride=1, sparse_window_size=6,
+                            sparse_topk=4, sparse_dense_len=16)),
 )
 
 # Tolerances.  Every comparison is max|a - b| <= tol * max|b| (an error
@@ -824,6 +844,134 @@ def phase_serve(size, seed, devices, on_tpu):
     destroy_model_parallel()
 
 
+# -- phase: hybrid serve ------------------------------------------------------
+
+
+def phase_serve_hybrid(size, seed, devices, on_tpu):
+    """A layer list (block-sparse softmax + lightning linear attention)
+    through the paged engine, then kernel path against gather path."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import neuronx_distributed_tpu as nxd
+    from neuronx_distributed_tpu.models.llama import (
+        LlamaConfig,
+        LlamaForCausalLM,
+    )
+    from neuronx_distributed_tpu.parallel.layers import init_sharded_params
+    from neuronx_distributed_tpu.parallel.mesh import destroy_model_parallel
+    from neuronx_distributed_tpu.serving import Request, ServingEngine
+    from neuronx_distributed_tpu.trace import (
+        InferenceConfig,
+        ParallelInferenceModel,
+    )
+
+    h = size["hybrid"]
+    mixers = ("minicpm4", "lightning-attn", "lightning-attn", "minicpm4")
+    log(f"[hybrid] layers {mixers}, {h['heads'][0]} q / {h['heads'][1]} kv x "
+        f"{h['head_dim']}; {h['slots']} slots, context {h['context']}, page "
+        f"{h['page']} (= one block), chunks of {h['chunk']}")
+    nxd.initialize_model_parallel(tensor_parallel_size=1, devices=devices[:1])
+    dtype = jnp.float32 if size.get("rehearsal") else jnp.bfloat16
+    cfg = LlamaConfig(
+        vocab_size=h["vocab"], hidden_size=h["hidden"],
+        intermediate_size=h["mlp"], num_layers=len(mixers),
+        num_heads=h["heads"][0], num_kv_heads=h["heads"][1],
+        head_dim=h["head_dim"], max_seq_len=h["total"], rms_eps=1e-6,
+        mixer_types=mixers, embed_scale=12.0, residual_scale=1.4 / 32 ** 0.5,
+        logit_scale=1.0 / 16, lightning_heads=h["heads"][0],
+        lightning_head_dim=h["head_dim"], sequence_parallel=False,
+        remat="none", dtype=dtype, param_dtype=dtype, **h["sparse"])
+    module = LlamaForCausalLM(cfg)
+    params, _ = init_sharded_params(
+        module, jax.random.PRNGKey(seed), jnp.zeros((1, h["page"]), jnp.int32))
+    B, C, T, page, W, nd = (h["slots"], h["context"], h["total"], h["page"],
+                            h["chunk"], h["probe_decodes"])
+    icfg = InferenceConfig(batch_size=B, context_len=C, max_total_len=T,
+                           kv_cache_dtype=dtype)
+    rs = np.random.RandomState(seed + 5)
+    lens = h["prompts"]
+    seqs = [rs.randint(1, h["vocab"], size=L + nd).astype(np.int32)
+            for L in lens]
+
+    model = ParallelInferenceModel(module, params, icfg)
+    engine = ServingEngine(model, page_size=page, prefill_chunk_tokens=W)
+    if on_tpu and not engine._paged_kernel:
+        raise AssertionError("paged_kernel='auto' took the gather path on a TPU")
+    if engine._kv.index is not None:
+        raise AssertionError("prefix sharing is on for a model with state rows")
+    for rid, seq in enumerate(seqs + seqs[:1]):   # the first prompt twice
+        engine.submit(Request(request_id=rid, prompt_ids=seq[:-nd].tolist(),
+                              max_new_tokens=h["new"]))
+    outs = {o.request_id: o for o in engine.run_until_complete(max_steps=4000)}
+    engine._kv.assert_invariants()
+    snap = engine.registry.snapshot()
+    if sorted(outs) != list(range(len(lens) + 1)) or any(
+            o.state != "finished" or len(o.token_ids) != h["new"]
+            for o in outs.values()):
+        raise AssertionError(f"hybrid requests did not all finish: {outs}")
+    if tuple(outs[0].token_ids) != tuple(outs[len(lens)].token_ids):
+        raise AssertionError("a repeated prompt gave other tokens")
+    if snap["kvcache/prefix_hits_total"] or snap["kvcache/state_rows_in_use"] \
+            or engine._kv.alloc.in_use:
+        raise AssertionError(f"state not returned: {snap}")
+    log(f"  {len(outs)} requests finished; blocks chosen / visible "
+        f"{snap['serving/sparse_blocks_selected_total']:.0f} / "
+        f"{snap['serving/sparse_blocks_visible_total']:.0f}, dense queries "
+        f"{snap['serving/sparse_dense_queries_total']:.0f}")
+    engine.close()
+
+    PP = T // page
+    tables = np.zeros((B, PP), np.int32)
+    valid = np.zeros((B, T), np.int32)
+    nxt = 1
+    for b, L in enumerate(lens):
+        for lp in range((C - L) // page, (C + nd - 1) // page + 1):
+            tables[b, lp] = nxt
+            nxt += 1
+        valid[b, C - L:C] = 1
+
+    def probe(kernel):
+        m = ParallelInferenceModel(module, params, icfg, paged_kernel=kernel)
+        caches = m.make_page_pool(nxt + 1, page).caches
+        got = {}
+        for b, L in enumerate(lens):
+            row = np.zeros((C,), np.int32)
+            row[C - L:] = seqs[b][:L]
+            off = (C - L) // page * page
+            while off < C:
+                width = min(W, C - off)
+                ids = np.zeros((1, W), np.int32)
+                ids[0, :width] = row[off:off + width]
+                logits, caches = m.prefill_chunk_pages(
+                    jnp.asarray(ids), off, tables[b][None, :], caches,
+                    valid[b][None, :], last_row=width - 1, state_row=b)
+                off += width
+            got[(b, 0)] = np.asarray(logits[0], np.float32)
+        dvalid = jnp.asarray(valid)
+        for j in range(nd):
+            tok = np.zeros((B, 1), np.int32)
+            offs = np.full((B,), T, np.int32)
+            for b, L in enumerate(lens):
+                tok[b, 0], offs[b] = seqs[b][L + j], C + j
+            logits, caches, dvalid = m.decode_pages(
+                jnp.asarray(tok), offs, tables, caches, dvalid)
+            for b in range(len(lens)):
+                got[(b, j + 1)] = np.asarray(logits[b], np.float32)
+        return got
+
+    kern, gath = probe(True), probe(False)
+    tol = REHEARSAL_LOGITS_TOL if size.get("rehearsal") else LOGITS_TOL
+    for b, L in enumerate(lens):
+        for j in range(nd + 1):
+            check_close(f"hybrid prompt {L}, "
+                        + ("prefill" if j == 0 else f"decode step {j - 1}")
+                        + " logits, kernels vs gather",
+                        kern[(b, j)], gath[(b, j)], tol)
+    destroy_model_parallel()
+
+
 # -- phase: four chips --------------------------------------------------------
 
 
@@ -956,6 +1104,8 @@ def main():
         gc.collect()  # drop the phase's device arrays
         phase_train(size, args.seed, devices, on_tpu)
         phase_serve(size, args.seed, devices, on_tpu)
+        gc.collect()
+        phase_serve_hybrid(size, args.seed, devices, on_tpu)
     log(f"[cache] {cache_events['requests']} compile requests, "
         f"{cache_events['hits']} served from the persistent cache, "
         f"{cache_events['requests'] - cache_events['hits']} compiled")

@@ -24,6 +24,9 @@ from neuronx_distributed_tpu.convert.hf import (  # noqa: F401
     llama_params_from_hf,
     llama_params_from_pipelined,
     llama_params_to_hf,
+    minicpm_sala_config_from_hf,
+    minicpm_sala_params_from_hf,
+    minicpm_sala_params_to_hf,
     olmoe_params_from_hf,
     olmoe_params_to_hf,
     llama_stack_layers,

@@ -257,6 +257,148 @@ def olmoe_params_to_hf(params: Mapping[str, Any], cfg) -> Dict[str, np.ndarray]:
 
 
 # ---------------------------------------------------------------------------
+# MiniCPM-SALA (Llama layout + a layer list: per-head q/k norms, output
+# gates, an output norm on the lightning layers, muP scalars)
+# ---------------------------------------------------------------------------
+
+# the attention sub-module's tensors beside q/k/v/o_proj, by the name this
+# package gives them.  The published ``modeling_minicpm_sala.py`` could not
+# be read where this was written: the right-hand names are the family's
+# convention (MiniCPM4 / Qwen3 ``q_norm`` / ``k_norm``; an ``o_gate``
+# projection; ``o_norm`` on the lightning layers) and are ASSUMED — a real
+# checkpoint that names them otherwise changes this one table.
+MINICPM_SALA_ATTN_NAMES = {
+    ("q_norm", "weight"): "self_attn.q_norm.weight",
+    ("k_norm", "weight"): "self_attn.k_norm.weight",
+    ("gate", "kernel"): "self_attn.o_gate.weight",
+    ("out_norm", "weight"): "self_attn.o_norm.weight",   # lightning-attn only
+}
+
+
+def minicpm_sala_config_from_hf(hf_config: Mapping[str, Any], **overrides):
+    """The published ``config.json`` of a ``minicpm_sala`` model -> a
+    :class:`~..models.llama.LlamaConfig`: ``mixer_types`` as the layer list,
+    the muP scalars (``scale_emb`` -> ``embed_scale``, ``scale_depth /
+    sqrt(num_hidden_layers)`` -> ``residual_scale``, ``dim_model_base /
+    hidden_size`` -> ``logit_scale``), the lightning head geometry.  The
+    ``sparse_config`` keys (``kernel_size``, ``kernel_stride``,
+    ``block_size``, ``init_blocks``, ``window_size``, ``topk``,
+    ``dense_len``) are read where the config has them and keep MiniCPM4's
+    values where it does not."""
+    import math
+
+    from neuronx_distributed_tpu.models.llama import LlamaConfig
+
+    c = dict(hf_config)
+    layers = int(c["num_hidden_layers"])
+    sparse = {f"sparse_{k}": int(v)
+              for k, v in dict(c.get("sparse_config") or {}).items()
+              if k in ("kernel_size", "kernel_stride", "block_size",
+                       "init_blocks", "window_size", "topk", "dense_len")}
+    return LlamaConfig(**{**dict(
+        vocab_size=int(c["vocab_size"]), hidden_size=int(c["hidden_size"]),
+        intermediate_size=int(c["intermediate_size"]), num_layers=layers,
+        num_heads=int(c["num_attention_heads"]),
+        num_kv_heads=int(c["num_key_value_heads"]),
+        head_dim=int(c["head_dim"]), rope_theta=float(c["rope_theta"]),
+        rms_eps=float(c["rms_norm_eps"]),
+        max_seq_len=int(c["max_position_embeddings"]),
+        mixer_types=tuple(c["mixer_types"]),
+        embed_scale=float(c["scale_emb"]),
+        residual_scale=float(c["scale_depth"]) / math.sqrt(layers),
+        logit_scale=float(c["dim_model_base"]) / float(c["hidden_size"]),
+        lightning_heads=int(c["lightning_nh"]),
+        lightning_head_dim=int(c["lightning_head_dim"])),
+        **sparse, **overrides})
+
+
+def _sala_layer_dims(cfg, i):
+    from neuronx_distributed_tpu.models.hybrid import lightning_dims
+
+    if cfg.mixer(i) == "lightning-attn":
+        nh, d = lightning_dims(cfg)
+        return nh, nh, d
+    return cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+
+
+def minicpm_sala_params_from_hf(state_dict: Mapping[str, Any], cfg
+                                ) -> Dict[str, Any]:
+    """A ``minicpm_sala`` state dict -> the param tree of
+    :class:`~..models.llama.LlamaForCausalLM` under a config with
+    ``mixer_types`` (``models/hybrid.py``): the Llama layout, q/k/v shaped by
+    each layer's own head geometry, and the tensors of
+    :data:`MINICPM_SALA_ATTN_NAMES`."""
+    sd = {k: _np(v) for k, v in state_dict.items()}
+    H = cfg.hidden_size
+    model: Dict[str, Any] = {
+        "embed": {"embedding": sd["model.embed_tokens.weight"]},
+        "final_norm": {"weight": sd["model.norm.weight"]},
+    }
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}."
+        nq, nkv, d = _sala_layer_dims(cfg, i)
+        attn: Dict[str, Any] = {
+            "qkv": {
+                "q_kernel": sd[p + "self_attn.q_proj.weight"].T.reshape(H, nq, d),
+                "k_kernel": sd[p + "self_attn.k_proj.weight"].T.reshape(H, nkv, d),
+                "v_kernel": sd[p + "self_attn.v_proj.weight"].T.reshape(H, nkv, d),
+            },
+            "o_proj": {"kernel": sd[p + "self_attn.o_proj.weight"].T},
+        }
+        for (mod, leaf), name in MINICPM_SALA_ATTN_NAMES.items():
+            if mod == "out_norm" and cfg.mixer(i) != "lightning-attn":
+                continue
+            w = sd[p + name]
+            attn.setdefault(mod, {})[leaf] = w.T if leaf == "kernel" else w
+        model[f"layer_{i}"] = {
+            "attn": attn,
+            "mlp": {
+                "gate_up": {"kernel": np.stack(
+                    [sd[p + "mlp.gate_proj.weight"].T,
+                     sd[p + "mlp.up_proj.weight"].T], axis=1)},
+                "down": {"kernel": sd[p + "mlp.down_proj.weight"].T},
+            },
+            "input_norm": {"weight": sd[p + "input_layernorm.weight"]},
+            "post_attn_norm": {
+                "weight": sd[p + "post_attention_layernorm.weight"]},
+        }
+    return {"params": {"model": model,
+                       "lm_head": {"kernel": sd["lm_head.weight"].T}}}
+
+
+def minicpm_sala_params_to_hf(params: Mapping[str, Any], cfg
+                              ) -> Dict[str, np.ndarray]:
+    """Inverse of :func:`minicpm_sala_params_from_hf`."""
+    tree = params.get("params", params)
+    model, H = tree["model"], cfg.hidden_size
+    out: Dict[str, np.ndarray] = {
+        "model.embed_tokens.weight": _np(model["embed"]["embedding"]),
+        "model.norm.weight": _np(model["final_norm"]["weight"]),
+        "lm_head.weight": _np(tree["lm_head"]["kernel"]).T,
+    }
+    for i in range(cfg.num_layers):
+        lyr, p = model[f"layer_{i}"], f"model.layers.{i}."
+        attn = lyr["attn"]
+        gu = _np(lyr["mlp"]["gate_up"]["kernel"])
+        out.update({
+            p + "self_attn.q_proj.weight": _np(attn["qkv"]["q_kernel"]).reshape(H, -1).T,
+            p + "self_attn.k_proj.weight": _np(attn["qkv"]["k_kernel"]).reshape(H, -1).T,
+            p + "self_attn.v_proj.weight": _np(attn["qkv"]["v_kernel"]).reshape(H, -1).T,
+            p + "self_attn.o_proj.weight": _np(attn["o_proj"]["kernel"]).T,
+            p + "mlp.gate_proj.weight": gu[:, 0, :].T,
+            p + "mlp.up_proj.weight": gu[:, 1, :].T,
+            p + "mlp.down_proj.weight": _np(lyr["mlp"]["down"]["kernel"]).T,
+            p + "input_layernorm.weight": _np(lyr["input_norm"]["weight"]),
+            p + "post_attention_layernorm.weight": _np(lyr["post_attn_norm"]["weight"]),
+        })
+        for (mod, leaf), name in MINICPM_SALA_ATTN_NAMES.items():
+            if mod in attn:
+                w = _np(attn[mod][leaf])
+                out[p + name] = w.T if leaf == "kernel" else w
+    return out
+
+
+# ---------------------------------------------------------------------------
 # GPT-NeoX
 # ---------------------------------------------------------------------------
 

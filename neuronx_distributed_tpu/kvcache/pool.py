@@ -24,6 +24,7 @@ pages — a dp-sharded page axis would turn every gather into a collective).
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, List, Optional, Tuple
 
 import jax
@@ -47,6 +48,64 @@ logger = get_logger(__name__)
 GATHER_BYTES_TOTAL = "kvcache/gather_bytes_total"
 
 
+# what a layer keeps for a live sequence: K/V pages; K/V pages it chooses
+# among, with compressed keys beside them; a row of a state array
+CACHE_KINDS = ("pages", "selected_pages", "state")
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerStates:
+    """What each layer of a model with a layer LIST keeps for a sequence
+    (``kinds``, one of :data:`CACHE_KINDS` a layer): K/V pages for the
+    softmax layers only — with ``comp_slots`` compressed keys a page beside
+    them where the layer selects pages — and, for the recurrent layers, one
+    row of a ``[state_rows, *state_shape]`` float32 array a live sequence,
+    which is neither paged nor shareable by page."""
+
+    kinds: Tuple[str, ...]
+    comp_slots: int = 0
+    state_rows: int = 0
+    state_shape: Tuple[int, ...] = ()
+
+    @staticmethod
+    def for_config(cfg, page_size: int, state_rows: int
+                   ) -> "Optional[LayerStates]":
+        """From a model config that says what its layers keep
+        (``layer_caches``, ``state_shape``, ``selection_spec``:
+        ``models.llama.LlamaConfig``); None where every layer keeps pages."""
+        kinds = getattr(cfg, "layer_caches", None)
+        if kinds is None:
+            return None
+        spec = cfg.selection_spec
+        if spec is not None and page_size != spec.block_size:
+            raise ValueError(
+                f"selecting layers choose pages: page_size ({page_size}) "
+                f"must equal the selection's block_size ({spec.block_size})")
+        recurrent = "state" in kinds
+        return LayerStates(
+            kinds=tuple(kinds),
+            comp_slots=(page_size // spec.kernel_stride if spec is not None
+                        else 0),
+            state_rows=state_rows if recurrent else 0,
+            state_shape=tuple(cfg.state_shape) if recurrent else ())
+
+    @property
+    def recurrent(self) -> int:
+        return sum(k == "state" for k in self.kinds)
+
+    @property
+    def paged(self) -> int:
+        return len(self.kinds) - self.recurrent
+
+    @property
+    def state_row_bytes(self) -> int:
+        """Bytes one state row costs across the recurrent layers."""
+        n = 4
+        for dim in self.state_shape:
+            n *= dim
+        return n * self.recurrent
+
+
 def init_page_pool_caches(
     num_layers: int,
     num_pages: int,
@@ -55,6 +114,7 @@ def init_page_pool_caches(
     head_dim: int,
     dtype: Any = jnp.bfloat16,
     quant: Optional[str] = None,
+    layers: Optional[LayerStates] = None,
 ) -> List[Tuple[jax.Array, ...]]:
     """Zero page-pool caches ``[NP, NKV, page, D]`` per layer, kv-heads
     sharded over tp when divisible (the same policy as the contiguous
@@ -89,6 +149,25 @@ def init_page_pool_caches(
     def params():
         return jnp.zeros((num_pages,), jnp.float32, device=scale_sh)
 
+    if layers is not None:
+        # a layer list: an entry a layer, shaped by what it keeps — ``(k,
+        # v)``, ``(k, v, compressed keys [NP, slots, NKV, D])`` or
+        # ``(states [R, heads, D, D] float32,)``
+        if quant is not None:
+            raise ValueError("an int8 pool is not carried through a layer "
+                             "list (selected pages, state rows)")
+
+        def entry(kind):
+            if kind == "state":
+                return (jnp.zeros((layers.state_rows,) + layers.state_shape,
+                                  jnp.float32, device=scale_sh),)
+            if kind == "selected_pages":
+                comp = jnp.zeros((num_pages, layers.comp_slots, num_kv_heads,
+                                  head_dim), dtype, device=scale_sh)
+                return (pages(dtype), pages(dtype), comp)
+            return (pages(dtype), pages(dtype))
+
+        return [entry(k) for k in layers.kinds]
     if quant is None:
         return [(pages(dtype), pages(dtype)) for _ in range(num_layers)]
     return [(pages(jnp.int8), pages(jnp.int8),
@@ -115,6 +194,7 @@ class PagePool:
         head_dim: int,
         dtype: Any = jnp.bfloat16,
         quant: Optional[str] = None,
+        layers: Optional[LayerStates] = None,
     ):
         if num_pages < 2:
             raise ValueError(
@@ -129,37 +209,60 @@ class PagePool:
         self.head_dim = head_dim
         self.dtype = dtype
         self.quant = quant
+        self.layers = layers
         self.caches = init_page_pool_caches(
             num_layers, num_pages, page_size, num_kv_heads, head_dim, dtype,
-            quant=quant)
+            quant=quant, layers=layers)
 
     @property
     def page_bytes(self) -> int:
         """HBM bytes one page costs across all layers (k + v, plus the
         per-page scale/zero params under int8 quantization — honest
         accounting: the quantized pool pays for its metadata)."""
-        from neuronx_distributed_tpu.kvcache.quant import page_layer_bytes
+        return _page_bytes(self.num_layers, self.page_size, self.num_kv_heads,
+                           self.head_dim, self.dtype, self.quant, self.layers)
 
-        return self.num_layers * page_layer_bytes(
-            self.page_size, self.num_kv_heads, self.head_dim, self.quant,
-            self.dtype)
+    @property
+    def state_bytes(self) -> int:
+        """HBM bytes of the recurrent layers' state rows (0 without any)."""
+        return (self.layers.state_rows * self.layers.state_row_bytes
+                if self.layers is not None else 0)
 
     @property
     def total_bytes(self) -> int:
-        return self.num_pages * self.page_bytes
+        return self.num_pages * self.page_bytes + self.state_bytes
 
     @staticmethod
     def pages_for_budget(budget_bytes: int, num_layers: int, page_size: int,
                          num_kv_heads: int, head_dim: int,
                          dtype: Any = jnp.bfloat16,
-                         quant: Optional[str] = None) -> int:
+                         quant: Optional[str] = None,
+                         layers: Optional[LayerStates] = None) -> int:
         """How many pool pages a given HBM budget buys — the sizing half of
         the paged-vs-contiguous comparison (a contiguous ``[B, T]`` cache's
         budget is ``B * T / page_size`` pages).  ``quant="int8"`` roughly
         doubles the answer at a fixed budget versus bf16 (1 byte/element +
         four fp32 page params instead of 2 bytes/element)."""
-        from neuronx_distributed_tpu.kvcache.quant import page_layer_bytes
-
-        per_page = num_layers * page_layer_bytes(
-            page_size, num_kv_heads, head_dim, quant, dtype)
+        per_page = _page_bytes(num_layers, page_size, num_kv_heads, head_dim,
+                               dtype, quant, layers)
+        if layers is not None:
+            # the state rows come off the budget first: they are there
+            # whatever the pages hold
+            budget_bytes -= layers.state_rows * layers.state_row_bytes
         return max(int(budget_bytes // per_page), 0)
+
+
+def _page_bytes(num_layers, page_size, num_kv_heads, head_dim, dtype, quant,
+                layers: Optional[LayerStates]) -> int:
+    """Bytes one page costs across the layers that HAVE pages: K and V, the
+    int8 page params, the compressed keys of a block-sparse layer."""
+    from neuronx_distributed_tpu.kvcache.quant import page_layer_bytes
+
+    per_layer = page_layer_bytes(page_size, num_kv_heads, head_dim, quant,
+                                 dtype)
+    if layers is None:
+        return num_layers * per_layer
+    comp = (layers.comp_slots * num_kv_heads * head_dim
+            * jnp.dtype(dtype).itemsize)
+    return sum(per_layer + (comp if k == "selected_pages" else 0)
+               for k in layers.kinds if k != "state")

@@ -152,12 +152,100 @@ class LlamaConfig:
     lora_rank: int = 0
     lora_alpha: float = 16.0
     lora_targets: Tuple[str, ...] = ("qkv",)
+    # the layer list as DATA (HF ``mixer_types``): one mixer name a layer —
+    # "attention" (this file's RoPE GQA softmax attention), "minicpm4"
+    # (block-sparse softmax attention, no RoPE, output gate) or
+    # "lightning-attn" (decayed linear attention with a recurrent state) —
+    # models/hybrid.py.  None: every layer is "attention".
+    mixer_types: Optional[Tuple[str, ...]] = None
+    # muP scalars (MiniCPM): the embedding is multiplied by embed_scale,
+    # every residual branch by residual_scale, the final hidden state by
+    # logit_scale before the head
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
+    # lightning-attn: heads (no kv sharing) and their size; 0 = as attention
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
+    # minicpm4 (InfLLM-V2) selection: ops.block_select.SparseSpec
+    sparse_block_size: int = 64
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_init_blocks: int = 1
+    sparse_window_size: int = 2048
+    sparse_topk: int = 64
+    sparse_dense_len: int = 8192
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+
+    def __post_init__(self):
+        if self.mixer_types is not None:
+            # a JSON list: the frozen config must stay hashable for flax
+            object.__setattr__(self, "mixer_types", tuple(self.mixer_types))
+            from neuronx_distributed_tpu.models.hybrid import MIXERS
+
+            bad = sorted(set(self.mixer_types) - set(MIXERS))
+            if bad or len(self.mixer_types) != self.num_layers:
+                raise ValueError(
+                    f"mixer_types names one of {MIXERS} for each of the "
+                    f"{self.num_layers} layers, got {self.mixer_types}")
 
     @property
     def head_dim_(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
+
+    def mixer(self, layer: int) -> str:
+        return "attention" if self.mixer_types is None \
+            else self.mixer_types[layer]
+
+    # What each layer keeps for a live sequence, in the page pool's terms
+    # (``kvcache.pool.CACHE_KINDS``).  The pool, the trace engine and the
+    # serving engine read THESE; which mixer keeps what is models/hybrid.py's
+
+    @property
+    def layer_caches(self) -> Optional[Tuple[str, ...]]:
+        """``"pages"`` (K/V pages), ``"selected_pages"`` (K/V pages the
+        layer chooses among, with compressed keys beside them) or
+        ``"state"`` (a fixed-size recurrent state row) a layer; None without
+        a layer list: every layer keeps pages."""
+        if self.mixer_types is None:
+            return None
+        from neuronx_distributed_tpu.models.hybrid import CACHE_OF
+
+        return tuple(CACHE_OF[m] for m in self.mixer_types)
+
+    def _layers_keeping(self, kind: str) -> Tuple[int, ...]:
+        return tuple(i for i, c in enumerate(self.layer_caches or ())
+                     if c == kind)
+
+    @property
+    def recurrent_layers(self) -> Tuple[int, ...]:
+        """Layers whose per-sequence state is a fixed-size recurrent state
+        (a state row a slot), not K/V pages."""
+        return self._layers_keeping("state")
+
+    @property
+    def selecting_layers(self) -> Tuple[int, ...]:
+        """Layers that choose, a query, the pages they attend."""
+        return self._layers_keeping("selected_pages")
+
+    @property
+    def state_shape(self) -> Tuple[int, ...]:
+        """One recurrent layer's state of one sequence (float32)."""
+        from neuronx_distributed_tpu.models.hybrid import lightning_dims
+
+        nh, d = lightning_dims(self)
+        return (nh, d, d)
+
+    @property
+    def selection_spec(self):
+        """The selecting layers' ``ops.block_select.SparseSpec``; None
+        where no layer selects."""
+        if not self.selecting_layers:
+            return None
+        from neuronx_distributed_tpu.models.hybrid import sparse_spec
+
+        return sparse_spec(self)
 
     @property
     def rope_scaling_(self):
@@ -696,21 +784,42 @@ def row_validity(kv_valid, cache_offset, rows: int, cached: bool):
         kv_valid, jnp.clip(idx, 0, T - 1), axis=1) > 0)
 
 
+def _residual(x, h, scale: float):
+    """``x + scale * h``; a scaled branch (muP ``scale_depth``) is scaled and
+    added in float32 and rounded once."""
+    if scale == 1.0:
+        return x + h
+    return (x.astype(jnp.float32)
+            + scale * h.astype(jnp.float32)).astype(x.dtype)
+
+
 class LlamaBlock(nn.Module):
     config: LlamaConfig
+    mixer: str = "attention"
 
     @nn.compact
     def __call__(self, x, positions, kv_cache=None, cache_offset=0, kv_valid=None,
                  segment_ids=None, block_table=None, adapter=None,
-                 paged_kernel=False):
+                 paged_kernel=False, state_rows=None):
         cfg = self.config
-        h, new_cache = LlamaAttention(cfg, name="attn")(
-            RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                    name="input_norm")(x),
-            positions, kv_cache, cache_offset, kv_valid, segment_ids,
-            block_table, adapter, paged_kernel,
-        )
-        x = x + h
+        normed = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
+                         param_dtype=cfg.param_dtype, name="input_norm")(x)
+        if self.mixer == "attention":
+            h, new_cache = LlamaAttention(cfg, name="attn")(
+                normed, positions, kv_cache, cache_offset, kv_valid,
+                segment_ids, block_table, adapter, paged_kernel,
+            )
+        else:
+            from neuronx_distributed_tpu.models.hybrid import hybrid_mixer
+
+            if adapter is not None or segment_ids is not None:
+                raise ValueError(
+                    f"the {self.mixer!r} mixer takes no LoRA adapter pages "
+                    "and no packed segments")
+            h, new_cache = hybrid_mixer(cfg, self.mixer)(
+                normed, positions, kv_cache, cache_offset, kv_valid,
+                block_table, paged_kernel, state_rows)
+        x = _residual(x, h, cfg.residual_scale)
         normed = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          name="post_attn_norm")(x)
         if cfg.num_experts > 1:
@@ -743,7 +852,7 @@ class LlamaBlock(nn.Module):
             self.sow("losses", "moe_aux", aux)
         else:
             h = LlamaMLP(cfg, name="mlp")(normed)
-        x = x + h
+        x = _residual(x, h, cfg.residual_scale)
         if cfg.sequence_parallel:
             # residual stream lives sequence-sharded between blocks
             x = shard_activation(x, trailing_spec(x.ndim, seq=SEQUENCE_AXES, last=None))
@@ -769,10 +878,18 @@ class LlamaModel(nn.Module):
     @nn.compact
     def __call__(self, ids, positions=None, kv_caches=None, cache_offset=0,
                  kv_valid=None, segment_ids=None, block_table=None,
-                 adapters=None, paged_kernel=False):
+                 adapters=None, paged_kernel=False, state_rows=None):
         cfg = self.config
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
+        seeded = {}
+        if cfg.mixer_types is not None:
+            # a layer list is MiniCPM's: a SEEDED table is drawn as that
+            # family draws it (initialisation only, no program reads it)
+            from neuronx_distributed_tpu.models.hybrid import SEEDED_EMBED_STD
+
+            seeded["embedding_init"] = nn.initializers.normal(
+                stddev=SEEDED_EMBED_STD)
         h = ParallelEmbedding(
             num_embeddings=cfg.vocab_size,
             features=cfg.hidden_size,
@@ -780,7 +897,13 @@ class LlamaModel(nn.Module):
             dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
             name="embed",
+            **seeded,
         )(ids)
+        if cfg.embed_scale != 1.0:
+            h = h * jnp.asarray(cfg.embed_scale, h.dtype)
+        if cfg.mixer_types is not None and cfg.scan_layers:
+            raise ValueError("scan_layers traces ONE block: a model with "
+                             "mixer_types has several kinds")
 
         block_cls = maybe_remat(LlamaBlock, cfg.remat)
 
@@ -810,17 +933,25 @@ class LlamaModel(nn.Module):
             new_caches = []
             for i in range(cfg.num_layers):
                 cache = kv_caches[i] if kv_caches is not None else None
+                # the default layer list leaves the block as it was built
+                # before there was one (same module, same arguments)
+                kind = ({} if cfg.mixer_types is None
+                        else {"mixer": cfg.mixer(i)})
                 if kv_caches is not None:
-                    h, c = LlamaBlock(cfg, name=f"layer_{i}")(
+                    h, c = LlamaBlock(cfg, name=f"layer_{i}", **kind)(
                         h, positions, cache, cache_offset, kv_valid, segment_ids,
                         block_table,
                         adapters[i] if adapters is not None else None,
-                        paged_kernel)
+                        paged_kernel,
+                        **({} if state_rows is None
+                           else {"state_rows": state_rows}))
                 else:
-                    h, c = block_cls(cfg, name=f"layer_{i}")(
+                    h, c = block_cls(cfg, name=f"layer_{i}", **kind)(
                         h, positions, None, 0, kv_valid, segment_ids)
                 new_caches.append(c)
         h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="final_norm")(h)
+        if cfg.logit_scale != 1.0:
+            h = h * jnp.asarray(cfg.logit_scale, h.dtype)
         return (h, new_caches) if kv_caches is not None else (h, None)
 
 
@@ -858,10 +989,11 @@ class LlamaForCausalLM(nn.Module):
 
     def __call__(self, ids, positions=None, kv_caches=None, cache_offset=0,
                  kv_valid=None, segment_ids=None, block_table=None,
-                 adapters=None, paged_kernel=False):
+                 adapters=None, paged_kernel=False, state_rows=None):
         h, new_caches = self.model(
             ids, positions, kv_caches, cache_offset, kv_valid, segment_ids,
-            block_table, adapters, paged_kernel)
+            block_table, adapters, paged_kernel,
+            **({} if state_rows is None else {"state_rows": state_rows}))
         if self.config.sequence_parallel and kv_caches is None:
             # gather the sequence back before the (batched) head matmul
             h = shard_activation(h, trailing_spec(h.ndim, seq=None, last=None))

@@ -79,6 +79,9 @@ _MAX_STEP_TILES = 4
 # score tile; and on its double-buffered page blocks
 _VMEM_BUDGET = 4 * 2 ** 20
 _SUBLANES = 8
+# pages of a block mask read a step (a whole tile; a step attends at most
+# _MAX_STEP_TILES * _TILE_KEYS keys, never more pages than this)
+_MASK_WINDOW = 128
 
 
 def resolve_paged_kernel(flag, platform: Optional[str] = None) -> bool:
@@ -166,7 +169,7 @@ def _keys_tile(buf, dtype):
 
 def _walk_kernel(bt_ref, off_ref, start_ref, q_ref, k_hbm, v_hbm, *rest,
                  sm_scale, page, block_pages, kv_len, group, chunk, window,
-                 softcap, quantized):
+                 softcap, quantized, masked=False):
     """One (slot, kv-head block) program: the walk over the slot's band.
 
     ``rest`` is ``[par_hbm?, o, k_buf, v_buf, par_buf?, sem, m_scr, l_scr,
@@ -176,7 +179,11 @@ def _walk_kernel(bt_ref, off_ref, start_ref, q_ref, k_hbm, v_hbm, *rest,
     D]``, the params' buffer, the DMA semaphores ``[2, 3]`` (buffer x K / V /
     params) and the online-softmax scratch."""
     bp = block_pages
-    par_hbm = par_buf = None
+    par_hbm = par_buf = bm_ref = None
+    if masked:
+        # block-sparse walk (ops.block_select): which pages each query row
+        # attends, [1, PP + _MASK_WINDOW, S] float32, page-major
+        bm_ref, rest = rest[0], rest[1:]
     if quantized:
         par_hbm, o_ref, k_buf, v_buf, par_buf, sem, m_scr, l_scr, acc_scr = rest
     else:
@@ -270,6 +277,23 @@ def _walk_kernel(bt_ref, off_ref, start_ref, q_ref, k_hbm, v_hbm, *rest,
         mask = jnp.logical_and(kpos <= qpos, kpos >= start)
         if window is not None:
             mask = jnp.logical_and(mask, kpos > qpos - window)
+        if masked:
+            # the step's pages out of the per-(page, query row) mask: a
+            # window of pages from the step's first, the query rows spread
+            # over their group by a 0/1 matmul, a column a page
+            win = bm_ref[0, pl.ds(first + i * bp, _MASK_WINDOW), :]
+            spread = (jax.lax.broadcasted_iota(
+                jnp.int32, (rows, win.shape[1]), 0) // group
+                == jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, win.shape[1]), 1)).astype(jnp.bfloat16)
+            per_row = jax.lax.dot_general(
+                spread, win.astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [rows, _MASK_WINDOW]
+            picked = jnp.zeros((rows, width), jnp.float32)
+            for j in range(bp):
+                picked = jnp.where(col // page == j, per_row[:, j:j + 1],
+                                   picked)
+            mask = jnp.logical_and(mask, picked > 0.5)
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_scr[:, :, :1]
@@ -327,11 +351,12 @@ def _page_param_rows(block_table, params, first, last, live, page,
 @functools.partial(
     jax.jit,
     static_argnames=("sm_scale", "window", "softcap", "block_pages",
-                     "interpret"),
+                     "interpret", "name"),
 )
 def _paged_attention_impl(q, kv_pages, block_table, cache_offset, kv_start,
                           sm_scale=None, window=None, softcap=None,
-                          block_pages=None, interpret=None):
+                          block_pages=None, interpret=None, block_mask=None,
+                          name=None):
     quantized = len(kv_pages) == 6
     k_pages, v_pages = kv_pages[:2]
     B, S, NQ, D = q.shape
@@ -367,6 +392,15 @@ def _paged_attention_impl(q, kv_pages, block_table, cache_offset, kv_start,
     operands = [qg, k_pages, v_pages]
     page_buf = pltpu.VMEM((2, heads, bp, page, D), k_pages.dtype)
     scratch = [page_buf, page_buf]
+    if block_mask is not None:
+        # [B, PP, S] (page-major, 1.0 = row s attends page p), padded by a
+        # window of pages so that the last step's read stays inside
+        if heads != NKV or bp > _MASK_WINDOW:
+            raise ValueError("a block mask needs one program a slot")
+        in_specs.append(pl.BlockSpec((1, PP + _MASK_WINDOW, S),
+                                     lambda b, h, *_: (b, 0, 0)))
+        operands.append(jnp.pad(block_mask.astype(jnp.float32),
+                                ((0, 0), (0, _MASK_WINDOW), (0, 0))))
     if quantized:
         in_specs.append(any_space)
         operands.append(_page_param_rows(
@@ -382,7 +416,8 @@ def _paged_attention_impl(q, kv_pages, block_table, cache_offset, kv_start,
 
     kernel = functools.partial(
         _walk_kernel, sm_scale=scale, page=page, block_pages=bp, kv_len=T,
-        group=G, chunk=S, window=window, softcap=softcap, quantized=quantized)
+        group=G, chunk=S, window=window, softcap=softcap, quantized=quantized,
+        **({"masked": True} if block_mask is not None else {}))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -402,8 +437,8 @@ def _paged_attention_impl(q, kv_pages, block_table, cache_offset, kv_start,
             interpret=interp,
             # one query row a slot is the decode step, more is a prefill chunk:
             # the device trace tells them apart by this name
-            name=("paged_attention_decode" if S == 1
-                  else "paged_attention_chunk"),
+            name=name or ("paged_attention_decode" if S == 1
+                          else "paged_attention_chunk"),
         )
 
     o = run_kernel(call, interpret, bt, off, start, *operands)

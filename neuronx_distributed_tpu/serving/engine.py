@@ -556,6 +556,36 @@ class ServingEngine:
                 f"kv_quant must be 'int8' or None, got {kv_quant!r}")
         self._adapters = adapter_store
         self._kv_quant = kv_quant
+        # a model with a layer LIST whose layers keep more than K/V pages
+        # (models/hybrid.py): recurrent layers hold a state row a slot, and
+        # block-sparse layers write a row's pages at a shift of its own.  A
+        # page chain then carries neither a recurrent state nor a layout
+        # another prompt could share: prefix sharing is OFF for such a
+        # model (derived here, no argument), and what is not carried
+        # through these layers raises now rather than run wrong
+        mcfg = getattr(getattr(model, "module", None), "config", None)
+        self._recurrent = bool(getattr(mcfg, "recurrent_layers", ()))
+        self._sparse_spec = getattr(mcfg, "selection_spec", None)
+        if self._recurrent or self._sparse_spec is not None:
+            from neuronx_distributed_tpu.parallel.mesh import (
+                TENSOR_AXIS,
+                get_mesh,
+                model_parallel_is_initialized,
+            )
+
+            refused = [what for what, on in (
+                ("speculative decoding (spec_k): no state roll-back", spec_k),
+                ("an int8 page pool (kv_quant)", kv_quant is not None),
+                ("LoRA adapter pages (adapter_store)",
+                 adapter_store is not None),
+                ("tensor parallelism (tp > 1)",
+                 model_parallel_is_initialized()
+                 and get_mesh().shape[TENSOR_AXIS] > 1)) if on]
+            if refused:
+                raise ValueError(
+                    "not carried through recurrent or page-selecting layers "
+                    "yet: " + "; ".join(refused))
+            prefix_cache = False
         if spec_k:
             # the draft keeps a contiguous [B, T] row a slot (see
             # _prefill_draft_row): the one user of these phase functions
@@ -631,7 +661,7 @@ class ServingEngine:
             num_slots=self.B, context_len=self.C, max_total_len=self.T,
             page_size=page_size, num_pages=num_pages,
             registry=self.registry, prefix_cache=prefix_cache,
-            spec_overshoot=self._spec_k)
+            spec_overshoot=self._spec_k, state_rows=self._recurrent)
         # chunked prefill (Sarathi-style stall-free batching): a prompt's
         # fresh pages trickle into the pool a chunk a step — a PREFILLING
         # slot co-exists with decoding slots, and the chunk width bounds how
@@ -1000,6 +1030,7 @@ class ServingEngine:
         of both KV migration and the fleet-global prefix cache.  Returns
         None when the index does not hold the chain (evicted since the
         directory last synced, or prefix caching off)."""
+        self._refuse_migration()
         if self._kv.index is None:
             return None
         hit = self._kv.index.find_fingerprint(fingerprint)
@@ -1017,6 +1048,7 @@ class ServingEngine:
         chaos kill at ``kvcache/page_import``, leaks nothing).  Returns
         the number of pages actually copied in (0 = already fully cached
         here)."""
+        self._refuse_migration()
         if self._kv.index is None:
             raise TransferError(
                 "engine has no prefix index; cannot import a chain")
@@ -1025,6 +1057,12 @@ class ServingEngine:
         self.caches = import_chain(self.caches, self._kv.index, export,
                                    registry=self.registry)
         return export.n_pages - already
+
+    def _refuse_migration(self) -> None:
+        if self._recurrent or self._sparse_spec is not None:
+            raise TransferError(
+                "KV migration moves page chains: a model with recurrent "
+                "state rows or block-sparse page layouts has none to move")
 
     @property
     def has_work(self) -> bool:
@@ -1187,8 +1225,13 @@ class ServingEngine:
             if active:
                 self._count_paged_walk(active)
                 self._count_decode_write(active)
+                lens = np.asarray([
+                    int(self._offsets[slot]) - self.C + req.prompt_len + 1
+                    for slot, req in active])
                 with phase("serve/dispatch", active=len(active),
-                           ctx_tokens=self._attended_keys(active)):
+                           ctx_tokens=self._attended_keys(active),
+                           **self._count_selection("decode_pages", lens - 1,
+                                                   lens)):
                     if self._spec_k:
                         self._spec_dispatch(active)
                     else:
@@ -1613,10 +1656,15 @@ class ServingEngine:
             try:
                 # ctx_tokens: the keys the chunk's last row attends — its
                 # end in the left-padded row less the pad
+                ctx = off + n_pages * page - (self.C - req.prompt_len)
                 with phase("serve/prefill_chunk", request_id=req.request_id,
                            tok_start=off, width=n_pages * page,
-                           ctx_tokens=off + n_pages * page
-                           - (self.C - req.prompt_len)):
+                           ctx_tokens=ctx,
+                           **self._count_selection(
+                               "prefill_chunk_pages",
+                               np.arange(max(off - (self.C - req.prompt_len),
+                                             0), ctx),
+                               req.prompt_len)):
                     self._dispatch_chunk(slot, st, n_pages)
             except BaseException as e:
                 # transactional like the admission path: the one request
@@ -1680,7 +1728,8 @@ class ServingEngine:
                 jnp.asarray(ids_chunk), off,
                 self._kv.tables[slot][None, :].copy(), self.caches,
                 st.valid_row[None, :].copy(), apool=ad[0], atables=ad[1],
-                paged_kernel=self._paged_kernel, last_row=width - 1)
+                paged_kernel=self._paged_kernel, last_row=width - 1,
+                **({"state_row": slot} if self._recurrent else {}))
         except BaseException as e:
             if t0 is not None:
                 t1 = self._clock()
@@ -1792,6 +1841,40 @@ class ServingEngine:
         self.registry.counter("serving/paged_pages_walked_total").inc(walked)
         self.registry.counter("serving/paged_pages_tabled_total").inc(
             self.B * self._kv.pages_per_slot)
+
+    def _count_selection(self, family: str, positions, lengths) -> dict:
+        """What the coming program's block-sparse layers choose, from the
+        host offsets (no device fetch): for queries at ``positions`` of rows
+        ``lengths`` long, the blocks chosen and the blocks visible, a kv
+        head a layer (``serving/sparse_blocks_selected_total`` and
+        ``..._visible_total``, also by program family) and the queries under
+        the dense rule (``serving/sparse_dense_queries_total``).  Returns
+        the span's ``selected_tokens`` — the keys the program's LAST query
+        attends in a layer: what a selected walk reads, where ``ctx_tokens``
+        is what a dense one would.  Empty for a model that selects nothing."""
+        if self._sparse_spec is None:
+            return {}
+        from neuronx_distributed_tpu.ops.block_select import selection_counts
+
+        spec = self._sparse_spec
+        chosen, visible, dense = selection_counts(positions, lengths, spec)
+        reg = self.registry
+        for name, n in (("selected", chosen), ("visible", visible)):
+            reg.counter(f"serving/sparse_blocks_{name}_total").inc(n)
+            reg.counter(f"serving/sparse_blocks_{name}_total/{family}").inc(n)
+        reg.counter("serving/sparse_dense_queries_total").inc(dense)
+        positions = np.atleast_1d(positions)
+        if family == "decode_pages":
+            # every live slot's one query: blocks before its own are whole
+            tokens = (chosen - len(positions)) * spec.block_size + int(
+                (positions % spec.block_size + 1).sum())
+        elif len(positions):
+            last = int(positions[-1])
+            sel = selection_counts(last, lengths, spec)[0]
+            tokens = (sel - 1) * spec.block_size + last % spec.block_size + 1
+        else:
+            tokens = 0
+        return {"selected_tokens": int(tokens)}
 
     def _count_kv_write(self, rows: int, pages: int) -> None:
         """What the coming program commits to the page pool, a layer: the

@@ -46,6 +46,7 @@ logger = get_logger(__name__)
 PAGES_TOTAL = "kvcache/pages_total"
 PAGES_IN_USE = "kvcache/pages_in_use"
 PAGES_CACHED = "kvcache/pages_cached"
+STATE_ROWS_IN_USE = "kvcache/state_rows_in_use"
 PREFIX_HITS_TOTAL = "kvcache/prefix_hits_total"
 PREFIX_MISSES_TOTAL = "kvcache/prefix_misses_total"
 PREFILL_SKIPPED_TOTAL = "kvcache/prefill_skipped_total"
@@ -64,7 +65,8 @@ class PagedKVManager:
 
     def __init__(self, *, num_slots: int, context_len: int, max_total_len: int,
                  page_size: int, num_pages: int, registry: Any = None,
-                 prefix_cache: bool = True, spec_overshoot: int = 0):
+                 prefix_cache: bool = True, spec_overshoot: int = 0,
+                 state_rows: bool = False):
         if context_len % page_size != 0 or max_total_len % page_size != 0:
             raise ValueError(
                 f"page_size {page_size} must divide context_len "
@@ -96,7 +98,16 @@ class PagedKVManager:
         # parked preemption victims holding resume pins (insertion = park
         # order, so last-resort reclaim drops the oldest park first)
         self._resume: Dict[int, Request] = {}
+        # recurrent layers (kvcache.pool.LayerStates): the second kind of
+        # state this manager accounts — a state row a live sequence, row s
+        # for slot s, taken at admission and released with the slot's pages
+        # (finish, cancel, preemption: a parked request keeps no state and
+        # is recomputed from its prompt).  None: the model has no such layer
+        self.state_rows: Optional[List[Optional[int]]] = (
+            [None] * num_slots if state_rows else None)
         if registry is not None:
+            if state_rows:
+                registry.gauge(STATE_ROWS_IN_USE)
             registry.gauge(PAGES_TOTAL).set(self.alloc.capacity)
             registry.gauge(PAGES_IN_USE)
             registry.gauge(PAGES_CACHED)
@@ -217,6 +228,10 @@ class PagedKVManager:
         self._slot_keys[slot] = keys
         self.tables[slot] = table
         self.tables_dirty = True
+        if self.state_rows is not None:
+            # the sequence's first chunk holds position 0 and starts the
+            # row from zeros (models/hybrid.py): nothing to clear here
+            self.state_rows[slot] = req.request_id
         n_hit = sum(1 for lp, p in enumerate(matched)
                     if not is_padding_key(keys[lp]))
         if self.registry is not None:
@@ -271,6 +286,8 @@ class PagedKVManager:
         the device pages are never touched).  Idempotent — terminal paths
         and the sweep's park can both call it."""
         pages = self._slot_pages[slot]
+        if self.state_rows is not None:
+            self.state_rows[slot] = None
         if not pages and self._slot_keys[slot] is None:
             return
         self.alloc.free_tail(pages)
@@ -385,6 +402,9 @@ class PagedKVManager:
         self.registry.gauge(PAGES_IN_USE).set(self.alloc.in_use)
         self.registry.gauge(PAGES_CACHED).set(
             self.index.evictable_pages() if self.index is not None else 0)
+        if self.state_rows is not None:
+            self.registry.gauge(STATE_ROWS_IN_USE).set(
+                sum(r is not None for r in self.state_rows))
 
     def assert_invariants(self) -> None:
         """Allocator + index invariants, plus the slot-table contract: every
@@ -393,6 +413,18 @@ class PagedKVManager:
         self.alloc.assert_invariants()
         if self.index is not None:
             self.index.assert_invariants()
+        if self.state_rows is not None:
+            assert self.index is None, (
+                "recurrent state rows and a prefix index: a shared page "
+                "chain carries no state")
+            for slot, rid in enumerate(self.state_rows):
+                # a state row is held exactly while its slot holds pages
+                assert (rid is not None) == bool(self._slot_pages[slot]), (
+                    f"slot {slot}: state row held by {rid}, pages "
+                    f"{len(self._slot_pages[slot])}")
+            live = [r for r in self.state_rows if r is not None]
+            assert len(live) == len(set(live)), (
+                f"one request holds two state rows: {live}")
         for slot in range(self.B):
             for p in self._slot_pages[slot]:
                 assert self.alloc.refcount(p) >= 1, (

@@ -725,7 +725,23 @@ class ParallelInferenceModel(_ServingBase):
         self._moe = getattr(mcfg, "num_experts", 1) > 1
         self._moe_stats: collections.deque = collections.deque(maxlen=256)
         self.moe_seq = 0      # paged programs of a routed model run so far
+        # a model with a layer LIST (LlamaConfig.mixer_types): which layers
+        # keep a recurrent state row a sequence and which choose the pages
+        # they attend (the paged programs then also return that choice)
+        self.recurrent = bool(getattr(mcfg, "recurrent_layers", ()))
+        self._sparse = tuple(getattr(mcfg, "selecting_layers", ()))
+        self._sparse_stats: collections.deque = collections.deque(maxlen=256)
         self._build()
+
+    def take_sparse_stats(self) -> list:
+        """One ``{"chosen": [Ls, B, NKV, PP] bool, "program": family}`` of
+        device arrays for each paged program run since the last call,
+        oldest first: the pages (in units of the slot's block table) that
+        the LAST token row of each batch row attended in each block-sparse
+        layer.  Empty for a model without such layers."""
+        out = list(self._sparse_stats)
+        self._sparse_stats.clear()
+        return out
 
     def take_moe_stats(self, upto: Optional[int] = None) -> list:
         """One ``{"load": [L, E], "choice": [L, rows, K]}`` of device
@@ -975,11 +991,16 @@ class ParallelInferenceModel(_ServingBase):
         serving engine's KV state.  ``quant="int8"`` builds the quantized
         layout (int8 pages + per-page fp32 scale/zero; see
         :mod:`~..kvcache.quant`) — roughly 2x the pages per HBM byte."""
-        from neuronx_distributed_tpu.kvcache.pool import PagePool
+        from neuronx_distributed_tpu.kvcache.pool import LayerStates, PagePool
 
+        # a state row a slot: a recurrent layer's batch row b continues row b
+        layers = LayerStates.for_config(
+            getattr(self.module, "config", None), page_size,
+            state_rows=self.config.batch_size)
         return PagePool(self.num_layers, num_pages, page_size,
                         self.num_kv_heads, self.head_dim,
-                        self.config.kv_cache_dtype, quant=quant)
+                        self.config.kv_cache_dtype, quant=quant,
+                        **({} if layers is None else {"layers": layers}))
 
     @staticmethod
     def _pool_tag(caches) -> str:
@@ -999,7 +1020,8 @@ class ParallelInferenceModel(_ServingBase):
 
     def _paged_step_fn(self, params, toks, offsets, block_table, caches,
                        valid, apool=None, atables=None, last_row=None,
-                       paged_kernel=False, update_valid=True, last_only=True):
+                       state_rows=None, paged_kernel=False, update_valid=True,
+                       last_only=True):
         """THE paged phase fn — one parameterized family serving decode,
         multi-adapter decode, speculative verify and chunked prefill (the
         former ``_decode_pages_fn`` / ``_decode_pages_lora_fn`` /
@@ -1052,12 +1074,16 @@ class ParallelInferenceModel(_ServingBase):
             extra["adapters"] = self._gather_adapters(apool, atables)
         if paged_kernel:
             extra["paged_kernel"] = True
+        if state_rows is not None:
+            extra["state_rows"] = state_rows
+        collect = (["moe_stats"] if self._moe else []) + (
+            ["sparse_stats"] if self._sparse else [])
         out = self.module.apply(
             params, toks, positions.astype(jnp.int32), caches, offsets,
             kv_valid=valid, block_table=block_table,
-            mutable=["moe_stats"] if self._moe else False, **extra,
+            mutable=collect or False, **extra,
         )
-        (logits, caches), stats = out if self._moe else (out, None)
+        (logits, caches), stats = out if collect else (out, None)
         if last_only and last_row is not None:
             logits = jax.lax.dynamic_index_in_dim(
                 logits, last_row, axis=1, keepdims=False)
@@ -1068,11 +1094,17 @@ class ParallelInferenceModel(_ServingBase):
 
             return logits, caches, valid, moe_layer_stats(
                 stats, self.num_layers)
+        if self._sparse:
+            layers = stats["sparse_stats"]["model"]
+            return logits, caches, valid, jnp.stack(
+                [layers[f"layer_{i}"]["attn"]["chosen"][-1]
+                 for i in self._sparse])
         return logits, caches, valid
 
     def _paged_phase(self, toks, offsets, block_table, caches, valid,
                      apool=None, atables=None, paged_kernel=None,
-                     update_valid=True, last_only=True, last_row=None):
+                     update_valid=True, last_only=True, last_row=None,
+                     state_rows=None):
         """Compile-cache dispatcher for :meth:`_paged_step_fn`: every
         configuration jits the SAME underlying fn, keyed on its static
         parameterization — (chunk width, pool layout, batch rows, kernel
@@ -1107,22 +1139,40 @@ class ParallelInferenceModel(_ServingBase):
                             update_valid=update_valid, last_only=last_only),
                 donate_argnums=(4,),
                 out_shardings=(None, self._pool_out_shardings(caches), vout)
-                + ((None,) if self._moe else ()))
+                + ((None,) if self._moe or self._sparse else ()))
             fn = self._serving_cache.put(key, fn)
         args = (self.params, toks, jnp.asarray(offsets, jnp.int32),
                 jnp.asarray(block_table, jnp.int32), caches, valid)
         if lora:
             args = args + (apool, jnp.asarray(atables, jnp.int32))
-        out = (fn(*args, last_row=jnp.int32(last_row))
-               if last_row is not None else fn(*args))
+        kw = {}
+        if last_row is not None:
+            kw["last_row"] = jnp.int32(last_row)
+        if self.recurrent:
+            # which state row each batch row continues: its slot — a
+            # decode's rows are the slots, a one-row chunk names its own
+            if lora or not last_only:
+                raise ValueError(
+                    "LoRA pages and speculative verification are not "
+                    "carried through the recurrent (lightning-attn) layers")
+            if state_rows is None:
+                if int(toks.shape[0]) != self.config.batch_size:
+                    raise ValueError(
+                        "a paged program over fewer rows than slots must "
+                        "be told its state rows (state_row=)")
+                state_rows = np.arange(self.config.batch_size)
+            kw["state_rows"] = jnp.asarray(state_rows, jnp.int32)
+        out = fn(*args, **kw)
         if self._moe:
             self.moe_seq += 1
             self._moe_stats.append({**out[3], "program": name,
                                     "seq": self.moe_seq})
+        elif self._sparse:
+            self._sparse_stats.append({"chosen": out[3], "program": name})
         return out[:3]
 
     def decode_pages(self, tok, offsets, block_table, caches, valid,
-                     paged_kernel=None):
+                     paged_kernel=None, state_rows=None):
         """Compiled paged per-slot decode step (page pool donated) — the
         ``S = 1`` member of the :meth:`_paged_step_fn` family.
         ``block_table`` is the ``[B, max_total_len // page_size]`` int32
@@ -1130,9 +1180,12 @@ class ParallelInferenceModel(_ServingBase):
         the int8 six-tuples — each layout compiles its own program).
         ``paged_kernel`` (default: the model's resolved flag) selects the
         block-table-native kernel over the gather path; each value is its
-        own cached program."""
+        own cached program.  ``state_rows [B]`` (a model with recurrent
+        layers only; default: row ``b`` continues state row ``b``) names the
+        state row each batch row continues."""
         return self._paged_phase(tok, offsets, block_table, caches, valid,
-                                 paged_kernel=paged_kernel)
+                                 paged_kernel=paged_kernel,
+                                 state_rows=state_rows)
 
     # -- multi-adapter (tenancy/) phase fns --------------------------------
 
@@ -1230,7 +1283,7 @@ class ParallelInferenceModel(_ServingBase):
 
     def prefill_chunk_pages(self, ids, offset, block_table, caches, valid,
                             apool=None, atables=None, paged_kernel=None,
-                            last_row=None):
+                            last_row=None, state_row=None):
         """Compiled paged chunk prefill (pool donated) — the ``S = Cc``,
         ``update_valid=False`` member of the :meth:`_paged_step_fn` family
         (Sarathi-style chunked prefill for the serving engine), lazily
@@ -1253,13 +1306,20 @@ class ParallelInferenceModel(_ServingBase):
         either later prompt positions (written early, rewritten by their
         own chunk) or invalid cells past the prompt (never committed), so
         one compiled program serves every chunk of every prompt length.
+        ``state_row`` (required for a model with recurrent layers, refused
+        by none) is the slot whose state row the chunk continues: the
+        program is one row wide and its block table says nothing of whose
+        recurrent state it carries; a chunk that holds position 0 starts
+        that row from zeros.
         Returns that row's logits — the chunk's last position by default
         (the final chunk's are the prefill logits the first token samples
         from) — and the updated pool."""
         logits, caches, _ = self._paged_phase(
             ids, jnp.asarray([offset], jnp.int32), block_table, caches,
             valid, apool=apool, atables=atables, paged_kernel=paged_kernel,
-            update_valid=False, last_only=True, last_row=last_row)
+            update_valid=False, last_only=True, last_row=last_row,
+            state_rows=None if state_row is None or not self.recurrent
+            else [int(state_row)])
         return logits, caches
 
     def verify_pages(self, toks, offsets, block_table, caches, valid,
@@ -1296,6 +1356,11 @@ class ParallelInferenceModel(_ServingBase):
         """Compiled pool-internal page copy (pool donated) — the device half
         of the allocator's copy-on-write: duplicate a shared page before
         writing the copy."""
+        if self.recurrent or self._sparse:
+            raise ValueError(
+                "copy_page duplicates a page of every layer: a layer list "
+                "with state rows or compressed keys has no shared pages to "
+                "copy (prefix sharing is off for it)")
         self._serving_lru()
         key = ("copy_page", self._pool_tag(caches))
         fn = self._serving_cache.get(key)

@@ -323,3 +323,82 @@ def test_routed_expert_block_compiles_for_v5e_without_relaying_out_its_weights(t
         params, x).compile()
     assert compiled.as_text().count("tpu_custom_call") == 3
     assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_s512"])
+def test_hybrid_paged_programs_compile_for_v5e_and_copy_no_state(topo, program):
+    """MiniCPM-SALA's paged programs at its PUBLISHED widths and the cell's
+    serving geometry (8 slots of 20,992 tokens, pages of one 64-token block,
+    a 512-row chunk), cut to one period of two layers (a block-sparse
+    softmax layer, a lightning layer): the chosen-table decode walk, the
+    masked chunk walk and the pool writer are Mosaic calls; the pool, the
+    compressed keys and the float32 state rows are donated, aliased to their
+    outputs and never copied."""
+    import functools
+    import re
+
+    from neuronx_distributed_tpu.kvcache.pool import LayerStates
+    from neuronx_distributed_tpu.models.llama import (
+        LlamaConfig,
+        LlamaForCausalLM,
+    )
+    from neuronx_distributed_tpu.trace import (
+        InferenceConfig,
+        ParallelInferenceModel,
+    )
+    from flax import linen as nn
+
+    mesh = _mesh(topo)
+    B, T, page, W, num_pages = 8, 20992, 64, 512, 2689
+    cfg = LlamaConfig(
+        vocab_size=73448, hidden_size=4096, intermediate_size=16384,
+        num_layers=2, num_heads=32, num_kv_heads=2, head_dim=128,
+        max_seq_len=T, rms_eps=1e-6,
+        mixer_types=("minicpm4", "lightning-attn"), embed_scale=12.0,
+        residual_scale=1.4 / 32 ** 0.5, logit_scale=1.0 / 16,
+        lightning_heads=32, lightning_head_dim=128, sequence_parallel=False,
+        remat="none", dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    module = LlamaForCausalLM(cfg)
+    rep = NamedSharding(mesh, P())
+    boxed = jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                           jnp.zeros((1, page), jnp.int32))
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep),
+        nn.unbox(boxed))
+    model = ParallelInferenceModel(
+        module, params,
+        InferenceConfig(batch_size=B, context_len=20480, max_total_len=T,
+                        kv_cache_dtype=jnp.bfloat16))
+    layers = LayerStates.for_config(cfg, page, state_rows=B)
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    pages = sds((num_pages, 2, page, 128), jnp.bfloat16)
+    comp = sds((num_pages, layers.comp_slots, 2, 128), jnp.bfloat16)
+    state = sds((B, 32, 128, 128), jnp.float32)
+    caches = ((pages, pages, comp), (state,))
+    decode = program == "decode"
+    rows, S = (B, 1) if decode else (1, W)
+    fn = jax.jit(functools.partial(
+        model._paged_step_fn, paged_kernel=True, update_valid=decode,
+        last_only=True), donate_argnums=(4,))
+    compiled = fn.lower(
+        params, sds((rows, S)), sds((rows,)), sds((rows, T // page)), caches,
+        sds((rows, T)), state_rows=sds((rows,)),
+        **({} if decode else {"last_row": sds(())})).compile()
+    text = compiled.as_text()
+    kernel = "sparse_attention_decode" if decode else "sparse_attention_chunk"
+    assert f"%{kernel}" in text and text.count("%kv_pool_write") >= 2
+    # (a decode's per-slot state UPDATE has the state array's own shape, 8
+    # slots being 8 rows: for it only a ``copy`` is a copy)
+    for shape, ops in ((f"bf16[{num_pages},2,{page},128]", "copy|transpose"),
+                       (f"bf16[{num_pages},{layers.comp_slots},2,128]",
+                        "copy|transpose"),
+                       (f"f32[{B},32,128,128]", "copy")):
+        copied = [ln.strip()[:120] for ln in text.splitlines()
+                  if re.search(rf"= {re.escape(shape)}\S* ({ops})\(", ln)]
+        assert not copied, f"{shape} is copied: {copied}"
+    memory = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(caches))
+    assert memory.alias_size_in_bytes >= held
