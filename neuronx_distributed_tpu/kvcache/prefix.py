@@ -38,6 +38,7 @@ without compiling anything.
 from __future__ import annotations
 
 import hashlib
+import heapq
 import struct
 from typing import Any, Iterator, List, Optional, Sequence, Set, Tuple
 
@@ -51,6 +52,8 @@ PAD = -1
 SALT_MARK = -2
 
 EVICTIONS_TOTAL = "kvcache/evictions_total"
+# nodes the index examined while evicting: the work behind evictions_total
+EVICT_SCANNED_TOTAL = "kvcache/evict_scanned_total"
 
 PageKey = Tuple[int, ...]
 
@@ -152,7 +155,9 @@ class PrefixIndex:
       its own reference per new page) with an optional terminal payload
       (the prefill's last-position logits);
     - :meth:`evict` reclaims LRU refcount-0 chains leaf-first until enough
-      pages are free.
+      pages are free: one pass over the trie a call, then log(leaves) a
+      page freed (``kvcache/evict_scanned_total`` counts the nodes looked
+      at).
     """
 
     def __init__(self, allocator: BlockAllocator, registry: Any = None):
@@ -168,6 +173,7 @@ class PrefixIndex:
         self._evictable_memo = (-1, -1, 0)
         if registry is not None:
             registry.counter(EVICTIONS_TOTAL)
+            registry.counter(EVICT_SCANNED_TOTAL)
 
     def __len__(self) -> int:
         return self._nodes
@@ -350,22 +356,45 @@ class PrefixIndex:
     def evict(self, need_pages: int) -> int:
         """Evict least-recently-used unpinned leaves until ``need_pages``
         pages were freed (or nothing evictable remains).  Returns the pages
-        actually freed."""
+        actually freed.
+
+        Cost: ONE pass over the trie for the leaves evictable now, then a
+        heap by ``last_used`` — log(leaves) a page freed.  Inside a call no
+        refcount moves but the victims' own, so the only node that can
+        BECOME evictable is a parent whose last child just went: it enters
+        the heap then, under its own ``last_used``.  The victims and their
+        order are those of taking the LRU evictable leaf of the whole trie
+        afresh for every page.  Each call starts from a fresh pass:
+        refcounts change in the allocator between calls without the index
+        hearing of it."""
+        if need_pages <= 0:
+            return 0
+        scanned = 0
+        heap = []
+        for node in self._iter():
+            scanned += 1
+            if self._evictable(node):
+                # ``last_used`` is unique (one clock tick a touch); the
+                # running count keeps a node from ever being compared
+                heap.append((node.last_used, scanned, node))
+        heapq.heapify(heap)
         freed = 0
-        while freed < need_pages:
-            leaf = min(
-                (n for n in self._iter() if self._evictable(n)),
-                key=lambda n: n.last_used, default=None)
-            if leaf is None:
-                break
-            del leaf.parent.children[leaf.key]
+        while freed < need_pages and heap:
+            _, _, leaf = heapq.heappop(heap)
+            parent = leaf.parent
+            del parent.children[leaf.key]
             self._nodes -= 1
             self._version += 1
             if leaf.page != NULL_PAGE:
                 self.alloc.free(leaf.page)
                 freed += 1
-                if self.registry is not None:
-                    self.registry.counter(EVICTIONS_TOTAL).inc()
+            if parent is not self._root:
+                scanned += 1
+                if self._evictable(parent):
+                    heapq.heappush(heap, (parent.last_used, scanned, parent))
+        if self.registry is not None:
+            self.registry.counter(EVICTIONS_TOTAL).inc(freed)
+            self.registry.counter(EVICT_SCANNED_TOTAL).inc(scanned)
         return freed
 
     # -- invariants --------------------------------------------------------

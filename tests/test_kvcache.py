@@ -280,6 +280,211 @@ def test_prefix_index_randomized_churn():
     alloc.assert_invariants()
 
 
+# -- eviction: the same victims in the same order, found with less work -------
+
+def _evict_oracle(index, need_pages):
+    """The eviction rule as it was before the heap, kept as the DEFINITION
+    of the order: take the LRU evictable leaf of the whole trie afresh for
+    every page (pages freed x nodes in the trie)."""
+    freed = 0
+    while freed < need_pages:
+        leaf = min((n for n in index._iter() if index._evictable(n)),
+                   key=lambda n: n.last_used, default=None)
+        if leaf is None:
+            break
+        del leaf.parent.children[leaf.key]
+        index._nodes -= 1
+        index._version += 1
+        if leaf.page != NULL_PAGE:
+            index.alloc.free(leaf.page)
+            freed += 1
+    return freed
+
+
+def _trie(index):
+    """The trie as plain data: every node's key, page, clock and payload."""
+    def walk(node):
+        return {k: (c.page, c.last_used, c.payload, walk(c))
+                for k, c in node.children.items()}
+    return walk(index._root)
+
+
+class _Twins:
+    """Two (allocator, index) pairs fed the same operations: ``new`` evicts
+    with :meth:`PrefixIndex.evict`, ``old`` with the oracle.  After every
+    operation the free lists (the ORDER pages came back in), the refcounts
+    and the tries must be equal."""
+
+    def __init__(self, num_pages):
+        from neuronx_distributed_tpu.obs import MetricRegistry
+        self.reg = MetricRegistry()
+        self.new = PrefixIndex(BlockAllocator(num_pages), registry=self.reg)
+        self.old = PrefixIndex(BlockAllocator(num_pages))
+        self.oracle_freed = 0
+
+    def both(self, fn):
+        got = [fn(self.new), fn(self.old)]
+        assert got[0] == got[1]
+        return got[0]
+
+    def free(self, pages):
+        for ix in (self.new, self.old):
+            for p in pages:
+                ix.alloc.free(p)
+
+    def evict(self, need):
+        got = self.new.evict(need)
+        want = _evict_oracle(self.old, need)
+        self.oracle_freed += want
+        assert got == want
+        return got
+
+    def check(self):
+        assert self.new.alloc._free == self.old.alloc._free
+        assert self.new.alloc._refs == self.old.alloc._refs
+        assert _trie(self.new) == _trie(self.old)
+        assert len(self.new) == len(self.old)
+        self.new.assert_invariants()
+        self.new.alloc.assert_invariants()
+
+
+@pytest.mark.parametrize("seed", range(10))
+def test_evict_frees_the_oracles_pages_in_the_oracles_order(seed):
+    """Randomized churn on twin indexes — chains with leading padding (NULL
+    page) keys, lookups that pin, releases, partial and over-asking evict
+    calls: the heap's victims are the quadratic rule's, page for page."""
+    rs = np.random.RandomState(100 + seed)
+    t = _Twins(num_pages=40)
+    pad = (PAD, PAD)
+    chains = []
+    held = []       # references the "requests" hold, on BOTH twins alike
+    for _ in range(400):
+        op = rs.rand()
+        if op < 0.4:
+            keys = [pad] * rs.randint(0, 3) + _keys(
+                *[(rs.randint(0, 4), rs.randint(0, 4))
+                  for _ in range(rs.randint(1, 5))])
+            matched = t.both(lambda ix: ix.lookup(keys)[0])
+            rest = keys[len(matched):]
+            need = sum(not is_padding_key(k) for k in rest)
+            room = t.both(
+                lambda ix: ix.alloc.free_count + ix.evictable_pages())
+            if need <= room:
+                t.evict(need - t.new.alloc.free_count)   # may be <= 0
+                fresh = t.both(lambda ix: ix.alloc.alloc(need))
+                it = iter(fresh)
+                pages = matched + [NULL_PAGE if is_padding_key(k)
+                                   else next(it) for k in rest]
+                t.both(lambda ix: ix.insert(keys, pages, payload=len(chains)))
+                held.extend(p for p in matched + fresh if p != NULL_PAGE)
+                chains.append(keys)
+            else:
+                t.free(matched)
+        elif op < 0.65 and held:
+            t.free([held.pop(rs.randint(len(held)))])
+        elif op < 0.8 and chains:
+            # a lookup that PINS: the references stay held for a while
+            keys = chains[rs.randint(len(chains))]
+            keys = keys[:rs.randint(1, len(keys) + 1)]
+            matched = t.both(lambda ix: ix.lookup(keys)[0])
+            held.extend(p for p in matched if p != NULL_PAGE)
+        elif op < 0.95:
+            t.evict(rs.randint(1, 4))                    # partial
+        else:
+            t.evict(2 * t.new.alloc.capacity)            # over-asking
+        t.check()
+    t.free(held)
+    t.evict(t.new.alloc.capacity + 1)    # more than there is: NULL leaves too
+    t.check()
+    assert t.new.alloc.in_use == 0 and len(t.new) == 0
+    assert t.oracle_freed > 0
+    assert t.reg.snapshot()["kvcache/evictions_total"] == t.oracle_freed
+
+
+def test_evict_takes_an_exposed_parent_when_it_is_the_lru_leaf():
+    """A parent becomes a leaf only by the removal evict itself makes; it
+    goes next exactly when its own clock is the oldest among the leaves."""
+    def build():
+        alloc = BlockAllocator(num_pages=8)
+        index = PrefixIndex(alloc)
+        a = alloc.alloc(2)
+        b = alloc.alloc(1)
+        index.insert(_keys((1,), (2,)), a)      # clocks: a1 = 1, a2 = 2
+        index.insert(_keys((9,)), b)            # b1 = 3
+        for p in a + b:
+            alloc.free(p)
+        return alloc, index, a, b
+
+    # the parent is older than the other leaf: a2, then a1, and b1 stays
+    alloc, index, a, b = build()
+    assert index.evict(2) == 2
+    assert alloc._free[-2:] == [a[1], a[0]] and alloc.refcount(b[0]) == 1
+    # touched since, the parent is younger than b1: a2, then b1, a1 stays
+    alloc, index, a, b = build()
+    alloc.free(index.lookup(_keys((1,)))[0][0])    # a1 = 4
+    assert index.evict(2) == 2
+    assert alloc._free[-2:] == [a[1], b[0]] and alloc.refcount(a[0]) == 1
+    assert index.peek(_keys((1,), (2,)))[0] == [a[0]]
+    index.assert_invariants()
+
+
+def test_evict_collapses_a_padding_chain_without_counting_it():
+    from neuronx_distributed_tpu.obs import MetricRegistry
+    reg = MetricRegistry()
+    alloc = BlockAllocator(num_pages=8)
+    index = PrefixIndex(alloc, registry=reg)
+    pad = (PAD, PAD)
+    [page] = alloc.alloc(1)
+    index.insert([pad, pad, (5, 6)], [NULL_PAGE, NULL_PAGE, page])
+    alloc.free(page)
+    # asked for what the real page gives: the padding nodes stay (nothing
+    # is searched for once enough is free)
+    assert index.evict(1) == 1 and len(index) == 2
+    # asked for more: the exposed NULL leaves go one after the other, free
+    # nothing and count nothing
+    assert index.evict(3) == 0 and len(index) == 0
+    assert alloc.in_use == 0
+    snap = reg.snapshot()
+    assert snap["kvcache/evictions_total"] == 1.0
+    # 3 nodes passed + 1 parent looked at, then 2 passed + 1 parent
+    assert snap["kvcache/evict_scanned_total"] == 7.0
+    assert index.evict(0) == 0 and index.evict(-2) == 0
+    assert reg.snapshot()["kvcache/evict_scanned_total"] == 7.0
+    index.assert_invariants()
+
+
+def test_evict_work_is_one_pass_and_a_page_not_a_pass_a_page():
+    """The docs cell's geometry (PERF.md, PR 30): a trie of ~4,000 nodes,
+    ~300 pages evicted an admission.  The counter bounds the work at one
+    pass plus a constant a page (the quadratic rule looks at ~N x k nodes),
+    and nothing of it touches a device."""
+    from neuronx_distributed_tpu.obs import MetricRegistry
+    reg = MetricRegistry()
+    chains, depth, k = 16, 250, 300
+    alloc = BlockAllocator(num_pages=chains * depth + 1)
+    index = PrefixIndex(alloc, registry=reg)
+    for c in range(chains):
+        pages = alloc.alloc(depth)
+        index.insert([(c, i) for i in range(depth)], pages)
+        for p in pages:
+            alloc.free(p)
+    n = len(index)
+    assert n == chains * depth == 4000
+    arrays = len(jax.live_arrays())
+    with jax.transfer_guard("disallow"):
+        assert index.evict(k) == k
+    assert len(jax.live_arrays()) == arrays
+    snap = reg.snapshot()
+    assert snap["kvcache/evictions_total"] == k
+    assert n <= snap["kvcache/evict_scanned_total"] <= n + 2 * k
+    # the oldest chain went first, leaf-first, then the next one's tail
+    assert index.peek([(0, 0)])[0] == []
+    assert len(index.peek([(1, i) for i in range(depth)])[0]) == 2 * depth - k
+    assert len(index) == n - k
+    index.assert_invariants()
+    alloc.assert_invariants()
+
+
 # -- page pool sizing -------------------------------------------------------
 
 def test_page_pool_shapes_and_budget_math(devices8):
