@@ -1,0 +1,48 @@
+"""Operations and bytes of a routed expert block that HOLDS a share of its
+experts and whose experts are not gated — the yardstick's own arithmetic for
+the grouped matmuls of ``down(relu(up x)^2)`` experts on one expert-parallel
+rank (``moe_flops.py`` beside it counts three matmuls an assignment and
+every expert: a SwiGLU mixture whole on the chip).
+
+An ASSIGNMENT is one (token, expert) pair; it is HELD where its expert's
+weights are here.  Operations count the held assignments, two matmuls each
+(what this rank's part of the mathematics requires); bytes count the up and
+down weights of the held experts that were HIT — an expert no row chose is
+never read — plus the rows in and out.
+
+Keys are the published ``config.json`` names (``hidden_size``,
+``moe_intermediate_size`` = the width of one routed expert), read from the
+cell's configuration file.
+"""
+
+from __future__ import annotations
+
+from benchmarks.harness import flops
+
+
+def grouped_matmul_flops(assignments_held: float, cfg: dict) -> float:
+    """Up and down of every held assignment: 2 x 2 x H x F each."""
+    return 2.0 * assignments_held * 2 * cfg["hidden_size"] \
+        * cfg["moe_intermediate_size"]
+
+
+def grouped_matmul_bytes(assignments_held: float, experts_hit: float,
+                         cfg: dict, weight_bytes: int = 2,
+                         act_bytes: int = 2) -> float:
+    """Least HBM traffic of the two grouped matmuls of one expert block:
+    the up and down weights of the held experts hit, once; the up reads
+    ``[A, H]`` and writes ``[A, F]``, the down reads ``[A, F]`` and writes
+    ``[A, H]``."""
+    H, F = cfg["hidden_size"], cfg["moe_intermediate_size"]
+    return (experts_hit * 2.0 * H * F * weight_bytes
+            + assignments_held * 2.0 * (H + F) * act_bytes)
+
+
+def expert_block_least_seconds(assignments_held: float, experts_hit: float,
+                               cfg: dict, peak: dict):
+    """The least time of the grouped matmuls of ONE expert block given
+    ``assignments_held`` rows over ``experts_hit`` held experts, and which
+    bound sets it."""
+    return flops.roofline_seconds(
+        grouped_matmul_flops(assignments_held, cfg),
+        grouped_matmul_bytes(assignments_held, experts_hit, cfg), peak)

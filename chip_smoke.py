@@ -33,7 +33,14 @@ window 4096 — only depth is cut, and the cut is printed):
   head geometry) through the same engine: requests finish, the state rows
   and the pages come back, no prefix is shared; logits of the kernel path
   (the chosen-table decode walk, the masked chunk walk) against the gather
-  path.
+  path;
+- **ssm**     — a thin model of ONE-sublayer layers (``mixer_types`` /
+  ``ffn_types``: Mamba-2 scans, attention without RoPE, sigmoid-routed relu2
+  experts with a shared one and HALF of the experts held, at
+  Nemotron-3-Nano's head and state geometry) through the same engine:
+  requests finish, a repeated prompt repeats its tokens, the state rows come
+  back; logits of the kernel path (a 16-head group's chunk walked in parts)
+  against the gather path.
 
 ``--four-chips`` runs ONLY the path that exists across chips and what it is
 compared with: a tp=4 mesh (sequence parallel on) over four real devices —
@@ -100,6 +107,14 @@ REAL = dict(
               prompts=(600, 1297, 2000), probe=(1297,), probe_decodes=2),
     # MiniCPM-SALA's mixers at their published head geometry, thin elsewhere;
     # dense_len low enough that 2300 and 3900 tokens take the sparse rule
+    # Nemotron-3-Nano's mixers at their published geometry (Mamba-2 64 heads
+    # x 64 in 8 groups, state 128; 32 q / 2 kv x 128), thin elsewhere
+    ssm=dict(heads=(32, 2), head_dim=128, hidden=512, vocab=1024,
+             ssm=dict(ssm_heads=64, ssm_head_dim=64, ssm_groups=8,
+                      ssm_state_size=128),
+             experts=(16, 8, 4), expert_width=256, shared_width=512,
+             slots=8, context=1536, total=2304, page=16, chunk=512, new=8,
+             prompts=(300, 1100, 1530), probe_decodes=2),
     hybrid=dict(heads=(32, 2), head_dim=128, hidden=512, mlp=1024, vocab=1024,
                 slots=4, context=4096, total=4608, page=64, chunk=512, new=8,
                 prompts=(700, 2300, 3900), probe_decodes=2,
@@ -124,6 +139,12 @@ TINY = dict(
     four=dict(layers=2, batch=2, seq=128, steps=3, loss_chunk=64,
               slots=4, context=64, total=96, page=8, chunk=16, new=4,
               prompts=(9, 33, 50), probe=(33,), probe_decodes=2),
+    ssm=dict(heads=(4, 2), head_dim=16, hidden=64, vocab=256,
+             ssm=dict(ssm_heads=8, ssm_head_dim=8, ssm_groups=2,
+                      ssm_state_size=16, ssm_chunk_rows=4),
+             experts=(8, 4, 3), expert_width=48, shared_width=96,
+             slots=3, context=48, total=64, page=4, chunk=8, new=3,
+             prompts=(7, 14, 45), probe_decodes=2),
     hybrid=dict(heads=(4, 2), head_dim=16, hidden=64, mlp=96, vocab=256,
                 slots=3, context=48, total=64, page=4, chunk=8, new=3,
                 prompts=(7, 14, 45), probe_decodes=2,
@@ -847,7 +868,7 @@ def phase_serve(size, seed, devices, on_tpu):
 # -- phase: hybrid serve ------------------------------------------------------
 
 
-def phase_serve_hybrid(size, seed, devices, on_tpu):
+def phase_serve_hybrid(size, seed, devices, on_tpu, kind="hybrid"):
     """A layer list (block-sparse softmax + lightning linear attention)
     through the paged engine, then kernel path against gather path."""
     import jax
@@ -867,22 +888,40 @@ def phase_serve_hybrid(size, seed, devices, on_tpu):
         ParallelInferenceModel,
     )
 
-    h = size["hybrid"]
-    mixers = ("minicpm4", "lightning-attn", "lightning-attn", "minicpm4")
-    log(f"[hybrid] layers {mixers}, {h['heads'][0]} q / {h['heads'][1]} kv x "
-        f"{h['head_dim']}; {h['slots']} slots, context {h['context']}, page "
-        f"{h['page']} (= one block), chunks of {h['chunk']}")
+    h = size[kind]
     nxd.initialize_model_parallel(tensor_parallel_size=1, devices=devices[:1])
     dtype = jnp.float32 if size.get("rehearsal") else jnp.bfloat16
-    cfg = LlamaConfig(
+    shared = dict(
         vocab_size=h["vocab"], hidden_size=h["hidden"],
-        intermediate_size=h["mlp"], num_layers=len(mixers),
         num_heads=h["heads"][0], num_kv_heads=h["heads"][1],
-        head_dim=h["head_dim"], max_seq_len=h["total"], rms_eps=1e-6,
-        mixer_types=mixers, embed_scale=12.0, residual_scale=1.4 / 32 ** 0.5,
-        logit_scale=1.0 / 16, lightning_heads=h["heads"][0],
-        lightning_head_dim=h["head_dim"], sequence_parallel=False,
-        remat="none", dtype=dtype, param_dtype=dtype, **h["sparse"])
+        head_dim=h["head_dim"], max_seq_len=h["total"],
+        sequence_parallel=False, remat="none", dtype=dtype, param_dtype=dtype)
+    if kind == "ssm":
+        pattern = "MEM*E"
+        mixers = tuple({"M": "mamba2", "E": "none", "*": "attention"}[c]
+                       for c in pattern)
+        routed, held, top = h["experts"]
+        cfg = LlamaConfig(
+            **shared, intermediate_size=h["expert_width"],
+            num_layers=len(pattern), rms_eps=1e-5, attn_rope=False,
+            mixer_types=mixers, ffn_types=tuple(
+                {"M": "none", "E": "moe", "*": "none"}[c] for c in pattern),
+            num_experts=routed, moe_top_k=top, moe_dispatch="dropless",
+            moe_router_scores="sigmoid", moe_router_bias=True,
+            moe_route_scale=2.5, mlp_activation="relu2",
+            moe_shared_intermediate_size=h["shared_width"],
+            moe_experts_held=(0, held), **h["ssm"])
+    else:
+        mixers = ("minicpm4", "lightning-attn", "lightning-attn", "minicpm4")
+        cfg = LlamaConfig(
+            **shared, intermediate_size=h["mlp"], num_layers=len(mixers),
+            rms_eps=1e-6, mixer_types=mixers, embed_scale=12.0,
+            residual_scale=1.4 / 32 ** 0.5, logit_scale=1.0 / 16,
+            lightning_heads=h["heads"][0], lightning_head_dim=h["head_dim"],
+            **h["sparse"])
+    log(f"[{kind}] layers {mixers}, {h['heads'][0]} q / {h['heads'][1]} kv x "
+        f"{h['head_dim']}; {h['slots']} slots, context {h['context']}, page "
+        f"{h['page']}, chunks of {h['chunk']}")
     module = LlamaForCausalLM(cfg)
     params, _ = init_sharded_params(
         module, jax.random.PRNGKey(seed), jnp.zeros((1, h["page"]), jnp.int32))
@@ -910,16 +949,21 @@ def phase_serve_hybrid(size, seed, devices, on_tpu):
     if sorted(outs) != list(range(len(lens) + 1)) or any(
             o.state != "finished" or len(o.token_ids) != h["new"]
             for o in outs.values()):
-        raise AssertionError(f"hybrid requests did not all finish: {outs}")
+        raise AssertionError(f"{kind} requests did not all finish: {outs}")
     if tuple(outs[0].token_ids) != tuple(outs[len(lens)].token_ids):
         raise AssertionError("a repeated prompt gave other tokens")
     if snap["kvcache/prefix_hits_total"] or snap["kvcache/state_rows_in_use"] \
             or engine._kv.alloc.in_use:
         raise AssertionError(f"state not returned: {snap}")
-    log(f"  {len(outs)} requests finished; blocks chosen / visible "
+    log(f"  {len(outs)} requests finished; " + (
+        f"state rows stepped "
+        f"{snap['serving/ssm_state_rows_stepped_total']:.0f}, assignments "
+        f"held / made {snap['moe/assignments_held_total']:.0f} / "
+        f"{snap['moe/assignments_total']:.0f}" if kind == "ssm" else
+        f"blocks chosen / visible "
         f"{snap['serving/sparse_blocks_selected_total']:.0f} / "
         f"{snap['serving/sparse_blocks_visible_total']:.0f}, dense queries "
-        f"{snap['serving/sparse_dense_queries_total']:.0f}")
+        f"{snap['serving/sparse_dense_queries_total']:.0f}"))
     engine.close()
 
     PP = T // page
@@ -965,7 +1009,7 @@ def phase_serve_hybrid(size, seed, devices, on_tpu):
     tol = REHEARSAL_LOGITS_TOL if size.get("rehearsal") else LOGITS_TOL
     for b, L in enumerate(lens):
         for j in range(nd + 1):
-            check_close(f"hybrid prompt {L}, "
+            check_close(f"{kind} prompt {L}, "
                         + ("prefill" if j == 0 else f"decode step {j - 1}")
                         + " logits, kernels vs gather",
                         kern[(b, j)], gath[(b, j)], tol)
@@ -1106,6 +1150,8 @@ def main():
         phase_serve(size, args.seed, devices, on_tpu)
         gc.collect()
         phase_serve_hybrid(size, args.seed, devices, on_tpu)
+        gc.collect()
+        phase_serve_hybrid(size, args.seed, devices, on_tpu, kind="ssm")
     log(f"[cache] {cache_events['requests']} compile requests, "
         f"{cache_events['hits']} served from the persistent cache, "
         f"{cache_events['requests'] - cache_events['hits']} compiled")

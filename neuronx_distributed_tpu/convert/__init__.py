@@ -27,6 +27,9 @@ from neuronx_distributed_tpu.convert.hf import (  # noqa: F401
     minicpm_sala_config_from_hf,
     minicpm_sala_params_from_hf,
     minicpm_sala_params_to_hf,
+    nemotron_h_config_from_hf,
+    nemotron_h_params_from_hf,
+    nemotron_h_params_to_hf,
     olmoe_params_from_hf,
     olmoe_params_to_hf,
     llama_stack_layers,

@@ -399,6 +399,178 @@ def minicpm_sala_params_to_hf(params: Mapping[str, Any], cfg
 
 
 # ---------------------------------------------------------------------------
+# Nemotron-H (``nemotron_h``: Mamba-2 / attention / routed-expert layers,
+# one sublayer each)
+# ---------------------------------------------------------------------------
+
+# The tensor names of ``modeling_nemotron_h.py`` as this file ASSUMES them
+# (no checkpoint was read here): ``backbone.embeddings``, ``backbone.norm_f``,
+# ``lm_head``; a layer ``backbone.layers.{i}`` holds ``norm`` and ``mixer``,
+# whose tensors depend on the layer's character of
+# ``hybrid_override_pattern``:
+NEMOTRON_H_MIXER_NAMES = {
+    "M": ("in_proj.weight", "conv1d.weight", "conv1d.bias", "dt_bias",
+          "A_log", "D", "norm.weight", "out_proj.weight"),
+    "*": ("q_proj.weight", "k_proj.weight", "v_proj.weight",
+          "o_proj.weight"),
+    "E": ("gate.weight", "gate.e_score_correction_bias",
+          "experts.{e}.up_proj.weight", "experts.{e}.down_proj.weight",
+          "shared_experts.up_proj.weight", "shared_experts.down_proj.weight"),
+}
+_NEMOTRON_H_KINDS = {"M": ("mamba2", "none"), "*": ("attention", "none"),
+                     "E": ("none", "moe"), "-": ("none", "mlp")}
+
+
+def nemotron_h_config_from_hf(hf_config: Mapping[str, Any], **overrides):
+    """The published ``config.json`` of a ``nemotron_h`` model -> a
+    :class:`~..models.llama.LlamaConfig`: ``hybrid_override_pattern`` as the
+    two layer lists, the Mamba-2 geometry, the sigmoid router with its
+    correction bias and scale, relu2 experts and the shared expert, attention
+    without RoPE.  ``moe_experts_held=(first, count)`` (an override) makes it
+    one expert-parallel rank's share."""
+    from neuronx_distributed_tpu.models.llama import LlamaConfig
+
+    c = dict(hf_config)
+    pattern = str(c["hybrid_override_pattern"])
+    if len(pattern) != int(c["num_hidden_layers"]) or "-" in pattern:
+        raise ValueError(
+            "hybrid_override_pattern names M, E or * for each of the "
+            f"num_hidden_layers layers (dense '-' layers are not carried), "
+            f"got {pattern!r}")
+    if c.get("n_group", 1) != 1 or c.get("topk_group", 1) != 1:
+        raise ValueError("group-limited routing is not carried")
+    return LlamaConfig(**{**dict(
+        vocab_size=int(c["vocab_size"]), hidden_size=int(c["hidden_size"]),
+        intermediate_size=int(c["moe_intermediate_size"]),
+        num_layers=len(pattern), num_heads=int(c["num_attention_heads"]),
+        num_kv_heads=int(c["num_key_value_heads"]),
+        head_dim=int(c["head_dim"]), rope_theta=float(c["rope_theta"]),
+        rms_eps=float(c["layer_norm_epsilon"]),
+        max_seq_len=int(c["max_position_embeddings"]), attn_rope=False,
+        mixer_types=tuple(_NEMOTRON_H_KINDS[k][0] for k in pattern),
+        ffn_types=tuple(_NEMOTRON_H_KINDS[k][1] for k in pattern),
+        ssm_heads=int(c["mamba_num_heads"]),
+        ssm_head_dim=int(c["mamba_head_dim"]), ssm_groups=int(c["n_groups"]),
+        ssm_state_size=int(c["ssm_state_size"]),
+        ssm_conv_kernel=int(c["conv_kernel"]),
+        ssm_chunk_rows=int(c["chunk_size"]),
+        ssm_dt_min=float(c["time_step_min"]),
+        ssm_dt_max=float(c["time_step_max"]),
+        ssm_dt_floor=float(c["time_step_floor"]),
+        num_experts=int(c["n_routed_experts"]),
+        moe_top_k=int(c["num_experts_per_tok"]), moe_dispatch="dropless",
+        moe_norm_topk_prob=bool(c["norm_topk_prob"]),
+        moe_router_scores="sigmoid", moe_router_bias=True,
+        moe_route_scale=float(c["routed_scaling_factor"]),
+        mlp_activation=str(c["mlp_hidden_act"]),
+        moe_shared_intermediate_size=int(
+            c["moe_shared_expert_intermediate_size"])), **overrides})
+
+
+def _nemotron_h_held(cfg):
+    return cfg.moe_experts_held or (0, cfg.num_experts)
+
+
+def nemotron_h_params_from_hf(state_dict: Mapping[str, Any], cfg
+                              ) -> Dict[str, Any]:
+    """A ``nemotron_h`` state dict (:data:`NEMOTRON_H_MIXER_NAMES`) -> the
+    param tree of :class:`~..models.llama.LlamaForCausalLM` under the layer
+    lists of :func:`nemotron_h_config_from_hf`: a Mamba layer's convolution
+    ``[C, 1, K]`` as ``conv_weight [K, C]``, a routed layer's held experts
+    stacked (``up [held, F, H]`` as a Linear stores each, ``down [held, F,
+    H]``), everything else transposed in-major."""
+    sd = {k: _np(v) for k, v in state_dict.items()}
+    H = cfg.hidden_size
+    first, count = _nemotron_h_held(cfg)
+    model: Dict[str, Any] = {
+        "embed": {"embedding": sd["backbone.embeddings.weight"]},
+        "final_norm": {"weight": sd["backbone.norm_f.weight"]},
+    }
+    for i in range(cfg.num_layers):
+        p = f"backbone.layers.{i}."
+        m = p + "mixer."
+        norm = {"weight": sd[p + "norm.weight"]}
+        if cfg.mixer(i) == "mamba2":
+            model[f"layer_{i}"] = {"input_norm": norm, "attn": {
+                "in_proj": {"kernel": sd[m + "in_proj.weight"].T},
+                "conv_weight": sd[m + "conv1d.weight"][:, 0, :].T,
+                "conv_bias": sd[m + "conv1d.bias"],
+                "dt_bias": sd[m + "dt_bias"], "A_log": sd[m + "A_log"],
+                "D": sd[m + "D"], "norm_weight": sd[m + "norm.weight"],
+                "out_proj": {"kernel": sd[m + "out_proj.weight"].T}}}
+        elif cfg.mixer(i) == "attention":
+            nq, nkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+            model[f"layer_{i}"] = {"input_norm": norm, "attn": {
+                "qkv": {
+                    "q_kernel": sd[m + "q_proj.weight"].T.reshape(H, nq, d),
+                    "k_kernel": sd[m + "k_proj.weight"].T.reshape(H, nkv, d),
+                    "v_kernel": sd[m + "v_proj.weight"].T.reshape(H, nkv, d)},
+                "o_proj": {"kernel": sd[m + "o_proj.weight"].T}}}
+        else:
+            experts = range(first, first + count)
+            model[f"layer_{i}"] = {"post_attn_norm": norm, "moe_mlp": {
+                "router": sd[m + "gate.weight"].T,
+                "router_bias": sd[m + "gate.e_score_correction_bias"],
+                "up": np.stack([sd[m + f"experts.{e}.up_proj.weight"]
+                                for e in experts]),
+                "down": np.stack([sd[m + f"experts.{e}.down_proj.weight"].T
+                                  for e in experts]),
+                "shared_up": {
+                    "kernel": sd[m + "shared_experts.up_proj.weight"].T},
+                "shared_down": {
+                    "kernel": sd[m + "shared_experts.down_proj.weight"].T}}}
+    return {"params": {"model": model,
+                       "lm_head": {"kernel": sd["lm_head.weight"].T}}}
+
+
+def nemotron_h_params_to_hf(params: Mapping[str, Any], cfg
+                            ) -> Dict[str, np.ndarray]:
+    """Inverse of :func:`nemotron_h_params_from_hf` (the held experts under
+    their own numbers among the routed ones)."""
+    tree = params.get("params", params)
+    model, H = tree["model"], cfg.hidden_size
+    first, count = _nemotron_h_held(cfg)
+    out: Dict[str, np.ndarray] = {
+        "backbone.embeddings.weight": _np(model["embed"]["embedding"]),
+        "backbone.norm_f.weight": _np(model["final_norm"]["weight"]),
+        "lm_head.weight": _np(tree["lm_head"]["kernel"]).T,
+    }
+    for i in range(cfg.num_layers):
+        lyr, p = model[f"layer_{i}"], f"backbone.layers.{i}."
+        m = p + "mixer."
+        out[p + "norm.weight"] = _np(
+            lyr.get("input_norm", lyr.get("post_attn_norm"))["weight"])
+        if cfg.mixer(i) == "mamba2":
+            a = lyr["attn"]
+            out.update({
+                m + "in_proj.weight": _np(a["in_proj"]["kernel"]).T,
+                m + "conv1d.weight": _np(a["conv_weight"]).T[:, None, :],
+                m + "conv1d.bias": _np(a["conv_bias"]),
+                m + "dt_bias": _np(a["dt_bias"]), m + "A_log": _np(a["A_log"]),
+                m + "D": _np(a["D"]), m + "norm.weight": _np(a["norm_weight"]),
+                m + "out_proj.weight": _np(a["out_proj"]["kernel"]).T})
+        elif cfg.mixer(i) == "attention":
+            a = lyr["attn"]
+            for name, key in (("q_proj", "q_kernel"), ("k_proj", "k_kernel"),
+                              ("v_proj", "v_kernel")):
+                out[m + name + ".weight"] = _np(a["qkv"][key]).reshape(H, -1).T
+            out[m + "o_proj.weight"] = _np(a["o_proj"]["kernel"]).T
+        else:
+            moe = lyr["moe_mlp"]
+            out[m + "gate.weight"] = _np(moe["router"]).T
+            out[m + "gate.e_score_correction_bias"] = _np(moe["router_bias"])
+            up, down = _np(moe["up"]), _np(moe["down"])
+            for n in range(count):
+                out[m + f"experts.{first + n}.up_proj.weight"] = up[n]
+                out[m + f"experts.{first + n}.down_proj.weight"] = down[n].T
+            out[m + "shared_experts.up_proj.weight"] = _np(
+                moe["shared_up"]["kernel"]).T
+            out[m + "shared_experts.down_proj.weight"] = _np(
+                moe["shared_down"]["kernel"]).T
+    return out
+
+
+# ---------------------------------------------------------------------------
 # GPT-NeoX
 # ---------------------------------------------------------------------------
 

@@ -25,6 +25,7 @@ pages — a dp-sharded page axis would turn every gather into a collective).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, List, Optional, Tuple
 
 import jax
@@ -49,8 +50,9 @@ GATHER_BYTES_TOTAL = "kvcache/gather_bytes_total"
 
 
 # what a layer keeps for a live sequence: K/V pages; K/V pages it chooses
-# among, with compressed keys beside them; a row of a state array
-CACHE_KINDS = ("pages", "selected_pages", "state")
+# among, with compressed keys beside them; a row of each of its state
+# arrays; nothing (a layer without a mixer)
+CACHE_KINDS = ("pages", "selected_pages", "state", "none")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,19 +61,22 @@ class LayerStates:
     (``kinds``, one of :data:`CACHE_KINDS` a layer): K/V pages for the
     softmax layers only — with ``comp_slots`` compressed keys a page beside
     them where the layer selects pages — and, for the recurrent layers, one
-    row of a ``[state_rows, *state_shape]`` float32 array a live sequence,
-    which is neither paged nor shareable by page."""
+    row a live sequence of each of the layer's state arrays
+    (``state_arrays``: ``(shape, dtype name)`` each, ``[state_rows, *shape]``
+    on the device — a lightning layer's one float32 state, a Mamba-2
+    layer's scan state and convolution taps), which is neither paged nor
+    shareable by page.  A ``"none"`` layer's entry of the pool is ``()``."""
 
     kinds: Tuple[str, ...]
     comp_slots: int = 0
     state_rows: int = 0
-    state_shape: Tuple[int, ...] = ()
+    state_arrays: Tuple[Tuple[Tuple[int, ...], str], ...] = ()
 
     @staticmethod
     def for_config(cfg, page_size: int, state_rows: int
                    ) -> "Optional[LayerStates]":
         """From a model config that says what its layers keep
-        (``layer_caches``, ``state_shape``, ``selection_spec``:
+        (``layer_caches``, ``state_arrays``, ``selection_spec``:
         ``models.llama.LlamaConfig``); None where every layer keeps pages."""
         kinds = getattr(cfg, "layer_caches", None)
         if kinds is None:
@@ -87,7 +92,7 @@ class LayerStates:
             comp_slots=(page_size // spec.kernel_stride if spec is not None
                         else 0),
             state_rows=state_rows if recurrent else 0,
-            state_shape=tuple(cfg.state_shape) if recurrent else ())
+            state_arrays=tuple(cfg.state_arrays) if recurrent else ())
 
     @property
     def recurrent(self) -> int:
@@ -95,15 +100,19 @@ class LayerStates:
 
     @property
     def paged(self) -> int:
-        return len(self.kinds) - self.recurrent
+        return sum(k in ("pages", "selected_pages") for k in self.kinds)
+
+    @property
+    def state_shape(self) -> Tuple[int, ...]:
+        """The first state array's shape: the recurrent state itself."""
+        return self.state_arrays[0][0] if self.state_arrays else ()
 
     @property
     def state_row_bytes(self) -> int:
         """Bytes one state row costs across the recurrent layers."""
-        n = 4
-        for dim in self.state_shape:
-            n *= dim
-        return n * self.recurrent
+        return self.recurrent * sum(
+            math.prod(shape) * jnp.dtype(dt).itemsize
+            for shape, dt in self.state_arrays)
 
 
 def init_page_pool_caches(
@@ -151,16 +160,20 @@ def init_page_pool_caches(
 
     if layers is not None:
         # a layer list: an entry a layer, shaped by what it keeps — ``(k,
-        # v)``, ``(k, v, compressed keys [NP, slots, NKV, D])`` or
-        # ``(states [R, heads, D, D] float32,)``
+        # v)``, ``(k, v, compressed keys [NP, slots, NKV, D])``, a ``[R,
+        # ...]`` array for each of ``state_arrays``, or ``()``
         if quant is not None:
             raise ValueError("an int8 pool is not carried through a layer "
                              "list (selected pages, state rows)")
 
         def entry(kind):
+            if kind == "none":
+                return ()
             if kind == "state":
-                return (jnp.zeros((layers.state_rows,) + layers.state_shape,
-                                  jnp.float32, device=scale_sh),)
+                return tuple(
+                    jnp.zeros((layers.state_rows,) + shape, jnp.dtype(dt),
+                              device=scale_sh)
+                    for shape, dt in layers.state_arrays)
             if kind == "selected_pages":
                 comp = jnp.zeros((num_pages, layers.comp_slots, num_kv_heads,
                                   head_dim), dtype, device=scale_sh)
@@ -265,4 +278,4 @@ def _page_bytes(num_layers, page_size, num_kv_heads, head_dim, dtype, quant,
     comp = (layers.comp_slots * num_kv_heads * head_dim
             * jnp.dtype(dtype).itemsize)
     return sum(per_layer + (comp if k == "selected_pages" else 0)
-               for k in layers.kinds if k != "state")
+               for k in layers.kinds if k in ("pages", "selected_pages"))

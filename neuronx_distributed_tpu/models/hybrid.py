@@ -1,5 +1,6 @@
 """The mixers a ``LlamaConfig.mixer_types`` layer list may name beside the
-block's own ``"attention"`` — MiniCPM-SALA's two:
+block's own ``"attention"`` (and ``"none"``: a layer that is its
+feed-forward part alone) — MiniCPM-SALA's two and Nemotron-H's Mamba-2:
 
 - ``"lightning-attn"``: decayed linear attention (``ops.lightning_attention``)
   — per-head RMSNorm of q and k, RoPE, an RMSNorm over the concatenated
@@ -12,6 +13,12 @@ block's own ``"attention"`` — MiniCPM-SALA's two:
   (``ops.block_select``) — per-head RMSNorm of q and k, NO positional
   encoding, a sigmoid output gate; K/V pages as every softmax layer plus a
   compressed-key cache, and attention over the pages the selection chose.
+- ``"mamba2"``: the Mamba-2 selective scan (``ops.ssm_scan``) — one input
+  projection to ``[z | x B C | dt]``, a causal depthwise convolution over
+  ``x B C``, the scan with its data-dependent decay, a grouped RMSNorm gated
+  by ``silu(z)``.  Its per-sequence state is a state row of TWO arrays: the
+  float32 scan state ``[heads, P, N]`` and the convolution's last ``K - 1``
+  inputs ``[K - 1, channels]`` in the activations' dtype.
 
 Projections, norms and the cache protocol are the block's own
 (``GQAQKVColumnParallelLinear``, ``RowParallelLinear``, ``RMSNorm``): a
@@ -36,13 +43,13 @@ from neuronx_distributed_tpu.parallel.qkv import (
     Q_HEAD_AXES,
 )
 
-MIXERS = ("attention", "minicpm4", "lightning-attn")
+MIXERS = ("attention", "minicpm4", "lightning-attn", "mamba2", "none")
 # what each mixer keeps for a live sequence, in the page pool's terms
 # (``kvcache.pool.CACHE_KINDS``): the one place a mixer's name decides it —
 # ``LlamaConfig.layer_caches`` hands it on, and the pool and the engines
 # read the config
 CACHE_OF = {"attention": "pages", "minicpm4": "selected_pages",
-            "lightning-attn": "state"}
+            "lightning-attn": "state", "mamba2": "state", "none": "none"}
 # the standard deviation a SEEDED embedding table of a layer-list model is
 # drawn with: the MiniCPM family's ``initializer_range``.  With muP's 12 x
 # embedding the table then leads the residual stream, as in a trained model;
@@ -66,6 +73,24 @@ def lightning_dims(cfg):
     """``(heads, head size)`` of the lightning layers."""
     return (cfg.lightning_heads or cfg.num_heads,
             cfg.lightning_head_dim or cfg.head_dim_)
+
+
+def ssm_dims(cfg):
+    """``(heads, head size P, groups G, state size N, convolution taps K)``
+    of the Mamba-2 layers."""
+    return (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+            cfg.ssm_state_size, cfg.ssm_conv_kernel)
+
+
+def state_arrays(cfg, kind: str):
+    """``((shape, dtype name), ...)``: the arrays of ONE recurrent layer's
+    state of one sequence, for the mixer ``kind``."""
+    if kind == "lightning-attn":
+        nh, d = lightning_dims(cfg)
+        return (((nh, d, d), "float32"),)
+    nh, p, g, n, k = ssm_dims(cfg)
+    return (((nh, p, n), "float32"),
+            ((k - 1, nh * p + 2 * g * n), jnp.dtype(cfg.dtype).name))
 
 
 def encode_positions(cfg, kind: str, q, k, positions):
@@ -150,8 +175,7 @@ class LightningMixer(_GatedMixer):
                 with jax.named_scope("state_read"):
                     state = states[state_rows]
                     # a call that holds position 0 begins its sequence
-                    fresh = jnp.any((positions == 0) & (
-                        live if live is not None else True), axis=1)
+                    fresh = _fresh(positions, live)
                     state = jnp.where(fresh[:, None, None, None], 0.0, state)
                 o, state = lightning_attention(q, k, v, live, state)
                 with jax.named_scope("state_write"):
@@ -260,7 +284,121 @@ def _uncached_sparse(q, k, v, positions, kv_valid, spec):
     return out.reshape(B, S, NQ, D)
 
 
+def _fresh(positions, live):
+    """Which batch rows hold position 0 in this call: they begin their
+    sequence, whatever their state row held."""
+    return jnp.any((positions == 0) & (live if live is not None else True),
+                   axis=1)
+
+
+def _mamba_dt_bias(cfg):
+    """Mamba-2's own draw: ``dt`` log-uniform in ``[dt_min, dt_max]``,
+    floored, stored as its inverse softplus."""
+    lo, hi, floor = cfg.ssm_dt_min, cfg.ssm_dt_max, cfg.ssm_dt_floor
+
+    def init(key, shape, dtype):
+        import math
+
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
+                     * (math.log(hi) - math.log(lo)) + math.log(lo))
+        dt = jnp.maximum(dt, floor)
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+    return init
+
+
+class Mamba2Mixer(nn.Module):
+    config: object
+
+    @nn.compact
+    def __call__(self, x, positions, kv_cache=None, cache_offset=0,
+                 kv_valid=None, block_table=None, paged_kernel=False,
+                 state_rows=None):
+        from neuronx_distributed_tpu.models.llama import row_validity
+        from neuronx_distributed_tpu.ops.ssm_scan import causal_conv, ssm_scan
+
+        cfg = self.config
+        NH, P, G, N, K = ssm_dims(cfg)
+        d_inner, conv_ch = NH * P, NH * P + 2 * G * N
+        B, S = x.shape[0], x.shape[1]
+        f32 = jnp.float32
+        small = lambda name, init, shape, dtype: jnp.asarray(self.param(  # noqa: E731
+            name, nn.with_partitioning(init, (None,) * len(shape)), shape,
+            dtype))
+        from neuronx_distributed_tpu.parallel.moe import per_expert_lecun
+
+        # (seeded weights are drawn in float32 and rounded: per_expert_lecun)
+        proj = ColumnParallelLinear(
+            features=2 * d_inner + 2 * G * N + NH, use_bias=False,
+            sequence_parallel=cfg.sequence_parallel, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, kernel_init=per_expert_lecun,
+            name="in_proj")(x)
+        z, xbc, dt = jnp.split(proj, [d_inner, d_inner + conv_ch], axis=-1)
+        bound = K ** -0.5       # torch's Conv1d draw at a fan-in of K
+        uniform = lambda key, shape, dtype: jax.random.uniform(  # noqa: E731
+            key, shape, f32, -bound, bound).astype(dtype)
+        conv_w = small("conv_weight", uniform, (K, conv_ch), cfg.param_dtype)
+        conv_b = small("conv_bias", uniform, (conv_ch,), cfg.param_dtype)
+        # the scan's own scalars a head stay float32 whatever the weights are
+        dt_bias = small("dt_bias", _mamba_dt_bias(cfg), (NH,), f32)
+        A_log = small("A_log", lambda key, shape, dtype: jnp.log(
+            jax.random.uniform(key, shape, dtype, 1.0, 16.0)), (NH,), f32)
+        D = small("D", nn.initializers.ones, (NH,), f32)
+        live = row_validity(kv_valid, cache_offset, S, kv_cache is not None)
+        new_cache = None
+        if kv_cache is None:
+            state = jnp.zeros((B, NH, P, N), f32)
+            taps = jnp.zeros((B, K - 1, conv_ch), cfg.dtype)
+        else:
+            states, all_taps = kv_cache
+            # ``state_rows`` None: batch row b continues state row b (a
+            # decode of every slot).  The arrays are then stepped where
+            # they lie: gathering 64 rows of 2 MiB at traced ids, stepping
+            # and scattering them back took 1.62 ms a layer on the v5e,
+            # the step on the array itself 0.51 (PERF.md, PR 32, step 0)
+            whole = state_rows is None
+            if whole and states.shape[0] != B:
+                raise ValueError(
+                    "a recurrent layer's cached call over fewer rows than "
+                    "state rows needs state_rows: which row of the state "
+                    "arrays each batch row continues")
+            with jax.named_scope("state_read"):
+                fresh = _fresh(positions, live)
+                state = jnp.where(fresh[:, None, None, None], 0.0,
+                                  states if whole else states[state_rows])
+                taps = jnp.where(fresh[:, None, None], 0,
+                                 all_taps if whole else all_taps[state_rows])
+        xbc, taps = causal_conv(xbc, taps, conv_w, conv_b, live)
+        xs, Bm, Cm = jnp.split(xbc, [d_inner, d_inner + G * N], axis=-1)
+        dt = jax.nn.softplus(dt.astype(f32) + dt_bias)
+        y, state = ssm_scan(
+            xs.reshape(B, S, NH, P), Bm.reshape(B, S, G, N),
+            Cm.reshape(B, S, G, N), dt, -jnp.exp(A_log), D, live, state,
+            cfg.ssm_chunk_rows)
+        if kv_cache is not None:
+            with jax.named_scope("state_write"):
+                new_cache = (state, taps) if whole else (
+                    states.at[state_rows].set(state),
+                    all_taps.at[state_rows].set(taps))
+        # the gated norm: RMSNorm over each group's channels of y * silu(z)
+        y = y.reshape(B, S, d_inner) * jax.nn.silu(z.astype(f32))
+        yg = y.reshape(B, S, G, d_inner // G)
+        yg = yg * jax.lax.rsqrt(
+            jnp.mean(yg * yg, axis=-1, keepdims=True) + cfg.rms_eps)
+        norm_w = small("norm_weight", nn.initializers.ones, (d_inner,),
+                       cfg.param_dtype)
+        y = (yg.reshape(B, S, d_inner) * norm_w.astype(f32)).astype(cfg.dtype)
+        return RowParallelLinear(
+            features=cfg.hidden_size, use_bias=False,
+            sequence_parallel=cfg.sequence_parallel,
+            input_partition_axes=Q_HEAD_AXES, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, kernel_init=per_expert_lecun,
+            name="out_proj")(y), new_cache
+
+
 def hybrid_mixer(cfg, kind: str):
+    if kind == "mamba2":
+        return Mamba2Mixer(cfg, name="attn")
     if kind == "lightning-attn":
         return LightningMixer(cfg, name="attn")
     if kind == "minicpm4":

@@ -152,12 +152,47 @@ class LlamaConfig:
     lora_rank: int = 0
     lora_alpha: float = 16.0
     lora_targets: Tuple[str, ...] = ("qkv",)
+    # the routed block of the DeepSeek-V3 / Nemotron-H family, on the
+    # dropless path (parallel/moe.py): "sigmoid" scores each expert on its
+    # own; a correction bias is added for the CHOICE only; the chosen
+    # scores, renormalised where moe_norm_topk_prob, are multiplied by
+    # moe_route_scale; a shared expert of its own width runs beside them;
+    # with mlp_activation "relu2" an expert is down(relu(up x)^2), no gate
+    moe_router_scores: str = "softmax"
+    moe_router_bias: bool = False
+    moe_route_scale: float = 1.0
+    moe_shared_intermediate_size: int = 0
+    # ``(first, count)``: the contiguous range of the num_experts routed
+    # experts whose weights THIS program holds — one rank's share of an
+    # expert-parallel layer, served without its exchange: routing is over
+    # all of them, the sum over the chosen ones that are held.  None: all
+    moe_experts_held: Optional[Tuple[int, int]] = None
     # the layer list as DATA (HF ``mixer_types``): one mixer name a layer —
-    # "attention" (this file's RoPE GQA softmax attention), "minicpm4"
-    # (block-sparse softmax attention, no RoPE, output gate) or
-    # "lightning-attn" (decayed linear attention with a recurrent state) —
-    # models/hybrid.py.  None: every layer is "attention".
+    # "attention" (this file's GQA softmax attention), "minicpm4"
+    # (block-sparse softmax attention, no RoPE, output gate),
+    # "lightning-attn" (decayed linear attention with a recurrent state),
+    # "mamba2" (the Mamba-2 selective scan, a scan state and a convolution
+    # state) — models/hybrid.py — or "none": the layer has no mixer.  None:
+    # every layer is "attention".
     mixer_types: Optional[Tuple[str, ...]] = None
+    # its feed-forward parts, likewise: "mlp", "moe" (num_experts > 1) or
+    # "none" a layer.  None: every layer has the one num_experts implies.
+    # A layer with one of the two "none" is ONE sublayer, x + f(norm(x))
+    ffn_types: Optional[Tuple[str, ...]] = None
+    # RoPE on the "attention" mixer's q and k (Nemotron-H's has none)
+    attn_rope: bool = True
+    # mamba2: heads and their size P, groups sharing B and C, state size N,
+    # convolution taps, rows of a block of the chunked scan; the last three
+    # are how a SEEDED dt_bias is drawn (Mamba-2's own initialisation)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state_size: int = 128
+    ssm_conv_kernel: int = 4
+    ssm_chunk_rows: int = 128
+    ssm_dt_min: float = 0.001
+    ssm_dt_max: float = 0.1
+    ssm_dt_floor: float = 1e-4
     # muP scalars (MiniCPM): the embedding is multiplied by embed_scale,
     # every residual branch by residual_scale, the final hidden state by
     # logit_scale before the head
@@ -189,6 +224,32 @@ class LlamaConfig:
                 raise ValueError(
                     f"mixer_types names one of {MIXERS} for each of the "
                     f"{self.num_layers} layers, got {self.mixer_types}")
+            if {"lightning-attn", "mamba2"} <= set(self.mixer_types):
+                raise ValueError(
+                    "one kind of recurrent layer a model: a state row is "
+                    "one tuple of arrays")
+        if self.ffn_types is not None:
+            object.__setattr__(self, "ffn_types", tuple(self.ffn_types))
+            kinds = ("mlp", "moe", "none")
+            if set(self.ffn_types) - set(kinds) \
+                    or len(self.ffn_types) != self.num_layers:
+                raise ValueError(
+                    f"ffn_types names one of {kinds} for each of the "
+                    f"{self.num_layers} layers, got {self.ffn_types}")
+            if "moe" in self.ffn_types and self.num_experts < 2:
+                raise ValueError("ffn_types names 'moe': num_experts > 1")
+            if any(m == "none" and f == "none" for m, f in zip(
+                    self.mixer_types or (), self.ffn_types)):
+                raise ValueError("a layer with no mixer and no "
+                                 "feed-forward part is no layer")
+        if self.moe_experts_held is not None:
+            first, count = self.moe_experts_held
+            object.__setattr__(self, "moe_experts_held",
+                               (int(first), int(count)))
+            if not 0 <= first < first + count <= self.num_experts:
+                raise ValueError(
+                    f"moe_experts_held (first, count) = ({first}, {count}) "
+                    f"is no range of the {self.num_experts} experts")
 
     @property
     def head_dim_(self) -> int:
@@ -198,6 +259,17 @@ class LlamaConfig:
         return "attention" if self.mixer_types is None \
             else self.mixer_types[layer]
 
+    def ffn(self, layer: int) -> str:
+        if self.ffn_types is not None:
+            return self.ffn_types[layer]
+        return "moe" if self.num_experts > 1 else "mlp"
+
+    @property
+    def moe_layers(self) -> Tuple[int, ...]:
+        """Layers whose feed-forward part is the routed block."""
+        return tuple(i for i in range(self.num_layers)
+                     if self.ffn(i) == "moe")
+
     # What each layer keeps for a live sequence, in the page pool's terms
     # (``kvcache.pool.CACHE_KINDS``).  The pool, the trace engine and the
     # serving engine read THESE; which mixer keeps what is models/hybrid.py's
@@ -205,9 +277,10 @@ class LlamaConfig:
     @property
     def layer_caches(self) -> Optional[Tuple[str, ...]]:
         """``"pages"`` (K/V pages), ``"selected_pages"`` (K/V pages the
-        layer chooses among, with compressed keys beside them) or
-        ``"state"`` (a fixed-size recurrent state row) a layer; None without
-        a layer list: every layer keeps pages."""
+        layer chooses among, with compressed keys beside them), ``"state"``
+        (a fixed-size recurrent state row) or ``"none"`` (a layer without a
+        mixer keeps nothing) a layer; None without a layer list: every
+        layer keeps pages."""
         if self.mixer_types is None:
             return None
         from neuronx_distributed_tpu.models.hybrid import CACHE_OF
@@ -230,12 +303,16 @@ class LlamaConfig:
         return self._layers_keeping("selected_pages")
 
     @property
-    def state_shape(self) -> Tuple[int, ...]:
-        """One recurrent layer's state of one sequence (float32)."""
-        from neuronx_distributed_tpu.models.hybrid import lightning_dims
+    def state_arrays(self) -> Tuple[Tuple[Tuple[int, ...], str], ...]:
+        """``((shape, dtype name), ...)``: the arrays of one recurrent
+        layer's state of one sequence — the lightning layers' one float32
+        state, Mamba-2's scan state and convolution taps; () without a
+        recurrent layer."""
+        if not self.recurrent_layers:
+            return ()
+        from neuronx_distributed_tpu.models.hybrid import state_arrays
 
-        nh, d = lightning_dims(self)
-        return (nh, d, d)
+        return state_arrays(self, self.mixer(self.recurrent_layers[0]))
 
     @property
     def selection_spec(self):
@@ -520,9 +597,11 @@ class LlamaAttention(nn.Module):
 
             q = full_width_norm(q, "q_norm")
             k = full_width_norm(k, "k_norm")
-        sin, cos = rope_sin_cos(positions, D, cfg.rope_theta, cfg.rope_scaling_)
-        q = apply_rope(q, sin, cos)
-        k = apply_rope(k, sin, cos)
+        if cfg.attn_rope:
+            sin, cos = rope_sin_cos(positions, D, cfg.rope_theta,
+                                    cfg.rope_scaling_)
+            q = apply_rope(q, sin, cos)
+            k = apply_rope(k, sin, cos)
 
         new_cache = None
         if kv_cache is not None:
@@ -796,41 +875,73 @@ def _residual(x, h, scale: float):
 class LlamaBlock(nn.Module):
     config: LlamaConfig
     mixer: str = "attention"
+    # "mlp" | "moe" | "none"; "" is what the config's num_experts implies
+    ffn: str = ""
 
     @nn.compact
     def __call__(self, x, positions, kv_cache=None, cache_offset=0, kv_valid=None,
                  segment_ids=None, block_table=None, adapter=None,
                  paged_kernel=False, state_rows=None):
         cfg = self.config
-        normed = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
-                         param_dtype=cfg.param_dtype, name="input_norm")(x)
-        if self.mixer == "attention":
-            h, new_cache = LlamaAttention(cfg, name="attn")(
-                normed, positions, kv_cache, cache_offset, kv_valid,
-                segment_ids, block_table, adapter, paged_kernel,
-            )
-        else:
-            from neuronx_distributed_tpu.models.hybrid import hybrid_mixer
+        # a layer without a mixer keeps nothing: its pool entry, () when
+        # cached, goes back as it came
+        new_cache = kv_cache
+        if self.mixer != "none":
+            normed = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
+                             param_dtype=cfg.param_dtype, name="input_norm")(x)
+            if self.mixer == "attention":
+                h, new_cache = LlamaAttention(cfg, name="attn")(
+                    normed, positions, kv_cache, cache_offset, kv_valid,
+                    segment_ids, block_table, adapter, paged_kernel,
+                )
+            else:
+                from neuronx_distributed_tpu.models.hybrid import hybrid_mixer
 
-            if adapter is not None or segment_ids is not None:
-                raise ValueError(
-                    f"the {self.mixer!r} mixer takes no LoRA adapter pages "
-                    "and no packed segments")
-            h, new_cache = hybrid_mixer(cfg, self.mixer)(
-                normed, positions, kv_cache, cache_offset, kv_valid,
-                block_table, paged_kernel, state_rows)
-        x = _residual(x, h, cfg.residual_scale)
+                if adapter is not None or segment_ids is not None:
+                    raise ValueError(
+                        f"the {self.mixer!r} mixer takes no LoRA adapter "
+                        "pages and no packed segments")
+                h, new_cache = hybrid_mixer(cfg, self.mixer)(
+                    normed, positions, kv_cache, cache_offset, kv_valid,
+                    block_table, paged_kernel, state_rows)
+            x = _residual(x, h, cfg.residual_scale)
+        ffn = self.ffn or ("moe" if cfg.num_experts > 1 else "mlp")
+        if ffn == "none":
+            return self._out(x), new_cache
         normed = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          name="post_attn_norm")(x)
-        if cfg.num_experts > 1:
-            from neuronx_distributed_tpu.parallel.moe import ExpertParallelMLP
+        if ffn == "moe":
+            from neuronx_distributed_tpu.parallel.moe import (
+                ExpertParallelMLP,
+                per_expert_lecun,
+            )
 
             # served (a cache is there) without capacity whatever the model
             # trains with: a request's logits may not depend on its co-batch
             dropless = kv_cache is not None or cfg.moe_dispatch == "dropless"
+            # the sigmoid-routed family's arguments, where the config has
+            # them: a config without builds the module it built before
+            family = {k: v for k, v, default in (
+                ("router_scores", cfg.moe_router_scores, "softmax"),
+                ("router_bias", cfg.moe_router_bias, False),
+                ("route_scale", cfg.moe_route_scale, 1.0),
+                ("activation", "relu2" if cfg.mlp_activation == "relu2"
+                 else "silu", "silu"),
+                ("shared_intermediate_size",
+                 cfg.moe_shared_intermediate_size, 0)) if v != default}
+            if family:
+                # initialisation only: a seeded expert of this family is
+                # drawn at its own fan-in, so that the routed block is a
+                # visible part of a seeded model's output
+                family["kernel_init"] = per_expert_lecun
+            held = cfg.moe_experts_held
+            if held is not None:
+                family.update(first_expert=held[0])
             moe = ExpertParallelMLP(
-                num_experts=cfg.moe_local_experts or cfg.num_experts,
-                num_experts_global=cfg.num_experts if cfg.moe_local_experts else 0,
+                num_experts=(held[1] if held is not None
+                             else cfg.moe_local_experts or cfg.num_experts),
+                num_experts_global=(cfg.num_experts if held is not None
+                                    or cfg.moe_local_experts else 0),
                 intermediate_size=cfg.intermediate_size,
                 top_k=cfg.moe_top_k,
                 capacity_factor=cfg.moe_capacity_factor,
@@ -841,6 +952,7 @@ class LlamaBlock(nn.Module):
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 name="moe_mlp",
+                **family,
             )
             # the expert block is the layer's MLP in a device trace too
             with jax.named_scope("mlp"):
@@ -853,21 +965,29 @@ class LlamaBlock(nn.Module):
         else:
             h = LlamaMLP(cfg, name="mlp")(normed)
         x = _residual(x, h, cfg.residual_scale)
-        if cfg.sequence_parallel:
+        return self._out(x), new_cache
+
+    def _out(self, x):
+        if self.config.sequence_parallel:
             # residual stream lives sequence-sharded between blocks
             x = shard_activation(x, trailing_spec(x.ndim, seq=SEQUENCE_AXES, last=None))
-        return x, new_cache
+        return x
 
 
-def moe_layer_stats(variables, num_layers: int) -> dict:
+def moe_layer_stats(variables, layers) -> dict:
     """The ``moe_stats`` collection of one apply of a
-    :class:`LlamaForCausalLM`, stacked over layers: ``load [L, E]``, the
-    valid assignments each expert took in that call, and ``choice [L, rows,
-    K]``, each row's experts (``parallel/moe.py``, dropless path)."""
-    layers = variables["moe_stats"]["model"]
-    return {k: jnp.stack([layers[f"layer_{i}"]["moe_mlp"][k][-1]
-                          for i in range(num_layers)])
-            for k in ("load", "choice")}
+    :class:`LlamaForCausalLM`, stacked over its routed layers (``layers``:
+    their indices, or how many layers where every one is routed): ``load
+    [L, E]``, the valid assignments each expert held took in that call,
+    ``choice [L, rows, K]``, each row's experts (``parallel/moe.py``,
+    dropless path), and, where the layer holds a share of its experts,
+    ``assigned [L]``, the valid assignments whether held or not."""
+    stats = variables["moe_stats"]["model"]
+    layers = range(layers) if isinstance(layers, int) else layers
+    first = stats[f"layer_{layers[0]}"]["moe_mlp"]
+    return {k: jnp.stack([stats[f"layer_{i}"]["moe_mlp"][k][-1]
+                          for i in layers])
+            for k in ("load", "choice", "assigned") if k in first}
 
 
 class LlamaModel(nn.Module):
@@ -935,8 +1055,10 @@ class LlamaModel(nn.Module):
                 cache = kv_caches[i] if kv_caches is not None else None
                 # the default layer list leaves the block as it was built
                 # before there was one (same module, same arguments)
-                kind = ({} if cfg.mixer_types is None
-                        else {"mixer": cfg.mixer(i)})
+                kind = {**({} if cfg.mixer_types is None
+                           else {"mixer": cfg.mixer(i)}),
+                        **({} if cfg.ffn_types is None
+                           else {"ffn": cfg.ffn(i)})}
                 if kv_caches is not None:
                     h, c = LlamaBlock(cfg, name=f"layer_{i}", **kind)(
                         h, positions, cache, cache_offset, kv_valid, segment_ids,

@@ -78,6 +78,12 @@ _MAX_STEP_TILES = 4
 # tile of scores (what grows with the kv heads it takes); on a step's whole
 # score tile; and on its double-buffered page blocks
 _VMEM_BUDGET = 4 * 2 ** 20
+# query rows of ONE kv head (group x chunk rows) a program may hold: at 128
+# lanes its q and out blocks (double-buffered) and the m, l and accumulator
+# scratch are 2 KiB a row, and 8192 rows overran the 16 MiB of scoped VMEM
+# by 132 KiB and 4096 by 116 KiB (a group of 16 x a chunk of 512 and its half,
+# AOT for a v5e, PR 32); 3584 (a group of 7 x 512) is the most the chip has run
+_MAX_HEAD_ROWS = 3584
 _SUBLANES = 8
 # pages of a block mask read a step (a whole tile; a step attends at most
 # _MAX_STEP_TILES * _TILE_KEYS keys, never more pages than this)
@@ -506,6 +512,21 @@ def paged_attention(
             f"q heads ({q.shape[2]}) must group over kv heads ({nkv})")
     kw = dict(sm_scale=sm_scale, window=window, softcap=softcap,
               block_pages=block_pages, interpret=interpret)
+    S = q.shape[1]
+    parts = 1
+    while (q.shape[2] // nkv) * (S // parts) > _MAX_HEAD_ROWS \
+            and S % (2 * parts) == 0:
+        parts *= 2
+    if parts > 1:
+        # a wide group times a long chunk: a program's rows (one kv head's
+        # queries, their output, m, l and the accumulator) would not fit
+        # VMEM, so the chunk's query rows are walked in parts — exact, a
+        # row's keys are those at or before ITS cell
+        step = S // parts
+        return jnp.concatenate([paged_attention(
+            q[:, i * step:(i + 1) * step], kv_pages, block_table,
+            cache_offset + i * step, kv_start, **kw) for i in range(parts)],
+            axis=1)
     wrap = _tp_shard_mapped(q.shape[2], nkv)
     if wrap is not None:
         if kv_start is None:

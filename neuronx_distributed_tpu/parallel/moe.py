@@ -34,6 +34,20 @@ result is a function of that row alone.  Rows marked invalid (a chunk's
 padding, a parked slot) are routed nowhere.  It is the path every
 ``LlamaConfig`` MoE model is served on, and the path OLMoE (dropless by its
 published definition) takes everywhere.
+
+The dropless path also carries the sigmoid-routed family (DeepSeek-V3,
+Nemotron-H): ``router_scores="sigmoid"`` scores each expert on its own, a
+learned correction bias joins the scores for the CHOICE only, the chosen
+UNBIASED scores are renormalised and multiplied by ``route_scale``;
+``activation="relu2"`` makes an expert ``down(relu(up x)^2)``, two matmuls
+and no gate; ``shared_intermediate_size`` adds one shared expert that every
+row passes (scope ``moe_shared``).  And it can HOLD a share of the experts:
+with ``num_experts_global`` routed experts of which this program holds
+``num_experts``, from ``first_expert`` on, the router, its top-k and its
+normalisation run over all of them and the sum over the chosen ones that
+are held — one rank's part of an expert-parallel layer, computed without
+the exchange (an assignment to an absent expert goes where an invalid row's
+goes: group ``E``, a zero gate, no count).
 """
 
 from __future__ import annotations
@@ -89,11 +103,27 @@ def load_balancing_loss(probs: jax.Array, expert_mask: jax.Array) -> jax.Array:
 GMM_TILING = (128, 2048, 1024)
 
 
+def per_expert_lecun(key, shape, dtype=jnp.float32):
+    """LeCun normal at ONE expert's fan-in for a stacked ``[E, in, out]``
+    kernel (``lecun_normal`` on the stack counts ``E`` into the fan-in and
+    draws every expert ``sqrt(E)`` times smaller); plain LeCun normal for
+    a ``[in, out]`` kernel.  Drawn in float32 and rounded: ``jax.random``'s
+    bfloat16 normal has a mean of -1.8% of its standard deviation, which a
+    fan-in of thousands of POSITIVE inputs (relu2 hidden units) turns into
+    one vector added to every token — 45% of a down-projection's output at
+    3,712 inputs, and with it every token to the same experts (the busiest
+    took 21 x the mean on the v5e: PERF.md, PR 32)."""
+    return nn.initializers.variance_scaling(
+        1.0, "fan_in", "normal", in_axis=-2, out_axis=-1,
+        batch_axis=tuple(range(len(shape) - 2)))(
+        key, shape, jnp.float32).astype(dtype)
+
+
 def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
-                   dtype: Dtype) -> jax.Array:
-    """``x [M, K]`` rows sorted by group, ``w [G, K, N]``, ``group_sizes
-    [G]`` -> ``[M, N]``: row ``r`` of group ``g`` is ``x[r] @ w[g]``, fp32
-    accumulation, stored in ``dtype``.  Rows past ``sum(group_sizes)``
+                   dtype: Dtype, transpose_rhs: bool = False) -> jax.Array:
+    """``x [M, K]`` rows sorted by group, ``w [G, K, N]`` (``[G, N, K]``
+    with ``transpose_rhs``), ``group_sizes [G]`` -> ``[M, N]``: row ``r`` of
+    group ``g`` is ``x[r] @ w[g]``, fp32 accumulation, stored in ``dtype``.  Rows past ``sum(group_sizes)``
     belong to no group; what they hold is unspecified (callers mask them).
     A row's result does not depend on the other rows or on the sizes.
 
@@ -103,6 +133,8 @@ def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
     sizes = group_sizes.astype(jnp.int32)
 
     def ragged(x, w, sizes):
+        if transpose_rhs:
+            w = jnp.swapaxes(w, 1, 2)
         return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=dtype)
 
     def kernel(x, w, sizes):
@@ -111,9 +143,11 @@ def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
         tm, tk, tn = GMM_TILING
         pad = -x.shape[0] % tm          # whole row tiles; the pad is in no group
         xp = jnp.pad(x, ((0, pad), (0, 0))) if pad else x
+        k, n = (w.shape[2], w.shape[1]) if transpose_rhs else w.shape[1:]
         with jax.named_scope("moe_gmm"):
             out = gmm(xp, w, sizes, preferred_element_type=dtype,
-                      tiling=(tm, min(tk, w.shape[1]), min(tn, w.shape[2])))
+                      tiling=(tm, min(tk, k), min(tn, n)),
+                      transpose_rhs=transpose_rhs)
         return out[:x.shape[0]]
 
     if model_parallel_is_initialized() and get_tensor_parallel_size() > 1:
@@ -172,6 +206,18 @@ class ExpertParallelMLP(nn.Module):
     #   differs between teacher-forced training and incremental decoding —
     #   expert choice is principally an encoder/non-autoregressive router.
     router_type: str = "topk"
+    # the dropless path's sigmoid-routed family (module docstring):
+    # "softmax" | "sigmoid" scores; a correction bias ``router_bias [Eg]``
+    # added for the choice only; the gates' scale; "silu" (SwiGLU experts,
+    # gate/up/down) | "relu2" (up/down); a shared expert's width (0: none)
+    router_scores: str = "softmax"
+    router_bias: bool = False
+    route_scale: float = 1.0
+    activation: str = "silu"
+    shared_intermediate_size: int = 0
+    # dropless with ``num_experts_global != num_experts``: the first of
+    # the ``num_experts`` routed experts this program holds
+    first_expert: int = 0
     dtype: Dtype = jnp.bfloat16
     param_dtype: Dtype = jnp.float32
     kernel_init: Initializer = nn.initializers.lecun_normal()
@@ -183,8 +229,13 @@ class ExpertParallelMLP(nn.Module):
         come out zero."""
         from jax import lax
 
-        manual_ep = bool(self.num_experts_global) and \
+        dropless = self.dispatch == "dropless"
+        # fewer experts here than are routed: inside the PP engine's
+        # shard_map this rank's share WITH its exchange (manual ep); on the
+        # dropless path a held share without one
+        share = bool(self.num_experts_global) and \
             self.num_experts_global != self.num_experts
+        manual_ep = share and not dropless
         Eg = self.num_experts_global or self.num_experts
         if manual_ep and EXPERT_AXIS not in ambient_manual_axes():
             raise ValueError(
@@ -198,11 +249,28 @@ class ExpertParallelMLP(nn.Module):
             raise ValueError(
                 f"unknown dispatch {self.dispatch!r} "
                 "(einsum | scatter | dropless)")
-        dropless = self.dispatch == "dropless"
-        if dropless and (manual_ep or self.router_type != "topk"):
+        if dropless and self.router_type != "topk":
             raise ValueError(
-                "dispatch='dropless' is token-choice routing with every "
-                "expert in one program (no manual ep, no expert_choice)")
+                "dispatch='dropless' is token-choice routing (no "
+                "expert_choice)")
+        if share and dropless and not (
+                0 <= self.first_expert
+                and self.first_expert + self.num_experts <= Eg):
+            raise ValueError(
+                f"experts {self.first_expert}..+{self.num_experts} are no "
+                f"range of the {Eg} routed ones")
+        family = (self.router_scores != "softmax" or self.router_bias
+                  or self.route_scale != 1.0 or self.activation != "silu"
+                  or self.shared_intermediate_size)
+        if family and not dropless:
+            raise ValueError(
+                "sigmoid scores, a router bias, a route scale, relu2 "
+                "experts and a shared expert are the dropless path's")
+        if self.router_scores not in ("softmax", "sigmoid") \
+                or self.activation not in ("silu", "relu2"):
+            raise ValueError(
+                f"unknown router_scores {self.router_scores!r} (softmax | "
+                f"sigmoid) or activation {self.activation!r} (silu | relu2)")
         if valid is not None and not dropless:
             raise ValueError("row validity is the dropless path's argument")
         if self.router_type not in ("topk", "expert_choice"):
@@ -225,7 +293,20 @@ class ExpertParallelMLP(nn.Module):
             "router", nn.with_partitioning(self.kernel_init, (None, None)),
             (H, Eg), self.param_dtype,
         )
-        if self.fused_gate_up:
+        gated = self.activation == "silu"
+        if not gated:
+            # ``up [E, I, H]``, each expert's matrix out-major (as a Linear
+            # stores it): the kernel takes it transposed.  Stored ``[E, H,
+            # I]`` at I = 1856, not a multiple of the 128 lanes, the device
+            # kept H minor and every program copied 638 MB a layer into the
+            # kernel's layout — 60% of the device's time (PERF.md, PR 32)
+            wi = (jnp.asarray(self.param(
+                "up", nn.with_partitioning(
+                    lambda key, shape, dtype: jnp.swapaxes(self.kernel_init(
+                        key, (shape[0], shape[2], shape[1]), dtype), 1, 2),
+                    (EXPERT_AXIS, TENSOR_AXES, None)),
+                (E, I, H), self.param_dtype)),)
+        elif self.fused_gate_up:
             wi = self.param(
                 "gate_up",
                 nn.with_partitioning(self.kernel_init, (EXPERT_AXIS, None, None, TENSOR_AXES)),
@@ -245,11 +326,45 @@ class ExpertParallelMLP(nn.Module):
         )
 
         if dropless:
-            if self.fused_gate_up:
+            if gated and self.fused_gate_up:
                 wi = (jnp.asarray(wi)[:, :, 0, :], jnp.asarray(wi)[:, :, 1, :])
+            bias = None
+            if self.router_bias:
+                # a SEEDED bias is drawn non-zero, so that the choice
+                # differs from the unbiased scores' own, and SMALL: a
+                # trained one is what load balancing left, and at 0.05 a
+                # seeded one unbalances instead (busiest expert 2.6-4.5 x
+                # the mean against 1.3-1.6 x at 0.005; what a share of the
+                # experts is handed then swings with the seed: PERF.md,
+                # PR 32)
+                bias = jnp.asarray(self.param(
+                    "router_bias", nn.with_partitioning(
+                        nn.initializers.normal(0.005), (None,)),
+                    (Eg,), jnp.float32))
             y, aux = self._dropless(
                 xt, None if valid is None else valid.reshape(-1),
-                jnp.asarray(router), wi, jnp.asarray(wo))
+                jnp.asarray(router), wi, jnp.asarray(wo), bias)
+            if self.shared_intermediate_size:
+                # the shared expert: every row, the experts' activation at
+                # its own width
+                from neuronx_distributed_tpu.parallel.layers import (
+                    ColumnParallelLinear,
+                    RowParallelLinear,
+                )
+
+                F = self.shared_intermediate_size
+                lin = dict(use_bias=False, dtype=self.dtype,
+                           param_dtype=self.param_dtype,
+                           kernel_init=per_expert_lecun)
+                with jax.named_scope("moe_shared"):
+                    xs = xt.astype(self.dtype)
+                    up = ColumnParallelLinear(features=F, name="shared_up",
+                                              **lin)(xs)
+                    h = (jax.nn.silu(ColumnParallelLinear(
+                        features=F, name="shared_gate", **lin)(xs)) * up
+                         if gated else jnp.square(jax.nn.relu(up)))
+                    y = y + RowParallelLinear(features=H, name="shared_down",
+                                              **lin)(h)
             return y.reshape(*lead, H), aux
 
         # -- routing (fp32), over the GLOBAL expert space ---------------------
@@ -374,37 +489,58 @@ class ExpertParallelMLP(nn.Module):
         y = shard_activation(y, _auto_spec(BATCH_AXES, None))
         return y.reshape(*lead, H).astype(self.dtype), aux.astype(jnp.float32)
 
-    def _dropless(self, xt, valid, router, wi, wo):
+    def _dropless(self, xt, valid, router, wi, wo, bias=None):
         """``xt [N, H]`` -> ``(y [N, H], aux)`` with no capacity; ``wi`` is
-        the pair ``(gate, up)``, each ``[E, H, I]``.  Sown into
-        ``moe_stats`` (kept only by an apply that makes it mutable): ``load
-        [E]``, the valid assignments each expert took, and ``choice [N,
-        K]``, each row's experts in gate order (``E`` for an invalid row)."""
+        the pair ``(gate, up)``, each ``[E, H, I]`` (``(up,)`` for relu2
+        experts, ``[E, I, H]``).  Sown into ``moe_stats`` (kept only by an apply that
+        makes it mutable): ``load [E]``, the valid assignments each expert
+        HELD took; ``choice [N, K]``, each row's experts of all ``Eg`` in
+        gate order (``Eg`` for an invalid row); and, where a share is held,
+        ``assigned``, the valid assignments held or not."""
         N, H = xt.shape
         E, I, K = self.num_experts, self.intermediate_size, self.top_k
+        Eg = self.num_experts_global or E
         with jax.named_scope("moe_router"):
             logits = jnp.einsum("nh,he->ne", xt.astype(jnp.float32),
                                 router.astype(jnp.float32))
-            probs = jax.nn.softmax(logits, axis=-1)
-            gates, choice = jax.lax.top_k(probs, K)            # [N, K]
+            if self.router_scores == "sigmoid":
+                probs = jax.nn.sigmoid(logits)
+            else:
+                probs = jax.nn.softmax(logits, axis=-1)
+            if bias is None:
+                gates, choice = jax.lax.top_k(probs, K)        # [N, K]
+            else:
+                # chosen by the biased score, weighted by the unbiased
+                _, choice = jax.lax.top_k(probs + bias[None, :], K)
+                gates = jnp.take_along_axis(probs, choice, axis=1)
             if self.norm_topk_prob:
                 gates = gates / jnp.maximum(
-                    jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+                    jnp.sum(gates, axis=-1, keepdims=True),
+                    1e-9 if self.router_scores == "softmax" else 1e-20)
+            if self.route_scale != 1.0:
+                gates = gates * self.route_scale
             live = (jnp.ones((N, 1), bool) if valid is None
                     else valid.astype(bool)[:, None])
             # the Switch loss over the live rows (:func:`load_balancing_loss`)
             took = jnp.where(live, jnp.sum(
-                jax.nn.one_hot(choice, E, dtype=jnp.float32), axis=1), 0.0)
+                jax.nn.one_hot(choice, Eg, dtype=jnp.float32), axis=1), 0.0)
             n_live = jnp.maximum(jnp.sum(live), 1)
-            aux = E * jnp.sum(jnp.sum(took, 0) / n_live
-                              * jnp.sum(jnp.where(live, probs, 0.0), 0)
-                              / n_live)
+            aux = Eg * jnp.sum(jnp.sum(took, 0) / n_live
+                               * jnp.sum(jnp.where(live, probs, 0.0), 0)
+                               / n_live)
             # an invalid row's assignments go to group E: past every
             # expert's rows once sorted, in no count, under a zero gate
-            choice = jnp.where(live, choice, E)
+            choice = jnp.where(live, choice, Eg)
             gates = jnp.where(live, gates, 0.0)
+            group = choice
+            if Eg != E:
+                # and so does an assignment to an expert held elsewhere
+                held = (choice >= self.first_expert) \
+                    & (choice < self.first_expert + E)
+                group = jnp.where(held, choice - self.first_expert, E)
+                gates = jnp.where(held, gates, 0.0)
         with jax.named_scope("moe_dispatch"):
-            flat = choice.reshape(-1)                          # token-major
+            flat = group.reshape(-1)                           # token-major
             # stable: within an expert the rows keep their token order, so
             # nothing about a row's place depends on its neighbours' values
             order = jnp.argsort(flat, stable=True)
@@ -412,9 +548,14 @@ class ExpertParallelMLP(nn.Module):
                            dtype=jnp.int32)                    # [E]
             xs = xt.astype(self.dtype)[order // K]             # [N*K, H]
         with jax.named_scope("moe_experts"):
-            gate, up = (grouped_matmul(xs, w.astype(self.dtype), load,
-                                       self.dtype) for w in wi)
-            h = jax.nn.silu(gate) * up
+            if self.activation == "relu2":
+                h = jnp.square(jax.nn.relu(grouped_matmul(
+                    xs, wi[0].astype(self.dtype), load, self.dtype,
+                    transpose_rhs=True)))
+            else:
+                gate, up = (grouped_matmul(xs, w.astype(self.dtype), load,
+                                           self.dtype) for w in wi)
+                h = jax.nn.silu(gate) * up
             h = shard_activation(h, _auto_spec(None, TENSOR_AXES))
             ys = grouped_matmul(h, wo.astype(self.dtype), load, self.dtype)
         with jax.named_scope("moe_combine"):
@@ -428,4 +569,7 @@ class ExpertParallelMLP(nn.Module):
         if not self.is_initializing():  # never part of a parameter tree
             self.sow("moe_stats", "load", load)
             self.sow("moe_stats", "choice", choice)
+            if Eg != E:
+                self.sow("moe_stats", "assigned",
+                         jnp.sum(live, dtype=jnp.int32) * K)
         return y, aux.astype(jnp.float32)

@@ -566,6 +566,8 @@ class ServingEngine:
         mcfg = getattr(getattr(model, "module", None), "config", None)
         self._recurrent = bool(getattr(mcfg, "recurrent_layers", ()))
         self._sparse_spec = getattr(mcfg, "selection_spec", None)
+        # Mamba-2 layers: a decode steps one state row a live slot a layer
+        self._ssm = "mamba2" in (getattr(mcfg, "mixer_types", None) or ())
         if self._recurrent or self._sparse_spec is not None:
             from neuronx_distributed_tpu.parallel.mesh import (
                 TENSOR_AXIS,
@@ -1924,15 +1926,22 @@ class ServingEngine:
         return packed
 
     def _take_moe_loads(self, upto=None):
-        """``(program families, device loads [L, E])`` of the paged
-        programs a routed model ran since the last call."""
+        """``(program families, device loads)`` of the paged programs a
+        routed model ran since the last call: ``{"load": [L, E]}`` a
+        program, with ``"assigned" [L]`` beside it where the layers hold a
+        share of their experts."""
         take = getattr(self.model, "take_moe_stats", None)
         stats = take(upto) if take is not None else []
-        return [s["program"] for s in stats], [s["load"] for s in stats]
+        return [s["program"] for s in stats], [
+            {k: s[k] for k in ("load", "assigned") if k in s} for s in stats]
 
     def _count_moe(self, programs, loads) -> None:
         """Book the expert loads of the paged programs just fetched:
         ``moe/assignments_total`` (valid rows x experts a token x layers),
+        where the layers hold a share of their experts
+        ``moe/assignments_held_total`` (those that went to an expert this
+        program holds; it and ``moe/assignments_total`` then also by program
+        family),
         ``moe/layer_calls_total`` (expert blocks that ran with a token),
         ``moe/experts_hit_total`` (experts with a row, summed over those
         calls) — the last two also by program family, ``.../decode_pages``
@@ -1944,10 +1953,18 @@ class ServingEngine:
         if not loads:
             return
         reg = self.registry
-        for program, load in zip(programs, loads):
-            load = np.asarray(load, np.int64)
+        for program, stats in zip(programs, loads):
+            load = np.asarray(stats["load"], np.int64)
+            assigned = stats.get("assigned")
             calls, hit = int((load.sum(axis=1) > 0).sum()), int((load > 0).sum())
-            reg.counter("moe/assignments_total").inc(int(load.sum()))
+            made = int(load.sum() if assigned is None else np.sum(assigned))
+            reg.counter("moe/assignments_total").inc(made)
+            if assigned is not None:
+                # a held share: what fell to it, also by program family
+                for suffix in ("", "/" + program):
+                    reg.counter("moe/assignments_held_total" + suffix).inc(
+                        int(load.sum()))
+                reg.counter("moe/assignments_total/" + program).inc(made)
             for suffix in ("", "/" + program):
                 reg.counter("moe/layer_calls_total" + suffix).inc(calls)
                 reg.counter("moe/experts_hit_total" + suffix).inc(hit)
@@ -2092,6 +2109,9 @@ class ServingEngine:
                 tok, offs, self._tables_dev, self.caches, self.valid,
                 paged_kernel=self._paged_kernel)
         self._count_gather_step()
+        if self._ssm:
+            self.registry.counter(
+                "serving/ssm_state_rows_stepped_total").inc(len(active))
         if self._kv_quant is not None:
             # every active slot's decode write requantized its page
             self.registry.counter(QUANT_PAGES_TOTAL).inc(len(active))

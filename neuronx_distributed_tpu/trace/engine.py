@@ -723,12 +723,20 @@ class ParallelInferenceModel(_ServingBase):
         # (models.llama.moe_layer_stats); it waits here, still on the
         # device, for whoever reads the step's tokens to take it along
         self._moe = getattr(mcfg, "num_experts", 1) > 1
+        # its routed layers: all of them, or those a layer list names
+        self._moe_layers = getattr(mcfg, "moe_layers", None) \
+            or self.num_layers
         self._moe_stats: collections.deque = collections.deque(maxlen=256)
         self.moe_seq = 0      # paged programs of a routed model run so far
         # a model with a layer LIST (LlamaConfig.mixer_types): which layers
         # keep a recurrent state row a sequence and which choose the pages
         # they attend (the paged programs then also return that choice)
         self.recurrent = bool(getattr(mcfg, "recurrent_layers", ()))
+        # Mamba-2 layers step their state arrays where they lie when every
+        # slot is a batch row (``models.hybrid.Mamba2Mixer``): a decode is
+        # then told no rows
+        self._rows_in_place = "mamba2" in (
+            getattr(mcfg, "mixer_types", None) or ())
         self._sparse = tuple(getattr(mcfg, "selecting_layers", ()))
         self._sparse_stats: collections.deque = collections.deque(maxlen=256)
         self._build()
@@ -1093,7 +1101,7 @@ class ParallelInferenceModel(_ServingBase):
             from neuronx_distributed_tpu.models.llama import moe_layer_stats
 
             return logits, caches, valid, moe_layer_stats(
-                stats, self.num_layers)
+                stats, self._moe_layers)
         if self._sparse:
             layers = stats["sparse_stats"]["model"]
             return logits, caches, valid, jnp.stack(
@@ -1154,14 +1162,17 @@ class ParallelInferenceModel(_ServingBase):
             if lora or not last_only:
                 raise ValueError(
                     "LoRA pages and speculative verification are not "
-                    "carried through the recurrent (lightning-attn) layers")
+                    "carried through the recurrent (lightning-attn, mamba2) "
+                    "layers")
             if state_rows is None:
                 if int(toks.shape[0]) != self.config.batch_size:
                     raise ValueError(
                         "a paged program over fewer rows than slots must "
                         "be told its state rows (state_row=)")
-                state_rows = np.arange(self.config.batch_size)
-            kw["state_rows"] = jnp.asarray(state_rows, jnp.int32)
+                if not self._rows_in_place:
+                    state_rows = np.arange(self.config.batch_size)
+            if state_rows is not None:
+                kw["state_rows"] = jnp.asarray(state_rows, jnp.int32)
         out = fn(*args, **kw)
         if self._moe:
             self.moe_seq += 1

@@ -402,3 +402,59 @@ def test_hybrid_paged_programs_compile_for_v5e_and_copy_no_state(topo, program):
     memory = compiled.memory_analysis()
     held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(caches))
     assert memory.alias_size_in_bytes >= held
+
+
+@pytest.fixture(scope="module")
+def nemotron_programs(topo):
+    """Both serve programs of the benchmark's Nemotron-3-Nano configuration
+    (``benchmarks/tools/nemotron_aot.py``: every layer, published widths, 64
+    slots), compiled once for the two cases below."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from benchmarks.harness import manifest
+    from benchmarks.tools import nemotron_aot
+
+    prev = jax.config.jax_enable_compilation_cache
+    try:
+        cell = manifest.Cell("nemotron-3-nano.serve-agents")
+        programs, weights, pool, shapes, _ = \
+            nemotron_aot.compile_serve_programs(cell)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+    return dict(programs), weights, pool, shapes
+
+
+@pytest.mark.parametrize("program", ["paged decode", "paged chunk prefill"])
+def test_nemotron_serve_programs_fit_a_v5e_and_copy_no_state(
+        nemotron_programs, program):
+    """14 one-sublayer layers at the PUBLISHED widths (Mamba-2 64 heads x 64
+    with a state of 128, 32 q / 2 kv attention, 64 held of 128 relu2 experts
+    and the shared one), 64 slots of 2,304 tokens, a 512-row chunk: the
+    grouped matmuls, the paged walk (a chunk's 8,192 query rows a kv head in
+    parts VMEM holds) and the pool writer are Mosaic calls; the expert
+    weights enter the kernel as they lie; the pages, the float32 scan states
+    and the convolution taps are donated and aliased; all of it under 14 GiB."""
+    import re
+
+    programs, weights, pool, shapes = nemotron_programs
+    compiled = programs[program]
+    text = compiled.as_text()
+    kernel = ("paged_attention_decode" if program == "paged decode"
+              else "paged_attention_chunk")
+    assert f"%{kernel}" in text and "%gmm" in text
+    assert text.count("%kv_pool_write") >= 2
+    # no copy of an expert stack ([64, 1856, 2688] up and down), of the page
+    # pool or of the scan states
+    for shape in ["bf16[64,1856,2688]"] + [
+            f"{'f32' if s.dtype == jnp.float32 else 'bf16'}"
+            f"[{','.join(map(str, s.shape))}]" for s in shapes[:2]]:
+        copied = [ln.strip()[:120] for ln in text.splitlines()
+                  if re.search(rf"= {re.escape(shape)}\S* (copy|transpose)\(",
+                               ln) and "fused_computation" not in ln]
+        assert not copied, f"{shape} is copied: {copied}"
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= pool
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert weights + pool < total < 14 * 2 ** 30
