@@ -22,22 +22,35 @@ There is ONE path through the loop, the one every benchmark cell measures:
   step; one chunk of ``context_len`` when the caller names no size).  An
   exact repeated prompt skips compute: the prefix index hands back its
   pages and its prefill logits;
-- **one decode loop, pipelined** — ``step()`` dispatches decode step N+1
-  *before* running step N's deferred host work (stream callbacks,
-  inter-token telemetry, stats serialization), and the whole per-step
+- **one decode loop, one step ahead** — with decode step N in flight,
+  ``step()`` launches step N+1 BEFORE it reads N's tokens: each slot of N
+  is fed N's sampled token where it lies on the device (a slot that began
+  decoding since is fed the host's first token; one ``where`` over a
+  ``[B]`` mask), its write offset and token index advanced by the row in
+  flight, and only THEN is N fetched, its offsets committed, its stops
+  detected and its slots released — so the host's whole turn a token
+  (fetch, bookkeeping, staging, launch, stream callbacks, stats) runs
+  under a queued program.  What the launch needs it has: a stop by LENGTH
+  is a count the host holds, and the pages past an offset were reserved at
+  admission.  A stop TOKEN or a non-finite row shows only in the fetch:
+  such a request is found one step late and has one row in the step
+  already queued — an *overrun*, whose token is never read and whose write
+  lands in a decode page the slot still held (``_collect_decode``;
+  ``serving/decode_overrun_rows_total``, beside
+  ``serving/decode_runahead_total``: steps launched behind an unfetched
+  one).  A speculative round keeps collect-then-dispatch: its offsets are
+  the accepted counts, which only the fetch gives.  The whole per-step
   device→host traffic — sampled tokens and per-slot finite flags — is
   packed into ONE ``[2, B]`` array fetched with a single explicit
   ``device_get`` per step (counted by the
   :class:`~..obs.transfer_audit.TransferAudit`; host wait exported as
   ``serving/host_blocked_ms``).  The host→device direction is symmetric:
-  the next-token feed, per-slot write offsets and token indices stage as
-  one packed explicit ``device_put``, and the per-slot sampling state (keys
-  / temperature / top-k / top-p) lives in device mirrors refreshed only
-  when admission changes them.  Stop *detection* stays pre-dispatch — it is
-  a few integer compares and the next step's active set depends on it — so
-  the pipeline never decodes speculatively for a finished slot.  A token's
-  stream callback fires after the next step's dispatch, and the final
-  token's callback sees its request already in a terminal state.
+  the host's token feed and its mask, per-slot write offsets and token
+  indices stage as one packed explicit ``device_put``, and the per-slot
+  sampling state (keys / temperature / top-k / top-p) lives in device
+  mirrors refreshed only when admission changes them.  A token's stream
+  callback fires after the next step's dispatch, and the final token's
+  callback sees its request already in a terminal state.
 
 The reference the loop is held to is the solo ``generate`` of the same
 weights: greedy outputs are token-identical to it (the per-row
@@ -59,6 +72,7 @@ from __future__ import annotations
 
 import json
 import time
+from collections import deque
 from typing import Any, Callable, List, Optional
 
 import jax
@@ -132,6 +146,33 @@ class _ChunkPrefill:
     @property
     def pages_remaining(self) -> int:
         return len(self.fresh) - self.next_i
+
+
+class _InFlight:
+    """One decode program (or speculative round) launched and not yet
+    fetched: its packed device payload, the rows it computed as ``(slot,
+    request, occupancy generation)``, the sampled tokens where they lie on
+    the device (what the step launched after it is fed; None for a
+    speculative round, which keeps its last proposal instead), the weights
+    version and ``moe_seq`` it was launched under, and its batch span and
+    perf stamp — opened at the launch into an idle loop, or when the step
+    before it is collected, so the spans tile as the device's work does."""
+
+    __slots__ = ("packed", "active", "toks", "last_prop", "version",
+                 "moe_seq", "step", "family", "span", "t0")
+
+    def __init__(self, packed, active, version, moe_seq, step, family,
+                 toks=None, last_prop=None):
+        self.packed = packed
+        self.active = active
+        self.toks = toks
+        self.last_prop = last_prop
+        self.version = version
+        self.moe_seq = moe_seq
+        self.step = step
+        self.family = family    # "decode_step" | "spec_round"
+        self.span = None
+        self.t0 = None
 
 
 #: the sampler's paths, in the order of ``_sample_rows``'s ``lax.switch``
@@ -301,6 +342,19 @@ def _pack_tokens(toks, finite):
         return jnp.stack([toks.astype(jnp.int32), finite.astype(jnp.int32)])
 
 
+@jax.jit
+def _feed_tokens(prev_toks, host_tok, from_host):
+    """The token feed of a decode step launched while the step before it is
+    still in flight: per slot, that step's sampled token where it lies on
+    the device (``prev_toks [B]``, never read by the host first), or the
+    host's token (``host_tok [B, 1]``) for a slot that began decoding since
+    — its first token came from the prefill's logits.  ``from_host [B]``
+    rides the step's one packed put."""
+    with jax.named_scope("feed_tokens"):
+        return jnp.where(from_host, host_tok[:, 0],
+                         prev_toks.astype(jnp.int32))[:, None]
+
+
 #: module-level jits shared by every engine in the process: their compiles
 #: are invisible to the per-model _CompiledLRU accounting, so the ledger-on
 #: engine polls their jit cache sizes per step instead (growth after
@@ -309,7 +363,8 @@ def _pack_tokens(toks, finite):
 _MODULE_JITS = (("sample_rows", _sample_rows),
                 ("propose_rows", _propose_rows),
                 ("spec_accept", _spec_accept),
-                ("pack_tokens", _pack_tokens))
+                ("pack_tokens", _pack_tokens),
+                ("feed_tokens", _feed_tokens))
 
 
 def _module_jit_sizes() -> dict:
@@ -373,13 +428,14 @@ class ServingEngine:
       ``replay_trace`` dumps it on an unhandled exception, and the engine's
       metrics then ride the hub's registry unless one was passed explicitly.
 
-    The decode loop is pipelined (see the module docstring): step N+1 is
-    dispatched before step N's stream callbacks / stats run, and all
-    per-step host↔device traffic packs into one explicit fetch + one
-    explicit put.  ``transfer_guard="forbid"`` wraps the steady decode
-    section in ``jax.transfer_guard("disallow")``: an implicit transfer in
-    the hot path raises instead of silently draining the device.  Fetch/put
-    counts and ``serving/host_blocked_ms`` export in every mode.
+    The decode loop runs one step ahead (see the module docstring): step
+    N+1 is dispatched, from step N's tokens on the device, before step N is
+    fetched, and all per-step host↔device traffic packs into one explicit
+    fetch + one explicit put.  ``transfer_guard="forbid"`` wraps the steady
+    decode section in ``jax.transfer_guard("disallow")``: an implicit
+    transfer in the hot path raises instead of silently draining the
+    device.  Fetch/put counts and ``serving/host_blocked_ms`` export in
+    every mode.
 
     The KV cache is a global page pool plus per-slot block tables:
     ``page_size`` (required; it must divide ``context_len`` and
@@ -620,7 +676,6 @@ class ServingEngine:
         # expert loads summed since this engine began, [L, E]; what the
         # model ran before (another engine, a check) is not this engine's
         self._moe_load = None
-        self._pending_moe_seq = None   # the in-flight decode's moe_seq
         self._take_moe_loads()
         # resource ledgers (obs.compile_ledger / obs.memory_ledger).  An
         # explicit compile ledger is attached to the MODEL (and the draft)
@@ -715,7 +770,6 @@ class ServingEngine:
         # obs.tracing.SPANS_CREATED.
         self.tracer = tracer
         self._rt: dict = {}       # rid -> {"root": Span, "phase": Span?}
-        self._batch_span = None   # open decode_step/spec_round batch span
         # fleet health monitor (obs.health.HealthMonitor, None = off;
         # falls back to the Observability hub's when one is attached):
         # evaluated on the step cadence over THIS registry, fed one SLO
@@ -736,7 +790,6 @@ class ServingEngine:
             perf = getattr(obs, "perf", None)
         self._perf = perf
         self._perf_t0: dict = {}  # rid -> prefill-phase start (engine clock)
-        self._batch_t0 = None     # (family, t0) of the in-flight round
         if perf is not None:
             perf.attach(registry=self.registry, ledger=compile_ledger)
             # the _CompiledLRU first-call hook captures each program's
@@ -760,15 +813,16 @@ class ServingEngine:
         self._audit = TransferAudit(
             self.registry,
             mode="forbid" if transfer_guard == "forbid" else "observe")
-        # in-flight decode: (packed [2,B] device array, active snapshot)
-        self._pending: "Optional[tuple]" = None
+        # decode programs launched and not yet fetched, oldest first: one
+        # between steps, two between a step's dispatch and its collect (the
+        # plain loop launches step N+1 before it reads step N's tokens)
+        self._inflight: "deque[_InFlight]" = deque()
         # live weights (weights.WeightSwapper): the monotonic version of
-        # the params currently serving (0 = process-start, never swapped)
-        # and the version an in-flight async decode was DISPATCHED under —
-        # a swap between dispatch and collect must attribute the collected
+        # the params currently serving (0 = process-start, never swapped).
+        # An in-flight record keeps the version it was DISPATCHED under — a
+        # swap between dispatch and collect must attribute the collected
         # tokens to the old version (the buffers that computed them)
         self.weights_version = 0
-        self._pending_version = 0
         # device mirror of the paged block tables (refreshed via the packed
         # explicit put only when admission/termination changes them)
         self._tables_dev = None
@@ -904,7 +958,8 @@ class ServingEngine:
         reg.gauge("serving/last_step_ms")
         for c in ("admitted", "finished", "cancelled", "timed_out", "tokens",
                   "rejected", "failed", "slow_steps", "preemptions", "shed",
-                  "expired_before_prefill", "prefill_chunks"):
+                  "expired_before_prefill", "prefill_chunks",
+                  "decode_runahead", "decode_overrun_rows"):
             reg.counter(f"serving/{c}_total")
         for path in SAMPLER_PATHS:
             reg.counter(f"serving/sampler_steps_total/{path}")
@@ -1068,11 +1123,11 @@ class ServingEngine:
 
     @property
     def has_work(self) -> bool:
-        # an in-flight async decode is work: its results still need one
-        # more step() to be collected and emitted
+        # an in-flight decode is work: its results still need one more
+        # step() to be collected and emitted
         return (self.scheduler.queue_depth > 0
                 or self.scheduler.active_count > 0
-                or self._pending is not None)
+                or bool(self._inflight))
 
     # -- engine loop -------------------------------------------------------
 
@@ -1095,9 +1150,10 @@ class ServingEngine:
         compiled envelope, so every already-compiled phase program accepts
         the new pytree as a drop-in first argument — nothing recompiles
         (the compile ledger proves it).  The old buffers free by reference
-        drop; an in-flight async decode dispatched against them keeps them
+        drop; a decode in flight that was dispatched against them keeps them
         alive exactly until its collect, and its tokens are attributed to
-        ``_pending_version`` (the version that computed them).
+        the version its in-flight record carries (the one that computed
+        them).
 
         Co-located replicas may SHARE one ``ParallelInferenceModel`` (one
         set of compiled phase fns, one param pytree) — a fleet mid-roll
@@ -1144,9 +1200,12 @@ class ServingEngine:
         led.reconcile()
 
     def step(self) -> List[RequestOutput]:
-        """One engine iteration: sweep → admit/prefill → batched decode →
-        per-slot stop detection → slot free.  Returns the requests that
-        reached a terminal state during this step.
+        """One engine iteration: sweep → admit → one prefill chunk → launch
+        the next batched decode → collect the one before it (per-slot stop
+        detection, slot free) → its stream callbacks and stats.  Returns
+        the requests that reached a terminal state during this step; a
+        request that stopped on a token did so one launch ago, and the row
+        computed for it since is discarded.
 
         With a memory ledger attached, a RESOURCE_EXHAUSTED escaping the
         step dumps ``memory_breakdown.json`` naming the biggest holders
@@ -1212,32 +1271,22 @@ class ServingEngine:
         if self._chunking:
             self._run_prefill_chunks(outputs)
 
-        # 4) decode, pipelined: one single-token batched step, or —
-        # speculative mode — one draft-k-verify round committing up to k+1
-        # tokens per slot.  Collect the in-flight step's packed results (one
-        # explicit fetch + cheap stop detection), dispatch the next decode,
-        # THEN run the collected step's host-side work (stream callbacks,
-        # telemetry, stats) while the device computes
+        # 4) decode, one step ahead: with step N in flight, launch step N+1
+        # from N's tokens where they lie on the device, THEN collect N (one
+        # explicit fetch, offsets committed, stop detection, slot release)
+        # and run its host-side work (stream callbacks, telemetry, stats) —
+        # all of it under the program just queued.  A speculative round
+        # keeps collect-then-dispatch: its offsets are the accepted counts,
+        # which only the fetch gives
         with self._audit.section("serving/decode"):
-            with phase("serve/collect"):
-                post = (self._spec_collect() if self._spec_k
-                        else self._collect_decode())
-            active = [(slot, req) for slot, req in self.scheduler.active()
-                      if req.state is RequestState.DECODE]
-            if active:
-                self._count_paged_walk(active)
-                self._count_decode_write(active)
-                lens = np.asarray([
-                    int(self._offsets[slot]) - self.C + req.prompt_len + 1
-                    for slot, req in active])
-                with phase("serve/dispatch", active=len(active),
-                           ctx_tokens=self._attended_keys(active),
-                           **self._count_selection("decode_pages", lens - 1,
-                                                   lens)):
-                    if self._spec_k:
-                        self._spec_dispatch(active)
-                    else:
-                        self._dispatch_decode(active)
+            if self._spec_k:
+                with phase("serve/collect"):
+                    post = self._spec_collect()
+                self._launch_decode()
+            else:
+                launched = self._launch_decode()
+                with phase("serve/collect"):
+                    post = self._collect_decode(keep_newest=launched)
         with phase("serve/finish", tokens=sum(
                 len(p[3]) if p[0] == "tokens" else p[0] == "token"
                 for p in post)):
@@ -1279,6 +1328,107 @@ class ServingEngine:
             self._health.on_step(now=self._clock())
         return outputs
 
+    def _row_live(self, slot: int, req: Request, gen: int) -> bool:
+        """Whether a row of an in-flight program is still its request's:
+        False once the request was swept (cancelled / timed out), stopped,
+        or preempted AND re-admitted since the launch — the slot was
+        released (and possibly re-granted), so the row's token is stale and
+        its offset advance void.  The state check alone is not enough (a
+        preemption round-trip can put the request back in DECODE within
+        one step), and neither is slot identity (it can be re-granted the
+        SAME slot) — the occupancy generation tells the generations
+        apart."""
+        return (req.state is RequestState.DECODE
+                and self.scheduler.slot_of(req.request_id) == slot
+                and self._slot_gen[slot] == gen)
+
+    def _launch_decode(self) -> bool:
+        """Decide the next decode's rows from what the host knows NOW and
+        launch it; False when no slot has a token to compute.  A slot's
+        rows still in flight (``ahead``: one, for a slot the step before
+        also computed) count as done: its write offset and token index are
+        advanced by them here — the collect commits them, or drops them
+        with the stale token — and a request whose last token by LENGTH is
+        already in flight gets no further row.  A stop token or a
+        non-finite row shows only in the fetch, one step late: that row is
+        an overrun (see :meth:`_collect_decode`)."""
+        ahead = np.zeros((self.B,), np.int32)
+        for rec in self._inflight:
+            for slot, req, gen in rec.active:
+                if self._row_live(slot, req, gen):
+                    ahead[slot] += 1
+        active = []
+        for slot, req in self.scheduler.active():
+            if req.state is not RequestState.DECODE:
+                continue
+            if len(req.generated) + ahead[slot] < req.max_new_tokens:
+                active.append((slot, req))
+            elif self._temps[slot] > 0.0:
+                # its last token is in flight and it samples no other: its
+                # row must not hold the batch on the sampler's sort
+                # (:meth:`_park_slot` does this at the collect, a launch
+                # too late)
+                self._temps[slot] = 0.0
+                self._sampling_dirty = True
+        if not active:
+            return False
+        # parked (T: writes nothing) for every slot without a row
+        offs = np.full((self.B,), self.T, np.int32)
+        for slot, _ in active:
+            offs[slot] = self._offsets[slot] + ahead[slot]
+        self._count_paged_walk(active, offs)
+        self._count_decode_write(active, offs)
+        lens = np.asarray([int(offs[slot]) - self.C + req.prompt_len + 1
+                           for slot, req in active])
+        with phase("serve/dispatch", active=len(active),
+                   ctx_tokens=int(lens.sum()) - len(active),
+                   **self._count_selection("decode_pages", lens - 1, lens)):
+            if self._spec_k:
+                self._spec_dispatch(active)
+            else:
+                self._dispatch_decode(active, offs, ahead)
+        return True
+
+    def _put_in_flight(self, packed, active: list, family: str, t_launch,
+                       **kept) -> None:
+        """Queue the program just launched for its collect, under what it
+        was launched with: each row's occupancy generation, the weights
+        version, the model's ``moe_seq``.  ``t_launch`` (None: tracing and
+        perf off, or a step is in flight ahead of it) opens its batch
+        span."""
+        rec = _InFlight(
+            packed,
+            [(slot, req, int(self._slot_gen[slot])) for slot, req in active],
+            self.weights_version, getattr(self.model, "moe_seq", None),
+            self._steps, family, **kept)
+        if t_launch is not None:
+            self._open_batch_span(rec, t_launch)
+        self._inflight.append(rec)
+
+    def _open_batch_span(self, rec: _InFlight, t0: float) -> None:
+        """Open ``rec``'s batch-level span and perf stamp at ``t0``: its
+        launch when the loop was idle, else the collect of the step before
+        it — the honest device window of a program queued behind another;
+        per-slot child spans land at collect time.  The perf layer shares
+        the stamp so its accounting matches the span."""
+        rec.t0 = t0
+        if self.tracer is not None:
+            rec.span = self.tracer.begin(
+                rec.family, t=t0, step=rec.step, active=len(rec.active),
+                weights_version=rec.version,
+                **({"k": self._spec_k} if self._spec_k else {}))
+
+    def _close_batch_span(self, rec: _InFlight, now: float) -> None:
+        """Seal the collected record's span at the fetch's return and open
+        the next in-flight record's at the same instant."""
+        if rec.span is not None:
+            self.tracer.end(rec.span, t=now)
+        if self._perf is not None and rec.t0 is not None:
+            self._perf.note_phase(rec.family, (now - rec.t0) * 1e3)
+        if self._inflight and (self.tracer is not None
+                               or self._perf is not None):
+            self._open_batch_span(self._inflight[0], now)
+
     def dump_flight(self, reason: str) -> Optional[str]:
         """Persist the per-engine-step flight ring (when an ``obs`` hub is
         attached); the serving crash-evidence path used by ``replay_trace``."""
@@ -1309,13 +1459,14 @@ class ServingEngine:
             # coverage instead of losing it with the engine object
             now = self._clock()
             self.scheduler.trace_abort(now)
-            if self._batch_span is not None:
-                tr.end(self._batch_span, t=now, aborted=True)
-                self._batch_span = None
-                if self._perf is not None and self._batch_t0 is not None:
-                    fam, t0 = self._batch_t0
-                    self._perf.note_phase(fam, (now - t0) * 1e3)
-                self._batch_t0 = None
+            for rec in self._inflight:
+                if rec.span is not None:
+                    tr.end(rec.span, t=now, aborted=True)
+                    rec.span = None
+                    if self._perf is not None and rec.t0 is not None:
+                        self._perf.note_phase(rec.family,
+                                              (now - rec.t0) * 1e3)
+                    rec.t0 = None
             for rid, rt in list(self._rt.items()):
                 tr.end(rt.pop("phase", None), t=now, aborted=True)
                 tr.end(rt.get("root"), t=now, aborted=True)
@@ -1814,16 +1965,11 @@ class ServingEngine:
         self.registry.counter("serving/timed_out_total").inc()
         outputs.append(self._emit(req, now))
 
-    def _attended_keys(self, active: list) -> int:
-        """Keys the coming decode attends, summed over its slots: each
-        slot's write offset in the left-padded row less its pad (what the
-        paged kernel must read; the ``ctx_tokens`` of the dispatch span)."""
-        return sum(int(self._offsets[slot]) - self.C + req.prompt_len
-                   for slot, req in active)
-
-    def _count_paged_walk(self, active: list) -> None:
-        """What the traffic lets the paged kernel skip, from the host
-        offsets ``_attended_keys`` sums: the pages the coming decode's live
+    def _count_paged_walk(self, active: list, offs) -> None:
+        """What the traffic lets the paged kernel skip, from the write
+        offsets ``offs`` the coming decode is launched at (the dispatch
+        span's ``ctx_tokens`` sums each less its pad: the keys the paged
+        kernel must read): the pages the coming decode's live
         slots attend (``serving/paged_pages_walked_total`` — each slot's
         band ``[max(pad, offset - window + 1), offset + rows - 1]`` in
         pages; a hybrid model's global layers walk the whole band, this is
@@ -1835,7 +1981,7 @@ class ServingEngine:
         rows = self._spec_k + 1 if self._spec_k else 1
         walked = 0
         for slot, req in active:
-            off = int(self._offsets[slot])
+            off = int(offs[slot])
             low = self.C - req.prompt_len
             if self._attn_window is not None:
                 low = max(low, off - self._attn_window + 1)
@@ -1886,14 +2032,14 @@ class ServingEngine:
         self.registry.counter("serving/kv_rows_written_total").inc(rows)
         self.registry.counter("serving/kv_pages_touched_total").inc(pages)
 
-    def _count_decode_write(self, active: list) -> None:
-        """The coming decode's (or verify round's) pool write, from the host
-        offsets: every live slot's row(s) from its offset on — a verify
-        chunk's rows past the table's end are dropped."""
+    def _count_decode_write(self, active: list, offs) -> None:
+        """The coming decode's (or verify round's) pool write, from the
+        offsets it is launched at: every live slot's row(s) from its offset
+        on — a verify chunk's rows past the table's end are dropped."""
         page = self._kv.page_size
         rows = pages = 0
         for slot, _ in active:
-            off = int(self._offsets[slot])
+            off = int(offs[slot])
             last = min(off + self._spec_k + 1, self.T) - 1
             if last >= off:
                 rows += last - off + 1
@@ -1983,40 +2129,48 @@ class ServingEngine:
                                                self._topps))]
         self.registry.counter(f"serving/sampler_steps_total/{path}").inc()
 
-    def _collect_decode(self) -> list:
-        """Collect the in-flight decode step: ONE explicit packed fetch
-        (tokens + finite flags), then the *cheap* pre-dispatch bookkeeping —
-        offset advance, non-finite quarantine, stop detection, slot release
-        — so the next dispatch sees the true active set and never decodes
-        speculatively for a finished slot.  Returns the deferred host work
+    def _collect_decode(self, keep_newest: bool = False) -> list:
+        """Collect the OLDEST decode step in flight: ONE explicit packed
+        fetch (tokens + finite flags), then the cheap bookkeeping — the
+        offset advance its launch took on credit is committed, non-finite
+        quarantine, stop detection, slot release.  ``keep_newest``: this
+        step launched a program, which stays in flight (reading it now
+        would idle the device for the host's next turn) — so nothing is
+        collected when it is the only one.  Returns the deferred host work
         as ``(kind, slot, req, tok, intertoken_ms, now)`` records for
-        :meth:`_finish_decode` to run AFTER the next dispatch."""
-        if self._pending is None:
+        :meth:`_finish_decode`.
+
+        The step after this one may already be queued, launched when the
+        host knew only counts: a request that stops here on a TOKEN
+        (``stop_token_ids``, ``eos_token_id``) or goes non-finite has a row
+        in it — an **overrun** (``serving/decode_overrun_rows_total``).
+        That row's token is never read (the request is no longer live when
+        its step is collected: :meth:`_row_live`), its offset advance is
+        dropped, and its write landed past the request's last cell in a
+        decode page the slot held when the program was queued; the slot is
+        released here, whatever is admitted into it is launched later on
+        the same device queue, and the prefix index holds prompt pages
+        only, so nothing an overrun writes is ever read."""
+        if len(self._inflight) <= (1 if keep_newest else 0):
             return []
-        packed_dev, active = self._pending
-        self._pending = None
-        packed = self._fetch_tokens(packed_dev, self._pending_moe_seq)  # [2, B]
+        rec = self._inflight.popleft()
+        packed = self._fetch_tokens(rec.packed, rec.moe_seq)  # [2, B]
         toks, finite = packed[0], packed[1]
         now = self._clock()
         tr = self.tracer
-        bspan, self._batch_span = self._batch_span, None
+        bspan = rec.span
+        queued = self._inflight[0].active if self._inflight else ()
         post: list = []
-        for slot, req, gen in active:
-            if req.state is not RequestState.DECODE \
-                    or self.scheduler.slot_of(req.request_id) != slot \
-                    or self._slot_gen[slot] != gen:
-                # swept (cancelled / timed out) — or preempted AND
-                # re-admitted — while the step was in flight: the slot was
-                # released (and possibly re-granted), so the stale token is
-                # discarded and the offset untouched.  The state check
-                # alone is not enough (a preemption round-trip can put the
-                # request back in DECODE within one step), and neither is
-                # slot identity (it can be re-granted the SAME slot) — the
-                # occupancy generation is what tells the generations apart.
+        for slot, req, gen in rec.active:
+            if not self._row_live(slot, req, gen):
+                # swept, stopped, or preempted and re-admitted while the
+                # step was in flight: the stale token is discarded and the
+                # offset untouched
                 continue
             self._offsets[slot] += 1  # the step wrote req's previous token
             if not finite[slot]:
                 self._fail_slot_state(slot, req, now)
+                self._count_overrun(slot, gen, queued)
                 post.append(("fail", slot, req, 0, None, now))
                 continue
             tok = int(toks[slot])
@@ -2025,7 +2179,7 @@ class ServingEngine:
             req.generated.append(tok)
             # attributed to the version that DISPATCHED this step — a swap
             # between dispatch and collect computed under the old buffers
-            req.weights_version = self._pending_version
+            req.weights_version = rec.version
             req.decode_steps += 1
             if bspan is not None:
                 tr.instant("decode_slot", request_id=req.request_id,
@@ -2036,49 +2190,53 @@ class ServingEngine:
             reason = self._stop_reason(req, tok)
             if reason is not None:
                 self._finish_request(slot, req, reason, now)
+                self._count_overrun(slot, gen, queued)
             else:
                 self._next_tok[slot] = tok
             post.append(("token", slot, req, tok, ms, now))
-        if bspan is not None:
-            tr.end(bspan, t=now)
-        if self._perf is not None and self._batch_t0 is not None:
-            fam, t0 = self._batch_t0
-            self._perf.note_phase(fam, (now - t0) * 1e3)
-        self._batch_t0 = None
+        self._close_batch_span(rec, now)
         return post
 
-    def _dispatch_decode(self, active: list) -> None:
-        """Dispatch one per-slot-offset decode + row-wise sampling for the
-        current active set and leave the packed result in flight.  All
-        host→device traffic is explicit: the per-step-varying inputs
-        (next-token feed, write offsets, token indices) stage as ONE
-        explicit pytree put; the admission-time sampling state rides device
-        mirrors refreshed only when dirty.  Host arrays are copied before
-        staging — on backends where ``device_put`` aliases host memory, the
-        engine's in-place mutation of ``_next_tok``/``_offsets`` must never
-        reach into an in-flight computation."""
+    def _count_overrun(self, slot: int, gen: int, queued) -> None:
+        """A request just ended at a collect: count the row the step
+        already queued (``queued``: its rows) computed for it, if any — none
+        for a stop by length, whose last token the launch could count."""
+        if any(s == slot and g == gen for s, _, g in queued):
+            self.registry.counter("serving/decode_overrun_rows_total").inc()
+
+    def _dispatch_decode(self, active: list, offs, ahead) -> None:
+        """Dispatch one per-slot-offset decode + row-wise sampling for
+        ``active`` and leave the packed result in flight — BEHIND the step
+        before it, when that one has not been fetched yet
+        (``serving/decode_runahead_total``).  ``offs [B]`` are the write
+        offsets and ``ahead [B]`` each slot's rows still in flight
+        (:meth:`_launch_decode`); the token index is the request's count
+        plus those rows, so the sampler keys are what a fetched count would
+        give.  A slot with a row in flight is fed that row's sampled token
+        where it lies on the device; the others — they began decoding since
+        — the host's ``_next_tok`` (:func:`_feed_tokens`).
+
+        All host→device traffic is explicit: the per-step-varying inputs
+        (next-token feed, write offsets, token indices, the feed's mask)
+        stage as ONE explicit pytree put; the admission-time sampling state
+        rides device mirrors refreshed only when dirty.  Host arrays are
+        copied before staging — on backends where ``device_put`` aliases
+        host memory, the engine's in-place mutation of ``_next_tok`` must
+        never reach into an in-flight computation."""
         tok_idx = np.zeros((self.B,), np.int32)
         for slot, req in active:
-            tok_idx[slot] = len(req.generated)
-        if self.tracer is not None or self._perf is not None:
-            # the batch-level decode span covers dispatch -> collect (the
-            # honest in-flight device window of the pipelined engine);
-            # per-slot child spans land at collect time.  The perf layer
-            # shares the dispatch stamp so its accounting matches the span.
-            t0 = self._clock()
-            self._batch_t0 = ("decode_step", t0)
-            if self.tracer is not None:
-                self._batch_span = self.tracer.begin(
-                    "decode_step", t=t0, step=self._steps,
-                    active=len(active),
-                    weights_version=self.weights_version)
+            tok_idx[slot] = len(req.generated) + ahead[slot]
+        before = self._inflight[-1] if self._inflight else None
+        t_launch = (self._clock() if before is None and (
+            self.tracer is not None or self._perf is not None) else None)
         # eager slicing of a stacked [3, B] array would bind scalar start
         # indices host-side (an implicit transfer the guard rejects), so the
         # per-step inputs stage as one explicit pytree put instead; a dirty
         # block table rides the SAME put (still one explicit host→device
         # crossing per step) and a clean one reuses its mirror
-        staged = [self._next_tok[:, None].copy(), self._offsets.copy(),
-                  tok_idx]
+        staged = [self._next_tok[:, None].copy(), offs, tok_idx]
+        if before is not None:
+            staged.append(ahead == 0)
         stage_kv = self._kv.tables_dirty or self._tables_dev is None
         stage_ad = self._adapters is not None and (
             self._adapter_dirty or self._atables_dev is None)
@@ -2089,8 +2247,12 @@ class ServingEngine:
             # tables — still one explicit host→device crossing per step
             staged.append(self._adapter_tables.copy())
         put = list(self._audit.put(tuple(staged)))
-        tok, offs, tidx = put[:3]
+        tok, offs_dev, tidx = put[:3]
         cursor = 3
+        if before is not None:
+            tok = _feed_tokens(before.toks, tok, put[cursor])
+            cursor += 1
+            self.registry.counter("serving/decode_runahead_total").inc()
         if stage_kv:
             self._tables_dev = put[cursor]
             cursor += 1
@@ -2101,12 +2263,12 @@ class ServingEngine:
             self._adapter_dirty = False
         if self._adapters is not None:
             logits, self.caches, self.valid = self.model.decode_pages_lora(
-                tok, offs, self._tables_dev, self.caches, self.valid,
+                tok, offs_dev, self._tables_dev, self.caches, self.valid,
                 self._adapter_pool, self._atables_dev,
                 paged_kernel=self._paged_kernel)
         else:
             logits, self.caches, self.valid = self.model.decode_pages(
-                tok, offs, self._tables_dev, self.caches, self.valid,
+                tok, offs_dev, self._tables_dev, self.caches, self.valid,
                 paged_kernel=self._paged_kernel)
         self._count_gather_step()
         if self._ssm:
@@ -2127,11 +2289,8 @@ class ServingEngine:
         toks, finite = _sample_rows(
             logits, self._keys_dev, tidx,
             self._temps_dev, self._topks_dev, self._topps_dev)
-        self._pending = (_pack_tokens(toks, finite),
-                         [(slot, req, int(self._slot_gen[slot]))
-                          for slot, req in active])
-        self._pending_version = self.weights_version
-        self._pending_moe_seq = getattr(self.model, "moe_seq", None)
+        self._put_in_flight(_pack_tokens(toks, finite), active,
+                            "decode_step", t_launch, toks=toks)
 
     def _spec_dispatch(self, active: list) -> None:
         """Dispatch one speculative draft-k-verify round for the current
@@ -2153,14 +2312,8 @@ class ServingEngine:
         tok_idx = np.zeros((self.B,), np.int32)
         for slot, req in active:
             tok_idx[slot] = len(req.generated)
-        if self.tracer is not None or self._perf is not None:
-            t0 = self._clock()
-            self._batch_t0 = ("spec_round", t0)
-            if self.tracer is not None:
-                self._batch_span = self.tracer.begin(
-                    "spec_round", t=t0, step=self._steps,
-                    active=len(active), k=k,
-                    weights_version=self.weights_version)
+        t_launch = (self._clock() if self.tracer is not None
+                    or self._perf is not None else None)
         offs_steps = self._offsets[None, :] + np.arange(k, dtype=np.int32)[:, None]
         tidx_steps = tok_idx[None, :] + np.arange(k, dtype=np.int32)[:, None]
         staged = [self._next_tok[:, None].copy(), self._offsets.copy(),
@@ -2239,11 +2392,8 @@ class ServingEngine:
             vlogits, jnp.stack(q_filts, axis=1), jnp.stack(props, axis=1),
             self._keys_dev, tidx, self._temps_dev, self._topks_dev,
             self._topps_dev, dfin)
-        self._pending = (packed,
-                         [(slot, req, int(self._slot_gen[slot]))
-                          for slot, req in active], props[-1])
-        self._pending_version = self.weights_version
-        self._pending_moe_seq = getattr(self.model, "moe_seq", None)
+        self._put_in_flight(packed, active, "spec_round", t_launch,
+                            last_prop=props[-1])
 
     def _spec_collect(self) -> list:
         """Collect the in-flight speculative round: ONE explicit packed
@@ -2260,26 +2410,24 @@ class ServingEngine:
         host-side accounting, no device copy), index-based causal masking
         hides its stale keys, and later rounds overwrite them before any
         query can attend that far."""
-        if self._pending is None:
+        if not self._inflight:
             return []
-        packed_dev, active, last_prop = self._pending
-        self._pending = None
+        rec = self._inflight.popleft()
+        active, last_prop = rec.active, rec.last_prop
         k = self._spec_k
-        packed = self._fetch_tokens(packed_dev, self._pending_moe_seq)  # [k+3, B]
+        packed = self._fetch_tokens(rec.packed, rec.moe_seq)  # [k+3, B]
         commit, acc, finite = packed[:k + 1], packed[k + 1], packed[k + 2]
         now = self._clock()
         tr = self.tracer
-        bspan, self._batch_span = self._batch_span, None
+        bspan = rec.span
         post: list = []
         ingest = np.full((self.B,), self.T, np.int32)
         need_ingest = False
         reg = self.registry
         for slot, req, gen in active:
-            if req.state is not RequestState.DECODE \
-                    or self.scheduler.slot_of(req.request_id) != slot \
-                    or self._slot_gen[slot] != gen:
+            if not self._row_live(slot, req, gen):
                 # swept — or preempted and re-admitted — while the round
-                # was in flight (see _collect_decode)
+                # was in flight
                 continue
             if not finite[slot]:
                 self._fail_slot_state(slot, req, now)
@@ -2309,7 +2457,7 @@ class ServingEngine:
             reg.counter("serving/spec_committed_total").inc(m)
             if m:
                 # the round ran under the dispatching version's buffers
-                req.weights_version = self._pending_version
+                req.weights_version = rec.version
             req.decode_steps += 1
             if bspan is not None:
                 # per-slot round outcome: proposals accepted + tokens
@@ -2333,12 +2481,7 @@ class ServingEngine:
             # inter-token percentiles measure the effective per-token rate
             per_tok_ms = gap_ms / m if (gap_ms is not None and m) else None
             post.append(("tokens", slot, req, toks, per_tok_ms, now))
-        if bspan is not None:
-            tr.end(bspan, t=now)
-        if self._perf is not None and self._batch_t0 is not None:
-            fam, t0 = self._batch_t0
-            self._perf.note_phase(fam, (now - t0) * 1e3)
-        self._batch_t0 = None
+        self._close_batch_span(rec, now)
         if need_ingest:
             (ing_offs,) = self._audit.put((ingest,))
             dad = ((self._adapter_pool, self._atables_dev)

@@ -981,7 +981,8 @@ class ParallelInferenceModel(_ServingBase):
                   jnp.asarray(row_valid, jnp.int32), jnp.int32(slot))
 
     def _batch_committed(self, valid):
-        """``valid`` as int32, committed to the batch sharding.  An engine's
+        """``valid`` (or a step's ``[B, S]`` tokens: :meth:`_paged_phase`)
+        as int32, committed to the batch sharding.  An engine's
         first validity array is a fresh, uncommitted ``zeros``; every later
         one comes out of a program pinned to that sharding.  Committing the
         argument gives the inserts ONE signature: without it the first
@@ -1128,6 +1129,13 @@ class ParallelInferenceModel(_ServingBase):
 
         self._serving_lru()
         toks = jnp.asarray(toks).astype(jnp.int32)
+        if int(toks.shape[0]) == self.config.batch_size:
+            # ONE signature whoever feeds a step its tokens: the host's put
+            # is uncommitted, a token array that is a program's output (the
+            # serve loop feeds a step the tokens of the step before it
+            # where they lie) is committed — and an argument's commitment
+            # is part of a jit's cache key
+            toks = self._batch_committed(toks)
         valid = jnp.asarray(valid, jnp.int32)
         pk = self.paged_kernel if paged_kernel is None else bool(paged_kernel)
         lora = apool is not None

@@ -183,6 +183,104 @@ def step_until_decoding(engine):
         engine.step()
 
 
+def collect_then_dispatch(engine):
+    """Hold one engine to the order the serve loop had before it ran a step
+    ahead (test-only; the engine has no such switch): fetch step N, THEN
+    launch step N+1.  Nothing is launched behind an unfetched step, so no
+    row is ever computed for a request that has stopped: the reference the
+    pool-content tests compare the run-ahead loop with."""
+    launch, collect = engine._launch_decode, engine._collect_decode
+    fetched = []
+
+    def launch_after_collect():
+        fetched[:] = collect()
+        return launch()
+
+    def already_collected(keep_newest=False):
+        post = list(fetched)
+        fetched.clear()
+        return post
+
+    engine._launch_decode = launch_after_collect
+    engine._collect_decode = already_collected
+    return engine
+
+
+def readable_cache(engine):
+    """Every cell of the device cache that something may still read, as
+    ``{what: [numpy a layer array]}``: the pages the prefix index holds
+    (whole), the K/V cells of live slots that their validity rows mark, and
+    the state rows of live slots (a model with recurrent layers).  A slot
+    still prefilling counts up to the chunks it has run: its validity row is
+    written whole at admission, but no query reaches a cell (or a state
+    row) before the chunk that computes it.  What a released slot leaves
+    behind in pages and rows nobody holds is not in it."""
+    import numpy as np
+
+    from neuronx_distributed_tpu.kvcache.allocator import NULL_PAGE
+
+    kv = engine._kv
+    layers = [[np.asarray(a) for a in layer]
+              for layer in jax.device_get(engine.caches)]
+    valid = np.asarray(engine.valid)
+    NP, P = kv.alloc.num_pages, kv.page_size
+    paged = [a for layer in layers for a in layer
+             if a.ndim == 4 and a.shape[0] == NP and a.shape[2] == P]
+    rows = [a for layer in layers for a in layer
+            if a.shape[0] == engine.B and a.shape[0] != NP]
+    out = {}
+    if kv.index is not None:
+        for node in kv.index._iter():
+            if node.page != NULL_PAGE:
+                out["index", node.page] = [a[node.page] for a in paged]
+    for slot, _ in engine.scheduler.active():
+        st = engine._chunking.get(slot)
+        done = (engine.T if st is None else
+                st.fresh[st.next_i][0] * P if st.pages_remaining else engine.C)
+        for t in np.nonzero(valid[slot, :done])[0]:
+            page = kv.tables[slot][t // P]
+            out["cell", slot, int(t)] = [a[page, :, t % P] for a in paged]
+        if st is None or st.next_i:
+            out["state", slot] = [a[slot] for a in rows]
+    return out
+
+
+def lockstep_with_the_old_order(make_engine, make_requests, max_steps=600):
+    """Serve ``make_requests()`` through ``make_engine()`` as it is and
+    through a second one held to the old order
+    (:func:`collect_then_dispatch`), a step of each in turn, and hold them
+    to each other after every step: what :func:`readable_cache` finds, every
+    terminal output, the allocators' invariants.  Returns ``(the run-ahead
+    engine, the old-order engine, {request id: (state, finish reason,
+    tokens)})``."""
+    import numpy as np
+
+    ahead = make_engine()
+    old = collect_then_dispatch(make_engine())
+    for eng in (ahead, old):
+        for req in make_requests():
+            eng.submit(req)
+    got = ({}, {})
+    steps = 0
+    while ahead.has_work or old.has_work:
+        for outs, eng in zip(got, (ahead, old)):
+            for o in eng.step():
+                outs[o.request_id] = (o.state, o.finish_reason,
+                                      tuple(o.token_ids))
+            eng._kv.assert_invariants()
+            eng.scheduler.assert_invariants()
+        mine, theirs = readable_cache(ahead), readable_cache(old)
+        assert mine.keys() == theirs.keys(), steps
+        for what in mine:
+            for x, y in zip(mine[what], theirs[what]):
+                np.testing.assert_array_equal(
+                    x, y, err_msg=f"{what} after step {steps}")
+        assert got[0] == got[1], steps
+        steps += 1
+        assert steps < max_steps
+    return ahead, old, got[0]
+
+
 def sharded_params(params):
     """Place flax Partitioned params on the global mesh per their metadata
     (shared by the layer/qkv/model parity tests)."""

@@ -23,7 +23,14 @@ wall-clock, so they stay CI-safe):
   token-identical to the solo ``generate`` (greedy under staggered arrivals
   + slot reuse, and a sampled per-request rng stream), with ONE packed
   fetch + ONE packed put per steady engine step, counted by the transfer
-  audit.
+  audit;
+- **the decode loop one step ahead** — step N+1 is launched from step N's
+  tokens on the device before N is fetched: token identity with solo
+  ``generate`` whatever stops a request (length, a stop token mid-page and
+  at a page's last row, ``eos_token_id``; greedy and sampled; fresh and
+  continuing slots in one program), an overrun row reaching nothing, the
+  two counters, a request that leaves with a step in flight, and the pool
+  after an overrun held cell for cell to the old order's.
 """
 
 import os
@@ -36,7 +43,12 @@ import numpy as np
 import pytest
 
 import neuronx_distributed_tpu as nxd
-from conftest import sharded_params, solo_generate
+from conftest import (
+    lockstep_with_the_old_order,
+    readable_cache,
+    sharded_params,
+    solo_generate,
+)
 from neuronx_distributed_tpu.data.prefetch import DevicePrefetcher
 from neuronx_distributed_tpu.obs import MetricRegistry, Observability, TransferAudit
 from neuronx_distributed_tpu.resilience import AnomalyPolicy, clear_plan, install_plan
@@ -417,3 +429,329 @@ def test_serving_one_packed_fetch_and_put_per_steady_step(pool_factory):
     assert snap["transfer/explicit_fetches_total"] == \
         snap["serving/host_blocked_ms"]["count"]
     assert snap["transfer/explicit_fetches_total"] <= engine2._steps + 4
+
+
+# -- serving: the decode loop one step ahead --------------------------------
+
+C_, PAGE_ = 8, 4          # pool_factory's context_len and the page the tests use
+
+
+def _cut_at_stop(tokens, max_new, stops):
+    """What a request generates: the solo tokens up to ``max_new``, cut
+    after the first one in ``stops`` (the stop token itself is kept)."""
+    out = []
+    for t in tokens[:max_new]:
+        out.append(t)
+        if t in stops:
+            break
+    return out
+
+
+def _first_seen_at(tokens, j, last):
+    """The least index >= ``j`` (and <= ``last``) at which ``tokens`` shows a
+    token for the first time: a stop on it ends the request exactly there."""
+    for k in range(j, last + 1):
+        if tokens[k] not in tokens[:k]:
+            return k
+    raise AssertionError(f"no fresh token in {tokens} from {j} to {last}")
+
+
+def _committed_offsets_hold(engine):
+    """Between steps a decoding slot's COMMITTED offset is where its last
+    token will be written — whatever is in flight was advanced on credit at
+    the launch and is not in ``_offsets`` — and every other slot is parked."""
+    from neuronx_distributed_tpu.serving import RequestState
+
+    live = {}
+    for slot, req in engine.scheduler.active():
+        if req.state is RequestState.DECODE:
+            live[slot] = engine.C + len(req.generated) - 1
+    for slot in range(engine.B):
+        assert engine._offsets[slot] == live.get(slot, engine.T), (
+            slot, engine._offsets, live)
+
+
+def _drain(engine, outs, max_steps=300):
+    """Step to the end, holding the committed offsets and the allocator's
+    invariants after every step."""
+    steps = 0
+    while engine.has_work:
+        for o in engine.step():
+            outs[o.request_id] = o
+        _committed_offsets_hold(engine)
+        engine._kv.assert_invariants()
+        steps += 1
+        assert steps < max_steps
+
+
+@pytest.fixture
+def ahead_case(pool_factory):
+    """Six prompts and what each generates alone, greedy and sampled."""
+    cfg, make = pool_factory
+    rs = np.random.RandomState(11)
+    prompts = [rs.randint(1, cfg.vocab_size, size=rs.randint(3, 9)).tolist()
+               for _ in range(6)]
+    rng = jax.random.PRNGKey(42)
+    solo = make(batch_size=1)
+
+    def alone(i, sampled, n=8):
+        kw = (dict(temperature=0.8, rng=rng, request_ids=[i])
+              if sampled else {})
+        return solo_generate(solo, prompts[i], n, **kw)
+
+    return cfg, make, prompts, rng, alone
+
+
+@pytest.mark.perf
+@pytest.mark.parametrize("eos", [False, True], ids=["stop_ids", "eos"])
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+def test_runahead_token_identical_whatever_stops_a_request(ahead_case,
+                                                           sampled, eos):
+    """Acceptance bar of the run-ahead loop: six requests over three slots,
+    admitted at different steps so that fresh and continuing slots share a
+    program, stopped by length, by a stop token mid-page, by a stop token
+    whose step wrote a page's LAST row (the overrun lands on the next
+    page's first), and by the engine's ``eos_token_id`` — each generates
+    what it generates alone, streams exactly that, and nothing else is
+    counted."""
+    from neuronx_distributed_tpu.serving import Request, SamplingParams, ServingEngine
+
+    cfg, make, prompts, rng, alone = ahead_case
+    want = {i: alone(i, sampled) for i in range(6)}
+    max_new = {0: 5, 1: 7, 2: 7, 3: 6, 4: 7, 5: 3}
+    # stop on the token first seen at (or after) index 2 / 4 / 1: request 2's
+    # stopping step writes cell C + 3, the last row of its page
+    at = {1: _first_seen_at(want[1], 2, 5), 2: _first_seen_at(want[2], 4, 5),
+          4: _first_seen_at(want[4], 1, 5)}
+    stop_ids = {i: (want[i][j],) for i, j in at.items()}
+    eos_id = want[0][_first_seen_at(want[0], 3, 3)] if eos else None
+    stops = {i: set(stop_ids.get(i, ())) | ({eos_id} if eos else set())
+             for i in range(6)}
+    expect = {i: _cut_at_stop(want[i], max_new[i], stops[i]) for i in range(6)}
+    lands = {(C_ + len(expect[i]) - 1) % PAGE_ for i in at
+             if expect[i][-1] in stops[i]}
+    assert 0 in lands and lands - {0}, f"stops land at {lands}: {expect}"
+
+    streamed = {}
+    engine = ServingEngine(make(), page_size=PAGE_, rng=rng,
+                           eos_token_id=eos_id)
+
+    def submit(i):
+        engine.submit(Request(
+            request_id=i, prompt_ids=prompts[i], max_new_tokens=max_new[i],
+            stop_token_ids=stop_ids.get(i, ()),
+            sampling=SamplingParams(temperature=0.8 if sampled else 0.0),
+            stream_cb=lambda r, t: streamed.setdefault(
+                r.request_id, []).append(t)))
+
+    outs = {}
+    for i in range(3):
+        submit(i)
+    for _ in range(3):               # all three decoding, a step in flight
+        for o in engine.step():
+            outs[o.request_id] = o
+    for i in range(3, 6):            # late joiners: fresh rows beside fed ones
+        submit(i)
+    _drain(engine, outs)
+    assert set(outs) == set(range(6))
+    for i in range(6):
+        assert list(outs[i].token_ids) == expect[i], f"request {i} diverged"
+        assert streamed[i] == expect[i]
+        by_token = expect[i][-1] in stops[i]
+        assert outs[i].finish_reason == ("stop_token" if by_token
+                                         else "length")
+    snap = engine.registry.snapshot()
+    assert snap["serving/tokens_total"] == sum(map(len, expect.values()))
+    # one row a request that a TOKEN stopped in a decode step short of its
+    # length; none for a stop by length
+    assert snap["serving/decode_overrun_rows_total"] == sum(
+        1 for i in range(6) if expect[i][-1] in stops[i]
+        and 1 < len(expect[i]) < max_new[i])
+    engine.scheduler.assert_invariants()
+
+
+@pytest.mark.perf
+@pytest.mark.parametrize("how", ["stop_token", "eos", "non_finite", "length"])
+def test_an_overrun_row_reaches_nothing(ahead_case, how):
+    """A request that a token (or a non-finite row) stops is found one
+    launch late: the row already queued for it is counted as an overrun and
+    its token reaches neither ``generated``, the stream, the stats nor
+    ``serving/tokens_total``; its co-batch never notices.  A stop by length
+    is a count the host holds: no row, no overrun."""
+    from neuronx_distributed_tpu.serving import Request, ServingEngine
+
+    cfg, make, prompts, rng, alone = ahead_case
+    want = {i: alone(i, False) for i in (1, 2)}
+    j = _first_seen_at(want[1], 2, 5)
+    stop = want[1][j]
+    engine = ServingEngine(make(), page_size=PAGE_,
+                           eos_token_id=stop if how == "eos" else None)
+    streamed = {}
+    for i in (1, 2):
+        engine.submit(Request(
+            request_id=i, prompt_ids=prompts[i], max_new_tokens=7,
+            stop_token_ids=(stop,) if how == "stop_token" and i == 1 else (),
+            stream_cb=lambda r, t: streamed.setdefault(
+                r.request_id, []).append(t)))
+    outs = {}
+    if how == "non_finite":
+        from conftest import step_until_decoding
+
+        step_until_decoding(engine)
+        slot = {r.request_id: s for s, r in engine.scheduler.active()}[1]
+        install_plan({"faults": [{"point": "serving/decode_logits",
+                                  "action": "nan", "slot": slot}]})
+    try:
+        _drain(engine, outs)
+    finally:
+        clear_plan()
+    expect = {i: _cut_at_stop(want[i], 7, {stop} if how == "eos" or (
+        how == "stop_token" and i == 1) else ()) for i in (1, 2)}
+    if how == "non_finite":
+        assert outs[1].state == "failed"
+        expect[1] = list(outs[1].token_ids)
+        assert expect[1] == want[1][:len(expect[1])] and len(expect[1]) < 7
+    for i in (1, 2):
+        assert list(outs[i].token_ids) == expect[i]
+        assert streamed[i] == expect[i]
+    snap = engine.registry.snapshot()
+    assert snap["serving/tokens_total"] == len(expect[1]) + len(expect[2])
+    # found in a decode step's fetch (a first token that stops is found at
+    # the prefill's own fetch: no decode row was ever launched for it)
+    late = (1 if how == "non_finite"
+            else sum(1 < len(expect[i]) < 7 for i in (1, 2)))
+    assert late == (how != "length") or how == "eos"
+    assert snap["serving/decode_overrun_rows_total"] == late
+
+
+@pytest.mark.perf
+def test_runahead_is_every_decode_step_but_the_first_of_a_busy_stretch(
+        pool_factory):
+    """``serving/decode_runahead_total`` counts the decode steps launched
+    behind an unfetched one: over two busy stretches, every step but each
+    stretch's first — and the loop still makes one fetch a decode step."""
+    from neuronx_distributed_tpu.serving import Request, ServingEngine
+    from neuronx_distributed_tpu.serving.engine import SAMPLER_PATHS
+
+    _, make = pool_factory
+    engine = ServingEngine(make(), page_size=PAGE_, transfer_guard="forbid")
+
+    def decode_steps():
+        snap = engine.registry.snapshot()
+        return sum(snap[f"serving/sampler_steps_total/{p}"]
+                   for p in SAMPLER_PATHS)
+
+    for stretch, n in enumerate((6, 4), start=1):
+        for rid in range(2):
+            engine.submit(Request(request_id=10 * stretch + rid,
+                                  prompt_ids=[1, 2, 3 + rid],
+                                  max_new_tokens=n + rid))
+        assert len(engine.run_until_complete(max_steps=100)) == 2
+        assert not engine.has_work and not engine._inflight
+        snap = engine.registry.snapshot()
+        assert decode_steps() > 2 * stretch
+        assert snap["serving/decode_runahead_total"] \
+            == decode_steps() - stretch
+    assert snap["serving/decode_overrun_rows_total"] == 0.0
+
+
+@pytest.mark.perf
+@pytest.mark.parametrize("how", ["cancel", "timeout", "preempt"])
+def test_a_request_that_leaves_with_a_step_in_flight(ahead_case, how):
+    """Cancelled, timed out or preempted between two steps — a decode is in
+    flight then: the token in flight is dropped with the advance its launch
+    took on credit (the committed offset stands, :func:
+    `_committed_offsets_hold` after every step), a cancelled or expired
+    request keeps exactly the tokens it had, a preempted one resumes
+    token-identically, and the co-batch generates what it generates alone."""
+    from neuronx_distributed_tpu.serving import Request, ServingEngine
+
+    cfg, make, prompts, rng, alone = ahead_case
+    t = [0.0]
+    engine = ServingEngine(make(), page_size=PAGE_, num_pages=13,
+                           clock=lambda: t[0])
+    outs = {}
+    for i in range(3):
+        engine.submit(Request(
+            request_id=i, prompt_ids=prompts[i], max_new_tokens=8,
+            priority="batch" if how == "preempt" else "interactive",
+            deadline_s=5.0 if how == "timeout" and i == 1 else None))
+    for _ in range(5):
+        for o in engine.step():
+            outs[o.request_id] = o
+        _committed_offsets_hold(engine)
+    assert engine._inflight and not outs
+    had = {r.request_id: list(r.generated)
+           for _, r in engine.scheduler.active()}
+    assert all(len(g) >= 2 for g in had.values())
+    if how == "cancel":
+        assert engine.cancel(1)
+    elif how == "timeout":
+        t[0] = 10.0
+    else:
+        engine.submit(Request(request_id=3, prompt_ids=prompts[3],
+                              max_new_tokens=3, priority="interactive"))
+    _drain(engine, outs)
+    snap = engine.registry.snapshot()
+    full = {i: alone(i, False) for i in range(4)}
+    if how == "preempt":
+        assert snap["serving/preemptions_total"] >= 1.0
+        assert any(o.preemptions for o in outs.values())
+        assert list(outs[3].token_ids) == full[3][:3]
+    else:
+        assert outs[1].state == ("cancelled" if how == "cancel"
+                                 else "timed_out")
+        # what it had committed, and not the token that was in flight
+        assert list(outs[1].token_ids) == had[1] == full[1][:len(had[1])]
+    for i in range(3):
+        if how == "preempt" or i != 1:
+            assert list(outs[i].token_ids) == full[i], f"request {i}"
+    assert snap["serving/decode_overrun_rows_total"] == 0.0
+    evictable = engine._kv.index.evictable_pages()
+    assert engine._kv.alloc.in_use == evictable, "leaked pages"
+
+
+@pytest.mark.perf
+def test_pool_after_an_overrun_is_cell_for_cell_the_old_orders(ahead_case):
+    """Pool safety: the same requests through the run-ahead loop and through
+    the order it replaced (fetch, then launch: no row is ever computed for a
+    stopped request), step for step.  After every step — the one in which a
+    stop token is found and its overrun row is already queued among them —
+    every page the prefix index holds, every valid cell of every live slot
+    (the next occupant of the released slot too) and every output is bit
+    for bit the same, and both allocators pass their invariants.  An
+    overrun writes a decode page only, and the index holds prompt pages."""
+    from neuronx_distributed_tpu.serving import Request, ServingEngine
+
+    cfg, make, prompts, rng, alone = ahead_case
+    # prompts of whole pages (4 or 8 tokens): an index page has no pad cell
+    # that only a former occupant of the physical page could have written
+    whole = {i: (prompts[i] * 3)[:8 if i % 2 else 4] for i in range(6)}
+    solo = make(batch_size=1)
+    want = {i: solo_generate(solo, whole[i], 8) for i in range(6)}
+    # two of the first three stop on a token: one whose stopping step wrote
+    # its page's last row (index 4: the overrun opens the next page), one
+    # mid-page
+    fresh = {i: [k for k in range(1, 6) if want[i][k] not in want[i][:k]]
+             for i in range(3)}
+    last_row = next(i for i in range(3) if 4 in fresh[i])
+    mid = next(i for i in range(3) if i != last_row and {2, 3} & set(fresh[i]))
+    at = {last_row: 4, mid: min({2, 3} & set(fresh[mid]))}
+
+    def requests():
+        return [Request(request_id=i, prompt_ids=whole[i], max_new_tokens=7,
+                        stop_token_ids=(want[i][at[i]],) if i in at else ())
+                for i in range(6)]
+
+    ahead, old, got = lockstep_with_the_old_order(
+        lambda: ServingEngine(make(), page_size=PAGE_, num_pages=14),
+        requests)
+    assert len(got) == 6
+    for i in at:
+        assert got[i][1] == "stop_token"
+        assert list(got[i][2]) == want[i][:at[i] + 1]
+    assert ahead.registry.snapshot()[
+        "serving/decode_overrun_rows_total"] == len(at)
+    assert old.registry.snapshot()["serving/decode_overrun_rows_total"] == 0
+    assert old.registry.snapshot()["serving/decode_runahead_total"] == 0
+    assert any(k[0] == "index" for k in readable_cache(ahead))

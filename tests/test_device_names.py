@@ -226,12 +226,13 @@ def test_engine_steps_are_nested_phase_spans_in_a_profile(tiny_paged,
     attended = []
     dispatch = engine._dispatch_decode
 
-    def recording(active):
+    def recording(active, offs, ahead):
         # independent of the engine's offsets: a slot has attended its
-        # prompt and all it generated but the token this step feeds in
-        attended.append(sum(req.prompt_len + len(req.generated) - 1
-                            for _, req in active))
-        return dispatch(active)
+        # prompt and all it generated — the token still in flight counts,
+        # the step is launched one ahead — but the token this step feeds in
+        attended.append(sum(req.prompt_len + len(req.generated)
+                            + int(ahead[slot]) - 1 for slot, req in active))
+        return dispatch(active, offs, ahead)
 
     engine._dispatch_decode = recording
     opts = jax.profiler.ProfileOptions()
@@ -262,7 +263,8 @@ def test_engine_steps_are_nested_phase_spans_in_a_profile(tiny_paged,
         inner = [s for s in inner if s[0] != "fetch"
                  or (collect[1] <= s[1] and s[2] <= collect[2])]
         order = [s[0] for s in inner if s[0] != "prefill_chunk"]
-        assert order == ["admit", "collect", "fetch", "dispatch", "finish"]
+        # the next step is launched BEFORE the one in flight is collected
+        assert order == ["admit", "dispatch", "collect", "fetch", "finish"]
         by = {s[0]: s for s in inner}
         assert by["collect"][1] <= by["fetch"][1] \
             and by["fetch"][2] <= by["collect"][2]
@@ -272,7 +274,7 @@ def test_engine_steps_are_nested_phase_spans_in_a_profile(tiny_paged,
         for s in inner:
             if s[0] == "prefill_chunk":
                 chunks += 1
-                assert by["admit"][2] <= s[1] and s[2] <= by["collect"][1]
+                assert by["admit"][2] <= s[1] and s[2] <= by["dispatch"][1]
                 assert int(s[3]["request_id"]) == 3
                 assert int(s[3]["width"]) == 4
                 # the chunk's last row attends the prompt up to its end

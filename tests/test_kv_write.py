@@ -124,9 +124,9 @@ def test_engine_counts_rows_and_pages_from_the_host_offsets():
     dispatched = []
     dispatch = engine._dispatch_decode
 
-    def spy(active):
-        dispatched.append([int(engine._offsets[s]) for s, _ in active])
-        return dispatch(active)
+    def spy(active, offs, ahead):
+        dispatched.append([int(offs[s]) for s, _ in active])
+        return dispatch(active, offs, ahead)
 
     engine._dispatch_decode = spy
     engine.submit(Request(request_id=0, prompt_ids=[3, 4, 5, 6, 7, 8],
@@ -142,7 +142,7 @@ def test_engine_counts_rows_and_pages_from_the_host_offsets():
     # 7..9 (two pages), slot 1 cells 14..15 of 16 (its third row is past T)
     engine._spec_k = 2
     engine._offsets[:] = (7, 14)
-    engine._count_decode_write([(0, None), (1, None)])
+    engine._count_decode_write([(0, None), (1, None)], engine._offsets)
     snap = engine.registry.snapshot()
     assert snap["serving/kv_rows_written_total"] == 6 + rows + 5
     assert snap["serving/kv_pages_touched_total"] == 2 + rows + 3

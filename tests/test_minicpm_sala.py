@@ -578,6 +578,41 @@ def test_a_preempted_request_is_recomputed_from_its_prompt(
     assert eng._kv.alloc.in_use == 0
 
 
+def test_an_overrun_leaves_the_next_occupants_state_row_alone(
+        pool_model, prompts, solo_tokens):
+    """The decode loop runs one step ahead: a request that a stop TOKEN ends
+    has a row in the step already queued, which steps its lightning state
+    row once more and writes one more K/V cell.  Five requests over three
+    slots, two of them stopped by a token, through that loop and through
+    the old order (fetch, then launch) step for step: every live slot's
+    state rows and valid cells — the next occupant of a released slot
+    begins at position 0, from zeros — and every output are the same bit
+    for bit."""
+    from conftest import lockstep_with_the_old_order
+
+    at = {}
+    for i in (0, 2):
+        at[i] = next(k for k in range(2, 5)
+                     if solo_tokens[i][k] not in solo_tokens[i][:k])
+
+    def requests():
+        return [Request(request_id=i, prompt_ids=p, max_new_tokens=6,
+                        stop_token_ids=((solo_tokens[i][at[i]],)
+                                        if i in at else ()))
+                for i, p in enumerate(prompts)]
+
+    ahead, old, got = lockstep_with_the_old_order(
+        lambda: engine_for(pool_model), requests)
+    for i, want in enumerate(solo_tokens):
+        assert got[i][2] == (want[:at[i] + 1] if i in at else want), i
+        assert got[i][1] == ("stop_token" if i in at else "length")
+    snap = ahead.registry.snapshot()
+    assert snap["serving/decode_overrun_rows_total"] == len(at)
+    assert snap["serving/decode_runahead_total"] > 0
+    assert old.registry.snapshot()["serving/decode_runahead_total"] == 0
+    assert ahead._kv.state_rows == [None] * B
+
+
 def test_state_row_invariants_are_asserted(pool_model, prompts):
     eng = engine_for(pool_model)
     eng.submit(Request(request_id=0, prompt_ids=prompts[0], max_new_tokens=4))

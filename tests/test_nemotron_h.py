@@ -584,6 +584,49 @@ def test_slots_are_released_and_readmitted_with_their_state_rows(pool_model):
     engine.close()
 
 
+def test_an_overrun_leaves_the_next_occupants_scan_state_alone(pool_model):
+    """The decode loop runs one step ahead: a request that a stop TOKEN ends
+    has a row in the step already queued, which steps its Mamba-2 scan
+    state and convolution taps once more.  Six requests over three slots,
+    two stopped by a token, through that loop and through the old order
+    (fetch, then launch), step for step: every live slot's state rows and
+    valid cells — the released slot's next occupant begins from zeros — and
+    every output are the same bit for bit."""
+    from conftest import lockstep_with_the_old_order
+
+    _, _, model = pool_model
+    rs = np.random.RandomState(9)
+    prompts = [rs.randint(1, 128, size=n).tolist()
+               for n in (6, 19, 11, 27, 4, 15)]
+
+    def engine():
+        return ServingEngine(model, page_size=PAGE, num_pages=60,
+                             prefill_chunk_tokens=W)
+
+    def alone(p):
+        eng = engine()
+        eng.submit(Request(request_id=0, prompt_ids=p, max_new_tokens=6))
+        return tuple(eng.run_until_complete(max_steps=500)[0].token_ids)
+
+    solo = [alone(p) for p in prompts]
+    at = {}
+    for i in (0, 1):
+        at[i] = next(k for k in range(1, 5) if solo[i][k] not in solo[i][:k])
+
+    def requests():
+        return [Request(request_id=i, prompt_ids=p, max_new_tokens=6,
+                        stop_token_ids=((solo[i][at[i]],) if i in at else ()))
+                for i, p in enumerate(prompts)]
+
+    ahead, old, got = lockstep_with_the_old_order(engine, requests)
+    for i, want in enumerate(solo):
+        assert got[i][2] == (want[:at[i] + 1] if i in at else want), i
+    snap = ahead.registry.snapshot()
+    assert snap["serving/decode_overrun_rows_total"] == len(at)
+    assert snap["serving/decode_runahead_total"] > 0
+    assert ahead._kv.state_rows == [None] * B
+
+
 @pytest.mark.parametrize("what", ["spec_k", "kv_quant", "adapter_store"])
 def test_what_is_not_carried_through_raises(pool_model, what):
     _, _, model = pool_model
