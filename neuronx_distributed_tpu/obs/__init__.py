@@ -14,7 +14,10 @@ This package correlates them:
 - :mod:`.flight` — a ring buffer of the last K step records (loss,
   grad-norm, host/device/data-wait step-time breakdown) dumped to
   ``flight_record.json`` on crash/SIGTERM, with built-in anomaly detectors
-  (NaN/Inf loss, loss-spike z-score, throughput regression);
+  (NaN/Inf loss, loss-spike z-score, throughput regression); and
+  ``StepAccount``, a stepping loop's own books (host time by phase, CPU
+  against off-CPU time, the stall rule), one flat record a step into the
+  same ring;
 - :mod:`.hlo_audit` — compile-time collective-op counts and byte volumes
   walked out of a compiled program's HLO (the reusable form of the
   assertions in ``tests/test_hlo_collectives.py``), one audit record per
@@ -56,6 +59,7 @@ from neuronx_distributed_tpu.obs.flight import (
     FlightRecorder,
     LossSpikeDetector,
     NanLossDetector,
+    StepAccount,
     ThroughputRegressionDetector,
     default_detectors,
 )
@@ -341,6 +345,7 @@ __all__ = [
     "Gauge",
     "Histogram",
     "FlightRecorder",
+    "StepAccount",
     "AnomalyDetector",
     "NanLossDetector",
     "LossSpikeDetector",

@@ -27,7 +27,9 @@ SCHEMAS: Dict[str, Dict[str, Any]] = {
         "schema": str, "reason": str, "dumped_at": _NUM, "capacity": int,
         "steps_recorded": int, "records": list, "warnings": list,
     },
-    # one entry of flight_record.json["records"]
+    # one entry of flight_record.json["records"]: a training step's fields,
+    # or a serve step's account (obs.flight.step_record_type: wall_ms,
+    # cpu_ms, blocked_ms, off_cpu_ms, between_ms, <phase>_ms, ...)
     "flight_step": {"step": int, "time": _NUM},
     # one entry of flight_record.json["warnings"] (anomaly detectors)
     "anomaly": {"step": int, "detector": str, "message": str, "time": _NUM},

@@ -455,6 +455,9 @@ def device_trace(log_dir: str):
         jax.profiler.stop_trace()
 
 
+_TRACE_ANNOTATION = None    # jax.profiler.TraceAnnotation, resolved once
+
+
 def phase(name: str, **attrs):
     """A host span ``nxd/<name>`` in the JAX profiler's own trace, on the
     clock its device planes use, so a reduction of the trace can set a
@@ -462,6 +465,9 @@ def phase(name: str, **attrs):
     ``jax.profiler.TraceAnnotation``: a flag test when no profile is being
     taken, so call sites are unconditional and nothing turns it on.  Not a
     :class:`Tracer` span — those are per request, on ``time.monotonic``."""
-    from jax.profiler import TraceAnnotation
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
 
-    return TraceAnnotation("nxd/" + name, **attrs)
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION("nxd/" + name, **attrs)

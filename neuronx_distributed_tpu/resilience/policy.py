@@ -117,13 +117,12 @@ class StepWatchdog:
         self._det = ThroughputRegressionDetector(
             window=window, factor=factor, min_history=min_history,
             min_excess_s=min_excess_s)
-        self._history: Deque[dict] = deque(maxlen=window)
         self.strikes = 0
 
     def check(self, step: int, step_time_s: float) -> Optional[str]:
-        rec = {"step": step, "step_time_s": step_time_s}
-        msg = self._det.check(rec, self._history)
-        self._history.append(rec)
+        # the detector keeps its own trailing window
+        msg = self._det.check({"step": step, "step_time_s": step_time_s},
+                              None)
         if msg:
             self.strikes += 1
         return msg

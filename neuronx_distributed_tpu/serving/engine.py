@@ -80,6 +80,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from neuronx_distributed_tpu.obs import MS_BUCKETS, MetricRegistry
+from neuronx_distributed_tpu.obs.flight import FlightRecorder, StepAccount
 from neuronx_distributed_tpu.obs.tracing import phase
 from neuronx_distributed_tpu.obs.transfer_audit import TransferAudit
 from neuronx_distributed_tpu.resilience.faults import fault_point, perturb
@@ -177,6 +178,16 @@ class _InFlight:
 
 #: the sampler's paths, in the order of ``_sample_rows``'s ``lax.switch``
 SAMPLER_PATHS = ("greedy", "temperature", "filtered")
+# the phases of a step, in the one place: each is a span ``nxd/serve/<name>``
+# of the profiler's trace and a column of the step's record, both through
+# ``ServingEngine._phase``.  ``step`` is the whole step (in the record: what
+# no other phase owns); ``fetch`` is the blocking device->host read, inside
+# ``collect`` or ``first_token``; ``first_token`` is a prompt's first-token
+# tail (sample, blocking read, hand-over to decode) after its last chunk or
+# inside ``admit`` on an exact prefix hit; ``tail`` is what follows
+# ``finish`` (gauges, watchdog, health, ledger poll) and launches nothing
+SERVE_PHASES = ("step", "admit", "prefill_chunk", "first_token", "dispatch",
+                "collect", "fetch", "finish", "tail")
 
 
 def _sampler_path(temperature, top_k, top_p):
@@ -423,10 +434,13 @@ class ServingEngine:
       slower than the threshold logs a warning and counts into
       ``serving/slow_steps_total`` (every step's duration exports as the
       ``serving/step_ms`` histogram and ``serving/last_step_ms`` gauge);
-    - ``obs`` (an ``obs.Observability`` hub) records one flight-recorder
-      entry per engine step (queue depth, active slots, tokens, step time);
-      ``replay_trace`` dumps it on an unhandled exception, and the engine's
-      metrics then ride the hub's registry unless one was passed explicitly.
+    - every step keeps its own account (``obs.flight.StepAccount``: host
+      time by phase, CPU time, time blocked in a fetch, time between steps;
+      the stall rule; ``SERVE_PHASES`` names the phases) and leaves one flat
+      record in a flight ring, hub or no hub; with ``obs`` (an
+      ``obs.Observability`` hub) that ring is the hub's, ``replay_trace``
+      dumps it on an unhandled exception, and the engine's metrics then
+      ride the hub's registry unless one was passed explicitly.
 
     The decode loop runs one step ahead (see the module docstring): step
     N+1 is dispatched, from step N's tokens on the device, before step N is
@@ -691,10 +705,15 @@ class ServingEngine:
         # below guards on `is not None`.
         self.compile_ledger = compile_ledger
         self.memory_ledger = memory_ledger
+        # the step's own account (obs.flight): ONE ring of flat step
+        # records, the hub's where a hub is given
+        self._flight = (obs.flight if obs is not None
+                        else FlightRecorder(registry=self.registry))
+        self._account = StepAccount(SERVE_PHASES, self._flight,
+                                    self.registry, "serving")
         if compile_ledger is not None:
             compile_ledger.attach(registry=self.registry, tracer=tracer,
-                                  flight=(getattr(obs, "flight", None)
-                                          if obs is not None else None),
+                                  flight=self._flight,
                                   memory_ledger=memory_ledger)
             model.compile_ledger = compile_ledger
             if draft is not None:
@@ -806,6 +825,7 @@ class ServingEngine:
             shed_infeasible=shed_infeasible, tracer=tracer)
         self.step_timeout_s = step_timeout_s
         self._steps = 0
+        self._t_step0 = 0.0   # the step's start on the engine's clock
         if transfer_guard not in ("off", "forbid"):
             raise ValueError(
                 f"transfer_guard must be 'off' or 'forbid', "
@@ -1135,7 +1155,9 @@ class ServingEngine:
         """Everything this engine will run is compiled now: any compile the
         ledger sees from here on is a ``compile_storm`` (counted, flight-
         warned, traced).  Benches call this between their warm pass and the
-        measured pass; no-op without a compile ledger."""
+        measured pass.  The step account starts over too (totals, longest
+        step, trailing median): a warm-up's compiles are not stalls."""
+        self._account.reset()
         if self.compile_ledger is not None:
             self.compile_ledger.declare_warmup_done("engine")
         if self._perf is not None:
@@ -1210,32 +1232,46 @@ class ServingEngine:
         With a memory ledger attached, a RESOURCE_EXHAUSTED escaping the
         step dumps ``memory_breakdown.json`` naming the biggest holders
         before re-raising; with a compile ledger attached, the shared
-        sampler jits' cache sizes are polled after the step.  Ledgers-off
-        is two attribute reads."""
+        sampler jits' cache sizes are polled at the step's end (in its
+        ``tail``).  Every step is bracketed by its own account
+        (``obs.flight.StepAccount``: one flat record, the stall rule)."""
         # the loop's phases are spans of the profiler's own trace
-        # (obs.tracing.phase): a flag test each when no profile is taken
-        with phase("serve/step", step=self._steps + 1,
-                   active=self.scheduler.active_count,
-                   queued=self.scheduler.queue_depth):
-            if self.compile_ledger is None and self.memory_ledger is None:
-                return self._step_impl()
-            try:
-                out = self._step_impl()
-            except Exception as e:
-                if self.memory_ledger is not None:
-                    self.memory_ledger.oom_dump(e)
-                raise
-            if self.compile_ledger is not None:
-                self._poll_module_jits(self.compile_ledger)
-            return out
+        # (obs.tracing.phase: a flag test each when no profile is taken)
+        # and entries of the step's account, through the one ``_phase``
+        account = self._account
+        account.begin(self._steps + 1)
+        outputs: List[RequestOutput] = []
+        try:
+            with self._phase("step", step=self._steps + 1,
+                             active=self.scheduler.active_count,
+                             queued=self.scheduler.queue_depth):
+                try:
+                    outputs = self._step_impl()
+                except Exception as e:
+                    if self.memory_ledger is not None:
+                        self.memory_ledger.oom_dump(e)
+                    raise
+                with self._phase("tail"):
+                    self._step_tail()
+        finally:
+            account.end(self.scheduler.queue_depth,
+                        self.scheduler.active_count, len(outputs),
+                        self.has_work)
+        return outputs
+
+    def _phase(self, name: str, **attrs):
+        """One phase of the step (a name of ``SERVE_PHASES``): the span
+        ``nxd/serve/<name>`` in the profiler's trace and the phase's wall
+        time in the step's account, at the same boundary."""
+        return self._account.span(name, phase("serve/" + name, **attrs))
 
     def _step_impl(self) -> List[RequestOutput]:
         outputs: List[RequestOutput] = []
         now = self._clock()
-        t_step0 = now
+        self._t_step0 = now
         self._steps += 1
 
-        with phase("serve/admit") as span:
+        with self._phase("admit") as span:
             # 1) cancellation / deadline sweep (frees slots before admission)
             swept = self.scheduler.sweep(now)
             if swept:
@@ -1264,6 +1300,7 @@ class ServingEngine:
                 self._prefill_into_slot(slot, req, outputs)
                 granted += 1
             span.set_metadata(granted=granted)
+            self._account.granted = granted
 
         # 3b) prefill: advance the PREFILLING slots by the step's budget,
         # one chunk program (Sarathi-style — decodes below keep ticking
@@ -1280,18 +1317,25 @@ class ServingEngine:
         # which only the fetch gives
         with self._audit.section("serving/decode"):
             if self._spec_k:
-                with phase("serve/collect"):
+                with self._phase("collect"):
                     post = self._spec_collect()
                 self._launch_decode()
             else:
                 launched = self._launch_decode()
-                with phase("serve/collect"):
+                with self._phase("collect"):
                     post = self._collect_decode(keep_newest=launched)
-        with phase("serve/finish", tokens=sum(
+        with self._phase("finish", tokens=sum(
                 len(p[3]) if p[0] == "tokens" else p[0] == "token"
                 for p in post)):
             self._finish_decode(post, outputs)
+        return outputs
 
+    def _step_tail(self) -> None:
+        """What a step does once its tokens are out (the span ``tail``; it
+        launches nothing): the gauges — the pool's walk its free and
+        evictable pages — the watchdog on the engine's clock, the perf
+        rollup, the health rules, and the compile ledger's poll of the
+        shared sampler jits."""
         self.registry.gauge("serving/queue_depth").set(self.scheduler.queue_depth)
         self.registry.gauge("serving/slots_active").set(self.scheduler.active_count)
         self._kv.export_gauges()
@@ -1301,7 +1345,7 @@ class ServingEngine:
         # step watchdog: a slow engine step is the host-side signature of a
         # recompile, a device stall, or a wedged model call — the gauge/
         # histogram make it graphable, the counter makes it alertable
-        step_s = self._clock() - t_step0
+        step_s = self._clock() - self._t_step0
         self.registry.gauge("serving/last_step_ms").set(step_s * 1e3)
         self.registry.histogram("serving/step_ms", MS_BUCKETS).observe(
             step_s * 1e3)
@@ -1312,12 +1356,6 @@ class ServingEngine:
                 "active=%d queued=%d)", self._steps, step_s,
                 self.step_timeout_s, self.scheduler.active_count,
                 self.scheduler.queue_depth)
-        if self.obs is not None:
-            self.obs.flight.record(
-                self._steps, step_time_s=step_s,
-                queue_depth=self.scheduler.queue_depth,
-                slots_active=self.scheduler.active_count,
-                terminal=len(outputs))
         if self._perf is not None:
             # refresh the perf/* rollup gauges on the step cadence so the
             # health TrendRules (mfu_sag / roofline_drift) see live values
@@ -1326,7 +1364,8 @@ class ServingEngine:
             # rule evaluation rides the engine clock (alert edges share
             # the spans'/stats' timescale under a fake-clock harness)
             self._health.on_step(now=self._clock())
-        return outputs
+        if self.compile_ledger is not None:
+            self._poll_module_jits(self.compile_ledger)
 
     def _row_live(self, slot: int, req: Request, gen: int) -> bool:
         """Whether a row of an in-flight program is still its request's:
@@ -1380,9 +1419,11 @@ class ServingEngine:
         self._count_decode_write(active, offs)
         lens = np.asarray([int(offs[slot]) - self.C + req.prompt_len + 1
                            for slot, req in active])
-        with phase("serve/dispatch", active=len(active),
-                   ctx_tokens=int(lens.sum()) - len(active),
-                   **self._count_selection("decode_pages", lens - 1, lens)):
+        self._account.rows = len(active)
+        with self._phase("dispatch", active=len(active),
+                         ctx_tokens=int(lens.sum()) - len(active),
+                         **self._count_selection("decode_pages", lens - 1,
+                                                 lens)):
             if self._spec_k:
                 self._spec_dispatch(active)
             else:
@@ -1430,11 +1471,11 @@ class ServingEngine:
             self._open_batch_span(self._inflight[0], now)
 
     def dump_flight(self, reason: str) -> Optional[str]:
-        """Persist the per-engine-step flight ring (when an ``obs`` hub is
-        attached); the serving crash-evidence path used by ``replay_trace``."""
-        if self.obs is not None:
-            return self.obs.dump_flight(reason)
-        return None
+        """Persist the ring of step records (``flight_step`` documents at
+        this moment, flat until then) where it has a file — an ``obs`` hub's
+        ``flight_record.json``; the serving crash-evidence path used by
+        ``replay_trace``."""
+        return self._flight.dump(reason)
 
     def run_until_complete(self, max_steps: Optional[int] = None) -> List[RequestOutput]:
         """Drive ``step()`` until queue and slots drain; returns every
@@ -1573,8 +1614,9 @@ class ServingEngine:
             # last-position logits (keys are adapter-salted, so both were
             # computed under this same adapter)
             self._trace_phase_attrs(req, prefix_hit=True)
-            self._finish_prefill(slot, req, jnp.asarray(cached), outputs,
-                                 prefilled_fresh=False)
+            with self._phase("first_token", request_id=req.request_id):
+                self._finish_prefill(slot, req, jnp.asarray(cached), outputs,
+                                     prefilled_fresh=False)
             return
         # EVERY fresh prompt rides the chunk loop: the block table is
         # assembled and the fresh pages reserved; the compute is the loop's,
@@ -1810,14 +1852,15 @@ class ServingEngine:
                 # ctx_tokens: the keys the chunk's last row attends — its
                 # end in the left-padded row less the pad
                 ctx = off + n_pages * page - (self.C - req.prompt_len)
-                with phase("serve/prefill_chunk", request_id=req.request_id,
-                           tok_start=off, width=n_pages * page,
-                           ctx_tokens=ctx,
-                           **self._count_selection(
-                               "prefill_chunk_pages",
-                               np.arange(max(off - (self.C - req.prompt_len),
-                                             0), ctx),
-                               req.prompt_len)):
+                self._account.chunk = n_pages * page
+                with self._phase(
+                        "prefill_chunk", request_id=req.request_id,
+                        tok_start=off, width=n_pages * page, ctx_tokens=ctx,
+                        **self._count_selection(
+                            "prefill_chunk_pages",
+                            np.arange(max(off - (self.C - req.prompt_len),
+                                          0), ctx),
+                            req.prompt_len)):
                     self._dispatch_chunk(slot, st, n_pages)
             except BaseException as e:
                 # transactional like the admission path: the one request
@@ -1836,8 +1879,9 @@ class ServingEngine:
                 raise
             if st.pages_remaining == 0:
                 self._chunking.pop(slot, None)
-                self._finish_prefill(slot, req, st.logits, outputs,
-                                     prefilled_fresh=True)
+                with self._phase("first_token", request_id=req.request_id):
+                    self._finish_prefill(slot, req, st.logits, outputs,
+                                         prefilled_fresh=True)
             break  # the step's budget is one chunk program
 
     def _dispatch_chunk(self, slot: int, st: _ChunkPrefill,
@@ -2063,7 +2107,8 @@ class ServingEngine:
         launched AFTER the one whose tokens are read — this step's prefill
         chunk — for the next fetch, which would otherwise wait for them."""
         programs, loads = self._take_moe_loads(upto)
-        with phase("serve/fetch"):
+        self._account.fetches += 1
+        with self._phase("fetch"):
             if not loads:
                 return self._audit.fetch(packed_dev, label="serving")
             packed, loads = self._audit.fetch((packed_dev, loads),

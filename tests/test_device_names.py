@@ -251,23 +251,36 @@ def test_engine_steps_are_nested_phase_spans_in_a_profile(tiny_paged,
     spans = _host_spans(str(tmp_path), "nxd/serve/")
     steps = [s for s in spans if s[0] == "step"]
     assert [int(s[3]["step"]) for s in steps] == list(range(first, first + 4))
+    # what a step emits is SERVE_PHASES and nothing else
+    assert {s[0] for s in spans} == set(engine_mod.SERVE_PHASES)
     ctx = []
-    chunks = 0
+    chunks = first_tokens = 0
     for _, lo, hi, attrs in steps:
         assert {"active", "queued"} <= set(attrs)
         inner = [s for s in spans if s[0] != "step" and lo <= s[1]
                  and s[2] <= hi]
         collect = next(s for s in inner if s[0] == "collect")
-        # a prompt's last chunk ends in a first-token fetch of its own,
-        # before the collect: not the one under test
-        inner = [s for s in inner if s[0] != "fetch"
-                 or (collect[1] <= s[1] and s[2] <= collect[2])]
+        # a prompt's last chunk ends in a first-token tail of its own (span
+        # ``first_token``, its blocking fetch inside it), before the
+        # dispatch: not the collect's fetch
+        mine = [s for s in inner if s[0] == "first_token"]
+        for ft in mine:
+            first_tokens += 1
+            assert int(ft[3]["request_id"]) == 3
+            (fetch,) = [s for s in inner if s[0] == "fetch"
+                        and ft[1] <= s[1] and s[2] <= ft[2]]
+        inner = [s for s in inner if s[0] != "first_token"
+                 and (s[0] != "fetch"
+                      or (collect[1] <= s[1] and s[2] <= collect[2]))]
         order = [s[0] for s in inner if s[0] != "prefill_chunk"]
-        # the next step is launched BEFORE the one in flight is collected
-        assert order == ["admit", "dispatch", "collect", "fetch", "finish"]
+        # the next step is launched BEFORE the one in flight is collected;
+        # what follows ``finish`` has a span of its own, and ends the step
+        assert order == ["admit", "dispatch", "collect", "fetch", "finish",
+                         "tail"]
         by = {s[0]: s for s in inner}
         assert by["collect"][1] <= by["fetch"][1] \
             and by["fetch"][2] <= by["collect"][2]
+        assert by["finish"][2] <= by["tail"][1] and by["tail"][2] <= hi
         assert "granted" in by["admit"][3] and "tokens" in by["finish"][3]
         assert int(by["dispatch"][3]["active"]) >= 2
         ctx.append(int(by["dispatch"][3]["ctx_tokens"]))
@@ -279,6 +292,9 @@ def test_engine_steps_are_nested_phase_spans_in_a_profile(tiny_paged,
                 assert int(s[3]["width"]) == 4
                 # the chunk's last row attends the prompt up to its end
                 assert int(s[3]["ctx_tokens"]) == 4 * chunks
+                for ft in mine:     # the first-token tail follows the chunk
+                    assert s[2] <= ft[1] and ft[2] <= by["dispatch"][1]
+    assert first_tokens == 1
     assert chunks == 2
     assert ctx == attended and len(ctx) == 4
 
