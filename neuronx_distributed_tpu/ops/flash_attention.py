@@ -12,8 +12,20 @@ grouped queries read their kv head via ``h // G`` in the BlockSpec index map,
 so GQA costs no extra memory traffic.  Forward emits the per-row logsumexp;
 backward follows the standard two-kernel split (dq by q-block, dk/dv by
 kv-block) with the ``delta = rowsum(dO * O)`` trick so neither direction ever
-materializes probabilities in HBM.  Causal blocks strictly above the diagonal
-are skipped via ``pl.when`` (no wasted MXU work on the masked half).
+materializes probabilities in HBM.
+
+The grids walk the BAND, not the square (:func:`band_blocks`): under a causal
+(+ sliding-window) mask the inner axis of ``flash_fwd`` / ``flash_dq`` (over
+kv blocks) and of ``flash_dkv`` (over q blocks) is as wide as the widest row
+of blocks the mask leaves visible, and every operand's index map follows the
+band.  A row shorter than that waits on its first block — steps whose body
+does not run (``pl.when``) and for which the pipeline fetches nothing — and
+ends, like every row, on a live step.  At sequence 8192 under a window of
+4096 in blocks of 512 x 512 each kernel runs 108 live block pairs a (batch,
+head) in 144 grid steps (36 dead, none fetching) where the square had 256
+(148 dead, all fetching); causal without a window keeps 256 steps (136 live)
+but its 120 dead ones no longer fetch; a non-causal call has the grid it
+always had.
 
 Row statistics (m, l, lse, delta) are carried as ``[block, 128]``
 lane-replicated tiles — TPU VMEM wants a 128 minor dim.
@@ -22,10 +34,11 @@ lane-replicated tiles — TPU VMEM wants a 128 minor dim.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -109,6 +122,90 @@ def band_mask(q_len: int, kv_len: int, q_offset=0,
     return mask
 
 
+class Band(NamedTuple):
+    """The inner axis of one flash grid (:func:`band_blocks`)."""
+
+    width: int    # steps of the inner grid axis
+    live: int     # block pairs a (batch, head) whose body runs
+    stepped: int  # grid steps a (batch, head): outer blocks x ``width``
+    by_kv: bool   # outer axis over kv blocks (``flash_dkv``), else q blocks
+    # outer block index -> (first, last) inner block it reaches; None: all
+    reach: Optional[Callable]
+
+    def step(self, outer, j, xp=jnp):
+        """``(inner block, not visited before)`` of inner grid step ``j``.
+        A row ENDS on the axis' last step: a row shorter than ``width``
+        stands on its first block until its turn comes, so every row's last
+        step is a live one, long enough to hide the fetch of the next row's
+        first blocks."""
+        if self.reach is None:
+            return j, True
+        first, last = self.reach(outer, xp)
+        at = last - (self.width - 1) + j
+        return xp.maximum(at, first), at >= first
+
+
+def band_blocks(S: int, T: int, bq: int, bk: int, causal: bool,
+                window: Optional[int], by_kv: bool = False) -> Band:
+    """Which blocks a flash grid steps over.  ``flash_fwd`` and ``flash_dq``
+    run an outer axis over the ``S // bq`` q blocks and an inner one over kv
+    blocks; ``flash_dkv`` (``by_kv``) an outer axis over the ``T // bk`` kv
+    blocks and an inner one over q blocks.  Under the causal (+ window)
+    :func:`band_mask` an outer block reaches only the inner blocks
+    ``first .. last`` (``reach``, the mask's inequalities taken blockwise:
+    plain integer arithmetic, so it serves python ints, numpy arrays and the
+    traced indices of an index map alike), so the inner axis is as wide as the
+    widest such row, not as the sequence.  A row shorter than ``width``
+    stands on its first block before its turn (:meth:`Band.step`): the
+    pipeline fetches nothing for an index that does not change, and the
+    kernel runs no body there.  A row with no visible key at all (``T < S``)
+    stands on one clipped block whose body does not run.  Non-causal calls
+    reach everything: the grid they had."""
+    n_outer, n_inner = (T // bk, S // bq) if by_kv else (S // bq, T // bk)
+    off = T - S  # q positions sit at the end of the kv timeline
+
+    if not causal:
+        return Band(n_inner, n_outer * n_inner, n_outer * n_inner, by_kv, None)
+
+    def div(x, d):
+        # floor division; a shift where it can be one (the index maps run on
+        # the scalar core every grid step: 0.1-0.4 ms a call on the chip)
+        return x >> (d.bit_length() - 1) if d & (d - 1) == 0 else x // d
+
+    def reach(outer, xp=jnp):
+        if by_kv:   # q blocks from the diagonal down to the window's far edge
+            first = div(outer * bk - off, bq)
+            last = (n_inner - 1 if window is None
+                    else div((outer + 1) * bk + window - 2 - off, bq))
+        else:       # kv blocks from the window's far edge up to the diagonal
+            first = (0 if window is None
+                     else div(outer * bq + off - window + 1, bk))
+            last = div(outer * bq + off + bq - 1, bk)
+        first = xp.clip(first, 0, n_inner - 1)
+        return first, xp.clip(last, first, n_inner - 1)
+
+    first, last = reach(np.arange(n_outer), np)
+    width = int(np.max(last - first)) + 1
+    # the body runs where the blockwise inequalities hold (an empty row's one
+    # clipped block fails them)
+    outer, inner = np.meshgrid(np.arange(n_outer), np.arange(n_inner),
+                               indexing="ij")
+    qi, ki = (inner, outer) if by_kv else (outer, inner)
+    live = int(np.sum(_block_visible(qi, ki, bq, bk, off, window)))
+    return Band(width, live, n_outer * width, by_kv, reach)
+
+
+def _block_visible(qi, ki, bq, bk, off, window):
+    """Does q block ``qi`` see any key of kv block ``ki`` under the causal
+    (+ window) mask: the block's first key is not after its last query, and
+    (window) its last key not before the first query's lowest visible one."""
+    first_q = qi * bq + off
+    visible = ki * bk <= first_q + bq - 1
+    if window is not None:
+        visible = visible & ((ki + 1) * bk - 1 >= first_q - (window - 1))
+    return visible
+
+
 def mha_reference(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
     sm_scale: Optional[float] = None, window: Optional[int] = None,
@@ -161,29 +258,53 @@ def _segment_mask(qseg_ref, kseg_ref, block_q, block_k):
     return jnp.logical_and(qs == ks, qs > 0)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, sm_scale, causal, block_q, block_k, num_kv_blocks, kv_offset,
-                qseg_ref=None, kseg_ref=None, window=None, softcap=None):
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
+def _band_step(band, *, causal, block_q, block_k, kv_offset, window):
+    """Where this grid step stands: ``(qi, ki, j, run)``.  The body runs on
+    a block not visited before that the mask does not hide whole."""
+    outer, j = pl.program_id(2), pl.program_id(3)
+    inner, run = band.step(outer, j)
+    qi, ki = (inner, outer) if band.by_kv else (outer, inner)
+    if causal:
+        run = jnp.logical_and(run, _block_visible(
+            qi, ki, block_q, block_k, kv_offset, window))
+    return qi, ki, j, run
 
-    @pl.when(ki == 0)
+
+def _band_specs(band, bq, bk, D, G):
+    """``(q_like, kv_like, row_stat, q_seg, kv_seg)`` BlockSpecs of one grid
+    ``(b, h, outer, j)``: every operand's block follows the band, through
+    the arithmetic the kernel takes its own position from."""
+    def qi(outer, j):
+        return band.step(outer, j)[0] if band.by_kv else outer
+
+    def ki(outer, j):
+        return outer if band.by_kv else band.step(outer, j)[0]
+
+    return (
+        pl.BlockSpec((1, 1, bq, D), lambda b, h, o, j: (b, h, qi(o, j), 0)),
+        pl.BlockSpec((1, 1, bk, D), lambda b, h, o, j: (b, h // G, ki(o, j), 0)),
+        pl.BlockSpec((1, 1, bq, LANES), lambda b, h, o, j: (b, h, qi(o, j), 0)),
+        pl.BlockSpec((1, bq, LANES), lambda b, h, o, j: (b, qi(o, j), 0)),
+        pl.BlockSpec((1, _SUBLANES, bk), lambda b, h, o, j: (b, 0, ki(o, j))),
+    )
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+                *, sm_scale, causal, block_q, block_k, band, kv_offset,
+                qseg_ref=None, kseg_ref=None, window=None, softcap=None):
+    # causal: the inner axis walks only the kv blocks this q block reaches
+    # (neither those entirely above the diagonal nor, with a sliding window,
+    # those entirely left of the band: band_blocks)
+    qi, ki, j, run = _band_step(band, causal=causal, block_q=block_q,
+                                block_k=block_k, kv_offset=kv_offset,
+                                window=window)
+    first_q = qi * block_q + kv_offset  # q positions offset into kv timeline
+
+    @pl.when(j == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    # causal: skip blocks entirely above the diagonal (kv start > last q pos);
-    # with a sliding window also those entirely left of the band (kv end <
-    # the first q row's lowest visible key)
-    first_q = qi * block_q + kv_offset  # q positions offset into kv timeline
-    run = jnp.logical_or(
-        not causal, ki * block_k <= first_q + block_q - 1
-    )
-    if window is not None:
-        run = jnp.logical_and(
-            run, (ki + 1) * block_k - 1 >= first_q - (window - 1)
-        )
 
     @pl.when(run)
     def _body():
@@ -223,7 +344,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(ki == num_kv_blocks - 1)
+    @pl.when(j == band.width - 1)
     def _finish():
         l = l_scr[:, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -234,16 +355,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 _SUBLANES = 8
 
 
-def _seg_operands(q_seg, kv_seg, B, S, T, bq, bk):
+def _seg_operands(q_seg, kv_seg, B, S, T):
     """Broadcast [B, S]/[B, T] ids into the TPU-tileable layouts (the
     jax.experimental.pallas flash kernel's convention): q ids lane-broadcast
     to [B, S, LANES] with (1, bq, LANES) blocks, kv ids sublane-broadcast to
     [B, 8, T] with (1, 8, bk) blocks."""
     qs = jax.lax.broadcast_in_dim(q_seg.astype(jnp.int32), (B, S, LANES), (0, 1))
     ks = jax.lax.broadcast_in_dim(kv_seg.astype(jnp.int32), (B, _SUBLANES, T), (0, 2))
-    qs_spec = pl.BlockSpec((1, bq, LANES), lambda b, h, qi, ki: (b, qi, 0))
-    ks_spec = pl.BlockSpec((1, _SUBLANES, bk), lambda b, h, qi, ki: (b, 0, ki))
-    return qs, ks, qs_spec, ks_spec
+    return qs, ks
 
 
 def _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
@@ -255,12 +374,12 @@ def _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     G = HQ // HKV
     bq, bk = _block_sizes(S, T, block_q, block_k)
     scale = (D ** -0.5) if sm_scale is None else sm_scale
-    nq, nk = S // bq, T // bk
     kv_offset = T - S  # q positions sit at the end of the kv timeline
     if window is not None and (not causal or window < 1):
         raise ValueError("window requires causal=True and window >= 1")
 
-    grid = (B, HQ, nq, nk)
+    band = band_blocks(S, T, bq, bk, causal, window)
+    grid = (B, HQ, S // bq, band.width)
     segmented = q_seg is not None
 
     def kernel(*refs):
@@ -271,7 +390,7 @@ def _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
             qs_r = ks_r = None
         _fwd_kernel(q_r, k_r, v_r, o_r, lse_r, m_s, l_s, a_s,
                     sm_scale=scale, causal=causal, block_q=bq, block_k=bk,
-                    num_kv_blocks=nk, kv_offset=kv_offset,
+                    band=band, kv_offset=kv_offset,
                     qseg_ref=qs_r, kseg_ref=ks_r, window=window, softcap=softcap)
 
     scratch = [
@@ -280,16 +399,12 @@ def _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         pltpu.VMEM((bq, LANES), jnp.float32),
         pltpu.VMEM((bq, D), jnp.float32),
     ]
-    in_specs = [
-        pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, bk, D), lambda b, h, qi, ki, G=G: (b, h // G, ki, 0)),
-        pl.BlockSpec((1, 1, bk, D), lambda b, h, qi, ki, G=G: (b, h // G, ki, 0)),
-    ]
+    q_like, kv_like, row_stat, qs_spec, ks_spec = _band_specs(band, bq, bk, D, G)
+    in_specs = [q_like, kv_like, kv_like]
     operands = [q, k, v]
     if segmented:
-        qs, ks, qs_spec, ks_spec = _seg_operands(q_seg, kv_seg, B, S, T, bq, bk)
         in_specs += [qs_spec, ks_spec]
-        operands += [qs, ks]
+        operands += _seg_operands(q_seg, kv_seg, B, S, T)
 
     def call(interp):
         return pl.pallas_call(
@@ -297,10 +412,7 @@ def _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
             grid=grid,
             compiler_params=_compiler_params(_GRID_SEMANTICS, interp),
             in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-                pl.BlockSpec((1, 1, bq, LANES), lambda b, h, qi, ki: (b, h, qi, 0)),
-            ],
+            out_specs=[q_like, row_stat],
             out_shape=[
                 jax.ShapeDtypeStruct((B, HQ, S, D), q.dtype),
                 jax.ShapeDtypeStruct((B, HQ, S, LANES), jnp.float32),
@@ -320,19 +432,16 @@ def _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_scr,
-               *, sm_scale, causal, block_q, block_k, num_kv_blocks, kv_offset,
+               *, sm_scale, causal, block_q, block_k, band, kv_offset,
                qseg_ref=None, kseg_ref=None, window=None, softcap=None):
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    qi, ki, j, run = _band_step(band, causal=causal, block_q=block_q,
+                                block_k=block_k, kv_offset=kv_offset,
+                                window=window)
+    first_q = qi * block_q + kv_offset
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    first_q = qi * block_q + kv_offset
-    run = jnp.logical_or(not causal, ki * block_k <= first_q + block_q - 1)
-    if window is not None:
-        run = jnp.logical_and(run, (ki + 1) * block_k - 1 >= first_q - (window - 1))
 
     @pl.when(run)
     def _body():
@@ -371,27 +480,25 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_scr,
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(ki == num_kv_blocks - 1)
+    @pl.when(j == band.width - 1)
     def _finish():
         dq_ref[0, 0] = acc_scr[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
                 dk_scr, dv_scr,
-                *, sm_scale, causal, block_q, block_k, num_q_blocks, kv_offset,
+                *, sm_scale, causal, block_q, block_k, band, kv_offset,
                 qseg_ref=None, kseg_ref=None, window=None, softcap=None):
-    ki = pl.program_id(2)
-    qi = pl.program_id(3)
+    # the mirror: the inner axis walks the q blocks this kv block is seen by
+    qi, ki, j, run = _band_step(band, causal=causal, block_q=block_q,
+                                block_k=block_k, kv_offset=kv_offset,
+                                window=window)
+    first_q = qi * block_q + kv_offset
 
-    @pl.when(qi == 0)
+    @pl.when(j == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
-
-    first_q = qi * block_q + kv_offset
-    run = jnp.logical_or(not causal, ki * block_k <= first_q + block_q - 1)
-    if window is not None:
-        run = jnp.logical_and(run, (ki + 1) * block_k - 1 >= first_q - (window - 1))
 
     @pl.when(run)
     def _body():
@@ -433,7 +540,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )  # ds^T @ q -> [bk, D]
 
-    @pl.when(qi == num_q_blocks - 1)
+    @pl.when(j == band.width - 1)
     def _finish():
         dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
@@ -449,16 +556,22 @@ def _bwd_impl(q, k, v, lse, do, delta_rows, causal, sm_scale, block_q, block_k, 
     G = HQ // HKV
     bq, bk = _block_sizes(S, T, block_q, block_k)
     scale = (D ** -0.5) if sm_scale is None else sm_scale
-    nq, nk = S // bq, T // bk
     kv_offset = T - S
     segmented = q_seg is not None
 
     delta = jnp.broadcast_to(delta_rows[..., None], (B, HQ, S, LANES))
-
+    operands = [q, k, v, do, lse, delta]
     if segmented:
-        # the returned specs' (b, h, qi, ki) index maps match the dq grid;
-        # the dkv kernel's transposed (b, h, ki, qi) grid declares its own
-        qs, ks, qs_spec, ks_spec = _seg_operands(q_seg, kv_seg, B, S, T, bq, bk)
+        operands += _seg_operands(q_seg, kv_seg, B, S, T)
+
+    def in_specs(band):
+        """The six (eight) operands' specs on ``band``, and a q-like one."""
+        q_like, kv_like, row_stat, qs_spec, ks_spec = _band_specs(band, bq, bk, D, G)
+        specs = [q_like, kv_like, kv_like, q_like, row_stat, row_stat]
+        return (specs + [qs_spec, ks_spec] if segmented else specs), q_like
+
+    dq_band = band_blocks(S, T, bq, bk, causal, window)
+    dq_in_specs, dq_out_spec = in_specs(dq_band)
 
     def dq_kernel(*refs):
         if segmented:
@@ -468,38 +581,27 @@ def _bwd_impl(q, k, v, lse, do, delta_rows, causal, sm_scale, block_q, block_k, 
             qs_r = ks_r = None
         _dq_kernel(q_r, k_r, v_r, do_r, lse_r, d_r, dq_r, a_s,
                    sm_scale=scale, causal=causal, block_q=bq, block_k=bk,
-                   num_kv_blocks=nk, kv_offset=kv_offset,
+                   band=dq_band, kv_offset=kv_offset,
                    qseg_ref=qs_r, kseg_ref=ks_r, window=window, softcap=softcap)
-
-    dq_in_specs = [
-        pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, bk, D), lambda b, h, qi, ki, G=G: (b, h // G, ki, 0)),
-        pl.BlockSpec((1, 1, bk, D), lambda b, h, qi, ki, G=G: (b, h // G, ki, 0)),
-        pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, bq, LANES), lambda b, h, qi, ki: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, bq, LANES), lambda b, h, qi, ki: (b, h, qi, 0)),
-    ]
-    dq_operands = [q, k, v, do, lse, delta]
-    if segmented:
-        dq_in_specs += [qs_spec, ks_spec]
-        dq_operands += [qs, ks]
 
     def dq_call(interp):
         return pl.pallas_call(
             dq_kernel,
-            grid=(B, HQ, nq, nk),
+            grid=(B, HQ, S // bq, dq_band.width),
             compiler_params=_compiler_params(_GRID_SEMANTICS, interp),
             in_specs=dq_in_specs,
-            out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
+            out_specs=dq_out_spec,
             out_shape=jax.ShapeDtypeStruct((B, HQ, S, D), q.dtype),
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
             interpret=interp,
             name="flash_dq",
         )
 
-    dq = run_kernel(dq_call, interpret, *dq_operands)
+    dq = run_kernel(dq_call, interpret, *operands)
 
     # dk/dv are accumulated per q-head then group-summed onto kv heads
+    dkv_band = band_blocks(S, T, bq, bk, causal, window, by_kv=True)
+
     def dkv_kernel(*refs):
         if segmented:
             q_r, k_r, v_r, do_r, lse_r, d_r, qs_r, ks_r, dk_r, dv_r, dks, dvs = refs
@@ -508,35 +610,18 @@ def _bwd_impl(q, k, v, lse, do, delta_rows, causal, sm_scale, block_q, block_k, 
             qs_r = ks_r = None
         _dkv_kernel(q_r, k_r, v_r, do_r, lse_r, d_r, dk_r, dv_r, dks, dvs,
                     sm_scale=scale, causal=causal, block_q=bq, block_k=bk,
-                    num_q_blocks=nq, kv_offset=kv_offset,
+                    band=dkv_band, kv_offset=kv_offset,
                     qseg_ref=qs_r, kseg_ref=ks_r, window=window, softcap=softcap)
 
-    dkv_in_specs = [
-        pl.BlockSpec((1, 1, bq, D), lambda b, h, ki, qi: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, bk, D), lambda b, h, ki, qi, G=G: (b, h // G, ki, 0)),
-        pl.BlockSpec((1, 1, bk, D), lambda b, h, ki, qi, G=G: (b, h // G, ki, 0)),
-        pl.BlockSpec((1, 1, bq, D), lambda b, h, ki, qi: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, bq, LANES), lambda b, h, ki, qi: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, bq, LANES), lambda b, h, ki, qi: (b, h, qi, 0)),
-    ]
-    dkv_operands = [q, k, v, do, lse, delta]
-    if segmented:
-        dkv_in_specs += [
-            pl.BlockSpec((1, bq, LANES), lambda b, h, ki, qi: (b, qi, 0)),
-            pl.BlockSpec((1, _SUBLANES, bk), lambda b, h, ki, qi: (b, 0, ki)),
-        ]
-        dkv_operands += [qs, ks]
-
     def dkv_call(interp):
+        # a kv block a Q head: the outer index, no group division
+        per_q_head = pl.BlockSpec((1, 1, bk, D), lambda b, h, ki, j: (b, h, ki, 0))
         return pl.pallas_call(
             dkv_kernel,
-            grid=(B, HQ, nk, nq),
+            grid=(B, HQ, T // bk, dkv_band.width),
             compiler_params=_compiler_params(_GRID_SEMANTICS, interp),
-            in_specs=dkv_in_specs,
-            out_specs=[
-                pl.BlockSpec((1, 1, bk, D), lambda b, h, ki, qi: (b, h, ki, 0)),
-                pl.BlockSpec((1, 1, bk, D), lambda b, h, ki, qi: (b, h, ki, 0)),
-            ],
+            in_specs=in_specs(dkv_band)[0],
+            out_specs=[per_q_head, per_q_head],
             out_shape=[
                 jax.ShapeDtypeStruct((B, HQ, T, D), jnp.float32),
                 jax.ShapeDtypeStruct((B, HQ, T, D), jnp.float32),
@@ -549,7 +634,7 @@ def _bwd_impl(q, k, v, lse, do, delta_rows, causal, sm_scale, block_q, block_k, 
             name="flash_dkv",
         )
 
-    dk_q, dv_q = run_kernel(dkv_call, interpret, *dkv_operands)
+    dk_q, dv_q = run_kernel(dkv_call, interpret, *operands)
 
     dk = jnp.sum(dk_q.reshape(B, HKV, G, T, D), axis=2).astype(k.dtype)
     dv = jnp.sum(dv_q.reshape(B, HKV, G, T, D), axis=2).astype(v.dtype)
@@ -583,10 +668,12 @@ def flash_attention(
     lowers for a TPU, the pallas interpreter elsewhere (:func:`run_kernel`).
 
     ``window`` (causal only) is Mistral-style sliding-window attention:
-    query at position p attends keys in ``[p - window + 1, p]``.  KV blocks
-    entirely left of the band are skipped in the grid the same way causal
-    blocks above the diagonal are, so long-sequence SWA costs
-    O(S * window), not O(S^2).
+    query at position p attends keys in ``[p - window + 1, p]``.  The grids
+    of all three kernels step only over the blocks the band reaches
+    (:func:`band_blocks`): blocks entirely left of the band or above the
+    diagonal are neither computed, nor fetched, nor stepped over, so
+    long-sequence SWA costs O(S * window) in FLOPs, in HBM traffic AND in
+    grid steps, not O(S^2).
 
     ``softcap`` is Gemma-2-style logit softcapping: scaled scores pass
     through ``cap * tanh(s / cap)`` before masking; the backward kernels

@@ -8,6 +8,8 @@ the dense-vs-sharded numerical-equivalence methodology of
 hardware.
 """
 
+import contextlib
+import importlib
 import os
 
 # Must be set before the XLA backend initializes.  The tests are a CPU
@@ -279,6 +281,29 @@ def lockstep_with_the_old_order(make_engine, make_requests, max_steps=600):
         steps += 1
         assert steps < max_steps
     return ahead, old, got[0]
+
+
+@contextlib.contextmanager
+def square_flash_grid():
+    """The flash kernels on the grid they had before they walked the band:
+    the inner axis of ``flash_fwd`` / ``flash_dq`` / ``flash_dkv`` spans the
+    whole sequence, every operand's block is the plain grid index, and a
+    block the mask hides whole is a step whose body does not run.  Same
+    bodies, same ascending order over the live blocks: what the band grid
+    computes must equal this bit for bit."""
+    fa = importlib.import_module("neuronx_distributed_tpu.ops.flash_attention")
+    banded = fa.band_blocks
+
+    def square_blocks(S, T, bq, bk, causal, window, by_kv=False):
+        n_outer, n_inner = (T // bk, S // bq) if by_kv else (S // bq, T // bk)
+        live = banded(S, T, bq, bk, causal, window, by_kv).live
+        return fa.Band(n_inner, live, n_outer * n_inner, by_kv, None)
+
+    fa.band_blocks = square_blocks
+    try:
+        yield
+    finally:
+        fa.band_blocks = banded
 
 
 def sharded_params(params):

@@ -12,12 +12,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import square_flash_grid
 from neuronx_distributed_tpu.ops import (
     flash_attention,
     flash_attention_with_lse,
     mha_reference,
     ring_attention,
 )
+from neuronx_distributed_tpu.ops.flash_attention import band_blocks
 from neuronx_distributed_tpu.parallel.mesh import initialize_model_parallel
 
 
@@ -66,6 +68,73 @@ def test_flash_decode_offset():
     out = flash_attention(q, k, v, causal=True, block_q=8, block_k=8)
     ref = mha_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
+
+
+CAUSAL_GRIDS = {  # (S, T, block_q, block_k, causal)
+    "causal": (64, 64, 16, 16, True),
+    "causal_unequal_blocks": (64, 64, 32, 16, True),
+    "causal_decode_offset": (32, 96, 16, 16, True),
+    "full": (64, 64, 16, 16, False),
+}
+
+
+@pytest.mark.parametrize("case", CAUSAL_GRIDS.values(), ids=CAUSAL_GRIDS.keys())
+def test_flash_causal_band_equals_square_grid(case):
+    """Causal WITHOUT a window: the inner axis stays as wide as the widest
+    row, the steps above the diagonal repeat the diagonal's block (they stop
+    fetching) — and values and gradients equal the square grid's bit for
+    bit.  A non-causal call keeps the square itself."""
+    S, T, bq, bk, causal = case
+    B, HQ, HKV, D = 1, 4, 2, 8
+    q, k, v = _qkv(jax.random.PRNGKey(S + T), B, HQ, HKV, S, T, D)
+    for by_kv in (False, True):
+        band = band_blocks(S, T, bq, bk, causal, None, by_kv)
+        n_outer, n_inner = (T // bk, S // bq) if by_kv else (S // bq, T // bk)
+        if not causal:
+            assert band.reach is None
+            assert band.live == band.stepped == n_outer * n_inner
+        else:
+            assert band.live < band.stepped <= n_outer * n_inner
+
+    def everything():
+        f = lambda q, k, v: flash_attention(q, k, v, causal, None, bq, bk)  # noqa: E731
+        return (f(q, k, v),) + jax.grad(
+            lambda q, k, v: jnp.sum(f(q, k, v) ** 2), (0, 1, 2))(q, k, v)
+
+    banded = everything()
+    with square_flash_grid():
+        square = everything()
+    for a, b, name in zip(banded, square, ("o", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+    ref = mha_reference(q, k, v, causal=causal)
+    np.testing.assert_allclose(np.asarray(banded[0]), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_flash_autotune_prints_live_of_stepped():
+    """`tools/flash_autotune.py --cpu --tiny --window W`: a line a block
+    pair with the three kernels' `kernel_us` (None off the chip) and, beside
+    them, the live block pairs of the steps each grid makes."""
+    from conftest import run_cli
+
+    proc = run_cli("tools/flash_autotune.py", "--cpu", "--tiny", "--window", "24")
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    import json
+
+    lines = [json.loads(ln) for ln in proc.stdout.splitlines()
+             if ln.startswith("{")]
+    sweeps = [r for r in lines if "live_of_stepped" in r and "best" not in r]
+    assert len(sweeps) == 4 and "best" in lines[-1]
+    for rec in sweeps:
+        assert rec["shape"]["window"] == 24 and rec["kernel_us"] is None
+        S, bq, bk = rec["shape"]["seq"], rec["block_q"], rec["block_k"]
+        for kernel, by_kv in (("flash_fwd", False), ("flash_dq", False),
+                              ("flash_dkv", True)):
+            band = band_blocks(S, S, bq, bk, True, 24, by_kv)
+            assert rec["live_of_stepped"][kernel] == [band.live, band.stepped]
+            assert band.live < band.stepped <= (S // bq) * (S // bk)
+    [fine] = [r for r in sweeps if r["block_q"] == r["block_k"] == 16]
+    assert fine["live_of_stepped"]["flash_dkv"] == [9, 12]  # the square: 16
 
 
 @pytest.mark.parametrize("gqa", [1, 2], ids=["mha", "gqa2"])

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import square_flash_grid
 from neuronx_distributed_tpu.ops.flash_attention import (
     NEG_INF,
     flash_attention,
@@ -111,6 +112,38 @@ def test_flash_feature_matrix_matches_oracle(case):
     for a, b, name in zip(g_f, g_r, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("case", CASES, ids=[f"case{c[0]}" for c in CASES])
+def test_flash_feature_matrix_band_equals_square_grid(case):
+    """The same matrix, the banded grids against the square ones: blocks are
+    visited in the same order with the same bodies, so values and input
+    gradients are equal bit for bit whatever features compose."""
+    seed, B, HKV, G, S, D, bq, bk, window, softcap, segmented = case
+    kq, kk_, kv = jax.random.split(jax.random.PRNGKey(100 + seed), 3)
+    q = jax.random.normal(kq, (B, HKV * G, S, D), jnp.float32)
+    k = jax.random.normal(kk_, (B, HKV, S, D), jnp.float32)
+    v = jax.random.normal(kv, (B, HKV, S, D), jnp.float32)
+    seg_row = np.zeros(S, np.int32)
+    seg_row[:S // 4] = 1
+    seg_row[S // 4:S - 4] = 2
+    segs = jnp.broadcast_to(jnp.asarray(seg_row), (B, S))
+
+    def run_flash(q, k, v):
+        if segmented:
+            return flash_attention_segmented(
+                q, k, v, segs, segs, True, None, bq, bk, None, window, softcap)
+        return flash_attention(q, k, v, True, None, bq, bk, None, window, softcap)
+
+    def everything():
+        return (run_flash(q, k, v),) + jax.grad(
+            lambda q, k, v: jnp.sum(run_flash(q, k, v) ** 2), (0, 1, 2))(q, k, v)
+
+    banded = everything()
+    with square_flash_grid():
+        square = everything()
+    for a, b, name in zip(banded, square, ("o", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
 
 
 def test_flash_softcap_bounds_scores():

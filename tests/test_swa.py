@@ -18,12 +18,19 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import square_flash_grid
 from neuronx_distributed_tpu.ops import (
     flash_attention,
     flash_attention_segmented,
     mha_reference,
     ring_attention,
     ulysses_attention,
+)
+from neuronx_distributed_tpu.ops.flash_attention import (
+    band_blocks,
+    band_mask,
+    flash_attention_segmented_with_lse,
+    flash_attention_with_lse,
 )
 from neuronx_distributed_tpu.parallel.mesh import initialize_model_parallel
 
@@ -129,6 +136,150 @@ def test_swa_window_zero_rejected():
 
 
 # ---------------------------------------------------------------------------
+# the grids walk the band, not the square
+# ---------------------------------------------------------------------------
+
+BAND_CASES = {  # (S, T, block_q, block_k, window)
+    "train_cells_16th": (512, 512, 32, 32, 256),   # 8192 / 4096 / 512 scaled
+    "window_not_block_multiple": (256, 256, 32, 32, 100),
+    "window_under_one_block": (256, 256, 32, 32, 7),
+    "window_of_one": (128, 128, 32, 32, 1),
+    "window_equals_seq": (256, 256, 32, 32, 256),
+    "window_past_seq": (256, 256, 32, 32, 1000),
+    "wide_q_blocks": (256, 256, 64, 16, 96),
+    "wide_kv_blocks": (256, 256, 16, 64, 96),
+    "kv_longer": (128, 384, 32, 32, 80),           # T > S: chunked prefill, ring
+    "kv_longer_unequal_blocks": (128, 384, 32, 64, 150),
+    "kv_longer_no_window": (128, 384, 64, 32, None),
+    "no_window": (256, 256, 32, 32, None),
+    "kv_shorter": (256, 128, 32, 32, 40),          # T < S: rows with no key
+    "one_block": (64, 64, 64, 64, 5),
+    "blocks_no_power_of_two": (192, 384, 48, 96, 70),  # the `//` arm
+}
+
+
+@pytest.mark.parametrize("by_kv", [False, True], ids=["fwd_dq", "dkv"])
+@pytest.mark.parametrize("case", BAND_CASES.values(), ids=BAND_CASES.keys())
+def test_band_blocks_match_brute_force(case, by_kv):
+    """``band_blocks`` against the mask itself: a block pair is live when
+    ``band_mask`` shows a key in it.  The inner axis is as wide as the
+    widest row of live blocks; walking it visits every live block of a row
+    once, in ascending order, and a step before a short row's turn stands on
+    the row's first block (so the pipeline fetches nothing for it)."""
+    S, T, bq, bk, window = case
+    mask = np.asarray(band_mask(S, T, T - S, window))
+    seen = mask.reshape(S // bq, bq, T // bk, bk).any(axis=(1, 3))  # [nq, nk]
+    if by_kv:
+        seen = seen.T  # [outer, inner]
+    band = band_blocks(S, T, bq, bk, True, window, by_kv)
+    assert band.by_kv == by_kv
+    assert band.live == seen.sum()
+    assert band.width == max(1, seen.sum(axis=1).max())
+    assert band.stepped == seen.shape[0] * band.width
+    for outer, row in enumerate(seen):
+        steps = [band.step(outer, j, np) for j in range(band.width)]
+        ran = [i for i, fresh in steps if fresh and row[i]]
+        assert ran == list(np.flatnonzero(row)), outer
+        # a step that visits nothing new stands on a block that a live step
+        # of this row fetches anyway, and a row with live blocks ends on one
+        assert all(steps[j][0] == steps[j + 1][0]
+                   for j in range(band.width - 1) if not steps[j][1]), outer
+        assert steps[-1][1], outer
+
+
+def test_band_blocks_at_the_training_cells_shape():
+    """Sequence 8192 under a window of 4096 in blocks of 512 x 512: 108
+    live block pairs a (batch, head) in 144 grid steps where the square had
+    256; causal without a window keeps the width (the last q block sees
+    every key); a non-causal call keeps the square."""
+    for by_kv in (False, True):
+        band = band_blocks(8192, 8192, 512, 512, True, 4096, by_kv)
+        assert (band.width, band.live, band.stepped) == (9, 108, 144)
+        causal = band_blocks(8192, 8192, 512, 512, True, None, by_kv)
+        assert (causal.width, causal.live, causal.stepped) == (16, 136, 256)
+        square = band_blocks(8192, 8192, 512, 512, False, None, by_kv)
+        assert (square.width, square.live, square.stepped) == (16, 256, 256)
+        assert square.reach is None
+
+
+def _segments(B, S):
+    row = np.zeros(S, np.int32)
+    row[: S // 3] = 1
+    row[S // 3: S - 5] = 2  # tail stays 0 = padding
+    return jnp.broadcast_to(jnp.asarray(row), (B, S))
+
+
+KERNEL_CASES = {  # (HQ, HKV, S, T, block_q, block_k, window, softcap, segmented)
+    "plain": (2, 2, 128, 128, 32, 32, 40, None, False),
+    "gqa4": (4, 1, 128, 128, 32, 16, 70, None, False),
+    "kv_longer": (2, 2, 64, 192, 32, 32, 50, None, False),
+    "softcap": (2, 1, 128, 128, 16, 32, 24, 5.0, False),
+    "segmented": (2, 2, 128, 128, 32, 32, 40, None, True),
+    "segmented_softcap_gqa4": (4, 1, 128, 128, 32, 32, 33, 3.0, True),
+    "no_window": (2, 2, 128, 128, 32, 32, None, None, False),
+    "blocks_no_power_of_two": (2, 1, 192, 192, 48, 96, 70, None, False),
+}
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES.values(), ids=KERNEL_CASES.keys())
+def test_band_grid_matches_dense_and_the_square_grid(case):
+    """Forward, ``lse`` and all three gradients (the ``lse`` cotangent
+    included) of the banded grids: bit-equal to the same kernels stepping
+    the whole square, and equal to the dense oracle."""
+    HQ, HKV, S, T, bq, bk, window, softcap, segmented = case
+    B, D = 1, 16
+    q, k, v = _qkv(jax.random.PRNGKey(S + T + HQ), B, HQ, HKV, S, T, D)
+    r_o = jax.random.normal(jax.random.PRNGKey(7), (B, HQ, S, D))
+    r_lse = jax.random.normal(jax.random.PRNGKey(8), (B, HQ, S))
+    segs = _segments(B, S) if segmented else None
+    rows = (np.asarray(segs[0]) > 0) if segmented else np.ones(S, bool)
+    w = jnp.asarray(rows, jnp.float32)  # padding rows hold garbage by contract
+
+    def flash(q, k, v):
+        if segmented:
+            return flash_attention_segmented_with_lse(
+                q, k, v, segs, segs, True, None, bq, bk, None, window, softcap)
+        return flash_attention_with_lse(
+            q, k, v, True, None, bq, bk, None, window, softcap)
+
+    def dense(q, k, v):
+        G = HQ // HKV
+        s = jnp.einsum("bhsd,bhtd->bhst", q, jnp.repeat(k, G, axis=1)) * D ** -0.5
+        if softcap is not None:
+            s = softcap * jnp.tanh(s / softcap)
+        mask = band_mask(S, T, T - S, window)[None, None]
+        if segmented:
+            same = segs[:, None, :, None] == segs[:, None, None, :]
+            mask = mask & same & (segs > 0)[:, None, :, None]
+        s = jnp.where(mask, s, -1e30)
+        lse = jax.nn.logsumexp(s, axis=-1)
+        p = jnp.exp(s - lse[..., None])
+        return jnp.einsum("bhst,bhtd->bhsd", p, jnp.repeat(v, G, axis=1)), lse
+
+    def loss(fn):
+        def f(q, k, v):
+            o, lse = fn(q, k, v)
+            return (jnp.sum(o * r_o * w[None, None, :, None])
+                    + jnp.sum(lse * r_lse * w[None, None, :]))
+        return f
+
+    def everything(fn):
+        o, lse = fn(q, k, v)
+        return (o, lse) + jax.grad(loss(fn), (0, 1, 2))(q, k, v)
+
+    banded = everything(flash)
+    with square_flash_grid():
+        square = everything(flash)
+    oracle = everything(dense)
+    for name, a, b, c in zip(("o", "lse", "dq", "dk", "dv"), banded, square, oracle):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+        a, c = np.asarray(a), np.asarray(c)
+        if name in ("o", "lse"):
+            a, c = a[:, :, rows], c[:, :, rows]
+        np.testing.assert_allclose(a, c, rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+# ---------------------------------------------------------------------------
 # context-parallel composition
 # ---------------------------------------------------------------------------
 
@@ -200,6 +351,34 @@ def test_swa_ring_window_equals_chunk(devices8):
         a, b, c, causal=True, block_q=16, block_k=16, window=W))(_t(q), _t(k), _t(v))
     np.testing.assert_allclose(
         np.asarray(_t(out)), np.asarray(ref), rtol=1e-5, atol=1e-5)
+
+
+def test_swa_ring_band_equals_square_grid(devices8):
+    """The ring's windowed schedule calls the kernels on a ``[left | own]``
+    timeline (``T = 2 S``, the window a chunk or less): values and grads of
+    the banded grids equal the square grid's bit for bit."""
+    initialize_model_parallel(
+        tensor_parallel_size=2, context_parallel_size=4, devices=devices8
+    )
+    B, HKV, S, D, W = 1, 2, 128, 8, 20  # C = 32 in blocks of 16
+    q, k, v = _qkv(jax.random.PRNGKey(18), B, 4, HKV, S, S, D)
+
+    def run():
+        fn = jax.jit(lambda a, b, c: ring_attention(
+            a, b, c, causal=True, block_q=16, block_k=16, window=W))
+        out = fn(_t(q), _t(k), _t(v))
+        grads = jax.grad(lambda a, b, c: jnp.sum(fn(_t(a), _t(b), _t(c)) ** 2),
+                         (0, 1, 2))(q, k, v)
+        return (out,) + grads
+
+    banded = run()
+    with square_flash_grid():
+        square = run()
+    for a, b, n in zip(banded, square, ("o", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=n)
+    ref = mha_reference(q, k, v, causal=True, window=W)
+    np.testing.assert_allclose(np.asarray(_t(banded[0])), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_swa_ring_packed_matches_oracle(devices8):

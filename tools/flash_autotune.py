@@ -6,6 +6,12 @@ line per config plus a final ``best`` line.  Standalone kernel programs
 compile orders of magnitude faster than the full train step, so a sweep is
 cheap in chip time, and its numbers justify (or refute) the
 512x512 default the models use (`ops/flash_attention.py` block_q/block_k).
+``--window W`` times the sliding-window band, ``--non-causal`` the whole
+square.  On a TPU each line also gives ``kernel_us``: the device-clock time
+of ONE call of ``flash_fwd``, ``flash_dq`` and ``flash_dkv`` each (their
+events in a profiler trace of the fwd+bwd program), beside
+``live_of_stepped``: the block pairs a (batch, head) whose body runs and the
+steps the kernel's grid makes for them (`ops.flash_attention.band_blocks`).
 
 ``--paged`` instead times the paged-attention kernel
 (`ops/paged_attention.py`) at one serving shape with every live slot at
@@ -22,6 +28,9 @@ profiler trace).
 
 Usage:
     python tools/flash_autotune.py                 # flash bench shape, TPU
+    # the training cells' per-chip shapes (1 chip; a chip of tp=4: 8 / 2 heads)
+    python tools/flash_autotune.py --batch 2 --heads 32 --kv-heads 8 \
+        --seq 8192 --window 4096 --blocks 512
     python tools/flash_autotune.py --cpu --tiny    # flash smoke (interpret)
     python tools/flash_autotune.py --paged         # pages a step, TPU
     python tools/flash_autotune.py --paged --walk  # table width / live slots
@@ -37,6 +46,9 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+FLASH_KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 
 
 def _pct_roofline(flops: float, bytes_accessed: float, seconds: float):
@@ -125,10 +137,12 @@ def _time_chain(paged_call, chain, steps, q, *rest):
     return _time_fn(jax.jit(many), steps, q, *rest) / chain
 
 
-def _kernel_us(paged_call, steps, *xs):
-    """Device-clock microseconds of the paged kernel ALONE: the median
-    duration of its events (named ``paged_attention_*``) in a profiler
-    trace of ``steps`` calls.  None where there is no TPU to trace."""
+def _kernel_us(call, steps, *xs, kernels=("paged_attention",)):
+    """Device-clock microseconds of a program's Pallas kernels ALONE: for
+    each name in ``kernels`` the median duration of the events whose HLO
+    name starts with it, in a profiler trace of ``steps`` calls (a name
+    without events reads None).  One name gives a number, several a dict.
+    None where there is no TPU to trace."""
     import glob
     import statistics
     import tempfile
@@ -137,7 +151,7 @@ def _kernel_us(paged_call, steps, *xs):
 
     if jax.devices()[0].platform != "tpu":
         return None
-    fn = jax.jit(paged_call)
+    fn = jax.jit(call)
     jax.block_until_ready(fn(*xs))
     with tempfile.TemporaryDirectory() as trace_dir:
         with jax.profiler.trace(trace_dir):
@@ -147,13 +161,17 @@ def _kernel_us(paged_call, steps, *xs):
         [path] = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                            recursive=True)
         data = jax.profiler.ProfileData.from_file(path)
-    durations = [e.duration_ns
-                 for plane in data.planes
-                 if plane.name.startswith("/device:TPU:")
-                 for line in plane.lines if line.name == "XLA Ops"
-                 for e in line.events if "paged_attention" in e.name]
-    return (round(statistics.median(durations) / 1e3, 1) if durations
-            else None)
+    events = [(e.name.lstrip("%"), e.duration_ns)
+              for plane in data.planes
+              if plane.name.startswith("/device:TPU:")
+              for line in plane.lines if line.name == "XLA Ops"
+              for e in line.events]
+    found = {}
+    for kernel in kernels:
+        durations = [d for name, d in events if name.startswith(kernel)]
+        found[kernel] = (round(statistics.median(durations) / 1e3, 1)
+                         if durations else None)
+    return found if len(kernels) > 1 else found[kernels[0]]
 
 
 def _paged_shape(args):
@@ -310,7 +328,9 @@ def main() -> int:
                    help="paged mode: left pad before a live slot's keys (a "
                         "few more a slot, so bands start anywhere in a page)")
     p.add_argument("--window", type=int, default=None,
-                   help="paged mode: sliding window")
+                   help="sliding window (flash and paged mode)")
+    p.add_argument("--non-causal", action="store_true",
+                   help="flash mode: the whole square, no mask")
     p.add_argument("--chain", type=int, default=16,
                    help="paged mode: calls timed in one program")
     p.add_argument("--seed", type=int, default=0)
@@ -322,7 +342,11 @@ def main() -> int:
         jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
 
-    from neuronx_distributed_tpu.ops.flash_attention import flash_attention
+    from neuronx_distributed_tpu.ops.flash_attention import (
+        _block_sizes,
+        band_blocks,
+        flash_attention,
+    )
 
     if args.paged:
         return run_paged_walk(args) if args.walk else run_paged(args)
@@ -333,35 +357,52 @@ def main() -> int:
         args.blocks = "16,32"
 
     B, HQ, HKV, S, D = args.batch, args.heads, args.kv_heads, args.seq, args.head_dim
+    causal, window = not args.non_causal, args.window
     dtype = jnp.float32 if args.cpu else jnp.bfloat16
     q = jax.random.normal(jax.random.PRNGKey(0), (B, HQ, S, D), dtype)
     k = jax.random.normal(jax.random.PRNGKey(1), (B, HKV, S, D), dtype)
     v = jax.random.normal(jax.random.PRNGKey(2), (B, HKV, S, D), dtype)
-    # causal attention FLOPs: 2 matmuls x 2 flops, half the square
-    flops = 2 * 2 * B * HQ * S * S * D / 2
+    # attended (query, key) pairs a (batch, head): the band, not the square
+    reach = min(window or S, S)
+    pairs = (reach * (reach + 1) // 2 + (S - reach) * reach) if causal else S * S
+    flops = 2 * 2 * B * HQ * pairs * D  # forward: 2 matmuls x 2 flops
     # streamed bytes: q in + o out (HQ) and k + v in (HKV)
     fbytes = (B * HQ * S * D * 2 + B * HKV * S * D * 2) * q.dtype.itemsize
 
     blocks = [int(b) for b in args.blocks.split(",")]
     results = []
     for bq, bk in itertools.product(blocks, blocks):
-        fwd = jax.jit(lambda a, b_, c, bq=bq, bk=bk: flash_attention(
-            a, b_, c, True, None, bq, bk))
-        grad = jax.jit(jax.grad(lambda a, b_, c, bq=bq, bk=bk: flash_attention(
-            a, b_, c, True, None, bq, bk).astype(jnp.float32).sum(), (0, 1, 2)))
+        attend = lambda a, b_, c, bq=bq, bk=bk: flash_attention(  # noqa: E731
+            a, b_, c, causal, None, bq, bk, None, window)
+        fwd = jax.jit(attend)
+        grad = jax.jit(jax.grad(
+            lambda a, b_, c: attend(a, b_, c).astype(jnp.float32).sum(),
+            (0, 1, 2)))
 
         try:
             t_fwd = _time_fn(fwd, args.steps, q, k, v)
             t_bwd = _time_fn(grad, args.steps, q, k, v)
+            kernel_us = _kernel_us(grad, args.steps, q, k, v,
+                                   kernels=FLASH_KERNELS)
         except Exception as e:  # noqa: BLE001 — report and continue sweeping
             rec = {"block_q": bq, "block_k": bk, "error": str(e)[:200]}
             results.append(rec)
             print(json.dumps(rec), flush=True)
             continue
+        # block pairs a (batch, head) whose body runs, of the grid's steps
+        fitted = _block_sizes(S, S, bq, bk)
+        by_q = band_blocks(S, S, *fitted, causal, window)
+        bands = (by_q, by_q, band_blocks(S, S, *fitted, causal, window,
+                                         by_kv=True))
         rec = {
+            "shape": {"batch": B, "heads": HQ, "kv_heads": HKV, "seq": S,
+                      "head_dim": D, "causal": causal, "window": window},
             "block_q": bq, "block_k": bk,
             "fwd_ms": round(t_fwd * 1e3, 3),
             "fwd_bwd_ms": round(t_bwd * 1e3, 3),
+            "kernel_us": kernel_us,
+            "live_of_stepped": {kernel: [band.live, band.stepped]
+                                for kernel, band in zip(FLASH_KERNELS, bands)},
             "fwd_tflops": round(flops / t_fwd / 1e12, 2),
             "pct_roofline": _pct_roofline(flops, fbytes, t_fwd),
         }
