@@ -575,6 +575,227 @@ def nemotron_h_params_to_hf(params: Mapping[str, Any], cfg
 # ---------------------------------------------------------------------------
 
 
+# -- Xing4.0 (``model_type`` ``xing4_0``) ----------------------------------------
+#
+# The source's tensor names are ASSUMED (no checkpoint index is at hand): the
+# DeepSeek-V2/V3 names for latent attention and the routed block, which the
+# config's keys follow letter for letter, and ``attn_hc`` / ``ffn_hc`` for the
+# two sublayers' stream-mixing maps, which no published checkpoint names yet.
+XING4_NAMES = {
+    "attention": ("self_attn.q_a_proj.weight", "self_attn.q_a_layernorm.weight",
+                  "self_attn.q_b_proj.weight",
+                  "self_attn.kv_a_proj_with_mqa.weight",
+                  "self_attn.kv_a_layernorm.weight",
+                  "self_attn.kv_b_proj.weight", "self_attn.o_proj.weight"),
+    "dense": ("mlp.gate_proj.weight", "mlp.up_proj.weight",
+              "mlp.down_proj.weight"),
+    "routed": ("mlp.gate.weight", "mlp.gate.e_score_correction_bias",
+               "mlp.experts.{e}.gate_proj.weight",
+               "mlp.experts.{e}.up_proj.weight",
+               "mlp.experts.{e}.down_proj.weight",
+               "mlp.shared_experts.gate_proj.weight",
+               "mlp.shared_experts.up_proj.weight",
+               "mlp.shared_experts.down_proj.weight"),
+    "streams": ("{hc}.phi.weight", "{hc}.b", "{hc}.alpha_pre",
+                "{hc}.alpha_post", "{hc}.alpha_res"),
+    "norms": ("input_layernorm.weight", "post_attention_layernorm.weight"),
+}
+
+
+def xing4_config_from_hf(hf_config: Mapping[str, Any], **overrides):
+    """A ``xing4_0`` ``config.json`` -> :class:`~..models.llama.LlamaConfig`:
+    every layer ``"mla"``, the first ``first_k_dense_replace`` layers dense
+    and the rest routed, ``hc_mult`` streams.  The next-token module
+    (``num_nextn_predict_layers``) is not built."""
+    from neuronx_distributed_tpu.models.llama import LlamaConfig
+
+    c = hf_config
+    L, dense = c["num_hidden_layers"], c["first_k_dense_replace"]
+    rs = c.get("rope_scaling") or {}
+    if rs and rs.get("type", rs.get("rope_type")) != "yarn":
+        raise ValueError(f"xing4_0 rope_scaling {rs}: only YaRN is read")
+    if c.get("n_group", 1) != 1 or c.get("topk_group", 1) != 1:
+        raise ValueError("group-limited routing (n_group > 1) is not built")
+    return LlamaConfig(**{**dict(
+        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+        intermediate_size=c["intermediate_size"],
+        moe_intermediate_size=c["moe_intermediate_size"], num_layers=L,
+        num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_attention_heads"],
+        max_seq_len=c["max_position_embeddings"],
+        rope_theta=float(c["rope_theta"]), rms_eps=c["rms_norm_eps"],
+        mixer_types=("mla",) * L,
+        ffn_types=("mlp",) * dense + ("moe",) * (L - dense),
+        q_lora_rank=c["q_lora_rank"], kv_lora_rank=c["kv_lora_rank"],
+        qk_nope_head_dim=c["qk_nope_head_dim"],
+        qk_rope_head_dim=c["qk_rope_head_dim"], v_head_dim=c["v_head_dim"],
+        rope_yarn_factor=float(rs.get("factor", 1.0)),
+        rope_yarn_original_max_seq=int(
+            rs.get("original_max_position_embeddings", 4096)),
+        rope_yarn_beta_fast=float(rs.get("beta_fast", 32)),
+        rope_yarn_beta_slow=float(rs.get("beta_slow", 1)),
+        rope_yarn_mscale=float(rs.get("mscale", 1)),
+        rope_yarn_mscale_all_dim=float(rs.get("mscale_all_dim", 0)),
+        hc_mult=c["hc_mult"], hc_sinkhorn_iters=c["hc_sinkhorn_iters"],
+        hc_eps=c["hc_eps"],
+        hc_res_clamp=(c["mhc_h_res_clamp_min"], c["mhc_h_res_clamp_max"]),
+        num_experts=c["n_routed_experts"], moe_top_k=c["num_experts_per_tok"],
+        moe_dispatch="dropless", moe_norm_topk_prob=c["norm_topk_prob"],
+        moe_router_scores=c["scoring_func"], moe_router_bias=True,
+        moe_route_scale=float(c["routed_scaling_factor"]),
+        moe_shared_intermediate_size=(c["n_shared_experts"]
+                                      * c["moe_intermediate_size"]),
+    ), **overrides})
+
+
+def _rope_halves(rope: int) -> np.ndarray:
+    return np.concatenate([np.arange(0, rope, 2), np.arange(1, rope, 2)])
+
+
+def _permute_rope_columns(w, heads: int, lead: int, order) -> np.ndarray:
+    w = w.reshape(w.shape[0], heads, lead + len(order))
+    return np.concatenate([w[..., :lead], w[..., lead:][..., order]],
+                          axis=-1).reshape(w.shape[0], -1)
+
+
+def rope_half_from_interleaved(w: np.ndarray, heads: int, lead: int,
+                               rope: int) -> np.ndarray:
+    """The output columns of a projection ``w [in, heads * (lead + rope)]``
+    whose last ``rope`` columns a head are RoPE pairs INTERLEAVED ``(x0, x1),
+    (x2, x3), ...`` (the DeepSeek convention) -> the same with each head's
+    pairs as halves ``(x0, x2, ... | x1, x3, ...)``, which this package's
+    rotate-half RoPE turns.  ``q . k`` is unchanged when both sides are
+    permuted alike."""
+    return _permute_rope_columns(w, heads, lead, _rope_halves(rope))
+
+
+def rope_interleaved_from_half(w: np.ndarray, heads: int, lead: int,
+                               rope: int) -> np.ndarray:
+    """The inverse of :func:`rope_half_from_interleaved`."""
+    return _permute_rope_columns(w, heads, lead,
+                                 np.argsort(_rope_halves(rope)))
+
+
+def _xing4_hc_names(p: str, hc: str):
+    return [p + n.format(hc=hc) for n in XING4_NAMES["streams"]]
+
+
+def xing4_params_from_hf(state_dict: Mapping[str, Any], cfg
+                         ) -> Dict[str, Any]:
+    """A ``xing4_0`` state dict (:data:`XING4_NAMES`, assumed) -> the param
+    tree of :class:`~..models.llama.LlamaForCausalLM` under
+    :func:`xing4_config_from_hf`'s layer lists: Linear weights transposed
+    in-major, the RoPE columns of ``q_b`` and ``kv_a`` from interleaved pairs
+    to halves, ``kv_b`` split a head ``[rank, NH, dn + dv]``, the experts
+    stacked, ``phi [nC, n^2 + 2n]`` as ``[n, C, n^2 + 2n]``."""
+    sd = {k: _np(v) for k, v in state_dict.items()}
+    NH, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    n, C = cfg.hc_mult, cfg.hidden_size
+    model: Dict[str, Any] = {
+        "embed": {"embedding": sd["model.embed_tokens.weight"]},
+        "final_norm": {"weight": sd["model.norm.weight"]},
+    }
+
+    def hc(p, name):
+        phi, b, *alphas = (sd[k] for k in _xing4_hc_names(p, name))
+        return {"phi": phi.reshape(n, C, -1), "b": b,
+                **dict(zip(("a_pre", "a_post", "a_res"), alphas))}
+
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}."
+        qa, qan, qb, kva, kvan, kvb, wo = (
+            sd[p + k] for k in XING4_NAMES["attention"])
+        layer = {
+            "input_norm": {"weight": sd[p + "input_layernorm.weight"]},
+            "post_attn_norm": {
+                "weight": sd[p + "post_attention_layernorm.weight"]},
+            "attn_hc": hc(p, "attn_hc"), "ffn_hc": hc(p, "ffn_hc"),
+            "attn": {
+                "q_a": {"kernel": qa.T}, "q_a_norm": {"weight": qan},
+                "q_b": {"kernel": rope_half_from_interleaved(
+                    qb.T, NH, dn, dr)},
+                "kv_a": {"kernel": rope_half_from_interleaved(
+                    kva.T, 1, r, dr)},
+                "kv_a_norm": {"weight": kvan},
+                "kv_b": kvb.T.reshape(r, NH, dn + dv),
+                "o_proj": {"kernel": wo.T}}}
+        if cfg.ffn(i) == "mlp":
+            gate, up, down = (sd[p + k] for k in XING4_NAMES["dense"])
+            layer["mlp"] = {
+                "gate_up": {"kernel": np.stack([gate.T, up.T], axis=1)},
+                "down": {"kernel": down.T}}
+        else:
+            m = p + "mlp."
+            stack = lambda what: np.stack([  # noqa: E731
+                sd[m + f"experts.{e}.{what}_proj.weight"].T
+                for e in range(cfg.num_experts)])
+            layer["moe_mlp"] = {
+                "router": sd[m + "gate.weight"].T,
+                "router_bias": sd[m + "gate.e_score_correction_bias"],
+                "gate": stack("gate"), "up": stack("up"),
+                "down": stack("down"),
+                **{f"shared_{w}": {
+                    "kernel": sd[m + f"shared_experts.{w}_proj.weight"].T}
+                   for w in ("gate", "up", "down")}}
+        model[f"layer_{i}"] = layer
+    return {"params": {"model": model,
+                       "lm_head": {"kernel": sd["lm_head.weight"].T}}}
+
+
+def xing4_params_to_hf(params: Mapping[str, Any], cfg
+                       ) -> Dict[str, np.ndarray]:
+    """The inverse of :func:`xing4_params_from_hf`, bit for bit."""
+    p = params["params"] if "params" in params else params
+    model = p["model"]
+    NH, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    sd: Dict[str, np.ndarray] = {
+        "model.embed_tokens.weight": _np(model["embed"]["embedding"]),
+        "model.norm.weight": _np(model["final_norm"]["weight"]),
+        "lm_head.weight": _np(p["lm_head"]["kernel"]).T,
+    }
+    for i in range(cfg.num_layers):
+        lp, pre = model[f"layer_{i}"], f"model.layers.{i}."
+        at = lp["attn"]
+        sd[pre + "input_layernorm.weight"] = _np(lp["input_norm"]["weight"])
+        sd[pre + "post_attention_layernorm.weight"] = _np(
+            lp["post_attn_norm"]["weight"])
+        for name in ("attn_hc", "ffn_hc"):
+            h = lp[name]
+            phi = _np(h["phi"])
+            for key, val in zip(_xing4_hc_names(pre, name), (
+                    phi.reshape(-1, phi.shape[-1]), _np(h["b"]),
+                    _np(h["a_pre"]), _np(h["a_post"]), _np(h["a_res"]))):
+                sd[key] = val
+        values = (
+            _np(at["q_a"]["kernel"]).T, _np(at["q_a_norm"]["weight"]),
+            rope_interleaved_from_half(_np(at["q_b"]["kernel"]), NH, dn,
+                                       dr).T,
+            rope_interleaved_from_half(_np(at["kv_a"]["kernel"]), 1, r,
+                                       dr).T,
+            _np(at["kv_a_norm"]["weight"]),
+            _np(at["kv_b"]).reshape(r, -1).T, _np(at["o_proj"]["kernel"]).T)
+        for key, val in zip(XING4_NAMES["attention"], values):
+            sd[pre + key] = val
+        if "mlp" in lp:
+            gu = _np(lp["mlp"]["gate_up"]["kernel"])
+            for key, val in zip(XING4_NAMES["dense"], (
+                    gu[:, 0].T, gu[:, 1].T,
+                    _np(lp["mlp"]["down"]["kernel"]).T)):
+                sd[pre + key] = val
+        else:
+            moe, m = lp["moe_mlp"], pre + "mlp."
+            sd[m + "gate.weight"] = _np(moe["router"]).T
+            sd[m + "gate.e_score_correction_bias"] = _np(moe["router_bias"])
+            for w in ("gate", "up", "down"):
+                for e, mat in enumerate(_np(moe[w])):
+                    sd[m + f"experts.{e}.{w}_proj.weight"] = mat.T
+                sd[m + f"shared_experts.{w}_proj.weight"] = _np(
+                    moe[f"shared_{w}"]["kernel"]).T
+    return sd
+
+
 def _neox_deinterleave(w_qkv: np.ndarray, b_qkv: np.ndarray, num_heads: int, head_dim: int):
     """HF NeoX fused QKV rows are per-head interleaved ``[n,(q|k|v),d]``;
     the framework's fused axis wants ``[in, 3, n*d]``."""

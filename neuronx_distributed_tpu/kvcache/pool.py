@@ -51,8 +51,10 @@ GATHER_BYTES_TOTAL = "kvcache/gather_bytes_total"
 
 # what a layer keeps for a live sequence: K/V pages; K/V pages it chooses
 # among, with compressed keys beside them; a row of each of its state
-# arrays; nothing (a layer without a mixer)
-CACHE_KINDS = ("pages", "selected_pages", "state", "none")
+# arrays; pages of ONE latent row a token (no K/V pair, no kv-head axis:
+# ``[NP, page, latent_dim]``, ``ops.latent_attention``); nothing (a layer
+# without a mixer)
+CACHE_KINDS = ("pages", "selected_pages", "state", "latent", "none")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,12 +67,17 @@ class LayerStates:
     (``state_arrays``: ``(shape, dtype name)`` each, ``[state_rows, *shape]``
     on the device — a lightning layer's one float32 state, a Mamba-2
     layer's scan state and convolution taps), which is neither paged nor
-    shareable by page.  A ``"none"`` layer's entry of the pool is ``()``."""
+    shareable by page.  A ``"latent"`` layer's entry is ``(latents [NP,
+    page, latent_dim],)``: pages as any other to the allocator, the block
+    tables and the prefix index, ``latent_dim`` columns a token in place of
+    a K/V pair a kv head.  A ``"none"`` layer's entry of the pool is
+    ``()``."""
 
     kinds: Tuple[str, ...]
     comp_slots: int = 0
     state_rows: int = 0
     state_arrays: Tuple[Tuple[Tuple[int, ...], str], ...] = ()
+    latent_dim: int = 0
 
     @staticmethod
     def for_config(cfg, page_size: int, state_rows: int
@@ -92,7 +99,8 @@ class LayerStates:
             comp_slots=(page_size // spec.kernel_stride if spec is not None
                         else 0),
             state_rows=state_rows if recurrent else 0,
-            state_arrays=tuple(cfg.state_arrays) if recurrent else ())
+            state_arrays=tuple(cfg.state_arrays) if recurrent else (),
+            latent_dim=getattr(cfg, "latent_row_dim", 0))
 
     @property
     def recurrent(self) -> int:
@@ -100,7 +108,8 @@ class LayerStates:
 
     @property
     def paged(self) -> int:
-        return sum(k in ("pages", "selected_pages") for k in self.kinds)
+        return sum(k in ("pages", "selected_pages", "latent")
+                   for k in self.kinds)
 
     @property
     def state_shape(self) -> Tuple[int, ...]:
@@ -169,6 +178,9 @@ def init_page_pool_caches(
         def entry(kind):
             if kind == "none":
                 return ()
+            if kind == "latent":
+                return (jnp.zeros((num_pages, page_size, layers.latent_dim),
+                                  dtype, device=scale_sh),)
             if kind == "state":
                 return tuple(
                     jnp.zeros((layers.state_rows,) + shape, jnp.dtype(dt),
@@ -268,7 +280,8 @@ class PagePool:
 def _page_bytes(num_layers, page_size, num_kv_heads, head_dim, dtype, quant,
                 layers: Optional[LayerStates]) -> int:
     """Bytes one page costs across the layers that HAVE pages: K and V, the
-    int8 page params, the compressed keys of a block-sparse layer."""
+    int8 page params, the compressed keys of a block-sparse layer; a latent
+    layer's ``page * latent_dim`` elements."""
     from neuronx_distributed_tpu.kvcache.quant import page_layer_bytes
 
     per_layer = page_layer_bytes(page_size, num_kv_heads, head_dim, quant,
@@ -277,5 +290,7 @@ def _page_bytes(num_layers, page_size, num_kv_heads, head_dim, dtype, quant,
         return num_layers * per_layer
     comp = (layers.comp_slots * num_kv_heads * head_dim
             * jnp.dtype(dtype).itemsize)
+    latent = page_size * layers.latent_dim * jnp.dtype(dtype).itemsize
     return sum(per_layer + (comp if k == "selected_pages" else 0)
-               for k in layers.kinds if k in ("pages", "selected_pages"))
+               for k in layers.kinds if k in ("pages", "selected_pages")
+               ) + latent * sum(k == "latent" for k in layers.kinds)

@@ -19,6 +19,13 @@ feed-forward part alone) — MiniCPM-SALA's two and Nemotron-H's Mamba-2:
   by ``silu(z)``.  Its per-sequence state is a state row of TWO arrays: the
   float32 scan state ``[heads, P, N]`` and the convolution's last ``K - 1``
   inputs ``[K - 1, channels]`` in the activations' dtype.
+- ``"mla"``: latent attention (DeepSeek-V2's MLA, ``ops.latent_attention``)
+  — queries through a normed bottleneck, keys and values through ONE normed
+  latent a token beside one RoPE key all heads share; the pool keeps pages
+  of that row and nothing else, a decode attends them ABSORBED (the key
+  up-projection folded into the query, the value's applied to the result)
+  and a prefill chunk EXPANDED (keys and values up-projected a page inside
+  the walk).
 
 Projections, norms and the cache protocol are the block's own
 (``GQAQKVColumnParallelLinear``, ``RowParallelLinear``, ``RMSNorm``): a
@@ -43,13 +50,14 @@ from neuronx_distributed_tpu.parallel.qkv import (
     Q_HEAD_AXES,
 )
 
-MIXERS = ("attention", "minicpm4", "lightning-attn", "mamba2", "none")
+MIXERS = ("attention", "minicpm4", "lightning-attn", "mamba2", "mla", "none")
 # what each mixer keeps for a live sequence, in the page pool's terms
 # (``kvcache.pool.CACHE_KINDS``): the one place a mixer's name decides it —
 # ``LlamaConfig.layer_caches`` hands it on, and the pool and the engines
 # read the config
 CACHE_OF = {"attention": "pages", "minicpm4": "selected_pages",
-            "lightning-attn": "state", "mamba2": "state", "none": "none"}
+            "lightning-attn": "state", "mamba2": "state", "mla": "latent",
+            "none": "none"}
 # the standard deviation a SEEDED embedding table of a layer-list model is
 # drawn with: the MiniCPM family's ``initializer_range``.  With muP's 12 x
 # embedding the table then leads the residual stream, as in a trained model;
@@ -396,7 +404,157 @@ class Mamba2Mixer(nn.Module):
             name="out_proj")(y), new_cache
 
 
+def mla_softmax_scale(cfg) -> float:
+    """``(dn + dr)^-1/2``, times YaRN's ``mscale(factor, mscale_all_dim)^2``
+    where the config stretches its RoPE and sets that term."""
+    from neuronx_distributed_tpu.models.llama import yarn_mscale
+
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.rope_yarn_factor > 1.0 and cfg.rope_yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.rope_yarn_factor,
+                             cfg.rope_yarn_mscale_all_dim) ** 2
+    return scale
+
+
+# a cached call of at least this many rows a slot attends EXPANDED (a
+# prefill chunk), fewer ABSORBED (a decode): PERF.md, PR 36, step 0
+MLA_EXPANDED_MIN_ROWS = 64
+
+
+def _latent_cells(cache_offset, block_table, kv_valid, rows, page, num_pages):
+    """``(phys [B, rows], in_off)``: the pool cell of each new row, as the
+    K/V write of ``models.llama`` finds it — row ``s`` of slot ``b`` is
+    cache index ``cache_offset[b] + s``; a parked slot's, a row's past the
+    table and a pad row's (validity 0) page is ``num_pages``: dropped."""
+    PP = block_table.shape[1]
+    T = PP * page
+    idx = cache_offset[:, None] + jnp.arange(rows)[None, :]
+    phys = jnp.take_along_axis(block_table,
+                               jnp.clip(idx // page, 0, PP - 1), axis=1)
+    phys = jnp.where(idx < T, phys, num_pages)
+    if kv_valid is not None:
+        live = jnp.take_along_axis(jnp.asarray(kv_valid),
+                                   jnp.clip(idx, 0, T - 1), axis=1) > 0
+        phys = jnp.where(live, phys, num_pages)
+    return phys, idx % page
+
+
+class MLAMixer(nn.Module):
+    config: object
+
+    @nn.compact
+    def __call__(self, x, positions, kv_cache=None, cache_offset=0,
+                 kv_valid=None, block_table=None, paged_kernel=False,
+                 state_rows=None):
+        from neuronx_distributed_tpu.models.llama import (
+            _causal_mask,
+            apply_rope,
+            rope_sin_cos,
+        )
+        from neuronx_distributed_tpu.ops import latent_attention as la
+        from neuronx_distributed_tpu.parallel.moe import per_expert_lecun
+
+        cfg = self.config
+        NH, rank = cfg.num_heads, cfg.kv_lora_rank
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        B, S = x.shape[0], x.shape[1]
+        # (seeded weights are drawn in float32 and rounded: per_expert_lecun)
+        lin = dict(use_bias=False, sequence_parallel=cfg.sequence_parallel,
+                   dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                   kernel_init=per_expert_lecun)
+        norm = lambda name: RMSNorm(  # noqa: E731
+            eps=cfg.rms_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            name=name)
+        with jax.named_scope("mla_q"):
+            cq = norm("q_a_norm")(ColumnParallelLinear(
+                features=cfg.q_lora_rank, name="q_a", **lin)(x))
+            q = ColumnParallelLinear(features=NH * (dn + dr), name="q_b",
+                                     **lin)(cq).reshape(B, S, NH, dn + dr)
+        with jax.named_scope("mla_kv_down"):
+            kva = ColumnParallelLinear(features=rank + dr, name="kv_a",
+                                       **lin)(x)
+            ckv = norm("kv_a_norm")(kva[..., :rank])
+        sin, cos = rope_sin_cos(positions, dr, cfg.rope_theta,
+                                cfg.rope_scaling_)
+        q_nope, q_rope = q[..., :dn], apply_rope(q[..., dn:], sin, cos)
+        k_rope = apply_rope(kva[..., None, rank:], sin, cos)[:, :, 0]
+        # the one up-projection of the latent, a head: [rank, NH, dn | dv]
+        kv_b = jnp.asarray(self.param(
+            "kv_b", nn.with_partitioning(
+                lambda key, shape, dtype: per_expert_lecun(
+                    key, (shape[0], shape[1] * shape[2]), dtype
+                ).reshape(shape), (None, None, None)),
+            (rank, NH, dn + dv), cfg.param_dtype)).astype(cfg.dtype)
+        wk, wv = kv_b[..., :dn], kv_b[..., dn:]
+        scale = mla_softmax_scale(cfg)
+        new_cache = None
+        if kv_cache is None:
+            # a whole sequence, no cache: expanded, the [S, S] mask
+            with jax.named_scope("mla_kv_up"):
+                kn = jnp.einsum("btr,rhd->bthd", ckv, wk)
+                v = jnp.einsum("btr,rhd->bthd", ckv, wv)
+            s = (jnp.einsum("bshd,bthd->bhst", q_nope, kn,
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("bshd,btd->bhst", q_rope, k_rope,
+                              preferred_element_type=jnp.float32)) * scale
+            mask = _causal_mask(S, S, 0, None)[None, None]
+            if kv_valid is not None:
+                mask = jnp.logical_and(
+                    mask, jnp.asarray(kv_valid)[:, None, None, :] > 0)
+            s = jnp.where(mask, s, jnp.finfo(jnp.float32).min)
+            p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+            out = jnp.einsum("bhst,bthd->bshd", p, v,
+                             preferred_element_type=q.dtype)
+        else:
+            if block_table is None or jnp.ndim(cache_offset) != 1:
+                raise ValueError(
+                    "the mla mixer is served through the page pool (block "
+                    "tables, per-slot offsets)")
+            (pool,) = kv_cache
+            NP, page, R = pool.shape
+            with jax.named_scope("latent_write"):
+                from neuronx_distributed_tpu.ops.kv_pool_write import (
+                    write_pool_rows,
+                )
+
+                row = jnp.concatenate(
+                    [ckv, k_rope.astype(ckv.dtype),
+                     jnp.zeros((B, S, R - rank - dr), ckv.dtype)], axis=-1)
+                phys, in_off = _latent_cells(cache_offset, block_table,
+                                             kv_valid, S, page, NP)
+                # the K/V pool's writer, a latent row as one kv head's
+                pool = write_pool_rows(pool[:, None], row[:, :, None], phys,
+                                       in_off, kernel=paged_kernel)[:, 0]
+            new_cache = (pool,)
+            kv_start = (None if kv_valid is None else jnp.argmax(
+                jnp.asarray(kv_valid) > 0, axis=1).astype(jnp.int32))
+            attend = la.latent_attention if paged_kernel \
+                else la.latent_attention_reference
+            if S >= MLA_EXPANDED_MIN_ROWS:
+                out = attend(
+                    jnp.concatenate([q_nope, q_rope], axis=-1), pool,
+                    block_table, cache_offset, kv_start, rank=rank,
+                    sm_scale=scale, w_kv=(wk.transpose(1, 0, 2),
+                                          wv.transpose(1, 0, 2)))
+            else:
+                with jax.named_scope("mla_absorb"):
+                    q_abs = jnp.einsum("bshd,rhd->bshr", q_nope, wk)
+                o_lat = attend(
+                    jnp.concatenate([q_abs, q_rope], axis=-1), pool,
+                    block_table, cache_offset, kv_start, rank=rank,
+                    sm_scale=scale)
+                with jax.named_scope("mla_absorb"):
+                    out = jnp.einsum("bshr,rhd->bshd", o_lat, wv)
+        return RowParallelLinear(
+            features=cfg.hidden_size, name="o_proj",
+            input_partition_axes=Q_HEAD_AXES, **lin)(
+            out.reshape(B, S, NH * dv)), new_cache
+
+
 def hybrid_mixer(cfg, kind: str):
+    if kind == "mla":
+        return MLAMixer(cfg, name="attn")
     if kind == "mamba2":
         return Mamba2Mixer(cfg, name="attn")
     if kind == "lightning-attn":

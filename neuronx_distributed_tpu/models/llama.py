@@ -210,6 +210,36 @@ class LlamaConfig:
     sparse_window_size: int = 2048
     sparse_topk: int = 64
     sparse_dense_len: int = 8192
+    # latent attention (MLA, DeepSeek-V2; mixer "mla", models/hybrid.py):
+    # queries through a normed bottleneck of q_lora_rank, keys and values
+    # through ONE normed latent of kv_lora_rank a token beside one shared
+    # RoPE key of qk_rope_head_dim — all the pool keeps; a head is
+    # qk_nope_head_dim + qk_rope_head_dim wide for scores, v_head_dim out
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN RoPE scaling (HF ``rope_scaling={"type": "yarn", ...}``):
+    # factor > 1 turns it on (yarn_inv_freq, yarn_mscale)
+    rope_yarn_factor: float = 1.0
+    rope_yarn_original_max_seq: int = 4096
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_mscale: float = 1.0
+    rope_yarn_mscale_all_dim: float = 0.0
+    # hyper-connected residual (arXiv:2409.19606, mHC arXiv:2512.24880):
+    # hc_mult > 1 widens the residual to that many streams, read and
+    # written by each sublayer through per-token maps (HyperConnection);
+    # the residual map is made doubly stochastic by hc_sinkhorn_iters
+    # sweeps of its clamped exponential
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
+    # an expert's width where it is not the dense layers' (a model whose
+    # ffn_types names "mlp" and "moe" layers); 0: intermediate_size
+    moe_intermediate_size: int = 0
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
@@ -242,6 +272,9 @@ class LlamaConfig:
                     self.mixer_types or (), self.ffn_types)):
                 raise ValueError("a layer with no mixer and no "
                                  "feed-forward part is no layer")
+        object.__setattr__(self, "hc_res_clamp", tuple(self.hc_res_clamp))
+        if self.hc_mult > 1 and self.scan_layers:
+            raise ValueError("hc_mult > 1 is not carried through scan_layers")
         if self.moe_experts_held is not None:
             first, count = self.moe_experts_held
             object.__setattr__(self, "moe_experts_held",
@@ -278,9 +311,10 @@ class LlamaConfig:
     def layer_caches(self) -> Optional[Tuple[str, ...]]:
         """``"pages"`` (K/V pages), ``"selected_pages"`` (K/V pages the
         layer chooses among, with compressed keys beside them), ``"state"``
-        (a fixed-size recurrent state row) or ``"none"`` (a layer without a
-        mixer keeps nothing) a layer; None without a layer list: every
-        layer keeps pages."""
+        (a fixed-size recurrent state row), ``"latent"`` (pages of one
+        latent row a token) or ``"none"`` (a layer without a mixer keeps
+        nothing) a layer; None without a layer list: every layer keeps
+        pages."""
         if self.mixer_types is None:
             return None
         from neuronx_distributed_tpu.models.hybrid import CACHE_OF
@@ -315,6 +349,22 @@ class LlamaConfig:
         return state_arrays(self, self.mixer(self.recurrent_layers[0]))
 
     @property
+    def latent_layers(self) -> Tuple[int, ...]:
+        """Layers that keep pages of ONE latent row a token (MLA)."""
+        return self._layers_keeping("latent")
+
+    @property
+    def latent_row_dim(self) -> int:
+        """Columns of a stored latent row: the latent, then the shared RoPE
+        key, padded with zeros to whole 128-lane tiles (``ops.
+        latent_attention.row_dim``); 0 without a latent layer."""
+        if not self.latent_layers:
+            return 0
+        from neuronx_distributed_tpu.ops.latent_attention import row_dim
+
+        return row_dim(self.kv_lora_rank, self.qk_rope_head_dim)
+
+    @property
     def selection_spec(self):
         """The selecting layers' ``ops.block_select.SparseSpec``; None
         where no layer selects."""
@@ -325,8 +375,21 @@ class LlamaConfig:
         return sparse_spec(self)
 
     @property
+    def moe_intermediate_size_(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
     def rope_scaling_(self):
-        """``(factor, low, high, original_max_seq)`` or None when off."""
+        """``(factor, low, high, original_max_seq)`` (Llama-3.1), ``("yarn",
+        factor, original_max_seq, beta_fast, beta_slow, mscale ratio)`` or
+        None when off."""
+        if self.rope_yarn_factor > 1.0:
+            return ("yarn", self.rope_yarn_factor,
+                    self.rope_yarn_original_max_seq, self.rope_yarn_beta_fast,
+                    self.rope_yarn_beta_slow,
+                    yarn_mscale(self.rope_yarn_factor, self.rope_yarn_mscale)
+                    / yarn_mscale(self.rope_yarn_factor,
+                                  self.rope_yarn_mscale_all_dim))
         if self.rope_scaling_factor == 1.0:
             return None
         return (self.rope_scaling_factor, self.rope_scaling_low_freq_factor,
@@ -441,16 +504,65 @@ def llama3_scale_freqs(
     return jnp.where(wavelen < high_wl, inv_freq, scaled)
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature term ``0.1 mscale ln(factor) + 1`` (1
+    where the factor does not stretch).  cos and sin are multiplied by the
+    ratio of two of them (``mscale`` over ``mscale_all_dim``), and a model
+    whose ``mscale_all_dim`` is set multiplies its softmax scale by the
+    square of that one (DeepSeek-V2's reading of YaRN)."""
+    import math
+
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1.0 else 1.0
+
+
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  original_max_seq: int, beta_fast: float,
+                  beta_slow: float):
+    """YaRN's inverse frequencies (arXiv:2309.00071, "NTK-by-parts"): each
+    pair's own ``theta^(-2i/d)`` where it turns more than ``beta_fast`` times
+    in ``original_max_seq`` positions, that over ``factor`` where it turns
+    fewer than ``beta_slow`` times, and a linear ramp over the pair index
+    between the two (the bounds floored and ceiled to whole pairs).
+    Computed on the host in float64 and rounded once: a power taken in
+    float32 on the device is off by ~1e-6 of its value, 0.03 rad at
+    position 32,768 — 2.7% of a RoPE key read back from the pool there
+    (PERF.md, PR 36)."""
+    import math
+
+    import numpy as np
+
+    def pair_turning(turns):
+        return (head_dim * math.log(original_max_seq / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_turning(beta_fast)), 0)
+    high = min(math.ceil(pair_turning(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    i = np.arange(head_dim // 2, dtype=np.float64)
+    own = theta ** (-2.0 * i / head_dim)
+    keep = 1.0 - np.clip((i - low) / (high - low), 0.0, 1.0)
+    return jnp.asarray(own / factor * (1.0 - keep) + own * keep, jnp.float32)
+
+
 def rope_sin_cos(positions: jax.Array, head_dim: int, theta: float,
                  scaling=None) -> Tuple[jax.Array, jax.Array]:
     """RoPE tables in fp32 for the given positions ``[...s]`` →
     ``(sin, cos)`` of shape ``[..., s, head_dim/2]``.  ``scaling`` is the
     optional Llama-3.1 tuple ``(factor, low_freq_factor, high_freq_factor,
-    original_max_seq)``."""
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
-    if scaling is not None:
-        inv_freq = llama3_scale_freqs(inv_freq, *scaling)
+    original_max_seq)`` or YaRN's ``("yarn", factor, original_max_seq,
+    beta_fast, beta_slow, mscale ratio)`` (``LlamaConfig.rope_scaling_``)."""
+    mscale = 1.0
+    if scaling is not None and scaling[0] == "yarn":
+        inv_freq = yarn_inv_freq(head_dim, theta, *scaling[1:5])
+        mscale = scaling[5]
+    else:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+        if scaling is not None:
+            inv_freq = llama3_scale_freqs(inv_freq, *scaling)
     angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    if mscale != 1.0:
+        return jnp.sin(angles) * mscale, jnp.cos(angles) * mscale
     return jnp.sin(angles), jnp.cos(angles)
 
 
@@ -872,6 +984,105 @@ def _residual(x, h, scale: float):
             + scale * h.astype(jnp.float32)).astype(x.dtype)
 
 
+def sinkhorn(z: jax.Array, iters: int, eps: float) -> jax.Array:
+    """``[..., n, n]`` float32 logits -> a doubly stochastic matrix each:
+    ``exp``, then ``iters`` sweeps of columns over their sums and rows over
+    theirs (rows last, so a row sums to 1 to ``eps`` and a column to what
+    the last sweep left).  Float32 whatever the streams are.  Plain XLA
+    operations on the chip too: 81 fusions a sublayer, 2.07% of the serving
+    cell's busy time where one Pallas call for the sweeps read 1.70% and a
+    step no shorter (PERF.md, PR 36), so there is no kernel."""
+    m = jnp.exp(z.astype(jnp.float32))
+    for _ in range(iters):
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+    return m
+
+
+def hc_read(x, pre):
+    """What a sublayer reads of the streams ``x [B, n, S, C]``: ``sum_i
+    pre[..., i] x[:, i]``, accumulated in float32, rounded once."""
+    u = sum(pre[..., i, None] * x[:, i].astype(jnp.float32)
+            for i in range(x.shape[1]))
+    return u.astype(x.dtype)
+
+
+def hc_write(x, y, post, res):
+    """The streams after a sublayer: ``x'[:, i] = sum_j res[..., i, j] x[:,
+    j] + post[..., i] y``, float32, rounded once.  With one stream and maps
+    of 1 it is ``x + y``."""
+    n = x.shape[1]
+    xf, yf = x.astype(jnp.float32), y.astype(jnp.float32)
+    return jnp.stack(
+        [sum(res[..., i, j, None] * xf[:, j] for j in range(n))
+         + post[..., i, None] * yf for i in range(n)], axis=1).astype(x.dtype)
+
+
+# a seeded residual map starts at softmax-like weights exp(HC_RES_DIAG) on
+# the diagonal against 1 beside it (0.83 on the diagonal of 4 streams after
+# the sweeps), and a seeded phi moves every map's logit by about +-HC_PHI_STD
+# a token, so that a check of a seeded model sees all three maps at work
+HC_RES_DIAG = 3.0
+HC_PHI_STD = 0.5
+
+
+class HyperConnection(nn.Module):
+    """One sublayer's maps over the ``n = hc_mult`` residual streams ``x [B,
+    n, S, C]`` (streams before rows: a second-minor axis of 4 would pad
+    every tile of the streams fourfold), computed a token in float32 from
+    the streams as they are stored: ``m = (flat(x) phi) rsqrt(mean(flat(x)^2)
+    + eps)``, ``pre = sigmoid(a_pre m[:n] + b[:n])``, ``post = 2 sigmoid(a_post
+    m[n:2n] + b[n:2n])``, ``res = sinkhorn(clip(a_res mat(m[2n:]) + b[2n:]))``.
+    Returns ``(what the sublayer reads [B, S, C], post [B, S, n], res [B, S,
+    n, n])``; :func:`hc_write` makes the streams the sublayer leaves."""
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        B, n, S, C = x.shape
+        f32 = jnp.float32
+        k = n * n + 2 * n
+
+        def phi_init(key, shape, dtype):
+            return (jax.random.normal(key, shape, f32)
+                    * (HC_PHI_STD / (n * C) ** 0.5)).astype(dtype)
+
+        def b_init(key, shape, dtype):
+            import math
+
+            # the sublayer reads the streams' mean, adds its output to each
+            # stream once, and leaves each stream mostly to itself
+            return jnp.concatenate([
+                jnp.full((n,), -math.log(n - 1.0)),
+                jnp.zeros((n,)),
+                (HC_RES_DIAG * jnp.eye(n)).reshape(-1)]).astype(dtype)
+
+        small = lambda name, init, shape: jnp.asarray(self.param(  # noqa: E731
+            name, nn.with_partitioning(init, (None,) * len(shape)), shape,
+            f32))
+        phi = small("phi", phi_init, (n, C, k))
+        b = small("b", b_init, (k,))
+        a_pre, a_post, a_res = (small(a, nn.initializers.ones, ())
+                                for a in ("a_pre", "a_post", "a_res"))
+        with jax.named_scope("hc_maps"):
+            xf = x.astype(f32)
+            m = jnp.einsum("bnsc,nck->bsk", xf, phi,
+                           precision=jax.lax.Precision.HIGHEST)
+            m = m * jax.lax.rsqrt(
+                jnp.mean(jnp.square(xf), axis=(1, 3))[..., None] + cfg.hc_eps)
+            pre = jax.nn.sigmoid(a_pre * m[..., :n] + b[:n])
+            post = 2.0 * jax.nn.sigmoid(a_post * m[..., n:2 * n] + b[n:2 * n])
+            z = jnp.clip(a_res * m[..., 2 * n:] + b[2 * n:],
+                         *cfg.hc_res_clamp).reshape(B, S, n, n)
+        with jax.named_scope("hc_sinkhorn"):
+            res = sinkhorn(z, cfg.hc_sinkhorn_iters, cfg.hc_eps)
+        with jax.named_scope("hc_mix"):
+            u = hc_read(x, pre)
+        return u, post, res
+
+
 class LlamaBlock(nn.Module):
     config: LlamaConfig
     mixer: str = "attention"
@@ -886,9 +1097,16 @@ class LlamaBlock(nn.Module):
         # a layer without a mixer keeps nothing: its pool entry, () when
         # cached, goes back as it came
         new_cache = kv_cache
+        # hc_mult > 1: x is the n residual streams [B, n, S, C]; a sublayer
+        # reads them through its own maps and writes them back (the default,
+        # one stream, leaves the block as it was built before there were any)
+        hc = cfg.hc_mult > 1
         if self.mixer != "none":
+            u = x
+            if hc:
+                u, post, res = HyperConnection(cfg, name="attn_hc")(x)
             normed = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
-                             param_dtype=cfg.param_dtype, name="input_norm")(x)
+                             param_dtype=cfg.param_dtype, name="input_norm")(u)
             if self.mixer == "attention":
                 h, new_cache = LlamaAttention(cfg, name="attn")(
                     normed, positions, kv_cache, cache_offset, kv_valid,
@@ -904,12 +1122,15 @@ class LlamaBlock(nn.Module):
                 h, new_cache = hybrid_mixer(cfg, self.mixer)(
                     normed, positions, kv_cache, cache_offset, kv_valid,
                     block_table, paged_kernel, state_rows)
-            x = _residual(x, h, cfg.residual_scale)
+            x = self._add(x, h, *((post, res) if hc else ()))
         ffn = self.ffn or ("moe" if cfg.num_experts > 1 else "mlp")
         if ffn == "none":
             return self._out(x), new_cache
+        u = x
+        if hc:
+            u, post, res = HyperConnection(cfg, name="ffn_hc")(x)
         normed = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                         name="post_attn_norm")(x)
+                         name="post_attn_norm")(u)
         if ffn == "moe":
             from neuronx_distributed_tpu.parallel.moe import (
                 ExpertParallelMLP,
@@ -942,7 +1163,7 @@ class LlamaBlock(nn.Module):
                              else cfg.moe_local_experts or cfg.num_experts),
                 num_experts_global=(cfg.num_experts if held is not None
                                     or cfg.moe_local_experts else 0),
-                intermediate_size=cfg.intermediate_size,
+                intermediate_size=cfg.moe_intermediate_size_,
                 top_k=cfg.moe_top_k,
                 capacity_factor=cfg.moe_capacity_factor,
                 dispatch="dropless" if dropless else cfg.moe_dispatch,
@@ -964,11 +1185,19 @@ class LlamaBlock(nn.Module):
             self.sow("losses", "moe_aux", aux)
         else:
             h = LlamaMLP(cfg, name="mlp")(normed)
-        x = _residual(x, h, cfg.residual_scale)
+        x = self._add(x, h, *((post, res) if hc else ()))
         return self._out(x), new_cache
 
+    def _add(self, x, h, post=None, res=None):
+        """A sublayer's output into the residual: ``x + h`` (scaled where
+        the config scales its branches), or into the streams by its maps."""
+        if post is None:
+            return _residual(x, h, self.config.residual_scale)
+        with jax.named_scope("hc_mix"):
+            return hc_write(x, h, post, res)
+
     def _out(self, x):
-        if self.config.sequence_parallel:
+        if self.config.sequence_parallel and x.ndim == 3:
             # residual stream lives sequence-sharded between blocks
             x = shard_activation(x, trailing_spec(x.ndim, seq=SEQUENCE_AXES, last=None))
         return x
@@ -1021,6 +1250,10 @@ class LlamaModel(nn.Module):
         )(ids)
         if cfg.embed_scale != 1.0:
             h = h * jnp.asarray(cfg.embed_scale, h.dtype)
+        if cfg.hc_mult > 1:
+            # every stream starts as the embedding (Hyper-Connections' fan-out)
+            h = jnp.broadcast_to(h[:, None], (h.shape[0], cfg.hc_mult)
+                                 + h.shape[1:])
         if cfg.mixer_types is not None and cfg.scan_layers:
             raise ValueError("scan_layers traces ONE block: a model with "
                              "mixer_types has several kinds")
@@ -1071,6 +1304,10 @@ class LlamaModel(nn.Module):
                     h, c = block_cls(cfg, name=f"layer_{i}", **kind)(
                         h, positions, None, 0, kv_valid, segment_ids)
                 new_caches.append(c)
+        if cfg.hc_mult > 1:
+            # the read-out: the streams' sum, float32, rounded once
+            with jax.named_scope("hc_mix"):
+                h = jnp.sum(h.astype(jnp.float32), axis=1).astype(h.dtype)
         h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="final_norm")(h)
         if cfg.logit_scale != 1.0:
             h = h * jnp.asarray(cfg.logit_scale, h.dtype)

@@ -638,7 +638,14 @@ class ServingEngine:
         self._sparse_spec = getattr(mcfg, "selection_spec", None)
         # Mamba-2 layers: a decode steps one state row a live slot a layer
         self._ssm = "mamba2" in (getattr(mcfg, "mixer_types", None) or ())
-        if self._recurrent or self._sparse_spec is not None:
+        # latent layers (MLA) keep pages of ONE latent row a token: pages
+        # as any other to the allocator and the prefix index, which STAYS
+        # on (a shared page holds the same rows whoever wrote it), but the
+        # programs carry neither a verify chunk, an int8 row, LoRA deltas
+        # nor a head axis to shard through them yet
+        self._latent_layers = len(getattr(mcfg, "latent_layers", ()))
+        if self._recurrent or self._sparse_spec is not None \
+                or self._latent_layers:
             from neuronx_distributed_tpu.parallel.mesh import (
                 TENSOR_AXIS,
                 get_mesh,
@@ -655,9 +662,10 @@ class ServingEngine:
                  and get_mesh().shape[TENSOR_AXIS] > 1)) if on]
             if refused:
                 raise ValueError(
-                    "not carried through recurrent or page-selecting layers "
-                    "yet: " + "; ".join(refused))
-            prefix_cache = False
+                    "not carried through recurrent, page-selecting or "
+                    "latent layers yet: " + "; ".join(refused))
+            if self._recurrent or self._sparse_spec is not None:
+                prefix_cache = False
         if spec_k:
             # the draft keeps a contiguous [B, T] row a slot (see
             # _prefill_draft_row): the one user of these phase functions
@@ -1140,6 +1148,10 @@ class ServingEngine:
             raise TransferError(
                 "KV migration moves page chains: a model with recurrent "
                 "state rows or block-sparse page layouts has none to move")
+        if self._latent_layers:
+            raise TransferError(
+                "KV migration moves K/V page chains: chains of latent pages "
+                "are not carried through export and import yet")
 
     @property
     def has_work(self) -> bool:
@@ -1420,6 +1432,7 @@ class ServingEngine:
         lens = np.asarray([int(offs[slot]) - self.C + req.prompt_len + 1
                            for slot, req in active])
         self._account.rows = len(active)
+        self._count_latents("decode_pages", int(lens.sum()), len(active))
         with self._phase("dispatch", active=len(active),
                          ctx_tokens=int(lens.sum()) - len(active),
                          **self._count_selection("decode_pages", lens - 1,
@@ -1853,6 +1866,9 @@ class ServingEngine:
                 # end in the left-padded row less the pad
                 ctx = off + n_pages * page - (self.C - req.prompt_len)
                 self._account.chunk = n_pages * page
+                self._count_latents(
+                    "prefill_chunk_pages", ctx,
+                    ctx - max(off - (self.C - req.prompt_len), 0))
                 with self._phase(
                         "prefill_chunk", request_id=req.request_id,
                         tok_start=off, width=n_pages * page, ctx_tokens=ctx,
@@ -2067,6 +2083,31 @@ class ServingEngine:
         else:
             tokens = 0
         return {"selected_tokens": int(tokens)}
+
+    def _count_latents(self, family: str, visible: int, rows: int) -> None:
+        """What the coming program's latent layers read and write, a layer,
+        from the host offsets: the latent rows its queries attend
+        (``visible``: each live slot's context for a decode, the keys the
+        last row sees for a chunk: ``serving/latent_tokens_read_total``,
+        also by program family), those of them a chunk up-projects to keys
+        and values (the expanded path expands what it reads, once a chunk;
+        a decode, absorbed, none: ``serving/latent_tokens_expanded_total``)
+        and the rows it commits (``kvcache/latent_rows_written_total``,
+        also by family).  Nothing for a model without latent layers."""
+        if not self._latent_layers:
+            return
+        from neuronx_distributed_tpu.models.hybrid import (
+            MLA_EXPANDED_MIN_ROWS,
+        )
+
+        reg = self.registry
+        for name, n in (("serving/latent_tokens_read_total", visible),
+                        ("kvcache/latent_rows_written_total", rows)):
+            reg.counter(name).inc(n)
+            reg.counter(f"{name}/{family}").inc(n)
+        if family == "prefill_chunk_pages" \
+                and self._chunk_tokens >= MLA_EXPANDED_MIN_ROWS:
+            reg.counter("serving/latent_tokens_expanded_total").inc(visible)
 
     def _count_kv_write(self, rows: int, pages: int) -> None:
         """What the coming program commits to the page pool, a layer: the

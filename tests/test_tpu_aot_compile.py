@@ -458,3 +458,61 @@ def test_nemotron_serve_programs_fit_a_v5e_and_copy_no_state(
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert weights + pool < total < 14 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def xing4_programs(topo):
+    """Both serve programs of the benchmark's Xing4.0 configuration
+    (``benchmarks/tools/xing4_aot.py``: every layer, published widths, 8
+    slots of 33,280 tokens), compiled once for the two cases below."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from benchmarks.harness import manifest
+    from benchmarks.tools import xing4_aot
+
+    prev = jax.config.jax_enable_compilation_cache
+    try:
+        cell = manifest.Cell("xing4.0-29b-a4b.serve-longdocs")
+        programs, weights, pool, shapes, _ = \
+            xing4_aot.compile_serve_programs(cell)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+    return dict(programs), weights, pool, shapes
+
+
+@pytest.mark.parametrize("program", ["paged decode", "paged chunk prefill"])
+def test_xing4_serve_programs_fit_a_v5e_and_copy_no_pool(
+        xing4_programs, program):
+    """7 layers at the PUBLISHED widths (latent attention 32 heads of 128 +
+    64 over a latent of 512, four residual streams, a dense layer of 9216
+    and 64 experts of 1024 with a shared one), 8 slots of 33,280 tokens, a
+    512-row chunk: the latent walk (absorbed for a decode, expanded for a
+    chunk), the pool writer and the grouped matmuls are Mosaic calls (the
+    Sinkhorn sweeps are XLA's); the pages of latents are donated and aliased; nothing
+    shaped like the pool or like a slot's expanded keys is copied; all of it
+    under 14.5 GiB."""
+    import re
+
+    programs, weights, pool, shapes = xing4_programs
+    compiled = programs[program]
+    text = compiled.as_text()
+    kernel = ("latent_attention_decode" if program == "paged decode"
+              else "latent_attention_chunk")
+    assert text.count(f"%{kernel}") >= 7 and "%gmm" in text
+    assert "%paged_attention" not in text
+    assert text.count("%kv_pool_write") >= 7
+    assert "%hc_sinkhorn" not in text
+    assert shapes[0].shape == (4161, 64, 640)      # 1,280 bytes a token
+    for s in shapes:
+        shape = f"bf16[{','.join(map(str, s.shape))}]"
+        copied = [ln.strip()[:120] for ln in text.splitlines()
+                  if re.search(rf"= {re.escape(shape)}\S* (copy|transpose)\(",
+                               ln) and "fused_computation" not in ln]
+        assert not copied, f"{shape} is copied: {copied}"
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= pool
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert weights + pool < total < 14.5 * 2 ** 30
+    assert weights > 10.3 * 2 ** 30 and pool > 2.2 * 2 ** 30
