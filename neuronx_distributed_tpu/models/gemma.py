@@ -127,7 +127,7 @@ class GemmaForCausalLM(nn.Module):
         self.embed = ParallelEmbedding(
             num_embeddings=cfg.vocab_size,
             features=cfg.hidden_size,
-            # SP entry constraint applied per-phase in _backbone (decode
+            # SP entry constraint applied per-phase in backbone (decode
             # keeps the sequence unsharded)
             sequence_parallel_output=False,
             dtype=cfg.dtype,
@@ -142,9 +142,12 @@ class GemmaForCausalLM(nn.Module):
         self.final_norm = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
                                   param_dtype=cfg.param_dtype)
 
-    def _backbone(self, ids, positions, kv_caches, cache_offset, kv_valid,
-                  segment_ids, block_table=None, adapters=None,
-                  paged_kernel=False):
+    @nn.nowrap  # no scope of its own, as LlamaForCausalLM.backbone
+    def backbone(self, ids, positions=None, kv_caches=None, cache_offset=0,
+                 kv_valid=None, segment_ids=None, block_table=None,
+                 adapters=None, paged_kernel=False):
+        """Everything of :meth:`__call__` but the head: ``(final-norm hidden
+        states [B, S, H], new caches)`` (``LlamaForCausalLM.backbone``)."""
         cfg = self.config
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
@@ -173,7 +176,7 @@ class GemmaForCausalLM(nn.Module):
     def __call__(self, ids, positions=None, kv_caches=None, cache_offset=0,
                  kv_valid=None, segment_ids=None, block_table=None,
                  adapters=None, paged_kernel=False):
-        h, new_caches = self._backbone(
+        h, new_caches = self.backbone(
             ids, positions, kv_caches, cache_offset, kv_valid, segment_ids,
             block_table, adapters, paged_kernel)
         logits = self.embed.attend(h)
@@ -182,7 +185,7 @@ class GemmaForCausalLM(nn.Module):
     def hidden(self, ids, positions=None, kv_valid=None, segment_ids=None):
         """Backbone only: final-norm hidden states ``[B, S, H]`` — the input
         the chunked loss head consumes."""
-        h, _ = self._backbone(ids, positions, None, 0, kv_valid, segment_ids)
+        h, _ = self.backbone(ids, positions, None, 0, kv_valid, segment_ids)
         return h
 
     def head(self, h):
@@ -344,9 +347,12 @@ class Gemma2ForCausalLM(nn.Module):
         self.final_norm = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
                                   param_dtype=cfg.param_dtype)
 
-    def _backbone(self, ids, positions, kv_caches, cache_offset, kv_valid,
-                  segment_ids, block_table=None, adapters=None,
-                  paged_kernel=False):
+    @nn.nowrap  # no scope of its own, as LlamaForCausalLM.backbone
+    def backbone(self, ids, positions=None, kv_caches=None, cache_offset=0,
+                 kv_valid=None, segment_ids=None, block_table=None,
+                 adapters=None, paged_kernel=False):
+        """Everything of :meth:`__call__` but the head: ``(final-norm hidden
+        states [B, S, H], new caches)`` (``LlamaForCausalLM.backbone``)."""
         cfg = self.config
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(ids.shape[1]), ids.shape)
@@ -380,14 +386,14 @@ class Gemma2ForCausalLM(nn.Module):
     def __call__(self, ids, positions=None, kv_caches=None, cache_offset=0,
                  kv_valid=None, segment_ids=None, block_table=None,
                  adapters=None, paged_kernel=False):
-        h, new_caches = self._backbone(
+        h, new_caches = self.backbone(
             ids, positions, kv_caches, cache_offset, kv_valid, segment_ids,
             block_table, adapters, paged_kernel)
         logits = self._logits(h)
         return (logits, new_caches) if kv_caches is not None else logits
 
     def hidden(self, ids, positions=None, kv_valid=None, segment_ids=None):
-        h, _ = self._backbone(ids, positions, None, 0, kv_valid, segment_ids)
+        h, _ = self.backbone(ids, positions, None, 0, kv_valid, segment_ids)
         return h
 
     def head(self, h):

@@ -1349,6 +1349,20 @@ class LlamaForCausalLM(nn.Module):
     def __call__(self, ids, positions=None, kv_caches=None, cache_offset=0,
                  kv_valid=None, segment_ids=None, block_table=None,
                  adapters=None, paged_kernel=False, state_rows=None):
+        h, new_caches = self.backbone(
+            ids, positions, kv_caches, cache_offset, kv_valid, segment_ids,
+            block_table, adapters, paged_kernel, state_rows)
+        logits = self.lm_head(h)
+        return (logits, new_caches) if kv_caches is not None else logits
+
+    @nn.nowrap  # no scope of its own: __call__'s name stacks stay as they were
+    def backbone(self, ids, positions=None, kv_caches=None, cache_offset=0,
+                 kv_valid=None, segment_ids=None, block_table=None,
+                 adapters=None, paged_kernel=False, state_rows=None):
+        """Everything of :meth:`__call__` but the head, with its arguments:
+        ``(final-norm hidden states [B, S, H], new caches)``.  A caller that
+        reads some rows' logits picks them here and gives :meth:`head` those
+        (the serving programs, ``trace/engine.py``)."""
         h, new_caches = self.model(
             ids, positions, kv_caches, cache_offset, kv_valid, segment_ids,
             block_table, adapters, paged_kernel,
@@ -1356,16 +1370,13 @@ class LlamaForCausalLM(nn.Module):
         if self.config.sequence_parallel and kv_caches is None:
             # gather the sequence back before the (batched) head matmul
             h = shard_activation(h, trailing_spec(h.ndim, seq=None, last=None))
-        logits = self.lm_head(h)
-        return (logits, new_caches) if kv_caches is not None else logits
+        return h, new_caches
 
     def hidden(self, ids, positions=None, kv_valid=None, segment_ids=None):
         """Backbone only: final-norm hidden states ``[B, S, H]`` with the
         sequence gathered back from SP — the input the chunked loss head
         (``models.common.make_causal_lm_loss_sum``) consumes."""
-        h, _ = self.model(ids, positions, None, 0, kv_valid, segment_ids)
-        if self.config.sequence_parallel:
-            h = shard_activation(h, trailing_spec(h.ndim, seq=None, last=None))
+        h, _ = self.backbone(ids, positions, None, 0, kv_valid, segment_ids)
         return h
 
     def head(self, h):

@@ -131,8 +131,8 @@ SHED_EXPIRED_BEFORE_PREFILL = "expired_before_prefill"
 class _ChunkPrefill:
     """Per-slot progress of a paged chunked prefill: the admission-time
     prompt row and validity, the contiguous run of fresh ``(logical,
-    physical)`` pages still to compute, and the last chunk's logits (the
-    final chunk's are the prefill logits the first token samples from)."""
+    physical)`` pages still to compute, and the FINAL chunk's logits (the
+    prefill logits the first token samples from; no other chunk has any)."""
 
     __slots__ = ("req", "ids_row", "valid_row", "fresh", "next_i", "logits")
 
@@ -1907,10 +1907,13 @@ class ServingEngine:
         program is always ``prefill_chunk_tokens`` wide: a shorter span (a
         prompt's tail) is right-padded, and the logits are read at the
         span's last row.  The pad rows lie past the prompt's end — invalid
-        cells, which the model's scatter never commits."""
+        cells, which the model's scatter never commits.  Only the prompt's
+        LAST chunk asks for logits (one row of the head); the others ask
+        for none and ``st.logits`` stays unset."""
         page = self._kv.page_size
         off = st.fresh[st.next_i][0] * page
         width = n_pages * page
+        last = n_pages == st.pages_remaining
         ids_chunk = np.zeros((1, self._chunk_tokens), np.int32)
         ids_chunk[0, :width] = st.ids_row[off:off + width]
         # the chunk commits its valid cells (a first page may lead with pads)
@@ -1942,6 +1945,7 @@ class ServingEngine:
                 self._kv.tables[slot][None, :].copy(), self.caches,
                 st.valid_row[None, :].copy(), apool=ad[0], atables=ad[1],
                 paged_kernel=self._paged_kernel, last_row=width - 1,
+                want_logits=last,
                 **({"state_row": slot} if self._recurrent else {}))
         except BaseException as e:
             if t0 is not None:
@@ -1970,13 +1974,14 @@ class ServingEngine:
         if self._kv_quant is not None:
             # the chunk's page-aligned writes each requantized their page
             self.registry.counter(QUANT_PAGES_TOTAL).inc(n_pages)
-        if st.pages_remaining == 0:
+        if last:
             # the prefill logits the first token will sample from
-            logits = perturb("serving/prefill_logits", logits,
-                             request_id=st.req.request_id,
-                             engine_step=self._steps)
-        st.logits = logits
+            st.logits = perturb("serving/prefill_logits", logits,
+                                request_id=st.req.request_id,
+                                engine_step=self._steps)
         self.registry.counter("serving/prefill_chunks_total").inc()
+        self.registry.counter(
+            "serving/head_rows_total/prefill_chunk_pages").inc(int(last))
 
     def _preempt_for_priority(self, now: float) -> None:
         """Park batch-tier victims while the scheduler says the interactive
@@ -2357,6 +2362,8 @@ class ServingEngine:
                 tok, offs_dev, self._tables_dev, self.caches, self.valid,
                 paged_kernel=self._paged_kernel)
         self._count_gather_step()
+        self.registry.counter(
+            "serving/head_rows_total/decode_pages").inc(self.B)
         if self._ssm:
             self.registry.counter(
                 "serving/ssm_state_rows_stepped_total").inc(len(active))
@@ -2463,6 +2470,8 @@ class ServingEngine:
             chunk, offs, self._tables_dev, self.caches, self.valid,
             apool=ad[0], atables=ad[1], paged_kernel=self._paged_kernel)
         self._count_gather_step()
+        self.registry.counter(
+            "serving/head_rows_total/verify_pages").inc(self.B * (k + 1))
         if self._kv_quant is not None:
             # every active slot's k+1-token verify write requantized the
             # page(s) its chunk straddles — book them honestly

@@ -777,6 +777,42 @@ class ParallelInferenceModel(_ServingBase):
 
     # -- phase functions (pure; also used by the export path) --------------
 
+    def _apply_row(self, params, ids, *args, row=None, mutable=False, **kw):
+        """``module.apply`` for a caller that reads ONE row's logits: the
+        backbone runs whole and the head gets that row of its final-norm
+        hidden states (``[B, S, H]`` -> ``[B, 1, H]``), chosen BEFORE the
+        matmul — the compiler cannot sink a traced slice through it, and a
+        512-row chunk would pay the whole ``[S, vocab]`` product for a row.
+        ``row`` is a traced scalar, or ``None`` for the last row; a ``row``
+        below 0 says that NO row is read: the head is not run and the logits
+        are zeros.  At ``S = 1`` the choice is the identity and the module
+        is applied whole (the decode programs are what they were).  Returns
+        ``(logits [B, V], caches, collections or None)``."""
+        def apply(**how):
+            out = self.module.apply(params, ids, *args, mutable=mutable,
+                                    **how, **kw)
+            return out if mutable else (out, None)
+
+        if ids.shape[1] == 1:
+            (logits, caches), stats = apply()
+            return logits[:, -1, :], caches, stats
+        # the scope flax gives ``__call__``: a layer's name stack (what a
+        # trace is read by) is the same whichever way its program is built
+        with jax.named_scope(type(self.module).__name__):
+            (h, caches), stats = apply(method="backbone")
+
+        def head():
+            h_row = (h[:, -1:, :] if row is None else
+                     jax.lax.dynamic_slice_in_dim(h, row, 1, axis=1))
+            return self.module.apply(params, h_row, method="head")[:, 0, :]
+
+        if row is None:
+            return head(), caches, stats
+        blank = jax.eval_shape(head)
+        logits = jax.lax.cond(
+            row >= 0, head, lambda: jnp.zeros(blank.shape, blank.dtype))
+        return logits, caches, stats
+
     def _context_fn(self, params, ids, valid, adapters=None):
         """Prefill; ``valid [B, C]`` marks real (non-left-pad) prompt tokens.
         Positions come from the mask (a token's position = count of valid
@@ -794,10 +830,10 @@ class ParallelInferenceModel(_ServingBase):
             self.head_dim, self.config.kv_cache_dtype,
         )
         extra = {} if adapters is None else {"adapters": adapters}
-        logits, caches = self.module.apply(
+        logits, caches, _ = self._apply_row(
             params, ids, positions, caches, 0, kv_valid=kv_valid, **extra
         )
-        return logits[:, -1, :], caches
+        return logits, caches
 
     def _decode_step_traceable(self, params, tok, offset, caches, valid):
         return self._decode_fn(params, tok, offset, caches, valid)
@@ -823,10 +859,10 @@ class ParallelInferenceModel(_ServingBase):
         Cc = ids.shape[1]
         counts = jnp.cumsum(valid, axis=1) - valid  # valid keys strictly before
         positions = jax.lax.dynamic_slice_in_dim(counts, offset, Cc, axis=1)
-        logits, caches = self.module.apply(
+        logits, caches, _ = self._apply_row(
             params, ids, positions.astype(jnp.int32), caches, offset, kv_valid=valid
         )
-        return logits[:, -1, :], caches
+        return logits, caches
 
     def _score_chunk_fn(self, params, ids, offset, caches, valid):
         """Like :meth:`_prefill_chunk_fn` but (a) marks the chunk's cache
@@ -1059,8 +1095,11 @@ class ParallelInferenceModel(_ServingBase):
           q-offset band), so its validity row passes through untouched;
         - ``last_only`` — decode/prefill sample from one position only
           (the last, or the traced row ``last_row`` when a prefill chunk is
-          right-padded to its program's fixed width); verify needs the
-          whole ``[B, S, V]`` chunk of logits.
+          right-padded to its program's fixed width), and the head is
+          applied to that row alone (:meth:`_apply_row`); a ``last_row``
+          below 0 is a chunk that is not its prompt's last: nobody reads
+          its logits, the head is not run and zeros come back.  Verify
+          needs the whole ``[B, S, V]`` chunk of logits.
 
         Since every configuration is one parameterization of this single
         fn, the offset/validity/position math — the token-identity
@@ -1087,17 +1126,14 @@ class ParallelInferenceModel(_ServingBase):
             extra["state_rows"] = state_rows
         collect = (["moe_stats"] if self._moe else []) + (
             ["sparse_stats"] if self._sparse else [])
-        out = self.module.apply(
-            params, toks, positions.astype(jnp.int32), caches, offsets,
-            kv_valid=valid, block_table=block_table,
-            mutable=collect or False, **extra,
-        )
-        (logits, caches), stats = out if collect else (out, None)
-        if last_only and last_row is not None:
-            logits = jax.lax.dynamic_index_in_dim(
-                logits, last_row, axis=1, keepdims=False)
-        elif last_only:
-            logits = logits[:, -1, :]
+        args = (params, toks, positions.astype(jnp.int32), caches, offsets)
+        kw = dict(kv_valid=valid, block_table=block_table,
+                  mutable=collect or False, **extra)
+        if last_only:
+            logits, caches, stats = self._apply_row(*args, row=last_row, **kw)
+        else:
+            out = self.module.apply(*args, **kw)
+            (logits, caches), stats = out if collect else (out, None)
         if self._moe:
             from neuronx_distributed_tpu.models.llama import moe_layer_stats
 
@@ -1302,7 +1338,7 @@ class ParallelInferenceModel(_ServingBase):
 
     def prefill_chunk_pages(self, ids, offset, block_table, caches, valid,
                             apool=None, atables=None, paged_kernel=None,
-                            last_row=None, state_row=None):
+                            last_row=None, state_row=None, want_logits=True):
         """Compiled paged chunk prefill (pool donated) — the ``S = Cc``,
         ``update_valid=False`` member of the :meth:`_paged_step_fn` family
         (Sarathi-style chunked prefill for the serving engine), lazily
@@ -1329,17 +1365,22 @@ class ParallelInferenceModel(_ServingBase):
         by none) is the slot whose state row the chunk continues: the
         program is one row wide and its block table says nothing of whose
         recurrent state it carries; a chunk that holds position 0 starts
-        that row from zeros.
-        Returns that row's logits — the chunk's last position by default
-        (the final chunk's are the prefill logits the first token samples
-        from) — and the updated pool."""
+        that row from zeros.  ``want_logits=False`` is a chunk that is not
+        its prompt's last: the same compiled program (the flag is traced)
+        writes the chunk's K/V and spends nothing on the head.
+        Returns that row's logits ``[1, V]`` — the chunk's last position by
+        default (the final chunk's are the prefill logits the first token
+        samples from; the head is applied to that one row), ``None`` where
+        none were wanted — and the updated pool."""
         logits, caches, _ = self._paged_phase(
             ids, jnp.asarray([offset], jnp.int32), block_table, caches,
             valid, apool=apool, atables=atables, paged_kernel=paged_kernel,
-            update_valid=False, last_only=True, last_row=last_row,
+            update_valid=False, last_only=True,
+            # no member of its own: the traced-row program, a row below 0
+            last_row=last_row if want_logits else -1,
             state_rows=None if state_row is None or not self.recurrent
             else [int(state_row)])
-        return logits, caches
+        return (logits if want_logits else None), caches
 
     def verify_pages(self, toks, offsets, block_table, caches, valid,
                      apool=None, atables=None, paged_kernel=None):
