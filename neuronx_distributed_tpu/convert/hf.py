@@ -437,8 +437,6 @@ def nemotron_h_config_from_hf(hf_config: Mapping[str, Any], **overrides):
             "hybrid_override_pattern names M, E or * for each of the "
             f"num_hidden_layers layers (dense '-' layers are not carried), "
             f"got {pattern!r}")
-    if c.get("n_group", 1) != 1 or c.get("topk_group", 1) != 1:
-        raise ValueError("group-limited routing is not carried")
     return LlamaConfig(**{**dict(
         vocab_size=int(c["vocab_size"]), hidden_size=int(c["hidden_size"]),
         intermediate_size=int(c["moe_intermediate_size"]),
@@ -464,7 +462,9 @@ def nemotron_h_config_from_hf(hf_config: Mapping[str, Any], **overrides):
         moe_route_scale=float(c["routed_scaling_factor"]),
         mlp_activation=str(c["mlp_hidden_act"]),
         moe_shared_intermediate_size=int(
-            c["moe_shared_expert_intermediate_size"])), **overrides})
+            c["moe_shared_expert_intermediate_size"]),
+        moe_n_group=int(c.get("n_group", 1)),
+        moe_topk_group=int(c.get("topk_group", 1))), **overrides})
 
 
 def _nemotron_h_held(cfg):
@@ -614,8 +614,6 @@ def xing4_config_from_hf(hf_config: Mapping[str, Any], **overrides):
     rs = c.get("rope_scaling") or {}
     if rs and rs.get("type", rs.get("rope_type")) != "yarn":
         raise ValueError(f"xing4_0 rope_scaling {rs}: only YaRN is read")
-    if c.get("n_group", 1) != 1 or c.get("topk_group", 1) != 1:
-        raise ValueError("group-limited routing (n_group > 1) is not built")
     return LlamaConfig(**{**dict(
         vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
         intermediate_size=c["intermediate_size"],
@@ -645,6 +643,7 @@ def xing4_config_from_hf(hf_config: Mapping[str, Any], **overrides):
         moe_route_scale=float(c["routed_scaling_factor"]),
         moe_shared_intermediate_size=(c["n_shared_experts"]
                                       * c["moe_intermediate_size"]),
+        moe_n_group=c.get("n_group", 1), moe_topk_group=c.get("topk_group", 1),
     ), **overrides})
 
 
@@ -680,18 +679,24 @@ def _xing4_hc_names(p: str, hc: str):
     return [p + n.format(hc=hc) for n in XING4_NAMES["streams"]]
 
 
-def xing4_params_from_hf(state_dict: Mapping[str, Any], cfg
-                         ) -> Dict[str, Any]:
-    """A ``xing4_0`` state dict (:data:`XING4_NAMES`, assumed) -> the param
-    tree of :class:`~..models.llama.LlamaForCausalLM` under
-    :func:`xing4_config_from_hf`'s layer lists: Linear weights transposed
+def _latent_params_from_hf(state_dict: Mapping[str, Any], cfg
+                           ) -> Dict[str, Any]:
+    """The state dict of a latent-attention decoder with one leading run of
+    dense layers and routed ones after it (:data:`XING4_NAMES`;
+    :data:`DEEPSEEK_V2_NAMES` are those without the streams' maps and the
+    router bias) -> the param tree of
+    :class:`~..models.llama.LlamaForCausalLM`: Linear weights transposed
     in-major, the RoPE columns of ``q_b`` and ``kv_a`` from interleaved pairs
     to halves, ``kv_b`` split a head ``[rank, NH, dn + dv]``, the experts
-    stacked, ``phi [nC, n^2 + 2n]`` as ``[n, C, n^2 + 2n]``."""
+    HELD (``cfg.moe_experts_held``; all without) stacked, the router over
+    every expert, its bias where ``cfg.moe_router_bias``, and under
+    ``cfg.hc_mult > 1`` each sublayer's maps, ``phi [nC, n^2 + 2n]`` as ``[n,
+    C, n^2 + 2n]``."""
     sd = {k: _np(v) for k, v in state_dict.items()}
     NH, r = cfg.num_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     n, C = cfg.hc_mult, cfg.hidden_size
+    first, count = cfg.moe_experts_held or (0, cfg.num_experts)
     model: Dict[str, Any] = {
         "embed": {"embedding": sd["model.embed_tokens.weight"]},
         "final_norm": {"weight": sd["model.norm.weight"]},
@@ -710,7 +715,6 @@ def xing4_params_from_hf(state_dict: Mapping[str, Any], cfg
             "input_norm": {"weight": sd[p + "input_layernorm.weight"]},
             "post_attn_norm": {
                 "weight": sd[p + "post_attention_layernorm.weight"]},
-            "attn_hc": hc(p, "attn_hc"), "ffn_hc": hc(p, "ffn_hc"),
             "attn": {
                 "q_a": {"kernel": qa.T}, "q_a_norm": {"weight": qan},
                 "q_b": {"kernel": rope_half_from_interleaved(
@@ -720,6 +724,8 @@ def xing4_params_from_hf(state_dict: Mapping[str, Any], cfg
                 "kv_a_norm": {"weight": kvan},
                 "kv_b": kvb.T.reshape(r, NH, dn + dv),
                 "o_proj": {"kernel": wo.T}}}
+        if n > 1:
+            layer.update(attn_hc=hc(p, "attn_hc"), ffn_hc=hc(p, "ffn_hc"))
         if cfg.ffn(i) == "mlp":
             gate, up, down = (sd[p + k] for k in XING4_NAMES["dense"])
             layer["mlp"] = {
@@ -729,27 +735,31 @@ def xing4_params_from_hf(state_dict: Mapping[str, Any], cfg
             m = p + "mlp."
             stack = lambda what: np.stack([  # noqa: E731
                 sd[m + f"experts.{e}.{what}_proj.weight"].T
-                for e in range(cfg.num_experts)])
+                for e in range(first, first + count)])
             layer["moe_mlp"] = {
                 "router": sd[m + "gate.weight"].T,
-                "router_bias": sd[m + "gate.e_score_correction_bias"],
                 "gate": stack("gate"), "up": stack("up"),
                 "down": stack("down"),
                 **{f"shared_{w}": {
                     "kernel": sd[m + f"shared_experts.{w}_proj.weight"].T}
                    for w in ("gate", "up", "down")}}
+            if cfg.moe_router_bias:
+                layer["moe_mlp"]["router_bias"] = sd[
+                    m + "gate.e_score_correction_bias"]
         model[f"layer_{i}"] = layer
     return {"params": {"model": model,
                        "lm_head": {"kernel": sd["lm_head.weight"].T}}}
 
 
-def xing4_params_to_hf(params: Mapping[str, Any], cfg
-                       ) -> Dict[str, np.ndarray]:
-    """The inverse of :func:`xing4_params_from_hf`, bit for bit."""
+def _latent_params_to_hf(params: Mapping[str, Any], cfg
+                         ) -> Dict[str, np.ndarray]:
+    """The inverse of :func:`_latent_params_from_hf`, bit for bit (of a held
+    share: the held experts under their own numbers)."""
     p = params["params"] if "params" in params else params
     model = p["model"]
     NH, r = cfg.num_heads, cfg.kv_lora_rank
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    first, _ = cfg.moe_experts_held or (0, cfg.num_experts)
     sd: Dict[str, np.ndarray] = {
         "model.embed_tokens.weight": _np(model["embed"]["embedding"]),
         "model.norm.weight": _np(model["final_norm"]["weight"]),
@@ -761,7 +771,7 @@ def xing4_params_to_hf(params: Mapping[str, Any], cfg
         sd[pre + "input_layernorm.weight"] = _np(lp["input_norm"]["weight"])
         sd[pre + "post_attention_layernorm.weight"] = _np(
             lp["post_attn_norm"]["weight"])
-        for name in ("attn_hc", "ffn_hc"):
+        for name in ("attn_hc", "ffn_hc") if cfg.hc_mult > 1 else ():
             h = lp[name]
             phi = _np(h["phi"])
             for key, val in zip(_xing4_hc_names(pre, name), (
@@ -787,13 +797,119 @@ def xing4_params_to_hf(params: Mapping[str, Any], cfg
         else:
             moe, m = lp["moe_mlp"], pre + "mlp."
             sd[m + "gate.weight"] = _np(moe["router"]).T
-            sd[m + "gate.e_score_correction_bias"] = _np(moe["router_bias"])
+            if cfg.moe_router_bias:
+                sd[m + "gate.e_score_correction_bias"] = _np(
+                    moe["router_bias"])
             for w in ("gate", "up", "down"):
                 for e, mat in enumerate(_np(moe[w])):
-                    sd[m + f"experts.{e}.{w}_proj.weight"] = mat.T
+                    sd[m + f"experts.{first + e}.{w}_proj.weight"] = mat.T
                 sd[m + f"shared_experts.{w}_proj.weight"] = _np(
                     moe[f"shared_{w}"]["kernel"]).T
     return sd
+
+
+def xing4_params_from_hf(state_dict: Mapping[str, Any], cfg
+                         ) -> Dict[str, Any]:
+    """A ``xing4_0`` state dict (:data:`XING4_NAMES`, assumed) -> the param
+    tree of :class:`~..models.llama.LlamaForCausalLM` under
+    :func:`xing4_config_from_hf`'s layer lists
+    (:func:`_latent_params_from_hf`)."""
+    return _latent_params_from_hf(state_dict, cfg)
+
+
+def xing4_params_to_hf(params: Mapping[str, Any], cfg
+                       ) -> Dict[str, np.ndarray]:
+    """The inverse of :func:`xing4_params_from_hf`, bit for bit."""
+    return _latent_params_to_hf(params, cfg)
+
+
+# -- DeepSeek-V2 (``deepseek_v2``) ---------------------------------------------
+#
+# The published checkpoint's names: Xing4.0's (the same attention and
+# feed-forward modules) without the streams' maps and without a router bias.
+
+DEEPSEEK_V2_NAMES = {
+    "attention": XING4_NAMES["attention"],
+    "dense": XING4_NAMES["dense"],
+    "routed": tuple(n for n in XING4_NAMES["routed"]
+                    if n != "mlp.gate.e_score_correction_bias"),
+    "norms": XING4_NAMES["norms"],
+}
+
+
+def deepseek_v2_config_from_hf(hf_config: Mapping[str, Any], **overrides):
+    """A ``deepseek_v2`` ``config.json`` -> :class:`~..models.llama.LlamaConfig`:
+    every layer ``"mla"``, the first ``first_k_dense_replace`` layers dense
+    and the rest routed by softmax scores under the group limit (``n_group``,
+    ``topk_group``; ``topk_method`` ``"greedy"`` is one group), the
+    ``n_shared_experts`` shared experts as ONE gated MLP of their summed
+    width (as the published code builds them).  ``moe_experts_held=(first,
+    count)`` (an override) makes it one expert-parallel rank's share."""
+    from neuronx_distributed_tpu.models.llama import LlamaConfig
+
+    c = hf_config
+    L, dense = c["num_hidden_layers"], c["first_k_dense_replace"]
+    rs = c.get("rope_scaling") or {}
+    if rs and rs.get("type", rs.get("rope_type")) != "yarn":
+        raise ValueError(f"deepseek_v2 rope_scaling {rs}: only YaRN is read")
+    if c.get("scoring_func", "softmax") != "softmax" \
+            or c.get("moe_layer_freq", 1) != 1 or not c.get("q_lora_rank"):
+        raise ValueError(
+            "deepseek_v2: softmax scores, a routed block every layer past "
+            "the dense ones and a query bottleneck (q_lora_rank) are read")
+    method = c.get("topk_method", "greedy")
+    if method not in ("greedy", "group_limited_greedy"):
+        raise ValueError(f"deepseek_v2 topk_method {method!r} is not read")
+    grouped = method == "group_limited_greedy"
+    return LlamaConfig(**{**dict(
+        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+        intermediate_size=c["intermediate_size"],
+        moe_intermediate_size=c["moe_intermediate_size"], num_layers=L,
+        num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_attention_heads"],
+        max_seq_len=c["max_position_embeddings"],
+        rope_theta=float(c["rope_theta"]), rms_eps=c["rms_norm_eps"],
+        mixer_types=("mla",) * L,
+        ffn_types=("mlp",) * dense + ("moe",) * (L - dense),
+        q_lora_rank=c["q_lora_rank"], kv_lora_rank=c["kv_lora_rank"],
+        qk_nope_head_dim=c["qk_nope_head_dim"],
+        qk_rope_head_dim=c["qk_rope_head_dim"], v_head_dim=c["v_head_dim"],
+        rope_yarn_factor=float(rs.get("factor", 1.0)),
+        rope_yarn_original_max_seq=int(
+            rs.get("original_max_position_embeddings", 4096)),
+        rope_yarn_beta_fast=float(rs.get("beta_fast", 32)),
+        rope_yarn_beta_slow=float(rs.get("beta_slow", 1)),
+        rope_yarn_mscale=float(rs.get("mscale", 1)),
+        rope_yarn_mscale_all_dim=float(rs.get("mscale_all_dim", 0)),
+        num_experts=c["n_routed_experts"], moe_top_k=c["num_experts_per_tok"],
+        moe_dispatch="dropless", moe_norm_topk_prob=c["norm_topk_prob"],
+        moe_router_scores="softmax",
+        # the published code scales the gates only where it does not
+        # renormalise them
+        moe_route_scale=(1.0 if c["norm_topk_prob"]
+                         else float(c["routed_scaling_factor"])),
+        moe_shared_intermediate_size=(c["n_shared_experts"]
+                                      * c["moe_intermediate_size"]),
+        moe_n_group=c["n_group"] if grouped else 1,
+        moe_topk_group=c["topk_group"] if grouped else 1,
+    ), **overrides})
+
+
+def deepseek_v2_params_from_hf(state_dict: Mapping[str, Any], cfg
+                               ) -> Dict[str, Any]:
+    """A ``deepseek_v2`` state dict (:data:`DEEPSEEK_V2_NAMES`) -> the param
+    tree of :class:`~..models.llama.LlamaForCausalLM` under
+    :func:`deepseek_v2_config_from_hf`'s layer lists
+    (:func:`_latent_params_from_hf`: no streams' maps, no router bias, the
+    experts HELD under ``cfg.moe_experts_held``)."""
+    return _latent_params_from_hf(state_dict, cfg)
+
+
+def deepseek_v2_params_to_hf(params: Mapping[str, Any], cfg
+                             ) -> Dict[str, np.ndarray]:
+    """The inverse of :func:`deepseek_v2_params_from_hf`, bit for bit (of a
+    held share: the held experts under their own numbers)."""
+    return _latent_params_to_hf(params, cfg)
 
 
 def _neox_deinterleave(w_qkv: np.ndarray, b_qkv: np.ndarray, num_heads: int, head_dim: int):

@@ -167,6 +167,13 @@ class LlamaConfig:
     # expert-parallel layer, served without its exchange: routing is over
     # all of them, the sum over the chosen ones that are held.  None: all
     moe_experts_held: Optional[Tuple[int, int]] = None
+    # group-limited choice (DeepSeek-V2's device-limited routing; HF
+    # ``n_group`` / ``topk_group``): the experts lie in moe_n_group equal
+    # groups of consecutive ones, a group scores its best expert, a row
+    # keeps its moe_topk_group best groups and chooses among their experts
+    # alone.  1 group: no limit, and no such code in the program
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
     # the layer list as DATA (HF ``mixer_types``): one mixer name a layer —
     # "attention" (this file's GQA softmax attention), "minicpm4"
     # (block-sparse softmax attention, no RoPE, output gate),
@@ -283,6 +290,14 @@ class LlamaConfig:
                 raise ValueError(
                     f"moe_experts_held (first, count) = ({first}, {count}) "
                     f"is no range of the {self.num_experts} experts")
+            if self.moe_n_group > 1:
+                size = self.num_experts // self.moe_n_group
+                if first % size or count % size:
+                    raise ValueError(
+                        f"moe_experts_held ({first}, {count}) cuts through "
+                        f"a routing group of {size} experts: under a group "
+                        "limit a rank holds whole groups (a group a device "
+                        "is what the limit bounds a token's fan-out by)")
 
     @property
     def head_dim_(self) -> int:
@@ -1149,7 +1164,9 @@ class LlamaBlock(nn.Module):
                 ("activation", "relu2" if cfg.mlp_activation == "relu2"
                  else "silu", "silu"),
                 ("shared_intermediate_size",
-                 cfg.moe_shared_intermediate_size, 0)) if v != default}
+                 cfg.moe_shared_intermediate_size, 0),
+                ("n_group", cfg.moe_n_group, 1),
+                ("topk_group", cfg.moe_topk_group, 1)) if v != default}
             if family:
                 # initialisation only: a seeded expert of this family is
                 # drawn at its own fan-in, so that the routed block is a
@@ -1210,13 +1227,16 @@ def moe_layer_stats(variables, layers) -> dict:
     [L, E]``, the valid assignments each expert held took in that call,
     ``choice [L, rows, K]``, each row's experts (``parallel/moe.py``,
     dropless path), and, where the layer holds a share of its experts,
-    ``assigned [L]``, the valid assignments whether held or not."""
+    ``assigned [L]``, the valid assignments whether held or not, and
+    under a group limit ``reached [L, 2]``, the valid rows and those with a
+    held assignment."""
     stats = variables["moe_stats"]["model"]
     layers = range(layers) if isinstance(layers, int) else layers
     first = stats[f"layer_{layers[0]}"]["moe_mlp"]
     return {k: jnp.stack([stats[f"layer_{i}"]["moe_mlp"][k][-1]
                           for i in layers])
-            for k in ("load", "choice", "assigned") if k in first}
+            for k in ("load", "choice", "assigned", "reached")
+            if k in first}
 
 
 class LlamaModel(nn.Module):

@@ -48,6 +48,18 @@ normalisation run over all of them and the sum over the chosen ones that
 are held — one rank's part of an expert-parallel layer, computed without
 the exchange (an assignment to an absent expert goes where an invalid row's
 goes: group ``E``, a zero gate, no count).
+
+A third family is DeepSeek-V2's GROUP-LIMITED choice (``n_group`` > 1): the
+experts lie in ``n_group`` equal groups of consecutive ones, a group scores
+the MAX of its experts' scores (under a correction bias the sum of its two
+best biased scores, as DeepSeek-V3 has it), a row keeps its ``topk_group`` best groups
+and takes its top-k among their experts alone (scope ``moe_group_select``
+inside ``moe_router``) — the published model's device-limited routing, a
+group a device.  It composes with the rest: softmax or sigmoid scores, the
+gates' scale, renormalised or not, the shared expert, a held share (which,
+where it is whole groups, is what one device of that layout holds: a row
+whose groups are all elsewhere gives it no routed work, and ``moe_stats``
+then counts the rows that reach it).
 """
 
 from __future__ import annotations
@@ -218,6 +230,10 @@ class ExpertParallelMLP(nn.Module):
     # dropless with ``num_experts_global != num_experts``: the first of
     # the ``num_experts`` routed experts this program holds
     first_expert: int = 0
+    # group-limited choice (module docstring): ``n_group`` equal groups of
+    # consecutive experts, of which a row keeps ``topk_group``; 1: no limit
+    n_group: int = 1
+    topk_group: int = 1
     dtype: Dtype = jnp.bfloat16
     param_dtype: Dtype = jnp.float32
     kernel_init: Initializer = nn.initializers.lecun_normal()
@@ -261,11 +277,20 @@ class ExpertParallelMLP(nn.Module):
                 f"range of the {Eg} routed ones")
         family = (self.router_scores != "softmax" or self.router_bias
                   or self.route_scale != 1.0 or self.activation != "silu"
-                  or self.shared_intermediate_size)
+                  or self.shared_intermediate_size or self.n_group > 1)
         if family and not dropless:
             raise ValueError(
                 "sigmoid scores, a router bias, a route scale, relu2 "
-                "experts and a shared expert are the dropless path's")
+                "experts, a shared expert and a group-limited choice are "
+                "the dropless path's")
+        if self.n_group > 1 and not (
+                Eg % self.n_group == 0
+                and 1 <= self.topk_group <= self.n_group
+                and self.top_k <= self.topk_group * (Eg // self.n_group)):
+            raise ValueError(
+                f"n_group={self.n_group} must divide the {Eg} experts into "
+                f"groups of which topk_group={self.topk_group} hold at "
+                f"least top_k={self.top_k}")
         if self.router_scores not in ("softmax", "sigmoid") \
                 or self.activation not in ("silu", "relu2"):
             raise ValueError(
@@ -496,7 +521,9 @@ class ExpertParallelMLP(nn.Module):
         makes it mutable): ``load [E]``, the valid assignments each expert
         HELD took; ``choice [N, K]``, each row's experts of all ``Eg`` in
         gate order (``Eg`` for an invalid row); and, where a share is held,
-        ``assigned``, the valid assignments held or not."""
+        ``assigned``, the valid assignments held or not, and under a group
+        limit ``reached [2]``, the valid rows and those of them with an
+        assignment that is held."""
         N, H = xt.shape
         E, I, K = self.num_experts, self.intermediate_size, self.top_k
         Eg = self.num_experts_global or E
@@ -507,11 +534,30 @@ class ExpertParallelMLP(nn.Module):
                 probs = jax.nn.sigmoid(logits)
             else:
                 probs = jax.nn.softmax(logits, axis=-1)
-            if bias is None:
+            # chosen by the biased score, weighted by the unbiased
+            ranked = probs if bias is None else probs + bias[None, :]
+            if self.n_group > 1:
+                with jax.named_scope("moe_group_select"):
+                    # a group scores its best expert (DeepSeek-V2's
+                    # greedy form) or, under a correction bias, the sum of
+                    # its two best biased scores (DeepSeek-V3's); the
+                    # experts of the groups not kept leave the choice
+                    # (scores are >= 0, a bias may not be: -inf, not 0)
+                    G = self.n_group
+                    by_group = ranked.reshape(N, G, Eg // G)
+                    score = (jnp.max(by_group, axis=-1) if bias is None
+                             else jnp.sum(jax.lax.top_k(by_group, 2)[0],
+                                          axis=-1))
+                    _, keep = jax.lax.top_k(score, self.topk_group)  # [N, tg]
+                    kept = jnp.any(
+                        keep[:, :, None] == jnp.arange(G)[None, None, :],
+                        axis=1)                                # [N, G]
+                    ranked = jnp.where(jnp.repeat(kept, Eg // G, axis=1),
+                                       ranked, -jnp.inf)
+            if bias is None and self.n_group == 1:
                 gates, choice = jax.lax.top_k(probs, K)        # [N, K]
             else:
-                # chosen by the biased score, weighted by the unbiased
-                _, choice = jax.lax.top_k(probs + bias[None, :], K)
+                _, choice = jax.lax.top_k(ranked, K)
                 gates = jnp.take_along_axis(probs, choice, axis=1)
             if self.norm_topk_prob:
                 gates = gates / jnp.maximum(
@@ -572,4 +618,11 @@ class ExpertParallelMLP(nn.Module):
             if Eg != E:
                 self.sow("moe_stats", "assigned",
                          jnp.sum(live, dtype=jnp.int32) * K)
+                if self.n_group > 1:
+                    # under a group limit a row may reach no held expert:
+                    # [the valid rows, those with a held assignment]
+                    self.sow("moe_stats", "reached", jnp.stack([
+                        jnp.sum(live, dtype=jnp.int32),
+                        jnp.sum(jnp.any(group < E, axis=1),
+                                dtype=jnp.int32)]))
         return y, aux.astype(jnp.float32)

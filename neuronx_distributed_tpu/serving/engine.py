@@ -2166,11 +2166,13 @@ class ServingEngine:
         """``(program families, device loads)`` of the paged programs a
         routed model ran since the last call: ``{"load": [L, E]}`` a
         program, with ``"assigned" [L]`` beside it where the layers hold a
-        share of their experts."""
+        share of their experts and ``"reached" [L, 2]`` where a group limit
+        lets a row reach none of them."""
         take = getattr(self.model, "take_moe_stats", None)
         stats = take(upto) if take is not None else []
         return [s["program"] for s in stats], [
-            {k: s[k] for k in ("load", "assigned") if k in s} for s in stats]
+            {k: s[k] for k in ("load", "assigned", "reached") if k in s}
+            for s in stats]
 
     def _count_moe(self, programs, loads) -> None:
         """Book the expert loads of the paged programs just fetched:
@@ -2178,7 +2180,10 @@ class ServingEngine:
         where the layers hold a share of their experts
         ``moe/assignments_held_total`` (those that went to an expert this
         program holds; it and ``moe/assignments_total`` then also by program
-        family),
+        family), where the router is group-limited besides
+        ``moe/rows_routed_total`` (valid rows x layers) and
+        ``moe/rows_reaching_held_total`` (those with at least one held
+        assignment), both also by program family,
         ``moe/layer_calls_total`` (expert blocks that ran with a token),
         ``moe/experts_hit_total`` (experts with a row, summed over those
         calls) — the last two also by program family, ``.../decode_pages``
@@ -2202,6 +2207,16 @@ class ServingEngine:
                     reg.counter("moe/assignments_held_total" + suffix).inc(
                         int(load.sum()))
                 reg.counter("moe/assignments_total/" + program).inc(made)
+            if "reached" in stats:
+                # a group-limited router over a held share: the rows that
+                # were routed (a layer each), and those with an assignment
+                # this program holds
+                rows, reached = np.asarray(stats["reached"]).sum(axis=0)
+                for suffix in ("", "/" + program):
+                    reg.counter("moe/rows_routed_total" + suffix).inc(
+                        int(rows))
+                    reg.counter("moe/rows_reaching_held_total" + suffix).inc(
+                        int(reached))
             for suffix in ("", "/" + program):
                 reg.counter("moe/layer_calls_total" + suffix).inc(calls)
                 reg.counter("moe/experts_hit_total" + suffix).inc(hit)
