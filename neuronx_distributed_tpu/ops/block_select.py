@@ -12,6 +12,19 @@ that hold the last ``window`` positions score ``+inf``; the query attends the
 in them every position ``<= p``.  A query whose sequence is shorter than
 ``dense_len`` attends every visible block.
 
+**Choosing without sorting** (PR 40).  The top-k SET is a threshold at the
+exact k-th largest score: float32 scores become int32 keys in the total order
+``lax.top_k`` sorts by, the k-th largest key is built from its highest bit
+down (32 passes, each a compare and a count over the pages), every key above
+it is chosen, and of the keys equal to it the lowest pages, as many as are
+still missing (nine more passes of the same search over the page index) —
+``lax.top_k``'s set bit for bit, ties to the lower page, forced ``+inf``,
+invisible ``-inf`` and underflowed ``0.0`` scores included, in one Pallas
+call (``sparse_topk_select``) whatever the rows: a 512-row chunk or a
+decode's slots.  The decode's TABLE of chosen pages is by rank: entry ``w``
+of a (slot, kv head) is the chosen page with ``w`` chosen pages before it;
+entries at or past the row's ``count`` are unspecified and never read.
+
 **Blocks are pages.**  The pool's page size is the block size, and a slot's
 rows are written so that position 0 sits at the START of a page: the engine
 left-pads a prompt of ``L`` tokens into cells ``[C - L, C)`` of its row, and
@@ -29,7 +42,9 @@ layer has, and the compressed keys ``[NP, page // stride, NKV, D]`` beside
 them — kernel ``j`` of a sequence lives in the page of its first position.
 
 Scopes in a device trace: ``kv_write``, ``sparse_compress``,
-``sparse_score``, ``sparse_topk``; the kernels ``sparse_attention_decode``
+``sparse_score``, ``sparse_topk`` (the chosen set, its kernel
+``sparse_topk_select``, and the decode's table); the kernels
+``sparse_attention_decode``
 (a walk over a table of at most ``max(topk, dense_len / block)`` chosen
 pages a (slot, kv head)) and ``sparse_attention_chunk`` (the chunk walk over
 the slot's pages under a per-(row, page) mask).
@@ -38,18 +53,29 @@ the slot's pages under a per-(row, page) mask).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
 
-from neuronx_distributed_tpu.ops.flash_attention import NEG_INF
+from neuronx_distributed_tpu.ops.flash_attention import (
+    LANES,
+    NEG_INF,
+    _compiler_params,
+    run_kernel,
+)
 
 # query rows of one program of the chunk walk: a prefill chunk is split into
 # runs of this many rows (x the query heads of a kv head: the kernel's rows)
 CHUNK_SPLIT_ROWS = 128
 _MASKED_STEP_KEYS = 256
+_INT_MIN = np.int32(-2 ** 31)
+# query rows (lanes) of one program of the top-k selection: 328 pages x 512
+# rows of keys are 0.64 MiB a block
+_TOP_K_TILE_ROWS = 512
 
 
 @dataclasses.dataclass(frozen=True)
@@ -183,6 +209,82 @@ def block_scores(q, kc, block_table, qpos, astart, spec: SparseSpec):
         return jnp.where(visible[:, None], own, -jnp.inf)
 
 
+def _ordered_keys(scores):
+    """float32 -> int32 keys in the total order ``lax.top_k`` sorts by:
+    ``-inf`` lowest, ``+inf`` highest, ``-0.0`` just below ``+0.0``."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    return bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+
+
+def _kth_largest(x, k, bits: int):
+    """The ``k``-th largest value of each COLUMN of int32 ``x [N, R]`` (``k
+    [1, R]`` or an int, ``1 <= k <= N``) as ``[1, R]``: the largest ``t``
+    with ``count(x >= t) >= k``, built from its highest bit down, a compare
+    and a column count a bit.  ``bits`` 32 takes any ``x``; fewer take ``0
+    <= x < 2 ** bits``."""
+    bias = _INT_MIN if bits == 32 else np.int32(0)
+
+    def with_bit(i, t):                    # t as an unsigned number
+        cand = t | (jnp.int32(1) << (bits - 1 - i))
+        n = jnp.sum((x >= (cand ^ bias)).astype(jnp.int32), axis=0,
+                    keepdims=True)
+        return jnp.where(n >= k, cand, t)
+
+    t = jax.lax.fori_loop(0, bits, with_bit,
+                          jnp.zeros((1, x.shape[1]), jnp.int32))
+    return t ^ bias
+
+
+def _top_k_kernel(keys_ref, out_ref, *, k: int):
+    """``keys [N, R]`` (a column a query row, ordered keys down it) -> 1
+    where the key is among its column's ``k`` largest, ties to the lower
+    index: every key above the k-th largest VALUE, and of those equal to it
+    the first ``k - count(above)`` — the same threshold search over ``N -
+    index`` among the tied."""
+    keys = keys_ref[...]
+    n = keys.shape[0]
+    t = _kth_largest(keys, k, 32)
+    above, tied = keys > t, keys == t
+    need = k - jnp.sum(above.astype(jnp.int32), axis=0, keepdims=True)
+    low = jnp.where(
+        tied, n - jax.lax.broadcasted_iota(jnp.int32, keys.shape, 0), 0)
+    first = low >= _kth_largest(low, need, n.bit_length())
+    out_ref[...] = (above | first).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _top_k_set(scores, k: int):
+    """``scores [..., PP]`` float32, ``k < PP`` -> the boolean set of the
+    ``k`` highest a row, ties to the lower index: ``lax.top_k``'s set bit
+    for bit, by a threshold at the exact k-th largest score and no sort
+    (top-64 of 328 is a full sort of (score, index) pairs on the chip: 1.96
+    ms a 512-row chunk layer, an eighth of a step; PERF.md §6, PR 40).  One
+    Pallas call over the keys laid pages-major, the query rows on the lanes:
+    the counts are vector adds down a column, whatever layout the compiler
+    gave the scores."""
+    pp = scores.shape[-1]
+    rows = int(np.prod(scores.shape[:-1]))
+    tile = min(_TOP_K_TILE_ROWS, -(-rows // LANES) * LANES)
+    n, r = -(-pp // 8) * 8, -(-rows // tile) * tile
+    # pages (to whole sublane tiles of 8) and rows (to whole tiles of lanes)
+    # added hold the lowest key: below every score
+    keys = jnp.pad(_ordered_keys(scores).reshape(rows, pp).T,
+                   ((0, n - pp), (0, r - rows)), constant_values=_INT_MIN)
+
+    def call(interp):
+        return pl.pallas_call(
+            functools.partial(_top_k_kernel, k=k),
+            grid=(r // tile,),
+            in_specs=[pl.BlockSpec((n, tile), lambda i: (0, i))],
+            out_specs=pl.BlockSpec((n, tile), lambda i: (0, i)),
+            out_shape=jax.ShapeDtypeStruct((n, r), jnp.int32),
+            compiler_params=_compiler_params(("parallel",), interp),
+            interpret=interp, name="sparse_topk_select")
+
+    out = run_kernel(call, None, keys)
+    return (out[:pp, :rows] != 0).T.reshape(scores.shape)
+
+
 def choose_blocks(scores, n_row, spec: SparseSpec):
     """``scores [B, NKV, S, PP]`` -> the boolean set of chosen pages, same
     shape: the ``topk`` of highest score (ties to the lower page) among the
@@ -194,9 +296,7 @@ def choose_blocks(scores, n_row, spec: SparseSpec):
         if spec.topk >= PP:
             chosen = visible
         else:
-            _, idx = jax.lax.top_k(scores, spec.topk)        # [B,NKV,S,K]
-            chosen = jnp.any(idx[..., None] == jnp.arange(PP), axis=-2)
-            chosen = chosen & visible
+            chosen = _top_k_set(scores, spec.topk) & visible
         dense = (n_row < spec.dense_len)[:, None, None, None]
         return jnp.where(dense, visible, chosen)
 
@@ -204,16 +304,22 @@ def choose_blocks(scores, n_row, spec: SparseSpec):
 def _chosen_table(chosen, block_table, width: int, nkv: int):
     """``chosen [B, NKV, PP]`` -> ``(table [B * NKV, width], count [B *
     NKV])``: each (slot, kv head)'s chosen pages in ascending order, as
-    pages of the pool seen as ``[NP * NKV, 1, page, D]``."""
+    pages of the pool seen as ``[NP * NKV, 1, page, D]``, by RANK and no
+    sort: the ``w``-th chosen page of a row is the one with ``w`` chosen
+    pages before it.  Entries at or past ``count`` are unspecified (some
+    page of the pool): the decode walk ends in page ``count - 1`` (``off``;
+    a row with none is parked past the table), and its look-ahead
+    re-addresses that last page (``ops/paged_attention.py::_walk_kernel``:
+    ``p_log = min(.., last)``), never entry ``count``."""
     B, NKV, _ = chosen.shape
-    order = jnp.argsort(~chosen, axis=-1, stable=True)[..., :width]
-    if order.shape[-1] < width:
-        order = jnp.pad(order, ((0, 0), (0, 0), (0, width - order.shape[-1])))
-    phys = jnp.take_along_axis(
-        jnp.broadcast_to(block_table[:, None, :], chosen.shape), order, axis=-1)
-    phys = phys * nkv + jnp.arange(nkv)[None, :, None]
-    return (phys.reshape(B * NKV, width).astype(jnp.int32),
-            jnp.sum(chosen, axis=-1).reshape(B * NKV).astype(jnp.int32))
+    rank = jnp.cumsum(chosen.astype(jnp.int32), axis=-1) - 1
+    phys = (block_table[:, None, :] * nkv
+            + jnp.arange(nkv)[None, :, None]).astype(jnp.int32)
+    hit = chosen[:, :, None, :] & (rank[:, :, None, :]
+                                   == jnp.arange(width)[None, None, :, None])
+    table = jnp.sum(jnp.where(hit, phys[:, :, None, :], 0), axis=-1)
+    return (table.reshape(B * NKV, width),
+            (rank[..., -1] + 1).reshape(B * NKV))
 
 
 def sparse_paged_attention(q, k, v, cache, block_table, cache_offset,
@@ -294,7 +400,9 @@ def sparse_paged_attention(q, k, v, cache, block_table, cache_offset,
     pool = (ck.reshape(NP * NKV, 1, page, D), cv.reshape(NP * NKV, 1, page, D))
     if S == 1:
         width = min(spec.table_width, PP)
-        table, count = _chosen_table(chosen[:, :, 0], block_table, width, NKV)
+        with jax.named_scope("sparse_topk"):
+            table, count = _chosen_table(chosen[:, :, 0], block_table, width,
+                                         NKV)
         parked = jnp.repeat(~live[:, 0], NKV)
         off = jnp.where(parked | (count == 0), width * page,
                         (count - 1) * page + jnp.repeat(cell[:, 0] % page, NKV))

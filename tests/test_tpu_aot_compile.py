@@ -331,7 +331,8 @@ def test_hybrid_paged_programs_compile_for_v5e_and_copy_no_state(topo, program):
     serving geometry (8 slots of 20,992 tokens, pages of one 64-token block,
     a 512-row chunk), cut to one period of two layers (a block-sparse
     softmax layer, a lightning layer): the chosen-table decode walk, the
-    masked chunk walk and the pool writer are Mosaic calls; the pool, the
+    masked chunk walk, the top-k selection and the pool writer are Mosaic
+    calls and nothing sorts; the pool, the
     compressed keys and the float32 state rows are donated, aliased to their
     outputs and never copied."""
     import functools
@@ -390,6 +391,10 @@ def test_hybrid_paged_programs_compile_for_v5e_and_copy_no_state(topo, program):
     text = compiled.as_text()
     kernel = "sparse_attention_decode" if decode else "sparse_attention_chunk"
     assert f"%{kernel}" in text and text.count("%kv_pool_write") >= 2
+    # the top-64 of 328 pages is a threshold search in one Mosaic call, not
+    # the full sort the chip made of ``lax.top_k`` (PR 40)
+    assert "%sparse_topk_select" in text
+    assert not re.search(r" sort\(|TopK", text)
     # (a decode's per-slot state UPDATE has the state array's own shape, 8
     # slots being 8 rows: for it only a ``copy`` is a copy)
     for shape, ops in ((f"bf16[{num_pages},2,{page},128]", "copy|transpose"),
