@@ -358,6 +358,13 @@ REGISTRY_METRICS: Dict[str, str] = {
     "trace/compiled_cache_hits_total": "counter",
     "trace/compiled_cache_misses_total": "counter",
     "trace/compiled_cache_evictions_total": "counter",
+    # the routed block's grouped matmuls (parallel.moe.grouped_matmul): the
+    # megablox calls lowered, by whether the k-tile divides the contraction
+    # or upstream masks its last k-tile — counted at trace time, booked by
+    # the serving engine with a program family's first expert loads (the
+    # other moe/* names end in _total and go by the naming convention)
+    "moe/gmm_lowered_total/whole_k": "counter",
+    "moe/gmm_lowered_total/masked_k": "counter",
     # memory ledger (obs.memory_ledger.MemoryLedger): per-subsystem device
     # bytes + peak watermarks (the gauges' sum is the logical sizing
     # model), device truth where the backend reports it, and the largest

@@ -108,11 +108,67 @@ def load_balancing_loss(probs: jax.Array, expert_mask: jax.Array) -> jax.Array:
     return E * jnp.sum(frac * mean_p)
 
 
-# megablox's (m, k, n) tile: the fastest of those tried at OLMoE-1B-7B's
-# widths on the v5e, a decode's 128 assignment rows (1.60 ms an expert block
-# where ``lax.ragged_dot`` took 1.91) and a chunk's 4096 (2.18 against 3.73):
-# benchmarks/tools/moe_gmm_probe.py, PERF.md (Findings, PR 25)
+# megablox's (m, k, n) tile is chosen from the operand's shape
+# (:func:`gmm_tile`).  GMM_TILING is the most its row and lane tiles grow to
+# and what it falls back to: the fastest of those tried at OLMoE-1B-7B's
+# widths on the v5e (benchmarks/tools/moe_gmm_probe.py; PERF.md, PR 25), which
+# both of OLMoE's matmuls tile exactly.  GMM_TILE_BYTES bounds a call's
+# double-buffered inputs — a row tile ``[tm, tk]`` and a weight tile
+# ``[tk, tn]``: 12.5 MiB ran beside the output and the accumulator under the
+# 16 MiB a Mosaic call may hold, 15.75 MiB was refused
+# (tools/gmm_tile_probe.py; PERF.md, PR 41).
 GMM_TILING = (128, 2048, 1024)
+GMM_TILE_BYTES = 25 * 2 ** 19
+
+
+def gmm_tile(m: int, k: int, n: int, itemsize: int) -> tuple[int, int, int]:
+    """The ``(tm, tk, tn)`` tile :func:`grouped_matmul` hands megablox for
+    ``[m, k] x [G, k, n]`` operands of ``itemsize`` bytes: of the tiles that
+    DIVIDE the weight matrix — ``tk`` K whole or a multiple of 128 that
+    divides it, ``tn`` a multiple of 128 from 512 to GMM_TILING's that
+    divides N — and fit GMM_TILE_BYTES, the one that walks an expert in the
+    fewest grid steps; of those, the widest in lanes.
+
+    Why: upstream's kernel masks the LAST k-tile whenever ``k % tk != 0`` —
+    the whole loaded weight tile through float32 on the vector unit
+    (``mask_k_rem``) — and with more than one k-tile it fetches the row tile
+    again every step and adds into its accumulator; a lane tile that hangs
+    over N multiplies columns nobody reads.  At a decode's few rows an
+    expert the MXU's weight push and the HBM read take about the same time,
+    so all of that is exposed: Nemotron's up-projection (K 2688) took 1.30
+    ms under ``(128, 2048, 1024)`` and 0.97 under ``(128, 2688, 1024)``,
+    DeepSeek-V2's gate (K 5120) 0.51 and 0.43 under ``(128, 5120, 512)``
+    (v5e; tools/gmm_tile_probe.py).  OLMoE's two shapes get the tile they
+    were measured with.  Where no multiple of 128 divides N the lane tile is
+    GMM_TILING's; where nothing that divides K fits, GMM_TILING whole — and
+    upstream's mask is then right and needed."""
+    tm, tk, tn = GMM_TILING
+    room = GMM_TILE_BYTES // (2 * itemsize)     # elements, both input tiles
+    lanes = [n] if n <= tn else [
+        t for t in range(512, tn + 1, 128) if n % t == 0] or [tn]
+    depths = [k] + [d for d in range(512, k, 128) if k % d == 0]
+    fits = [(d, t) for d in depths for t in lanes if d * (tm + t) <= room]
+    if not fits:
+        return tm, min(tk, k), min(tn, n)
+    tk, tn = min(fits, key=lambda f: (-(-k // f[0]) * -(-n // f[1]), -f[1]))
+    return tm, tk, tn
+
+
+# grouped matmuls whose kernel arm was traced since the last take, by whether
+# the k-tile divides the contraction (``masked_k``: upstream masks the last)
+_GMM_LOWERED = {"whole_k": 0, "masked_k": 0}
+
+
+def take_gmm_lowered() -> dict:
+    """``{"whole_k": n, "masked_k": n}``: the calls of :func:`grouped_matmul`
+    whose megablox arm was traced since the last call of this — a count
+    made at trace time, once a lowered call and never again for a program
+    that is cached (a program for another platform traces the arm too and
+    drops it when it lowers).  The serving engine books it as
+    ``moe/gmm_lowered_total/*`` with a program's first expert loads."""
+    out = dict(_GMM_LOWERED)
+    _GMM_LOWERED.update(dict.fromkeys(_GMM_LOWERED, 0))
+    return out
 
 
 def per_expert_lecun(key, shape, dtype=jnp.float32):
@@ -152,14 +208,14 @@ def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
     def kernel(x, w, sizes):
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-        tm, tk, tn = GMM_TILING
-        pad = -x.shape[0] % tm          # whole row tiles; the pad is in no group
-        xp = jnp.pad(x, ((0, pad), (0, 0))) if pad else x
         k, n = (w.shape[2], w.shape[1]) if transpose_rhs else w.shape[1:]
+        tile = gmm_tile(x.shape[0], k, n, w.dtype.itemsize)
+        _GMM_LOWERED["masked_k" if k % tile[1] else "whole_k"] += 1
+        pad = -x.shape[0] % tile[0]     # whole row tiles; the pad is in no group
+        xp = jnp.pad(x, ((0, pad), (0, 0))) if pad else x
         with jax.named_scope("moe_gmm"):
             out = gmm(xp, w, sizes, preferred_element_type=dtype,
-                      tiling=(tm, min(tk, k), min(tn, n)),
-                      transpose_rhs=transpose_rhs)
+                      tiling=tile, transpose_rhs=transpose_rhs)
         return out[:x.shape[0]]
 
     if model_parallel_is_initialized() and get_tensor_parallel_size() > 1:

@@ -101,6 +101,7 @@ from neuronx_distributed_tpu.kvcache.transfer import (
     export_chain,
     import_chain,
 )
+from neuronx_distributed_tpu.parallel.moe import take_gmm_lowered
 from neuronx_distributed_tpu.serving.paged import PagedKVManager
 from neuronx_distributed_tpu.serving.scheduler import (
     DEFAULT_MAX_BATCH_WAIT_S,
@@ -698,6 +699,7 @@ class ServingEngine:
         # expert loads summed since this engine began, [L, E]; what the
         # model ran before (another engine, a check) is not this engine's
         self._moe_load = None
+        self._moe_programs: set = set()    # families whose loads were booked
         self._take_moe_loads()
         # resource ledgers (obs.compile_ledger / obs.memory_ledger).  An
         # explicit compile ledger is attached to the MODEL (and the draft)
@@ -2191,11 +2193,19 @@ class ServingEngine:
         unread, a chunk's hundreds do not — and the gauge
         ``moe/expert_load_max_over_mean`` (per layer, the busiest expert's
         assignments over the mean expert's since the engine began; the mean
-        over layers)."""
+        over layers).  With a family's FIRST loads — its program has been
+        traced by then — also ``moe/gmm_lowered_total/{whole_k,masked_k}``:
+        the grouped matmuls lowered in this process since the last booking,
+        by whether the k-tile divides the contraction
+        (``parallel.moe.take_gmm_lowered``)."""
         if not loads:
             return
         reg = self.registry
         for program, stats in zip(programs, loads):
+            if program not in self._moe_programs:
+                self._moe_programs.add(program)
+                for how, n in take_gmm_lowered().items():
+                    reg.counter("moe/gmm_lowered_total/" + how).inc(n)
             load = np.asarray(stats["load"], np.int64)
             assigned = stats.get("assigned")
             calls, hit = int((load.sum(axis=1) > 0).sum()), int((load > 0).sum())

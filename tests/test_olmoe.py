@@ -337,4 +337,10 @@ def test_the_counters_add_up_to_valid_rows_times_experts_times_layers():
     assert snap["moe/layer_calls_total"] == sum(
         snap[f"moe/layer_calls_total/{f}"]
         for f in ("decode_pages", "prefill_chunk_pages"))
+    # the grouped matmuls traced for the two programs — gate, up and down a
+    # layer — booked with each family's first loads; no k-tile is masked
+    assert snap["moe/gmm_lowered_total/whole_k"] >= 2 * 3 * L
+    assert snap["moe/gmm_lowered_total/masked_k"] == 0
+    from neuronx_distributed_tpu.obs.schemas import validate_registry_metrics
+    validate_registry_metrics(engine.registry)
     engine.close()
