@@ -9,7 +9,7 @@ arguments it needs ONE TPU chip and runs, at Mistral-7B-v0.1 widths (hidden
 window 4096 — only depth is cut, and the cut is printed):
 
 - **device**  — the platform must be ``tpu`` and its ``device_kind`` must be
-  in the one peak table (``obs.perf.DEVICE_SPECS``);
+  in the one peak table (``utils.profiling.DEVICE_SPECS``);
 - **kernels** — the flash kernel (forward + backward, through
   ``ring_attention``; causal, and window 4096 at sequence 8192) against
   ``mha_reference``, and ``paged_attention`` (decode, verify, chunk; fp and
@@ -317,7 +317,7 @@ def phase_device(args, cache_dir):
     import jax
 
     from neuronx_distributed_tpu.data.loader import loader_backend
-    from neuronx_distributed_tpu.obs.perf import device_spec
+    from neuronx_distributed_tpu.utils.profiling import device_spec
 
     devices = jax.devices()
     dev = devices[0]

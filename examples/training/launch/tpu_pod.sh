@@ -12,9 +12,9 @@
 # one host and brings the pod up on many.
 #
 # The mesh spans all hosts: 32 chips (v5e-32) below give TP=8 within hosts
-# and DP=4 across them — BASELINE.md's north-star topology.  Shardings ride
-# ICI within a host-block and DCN across; the mesh device order
-# (parallel/mesh.py multi-slice layout) keeps tp/cp/kvr axes on ICI.
+# and DP=4 across them — the north-star topology (Llama-2-7B pretrain).
+# Shardings ride ICI within a host-block and DCN across; the mesh device
+# order (parallel/mesh.py multi-slice layout) keeps tp/cp/kvr axes on ICI.
 set -euo pipefail
 
 REPO="$(cd "$(dirname "$0")/../../.." && pwd)"

@@ -270,7 +270,7 @@ def main():
     # raises on a TPU kind it does not hold.  A CPU run reports no MFU.
     peak_flops = None
     if dev.platform == "tpu":
-        from neuronx_distributed_tpu.obs.perf import device_spec
+        from neuronx_distributed_tpu.utils.profiling import device_spec
 
         peak_flops = device_spec(dev).peak_flops
 

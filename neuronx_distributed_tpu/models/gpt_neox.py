@@ -256,7 +256,7 @@ def build_pipelined_gpt_neox(
     pipeline_cuts=None, num_chunks: int = 1,
 ):
     """Pipeline-parallel GPT-NeoX (the reference's 20B milestone topology,
-    TP8 x PP4 1F1B — BASELINE config 4); same engine protocol as
+    TP8 x PP4 1F1B); same engine protocol as
     ``llama.build_pipelined_llama``."""
     from neuronx_distributed_tpu.models.common import build_pipelined_causal_lm
 

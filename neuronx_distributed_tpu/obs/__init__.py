@@ -81,19 +81,6 @@ from neuronx_distributed_tpu.obs.memory_ledger import (
     MemoryLedger,
     read_memory_breakdown,
 )
-from neuronx_distributed_tpu.obs.perf import (
-    DEVICE_SPECS,
-    PERF_ATTRIBUTION_FILE,
-    PERF_ATTRIBUTION_SCHEMA,
-    PERF_FAMILIES,
-    DeviceSpec,
-    PerfAttribution,
-    device_spec,
-    merge_perf_records,
-    read_perf_attribution,
-    roofline_attribution,
-    summarize_perf,
-)
 from neuronx_distributed_tpu.obs.health import (
     ALERT_SCHEMA,
     ALERTS_FILE,
@@ -162,7 +149,6 @@ class Observability:
         registry: Optional[MetricRegistry] = None,
         ledgers: bool = False,
         health: Any = False,
-        perf: Any = False,
     ):
         self.out_dir = out_dir
         os.makedirs(out_dir, exist_ok=True)
@@ -201,19 +187,6 @@ class Observability:
         # alert edges streamed to alerts.jsonl under out_dir.  Off by
         # default — every consumer guards on `is not None`, so the hot
         # path stays allocation-free (the ALERTS_EVALUATED discipline).
-        # perf attribution (perf=True, or a DeviceSpec cost model for a
-        # run without a known accelerator): per-phase device-time
-        # accounting joined with compile-ledger costs into roofline/MFU
-        # records, dumped to perf_attribution.jsonl on close.  perf=True
-        # reads the device's published peaks and raises on a device the
-        # table does not hold.  Off by default — consumers guard on
-        # `is not None` (the PERF_RECORDS discipline).
-        self.perf: Optional[PerfAttribution] = None
-        if perf:
-            self.perf = PerfAttribution(
-                path=os.path.join(out_dir, PERF_ATTRIBUTION_FILE),
-                registry=self.registry, ledger=self.compile_ledger,
-                spec=perf if isinstance(perf, DeviceSpec) else None)
         self.health_monitor: Optional[HealthMonitor] = None
         if isinstance(health, HealthMonitor):
             self.health_monitor = health
@@ -304,12 +277,6 @@ class Observability:
                 self.memory_ledger.dump(reason=reason)
             except OSError as e:  # telemetry IO must never mask the exit
                 logger.warning("obs: memory breakdown dump failed: %s", e)
-        if self.perf is not None:
-            try:
-                self.perf.update_metrics()
-                self.perf.dump()
-            except OSError as e:  # telemetry IO must never mask the exit
-                logger.warning("obs: perf attribution dump failed: %s", e)
         if self.health_monitor is not None:
             self.health_monitor.close()
         with open(self.prometheus_path, "w") as f:
@@ -368,17 +335,6 @@ __all__ = [
     "write_chrome_trace",
     "TRACE_EVENTS_FILE",
     "TRACE_EVENT_SCHEMA",
-    "PerfAttribution",
-    "DeviceSpec",
-    "DEVICE_SPECS",
-    "device_spec",
-    "roofline_attribution",
-    "summarize_perf",
-    "merge_perf_records",
-    "read_perf_attribution",
-    "PERF_ATTRIBUTION_FILE",
-    "PERF_ATTRIBUTION_SCHEMA",
-    "PERF_FAMILIES",
     "SCALARS_FILE",
     "FLIGHT_FILE",
     "HLO_AUDIT_FILE",

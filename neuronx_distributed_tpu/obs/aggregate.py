@@ -444,27 +444,6 @@ def merge_serving_stats(paths: Sequence[str]) -> List[dict]:
     return out
 
 
-def merge_perf_files(paths: Sequence[str]) -> List[dict]:
-    """Fold per-replica ``perf_attribution.jsonl`` files into one fleet
-    attribution stream: per-family calls / device time / flops / bytes SUM
-    across replicas (the fleet spent that much device time on prefill,
-    full stop) and the derived roofline numbers are recomputed against the
-    merged totals via :func:`~.perf.merge_perf_records`.  A single file
-    passes through untouched."""
-    from neuronx_distributed_tpu.obs.perf import (
-        merge_perf_records,
-        read_perf_attribution,
-    )
-
-    streams = [read_perf_attribution(p) for p in paths if os.path.exists(p)]
-    streams = [s for s in streams if s]
-    if not streams:
-        return []
-    if len(streams) == 1:
-        return streams[0]
-    return merge_perf_records(streams)
-
-
 def discover_replica_dirs(run_dir: str) -> List[Tuple[str, str]]:
     """Fleet-layout discovery for ``obs_report --run-dir``: immediate
     subdirectories holding a ``scalars.jsonl`` or ``serving_stats.jsonl``

@@ -11,7 +11,7 @@ it.  :class:`CompileLedger` is the one accounting surface:
   then the timing wrapper unwraps itself so steady-state calls pay
   nothing), the trainer-step compile in ``trainer/fit.py`` (which also
   covers the pipelined engine — its schedule compiles inside the same
-  train-step jit), and ``bench.py``'s cold/warm rung timing;
+  train-step jit);
 - **cache events join the program events**: ``_CompiledLRU`` hit / miss /
   eviction counts land next to the compiles they explain, and evictions
   carry the evicted ``(family, key)`` so thrash is attributable;
@@ -207,17 +207,9 @@ class CompileLedger:
         f = self._fams.get(family)
         if f is None:
             f = {"keys": set(), "capacity": None, "compiles": 0,
-                 "evictions": 0, "cold_ms": 0.0, "thrashed": False,
-                 "hits": 0}
+                 "evictions": 0, "cold_ms": 0.0, "thrashed": False}
             self._fams[family] = f
         return f
-
-    def family_hits(self, family: str) -> int:
-        """Steady-state cache hits recorded for one program family — the
-        call-count cross-check the perf-attribution join reads (one hit ==
-        one compiled execution that paid no compile)."""
-        f = self._fams.get(family)
-        return 0 if f is None else f["hits"]
 
     # -- recording ---------------------------------------------------------
 
@@ -276,8 +268,8 @@ class CompileLedger:
             missing = rep.get("cost_keys_missing")
             if missing:
                 # the cost model went blind for this program — count the
-                # degradation so downstream roofline joins can tell "moves
-                # no bytes" from "unreported"
+                # degradation so a reader of the row can tell "moves no
+                # bytes" from "unreported"
                 extra.setdefault("cost_keys_missing", int(missing))
                 if self.registry is not None:
                     self.registry.counter(
@@ -408,7 +400,6 @@ class CompileLedger:
 
     def cache_hit(self, family: str) -> None:
         self.cache_hits += 1
-        self._fam(family)["hits"] += 1
         if self.registry is not None:
             self.registry.counter("trace/compiled_cache_hits_total").inc()
 

@@ -477,27 +477,12 @@ def default_rules(scope: str = "serving", *,
       that may back either a trainer or a serving engine — the serving
       pack plus the train-scope rules under distinct names (rules over
       absent metrics stay silent).
-
-    Every scope additionally carries the perf-attribution drift rules
-    (``mfu_sag`` over ``perf/mfu_milli``, ``roofline_drift`` over
-    ``perf/roofline_pct_milli``) — silent unless the run profiles with
-    ``Observability(perf=True)``.
     """
     if scope not in ("serving", "fleet", "train", "all"):
         raise ValueError(f"unknown rule scope {scope!r}")
     rules: List[Rule] = [
         ThresholdRule("compile_storm", "trace/compile_storms_total",
                       0.0, op=">", rate=True, severity="warn"),
-        # perf-attribution drift (every scope: the perf/* gauges exist for
-        # trainers and engines alike, and rules over absent metrics stay
-        # silent).  Milli-unit gauges; min_slow keeps a sub-0.1%-MFU
-        # baseline — calibration noise, not utilization — from "sagging".
-        TrendRule("mfu_sag", "perf/mfu_milli",
-                  direction="down", ratio=1.5, warmup=8, min_slow=1.0,
-                  severity="warn", fire_after=2, resolve_after=2),
-        TrendRule("roofline_drift", "perf/roofline_pct_milli",
-                  direction="down", ratio=1.5, warmup=8, min_slow=1.0,
-                  severity="warn", fire_after=2, resolve_after=2),
     ]
     train_sag = TrendRule(
         "train_throughput_sag" if scope == "all" else "throughput_sag",

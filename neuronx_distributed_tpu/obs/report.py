@@ -33,10 +33,6 @@ from neuronx_distributed_tpu.obs.memory_ledger import (
     MEMORY_BREAKDOWN_FILE,
     read_memory_breakdown,
 )
-from neuronx_distributed_tpu.obs.perf import (
-    PERF_ATTRIBUTION_FILE,
-    summarize_perf,
-)
 from neuronx_distributed_tpu.obs.registry import read_histograms
 from neuronx_distributed_tpu.obs.tracing import (
     PHASE_NAMES,
@@ -54,11 +50,6 @@ from neuronx_distributed_tpu.obs.tracing import (
 # the run carried no health monitor), and --run-dir auto-discovers fleet
 # layouts (per-replica scalars/serving_stats subdirectories merged via
 # obs.aggregate, router_stats.jsonl rolled into the fleet section).
-# v5 (perf-attribution PR): required "perf" section (per-family roofline
-# attribution from perf_attribution.jsonl — device-time, achieved vs peak
-# FLOP/s and bytes/s, compute-/memory-bound classification, MFU/MBU and
-# tokens/s-ceiling rollup; replica streams merge additively; null when
-# the run carried no perf profiler).
 # v6 (fleet-autopilot PR): required "autopilot" section
 # (autopilot_actions.jsonl rollup — action table, per-action and
 # per-trigger counts, action rate over the covered mono span; null when
@@ -71,7 +62,10 @@ from neuronx_distributed_tpu.obs.tracing import (
 # swapper), and --compare gates on swap failures appearing in B when A's
 # swaps all committed (a deploy pipeline that starts refusing envelopes
 # under the same workload is a release regression).
-OBS_REPORT_SCHEMA = "obs_report_v7"
+# v8: v5's "perf" section, its ``sources`` and ``health`` entries and
+# the --compare MFU gate are gone with the roofline profiler that fed
+# them; the benchmark under ``benchmarks/`` is the one yardstick.
+OBS_REPORT_SCHEMA = "obs_report_v8"
 SUPERVISOR_EVENTS_FILE = "supervisor_events.jsonl"
 SERVING_STATS_FILE = "serving_stats.jsonl"
 ROUTER_STATS_FILE = "router_stats.jsonl"
@@ -472,20 +466,16 @@ def _summarize_memory(scalars: Dict[str, dict],
 def compare_resources(run_a: str, run_b: str,
                       compile_threshold: float = 0.0,
                       mem_threshold: float = 0.05,
-                      mfu_threshold: float = 0.05,
                       autopilot_threshold: float = 0.5) -> dict:
-    """Run-to-run compile/memory/alert/perf/autopilot regression diff
+    """Run-to-run compile/memory/alert/autopilot regression diff
     (``tools/obs_report.py --compare RUN_A RUN_B``): reads each run dir's
     ``compile_ledger.jsonl``, ``memory_breakdown.json``,
-    ``*alerts.jsonl``, ``*perf_attribution.jsonl`` and
-    ``*autopilot_actions.jsonl`` and flags B against A — more compiles
-    than ``(1 + compile_threshold) * A`` (or any storm in B), any
-    subsystem's peak bytes past ``(1 + mem_threshold) * A``'s, any alert
-    RULE that fired in B without firing in A (a new alert under the same
-    workload is a health regression, threshold-free), B's MFU sagging
-    below ``(1 - mfu_threshold) * A``'s (same workload, less of the
-    device's peak — the perf regression the roofline profiler exists to
-    catch), or B's autopilot action rate past
+    ``*alerts.jsonl`` and ``*autopilot_actions.jsonl`` and flags B
+    against A — more compiles than ``(1 + compile_threshold) * A`` (or
+    any storm in B), any subsystem's peak bytes past
+    ``(1 + mem_threshold) * A``'s, any alert RULE that fired in B without
+    firing in A (a new alert under the same workload is a health
+    regression, threshold-free), or B's autopilot action rate past
     ``(1 + autopilot_threshold) * A``'s (a controller that has to act
     more often under the same workload is flapping, or fighting a real
     regression upstream of it; actions appearing in B when A's autopilot
@@ -494,8 +484,8 @@ def compare_resources(run_a: str, run_b: str,
     committed, and any replica whose weights_version went non-monotonic
     (both threshold-free — a refused envelope or a version rollback under
     the same deploy pipeline is a release regression, not noise).
-    Returns ``{"a", "b", "compile", "memory", "alerts", "perf",
-    "autopilot", "weights", "regressions", "regressed", "markdown"}``."""
+    Returns ``{"a", "b", "compile", "memory", "alerts", "autopilot",
+    "weights", "regressions", "regressed", "markdown"}``."""
     def load(run_dir):
         cl_path = os.path.join(run_dir, COMPILE_LEDGER_FILE)
         mb_path = os.path.join(run_dir, MEMORY_BREAKDOWN_FILE)
@@ -505,18 +495,14 @@ def compare_resources(run_a: str, run_b: str,
                      if os.path.exists(mb_path) else None)
         alerts = summarize_alerts(
             sorted(glob.glob(os.path.join(run_dir, "*alerts.jsonl"))))
-        from neuronx_distributed_tpu.obs.aggregate import merge_perf_files
-
-        perf = summarize_perf(merge_perf_files(sorted(
-            glob.glob(os.path.join(run_dir, f"*{PERF_ATTRIBUTION_FILE}")))))
         autopilot = summarize_autopilot(sorted(glob.glob(
             os.path.join(run_dir, f"*{AUTOPILOT_ACTIONS_FILE}"))))
         weights = summarize_weights(sorted(glob.glob(
             os.path.join(run_dir, f"*{WEIGHT_SWAPS_FILE}"))))
-        return compile_sum, breakdown, alerts, perf, autopilot, weights
+        return compile_sum, breakdown, alerts, autopilot, weights
 
-    ca, ma, aa, perf_a, ap_a, wt_a = load(run_a)
-    cb, mb, ab, perf_b, ap_b, wt_b = load(run_b)
+    ca, ma, aa, ap_a, wt_a = load(run_a)
+    cb, mb, ab, ap_b, wt_b = load(run_b)
     regressions: List[str] = []
     lines = ["# Resource regression diff", "",
              f"- A: `{run_a}`", f"- B: `{run_b}`", ""]
@@ -587,24 +573,6 @@ def compare_resources(run_a: str, run_b: str,
                 f"alerts regressed: rule {name!r} fired "
                 f"{fb[name]['fired']}x in B (severity "
                 f"{fb[name]['severity']}), never in A")
-
-    ra = (perf_a or {}).get("rollup")
-    rb = (perf_b or {}).get("rollup")
-    if perf_a is not None or perf_b is not None:
-        lines += ["## Perf (roofline rollup)", "",
-                  "| metric | A | B |", "|---|---|---|"]
-        for key in ("mfu", "mbu", "pct_roofline", "device_ms"):
-            va = ra.get(key) if ra else None
-            vb = rb.get(key) if rb else None
-            fmt = (lambda v, k=key: "n/a" if v is None else
-                   (f"{v:,.1f}" if k == "device_ms" else f"{v:.1%}"))
-            lines.append(f"| {key} | {fmt(va)} | {fmt(vb)} |")
-        lines.append("")
-    if ra and rb and ra.get("mfu") and \
-            rb["mfu"] < ra["mfu"] * (1.0 - mfu_threshold):
-        regressions.append(
-            f"mfu regressed: {ra['mfu']:.2%} -> {rb['mfu']:.2%} "
-            f"(threshold {mfu_threshold:.0%})")
 
     if ap_a is not None or ap_b is not None:
         lines += ["## Autopilot (remediation actions)", "",
@@ -677,7 +645,6 @@ def compare_resources(run_a: str, run_b: str,
                                 ("subsystems", "total_bytes",
                                  "peak_total_bytes")}},
         "alerts": {"a": aa, "b": ab},
-        "perf": {"a": ra, "b": rb},
         "autopilot": {"a": ap_a, "b": ap_b},
         "weights": {"a": wt_a, "b": wt_b},
         "regressions": regressions,
@@ -1055,7 +1022,6 @@ def build_report(
     memory_breakdown_path: Optional[str] = None,
     alerts_paths: Sequence[str] = (),
     router_stats_path: Optional[str] = None,
-    perf_paths: Sequence[str] = (),
     autopilot_paths: Sequence[str] = (),
     weights_paths: Sequence[str] = (),
     tail: int = 10,
@@ -1076,7 +1042,6 @@ def build_report(
     timeline_paths = list(timeline_paths)
     trace_paths = list(trace_paths)
     alerts_paths = list(alerts_paths)
-    perf_paths = list(perf_paths)
     autopilot_paths = list(autopilot_paths)
     weights_paths = list(weights_paths)
     serving_stats_paths = ([serving_stats_path]
@@ -1103,10 +1068,6 @@ def build_report(
                     os.path.join(sub, f"*{TRACE_EVENTS_FILE}"))):
                 if q not in trace_paths:
                     trace_paths.append(q)
-            for q in sorted(glob.glob(
-                    os.path.join(sub, f"*{PERF_ATTRIBUTION_FILE}"))):
-                if q not in perf_paths:
-                    perf_paths.append(q)
             for q in sorted(glob.glob(
                     os.path.join(sub, f"*{WEIGHT_SWAPS_FILE}"))):
                 if q not in weights_paths:
@@ -1156,10 +1117,6 @@ def build_report(
         if memory_breakdown_path is None:
             q = os.path.join(run_dir, MEMORY_BREAKDOWN_FILE)
             memory_breakdown_path = q if os.path.exists(q) else None
-        for q in sorted(glob.glob(
-                os.path.join(run_dir, f"*{PERF_ATTRIBUTION_FILE}"))):
-            if q not in perf_paths:
-                perf_paths.append(q)
 
     scalar_records: List[dict] = []
     for p in scalar_paths:
@@ -1234,11 +1191,6 @@ def build_report(
                  if memory_breakdown_path
                  and os.path.exists(memory_breakdown_path) else None)
     memory_section = _summarize_memory(scalars, breakdown)
-    # fleet runs: per-replica attribution streams merge additively
-    # (device-time, flops and bytes sum; the rollup is rebuilt)
-    from neuronx_distributed_tpu.obs.aggregate import merge_perf_files
-
-    perf_section = summarize_perf(merge_perf_files(perf_paths))
     report = {
         "schema": OBS_REPORT_SCHEMA,
         "generated_at": time.time(),
@@ -1255,7 +1207,6 @@ def build_report(
             "memory_breakdown": memory_breakdown_path,
             "alerts": alerts_paths,
             "router_stats": router_stats_path,
-            "perf": perf_paths,
             "autopilot": autopilot_paths,
             "weights": weights_paths,
             "fleet_replicas": fleet_replicas,
@@ -1273,7 +1224,6 @@ def build_report(
         "alerts": alerts_section,
         "autopilot": autopilot_section,
         "weights": weights_section,
-        "perf": perf_section,
         "health": {
             "anomaly_count": len(anomalies),
             "host_blocked": host_blocked,
@@ -1312,15 +1262,6 @@ def build_report(
                 "swaps": weights_section["swaps"],
                 "failures": weights_section["failures"],
                 "monotonic": weights_section["monotonic"]}),
-            # slim perf rollup — the full per-family roofline table lives
-            # once, at the top-level "perf" section
-            "perf": (None if perf_section is None
-                     or perf_section.get("rollup") is None else {
-                         "mfu": perf_section["rollup"]["mfu"],
-                         "mbu": perf_section["rollup"]["mbu"],
-                         "pct_roofline":
-                             perf_section["rollup"]["pct_roofline"],
-                         "bound": perf_section["rollup"]["bound"]}),
             "total_collective_count": sum(
                 a.get("total_collective_count", 0) for a in audits),
             "total_collective_bytes": sum(
@@ -1461,15 +1402,6 @@ def render_markdown(report: dict) -> str:
             f"**{comp['storms']:.0f} storm(s)** after warmup, "
             f"{comp['thrash_warnings']:.0f} thrash warning(s), "
             f"{comp.get('evictions', 0):.0f} eviction(s); {hit}")
-    perf = report.get("perf")
-    if perf and perf.get("rollup"):
-        roll = perf["rollup"]
-        ceiling = (f"; tokens/s ceiling {roll['toks_per_s_ceiling']:,.0f}"
-                   if roll.get("toks_per_s_ceiling") else "")
-        lines.append(
-            f"- perf: MFU {roll['mfu']:.1%}, MBU {roll['mbu']:.1%}, "
-            f"{roll['pct_roofline']:.1%} of roofline "
-            f"({roll['bound']}-bound on {perf['device']}){ceiling}")
     memh = report.get("memory")
     if memh:
         top = ", ".join(f"{name} {nbytes / 2**20:,.1f}MiB"
@@ -1597,25 +1529,6 @@ def render_markdown(report: dict) -> str:
             lines.append(
                 f"| {name} | {f['compiles']} | {f['cold_ms']:.1f} | "
                 f"{f['distinct_keys']} | {f['evictions']} |")
-        lines.append("")
-
-    perf = report.get("perf")
-    if perf and perf.get("families"):
-        lines += [f"## Roofline attribution ({perf['device']})", "",
-                  "| family | calls | device ms | intensity | bound | "
-                  "% roofline | MFU | MBU |",
-                  "|---|---|---|---|---|---|---|---|"]
-        for name, f in sorted(perf["families"].items(),
-                              key=lambda kv: -kv[1]["device_ms"]):
-            ai = (f"{f['arithmetic_intensity']:.1f}"
-                  if f["arithmetic_intensity"] is not None else "n/a")
-            lines.append(
-                f"| {name} | {f['calls']:.0f} | {f['device_ms']:.1f} | "
-                f"{ai} | {f['bound']} | {f['pct_roofline']:.1%} | "
-                f"{f['mfu']:.1%} | {f['mbu']:.1%} |")
-        if perf.get("top_time_eaters"):
-            lines += ["", "Top time-eaters: "
-                      + ", ".join(perf["top_time_eaters"])]
         lines.append("")
 
     memr = report.get("memory")

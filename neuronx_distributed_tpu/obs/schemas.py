@@ -193,24 +193,6 @@ SCHEMAS: Dict[str, Dict[str, Any]] = {
         "total_bytes": _NUM, "peak_total_bytes": _NUM,
         "device": (dict, type(None)), "programs": dict, "top": list,
     },
-    # one line of perf_attribution.jsonl (obs.perf.PerfAttribution.dump)
-    # — one record per phase-fn family plus a "_total" rollup: device
-    # wall-time + call counts joined with the compiled program's
-    # flops/bytes against the DeviceSpec roofline.  arithmetic_intensity
-    # is null when the family moved no accounted bytes (cost model blind
-    # or truly zero); bound is "compute" | "memory"; pct_roofline is
-    # lower_bound/achieved (1.0 = at the roofline).  The "_total" record
-    # carries extra "tokens"/"toks_per_s_ceiling" keys (extras — this is
-    # a floor).
-    "perf_attribution": {
-        "schema": str, "family": str, "calls": _NUM, "device_ms": _NUM,
-        "flops": _NUM, "bytes": _NUM, "flops_per_s": _NUM,
-        "bytes_per_s": _NUM,
-        "arithmetic_intensity": (int, float, type(None)),
-        "bound": str, "lower_bound_ms": _NUM, "pct_roofline": _NUM,
-        "mfu": _NUM, "mbu": _NUM, "device": str, "peak_flops": _NUM,
-        "hbm_bytes_per_s": _NUM, "time": _NUM, "mono": _NUM,
-    },
     # tools/obs_report.py output document; v2 added the required "trace"
     # key (per-request waterfalls from trace_events.jsonl); v3 adds the
     # resource-ledger sections — "compile" (compile_ledger.jsonl rollup)
@@ -218,9 +200,7 @@ SCHEMAS: Dict[str, Dict[str, Any]] = {
     # the run carried no ledger; v4 (fleet health PR) adds the required
     # "alerts" section (alerts.jsonl rollup: firing count, worst severity,
     # per-rule edge counts and time-firing; null when the run carried no
-    # health monitor); v5 (perf attribution PR) adds the required "perf"
-    # section (perf_attribution.jsonl rollup: per-family roofline table +
-    # MFU/tokens-ceiling rollup; null when the run carried no perf layer);
+    # health monitor); v5 added a "perf" section that v8 removed;
     # v6 (autopilot PR) adds the required "autopilot" section
     # (autopilot_actions.jsonl rollup: action table, per-trigger/per-kind
     # counts, action rate; null when the run carried no autopilot); v7
@@ -233,7 +213,7 @@ SCHEMAS: Dict[str, Dict[str, Any]] = {
         "anomalies": list, "hlo_audits": list, "timeline": dict,
         "supervisor": (dict, type(None)), "trace": (dict, type(None)),
         "compile": (dict, type(None)), "memory": (dict, type(None)),
-        "alerts": (dict, type(None)), "perf": (dict, type(None)),
+        "alerts": (dict, type(None)),
         "autopilot": (dict, type(None)), "weights": (dict, type(None)),
     },
 }
@@ -405,20 +385,8 @@ REGISTRY_METRICS: Dict[str, str] = {
     "autopilot/admission_tightenings_total": "counter",
     "autopilot/rebalances_total": "counter",
     "autopilot/mode": "gauge",
-    # perf attribution (obs.perf.PerfAttribution): per-family device
-    # wall-time histograms on the hot path, the milli-scaled rollup gauges
-    # (mfu_milli = MFU fraction x 1e3 — gauge floats, and the health
-    # TrendRules watch these), and the cost-model degradation counter
-    # (compile rows whose cost_analysis() omitted keys — see
-    # utils.profiling.cost_report)
-    "perf/prefill_device_ms": "histogram",
-    "perf/prefill_chunk_device_ms": "histogram",
-    "perf/decode_step_device_ms": "histogram",
-    "perf/spec_round_device_ms": "histogram",
-    "perf/train_step_device_ms": "histogram",
-    "perf/mfu_milli": "gauge",
-    "perf/mbu_milli": "gauge",
-    "perf/roofline_pct_milli": "gauge",
+    # the compile ledger's cost-model degradation counter (compile rows
+    # whose cost_analysis() omitted keys — see utils.profiling.cost_report)
     "perf/cost_model_missing_total": "counter",
     # live weights (weights.swapper.WeightSwapper): hot-swap attempts and
     # failures, the end-to-end swap latency (load + validate + install),

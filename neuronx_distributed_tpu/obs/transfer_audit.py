@@ -56,8 +56,8 @@ class TransferAudit:
 
     ``registry`` (an ``obs.MetricRegistry``) receives the counters and
     host-blocked histograms; ``None`` keeps the audit free (time is still
-    accumulated on :attr:`blocked_s` for callers like ``bench.py`` that
-    report a fraction directly).  ``mode``:
+    accumulated on :attr:`blocked_s` for callers that report a fraction
+    directly).  ``mode``:
 
     - ``"off"``: :meth:`section` is a no-op (fetch/put still count);
     - ``"observe"``: sections are counted but transfers are not restricted;

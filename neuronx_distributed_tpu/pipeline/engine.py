@@ -475,10 +475,10 @@ def make_1f1b_loss_and_grad_fn(
       paid only on the PP path.  The schedule itself runs ``T = M + 2(P-1)``
       full fwd+bwd ticks for ``M`` useful pairs — ~2x the eager-1F1B bubble
       at equal M (``scheduler.bubble_fraction(..., "sync_1f1b")``), amortizing
-      identically with large M; measured against fill-drain autodiff it is
-      nonetheless equal-or-faster wall-clock at M >= 8 because its O(P)
-      circular stash replaces residuals that grow with M
-      (``docs/PP_SCHEDULE_NOTES.md``).  The backward is one uniform ``jax.vjp`` of a
+      identically with large M; its O(P) circular stash replaces the
+      residuals fill-drain autodiff keeps, which grow with M (no cell
+      measures a pipeline schedule on the chip: not measured).  The
+      backward is one uniform ``jax.vjp`` of a
       scalar-``where`` objective: the real loss on the last rank, an
       inner product ``sum(y * g_in)`` injecting the incoming cotangent on
       the others — the select's transpose zeroes head grads off the last

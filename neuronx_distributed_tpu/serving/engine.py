@@ -156,12 +156,12 @@ class _InFlight:
     request, occupancy generation)``, the sampled tokens where they lie on
     the device (what the step launched after it is fed; None for a
     speculative round, which keeps its last proposal instead), the weights
-    version and ``moe_seq`` it was launched under, and its batch span and
-    perf stamp — opened at the launch into an idle loop, or when the step
-    before it is collected, so the spans tile as the device's work does."""
+    version and ``moe_seq`` it was launched under, and its batch span —
+    opened at the launch into an idle loop, or when the step before it is
+    collected, so the spans tile as the device's work does."""
 
     __slots__ = ("packed", "active", "toks", "last_prop", "version",
-                 "moe_seq", "step", "family", "span", "t0")
+                 "moe_seq", "step", "family", "span")
 
     def __init__(self, packed, active, version, moe_seq, step, family,
                  toks=None, last_prop=None):
@@ -174,7 +174,6 @@ class _InFlight:
         self.step = step
         self.family = family    # "decode_step" | "spec_round"
         self.span = None
-        self.t0 = None
 
 
 #: the sampler's paths, in the order of ``_sample_rows``'s ``lax.switch``
@@ -584,7 +583,6 @@ class ServingEngine:
         compile_ledger: Any = None,
         memory_ledger: Any = None,
         health: Any = None,
-        perf: Any = None,
     ):
         attrs = ("make_page_pool", "prefill_chunk_pages", "decode_pages",
                  "insert_valid")
@@ -809,25 +807,6 @@ class ServingEngine:
         self._health = health
         if health is not None:
             health.attach_registry(self.registry)
-        # per-phase performance attribution (obs.perf.PerfAttribution,
-        # None = off; falls back to the Observability hub's when one is
-        # attached): device wall-time per phase family, stamped from the
-        # SAME clock reads the tracer spans use so the attribution sums to
-        # the traced wall-time exactly.  Guarded at every call site so the
-        # default path allocates nothing (the PERF_RECORDS discipline).
-        if perf is None and obs is not None:
-            perf = getattr(obs, "perf", None)
-        self._perf = perf
-        self._perf_t0: dict = {}  # rid -> prefill-phase start (engine clock)
-        if perf is not None:
-            perf.attach(registry=self.registry, ledger=compile_ledger)
-            # the _CompiledLRU first-call hook captures each program's
-            # flops/bytes onto its ledger row only when the model carries a
-            # perf layer (re-lowering is not free) — same persistence
-            # caveat as model.compile_ledger above
-            model.perf = perf
-            if draft is not None:
-                draft.perf = perf
         self.scheduler = SlotScheduler(
             self.B, self.C, self.T, max_queue=max_queue,
             page_gate=self._kv, reserve_extra=self._spec_k,
@@ -1107,8 +1086,6 @@ class ServingEngine:
             if rt is not None:
                 self.tracer.end(rt.get("root"), t=now, migrated=True,
                                 new_tokens=len(req.generated))
-        if self._perf is not None:
-            self._perf_t0.pop(request_id, None)
         return req
 
     def export_prefix(self, fingerprint: int) -> Optional[ChainExport]:
@@ -1174,10 +1151,6 @@ class ServingEngine:
         self._account.reset()
         if self.compile_ledger is not None:
             self.compile_ledger.declare_warmup_done("engine")
-        if self._perf is not None:
-            # warm-pass program executions must not inflate the cost join:
-            # phase device time only covers the measured window
-            self._perf.mark_warmup_done()
 
     def install_params(self, params: Any, version: int) -> None:
         """Commit point of a live weight swap (``weights.WeightSwapper``):
@@ -1347,9 +1320,8 @@ class ServingEngine:
     def _step_tail(self) -> None:
         """What a step does once its tokens are out (the span ``tail``; it
         launches nothing): the gauges — the pool's walk its free and
-        evictable pages — the watchdog on the engine's clock, the perf
-        rollup, the health rules, and the compile ledger's poll of the
-        shared sampler jits."""
+        evictable pages — the watchdog on the engine's clock, the health
+        rules, and the compile ledger's poll of the shared sampler jits."""
         self.registry.gauge("serving/queue_depth").set(self.scheduler.queue_depth)
         self.registry.gauge("serving/slots_active").set(self.scheduler.active_count)
         self._kv.export_gauges()
@@ -1370,10 +1342,6 @@ class ServingEngine:
                 "active=%d queued=%d)", self._steps, step_s,
                 self.step_timeout_s, self.scheduler.active_count,
                 self.scheduler.queue_depth)
-        if self._perf is not None:
-            # refresh the perf/* rollup gauges on the step cadence so the
-            # health TrendRules (mfu_sag / roofline_drift) see live values
-            self._perf.update_metrics()
         if self._health is not None:
             # rule evaluation rides the engine clock (alert edges share
             # the spans'/stats' timescale under a fake-clock harness)
@@ -1449,9 +1417,8 @@ class ServingEngine:
                        **kept) -> None:
         """Queue the program just launched for its collect, under what it
         was launched with: each row's occupancy generation, the weights
-        version, the model's ``moe_seq``.  ``t_launch`` (None: tracing and
-        perf off, or a step is in flight ahead of it) opens its batch
-        span."""
+        version, the model's ``moe_seq``.  ``t_launch`` (None: tracing off,
+        or a step is in flight ahead of it) opens its batch span."""
         rec = _InFlight(
             packed,
             [(slot, req, int(self._slot_gen[slot])) for slot, req in active],
@@ -1462,12 +1429,10 @@ class ServingEngine:
         self._inflight.append(rec)
 
     def _open_batch_span(self, rec: _InFlight, t0: float) -> None:
-        """Open ``rec``'s batch-level span and perf stamp at ``t0``: its
-        launch when the loop was idle, else the collect of the step before
-        it — the honest device window of a program queued behind another;
-        per-slot child spans land at collect time.  The perf layer shares
-        the stamp so its accounting matches the span."""
-        rec.t0 = t0
+        """Open ``rec``'s batch-level span at ``t0``: its launch when the
+        loop was idle, else the collect of the step before it — the honest
+        device window of a program queued behind another; per-slot child
+        spans land at collect time."""
         if self.tracer is not None:
             rec.span = self.tracer.begin(
                 rec.family, t=t0, step=rec.step, active=len(rec.active),
@@ -1479,10 +1444,7 @@ class ServingEngine:
         the next in-flight record's at the same instant."""
         if rec.span is not None:
             self.tracer.end(rec.span, t=now)
-        if self._perf is not None and rec.t0 is not None:
-            self._perf.note_phase(rec.family, (now - rec.t0) * 1e3)
-        if self._inflight and (self.tracer is not None
-                               or self._perf is not None):
+        if self._inflight and self.tracer is not None:
             self._open_batch_span(self._inflight[0], now)
 
     def dump_flight(self, reason: str) -> Optional[str]:
@@ -1519,10 +1481,6 @@ class ServingEngine:
                 if rec.span is not None:
                     tr.end(rec.span, t=now, aborted=True)
                     rec.span = None
-                    if self._perf is not None and rec.t0 is not None:
-                        self._perf.note_phase(rec.family,
-                                              (now - rec.t0) * 1e3)
-                    rec.t0 = None
             for rid, rt in list(self._rt.items()):
                 tr.end(rt.pop("phase", None), t=now, aborted=True)
                 tr.end(rt.get("root"), t=now, aborted=True)
@@ -1601,10 +1559,6 @@ class ServingEngine:
             req.preempted_ms += max(t_grant - req.parked_at, 0.0) * 1e3
             req.parked_at = None
         self._trace_begin_phase(req, "prefill", t=t_grant, slot=slot)
-        if self._perf is not None:
-            # the same grant instant the span starts at — per-family sums
-            # match the traced prefill wall-time exactly
-            self._perf_t0[req.request_id] = t_grant
         # pre-dispatch expiry: the sweep ran at step start, but a request
         # can expire between sweep and prefill — never burn a prefill (or
         # its first chunk) on a deadline that is already dead
@@ -1797,10 +1751,6 @@ class ServingEngine:
         # contiguous phases, so the waterfall sums to the request latency
         self._trace_end_phase(req, t=now)
         self._trace_begin_phase(req, "decode", t=now)
-        if self._perf is not None:
-            t0 = self._perf_t0.pop(req.request_id, None)
-            if t0 is not None:
-                self._perf.note_phase("prefill", (now - t0) * 1e3)
         # TTFT is a property of the REQUEST, not of this replica's
         # prefill: a migrated clone arrives with the source's first-token
         # instant already stamped (the user streamed their first token
@@ -1922,13 +1872,9 @@ class ServingEngine:
         cells = st.valid_row[off:off + width].reshape(n_pages, page) > 0
         self._count_kv_write(int(cells.sum()), int(cells.any(axis=1).sum()))
         tr = self.tracer
-        # one shared start stamp: the chunk span and its perf accounting
-        # measure the identical interval (attribution sums to the trace)
-        t0 = (self._clock() if tr is not None or self._perf is not None
-              else None)
         cspan = (tr.begin("prefill_chunk", request_id=st.req.request_id,
                           parent=self._trace_phase_of(st.req),
-                          t=t0,
+                          t=self._clock(),
                           tok_start=int(off), tok_end=int(off + width),
                           pages=n_pages)
                  if tr is not None else None)
@@ -1950,19 +1896,11 @@ class ServingEngine:
                 want_logits=last,
                 **({"state_row": slot} if self._recurrent else {}))
         except BaseException as e:
-            if t0 is not None:
-                t1 = self._clock()
-                if cspan is not None:
-                    tr.end(cspan, t=t1, failed=type(e).__name__)
-                if self._perf is not None:
-                    self._perf.note_phase("prefill_chunk", (t1 - t0) * 1e3)
-            raise
-        if t0 is not None:
-            t1 = self._clock()
             if cspan is not None:
-                tr.end(cspan, t=t1)
-            if self._perf is not None:
-                self._perf.note_phase("prefill_chunk", (t1 - t0) * 1e3)
+                tr.end(cspan, t=self._clock(), failed=type(e).__name__)
+            raise
+        if cspan is not None:
+            tr.end(cspan, t=self._clock())
         st.req.prefill_chunks += 1
         st.next_i += n_pages
         if not self._paged_kernel:
@@ -2343,8 +2281,8 @@ class ServingEngine:
         for slot, req in active:
             tok_idx[slot] = len(req.generated) + ahead[slot]
         before = self._inflight[-1] if self._inflight else None
-        t_launch = (self._clock() if before is None and (
-            self.tracer is not None or self._perf is not None) else None)
+        t_launch = (self._clock() if before is None
+                    and self.tracer is not None else None)
         # eager slicing of a stacked [3, B] array would bind scalar start
         # indices host-side (an implicit transfer the guard rejects), so the
         # per-step inputs stage as one explicit pytree put instead; a dirty
@@ -2430,8 +2368,7 @@ class ServingEngine:
         tok_idx = np.zeros((self.B,), np.int32)
         for slot, req in active:
             tok_idx[slot] = len(req.generated)
-        t_launch = (self._clock() if self.tracer is not None
-                    or self._perf is not None else None)
+        t_launch = self._clock() if self.tracer is not None else None
         offs_steps = self._offsets[None, :] + np.arange(k, dtype=np.int32)[:, None]
         tidx_steps = tok_idx[None, :] + np.arange(k, dtype=np.int32)[:, None]
         staged = [self._next_tok[:, None].copy(), self._offsets.copy(),
@@ -2819,9 +2756,4 @@ class ServingEngine:
             # per-class deadline attainment feeds the SLO burn-rate
             # windows: good = finished within its deadline
             self._health.note_output(out, now)
-        if self._perf is not None:
-            # committed tokens feed the serving tokens/s-ceiling rollup;
-            # drop any prefill stamp a failed admission left behind
-            self._perf.note_tokens(len(out.token_ids))
-            self._perf_t0.pop(req.request_id, None)
         return out

@@ -36,7 +36,7 @@ matter what the triggers do.  Every action emitted is a schema-checked
 The kill-switch — ``mode="page_only"`` — reverts to pager behavior
 within one evaluation cadence (the mode is read at the top of every
 evaluation), and autopilot-off follows the module-counter discipline
-(:data:`ACTIONS_EVALUATED`, like ``SPANS_CREATED``/``PERF_RECORDS``):
+(:data:`ACTIONS_EVALUATED`, like ``SPANS_CREATED``/``LEDGER_ROWS``):
 nothing in the serving hot path allocates for a controller that is not
 attached.
 """

@@ -112,13 +112,6 @@ class _CompiledLRU:
             (led.cache_hit if fn is not None else led.cache_miss)(self.name)
         if fn is not None:
             self._d.move_to_end(key)
-            perf = getattr(self.owner, "perf", None)
-            if perf is not None:
-                # every steady-state execution starts with a cache hit —
-                # together with the first (compiling) call counted in
-                # _timed_first_call this gives perf attribution the exact
-                # per-program execution count, no ledger math needed
-                perf.note_program_call(self._family(key))
         return fn
 
     def _family(self, key) -> str:
@@ -146,23 +139,10 @@ class _CompiledLRU:
             wall_ms = (time.perf_counter() - t0) * 1e3
             if self._d.get(key) is first_call:  # unwrap unless evicted
                 self._d[key] = fn
-            perf = getattr(self.owner, "perf", None)
-            if perf is not None:
-                perf.note_program_call(self._family(key))
             led = getattr(self.owner, "compile_ledger", None)
             if led is not None:
-                compiled = None
-                if perf is not None:
-                    # perf attribution wants the program's flops/bytes on
-                    # the ledger row; re-lowering after the first call hits
-                    # jax's tracing machinery but not device dispatch —
-                    # paid once per (family, key), only with perf on
-                    try:
-                        compiled = fn.lower(*args, **kwargs).compile()
-                    except Exception:  # noqa: BLE001 — cost capture is
-                        compiled = None  # best-effort, never load-bearing
                 led.record_compile(self._family(key), key, wall_ms,
-                                   kind="jit", compiled=compiled)
+                                   kind="jit")
             return out
 
         return first_call
@@ -1156,9 +1136,8 @@ class ParallelInferenceModel(_ServingBase):
         flag, adapters, validity/logits mode).  The leading key component
         keeps the classic per-phase family names (``decode_pages`` /
         ``decode_pages_lora`` / ``verify_pages`` / ``prefill_chunk_pages``)
-        so the compile ledger's per-family thrash detection and
-        ``obs.perf``'s program→phase attribution join keep working — but a
-        mixed spec × int8 × lora × chunked run now holds a handful of
+        so the compile ledger's per-family thrash detection keeps working —
+        but a mixed spec × int8 × lora × chunked run now holds a handful of
         parameterizations of ONE program family, not four divergent code
         paths racing the LRU."""
         import functools as _ft

@@ -308,13 +308,6 @@ def fit(
     # guards on `is not None`.
     compile_led = getattr(obs_rt, "compile_ledger", None)
     memory_led = getattr(obs_rt, "memory_ledger", None)
-    # perf attribution (Observability(perf=True)): every executed step's
-    # wall time lands on the "train_step" family; per-call flops/bytes
-    # come from the compile ledger's cost extras (the AOT audit row) or,
-    # ledger-less, from the model-flops accounting below.  None by
-    # default — every hook guards on `is not None` (PERF_RECORDS
-    # discipline).
-    perf_rt = getattr(obs_rt, "perf", None)
     if compile_led is not None:
         from neuronx_distributed_tpu.obs.compile_ledger import jit_cache_size
     if memory_led is not None:
@@ -468,12 +461,6 @@ def fit(
                 pstep, loss=ploss, grad_norm=pgrad, seq_per_sec=pt["seqs"],
                 step_time_s=pt["dispatch_s"] + wait_s, host_s=pt["dispatch_s"],
                 device_s=wait_s, data_wait_s=pt["data_wait_s"])
-            if perf_rt is not None:
-                # same wall the step_time metric carries — MFU over the
-                # time a step actually took, compile included at step 0
-                perf_rt.note_phase(
-                    "train_step", (pt["dispatch_s"] + wait_s) * 1e3)
-                perf_rt.update_metrics()
         if scalars:
             scalars.scalars(pstep, loss=ploss, grad_norm=pgrad,
                             seq_per_sec=pt["seqs"])
@@ -558,15 +545,6 @@ def fit(
                 two_d = [x for x in leaves if x.ndim >= 2]
                 tokens_per_batch = bsz * two_d[0].shape[1] if two_d else None
                 thr = Throughput(bsz)
-                if perf_rt is not None and compile_led is None \
-                        and flops_per_token and tokens_per_batch:
-                    # no compiled cost report to join against: the model
-                    # flops feed the roofline directly (bytes stay 0, so
-                    # the family classifies compute-bound — the honest
-                    # floor without a cost model)
-                    perf_rt.note_cost(
-                        "train_step", flops_per_token * tokens_per_batch,
-                        0.0)
             rng = jax.random.fold_in(rng0, step) if step_rng else None
             if obs_rt is not None and not obs_audited:
                 obs_audited = True
@@ -642,9 +620,6 @@ def fit(
                     step, loss=loss, grad_norm=grad_norm, seq_per_sec=seqs,
                     step_time_s=t_done - t0, host_s=t_dispatch - t0,
                     device_s=t_done - t_dispatch, data_wait_s=data_wait_s)
-                if perf_rt is not None:
-                    perf_rt.note_phase("train_step", (t_done - t0) * 1e3)
-                    perf_rt.update_metrics()
             if policy_rt is not None:
                 decision = policy_rt.decide(step, loss=loss,
                                             grad_norm=grad_norm,
@@ -820,13 +795,6 @@ def fit(
             toks_per_sec = thr.batch_size * len(thr.window) / max(
                 sum(thr.window), 1e-9) * (tokens_per_batch / thr.batch_size)
             summary["mfu"] = mfu(toks_per_sec, flops_per_token, peak_flops)
-        if perf_rt is not None:
-            roll = perf_rt.rollup()
-            if roll is not None:
-                # attribution-side MFU: device-spec roofline over every
-                # accounted step (vs the throughput-window mfu above)
-                summary["mfu_model"] = roll["mfu"]
-                summary["pct_roofline"] = roll["pct_roofline"]
         metrics.update(**summary)
         metrics.write()
 

@@ -63,8 +63,7 @@ def mfu(
     flops_per_token: float,
     peak_flops: float,
 ) -> float:
-    """Model FLOPs utilization against the chip's peak (north-star metric,
-    BASELINE.md: >=35% on v5e)."""
+    """Model FLOPs utilization against the chip's peak."""
     return tokens_per_sec * flops_per_token / peak_flops
 
 

@@ -5,7 +5,7 @@ chip time; the persistent cache turns the second run's into disk reads.  The
 cache directory is part of the cache key, so it must be the same path on
 every run: never a temp name, a pid or a time.
 
-Every entry point that compiles (``chip_smoke.py``, ``bench.py``,
+Every entry point that compiles (``chip_smoke.py``,
 ``tools/fleet_bench.py`` and the two launchers)
 calls :func:`configure_compile_cache` before its first compile, and nothing
 else in the tree names the cache-directory option.

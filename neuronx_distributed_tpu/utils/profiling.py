@@ -3,12 +3,103 @@
 The reference leans on external Neuron tools for device-level profiling;
 on TPU the XLA compiler itself reports per-executable FLOPs, HBM traffic and
 memory footprints.  ``cost_report`` turns that into one dict, and
-``roofline`` into a lower-bound step time — the quick sanity check that
-caught the round-2 super-peak bench number would have been one call."""
+``roofline`` into a lower-bound step time against the package's ONE table
+of published peaks (:data:`DEVICE_SPECS`, keyed by jax's ``device_kind``; a
+device that is not in it is an error, never a default)."""
 
 from __future__ import annotations
 
+import dataclasses
+import time
 from typing import Any, Dict, Optional
+
+
+@dataclasses.dataclass(frozen=True)
+class DeviceSpec:
+    """Peak compute + HBM bandwidth for one device kind — the two numbers
+    a roofline needs.  ``kind`` is jax's ``device.device_kind``."""
+
+    kind: str
+    peak_flops: float
+    hbm_bytes_per_s: float
+
+
+def _specs(peak_flops: float, hbm_bytes_per_s: float, *kinds: str):
+    return {k: DeviceSpec(k, peak_flops, hbm_bytes_per_s) for k in kinds}
+
+
+# THE peak table: published bf16 peak FLOP/s and HBM bytes/s per chip, keyed
+# by ``jax.devices()[0].device_kind`` exactly as jax reports it (both
+# spellings a kind is known under).  Source: Google Cloud TPU documentation,
+# the "System architecture" page of each version ("TPU v5e": 197 TFLOP/s
+# bf16, 819 GB/s; "TPU v4": 275, 1228; "TPU v5p": 459, 2765; "TPU v6e": 918,
+# 1640).  No entry, no number: :func:`device_spec` raises.
+DEVICE_SPECS: Dict[str, DeviceSpec] = {
+    **_specs(197e12, 819e9, "TPU v5 lite", "TPU v5e"),
+    **_specs(275e12, 1228e9, "TPU v4"),
+    **_specs(459e12, 2765e9, "TPU v5", "TPU v5p"),
+    **_specs(918e12, 1640e9, "TPU v6 lite", "TPU v6e"),
+}
+
+
+class UnknownDeviceError(LookupError):
+    """The device's kind is not in :data:`DEVICE_SPECS`."""
+
+
+def device_spec(device: Any = None) -> DeviceSpec:
+    """The :class:`DeviceSpec` of ``device`` (default: the first jax
+    device) from :data:`DEVICE_SPECS`.  Raises :class:`UnknownDeviceError`
+    for a kind the table does not hold — a CPU included: utilization and
+    roofline figures exist for known accelerators only."""
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    kind = str(device.device_kind)
+    try:
+        return DEVICE_SPECS[kind]
+    except KeyError:
+        raise UnknownDeviceError(
+            f"no published peaks for device kind {kind!r} (known: "
+            f"{sorted(DEVICE_SPECS)}); add it to "
+            "utils.profiling.DEVICE_SPECS with its source") from None
+
+
+_CPU_SPEC: Optional[DeviceSpec] = None
+
+
+def calibrate_cpu_spec() -> DeviceSpec:
+    """An explicit cost MODEL for CPU tests that need peaks to hand to
+    :func:`roofline` without a chip: one fixed matmul + one fixed copy,
+    measured once per process and cached, so every classification in a
+    run sees the same numbers.  Never chosen for a caller —
+    :func:`device_spec` raises on a CPU — and its ``kind`` is ``"cpu"``,
+    so it cannot pass for a device's published peaks."""
+    global _CPU_SPEC
+    if _CPU_SPEC is not None:
+        return _CPU_SPEC
+    import numpy as np
+
+    n = 256
+    a = np.ones((n, n), np.float32)
+    b = np.ones((n, n), np.float32)
+    a @ b  # warm BLAS dispatch
+    peak = 0.0
+    for _ in range(3):
+        t0 = time.perf_counter()
+        a @ b
+        peak = max(peak, 2.0 * n ** 3 / max(time.perf_counter() - t0, 1e-9))
+    src = np.ones(4 << 20, np.uint8)
+    dst = np.empty_like(src)
+    np.copyto(dst, src)  # warm
+    bw = 0.0
+    for _ in range(3):
+        t0 = time.perf_counter()
+        np.copyto(dst, src)
+        # read + write of the buffer per copy
+        bw = max(bw, 2.0 * src.nbytes / max(time.perf_counter() - t0, 1e-9))
+    _CPU_SPEC = DeviceSpec("cpu", max(peak, 1e9), max(bw, 1e9))
+    return _CPU_SPEC
 
 
 def memory_analysis(compiled: Any) -> Optional[Dict[str, float]]:
@@ -50,9 +141,9 @@ def cost_report(compiled: Any, collectives: bool = False) -> Dict[str, Any]:
     ca = compiled.cost_analysis() or {}
     # newer jax backends omit keys entirely instead of reporting 0 — a
     # missing key silently dropped here used to surface downstream as NaN
-    # arithmetic intensities in the perf-attribution join.  Default to 0.0
-    # and COUNT the degradation so consumers can tell "program moves no
-    # bytes" from "the cost model went blind".
+    # arithmetic intensities.  Default to 0.0 and COUNT the degradation so
+    # consumers can tell "program moves no bytes" from "the cost model went
+    # blind".
     missing = 0
     for key in ("flops", "bytes accessed", "transcendentals"):
         if key in ca:
@@ -87,7 +178,7 @@ def roofline(
     ``max(flops/peak, bytes/bandwidth)`` — a measured step time below it
     was not synchronized with the device, one far above it indicates
     overhead or serialization to chase.  The peaks are the caller's
-    device's, from the one table (``obs.perf.device_spec``)."""
+    device's, from the one table (:func:`device_spec`)."""
     flops = report.get("flops", 0.0)
     bytes_ = report.get("bytes_accessed", 0.0)
     t_compute = flops / peak_flops if peak_flops else 0.0
@@ -113,8 +204,6 @@ def jit_cost_report(fn, *example_args, peak_flops: Optional[float] = None,
     compiled = jax.jit(fn).lower(*example_args).compile()
     rep = cost_report(compiled)
     if peak_flops is None or hbm_bytes_per_s is None:
-        from neuronx_distributed_tpu.obs.perf import device_spec
-
         spec = device_spec()
         peak_flops = peak_flops or spec.peak_flops
         hbm_bytes_per_s = hbm_bytes_per_s or spec.hbm_bytes_per_s

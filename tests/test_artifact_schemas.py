@@ -33,8 +33,7 @@ def test_schema_list_is_complete():
             "serving_stats", "supervisor_event",
             "router_stats", "trace_event",
             "compile_ledger", "memory_breakdown", "alert",
-            "perf_attribution", "autopilot_action",
-            "weight_swap"} <= set(SCHEMAS)
+            "autopilot_action", "weight_swap"} <= set(SCHEMAS)
 
 
 def test_committed_golden_scalars_validate():
@@ -456,64 +455,6 @@ def test_alert_schema_and_registry_metrics(tmp_path):
     validate_record("obs_report", report)
     assert report["alerts"]["firing"] == 1
     assert report["alerts"]["worst_severity"] == "page"
-
-
-def test_perf_attribution_schema_and_report_section(tmp_path):
-    """perf_attribution.jsonl smoke: the PerfAttribution layer's own dump
-    validates against the checked-in schema (the live engine/fit emitter
-    paths are covered end-to-end in tests/test_perf.py), the perf/*
-    registry metrics are declared with their kinds, hand-built records
-    missing roofline fields are rejected, and the obs report grows the
-    perf section + markdown table from the artifact."""
-    from neuronx_distributed_tpu.obs.perf import (
-        DeviceSpec,
-        PerfAttribution,
-        read_perf_attribution,
-    )
-    from neuronx_distributed_tpu.obs.schemas import (
-        REGISTRY_METRICS,
-        validate_registry_metrics,
-    )
-
-    assert {"perf/prefill_device_ms", "perf/prefill_chunk_device_ms",
-            "perf/decode_step_device_ms", "perf/spec_round_device_ms",
-            "perf/train_step_device_ms", "perf/mfu_milli", "perf/mbu_milli",
-            "perf/roofline_pct_milli",
-            "perf/cost_model_missing_total"} <= set(REGISTRY_METRICS)
-
-    spec = DeviceSpec("test", 1e12, 1e11)
-    reg = MetricRegistry()
-    path = str(tmp_path / "perf_attribution.jsonl")
-    perf = PerfAttribution(path=path, registry=reg, spec=spec)
-    perf.note_cost("prefill", 2e9, 1e8)       # per-call flops / bytes
-    perf.note_phase("prefill", 10.0, calls=2.0)
-    perf.note_cost("decode_step", 1e7, 1e8)
-    perf.note_phase("decode_step", 5.0, calls=8.0)
-    perf.note_tokens(64.0)
-    perf.update_metrics()
-    assert perf.dump() == path
-    assert validate_jsonl("perf_attribution", path) == 3  # 2 fams + _total
-    validate_registry_metrics(reg)
-
-    recs = read_perf_attribution(path)
-    assert [r["family"] for r in recs] == ["decode_step", "prefill", "_total"]
-    assert recs[-1]["tokens"] == 64.0
-    with pytest.raises(ValueError, match="missing required field"):
-        bad = dict(recs[0])
-        del bad["bound"]
-        validate_record("perf_attribution", bad)
-    with pytest.raises(ValueError, match="expected"):
-        validate_record("perf_attribution", dict(recs[0], device_ms="slow"))
-
-    from neuronx_distributed_tpu.obs.report import build_report, render_markdown
-
-    report = build_report(run_dir=str(tmp_path))
-    validate_record("obs_report", report)
-    assert report["perf"]["rollup"]["mfu"] > 0.0
-    assert set(report["perf"]["families"]) == {"prefill", "decode_step"}
-    assert report["health"]["perf"]["bound"] in ("compute", "memory")
-    md = render_markdown(report)
-    assert "## Roofline attribution" in md and "- perf:" in md
 
 
 def test_trace_events_schema(tmp_path):

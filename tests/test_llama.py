@@ -1,5 +1,5 @@
 """Llama end-to-end tests: TP+SP+GQA+ZeRO-1 training on the 8-device mesh —
-the framework's BASELINE config-3 slice (Llama-shaped model, TP=8, SP,
+the reference's Llama-2-7B pretrain slice (Llama-shaped model, TP=8, SP,
 ZeRO-1), mirroring the reference's model-level convergence tests."""
 
 import jax
@@ -96,7 +96,7 @@ def test_gqa_llama_with_kv_multiplier(devices8):
 
 
 def test_train_loop_tp_sp_zero1(devices8):
-    """BASELINE config 3: TP+SP+ZeRO-1 — loss must go down."""
+    """TP+SP+ZeRO-1 — loss must go down."""
     cfg = LlamaConfig.tiny(dtype=jnp.float32, param_dtype=jnp.float32)
     config = nxd.training_config(tensor_parallel_size=2, learning_rate=1e-3,
                                  compute_dtype="float32")

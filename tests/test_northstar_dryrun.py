@@ -1,4 +1,4 @@
-"""Pin the BASELINE.md north-star topology (VERDICT r4 next-step #2).
+"""Pin the north-star topology: Llama-2-7B pretrain on a v5e-32.
 
 Runs ``__graft_entry__.dryrun_northstar(32)`` as a subprocess: a 32-device
 virtual CPU mesh instantiated as tp=8 x dp=4 with sequence parallelism,

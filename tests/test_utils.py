@@ -196,3 +196,54 @@ def test_cost_report_and_roofline():
     rl = rep["roofline"]
     assert rl["lower_bound_s"] == max(rl["compute_s"], rl["memory_s"]) > 0
     assert rl["bound"] in ("compute", "memory")
+
+
+# -- device specs -------------------------------------------------------------
+
+def test_device_spec_is_keyed_by_device_kind():
+    from types import SimpleNamespace as NS
+
+    from neuronx_distributed_tpu.utils.profiling import device_spec
+
+    # exact device_kind strings, both spellings jax knows a chip under
+    assert device_spec(NS(device_kind="TPU v5 lite")).peak_flops == 197e12
+    assert device_spec(NS(device_kind="TPU v5e")).hbm_bytes_per_s == 819e9
+    assert device_spec(NS(device_kind="TPU v5p")).peak_flops == 459e12
+    assert device_spec(NS(device_kind="TPU v6 lite")).kind == "TPU v6 lite"
+
+
+def test_unknown_device_kind_raises():
+    """One peak table, no default: an unknown accelerator — and the CPU
+    the tests run on — has no roofline; a caller that wants a cost model
+    there passes one explicitly."""
+    from types import SimpleNamespace as NS
+
+    from neuronx_distributed_tpu.utils.profiling import (
+        UnknownDeviceError,
+        calibrate_cpu_spec,
+        device_spec,
+    )
+
+    with pytest.raises(UnknownDeviceError, match="mystery accelerator"):
+        device_spec(NS(device_kind="mystery accelerator"))
+    with pytest.raises(UnknownDeviceError):
+        device_spec()  # jax.devices()[0] is the CPU here
+    a = calibrate_cpu_spec()
+    assert a is calibrate_cpu_spec()    # calibrated once, cached
+    assert a.kind == "cpu" and a.peak_flops >= 1e9 and a.hbm_bytes_per_s >= 1e9
+
+
+# -- cost model ---------------------------------------------------------------
+
+def test_cost_report_defaults_missing_keys_to_zero():
+    from conftest import FakeCompiled
+    from neuronx_distributed_tpu.utils.profiling import cost_report
+
+    rep = cost_report(FakeCompiled({"flops": 5.0}))
+    assert rep["flops"] == 5.0
+    assert rep["bytes_accessed"] == 0.0         # defaulted, not absent
+    assert rep["transcendentals"] == 0.0
+    assert rep["cost_keys_missing"] == 2
+    full = cost_report(FakeCompiled(
+        {"flops": 1.0, "bytes accessed": 2.0, "transcendentals": 3.0}))
+    assert "cost_keys_missing" not in full

@@ -54,11 +54,11 @@ FLASH_KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 def _pct_roofline(flops: float, bytes_accessed: float, seconds: float):
     """Fraction of the device roofline a measured kernel time achieves:
     lower-bound time (compute- or bandwidth-limited, whichever dominates)
-    over observed time, against the one peak table (``obs.perf``).  A CPU
-    sweep (``--cpu``) has no roofline and reports None."""
+    over observed time, against the one peak table (``utils.profiling``).
+    A CPU sweep (``--cpu``) has no roofline and reports None."""
     import jax
 
-    from neuronx_distributed_tpu.obs.perf import device_spec
+    from neuronx_distributed_tpu.utils.profiling import device_spec
 
     if jax.devices()[0].platform == "cpu":
         return None

@@ -38,8 +38,7 @@ in each dir): markdown table to stdout (or ``--markdown``), JSON via
 ``--out``, and a NONZERO exit code when run B regressed — more compiles
 than ``(1 + --compile-regress-threshold) * A``, new compile storms, any
 subsystem's peak bytes past ``(1 + --mem-regress-threshold) * A``'s, any
-alert rule firing in B that never fired in A, B's perf-attribution
-rollup MFU sagging below ``(1 - --mfu-regress-threshold) * A``'s, or B's
+alert rule firing in B that never fired in A, or B's
 autopilot action rate past ``(1 + --autopilot-regress-threshold) * A``'s
 (a controller acting more often under the same workload is flapping or
 fighting a real regression), weight-swap FAILURES appearing in B when
@@ -98,12 +97,6 @@ def main(argv=None) -> int:
                         "auto-detected in --run-dir and its replica "
                         "subdirs) — builds the alerts section (firing "
                         "count, worst severity, per-rule time-firing)")
-    p.add_argument("--perf", action="append", default=[],
-                   help="perf_attribution.jsonl file (repeatable; "
-                        "*perf_attribution.jsonl auto-detected in --run-dir "
-                        "and its replica subdirs) — builds the per-family "
-                        "roofline attribution section (device time, MFU/MBU, "
-                        "compute-/memory-bound, tokens/s ceiling)")
     p.add_argument("--router-stats", default=None,
                    help="router_stats.jsonl path (auto-detected in "
                         "--run-dir) — rolls fleet terminal records into "
@@ -131,11 +124,6 @@ def main(argv=None) -> int:
     p.add_argument("--mem-regress-threshold", type=float, default=0.05,
                    help="--compare: allowed fractional growth in any "
                         "subsystem's peak bytes before rc 1 (default 5%%)")
-    p.add_argument("--mfu-regress-threshold", type=float, default=0.05,
-                   help="--compare: allowed fractional DROP in run B's "
-                        "rollup MFU below A's before rc 1 (default 5%%; "
-                        "only applies when both runs carry perf "
-                        "attribution)")
     p.add_argument("--autopilot-regress-threshold", type=float, default=0.5,
                    help="--compare: allowed fractional growth in run B's "
                         "autopilot action rate over A's before rc 1 "
@@ -155,13 +143,11 @@ def main(argv=None) -> int:
             args.compare[0], args.compare[1],
             compile_threshold=args.compile_regress_threshold,
             mem_threshold=args.mem_regress_threshold,
-            mfu_threshold=args.mfu_regress_threshold,
             autopilot_threshold=args.autopilot_regress_threshold)
         if args.out:
             doc = {k: diff[k] for k in ("a", "b", "compile", "memory",
-                                        "alerts", "perf", "autopilot",
-                                        "weights", "regressions",
-                                        "regressed")}
+                                        "alerts", "autopilot", "weights",
+                                        "regressions", "regressed")}
             with open(args.out, "w") as f:
                 f.write(json.dumps(doc, indent=2) + "\n")
         if args.markdown:
@@ -177,7 +163,7 @@ def main(argv=None) -> int:
     if not (args.run_dir or args.scalar_dir or args.scalars or args.flight
             or args.hlo_audit or args.timeline or args.supervisor_events
             or args.trace or args.compile_ledger or args.memory_breakdown
-            or args.alerts or args.perf or args.router_stats
+            or args.alerts or args.router_stats
             or args.autopilot or args.weight_swaps):
         p.error("nothing to report on: pass --run-dir or explicit artifact paths")
 
@@ -205,7 +191,6 @@ def main(argv=None) -> int:
         memory_breakdown_path=args.memory_breakdown,
         alerts_paths=args.alerts,
         router_stats_path=args.router_stats,
-        perf_paths=args.perf,
         autopilot_paths=args.autopilot,
         weights_paths=args.weight_swaps,
         tail=args.tail,
