@@ -912,6 +912,151 @@ def deepseek_v2_params_to_hf(params: Mapping[str, Any], cfg
     return _latent_params_to_hf(params, cfg)
 
 
+# -- LFM2 (``model_type`` ``lfm2_moe``) -----------------------------------------
+#
+# The source's tensor names are ASSUMED (no checkpoint index is at hand): the
+# family's public modeling code's — ``operator_norm`` / ``ffn_norm``, the
+# gated short convolution under ``conv``, attention under ``self_attn`` with
+# ``q_layernorm`` / ``k_layernorm`` and ``out_proj``, a feed-forward part's
+# ``w1`` (gate), ``w3`` (up), ``w2`` (down), a routed block's ``gate`` and
+# ``expert_bias``, the final norm as ``embedding_norm`` — and no ``lm_head``
+# (the head is the embedding table).
+
+
+def lfm2_moe_config_from_hf(hf_config: Mapping[str, Any], **overrides):
+    """An ``lfm2_moe`` ``config.json`` -> :class:`~..models.llama.LlamaConfig`:
+    ``layer_types`` as ``mixer_types`` (``"conv"`` | ``"attention"``), the
+    first ``num_dense_layers`` feed-forward parts dense and the rest routed
+    by sigmoid scores with a correction bias, renormalised with 1e-6, no
+    balance term in the loss; per-head q/k norm; tied embeddings.
+    ``moe_experts_held=(first, count)`` (an override) makes it one
+    expert-parallel rank's share."""
+    from neuronx_distributed_tpu.models.llama import LlamaConfig
+
+    c = hf_config
+    L, dense = c["num_hidden_layers"], c["num_dense_layers"]
+    if c.get("conv_bias"):
+        raise ValueError("lfm2_moe: a convolution bias is not read")
+    return LlamaConfig(**{**dict(
+        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+        intermediate_size=c["intermediate_size"],
+        moe_intermediate_size=c["moe_intermediate_size"], num_layers=L,
+        num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_key_value_heads"],
+        max_seq_len=c["max_position_embeddings"],
+        rope_theta=float(c["rope_theta"]), rms_eps=c["norm_eps"],
+        conv_L_cache=c["conv_L_cache"],
+        mixer_types=tuple("attention" if t == "full_attention" else t
+                          for t in c["layer_types"]),
+        ffn_types=("mlp",) * dense + ("moe",) * (L - dense),
+        num_experts=c["num_experts"], moe_top_k=c["num_experts_per_tok"],
+        moe_dispatch="dropless", moe_router_scores="sigmoid",
+        moe_router_bias=bool(c.get("use_expert_bias", True)),
+        moe_norm_topk_prob=c["norm_topk_prob"],
+        moe_route_scale=float(c["routed_scaling_factor"]),
+        moe_aux_loss=False, qk_norm_per_head=True, tie_word_embeddings=True,
+    ), **overrides})
+
+
+def lfm2_moe_params_from_hf(state_dict: Mapping[str, Any], cfg
+                            ) -> Dict[str, Any]:
+    """An ``lfm2_moe`` state dict (names as assumed above) -> the param tree
+    of :class:`~..models.llama.LlamaForCausalLM` under
+    :func:`lfm2_moe_config_from_hf`'s layer lists: a convolution ``[C, 1,
+    L]`` as ``conv_weight [L, C]``, a routed layer's HELD experts stacked
+    (``cfg.moe_experts_held``), every Linear transposed in-major."""
+    sd = {k: _np(v) for k, v in state_dict.items()}
+    H = cfg.hidden_size
+    first, count = cfg.moe_experts_held or (0, cfg.num_experts)
+    model: Dict[str, Any] = {
+        "embed": {"embedding": sd["model.embed_tokens.weight"]},
+        "final_norm": {"weight": sd["model.embedding_norm.weight"]},
+    }
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}."
+        lyr: Dict[str, Any] = {
+            "input_norm": {"weight": sd[p + "operator_norm.weight"]},
+            "post_attn_norm": {"weight": sd[p + "ffn_norm.weight"]}}
+        if cfg.mixer(i) == "conv":
+            lyr["attn"] = {
+                "in_proj": {"kernel": sd[p + "conv.in_proj.weight"].T},
+                "conv_weight": sd[p + "conv.conv.weight"][:, 0, :].T,
+                "out_proj": {"kernel": sd[p + "conv.out_proj.weight"].T}}
+        else:
+            a = p + "self_attn."
+            nq, nkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+            lyr["attn"] = {
+                "qkv": {
+                    "q_kernel": sd[a + "q_proj.weight"].T.reshape(H, nq, d),
+                    "k_kernel": sd[a + "k_proj.weight"].T.reshape(H, nkv, d),
+                    "v_kernel": sd[a + "v_proj.weight"].T.reshape(H, nkv, d)},
+                "q_norm": {"weight": sd[a + "q_layernorm.weight"]},
+                "k_norm": {"weight": sd[a + "k_layernorm.weight"]},
+                "o_proj": {"kernel": sd[a + "out_proj.weight"].T}}
+        f = p + "feed_forward."
+        if cfg.ffn(i) == "mlp":
+            lyr["mlp"] = {
+                "gate_up": {"kernel": np.stack(
+                    [sd[f + "w1.weight"].T, sd[f + "w3.weight"].T], axis=1)},
+                "down": {"kernel": sd[f + "w2.weight"].T}}
+        else:
+            experts = range(first, first + count)
+            lyr["moe_mlp"] = {
+                "router": sd[f + "gate.weight"].T,
+                "router_bias": sd[f + "expert_bias"],
+                **{ours: np.stack([sd[f + f"experts.{e}.{theirs}.weight"].T
+                                   for e in experts])
+                   for ours, theirs in (("gate", "w1"), ("up", "w3"),
+                                        ("down", "w2"))}}
+        model[f"layer_{i}"] = lyr
+    return {"params": {"model": model}}
+
+
+def lfm2_moe_params_to_hf(params: Mapping[str, Any], cfg
+                          ) -> Dict[str, np.ndarray]:
+    """The inverse of :func:`lfm2_moe_params_from_hf` (of a held share: the
+    held experts under their own numbers among the routed ones)."""
+    model = params.get("params", params)["model"]
+    H = cfg.hidden_size
+    first, count = cfg.moe_experts_held or (0, cfg.num_experts)
+    out: Dict[str, np.ndarray] = {
+        "model.embed_tokens.weight": _np(model["embed"]["embedding"]),
+        "model.embedding_norm.weight": _np(model["final_norm"]["weight"]),
+    }
+    for i in range(cfg.num_layers):
+        lyr, p = model[f"layer_{i}"], f"model.layers.{i}."
+        out[p + "operator_norm.weight"] = _np(lyr["input_norm"]["weight"])
+        out[p + "ffn_norm.weight"] = _np(lyr["post_attn_norm"]["weight"])
+        a = lyr["attn"]
+        if cfg.mixer(i) == "conv":
+            out[p + "conv.in_proj.weight"] = _np(a["in_proj"]["kernel"]).T
+            out[p + "conv.conv.weight"] = _np(a["conv_weight"]).T[:, None, :]
+            out[p + "conv.out_proj.weight"] = _np(a["out_proj"]["kernel"]).T
+        else:
+            s = p + "self_attn."
+            for name, key in (("q_proj", "q_kernel"), ("k_proj", "k_kernel"),
+                              ("v_proj", "v_kernel")):
+                out[s + name + ".weight"] = _np(a["qkv"][key]).reshape(H, -1).T
+            out[s + "q_layernorm.weight"] = _np(a["q_norm"]["weight"])
+            out[s + "k_layernorm.weight"] = _np(a["k_norm"]["weight"])
+            out[s + "out_proj.weight"] = _np(a["o_proj"]["kernel"]).T
+        f = p + "feed_forward."
+        if cfg.ffn(i) == "mlp":
+            gate_up = _np(lyr["mlp"]["gate_up"]["kernel"])
+            out[f + "w1.weight"] = gate_up[:, 0, :].T
+            out[f + "w3.weight"] = gate_up[:, 1, :].T
+            out[f + "w2.weight"] = _np(lyr["mlp"]["down"]["kernel"]).T
+        else:
+            moe = lyr["moe_mlp"]
+            out[f + "gate.weight"] = _np(moe["router"]).T
+            out[f + "expert_bias"] = _np(moe["router_bias"])
+            for ours, theirs in (("gate", "w1"), ("up", "w3"), ("down", "w2")):
+                w = _np(moe[ours])
+                for n in range(count):
+                    out[f + f"experts.{first + n}.{theirs}.weight"] = w[n].T
+    return out
+
+
 def _neox_deinterleave(w_qkv: np.ndarray, b_qkv: np.ndarray, num_heads: int, head_dim: int):
     """HF NeoX fused QKV rows are per-head interleaved ``[n,(q|k|v),d]``;
     the framework's fused axis wants ``[in, 3, n*d]``."""

@@ -68,6 +68,33 @@ def _causal_lm_loss_parts(module, params, batch, rng=None):
     return loss_sum, tok, aux
 
 
+def moe_step_stats(variables) -> dict:
+    """What a TRAIN step hands on of the ``moe_stats`` collection its routed
+    blocks sowed (``parallel/moe.py``, dropless path), stacked over the
+    routed layers in their order: ``{"moe_load": [L, E]}``, the valid
+    assignments each held expert took, and, where the layers hold a share
+    of their experts, ``"moe_assigned" [L]``, the valid assignments held or
+    not.  ``{}`` for a model that sows none.  Each row's choice, ``[tokens,
+    K]`` a layer, stays behind (``models.llama.moe_layer_stats`` reads it
+    from the collection for a check)."""
+    import re
+
+    from flax import traverse_util
+
+    flat = traverse_util.flatten_dict(dict(variables.get("moe_stats", {})))
+
+    def layer(path):
+        return [int(n) for p in path for n in re.findall(r"\d+", p)]
+
+    out = {}
+    for key, name in (("load", "moe_load"), ("assigned", "moe_assigned")):
+        rows = [v[-1] for p, v in sorted(flat.items(), key=lambda kv: layer(
+            kv[0])) if p[-1] == key]
+        if rows:
+            out[name] = jnp.stack(rows)
+    return out
+
+
 def causal_lm_loss(module, params, batch, rng=None) -> jax.Array:
     """Next-token loss over vocab-sharded logits; ``batch = {ids, labels[,
     mask]}``, labels < 0 (ignore convention) drop out of the mean.  Works for
@@ -125,7 +152,12 @@ def make_causal_lm_loss_sum(chunk_size: int = 0):
 
     Requires a module exposing the ``hidden(ids, ...)`` / ``head(h)`` method
     pair (the Llama family does); ``chunk_size == 0`` falls back to the
-    plain :func:`causal_lm_loss_sum`."""
+    plain :func:`causal_lm_loss_sum`.
+
+    A model whose routed blocks sow ``moe_stats`` gets a third value,
+    :func:`moe_step_stats`: ``make_train_step`` hands it on in the step's
+    metrics (summed over the microbatches of an accumulated step), and
+    ``fit()`` fetches it with the loss."""
     if chunk_size == 0:
         return causal_lm_loss_sum
 
@@ -144,7 +176,8 @@ def make_causal_lm_loss_sum(chunk_size: int = 0):
                     )
                 kwargs[key] = batch[key]
         h, variables = module.apply(
-            params, batch["ids"], mutable=["losses"], method="hidden", **kwargs
+            params, batch["ids"], mutable=["losses", "moe_stats"],
+            method="hidden", **kwargs
         )
         labels = batch["labels"]
         mask = batch.get("mask")
@@ -188,7 +221,8 @@ def make_causal_lm_loss_sum(chunk_size: int = 0):
         aux_terms = jax.tree.leaves(variables.get("losses", {}))
         if aux_terms:
             loss_sum = loss_sum + MOE_AUX_COEF * jnp.mean(jnp.stack(aux_terms)) * tok
-        return loss_sum, tok
+        stats = moe_step_stats(variables)
+        return (loss_sum, tok, stats) if stats else (loss_sum, tok)
 
     return loss_fn
 

@@ -1,6 +1,7 @@
 """The mixers a ``LlamaConfig.mixer_types`` layer list may name beside the
 block's own ``"attention"`` (and ``"none"``: a layer that is its
-feed-forward part alone) — MiniCPM-SALA's two and Nemotron-H's Mamba-2:
+feed-forward part alone) — MiniCPM-SALA's two, Nemotron-H's Mamba-2,
+DeepSeek-V2's latent attention and LFM2's gated short convolution:
 
 - ``"lightning-attn"``: decayed linear attention (``ops.lightning_attention``)
   — per-head RMSNorm of q and k, RoPE, an RMSNorm over the concatenated
@@ -27,11 +28,22 @@ feed-forward part alone) — MiniCPM-SALA's two and Nemotron-H's Mamba-2:
   and a prefill chunk EXPANDED (keys and values up-projected a page inside
   the walk).
 
+- ``"conv"``: LFM2's gated short convolution — ``B, C, x = split3(in_proj
+  h)``, a causal depthwise convolution of ``conv_L_cache`` taps over ``B *
+  x`` with NO activation and no bias, ``out_proj(C * taps)``.  Scopes
+  ``conv_in``, ``conv_gate``, ``conv_taps``, ``conv_out``.
+
 Projections, norms and the cache protocol are the block's own
 (``GQAQKVColumnParallelLinear``, ``RowParallelLinear``, ``RMSNorm``): a
 mixer is called like ``LlamaAttention`` and returns ``(out, new cache)``.
-Serving only: neither has a backward pass here, and tensor parallelism over
-their heads is not carried through (the engine refuses tp > 1).
+What each can do, mixer by mixer: ``"attention"`` trains (flash kernels
+with their backward, every parallel layout) and serves; ``"conv"`` TRAINS
+and has no cached call (its gradients are tested against the float32
+reference, ``tests/test_lfm2_moe.py``; the serving engine refuses it by
+name); ``"lightning-attn"``, ``"minicpm4"``, ``"mamba2"`` and ``"mla"``
+SERVE and have no tested backward (their uncached call differentiates as
+plain XLA operations, unmeasured and unchecked).  Tensor parallelism over
+the heads of the five is not carried through (the engine refuses tp > 1).
 """
 
 from __future__ import annotations
@@ -50,14 +62,16 @@ from neuronx_distributed_tpu.parallel.qkv import (
     Q_HEAD_AXES,
 )
 
-MIXERS = ("attention", "minicpm4", "lightning-attn", "mamba2", "mla", "none")
+MIXERS = ("attention", "minicpm4", "lightning-attn", "mamba2", "mla", "conv",
+          "none")
 # what each mixer keeps for a live sequence, in the page pool's terms
 # (``kvcache.pool.CACHE_KINDS``): the one place a mixer's name decides it —
 # ``LlamaConfig.layer_caches`` hands it on, and the pool and the engines
 # read the config
 CACHE_OF = {"attention": "pages", "minicpm4": "selected_pages",
             "lightning-attn": "state", "mamba2": "state", "mla": "latent",
-            "none": "none"}
+            # no cached call (trace/engine.py refuses the mixer by name)
+            "conv": "none", "none": "none"}
 # the standard deviation a SEEDED embedding table of a layer-list model is
 # drawn with: the MiniCPM family's ``initializer_range``.  With muP's 12 x
 # embedding the table then leads the residual stream, as in a trained model;
@@ -404,6 +418,54 @@ class Mamba2Mixer(nn.Module):
             name="out_proj")(y), new_cache
 
 
+class ConvMixer(nn.Module):
+    """LFM2's gated short convolution (module docstring).  The gates and
+    the taps' sum are float32, each rounded once to the activations' dtype."""
+
+    config: object
+
+    @nn.compact
+    def __call__(self, x, positions, kv_cache=None, cache_offset=0,
+                 kv_valid=None, block_table=None, paged_kernel=False,
+                 state_rows=None):
+        from neuronx_distributed_tpu.ops.ssm_scan import causal_conv
+        from neuronx_distributed_tpu.parallel.moe import per_expert_lecun
+
+        if kv_cache is not None:
+            raise ValueError(
+                "the 'conv' mixer has no cached call: it trains, and the "
+                "serving engine refuses it by name")
+        cfg = self.config
+        H, K = cfg.hidden_size, cfg.conv_L_cache
+        f32 = jnp.float32
+        # (seeded weights are drawn in float32 and rounded: per_expert_lecun)
+        lin = dict(use_bias=False, sequence_parallel=cfg.sequence_parallel,
+                   dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                   kernel_init=per_expert_lecun)
+        with jax.named_scope("conv_in"):
+            bcx = ColumnParallelLinear(features=3 * H, name="in_proj",
+                                       **lin)(x)
+        bound = K ** -0.5       # torch's Conv1d draw at a fan-in of K
+        w = jnp.asarray(self.param(
+            "conv_weight", nn.with_partitioning(
+                lambda key, shape, dtype: jax.random.uniform(
+                    key, shape, f32, -bound, bound).astype(dtype),
+                (None, None)), (K, H), cfg.param_dtype))
+        with jax.named_scope("conv_gate"):
+            b_gate, c_gate, u = jnp.split(bcx, 3, axis=-1)
+            u = (b_gate.astype(f32) * u.astype(f32)).astype(cfg.dtype)
+        live = None if kv_valid is None else jnp.asarray(kv_valid) > 0
+        v, _ = causal_conv(
+            u, jnp.zeros((x.shape[0], K - 1, H), cfg.dtype), w, None, live,
+            silu=False, scope="conv_taps")
+        with jax.named_scope("conv_gate"):
+            y = (c_gate.astype(f32) * v.astype(f32)).astype(cfg.dtype)
+        with jax.named_scope("conv_out"):
+            return RowParallelLinear(
+                features=H, name="out_proj", input_partition_axes=Q_HEAD_AXES,
+                **lin)(y), None
+
+
 def mla_softmax_scale(cfg) -> float:
     """``(dn + dr)^-1/2``, times YaRN's ``mscale(factor, mscale_all_dim)^2``
     where the config stretches its RoPE and sets that term."""
@@ -553,6 +615,8 @@ class MLAMixer(nn.Module):
 
 
 def hybrid_mixer(cfg, kind: str):
+    if kind == "conv":
+        return ConvMixer(cfg, name="attn")
     if kind == "mla":
         return MLAMixer(cfg, name="attn")
     if kind == "mamba2":

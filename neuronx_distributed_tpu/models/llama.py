@@ -51,6 +51,7 @@ from neuronx_distributed_tpu.parallel.mesh import (
     BATCH_AXES,
     KV_REPLICA_AXIS,
     SEQUENCE_AXES,
+    TENSOR_AXES,
     TENSOR_AXIS,
 )
 from neuronx_distributed_tpu.parallel.norm import RMSNorm
@@ -247,6 +248,18 @@ class LlamaConfig:
     # an expert's width where it is not the dense layers' (a model whose
     # ffn_types names "mlp" and "moe" layers); 0: intermediate_size
     moe_intermediate_size: int = 0
+    # LFM2 (HF ``lfm2_moe``): the taps of the "conv" mixer's causal
+    # depthwise convolution (models/hybrid.py); RMSNorm of q and of k over
+    # each head's head_dim channels before RoPE (``qk_norm`` above runs over
+    # all heads at once); the head is the embedding table; and whether the
+    # routed blocks add the Switch balance term to the loss (a family
+    # balanced by its router bias has none).  The family divides the chosen
+    # sigmoid scores by their sum + 1e-6; ``norm_topk_prob`` here divides
+    # by the sum, floored: 5e-7 of a gate apart at four scores summing to ~2
+    conv_L_cache: int = 3
+    qk_norm_per_head: bool = False
+    tie_word_embeddings: bool = False
+    moe_aux_loss: bool = True
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
@@ -724,6 +737,11 @@ class LlamaAttention(nn.Module):
 
             q = full_width_norm(q, "q_norm")
             k = full_width_norm(k, "k_norm")
+        elif cfg.qk_norm_per_head:
+            # each head's own statistic, one weight [head_dim] for all
+            q, k = (RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
+                            param_dtype=cfg.param_dtype, name=name)(t)
+                    for name, t in (("q_norm", q), ("k_norm", k)))
         if cfg.attn_rope:
             sin, cos = rope_sin_cos(positions, D, cfg.rope_theta,
                                     cfg.rope_scaling_)
@@ -1166,7 +1184,8 @@ class LlamaBlock(nn.Module):
                 ("shared_intermediate_size",
                  cfg.moe_shared_intermediate_size, 0),
                 ("n_group", cfg.moe_n_group, 1),
-                ("topk_group", cfg.moe_topk_group, 1)) if v != default}
+                ("topk_group", cfg.moe_topk_group, 1))
+                if v != default}
             if family:
                 # initialisation only: a seeded expert of this family is
                 # drawn at its own fan-in, so that the routed block is a
@@ -1199,7 +1218,8 @@ class LlamaBlock(nn.Module):
                     kv_cache is not None)) if dropless else moe(normed))
             # collected by losses-mutable apply (causal_lm_loss adds the
             # load-balancing term); silently dropped when not collected
-            self.sow("losses", "moe_aux", aux)
+            if cfg.moe_aux_loss:
+                self.sow("losses", "moe_aux", aux)
         else:
             h = LlamaMLP(cfg, name="mlp")(normed)
         x = self._add(x, h, *((post, res) if hc else ()))
@@ -1257,8 +1277,11 @@ class LlamaModel(nn.Module):
             # family draws it (initialisation only, no program reads it)
             from neuronx_distributed_tpu.models.hybrid import SEEDED_EMBED_STD
 
+            # ... and a table that is also the head at H^-1/2: logits of
+            # unit scale against the unit-RMS final hidden state
             seeded["embedding_init"] = nn.initializers.normal(
-                stddev=SEEDED_EMBED_STD)
+                stddev=cfg.hidden_size ** -0.5 if cfg.tie_word_embeddings
+                else SEEDED_EMBED_STD)
         h = ParallelEmbedding(
             num_embeddings=cfg.vocab_size,
             features=cfg.hidden_size,
@@ -1356,6 +1379,8 @@ class LlamaForCausalLM(nn.Module):
         # compact-era param paths ("model", "lm_head") exactly
         cfg = self.config
         self.model = LlamaModel(cfg)
+        if cfg.tie_word_embeddings:
+            return      # the head is the embedding table (:meth:`head`)
         self.lm_head = ColumnParallelLinear(
             features=cfg.vocab_size,
             use_bias=False,
@@ -1372,7 +1397,7 @@ class LlamaForCausalLM(nn.Module):
         h, new_caches = self.backbone(
             ids, positions, kv_caches, cache_offset, kv_valid, segment_ids,
             block_table, adapters, paged_kernel, state_rows)
-        logits = self.lm_head(h)
+        logits = self.head(h)
         return (logits, new_caches) if kv_caches is not None else logits
 
     @nn.nowrap  # no scope of its own: __call__'s name stacks stay as they were
@@ -1400,8 +1425,19 @@ class LlamaForCausalLM(nn.Module):
         return h
 
     def head(self, h):
-        """Vocab-sharded logits for a (chunk of) hidden states."""
-        return self.lm_head(h)
+        """Vocab-sharded logits for a (chunk of) hidden states; with
+        ``tie_word_embeddings`` against the embedding table itself
+        (``ParallelEmbedding.attend``'s product), so that the table's
+        gradient is the sum of both uses."""
+        cfg = self.config
+        if not cfg.tie_word_embeddings:
+            return self.lm_head(h)
+        table = nn.meta.unbox(
+            self.model.get_variable("params", "embed"))["embedding"]
+        y = jnp.einsum("...h,vh->...v", h.astype(cfg.dtype),
+                       jnp.asarray(table, cfg.dtype),
+                       preferred_element_type=cfg.dtype)
+        return shard_activation(y, trailing_spec(y.ndim, last=TENSOR_AXES))
 
 
 class LlamaHead(nn.Module):

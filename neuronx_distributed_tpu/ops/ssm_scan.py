@@ -40,8 +40,10 @@ import jax.numpy as jnp
 CHUNK_ROWS = 128
 
 
-def causal_conv(x, taps, weight, bias, valid):
-    """Depthwise causal convolution over the call's TOKENS, then ``silu``.
+def causal_conv(x, taps, weight, bias, valid, silu=True, scope="ssm_conv"):
+    """Depthwise causal convolution over the call's TOKENS, then ``silu``
+    (Mamba-2's; ``silu=False`` and ``bias=None`` leave the taps' sum as it
+    is — LFM2's short convolution — under the caller's own ``scope``).
 
     ``x [B, S, C]``; ``taps [B, K-1, C]`` the last ``K-1`` inputs of each
     row's sequence before this call (zeros start a sequence); ``weight [K,
@@ -53,7 +55,7 @@ def causal_conv(x, taps, weight, bias, valid):
     K = weight.shape[0]
     f32 = jnp.float32
     w = weight.astype(f32)
-    with jax.named_scope("ssm_conv"):
+    with jax.named_scope(scope):
         if S == 1:
             # the decode step: one dot over the window
             win = jnp.concatenate([taps, x], axis=1)           # [B, K, C]
@@ -82,7 +84,10 @@ def causal_conv(x, taps, weight, bias, valid):
                 )(full, n)
                 back = jnp.argsort(order, axis=1)
                 y = jnp.take_along_axis(y, back[:, :, None], axis=1)
-        y = jax.nn.silu(y + bias.astype(f32))
+        if bias is not None:
+            y = y + bias.astype(f32)
+        if silu:
+            y = jax.nn.silu(y)
         return y.astype(x.dtype), taps_out.astype(taps.dtype)
 
 

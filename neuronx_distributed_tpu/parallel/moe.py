@@ -64,10 +64,12 @@ then counts the rows that reach it).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 from neuronx_distributed_tpu.parallel.layers import shard_activation
@@ -154,6 +156,51 @@ def gmm_tile(m: int, k: int, n: int, itemsize: int) -> tuple[int, int, int]:
     return tm, tk, tn
 
 
+# the backward's tiles (:func:`gmm_backward_tiles`): the rows a step of the
+# weight gradient contracts, and what its blocks may hold of the 16 MiB a
+# Mosaic call is given (the compiler asked for a described v5e, PR 43: at
+# LFM2-8B-A1B's widths every tile the rule below would pass compiled, and the
+# nearest larger ones ran out of scoped memory)
+GMM_BACKWARD_ROWS = 512
+GMM_BACKWARD_BYTES = 15 * 2 ** 20
+
+
+def gmm_backward_tiles(m: int, k: int, n: int, itemsize: int):
+    """``(data-gradient tile, weight-gradient tile)`` for the backward of
+    ``[m, k] x [G, k, n]``, each chosen from the shape of ITS operands — the
+    forward's tile is the wrong shape for both (upstream's ``custom_vjp``
+    hands it on: at ``[8, 2048, 1792]`` the flipped ``gmm`` then asks for a
+    block wider than the array and ``tgmm`` for 28 MiB of scoped memory).
+
+    The data gradient is ``gmm`` of ``[m, n] x [G, k, n]`` with the
+    right-hand side flipped — contraction ``n``, ``k`` columns out — so its
+    tile is :func:`gmm_tile`'s for those.  The weight gradient ``tgmm`` holds
+    a ``[tk, tn]`` float32 accumulator beside the double-buffered output
+    block and the two row blocks ``[tm, tk]`` and ``[tm, tn]`` (which it
+    masks through float32): of the ``tk`` and ``tn`` that are multiples of
+    128 and DIVIDE ``k`` and ``n`` (or the dimension whole) and fit
+    GMM_BACKWARD_BYTES at ``tm`` = GMM_BACKWARD_ROWS, the pair that reads
+    the rows the fewest times over (``tiles_k x tiles_n``), then the widest
+    in lanes."""
+    dlhs = gmm_tile(m, n, k, itemsize)
+    # the kernels take whole row tiles: the forward pads to its own (128)
+    tm = next((t for t in (GMM_BACKWARD_ROWS, 256) if m % t == 0), 128)
+
+    def dividing(x):
+        return [x] + [t for t in range(128, x, 128) if x % t == 0]
+
+    def held(tk, tn):
+        return tk * tn * (4 + 2 * itemsize) \
+            + tm * (tk + tn) * (2 * itemsize + 4)
+
+    fits = [(tk, tn) for tk in dividing(k) for tn in dividing(n)
+            if held(tk, tn) <= GMM_BACKWARD_BYTES]
+    if not fits:
+        return dlhs, (tm, min(128, k), min(128, n))
+    tk, tn = min(fits, key=lambda f: (-(-k // f[0]) * -(-n // f[1]), -f[1]))
+    return dlhs, (tm, tk, tn)
+
+
 # grouped matmuls whose kernel arm was traced since the last take, by whether
 # the k-tile divides the contraction (``masked_k``: upstream masks the last)
 _GMM_LOWERED = {"whole_k": 0, "masked_k": 0}
@@ -169,6 +216,52 @@ def take_gmm_lowered() -> dict:
     out = dict(_GMM_LOWERED)
     _GMM_LOWERED.update(dict.fromkeys(_GMM_LOWERED, 0))
     return out
+
+
+def book_expert_loads(reg, program: str, stats: dict, running):
+    """Book one program's fetched expert loads — ``stats["load"] [L, E]``,
+    with ``"assigned" [L]`` where the layers hold a share of their experts
+    and ``"reached" [L, 2]`` where a group limit lets a row reach none of
+    them — into the registry ``reg`` under the family name ``program`` (a
+    serve program's, or ``train_step``); returns ``running``, the loads
+    summed since the caller began (``None`` at first), with this program's
+    added (:func:`set_expert_load_gauge` reads it).  The counters are those
+    ``ServingEngine._count_moe`` documents."""
+    load = np.asarray(stats["load"], np.int64)
+    assigned = stats.get("assigned")
+    calls, hit = int((load.sum(axis=1) > 0).sum()), int((load > 0).sum())
+    made = int(load.sum() if assigned is None else np.sum(assigned))
+    reg.counter("moe/assignments_total").inc(made)
+    if assigned is not None:
+        # a held share: what fell to it, also by program family
+        for suffix in ("", "/" + program):
+            reg.counter("moe/assignments_held_total" + suffix).inc(
+                int(load.sum()))
+        reg.counter("moe/assignments_total/" + program).inc(made)
+    if "reached" in stats:
+        # a group-limited router over a held share: the rows that were
+        # routed (a layer each), and those with an assignment this program
+        # holds
+        rows, reached = np.asarray(stats["reached"]).sum(axis=0)
+        for suffix in ("", "/" + program):
+            reg.counter("moe/rows_routed_total" + suffix).inc(int(rows))
+            reg.counter("moe/rows_reaching_held_total" + suffix).inc(
+                int(reached))
+    for suffix in ("", "/" + program):
+        reg.counter("moe/layer_calls_total" + suffix).inc(calls)
+        reg.counter("moe/experts_hit_total" + suffix).inc(hit)
+    return load + (0 if running is None else running)
+
+
+def set_expert_load_gauge(reg, running) -> None:
+    """The gauge ``moe/expert_load_max_over_mean`` from ``running``, the
+    ``[L, E]`` loads :func:`book_expert_loads` has summed: per layer the
+    busiest expert's assignments over the mean expert's, the mean over the
+    layers that took any."""
+    mean = running.mean(axis=1)
+    if (mean > 0).any():
+        reg.gauge("moe/expert_load_max_over_mean").set(float(np.mean(
+            running.max(axis=1)[mean > 0] / mean[mean > 0])))
 
 
 def per_expert_lecun(key, shape, dtype=jnp.float32):
@@ -187,6 +280,111 @@ def per_expert_lecun(key, shape, dtype=jnp.float32):
         key, shape, jnp.float32).astype(dtype)
 
 
+def _megablox():
+    import importlib
+
+    # the package's ``gmm`` attribute is its custom_vjp function; the
+    # module of that name holds the two kernels
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _gmm(x, w, sizes, dtype, tile, transpose_rhs, interpret=False):
+    """megablox ``gmm`` under ``tile``, its backward under
+    :func:`gmm_backward_tiles` (upstream's ``custom_vjp`` hands the backward
+    the forward's tile).  ``interpret``: the tests', on the CPU."""
+    return _megablox().gmm(x, w, sizes, dtype, tile,
+                           transpose_rhs=transpose_rhs, interpret=interpret)
+
+
+def _gmm_fwd(x, w, sizes, dtype, tile, transpose_rhs, interpret):
+    return _gmm(x, w, sizes, dtype, tile, transpose_rhs, interpret), (
+        x, w, sizes)
+
+
+def _gmm_bwd(dtype, tile, transpose_rhs, interpret, res, grad):
+    x, w, sizes = res
+    k, n = (w.shape[2], w.shape[1]) if transpose_rhs else w.shape[1:]
+    dlhs, drhs = gmm_backward_tiles(x.shape[0], k, n, w.dtype.itemsize)
+    mb = _megablox()
+    with jax.named_scope("moe_gmm"):
+        dx = mb.gmm(grad, w, sizes, x.dtype, dlhs,
+                    transpose_rhs=not transpose_rhs, interpret=interpret)
+        # the kernel writes the rows of its groups only, and a row in no
+        # group is still SOME token's row (an assignment to an expert held
+        # elsewhere): what it leaves there must not reach that token
+        dx = jnp.where((jnp.arange(x.shape[0]) < jnp.sum(sizes))[:, None],
+                       dx, 0)
+        # float32 accumulation over the rows, rounded once to the weight's
+        # dtype; an expert no row chose gets zeros
+        dw = mb.tgmm(x.swapaxes(0, 1), grad, sizes, w.dtype, drhs,
+                     num_actual_groups=w.shape[0], interpret=interpret)
+    return dx, (dw.swapaxes(1, 2) if transpose_rhs else dw), None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+@jax.custom_vjp
+def _dispatch_rows(x, order):
+    """``x[order // K]``: the ``N * K`` assignment rows of ``x [N, H]`` in
+    ``order``, a PERMUTATION of the token-major assignments ``n * K + k``.
+    JAX's transpose of a gather is a scatter-add, row after row on a TPU;
+    of a permutation it is the gather by the inverse and a sum over the
+    ``K`` copies, which is what the backward here does — gathered ``k``
+    major, so that the copies are ``K`` slabs ``[N, H]`` to add and no
+    ``[N, K, H]`` array (``K`` rows to a tile of 16) is ever laid out.  The
+    forward is the gather it always was."""
+    return x[order // (order.shape[0] // x.shape[0])]
+
+
+def _dispatch_rows_fwd(x, order):
+    return _dispatch_rows(x, order), (order, x.shape[0])
+
+
+def _dispatch_rows_bwd(res, g):
+    order, n = res
+    k = order.shape[0] // n
+    inv = jnp.argsort(order).reshape(n, k).T.reshape(-1)     # k-major
+    gx = jnp.sum(g[inv].reshape(k, n, g.shape[-1]).astype(jnp.float32),
+                 axis=0).astype(g.dtype)
+    return gx, None
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_rows(ys, back, order, gates):
+    """``y[n] = sum_k gates[n, k] * ys[back[n * K + k]]`` in float32 — the
+    experts' rows ``ys [N * K, H]`` un-sorted (``back`` the inverse of the
+    permutation ``order``) and summed under the gates ``[N, K]``.  The
+    backward never builds ``[N, K, H]``: a sorted row's cotangent is its
+    token's, gathered by ``order // K`` like the dispatch's rows, times its
+    own gate, and a gate's is the dot of the two rows, un-sorted as a
+    scalar."""
+    n, k = gates.shape
+    return jnp.sum(ys[back].reshape(n, k, ys.shape[-1]).astype(jnp.float32)
+                   * gates[:, :, None], axis=1)
+
+
+def _combine_rows_fwd(ys, back, order, gates):
+    return _combine_rows(ys, back, order, gates), (ys, back, order, gates)
+
+
+def _combine_rows_bwd(res, dy):
+    ys, back, order, gates = res
+    n, k = gates.shape
+    rows = dy[order // k]                                     # [N * K, H]
+    d_ys = (rows * gates.reshape(-1)[order][:, None]).astype(ys.dtype)
+    dots = jnp.sum(ys.astype(jnp.float32) * rows, axis=-1)
+    return d_ys, None, None, dots[back].reshape(n, k).astype(gates.dtype)
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
 def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
                    dtype: Dtype, transpose_rhs: bool = False) -> jax.Array:
     """``x [M, K]`` rows sorted by group, ``w [G, K, N]`` (``[G, N, K]``
@@ -197,7 +395,12 @@ def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
 
     A program lowered for a TPU with the expert width whole on each chip
     carries the megablox Pallas kernel (scope ``moe_gmm``); any other,
-    ``lax.ragged_dot``.  Both differentiate."""
+    ``lax.ragged_dot``.  Both differentiate, and both backwards are tested
+    against each other: ``ragged_dot`` by JAX's own rule, the kernel by the
+    flipped ``gmm`` (data gradient; rows in no group get what the kernel
+    leaves, their cotangent being the caller's zero) and ``tgmm`` (weight
+    gradient) under tiles chosen from THEIR operands
+    (:func:`gmm_backward_tiles`)."""
     sizes = group_sizes.astype(jnp.int32)
 
     def ragged(x, w, sizes):
@@ -206,16 +409,13 @@ def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
         return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=dtype)
 
     def kernel(x, w, sizes):
-        from jax.experimental.pallas.ops.tpu.megablox import gmm
-
         k, n = (w.shape[2], w.shape[1]) if transpose_rhs else w.shape[1:]
         tile = gmm_tile(x.shape[0], k, n, w.dtype.itemsize)
         _GMM_LOWERED["masked_k" if k % tile[1] else "whole_k"] += 1
         pad = -x.shape[0] % tile[0]     # whole row tiles; the pad is in no group
         xp = jnp.pad(x, ((0, pad), (0, 0))) if pad else x
         with jax.named_scope("moe_gmm"):
-            out = gmm(xp, w, sizes, preferred_element_type=dtype,
-                      tiling=tile, transpose_rhs=transpose_rhs)
+            out = _gmm(xp, w, sizes, jnp.dtype(dtype), tile, transpose_rhs)
         return out[:x.shape[0]]
 
     if model_parallel_is_initialized() and get_tensor_parallel_size() > 1:
@@ -418,10 +618,12 @@ class ExpertParallelMLP(nn.Module):
                 # the mean against 1.3-1.6 x at 0.005; what a share of the
                 # experts is handed then swings with the seed: PERF.md,
                 # PR 32)
-                bias = jnp.asarray(self.param(
+                # It joins the CHOICE only: no gradient reaches it, and the
+                # optimizer leaves it out (trainer.NON_TRAINABLE_LEAVES)
+                bias = jax.lax.stop_gradient(jnp.asarray(self.param(
                     "router_bias", nn.with_partitioning(
                         nn.initializers.normal(0.005), (None,)),
-                    (Eg,), jnp.float32))
+                    (Eg,), jnp.float32)))
             y, aux = self._dropless(
                 xt, None if valid is None else valid.reshape(-1),
                 jnp.asarray(router), wi, jnp.asarray(wo), bias)
@@ -648,7 +850,7 @@ class ExpertParallelMLP(nn.Module):
             order = jnp.argsort(flat, stable=True)
             load = jnp.sum(flat[:, None] == jnp.arange(E)[None, :], axis=0,
                            dtype=jnp.int32)                    # [E]
-            xs = xt.astype(self.dtype)[order // K]             # [N*K, H]
+            xs = _dispatch_rows(xt.astype(self.dtype), order)  # [N*K, H]
         with jax.named_scope("moe_experts"):
             if self.activation == "relu2":
                 h = jnp.square(jax.nn.relu(grouped_matmul(
@@ -665,8 +867,7 @@ class ExpertParallelMLP(nn.Module):
             ys = jnp.where(in_group[:, None], ys, 0)
             back = jnp.zeros((N * K,), jnp.int32).at[order].set(
                 jnp.arange(N * K, dtype=jnp.int32))
-            y = jnp.sum(ys[back].reshape(N, K, H).astype(jnp.float32)
-                        * gates[:, :, None], axis=1).astype(self.dtype)
+            y = _combine_rows(ys, back, order, gates).astype(self.dtype)
             y = shard_activation(y, _auto_spec(BATCH_AXES, None))
         if not self.is_initializing():  # never part of a parameter tree
             self.sow("moe_stats", "load", load)

@@ -101,7 +101,11 @@ from neuronx_distributed_tpu.kvcache.transfer import (
     export_chain,
     import_chain,
 )
-from neuronx_distributed_tpu.parallel.moe import take_gmm_lowered
+from neuronx_distributed_tpu.parallel.moe import (
+    book_expert_loads,
+    set_expert_load_gauge,
+    take_gmm_lowered,
+)
 from neuronx_distributed_tpu.serving.paged import PagedKVManager
 from neuronx_distributed_tpu.serving.scheduler import (
     DEFAULT_MAX_BATCH_WAIT_S,
@@ -2144,36 +2148,9 @@ class ServingEngine:
                 self._moe_programs.add(program)
                 for how, n in take_gmm_lowered().items():
                     reg.counter("moe/gmm_lowered_total/" + how).inc(n)
-            load = np.asarray(stats["load"], np.int64)
-            assigned = stats.get("assigned")
-            calls, hit = int((load.sum(axis=1) > 0).sum()), int((load > 0).sum())
-            made = int(load.sum() if assigned is None else np.sum(assigned))
-            reg.counter("moe/assignments_total").inc(made)
-            if assigned is not None:
-                # a held share: what fell to it, also by program family
-                for suffix in ("", "/" + program):
-                    reg.counter("moe/assignments_held_total" + suffix).inc(
-                        int(load.sum()))
-                reg.counter("moe/assignments_total/" + program).inc(made)
-            if "reached" in stats:
-                # a group-limited router over a held share: the rows that
-                # were routed (a layer each), and those with an assignment
-                # this program holds
-                rows, reached = np.asarray(stats["reached"]).sum(axis=0)
-                for suffix in ("", "/" + program):
-                    reg.counter("moe/rows_routed_total" + suffix).inc(
-                        int(rows))
-                    reg.counter("moe/rows_reaching_held_total" + suffix).inc(
-                        int(reached))
-            for suffix in ("", "/" + program):
-                reg.counter("moe/layer_calls_total" + suffix).inc(calls)
-                reg.counter("moe/experts_hit_total" + suffix).inc(hit)
-            self._moe_load = load + (0 if self._moe_load is None
-                                     else self._moe_load)
-        mean = self._moe_load.mean(axis=1)
-        if (mean > 0).any():
-            reg.gauge("moe/expert_load_max_over_mean").set(float(np.mean(
-                self._moe_load.max(axis=1)[mean > 0] / mean[mean > 0])))
+            self._moe_load = book_expert_loads(reg, program, stats,
+                                               self._moe_load)
+        set_expert_load_gauge(reg, self._moe_load)
 
     def _count_sampler_step(self) -> None:
         """Book which branch of ``_sample_rows`` the coming decode takes,

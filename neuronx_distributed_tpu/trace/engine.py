@@ -712,6 +712,11 @@ class ParallelInferenceModel(_ServingBase):
         # keep a recurrent state row a sequence and which choose the pages
         # they attend (the paged programs then also return that choice)
         self.recurrent = bool(getattr(mcfg, "recurrent_layers", ()))
+        if "conv" in (getattr(mcfg, "mixer_types", None) or ()):
+            raise ValueError(
+                "the 'conv' mixer (LFM2's gated short convolution) has no "
+                "cached call: a model with such layers trains and is not "
+                "served (models/hybrid.py)")
         # Mamba-2 layers step their state arrays where they lie when every
         # slot is a batch row (``models.hybrid.Mamba2Mixer``): a decode is
         # then told no rows

@@ -442,17 +442,39 @@ def fit(
     pending: list = []       # [(step, m, timing dict)]
     pending_eval: list = []  # [(eval_step, ev)]
 
+    moe_running = None       # [L, E] loads summed since the run began
+
+    def _book_moe(fetched: dict) -> None:
+        """A routed model's step: its expert loads into the registry's
+        ``moe/*`` counters, as the serving engine books a program's."""
+        nonlocal moe_running
+        if obs_rt is None or "moe_load" not in fetched:
+            return
+        from neuronx_distributed_tpu.parallel.moe import (
+            book_expert_loads,
+            set_expert_load_gauge,
+        )
+
+        moe_running = book_expert_loads(
+            obs_rt.registry, "train_step",
+            {"load": fetched["moe_load"],
+             "assigned": fetched.get("moe_assigned")}, moe_running)
+        set_expert_load_gauge(obs_rt.registry, moe_running)
+
     def _flush_step_metrics() -> None:
         nonlocal loss
         if not pending:
             return
         pstep, pm, pt = pending.pop()
         t_w = time.perf_counter()
-        fetched = audit.fetch((pm["loss"], pm["grad_norm"]), label="train")
+        # everything the step handed on, in the one fetch (a routed model's
+        # expert loads ride it as they ride the token fetch in serving)
+        fetched = audit.fetch(pm, label="train")
         wait_s = time.perf_counter() - t_w
-        ploss = perturb("fit/loss", float(fetched[0]), step=pstep)
-        pgrad = float(fetched[1])
+        ploss = perturb("fit/loss", float(fetched["loss"]), step=pstep)
+        pgrad = float(fetched["grad_norm"])
         loss = ploss
+        _book_moe(fetched)
         if obs_rt is not None:
             # host_s = dispatch, device_s = the (overlapped) fetch wait; the
             # two no longer tile one wall-clock step the way the sync loop's
@@ -464,7 +486,7 @@ def fit(
         if scalars:
             scalars.scalars(pstep, loss=ploss, grad_norm=pgrad,
                             seq_per_sec=pt["seqs"])
-        step_metrics = dict(pm)
+        step_metrics = dict(fetched)
         step_metrics.update(loss=ploss, grad_norm=pgrad, seq_per_sec=pt["seqs"])
         for cb in cbs:
             cb.on_step(pstep, step_metrics)
@@ -591,11 +613,11 @@ def fit(
                         "seqs": seqs, "dispatch_s": t_dispatch - t0,
                         "data_wait_s": data_wait_s}))
                 else:
-                    fetched = audit.fetch((m["loss"], m["grad_norm"]),
-                                          label="train")
-                    loss = perturb("fit/loss", float(fetched[0]), step=step)
-                    grad_norm = float(fetched[1])
+                    m = audit.fetch(m, label="train")
+                    loss = perturb("fit/loss", float(m["loss"]), step=step)
+                    grad_norm = float(m["grad_norm"])
                     t_done = time.perf_counter()
+                    _book_moe(m)
             if compile_led is not None:
                 n = jit_cache_size(step_fn)
                 if step == start_step:

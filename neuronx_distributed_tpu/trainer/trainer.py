@@ -228,6 +228,17 @@ def initialize_parallel_model(
     return model
 
 
+# parameter leaves that are STATE, not weights: a routed block's correction
+# bias joins the experts' CHOICE only (parallel/moe.py) and is moved by load
+# balancing, never by the loss.  The optimizer is built as if they were
+# frozen — no moment, no decay, a zero update — whatever ``trainable`` says
+NON_TRAINABLE_LEAVES = ("router_bias",)
+
+
+def _is_state_leaf(path: str) -> bool:
+    return any(f"'{name}'" in path for name in NON_TRAINABLE_LEAVES)
+
+
 def initialize_parallel_optimizer(
     config: TrainingConfig,
     model: ParallelModel,
@@ -241,7 +252,9 @@ def initialize_parallel_optimizer(
     ``trainable`` (a predicate over ``jax.tree_util.keystr`` param paths)
     freezes everything it rejects: frozen params get ``optax.set_to_zero``
     updates and carry no optimizer state — the PEFT path
-    (``peft.lora_trainable`` trains only LoRA adapters)."""
+    (``peft.lora_trainable`` trains only LoRA adapters).  A leaf named in
+    ``NON_TRAINABLE_LEAVES`` is frozen in the same way under every
+    ``trainable``, the default included."""
     oc = config.optimizer
     if tx is None:
         lr = (
@@ -259,11 +272,14 @@ def initialize_parallel_optimizer(
             eps=oc.eps,
             weight_decay=oc.weight_decay,
         )
-    if trainable is not None:
-        labels = jax.tree_util.tree_map_with_path(
-            lambda p, _: "train" if trainable(jax.tree_util.keystr(p)) else "freeze",
-            model.params,
-        )
+
+    def label(path, _):
+        path = jax.tree_util.keystr(path)
+        return "train" if (trainable is None or trainable(path)) \
+            and not _is_state_leaf(path) else "freeze"
+
+    labels = jax.tree_util.tree_map_with_path(label, model.params)
+    if trainable is not None or "freeze" in jax.tree.leaves(labels):
         n_train = sum(
             int(x.size)
             for x, l in zip(jax.tree.leaves(model.params), jax.tree.leaves(labels))
@@ -329,7 +345,10 @@ def make_train_step(
     - ``(loss_sum, token_count)`` (e.g. ``causal_lm_loss_sum``): the step
       accumulates both and normalizes once, yielding the exact token-masked
       global-batch mean regardless of how masking is distributed across
-      microbatches — the same normalization the PP engine uses.
+      microbatches — the same normalization the PP engine uses.  A third
+      value, a dict of arrays, rides the step's metrics under its keys (a
+      routed model's ``moe_load [L, E]``: ``models.common``), summed over
+      the microbatches under ``grad_accum_steps > 1`` (they are counts).
 
     A :class:`~..pipeline.engine.PipelinedModel` (from
     ``initialize_parallel_model`` with pp>1) is dispatched to
@@ -366,21 +385,27 @@ def make_train_step(
             lambda p, b: loss_fn(model.module, p, b, None), params, batch
         )
         token_weighted = isinstance(out_sd, tuple)
-        if token_weighted and len(out_sd) != 2:
+        if token_weighted and len(out_sd) not in (2, 3):
             raise ValueError(
-                "a tuple-returning loss_fn must return exactly "
-                f"(loss_sum, token_count); got a {len(out_sd)}-tuple"
+                "a tuple-returning loss_fn must return (loss_sum, "
+                "token_count) or (loss_sum, token_count, extras); got a "
+                f"{len(out_sd)}-tuple"
             )
+        has_extras = token_weighted and len(out_sd) == 3
+
+        def value_and_rest(*args):
+            out = loss_fn(*args)
+            return out[0], out[1:]
 
         if grad_accum_steps == 1:
             if token_weighted:
-                (loss_sum, tok), grads = jax.value_and_grad(
-                    loss_fn, argnums=1, has_aux=True
+                (loss_sum, (tok, *extras)), grads = jax.value_and_grad(
+                    value_and_rest, argnums=1, has_aux=True
                 )(model.module, params, batch, rng)
                 tok = jnp.maximum(tok, 1.0)
                 # d(sum/tok)/dp = d(sum)/dp / tok — tok depends only on labels
-                return loss_sum / tok, jax.tree.map(
-                    lambda g: (g / tok).astype(g.dtype), grads)
+                return (loss_sum / tok, jax.tree.map(
+                    lambda g: (g / tok).astype(g.dtype), grads), *extras)
             return jax.value_and_grad(loss_fn, argnums=1)(
                 model.module, params, batch, rng
             )
@@ -403,11 +428,14 @@ def make_train_step(
                 mb, r = xs, None
             else:
                 mb, r = xs
-            loss_acc, tok_acc, grad_acc = acc
+            loss_acc, tok_acc, grad_acc, *extra_acc = acc
             if token_weighted:
-                (l, t), g = jax.value_and_grad(loss_fn, argnums=1, has_aux=True)(
+                (l, (t, *extras)), g = jax.value_and_grad(
+                    value_and_rest, argnums=1, has_aux=True)(
                     model.module, params, mb, r)
                 tok_acc = tok_acc + t.astype(jnp.float32)
+                extra_acc = [jax.tree.map(jnp.add, a, e)
+                             for a, e in zip(extra_acc, extras)]
             else:
                 l, g = jax.value_and_grad(loss_fn, argnums=1)(model.module, params, mb, r)
             # fp32 accumulator: summing many bf16 gradients in bf16 rounds
@@ -416,24 +444,30 @@ def make_train_step(
                 loss_acc + l.astype(jnp.float32),
                 tok_acc,
                 jax.tree.map(lambda a, gg: a + gg.astype(jnp.float32), grad_acc, g),
+                *extra_acc,
             ), None
 
         xs = micro if rng is None else (micro, jax.random.split(rng, grad_accum_steps))
         zero = (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32),
                 jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params))
-        (loss_sum, tok, grads), _ = jax.lax.scan(body, zero, xs)
+        if has_extras:
+            zero += (jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype),
+                                  out_sd[2]),)
+        (loss_sum, tok, grads, *extras), _ = jax.lax.scan(body, zero, xs)
         # token_weighted: normalize by the GLOBAL unmasked-token count so the
         # update equals the single-shot whole-batch gradient exactly even
         # under uneven masking; legacy: mean of per-microbatch means.
         scale = 1.0 / jnp.maximum(tok, 1.0) if token_weighted \
             else jnp.float32(1.0 / grad_accum_steps)
-        return loss_sum * scale, jax.tree.map(
-            lambda g, p: (g * scale).astype(p.dtype), grads, params)
+        return (loss_sum * scale, jax.tree.map(
+            lambda g, p: (g * scale).astype(p.dtype), grads, params), *extras)
 
     mask = optimizer.update_mask
 
     def _step(params, opt_state, batch, rng):
-        loss, grads = _loss_and_grad(params, batch, rng)
+        # extras: what a loss_fn's third value holds (a routed model's
+        # expert loads), handed on in the step's metrics as it is
+        loss, grads, *extras = _loss_and_grad(params, batch, rng)
         # flax names every module's operations in the device trace; the
         # clip and the update belong to no module
         with jax.named_scope("optimizer"):
@@ -452,7 +486,8 @@ def make_train_step(
                 grad_norm = get_grad_norm(grads)
             updates, opt_state = optimizer.tx.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
-        metrics = {"loss": loss, "grad_norm": grad_norm}
+        metrics = {"loss": loss, "grad_norm": grad_norm,
+                   **(extras[0] if extras else {})}
         return params, opt_state, metrics
 
     batch_shardings = _batch_shardings(mesh, batch_spec)
@@ -561,8 +596,8 @@ def make_eval_step(
 
     def _eval(params, batch):
         out = loss_fn(model.module, params, batch, None)
-        if isinstance(out, tuple):  # (loss_sum, tok) contract, as in train
-            loss_sum, tok = out
+        if isinstance(out, tuple):  # (loss_sum, tok[, extras]), as in train
+            loss_sum, tok = out[:2]
             return {"loss": loss_sum / jnp.maximum(tok, 1.0)}
         return {"loss": out}
 

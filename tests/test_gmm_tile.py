@@ -34,8 +34,8 @@ def _routed():
     for path in sorted(glob.glob(os.path.join(CONFIGS, "*.json"))):
         cfg = json.load(open(path))
         kw = cfg["program"]["kwargs"]
-        if kw.get("moe_dispatch") != "dropless":
-            continue
+        if kw.get("moe_dispatch") != "dropless" or "serving" not in cfg:
+            continue        # (a TRAINED routed configuration has no slots)
         hidden = kw["hidden_size"]
         width = kw.get("moe_intermediate_size", kw["intermediate_size"])
         name = os.path.basename(path).split(".serve")[0]

@@ -325,6 +325,61 @@ def test_routed_expert_block_compiles_for_v5e_without_relaying_out_its_weights(t
     assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
 
 
+def test_held_gated_experts_backward_compiles_for_v5e_at_lfm2_widths(topo):
+    """The TRAINED dropless block at LFM2-8B-A1B's widths (8 of 32 experts x
+    1792 held, 4 a token, hidden 2048) over 16,384 tokens: ``jax.grad``
+    carries nine megablox calls — gate, up and down, each forward, flipped
+    (data gradient) and ``tgmm`` (weight gradient) — under the tiles
+    ``gmm_backward_tiles`` picks from the backward's own operands.  Under
+    the forward's tile (what upstream's ``custom_vjp`` hands on) the chip's
+    compiler refused both backward kernels (AOT, PR 41)."""
+    from flax import linen as nn
+
+    from neuronx_distributed_tpu.parallel.moe import ExpertParallelMLP
+
+    mesh = _mesh(topo)
+    moe = ExpertParallelMLP(
+        num_experts=8, num_experts_global=32, first_expert=0,
+        intermediate_size=1792, top_k=4, dispatch="dropless",
+        fused_gate_up=False, router_scores="sigmoid", router_bias=True,
+        norm_topk_prob=True, dtype=jnp.bfloat16,
+        param_dtype=jnp.float32)
+    rep = NamedSharding(mesh, P())
+    x = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16, sharding=rep)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        nn.unbox(jax.eval_shape(moe.init, jax.random.PRNGKey(0), x)))
+
+    def loss(p, x):
+        return jnp.sum(moe.apply(p, x)[0].astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 9
+    # the transposes of the dispatch and the combine are gathers: no
+    # scatter of ROWS (the scalar ones — the inverse permutation, the
+    # gates' place among the 32 scores, megablox's group tables — stay)
+    import re
+
+    assert not re.findall(r"\[\d+,\d+[^\]]*\]\S* scatter\(", text)
+
+
+def test_flash_kernels_compile_for_v5e_at_head_dim_64(topo):
+    """LFM2's attention geometry — 32 q / 8 kv heads of 64, full causal, 8192
+    rows: forward, dq and dkv (every other case here is at 128)."""
+    mesh = _mesh(topo)
+    sh = NamedSharding(mesh, P())
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 64), jnp.bfloat16, sharding=sh)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 64), jnp.bfloat16, sharding=sh)
+
+    def loss(q, k, v):
+        return jnp.sum(ring_attention(q, k, v, causal=True)
+                       .astype(jnp.float32))
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count("tpu_custom_call") >= 3
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk_s512"])
 def test_hybrid_paged_programs_compile_for_v5e_and_copy_no_state(topo, program):
     """MiniCPM-SALA's paged programs at its PUBLISHED widths and the cell's

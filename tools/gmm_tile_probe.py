@@ -23,6 +23,15 @@ tile the compiler refuses is a row that says so.  Rows are printed as they
 come and written to ``chiprun_out/gmm_tile_probe.json``.  ``--rehearse``
 runs a toy shape through the interpreter on the CPU (no number of it is a
 device number).
+
+``--backward`` asks the TRAINING question instead (PR 43): at ``--rows`` rows
+over ``--groups`` held experts of ``--widths H,I`` (default LFM2-8B-A1B's
+16,384 rows over 8 experts of 2048 x 1792) it times, for the gate/up shape
+``[G, H, I]`` and the down shape ``[G, I, H]``, the forward ``gmm`` under
+``gmm_tile``, the data gradient (``gmm`` with the right-hand side flipped)
+and the weight gradient (``tgmm``) under ``gmm_backward_tiles``' choice and
+under every dividing tile tried, each against ``2 x rows x H x I`` FLOPs at
+the bf16 peak; rows go to ``chiprun_out/gmm_backward_probe.json``.
 """
 
 import argparse
@@ -54,7 +63,7 @@ def routed_blocks():
     for path in sorted(glob.glob(os.path.join(
             ROOT, "benchmarks", "configs", "*.json"))):
         kw = json.load(open(path))["program"]["kwargs"]
-        if kw.get("moe_dispatch") != "dropless":
+        if kw.get("moe_dispatch") != "dropless" or ".serve" not in path:
             continue
         held = kw.get("moe_experts_held")
         out[os.path.basename(path).split(".serve")[0]] = dict(
@@ -102,13 +111,126 @@ def near_uniform_sizes(rs, E, held_rows):
                        minlength=E).astype(np.int32)
 
 
+def _dividing(x, least=256):
+    """Multiples of 128 from ``least`` up that divide ``x``, largest first."""
+    return [t for t in range(x, least - 1, -128) if x % t == 0]
+
+
+def backward_probe(args):
+    """The three grouped matmuls of a TRAINED expert's one weight, timed
+    alone (module docstring)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks.harness import manifest
+    from neuronx_distributed_tpu.parallel import moe
+
+    backend = moe._megablox()       # the module that holds both kernels
+    dev = jax.devices()[0]
+    H, I = (int(x) for x in args.widths.split(","))
+    M, G = args.rows, args.groups
+    if args.rehearse:
+        peak, samples, queued = {"bf16_flops_per_s": 1.0}, 1, 1
+        H, I, M, G = 256, 384, 512, 3
+    else:
+        if dev.platform != "tpu":
+            sys.exit(f"gmm_tile_probe measures a TPU; found {dev.platform}")
+        peak, samples, queued = manifest.peaks_for(str(dev.device_kind)), 10, 3
+    dt = jnp.float32 if args.rehearse else jnp.bfloat16
+    item = jnp.dtype(dt).itemsize
+    rs = np.random.RandomState(M)
+    sizes_np = near_uniform_sizes(rs, G, M - M // 16)   # a tail in no group
+    if G > 2:
+        sizes_np[1] += sizes_np[2]; sizes_np[2] = 0     # an empty group
+    sizes = jnp.asarray(sizes_np)
+    live = int(sizes_np.sum())
+    bounds = np.concatenate([[0], np.cumsum(sizes_np)])
+    table = []
+
+    def timed(fn, *a):
+        jax.block_until_ready(fn(*a))
+        ts = []
+        for _ in range(samples):
+            t0 = time.perf_counter()
+            for _ in range(queued):
+                out = fn(*a)
+            jax.block_until_ready(out)
+            ts.append((time.perf_counter() - t0) / queued)
+        return float(np.median(ts) * 1e3)
+
+    def run(row, fn, ref, *a):
+        try:
+            fn = jax.jit(fn)
+            out = np.asarray(fn(*a), np.float32)
+            row["rel_err"] = float(np.max(np.abs(out - ref))
+                                   / (np.max(np.abs(ref)) + 1e-9))
+            row["ms"] = timed(fn, *a)
+            row["share_of_peak"] = 100 * row["least_ms"] / row["ms"]
+        except Exception as e:  # a refused tile is a row
+            row["error"] = f"{type(e).__name__}: {str(e)[:200]}"
+        table.append(row)
+        print(json.dumps(row), flush=True)
+
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    for K, N in ((H, I), (I, H)):
+        x = jax.random.normal(keys[0], (M, K), jnp.float32).astype(dt)
+        g = jax.random.normal(keys[1], (M, N), jnp.float32).astype(dt)
+        w = (jax.random.normal(keys[2], (G, K, N), jnp.float32)
+             * 0.02).astype(dt)
+        xf, gf, wf = (np.asarray(t, np.float32) for t in (x, g, w))
+        fwd_ref, dx_ref = np.zeros((M, N), np.float32), np.zeros((M, K), np.float32)
+        dw_ref = np.zeros((G, K, N), np.float32)
+        for e in range(G):
+            a, b = bounds[e], bounds[e + 1]
+            fwd_ref[a:b] = xf[a:b] @ wf[e]
+            dx_ref[a:b] = gf[a:b] @ wf[e].T
+            dw_ref[e] = xf[a:b].T @ gf[a:b]
+        least = 2.0 * live * K * N / peak["bf16_flops_per_s"] * 1e3
+        dlhs, drhs = moe.gmm_backward_tiles(M, K, N, item)
+        base = dict(rows=M, live=live, groups=G, k=K, n=N, least_ms=least)
+        for tm in (128, 256, 512):
+            t = (tm,) + moe.gmm_tile(M, K, N, item)[1:]
+            run(dict(base, op="gmm", tiling=list(t), chosen=tm == 128),
+                lambda x, w, s, t=t: backend.gmm(
+                    x, w, s, dt, t, interpret=args.rehearse)[:live],
+                fwd_ref[:live], x, w, sizes)
+        tried = [dlhs] + [(tm, tk, tn) for tm in (128, 256, 512)
+                          for tk in _dividing(N, 512)[:2]
+                          for tn in _dividing(K, 512)[:3]]
+        for t in dict.fromkeys(tried):
+            run(dict(base, op="gmm_flipped", tiling=list(t), chosen=t == dlhs),
+                lambda g, w, s, t=t: backend.gmm(
+                    g, w, s, dt, t, transpose_rhs=True,
+                    interpret=args.rehearse)[:live],
+                dx_ref[:live], g, w, sizes)
+        tried = [drhs] + [(tm, tk, tn) for tm in (128, 256, 512, 1024)
+                          for tk in _dividing(K)[:3] for tn in _dividing(N)[:3]]
+        for t in dict.fromkeys(tried):
+            run(dict(base, op="tgmm", tiling=list(t), chosen=t == drhs),
+                lambda x, g, s, t=t: backend.tgmm(
+                    x.swapaxes(0, 1), g, s, dt, t, interpret=args.rehearse),
+                dw_ref, x, g, sizes)
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(ROOT, "chiprun_out", "gmm_backward_probe.json"),
+              "w") as f:
+        json.dump(dict(device=str(dev.device_kind), rows=table), f, indent=1)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--blocks", default="", help="comma list; default all")
     ap.add_argument("--no-sweep", action="store_true",
                     help="today's tile and the rule's only")
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--backward", action="store_true",
+                    help="the training question: flipped gmm and tgmm tiles")
+    ap.add_argument("--rows", type=int, default=16384)
+    ap.add_argument("--groups", type=int, default=8)
+    ap.add_argument("--widths", default="2048,1792", help="H,I")
     args = ap.parse_args()
+    if args.backward:
+        return backward_probe(args)
 
     import jax
     import jax.numpy as jnp
