@@ -74,8 +74,10 @@ def moe_step_stats(variables) -> dict:
     routed layers in their order: ``{"moe_load": [L, E]}``, the valid
     assignments each held expert took, and, where the layers hold a share
     of their experts, ``"moe_assigned" [L]``, the valid assignments held or
-    not.  ``{}`` for a model that sows none.  Each row's choice, ``[tokens,
-    K]`` a layer, stays behind (``models.llama.moe_layer_stats`` reads it
+    not, and, where such a share passes over the rows it holds,
+    ``"moe_computed" [L]``, the rows the spans that ran passed over.  ``{}``
+    for a model that sows none.  Each row's choice, ``[tokens, K]`` a layer,
+    stays behind (``models.llama.moe_layer_stats`` reads it
     from the collection for a check)."""
     import re
 
@@ -87,7 +89,8 @@ def moe_step_stats(variables) -> dict:
         return [int(n) for p in path for n in re.findall(r"\d+", p)]
 
     out = {}
-    for key, name in (("load", "moe_load"), ("assigned", "moe_assigned")):
+    for key, name in (("load", "moe_load"), ("assigned", "moe_assigned"),
+                      ("computed", "moe_computed")):
         rows = [v[-1] for p, v in sorted(flat.items(), key=lambda kv: layer(
             kv[0])) if p[-1] == key]
         if rows:

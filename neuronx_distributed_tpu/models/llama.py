@@ -1249,13 +1249,14 @@ def moe_layer_stats(variables, layers) -> dict:
     dropless path), and, where the layer holds a share of its experts,
     ``assigned [L]``, the valid assignments whether held or not, and
     under a group limit ``reached [L, 2]``, the valid rows and those with a
-    held assignment."""
+    held assignment, and where the share computes over the rows it holds
+    ``computed [L]``, the rows the spans that ran passed over."""
     stats = variables["moe_stats"]["model"]
     layers = range(layers) if isinstance(layers, int) else layers
     first = stats[f"layer_{layers[0]}"]["moe_mlp"]
     return {k: jnp.stack([stats[f"layer_{i}"]["moe_mlp"][k][-1]
                           for i in layers])
-            for k in ("load", "choice", "assigned", "reached")
+            for k in ("load", "choice", "assigned", "reached", "computed")
             if k in first}
 
 

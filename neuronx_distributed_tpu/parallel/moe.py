@@ -47,7 +47,18 @@ with ``num_experts_global`` routed experts of which this program holds
 normalisation run over all of them and the sum over the chosen ones that
 are held — one rank's part of an expert-parallel layer, computed without
 the exchange (an assignment to an absent expert goes where an invalid row's
-goes: group ``E``, a zero gate, no count).
+goes: group ``E``, a zero gate, no count).  The stable sort puts those rows
+LAST, so a held share of a long array (:func:`held_rows_slab`, a rule over
+shapes: a training step's or a long prefill's tens of thousands of rows,
+never a serving program's few thousand) passes over the rows it HOLDS: the
+grouped matmuls once over the whole sorted array, as ever (their kernel
+visits its groups' row tiles alone), and everything between them — the
+gather, the activation, the masks, in the forward and in a written-out
+backward — in two spans of static shape, the first sized for a balanced
+router's share and the second, the rest, skipped when it holds no held row
+(:func:`_walk_held_rows`) — exact for every count of held rows, with no
+other path beside it and no second copy of a kernel; ``moe_stats`` then also
+holds ``computed``, the rows the spans that ran passed over.
 
 A third family is DeepSeek-V2's GROUP-LIMITED choice (``n_group`` > 1): the
 experts lie in ``n_group`` equal groups of consecutive ones, a group scores
@@ -220,10 +231,12 @@ def take_gmm_lowered() -> dict:
 
 def book_expert_loads(reg, program: str, stats: dict, running):
     """Book one program's fetched expert loads — ``stats["load"] [L, E]``,
-    with ``"assigned" [L]`` where the layers hold a share of their experts
-    and ``"reached" [L, 2]`` where a group limit lets a row reach none of
-    them — into the registry ``reg`` under the family name ``program`` (a
-    serve program's, or ``train_step``); returns ``running``, the loads
+    with ``"assigned" [L]`` where the layers hold a share of their experts,
+    ``"reached" [L, 2]`` where a group limit lets a row reach none of them
+    and ``"computed" [L]`` where a share passes over the rows it holds (a
+    missing key and ``None`` are alike) — into the registry ``reg`` under
+    the family name ``program`` (a serve program's, or ``train_step``);
+    returns ``running``, the loads
     summed since the caller began (``None`` at first), with this program's
     added (:func:`set_expert_load_gauge` reads it).  The counters are those
     ``ServingEngine._count_moe`` documents."""
@@ -232,6 +245,12 @@ def book_expert_loads(reg, program: str, stats: dict, running):
     calls, hit = int((load.sum(axis=1) > 0).sum()), int((load > 0).sum())
     made = int(load.sum() if assigned is None else np.sum(assigned))
     reg.counter("moe/assignments_total").inc(made)
+    # the assignment rows the blocks passed over: those of the spans that
+    # ran, or every one made where a block runs over the whole array
+    computed = stats.get("computed")
+    for suffix in ("", "/" + program):
+        reg.counter("moe/rows_computed_total" + suffix).inc(
+            made if computed is None else int(np.sum(computed)))
     if assigned is not None:
         # a held share: what fell to it, also by program family
         for suffix in ("", "/" + program):
@@ -289,6 +308,16 @@ def _megablox():
         "jax.experimental.pallas.ops.tpu.megablox.gmm")
 
 
+def _tgmm(x, grad, sizes, w, tile, transpose_rhs, interpret=False):
+    """megablox ``tgmm``: the gradient of ``w`` where :func:`_gmm` took
+    ``x`` to the rows whose cotangent is ``grad`` — float32 accumulation
+    over ALL of a group's rows, rounded once to the weight's dtype; an
+    expert no row chose gets zeros."""
+    dw = _megablox().tgmm(x.swapaxes(0, 1), grad, sizes, w.dtype, tile,
+                          num_actual_groups=w.shape[0], interpret=interpret)
+    return dw.swapaxes(1, 2) if transpose_rhs else dw
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _gmm(x, w, sizes, dtype, tile, transpose_rhs, interpret=False):
     """megablox ``gmm`` under ``tile``, its backward under
@@ -316,11 +345,8 @@ def _gmm_bwd(dtype, tile, transpose_rhs, interpret, res, grad):
         # elsewhere): what it leaves there must not reach that token
         dx = jnp.where((jnp.arange(x.shape[0]) < jnp.sum(sizes))[:, None],
                        dx, 0)
-        # float32 accumulation over the rows, rounded once to the weight's
-        # dtype; an expert no row chose gets zeros
-        dw = mb.tgmm(x.swapaxes(0, 1), grad, sizes, w.dtype, drhs,
-                     num_actual_groups=w.shape[0], interpret=interpret)
-    return dx, (dw.swapaxes(1, 2) if transpose_rhs else dw), None
+        dw = _tgmm(x, grad, sizes, w, drhs, transpose_rhs, interpret)
+    return dx, dw, None
 
 
 _gmm.defvjp(_gmm_fwd, _gmm_bwd)
@@ -343,13 +369,19 @@ def _dispatch_rows_fwd(x, order):
     return _dispatch_rows(x, order), (order, x.shape[0])
 
 
+def _rows_to_tokens(g, back, n):
+    """The transpose of the dispatch: the sorted rows' cotangents ``g [N *
+    K, H]`` gathered by ``back`` (the inverse of the sort) ``k`` major and
+    summed in float32 over a token's ``K`` copies -> ``[N, H]``."""
+    k = back.shape[0] // n
+    inv = back.reshape(n, k).T.reshape(-1)
+    return jnp.sum(g[inv].reshape(k, n, g.shape[-1]).astype(jnp.float32),
+                   axis=0).astype(g.dtype)
+
+
 def _dispatch_rows_bwd(res, g):
     order, n = res
-    k = order.shape[0] // n
-    inv = jnp.argsort(order).reshape(n, k).T.reshape(-1)     # k-major
-    gx = jnp.sum(g[inv].reshape(k, n, g.shape[-1]).astype(jnp.float32),
-                 axis=0).astype(g.dtype)
-    return gx, None
+    return _rows_to_tokens(g, jnp.argsort(order), n), None
 
 
 _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
@@ -422,6 +454,289 @@ def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
         # GSPMD cannot split a Pallas call
         return ragged(x, w, sizes)
     return jax.lax.platform_dependent(x, w, sizes, tpu=kernel, default=ragged)
+
+
+def grouped_matmul_dw(x: jax.Array, g: jax.Array, w: jax.Array,
+                      group_sizes: jax.Array,
+                      transpose_rhs: bool = False) -> jax.Array:
+    """The gradient of ``w`` under :func:`grouped_matmul` ``(x, w,
+    group_sizes)`` for the cotangent ``g [M, N]`` of its rows — the weight's
+    half of that function's backward ALONE, for a caller that writes its own
+    backward (:func:`_walk_held_rows`) and would otherwise lower the forward
+    kernel and the data gradient's for the compiler to drop: the same
+    ``tgmm`` under the same tile (:func:`gmm_backward_tiles`), one float32
+    accumulation over all of a group's rows and one rounding;
+    ``lax.ragged_dot``'s transpose where :func:`grouped_matmul` takes that
+    arm."""
+    sizes = group_sizes.astype(jnp.int32)
+
+    def ragged(x, g, w, sizes):
+        return jax.linear_transpose(lambda w: jax.lax.ragged_dot(
+            x, jnp.swapaxes(w, 1, 2) if transpose_rhs else w, sizes,
+            preferred_element_type=g.dtype), w)(g)[0]
+
+    def kernel(x, g, w, sizes):
+        k, n = (w.shape[2], w.shape[1]) if transpose_rhs else w.shape[1:]
+        pad = -x.shape[0] % GMM_TILING[0]   # whole row tiles, in no group
+        if pad:
+            x, g = (jnp.pad(a, ((0, pad), (0, 0))) for a in (x, g))
+        tile = gmm_backward_tiles(x.shape[0], k, n, w.dtype.itemsize)[1]
+        with jax.named_scope("moe_gmm"):
+            return _tgmm(x, g, sizes, w, tile, transpose_rhs)
+
+    if model_parallel_is_initialized() and get_tensor_parallel_size() > 1:
+        return ragged(x, g, w, sizes)
+    return jax.lax.platform_dependent(x, g, w, sizes, tpu=kernel,
+                                      default=ragged)
+
+
+# A held share of a long array passes over the sorted rows it holds
+# (:func:`held_rows_slab`, :func:`_walk_held_rows`).  Not under
+# HELD_WALK_FLOOR assignment rows: a span is a predicate to wait for and a
+# fusion barrier, and what it saves goes with the rows (a serving program
+# lays out a few thousand, bound by launches: at DeepSeek-V2's 3,264 a span of
+# 512 or 1,024 took 6.61 / 6.66 ms a forward where the whole array took 6.53).
+# The first span is HELD_SLAB_SLACK times the rows a uniform router sends the
+# share: a balanced router's rows fit it with their few hundred rows of noise
+# to spare.  Module constants like GMM_TILING, measured on the v5e at
+# LFM2-8B-A1B's widths (tools/held_rows_probe.py; PERF.md, PR 45).
+HELD_WALK_FLOOR = 16384
+HELD_SLAB_SLACK = 1.125
+
+
+def held_rows_slab(rows: int, held: int, routed: int) -> int:
+    """``S``: the rows of the first span where a dropless layer that HOLDS
+    ``held`` of its ``routed`` experts passes over the sorted rows it holds
+    (:func:`_walk_held_rows`) and not over all ``rows`` (= ``N * K``); 0
+    where the block runs once over the whole array, as a layer that holds
+    every expert does.  A function of shapes only.
+
+    Why: the stable sort puts every held assignment before every assignment
+    of group ``E`` (held elsewhere, or an invalid row), so the held rows ARE
+    the prefix ``order[:sum(load)]`` — megablox visits the row tiles of its
+    groups and no others, but everything XLA emits around the grouped
+    matmuls (the gather, the activation, the masks, the transposes of each)
+    passes over the array it is given whatever the loads are.  ``S`` is a
+    multiple of GMM_BACKWARD_ROWS, the widest row tile of the kernels."""
+    if held >= routed or rows < HELD_WALK_FLOOR:
+        return 0
+    tile = GMM_BACKWARD_ROWS
+    share = HELD_SLAB_SLACK * rows * held / routed
+    slab = -(-int(share + 0.999) // tile) * tile
+    return slab if slab < rows else 0
+
+
+def _expert_activation(activation: str):
+    """``pre -> h``: the experts' activation over their pre-activations, the
+    pair ``(gate, up)`` or, of relu2 experts, ``(up,)``."""
+    def act(pre):
+        # in float32 whatever the compiler fuses, rounded once: a span is
+        # compiled apart from its program, and a hidden row must not depend
+        # on where a fusion's edge fell
+        wide = [p.astype(jnp.float32) for p in pre]
+        h = (jnp.square(jax.nn.relu(wide[0])) if activation == "relu2"
+             else jax.nn.silu(wide[0]) * wide[1])
+        return shard_activation(h.astype(pre[0].dtype),
+                                _auto_spec(None, TENSOR_AXES))
+    return act
+
+
+def _spans(rows: int, slab: int):
+    """The two spans ``(first row, rows)`` of the ``rows`` sorted
+    assignments: the first ``slab``, where a balanced router's held rows
+    lie, and all the rest."""
+    slab = min(slab, rows)
+    return ((0, slab),) + (((slab, rows - slab),) if slab < rows else ())
+
+
+def _over_held_spans(spans, held, turn, carry):
+    """``carry`` (arrays of one row an assignment) through ``turn(cut, put,
+    live, carry)`` for the first span and for every later span that holds
+    one of the ``held`` rows, in order: ``cut(a)`` is the span's rows of
+    ``a``, ``put(a, part)`` ``a`` with them replaced, ``live [size, 1]``
+    which of them are held at all.  A later span past every held row is
+    skipped and costs a predicate; what ``carry`` held there stays.  The
+    first span runs whatever it holds (with nothing held its rows are all
+    masked, and a branch there would be one more computation for the
+    compiler to lay out and a fence between fusions, for a case no router
+    produces).  Only what XLA emits row by row goes through here — the
+    grouped matmuls stand OUTSIDE, once, over the whole array (their kernel
+    visits the held rows' tiles alone) — so a span's body is a few fusions
+    and no second copy of a kernel.
+
+    The spans are few and stand in the program one after the other, a later
+    one under its own ``lax.cond`` — NOT a loop: with a ``while`` loop around
+    these kernels anywhere in the step, forward or backward, the compiled
+    TRAIN step's forward came out of the compiler rounding differently from
+    the same forward compiled without the optimizer, from the first routed
+    layer on, and that is what the benchmark's check reads as a gradient
+    5-10% off (PERF.md, PR 45: the finding and the diagnostic)."""
+    for lo, size in spans:
+        def cut(a, lo=lo, size=size):
+            return jax.lax.slice_in_dim(a, lo, lo + size)
+
+        def put(a, part, lo=lo):
+            return jax.lax.dynamic_update_slice_in_dim(a, part, lo, axis=0)
+
+        def body(carry, lo=lo, size=size, cut=cut, put=put):
+            return turn(cut, put, (lo + jnp.arange(size) < held)[:, None],
+                        carry)
+
+        carry = jax.lax.cond(held > lo, body, lambda c: c,
+                             carry) if lo else body(carry)
+    return carry
+
+
+def held_rows_computed(load, rows: int, slab: int):
+    """The rows the spans that ran passed over, of ``rows``."""
+    first = min(slab, rows)
+    return jnp.where(jnp.sum(load) > first, rows, first)
+
+
+def _unsorted(order):
+    """``back``: where each token-major assignment lies once sorted — the
+    inverse of the permutation ``order``."""
+    nk = order.shape[0]
+    return jnp.zeros((nk,), jnp.int32).at[order].set(
+        jnp.arange(nk, dtype=jnp.int32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _walk_held_rows(xt, order, gates, load, wi, wo, slab, activation, dtype):
+    """The dropless block after the sort, of a layer that holds a share of
+    its experts: ``xt [N, H]``, ``order [N * K]`` the stable sort of the
+    assignments by group, ``gates [N, K]`` (zero for an assignment in no
+    held group), ``load [E]``, ``wi`` the pair ``(gate, up)`` (``(up,)`` of
+    relu2 experts), ``wo`` the down projection -> ``y [N, H]`` float32.
+
+    The grouped matmuls are the whole-array block's own calls — the same
+    operand shapes, the same ``load``, the same tiles, ONE of each in the
+    program: megablox walks the row tiles of its groups, so the rows of
+    experts held elsewhere cost it nothing.  What passed over all ``N * K``
+    rows was everything between the kernels: the gather of the rows, the
+    activation, the mask.  Those go through :func:`_over_held_spans`: the
+    sorted rows in two spans of static shape (:func:`_spans`), the first
+    ``slab`` rows and the rest, the second skipped when it holds no held
+    row.  So a balanced router (or one that sends nothing here): the first
+    span alone; every row sent here: both, which is the whole array — every
+    count of held rows from 0 to ``N * K`` through the same code, with no
+    other path beside it, and the rows no span reached hold what the kernels
+    never read (or zeros, where the un-sort reads them).  The un-sort and
+    the gated sum over a token's ``K`` rows are the whole-array block's own
+    (:func:`_combine_rows`): step 0 chose that over adding the held rows
+    into ``y`` at their tokens (a row scatter-add costs 258 ns a row on the
+    v5e where the un-sort's gather costs ~60: PERF.md, PR 45), and it adds a
+    token's rows in one fixed order.
+
+    The backward is written out (``defvjp`` below) for the same reason: JAX's
+    transpose would pass over all the rows between the kernels.  Its kernels
+    are :func:`grouped_matmul`'s own backward's, once each over the whole
+    array — an expert's weight gradient is ONE ``tgmm`` over all its rows
+    wherever the spans' edge falls (:func:`grouped_matmul_dw`), float32
+    accumulation and one rounding, exactly the whole-array block's.  It
+    keeps nothing but its inputs: the held rows are gathered again (with
+    their tokens' cotangent rows, in one gather), the pre-activations
+    computed again (the two matmuls a remat block would repeat anyway), and
+    a gate's cotangent ``ys . dy`` is read as ``h . (dy wo^T)``, so the down
+    projection is not run again."""
+    rows, width = order.shape[0], xt.shape[1]
+    k = rows // xt.shape[0]
+    spans, held = _spans(rows, slab), jnp.sum(load)
+    act = _expert_activation(activation)
+    with jax.named_scope("moe_dispatch"):
+        xs = _over_held_spans(
+            spans, held, lambda cut, put, live, xs: put(
+                xs, xt[cut(order) // k]), jnp.zeros((rows, width), dtype))
+    with jax.named_scope("moe_experts"):
+        pre = tuple(grouped_matmul(xs, w, load, dtype,
+                                   transpose_rhs=activation == "relu2")
+                    for w in wi)
+        # the hidden rows take the place of the first pre-activation's
+        h = _over_held_spans(
+            spans, held, lambda cut, put, live, pre: (put(
+                pre[0], act(tuple(cut(p) for p in pre))),) + pre[1:], pre)[0]
+        out = grouped_matmul(h, wo, load, dtype)
+    with jax.named_scope("moe_combine"):
+        # a row in no group holds what the kernel left there
+        ys = _over_held_spans(
+            spans, held, lambda cut, put, live, ys: put(
+                ys, jnp.where(live, cut(out), 0)),
+            jnp.zeros((rows, width), dtype))
+        return _combine_rows(ys, _unsorted(order), order, gates)
+
+
+def _walk_held_rows_fwd(xt, order, gates, load, wi, wo, slab, activation,
+                        dtype):
+    return (_walk_held_rows(xt, order, gates, load, wi, wo, slab, activation,
+                            dtype), (xt, order, gates, load, wi, wo))
+
+
+def _walk_held_rows_bwd(slab, activation, dtype, res, dy):
+    xt, order, gates, load, wi, wo = res
+    n, width = xt.shape
+    rows = order.shape[0]
+    k = rows // n
+    relu2 = activation == "relu2"
+    spans, held = _spans(rows, slab), jnp.sum(load)
+    act = _expert_activation(activation)
+    flat_gates = gates.reshape(-1)
+
+    def matmul(x, w, transpose_rhs):
+        return grouped_matmul(x, w, load, dtype, transpose_rhs=transpose_rhs)
+
+    with jax.named_scope("moe_dispatch"):
+        # the held rows and their tokens' cotangent rows come in one gather:
+        # a row gather costs by the row, not by its width
+        both = jnp.concatenate([xt, dy.astype(xt.dtype)], axis=1)
+
+        def gather(cut, put, live, carry):
+            got = both[cut(order) // k]
+            return tuple(put(a, part) for a, part in zip(
+                carry, jnp.split(got, [width], axis=1)))
+
+        xs, d_out = _over_held_spans(
+            spans, held, gather, (jnp.zeros((rows, width), dtype),) * 2)
+    with jax.named_scope("moe_experts"):
+        pre = tuple(matmul(xs, w, relu2) for w in wi)
+        # ``dy wo^T`` ungated: the hidden rows' cotangent is the gate times
+        # it, the gate's its dot with the hidden row
+        u = matmul(d_out, wo, True)
+
+        def hidden(cut, put, live, carry):
+            # each result takes the place of an operand that is read no more
+            pre, u, d_out, dots = carry
+            gate = flat_gates[cut(order)][:, None]
+            h, pull = jax.vjp(act, tuple(cut(p) for p in pre))
+            wide = jnp.where(live, cut(u).astype(jnp.float32), 0)
+            dot = jnp.sum(jnp.where(live, h.astype(jnp.float32), 0) * wide,
+                          axis=-1)
+            d_pre = pull((wide * gate).astype(dtype))[0]
+            d_ys = jnp.where(live, cut(d_out).astype(jnp.float32) * gate,
+                             0).astype(dtype)
+            return (tuple(put(p, d) for p, d in zip(pre, d_pre)), put(u, h),
+                    put(d_out, d_ys), put(dots, dot))
+
+        d_pre, h, d_ys, dots = _over_held_spans(
+            spans, held, hidden,
+            (pre, u, d_out, jnp.zeros((rows,), jnp.float32)))
+        d_wo = grouped_matmul_dw(h, d_ys, wo, load)
+        d_wi = tuple(grouped_matmul_dw(xs, d, w, load, transpose_rhs=relu2)
+                     for w, d in zip(wi, d_pre))
+        d_rows = tuple(matmul(d, w, not relu2) for d, w in zip(d_pre, wi))
+    back = _unsorted(order)
+    with jax.named_scope("moe_dispatch"):
+        d_xs = _over_held_spans(
+            spans, held, lambda cut, put, live, d_xs: put(d_xs, jnp.where(
+                live, sum(cut(d).astype(jnp.float32) for d in d_rows),
+                0).astype(dtype)), jnp.zeros((rows, width), dtype))
+        # the transpose of the dispatch as the whole-array block has it
+        gx = _rows_to_tokens(d_xs, back, n).astype(xt.dtype)
+    with jax.named_scope("moe_combine"):
+        d_gates = dots[back].reshape(gates.shape).astype(gates.dtype)
+    return gx, None, d_gates, None, d_wi, d_wo
+
+
+_walk_held_rows.defvjp(_walk_held_rows_fwd, _walk_held_rows_bwd)
 
 
 class ExpertParallelMLP(nn.Module):
@@ -779,9 +1094,11 @@ class ExpertParallelMLP(nn.Module):
         makes it mutable): ``load [E]``, the valid assignments each expert
         HELD took; ``choice [N, K]``, each row's experts of all ``Eg`` in
         gate order (``Eg`` for an invalid row); and, where a share is held,
-        ``assigned``, the valid assignments held or not, and under a group
+        ``assigned``, the valid assignments held or not, under a group
         limit ``reached [2]``, the valid rows and those of them with an
-        assignment that is held."""
+        assignment that is held, and, where the share computes over the rows
+        it holds (:func:`held_rows_slab`), ``computed``, the rows the spans
+        that ran passed over."""
         N, H = xt.shape
         E, I, K = self.num_experts, self.intermediate_size, self.top_k
         Eg = self.num_experts_global or E
@@ -850,25 +1167,37 @@ class ExpertParallelMLP(nn.Module):
             order = jnp.argsort(flat, stable=True)
             load = jnp.sum(flat[:, None] == jnp.arange(E)[None, :], axis=0,
                            dtype=jnp.int32)                    # [E]
-            xs = _dispatch_rows(xt.astype(self.dtype), order)  # [N*K, H]
-        with jax.named_scope("moe_experts"):
-            if self.activation == "relu2":
-                h = jnp.square(jax.nn.relu(grouped_matmul(
-                    xs, wi[0].astype(self.dtype), load, self.dtype,
-                    transpose_rhs=True)))
-            else:
-                gate, up = (grouped_matmul(xs, w.astype(self.dtype), load,
-                                           self.dtype) for w in wi)
-                h = jax.nn.silu(gate) * up
-            h = shard_activation(h, _auto_spec(None, TENSOR_AXES))
-            ys = grouped_matmul(h, wo.astype(self.dtype), load, self.dtype)
-        with jax.named_scope("moe_combine"):
-            in_group = jnp.arange(N * K) < jnp.sum(load)
-            ys = jnp.where(in_group[:, None], ys, 0)
-            back = jnp.zeros((N * K,), jnp.int32).at[order].set(
-                jnp.arange(N * K, dtype=jnp.int32))
-            y = _combine_rows(ys, back, order, gates).astype(self.dtype)
-            y = shard_activation(y, _auto_spec(BATCH_AXES, None))
+        slab = held_rows_slab(N * K, E, Eg)
+        if slab:
+            # a held share of a long array: over the rows it holds
+            y = _walk_held_rows(
+                xt.astype(self.dtype), order, gates, load,
+                tuple(w.astype(self.dtype) for w in wi),
+                wo.astype(self.dtype), slab, self.activation, self.dtype)
+            with jax.named_scope("moe_combine"):
+                y = shard_activation(y.astype(self.dtype),
+                                     _auto_spec(BATCH_AXES, None))
+        else:
+            with jax.named_scope("moe_dispatch"):
+                xs = _dispatch_rows(xt.astype(self.dtype), order)  # [N*K, H]
+            with jax.named_scope("moe_experts"):
+                if self.activation == "relu2":
+                    h = jnp.square(jax.nn.relu(grouped_matmul(
+                        xs, wi[0].astype(self.dtype), load, self.dtype,
+                        transpose_rhs=True)))
+                else:
+                    gate, up = (grouped_matmul(xs, w.astype(self.dtype),
+                                               load, self.dtype) for w in wi)
+                    h = jax.nn.silu(gate) * up
+                h = shard_activation(h, _auto_spec(None, TENSOR_AXES))
+                ys = grouped_matmul(h, wo.astype(self.dtype), load,
+                                    self.dtype)
+            with jax.named_scope("moe_combine"):
+                in_group = jnp.arange(N * K) < jnp.sum(load)
+                ys = jnp.where(in_group[:, None], ys, 0)
+                y = _combine_rows(ys, _unsorted(order), order,
+                                  gates).astype(self.dtype)
+                y = shard_activation(y, _auto_spec(BATCH_AXES, None))
         if not self.is_initializing():  # never part of a parameter tree
             self.sow("moe_stats", "load", load)
             self.sow("moe_stats", "choice", choice)
@@ -882,4 +1211,8 @@ class ExpertParallelMLP(nn.Module):
                         jnp.sum(live, dtype=jnp.int32),
                         jnp.sum(jnp.any(group < E, axis=1),
                                 dtype=jnp.int32)]))
+            if slab:
+                # the rows the spans that ran passed over
+                self.sow("moe_stats", "computed",
+                         held_rows_computed(load, N * K, slab))
         return y, aux.astype(jnp.float32)

@@ -2124,7 +2124,11 @@ class ServingEngine:
         where the layers hold a share of their experts
         ``moe/assignments_held_total`` (those that went to an expert this
         program holds; it and ``moe/assignments_total`` then also by program
-        family), where the router is group-limited besides
+        family), ``moe/rows_computed_total`` (the assignment rows the
+        blocks passed over: every one made, or those of the spans that ran
+        where a held share of a long array computes over the rows it holds;
+        also by program family), where the
+        router is group-limited besides
         ``moe/rows_routed_total`` (valid rows x layers) and
         ``moe/rows_reaching_held_total`` (those with at least one held
         assignment), both also by program family,

@@ -458,7 +458,8 @@ def fit(
         moe_running = book_expert_loads(
             obs_rt.registry, "train_step",
             {"load": fetched["moe_load"],
-             "assigned": fetched.get("moe_assigned")}, moe_running)
+             "assigned": fetched.get("moe_assigned"),
+             "computed": fetched.get("moe_computed")}, moe_running)
         set_expert_load_gauge(obs_rt.registry, moe_running)
 
     def _flush_step_metrics() -> None:

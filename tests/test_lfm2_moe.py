@@ -396,11 +396,19 @@ def test_the_hf_name_map_round_trips_and_reads_the_published_config(
         assert pa == pb and np.array_equal(np.asarray(a), np.asarray(b)), pa
 
 
-def test_fit_books_the_expert_loads_that_ride_the_loss_fetch(tmp_path):
+@pytest.mark.parametrize("rows", ["whole", "walked"])
+def test_fit_books_the_expert_loads_that_ride_the_loss_fetch(
+        tmp_path, rows, monkeypatch):
     """A routed model's ``[L, E]`` loads leave the TRAIN step in its metrics,
     ``fit()`` fetches them with the loss and books them under the family
     ``train_step`` — the counters the serving engine feeds — and a callback
-    sees them as host arrays."""
+    sees them as host arrays.  ``moe/rows_computed_total`` is every
+    assignment made where the blocks run over their whole arrays, and the
+    rows of the spans that ran where a held share computes over the rows it
+    holds (``walked``: the toy's 512 rows a layer taken for a long array)."""
+    if rows == "walked":
+        monkeypatch.setattr(moe, "HELD_WALK_FLOOR", 0)
+        monkeypatch.setattr(moe, "GMM_BACKWARD_ROWS", 32)   # a first span of 288
     import neuronx_distributed_tpu as nxd
     from neuronx_distributed_tpu.obs import Observability
     from neuronx_distributed_tpu.trainer import (
@@ -440,6 +448,18 @@ def test_fit_books_the_expert_loads_that_ride_the_loss_fetch(tmp_path):
     assert snap["moe/experts_hit_total/train_step"] == sum(
         int((x > 0).sum()) for x in loads)
     assert snap["moe/expert_load_max_over_mean"] >= 1.0
+    made = 3 * 4 * B * S * 2
+    if rows == "whole":
+        assert "moe_computed" not in seen[0]
+        assert snap["moe/rows_computed_total/train_step"] == made
+    else:
+        slab = moe.held_rows_slab(B * S * 2, 4, 8)
+        assert 0 < slab < B * S * 2
+        computed = sum(int(np.where(x.sum(axis=1) > slab, B * S * 2,
+                                    slab).sum()) for x in loads)
+        assert snap["moe/rows_computed_total/train_step"] == computed < made
+        assert snap["moe/rows_computed_total"] == computed
+        assert all(m["moe_computed"].shape == (4,) for m in seen)
 
 
 def _toy_step(lr=None, accum=(1,)):

@@ -325,20 +325,33 @@ def test_routed_expert_block_compiles_for_v5e_without_relaying_out_its_weights(t
     assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
 
 
-def test_held_gated_experts_backward_compiles_for_v5e_at_lfm2_widths(topo):
+@pytest.mark.parametrize("rows", ["walked", "whole"])
+def test_held_gated_experts_backward_compiles_for_v5e_at_lfm2_widths(
+        topo, rows, monkeypatch):
     """The TRAINED dropless block at LFM2-8B-A1B's widths (8 of 32 experts x
-    1792 held, 4 a token, hidden 2048) over 16,384 tokens: ``jax.grad``
-    carries nine megablox calls — gate, up and down, each forward, flipped
-    (data gradient) and ``tgmm`` (weight gradient) — under the tiles
-    ``gmm_backward_tiles`` picks from the backward's own operands.  Under
-    the forward's tile (what upstream's ``custom_vjp`` hands on) the chip's
-    compiler refused both backward kernels (AOT, PR 41)."""
+    1792 held, 4 a token, hidden 2048) over 16,384 tokens, loss and
+    gradients.  ``walked``: what the program is since PR 45 — the grouped
+    matmuls once each over all 65,536 sorted rows, as before, and everything
+    between them in two spans, ``held_rows_slab``'s 18,432 and the other
+    47,104, the second under a ``cond``: eleven megablox calls (gate, up,
+    down forward; in the written-out backward gate and up again, ``dy
+    wo^T``, two flipped ``gmm`` for the rows' gradient and three ``tgmm``,
+    each weight's over all its rows), under the tiles the whole block's take.
+    ``whole``: the block as a layer that holds every expert runs it: nine
+    calls — gate, up and down, each forward, flipped and ``tgmm`` — under
+    tiles picked from the backward's own operands (under the forward's tile,
+    what upstream's ``custom_vjp`` hands on, the chip's compiler refused
+    both backward kernels: AOT, PR 41), and its transposes are gathers."""
     from flax import linen as nn
 
-    from neuronx_distributed_tpu.parallel.moe import ExpertParallelMLP
+    from neuronx_distributed_tpu.parallel import moe as moe_lib
 
+    if rows == "whole":
+        monkeypatch.setattr(moe_lib, "held_rows_slab", lambda *a: 0)
+    else:
+        assert moe_lib.held_rows_slab(16384 * 4, 8, 32) == 18432
     mesh = _mesh(topo)
-    moe = ExpertParallelMLP(
+    moe = moe_lib.ExpertParallelMLP(
         num_experts=8, num_experts_global=32, first_expert=0,
         intermediate_size=1792, top_k=4, dispatch="dropless",
         fused_gate_up=False, router_scores="sigmoid", router_bias=True,
@@ -351,16 +364,19 @@ def test_held_gated_experts_backward_compiles_for_v5e_at_lfm2_widths(topo):
         nn.unbox(jax.eval_shape(moe.init, jax.random.PRNGKey(0), x)))
 
     def loss(p, x):
-        return jnp.sum(moe.apply(p, x)[0].astype(jnp.float32))
+        y = moe.apply(p, x)[0].astype(jnp.float32)
+        return jnp.sum(y * jnp.cos(y))      # a loss that needs the forward
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        params, x).compile().as_text()
-    assert text.count("tpu_custom_call") == 9
-    # the transposes of the dispatch and the combine are gathers: no
-    # scatter of ROWS (the scalar ones — the inverse permutation, the
-    # gates' place among the 32 scores, megablox's group tables — stay)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile()
+    text = compiled.as_text()
     import re
 
+    assert text.count("tpu_custom_call") == (11 if rows == "walked" else 9)
+    # rows move by gathers, in the walk as in the transposes of the whole
+    # block's dispatch and combine: no scatter of ROWS (the scalar ones —
+    # the inverse permutation, the gates' place among the 32 scores,
+    # megablox's group tables — stay)
     assert not re.findall(r"\[\d+,\d+[^\]]*\]\S* scatter\(", text)
 
 
