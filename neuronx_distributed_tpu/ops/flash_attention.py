@@ -10,22 +10,40 @@ this kernel is the framework's attention hot path (SURVEY §7 hard-part 6).
 Layout: ``q [B, HQ, S, D]``, ``k/v [B, HKV, T, D]`` with ``HQ = G * HKV``;
 grouped queries read their kv head via ``h // G`` in the BlockSpec index map,
 so GQA costs no extra memory traffic.  Forward emits the per-row logsumexp;
-backward follows the standard two-kernel split (dq by q-block, dk/dv by
-kv-block) with the ``delta = rowsum(dO * O)`` trick so neither direction ever
+backward uses the ``delta = rowsum(dO * O)`` trick so neither direction ever
 materializes probabilities in HBM.
+
+The backward is ONE kernel, ``flash_dq_dkv``, wherever a head's float32 dq
+rows (``S * D * 4`` bytes, ``D`` padded to whole lanes) fit
+``_FUSED_DQ_BYTES`` of VMEM (8 MiB: S 16,384 at ``head_dim`` 128 or less): it
+walks the kv-major band once — ``flash_dkv``'s grid and
+index maps unchanged — and a live tile costs five matmuls (QK^T, dO V^T,
+P^T dO, dS^T Q, dS K) and one pass of the vector work on the score tile.  dk
+and dv leave a kv block at a time as before; ``dS @ K`` is added into the
+tile's rows of an ``[S, D]`` float32 scratch that is zeroed on the head's
+first grid step and cast into a resident ``(1, 1, S, D)`` output block on its
+last, so dq costs no more HBM than it did.  A longer sequence (a ring's
+shard of 32k rows) takes the standard two-kernel split, ``flash_dq`` by
+q-block then ``flash_dkv`` by kv-block: seven matmuls a tile, QK^T and dO V^T
+twice.  The choice is read from the shapes; both paths share one tile
+(:func:`_bwd_tile`) and sum in the same order — kv blocks ascending into a dq
+row, q blocks ascending into a dk / dv block — so they agree bit for bit
+(``tests/test_attention.py``).  The one kernel's NAME starts with
+``flash_dq`` because the benchmark's readers book a backward call by that
+prefix (``benchmarks/harness/trace_scopes.py::KERNEL_GROUPS``).
 
 The grids walk the BAND, not the square (:func:`band_blocks`): under a causal
 (+ sliding-window) mask the inner axis of ``flash_fwd`` / ``flash_dq`` (over
-kv blocks) and of ``flash_dkv`` (over q blocks) is as wide as the widest row
-of blocks the mask leaves visible, and every operand's index map follows the
-band.  A row shorter than that waits on its first block — steps whose body
-does not run (``pl.when``) and for which the pipeline fetches nothing — and
-ends, like every row, on a live step.  At sequence 8192 under a window of
-4096 in blocks of 512 x 512 each kernel runs 108 live block pairs a (batch,
-head) in 144 grid steps (36 dead, none fetching) where the square had 256
-(148 dead, all fetching); causal without a window keeps 256 steps (136 live)
-but its 120 dead ones no longer fetch; a non-causal call has the grid it
-always had.
+kv blocks) and of ``flash_dkv`` / ``flash_dq_dkv`` (over q blocks) is as wide
+as the widest row of blocks the mask leaves visible, and every operand's
+index map follows the band.  A row shorter than that waits on its first block
+— steps whose body does not run (``pl.when``) and for which the pipeline
+fetches nothing — and ends, like every row, on a live step.  At sequence 8192
+under a window of 4096 in blocks of 512 x 512 each kernel runs 108 live block
+pairs a (batch, head) in 144 grid steps (36 dead, none fetching) where the
+square had 256 (148 dead, all fetching); causal without a window keeps 256
+steps (136 live) but its 120 dead ones no longer fetch; a non-causal call has
+the grid it always had.
 
 Row statistics (m, l, lse, delta) are carried as ``[block, 128]``
 lane-replicated tiles — TPU VMEM wants a 128 minor dim.
@@ -64,19 +82,37 @@ def run_kernel(call, interpret: Optional[bool], *operands):
         *operands, tpu=call(False), default=call(True))
 
 
-def _compiler_params(dimension_semantics, interpret: bool):
+def _compiler_params(dimension_semantics, interpret: bool,
+                     vmem_limit_bytes: Optional[int] = None):
     """Mosaic grid-dimension semantics: batch/head/q-block dims are
     embarrassingly parallel; only the kv (resp. q) accumulation dim is
     sequential ("arbitrary").  Declaring this lets Mosaic pipeline and
     parallelize grid steps instead of running the whole grid serially.
+    ``vmem_limit_bytes`` raises Mosaic's scoped VMEM (16 MiB by default) for
+    a kernel that keeps more than blocks resident.
     The interpreter ignores compiler params; pass None to keep interpret
     mode permissive."""
     if interpret:
         return None
-    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=vmem_limit_bytes)
 
 
 _GRID_SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
+# flash_dq_dkv: a head's dq rows accumulate across the kv-block axis too
+_FUSED_GRID_SEMANTICS = ("parallel", "parallel", "arbitrary", "arbitrary")
+
+# One backward call (``flash_dq_dkv``) while a head's float32 dq rows fit
+# this much VMEM (``S * D * 4`` bytes, ``D`` padded to whole lanes: a row of
+# 64 takes the room of one of 128); past it ``flash_dq`` + ``flash_dkv``.
+_FUSED_DQ_BYTES = 8 * 2 ** 20
+_MOSAIC_SCOPED_VMEM = 16 * 2 ** 20  # Mosaic's default scope on a v5e
+
+
+def _dq_rows_vmem(S: int, D: int) -> int:
+    """Bytes of VMEM a head's float32 dq rows take (lanes padded)."""
+    return S * -(-D // LANES) * LANES * 4
+
 
 _MIN_BLOCK = 128  # below one MXU tile the kernel is pure overhead
 
@@ -128,7 +164,7 @@ class Band(NamedTuple):
     width: int    # steps of the inner grid axis
     live: int     # block pairs a (batch, head) whose body runs
     stepped: int  # grid steps a (batch, head): outer blocks x ``width``
-    by_kv: bool   # outer axis over kv blocks (``flash_dkv``), else q blocks
+    by_kv: bool   # outer axis over kv blocks (``flash_dkv`` / ``flash_dq_dkv``), else q
     # outer block index -> (first, last) inner block it reaches; None: all
     reach: Optional[Callable]
 
@@ -149,8 +185,8 @@ def band_blocks(S: int, T: int, bq: int, bk: int, causal: bool,
                 window: Optional[int], by_kv: bool = False) -> Band:
     """Which blocks a flash grid steps over.  ``flash_fwd`` and ``flash_dq``
     run an outer axis over the ``S // bq`` q blocks and an inner one over kv
-    blocks; ``flash_dkv`` (``by_kv``) an outer axis over the ``T // bk`` kv
-    blocks and an inner one over q blocks.  Under the causal (+ window)
+    blocks; ``flash_dkv`` and ``flash_dq_dkv`` (``by_kv``) an outer axis over
+    the ``T // bk`` kv blocks and an inner one over q blocks.  Under the causal (+ window)
     :func:`band_mask` an outer block reaches only the inner blocks
     ``first .. last`` (``reach``, the mask's inequalities taken blockwise:
     plain integer arithmetic, so it serves python ints, numpy arrays and the
@@ -258,16 +294,18 @@ def _segment_mask(qseg_ref, kseg_ref, block_q, block_k):
     return jnp.logical_and(qs == ks, qs > 0)
 
 
-def _band_step(band, *, causal, block_q, block_k, kv_offset, window):
-    """Where this grid step stands: ``(qi, ki, j, run)``.  The body runs on
-    a block not visited before that the mask does not hide whole."""
+def _band_step(band, *, causal, block_q, block_k, kv_offset, window, **_):
+    """Where this grid step stands: ``(qi, ki, j, run, first_q)``.  The body
+    runs on a block not visited before that the mask does not hide whole;
+    ``first_q`` is the q block's first position in the kv timeline.  Takes a
+    kernel's tile keywords (:func:`_score_tile`) and reads the mask's."""
     outer, j = pl.program_id(2), pl.program_id(3)
     inner, run = band.step(outer, j)
     qi, ki = (inner, outer) if band.by_kv else (outer, inner)
     if causal:
         run = jnp.logical_and(run, _block_visible(
             qi, ki, block_q, block_k, kv_offset, window))
-    return qi, ki, j, run
+    return qi, ki, j, run, qi * block_q + kv_offset
 
 
 def _band_specs(band, bq, bk, D, G):
@@ -289,16 +327,41 @@ def _band_specs(band, bq, bk, D, G):
     )
 
 
+def _score_tile(q, k, first_q, ki, *, sm_scale, causal, block_q, block_k,
+                qseg_ref, kseg_ref, window, softcap, **_):
+    """The masked fp32 scores ``[bq, bk]`` of the q block at ``first_q``
+    against kv block ``ki``, and the cap's ``tanh`` (None without ``softcap``) that the
+    backward chains through: written once for the four kernel bodies.
+
+    MXU dots consume the NATIVE (bf16) operands with fp32 accumulation
+    (preferred_element_type) — casting inputs to fp32 first would push the
+    matmuls onto the fp32 path at a fraction of bf16 throughput."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * sm_scale
+    t = None
+    if softcap is not None:
+        # Gemma-2-style logit softcapping, applied BEFORE masking (the
+        # mask's NEG_INF must stay -inf-like, not get squashed to ±cap)
+        t = jnp.tanh(s / softcap)
+        s = softcap * t
+    if causal:
+        qpos = first_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+        kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+        s = jnp.where(kpos <= qpos, s, NEG_INF)
+        if window is not None:
+            s = jnp.where(kpos > qpos - window, s, NEG_INF)
+    if qseg_ref is not None:
+        s = jnp.where(_segment_mask(qseg_ref, kseg_ref, block_q, block_k), s, NEG_INF)
+    return s, t
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, sm_scale, causal, block_q, block_k, band, kv_offset,
-                qseg_ref=None, kseg_ref=None, window=None, softcap=None):
+                *, band, **tile):
     # causal: the inner axis walks only the kv blocks this q block reaches
     # (neither those entirely above the diagonal nor, with a sliding window,
     # those entirely left of the band: band_blocks)
-    qi, ki, j, run = _band_step(band, causal=causal, block_q=block_q,
-                                block_k=block_k, kv_offset=kv_offset,
-                                window=window)
-    first_q = qi * block_q + kv_offset  # q positions offset into kv timeline
+    _, ki, j, run, first_q = _band_step(band, **tile)
 
     @pl.when(j == 0)
     def _init():
@@ -308,27 +371,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
     @pl.when(run)
     def _body():
-        # MXU dots consume the NATIVE (bf16) operands with fp32 accumulation
-        # (preferred_element_type) — casting inputs to fp32 first would push
-        # the matmuls onto the fp32 path at a fraction of bf16 throughput.
         q = q_ref[0, 0]  # [bq, D]
         k = k_ref[0, 0]  # [bk, D]
         v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # [bq, bk] fp32
-        if softcap is not None:
-            # Gemma-2-style logit softcapping, applied BEFORE masking (the
-            # mask's NEG_INF must stay -inf-like, not get squashed to ±cap)
-            s = softcap * jnp.tanh(s / softcap)
-        if causal:
-            qpos = first_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
-            if window is not None:
-                s = jnp.where(kpos > qpos - window, s, NEG_INF)
-        if qseg_ref is not None:
-            s = jnp.where(_segment_mask(qseg_ref, kseg_ref, block_q, block_k), s, NEG_INF)
+        s, _ = _score_tile(q, k, first_q, ki, **tile)  # [bq, bk] fp32
 
         m_prev = m_scr[:, :1]  # [bq, 1]
         l_prev = l_scr[:, :1]
@@ -353,6 +399,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 
 _SUBLANES = 8
+
+
+def _kernel(body, n_in, segmented, **kw):
+    """``body`` as the kernel of a call whose operands are ``n_in`` plain
+    ones, then (``segmented``) the two id tiles, then outputs and scratch:
+    the id tiles reach ``body`` as ``qseg_ref`` / ``kseg_ref`` (else None)."""
+    def kernel(*refs):
+        qs_r, ks_r = refs[n_in:n_in + 2] if segmented else (None, None)
+        body(*refs[:n_in], *refs[n_in + 2 * segmented:],
+             qseg_ref=qs_r, kseg_ref=ks_r, **kw)
+
+    return kernel
 
 
 def _seg_operands(q_seg, kv_seg, B, S, T):
@@ -382,16 +440,9 @@ def _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     grid = (B, HQ, S // bq, band.width)
     segmented = q_seg is not None
 
-    def kernel(*refs):
-        if segmented:
-            q_r, k_r, v_r, qs_r, ks_r, o_r, lse_r, m_s, l_s, a_s = refs
-        else:
-            q_r, k_r, v_r, o_r, lse_r, m_s, l_s, a_s = refs
-            qs_r = ks_r = None
-        _fwd_kernel(q_r, k_r, v_r, o_r, lse_r, m_s, l_s, a_s,
-                    sm_scale=scale, causal=causal, block_q=bq, block_k=bk,
-                    band=band, kv_offset=kv_offset,
-                    qseg_ref=qs_r, kseg_ref=ks_r, window=window, softcap=softcap)
+    kernel = _kernel(_fwd_kernel, 3, segmented, band=band,
+                     sm_scale=scale, causal=causal, block_q=bq, block_k=bk,
+                     kv_offset=kv_offset, window=window, softcap=softcap)
 
     scratch = [
         # m / l lane-replicated, acc in fp32
@@ -431,13 +482,33 @@ def _fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
 # ---------------------------------------------------------------------------
 
 
+def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, first_q, ki,
+              *, sm_scale, **tile):
+    """One live block pair of the backward, written once for the three
+    bodies: ``(q, k, do, p, ds)`` — ``p [bq, bk]`` the fp32 probabilities,
+    ``ds`` the score gradient, scaled and cast for the MXU (bf16 operands
+    into every dot, fp32 accumulation: see :func:`_score_tile`)."""
+    q = q_ref[0, 0]
+    k = k_ref[0, 0]
+    v = v_ref[0, 0]
+    do = do_ref[0, 0]
+    lse = lse_ref[0, 0][:, :1]
+    delta = delta_ref[0, 0][:, :1]
+    s, t = _score_tile(q, k, first_q, ki, sm_scale=sm_scale, **tile)
+    p = jnp.exp(s - lse)  # [bq, bk] fp32
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    ds = p * (dp - delta)
+    if t is not None:
+        # chain through the cap: d(cap*tanh(s0/cap))/ds0 = 1 - tanh^2
+        ds = ds * (1.0 - t * t)
+    return q, k, do, p, (ds * sm_scale).astype(q.dtype)
+
+
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_scr,
-               *, sm_scale, causal, block_q, block_k, band, kv_offset,
-               qseg_ref=None, kseg_ref=None, window=None, softcap=None):
-    qi, ki, j, run = _band_step(band, causal=causal, block_q=block_q,
-                                block_k=block_k, kv_offset=kv_offset,
-                                window=window)
-    first_q = qi * block_q + kv_offset
+               *, band, **tile):
+    _, ki, j, run, first_q = _band_step(band, **tile)
 
     @pl.when(j == 0)
     def _init():
@@ -445,37 +516,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_scr,
 
     @pl.when(run)
     def _body():
-        # bf16 operands into every MXU dot, fp32 accumulation (see _fwd_kernel)
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0][:, :1]
-        delta = delta_ref[0, 0][:, :1]
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        if softcap is not None:
-            t = jnp.tanh(s / softcap)
-            s = softcap * t
-        if causal:
-            qpos = first_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
-            if window is not None:
-                s = jnp.where(kpos > qpos - window, s, NEG_INF)
-        if qseg_ref is not None:
-            s = jnp.where(_segment_mask(qseg_ref, kseg_ref, block_q, block_k), s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta)
-        if softcap is not None:
-            # chain through the cap: d(cap*tanh(s0/cap))/ds0 = 1 - tanh^2
-            ds = ds * (1.0 - t * t)
-        ds = (ds * sm_scale).astype(k.dtype)
+        _, k, _, _, ds = _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                   delta_ref, first_q, ki, **tile)
         acc_scr[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -485,79 +527,77 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_scr,
         dq_ref[0, 0] = acc_scr[...].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                dk_scr, dv_scr,
-                *, sm_scale, causal, block_q, block_k, band, kv_offset,
-                qseg_ref=None, kseg_ref=None, window=None, softcap=None):
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *outs_and_scratch,
+                band, **tile):
+    """``flash_dkv`` — and ``flash_dq_dkv`` when the call also has a dq
+    output (a head's whole ``[S, D]`` block, resident over both inner axes)
+    and its float32 scratch: the same walk, one more matmul a tile."""
+    dq_ref = dq_scr = None
+    if len(outs_and_scratch) == 4:
+        dk_ref, dv_ref, dk_scr, dv_scr = outs_and_scratch
+    else:
+        dk_ref, dv_ref, dq_ref, dk_scr, dv_scr, dq_scr = outs_and_scratch
+    block_q = tile["block_q"]
     # the mirror: the inner axis walks the q blocks this kv block is seen by
-    qi, ki, j, run = _band_step(band, causal=causal, block_q=block_q,
-                                block_k=block_k, kv_offset=kv_offset,
-                                window=window)
-    first_q = qi * block_q + kv_offset
+    qi, ki, j, run, first_q = _band_step(band, **tile)
+    last = j == band.width - 1
 
     @pl.when(j == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
+    if dq_ref is not None:
+        @pl.when(jnp.logical_and(ki == 0, j == 0))
+        def _init_head():
+            dq_scr[...] = jnp.zeros_like(dq_scr)
+
     @pl.when(run)
     def _body():
-        # bf16 operands into every MXU dot, fp32 accumulation (see _fwd_kernel)
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0][:, :1]
-        delta = delta_ref[0, 0][:, :1]
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        if softcap is not None:
-            t = jnp.tanh(s / softcap)
-            s = softcap * t
-        if causal:
-            qpos = first_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
-            if window is not None:
-                s = jnp.where(kpos > qpos - window, s, NEG_INF)
-        if qseg_ref is not None:
-            s = jnp.where(_segment_mask(qseg_ref, kseg_ref, block_q, block_k), s, NEG_INF)
-        p = jnp.exp(s - lse)  # [bq, bk] fp32
-        pb = p.astype(do.dtype)
+        q, k, do, p, ds = _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                    delta_ref, first_q, ki, **tile)
         dv_scr[...] += jax.lax.dot_general(
-            pb, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )  # p^T @ do -> [bk, D]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta)
-        if softcap is not None:
-            ds = ds * (1.0 - t * t)  # chain through the cap (see _dq_kernel)
-        ds = (ds * sm_scale).astype(q.dtype)  # [bq, bk]
         dk_scr[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )  # ds^T @ q -> [bk, D]
+        if dq_ref is not None:
+            # kv blocks ascending into a dq row, as flash_dq's inner axis
+            rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+            dq_scr[rows, :] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    @pl.when(j == band.width - 1)
+    @pl.when(last)
     def _finish():
         dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
 
+    if dq_ref is not None:
+        @pl.when(jnp.logical_and(ki == pl.num_programs(2) - 1, last))
+        def _finish_head():
+            dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
+
 
 def _bwd_impl(q, k, v, lse, do, delta_rows, causal, sm_scale, block_q, block_k, interpret,
               q_seg=None, kv_seg=None, window=None, softcap=None):
-    """Backward kernels; ``delta_rows [B,HQ,S]`` is the softmax correction term
+    """The backward: ``delta_rows [B,HQ,S]`` is the softmax correction term
     (``rowsum(dO*O)``, minus the lse cotangent when one exists — see
-    :func:`flash_attention_with_lse`)."""
+    :func:`flash_attention_with_lse`).  ONE call, ``flash_dq_dkv``, while a
+    head's float32 dq rows fit ``_FUSED_DQ_BYTES`` of VMEM — it walks the
+    kv-major band once and does the tile's five matmuls; a longer sequence
+    takes ``flash_dq`` then ``flash_dkv`` (seven).  Both sum in the same
+    order: the same gradients bit for bit."""
     B, HQ, S, D = q.shape
     _, HKV, T, _ = k.shape
     G = HQ // HKV
     bq, bk = _block_sizes(S, T, block_q, block_k)
-    scale = (D ** -0.5) if sm_scale is None else sm_scale
-    kv_offset = T - S
     segmented = q_seg is not None
+    tile = dict(sm_scale=(D ** -0.5) if sm_scale is None else sm_scale,
+                causal=causal, block_q=bq, block_k=bk, kv_offset=T - S,
+                window=window, softcap=softcap)
 
     delta = jnp.broadcast_to(delta_rows[..., None], (B, HQ, S, LANES))
     operands = [q, k, v, do, lse, delta]
@@ -570,71 +610,60 @@ def _bwd_impl(q, k, v, lse, do, delta_rows, causal, sm_scale, block_q, block_k, 
         specs = [q_like, kv_like, kv_like, q_like, row_stat, row_stat]
         return (specs + [qs_spec, ks_spec] if segmented else specs), q_like
 
-    dq_band = band_blocks(S, T, bq, bk, causal, window)
-    dq_in_specs, dq_out_spec = in_specs(dq_band)
-
-    def dq_kernel(*refs):
-        if segmented:
-            q_r, k_r, v_r, do_r, lse_r, d_r, qs_r, ks_r, dq_r, a_s = refs
-        else:
-            q_r, k_r, v_r, do_r, lse_r, d_r, dq_r, a_s = refs
-            qs_r = ks_r = None
-        _dq_kernel(q_r, k_r, v_r, do_r, lse_r, d_r, dq_r, a_s,
-                   sm_scale=scale, causal=causal, block_q=bq, block_k=bk,
-                   band=dq_band, kv_offset=kv_offset,
-                   qseg_ref=qs_r, kseg_ref=ks_r, window=window, softcap=softcap)
+    dq_shape = jax.ShapeDtypeStruct((B, HQ, S, D), q.dtype)
+    dq_rows = _dq_rows_vmem(S, D)
+    fused = dq_rows <= _FUSED_DQ_BYTES
 
     def dq_call(interp):
+        band = band_blocks(S, T, bq, bk, causal, window)
+        specs, dq_spec = in_specs(band)
         return pl.pallas_call(
-            dq_kernel,
-            grid=(B, HQ, S // bq, dq_band.width),
+            _kernel(_dq_kernel, 6, segmented, band=band, **tile),
+            grid=(B, HQ, S // bq, band.width),
             compiler_params=_compiler_params(_GRID_SEMANTICS, interp),
-            in_specs=dq_in_specs,
-            out_specs=dq_out_spec,
-            out_shape=jax.ShapeDtypeStruct((B, HQ, S, D), q.dtype),
+            in_specs=specs,
+            out_specs=dq_spec,
+            out_shape=dq_shape,
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
             interpret=interp,
             name="flash_dq",
         )
 
-    dq = run_kernel(dq_call, interpret, *operands)
-
-    # dk/dv are accumulated per q-head then group-summed onto kv heads
-    dkv_band = band_blocks(S, T, bq, bk, causal, window, by_kv=True)
-
-    def dkv_kernel(*refs):
-        if segmented:
-            q_r, k_r, v_r, do_r, lse_r, d_r, qs_r, ks_r, dk_r, dv_r, dks, dvs = refs
-        else:
-            q_r, k_r, v_r, do_r, lse_r, d_r, dk_r, dv_r, dks, dvs = refs
-            qs_r = ks_r = None
-        _dkv_kernel(q_r, k_r, v_r, do_r, lse_r, d_r, dk_r, dv_r, dks, dvs,
-                    sm_scale=scale, causal=causal, block_q=bq, block_k=bk,
-                    band=dkv_band, kv_offset=kv_offset,
-                    qseg_ref=qs_r, kseg_ref=ks_r, window=window, softcap=softcap)
-
     def dkv_call(interp):
-        # a kv block a Q head: the outer index, no group division
+        band = band_blocks(S, T, bq, bk, causal, window, by_kv=True)
+        # dk/dv a kv block a Q head (the outer index, no group division):
+        # accumulated per q-head then group-summed onto kv heads
         per_q_head = pl.BlockSpec((1, 1, bk, D), lambda b, h, ki, j: (b, h, ki, 0))
+        dkv_shape = jax.ShapeDtypeStruct((B, HQ, T, D), jnp.float32)
+        block_scr = pltpu.VMEM((bk, D), jnp.float32)
+        out_specs, out_shape = [per_q_head, per_q_head], [dkv_shape, dkv_shape]
+        scratch, semantics, vmem_limit = [block_scr, block_scr], _GRID_SEMANTICS, None
+        if fused:
+            # the head's dq: one block, resident over both inner axes
+            out_specs.append(pl.BlockSpec((1, 1, S, D), lambda b, h, ki, j: (b, h, 0, 0)))
+            out_shape.append(dq_shape)
+            scratch.append(pltpu.VMEM((S, D), jnp.float32))
+            semantics = _FUSED_GRID_SEMANTICS
+            # the scratch and the output block's two buffers
+            vmem_limit = (_MOSAIC_SCOPED_VMEM + dq_rows
+                          + 2 * (dq_rows // 4) * q.dtype.itemsize)
         return pl.pallas_call(
-            dkv_kernel,
-            grid=(B, HQ, T // bk, dkv_band.width),
-            compiler_params=_compiler_params(_GRID_SEMANTICS, interp),
-            in_specs=in_specs(dkv_band)[0],
-            out_specs=[per_q_head, per_q_head],
-            out_shape=[
-                jax.ShapeDtypeStruct((B, HQ, T, D), jnp.float32),
-                jax.ShapeDtypeStruct((B, HQ, T, D), jnp.float32),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((bk, D), jnp.float32),
-                pltpu.VMEM((bk, D), jnp.float32),
-            ],
+            _kernel(_dkv_kernel, 6, segmented, band=band, **tile),
+            grid=(B, HQ, T // bk, band.width),
+            compiler_params=_compiler_params(semantics, interp, vmem_limit),
+            in_specs=in_specs(band)[0],
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=scratch,
             interpret=interp,
-            name="flash_dkv",
+            name="flash_dq_dkv" if fused else "flash_dkv",
         )
 
-    dk_q, dv_q = run_kernel(dkv_call, interpret, *operands)
+    if fused:
+        dk_q, dv_q, dq = run_kernel(dkv_call, interpret, *operands)
+    else:
+        dq = run_kernel(dq_call, interpret, *operands)
+        dk_q, dv_q = run_kernel(dkv_call, interpret, *operands)
 
     dk = jnp.sum(dk_q.reshape(B, HKV, G, T, D), axis=2).astype(k.dtype)
     dv = jnp.sum(dv_q.reshape(B, HKV, G, T, D), axis=2).astype(v.dtype)
@@ -669,7 +698,7 @@ def flash_attention(
 
     ``window`` (causal only) is Mistral-style sliding-window attention:
     query at position p attends keys in ``[p - window + 1, p]``.  The grids
-    of all three kernels step only over the blocks the band reaches
+    of the forward and the backward step only over the blocks the band reaches
     (:func:`band_blocks`): blocks entirely left of the band or above the
     diagonal are neither computed, nor fetched, nor stepped over, so
     long-sequence SWA costs O(S * window) in FLOPs, in HBM traffic AND in

@@ -112,6 +112,15 @@ SLOW_TESTS = {
 }
 
 
+# tier-1 all the same, inside a slow module: the guard of every model that
+# trains through the flash backward (one kernel under the VMEM budget, two
+# past it: they must agree bit for bit)
+TIER1_TESTS = {
+    "test_flash_fused_backward_equals_split",
+    "test_flash_backward_kernels_follow_the_budget",
+}
+
+
 def run_cli(script_path, *args, timeout=590):
     """Run a repo CLI (launcher/runner) as a subprocess with the repo on
     PYTHONPATH; asserts rc == 0 with tail-truncated diagnostics.  The one
@@ -146,7 +155,8 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         mod = item.module.__name__.rsplit(".", 1)[-1]
         name = getattr(item, "originalname", item.name)
-        slow = mod in SLOW_MODULES or name in SLOW_TESTS
+        slow = ((mod in SLOW_MODULES and name not in TIER1_TESTS)
+                or name in SLOW_TESTS)
         if mod == "test_pipeline" and "devices8" in getattr(item, "fixturenames", ()):
             slow = True  # engine tests compile multi-stage shard_maps
         if slow:
@@ -297,8 +307,8 @@ def lockstep_with_the_old_order(make_engine, make_requests, max_steps=600):
 @contextlib.contextmanager
 def square_flash_grid():
     """The flash kernels on the grid they had before they walked the band:
-    the inner axis of ``flash_fwd`` / ``flash_dq`` / ``flash_dkv`` spans the
-    whole sequence, every operand's block is the plain grid index, and a
+    the inner axis of ``flash_fwd`` / ``flash_dq`` / ``flash_dkv`` (and of
+    ``flash_dq_dkv``, which walks ``flash_dkv``'s) spans the whole sequence, every operand's block is the plain grid index, and a
     block the mask hides whole is a step whose body does not run.  Same
     bodies, same ascending order over the live blocks: what the band grid
     computes must equal this bit for bit."""
@@ -315,6 +325,22 @@ def square_flash_grid():
         yield
     finally:
         fa.band_blocks = banded
+
+
+@contextlib.contextmanager
+def split_flash_backward():
+    """The flash backward held to its two kernels, ``flash_dq`` then
+    ``flash_dkv``, whatever the sequence: the VMEM budget under which ONE
+    call (``flash_dq_dkv``) keeps a head's dq rows is set to nothing.  Same
+    tile, same ascending order of the sums: what the one call computes must
+    equal this bit for bit.  (The budget is read when the backward is
+    traced: take gradients inside, through a function not jitted before.)"""
+    fa = importlib.import_module("neuronx_distributed_tpu.ops.flash_attention")
+    budget, fa._FUSED_DQ_BYTES = fa._FUSED_DQ_BYTES, 0
+    try:
+        yield
+    finally:
+        fa._FUSED_DQ_BYTES = budget
 
 
 def sharded_params(params):

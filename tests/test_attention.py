@@ -12,14 +12,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import square_flash_grid
+from conftest import split_flash_backward, square_flash_grid
 from neuronx_distributed_tpu.ops import (
     flash_attention,
+    flash_attention_segmented,
     flash_attention_with_lse,
     mha_reference,
     ring_attention,
 )
-from neuronx_distributed_tpu.ops.flash_attention import band_blocks
+from neuronx_distributed_tpu.ops.flash_attention import (
+    band_blocks,
+    flash_attention_segmented_with_lse,
+)
 from neuronx_distributed_tpu.parallel.mesh import initialize_model_parallel
 
 
@@ -111,10 +115,109 @@ def test_flash_causal_band_equals_square_grid(case):
                                rtol=1e-5, atol=1e-5)
 
 
+FUSED_BACKWARD_CASES = {
+    # name: (S, T, bq, bk, causal, window, gqa, head_dim, dtype, softcap,
+    #        segmented, lse cotangent)
+    "causal_mha_d64_f32": (64, 64, 16, 16, True, None, 1, 64, "float32", None, False, False),
+    "causal_gqa2_d128_bf16": (64, 64, 16, 16, True, None, 2, 128, "bfloat16", None, False, False),
+    "causal_gqa4_d64_bf16": (64, 64, 16, 16, True, None, 4, 64, "bfloat16", None, False, False),
+    "window_gqa2_d64_bf16": (64, 64, 16, 16, True, 24, 2, 64, "bfloat16", None, False, False),
+    "window_mha_d128_f32": (64, 64, 16, 16, True, 40, 1, 128, "float32", None, False, False),
+    "window_unequal_blocks_gqa2": (64, 64, 32, 16, True, 24, 2, 64, "bfloat16", None, False, False),
+    "full_gqa4_d64_f32": (64, 64, 16, 16, False, None, 4, 64, "float32", None, False, False),
+    "full_mha_d128_bf16": (64, 64, 16, 16, False, None, 1, 128, "bfloat16", None, False, False),
+    "decode_offset_gqa2_bf16": (32, 96, 16, 16, True, None, 2, 64, "bfloat16", None, False, False),
+    "decode_offset_window_f32": (32, 96, 16, 16, True, 40, 1, 64, "float32", None, False, False),
+    "softcap_causal_gqa2_bf16": (64, 64, 16, 16, True, None, 2, 64, "bfloat16", 30.0, False, False),
+    "softcap_window_f32": (64, 64, 16, 16, True, 24, 1, 64, "float32", 20.0, False, False),
+    "segmented_gqa2_bf16": (64, 64, 16, 16, True, None, 2, 64, "bfloat16", None, True, False),
+    "segmented_window_softcap_f32": (64, 64, 16, 16, True, 24, 1, 128, "float32", 30.0, True, False),
+    "lse_cotangent_gqa4_bf16": (64, 64, 16, 16, True, None, 4, 64, "bfloat16", None, False, True),
+    "lse_cotangent_window_f32": (64, 64, 16, 16, True, 24, 2, 128, "float32", None, False, True),
+    "segmented_lse_cotangent_bf16": (64, 64, 16, 16, True, None, 2, 64, "bfloat16", None, True, True),
+}
+
+
+def _backward_kernels(loss, *args):
+    """The backward flash kernels' names in the traced text of ``loss``'s
+    gradient (nothing runs)."""
+    import re
+
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(*args))
+    return set(re.findall(r"name=(flash_\w+)", text)) - {"flash_fwd"}
+
+
+@pytest.mark.parametrize("case", FUSED_BACKWARD_CASES.values(),
+                         ids=FUSED_BACKWARD_CASES.keys())
+def test_flash_fused_backward_equals_split(case):
+    """``flash_dq_dkv`` (one call, five matmuls a tile, a head's dq rows in
+    VMEM) against ``flash_dq`` + ``flash_dkv`` (the path past the budget):
+    kv blocks ascending into a dq row, q blocks ascending into a dk / dv
+    block, in both — dq, dk and dv bit for bit, through all four entries."""
+    (S, T, bq, bk, causal, window, gqa, D, dtype, softcap, segmented,
+     with_lse) = case
+    B, HKV = 2, 2
+    q, k, v = _qkv(jax.random.PRNGKey(S + T + D), B, HKV * gqa, HKV, S, T, D,
+                   jnp.dtype(dtype))
+    # documents of 24 keys, the q rows at the end of the kv timeline
+    kv_seg = jnp.broadcast_to(1 + jnp.arange(T) // 24, (B, T))
+    segs = (kv_seg[:, T - S:], kv_seg) if segmented else ()
+    entry = {(False, False): flash_attention,
+             (False, True): flash_attention_with_lse,
+             (True, False): flash_attention_segmented,
+             (True, True): flash_attention_segmented_with_lse}[segmented, with_lse]
+
+    def loss(q, k, v):
+        out = entry(q, k, v, *segs, causal, None, bq, bk, None, window, softcap)
+        if not with_lse:
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+        o, lse = out  # a non-zero cotangent into the lse too
+        return jnp.sum(o.astype(jnp.float32) ** 2) + jnp.sum(jnp.sin(lse))
+
+    fused = jax.grad(loss, (0, 1, 2))(q, k, v)
+    assert _backward_kernels(loss, q, k, v) == {"flash_dq_dkv"}
+    with split_flash_backward():
+        split = jax.grad(loss, (0, 1, 2))(q, k, v)
+        assert _backward_kernels(loss, q, k, v) == {"flash_dq", "flash_dkv"}
+    for a, b, name in zip(fused, split, ("dq", "dk", "dv")):
+        assert a.dtype == b.dtype == jnp.dtype(dtype)
+        assert np.any(np.asarray(a, np.float32) != 0), name
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32), err_msg=name)
+
+
+@pytest.mark.parametrize("case", [
+    # (S, head_dim, dtype, the backward's kernels)
+    (8192, 128, "bfloat16", {"flash_dq_dkv"}),          # the Mistral cells
+    (8192, 64, "bfloat16", {"flash_dq_dkv"}),           # LFM2's
+    (16384, 128, "float32", {"flash_dq_dkv"}),          # 8 MiB: the budget
+    (16384, 64, "bfloat16", {"flash_dq_dkv"}),
+    # a row of 64 takes a whole lane tile in VMEM: 16 MiB, as at 128
+    (32768, 64, "bfloat16", {"flash_dq", "flash_dkv"}),
+    (32768, 128, "bfloat16", {"flash_dq", "flash_dkv"}),  # a ring's shard
+    (16384, 256, "bfloat16", {"flash_dq", "flash_dkv"}),
+], ids=lambda c: f"s{c[0]}_d{c[1]}_{c[2]}")
+def test_flash_backward_kernels_follow_the_budget(case):
+    """Which calls take the one kernel is read from the shapes: a head's
+    float32 dq rows (``S * D * 4`` bytes with ``D`` padded to whole lanes,
+    whatever the operands' dtype) within ``_FUSED_DQ_BYTES`` ->
+    ``flash_dq_dkv`` alone; past it -> the two kernels.  Read off the traced program's text (nothing runs)."""
+    S, D, dtype, kernels = case
+    q = jax.ShapeDtypeStruct((1, 4, S, D), jnp.dtype(dtype))
+    kv = jax.ShapeDtypeStruct((1, 2, S, D), jnp.dtype(dtype))
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, True, None, 512, 512, None,
+                                       4096).astype(jnp.float32))
+
+    assert _backward_kernels(loss, q, kv, kv) == kernels
+
+
 def test_flash_autotune_prints_live_of_stepped():
     """`tools/flash_autotune.py --cpu --tiny --window W`: a line a block
-    pair with the three kernels' `kernel_us` (None off the chip) and, beside
-    them, the live block pairs of the steps each grid makes."""
+    pair with the kernels' `kernel_us` (None off the chip) — the one
+    backward call beside the pair it replaces, each from its own program —
+    and, beside them, the live block pairs of the steps each grid makes."""
     from conftest import run_cli
 
     proc = run_cli("tools/flash_autotune.py", "--cpu", "--tiny", "--window", "24")
@@ -128,13 +231,14 @@ def test_flash_autotune_prints_live_of_stepped():
     for rec in sweeps:
         assert rec["shape"]["window"] == 24 and rec["kernel_us"] is None
         S, bq, bk = rec["shape"]["seq"], rec["block_q"], rec["block_k"]
+        assert rec["fwd_bwd_split_ms"] > 0  # under the budget: both timed
         for kernel, by_kv in (("flash_fwd", False), ("flash_dq", False),
-                              ("flash_dkv", True)):
+                              ("flash_dkv", True), ("flash_dq_dkv", True)):
             band = band_blocks(S, S, bq, bk, True, 24, by_kv)
             assert rec["live_of_stepped"][kernel] == [band.live, band.stepped]
             assert band.live < band.stepped <= (S // bq) * (S // bk)
     [fine] = [r for r in sweeps if r["block_q"] == r["block_k"] == 16]
-    assert fine["live_of_stepped"]["flash_dkv"] == [9, 12]  # the square: 16
+    assert fine["live_of_stepped"]["flash_dq_dkv"] == [9, 12]  # the square: 16
 
 
 @pytest.mark.parametrize("gqa", [1, 2], ids=["mha", "gqa2"])

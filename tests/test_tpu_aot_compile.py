@@ -70,17 +70,31 @@ def _compiled_text(fn, *args):
 
 
 FLASH_CASES = {
-    # name: (S, window, segmented, backward, custom calls expected)
-    "fwd": (2048, None, False, False, 1),
-    "bwd": (2048, None, False, True, 3),          # fwd + dq + dkv
-    "window4096_s8192_bwd": (8192, WINDOW, False, True, 3),
-    "segmented_bwd": (2048, None, True, True, 3),
+    # name: (S, window, segmented, backward, the Mosaic kernels expected)
+    "fwd": (2048, None, False, False, {"flash_fwd"}),
+    "bwd": (2048, None, False, True, {"flash_fwd", "flash_dq_dkv"}),
+    "window4096_s8192_bwd": (8192, WINDOW, False, True,
+                             {"flash_fwd", "flash_dq_dkv"}),
+    "segmented_bwd": (2048, None, True, True, {"flash_fwd", "flash_dq_dkv"}),
+    # 8 MiB of float32 dq rows a head: the most the one backward call keeps
+    "s16384_bwd": (16384, None, False, True, {"flash_fwd", "flash_dq_dkv"}),
+    # past it (a ring's shard): the two kernels
+    "s32768_bwd": (32768, None, False, True,
+                   {"flash_fwd", "flash_dq", "flash_dkv"}),
 }
+
+
+def _flash_kernels(text):
+    """The flash kernels' names among a compiled program's Mosaic calls."""
+    import re
+
+    return set(re.findall(r"%(flash_[a-z_]+?)[.\d]* = [^\n]*tpu_custom_call",
+                          text))
 
 
 @pytest.mark.parametrize("case", sorted(FLASH_CASES))
 def test_flash_kernel_compiles_for_v5e(topo, case):
-    S, window, segmented, backward, n_calls = FLASH_CASES[case]
+    S, window, segmented, backward, kernels = FLASH_CASES[case]
     mesh = _mesh(topo)
     sh = NamedSharding(mesh, P())
     q = jax.ShapeDtypeStruct((1, S, NQ, D), jnp.bfloat16, sharding=sh)
@@ -96,9 +110,10 @@ def test_flash_kernel_compiles_for_v5e(topo, case):
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else attn
     text = _compiled_text(fn, q, kv, kv, seg)
-    assert text.count("tpu_custom_call") >= n_calls, (
-        f"{case}: expected {n_calls} Mosaic kernel(s) in the program "
-        f"compiled for the TPU, found {text.count('tpu_custom_call')}")
+    assert _flash_kernels(text) == kernels, (
+        f"{case}: expected the Mosaic kernels {sorted(kernels)} in the "
+        f"program compiled for the TPU, found {text.count('tpu_custom_call')} "
+        f"custom call(s): {sorted(_flash_kernels(text))}")
 
 
 # -- paged attention ---------------------------------------------------------
@@ -382,7 +397,8 @@ def test_held_gated_experts_backward_compiles_for_v5e_at_lfm2_widths(
 
 def test_flash_kernels_compile_for_v5e_at_head_dim_64(topo):
     """LFM2's attention geometry — 32 q / 8 kv heads of 64, full causal, 8192
-    rows: forward, dq and dkv (every other case here is at 128)."""
+    rows: forward and the one backward call (every other case here is at
+    128)."""
     mesh = _mesh(topo)
     sh = NamedSharding(mesh, P())
     q = jax.ShapeDtypeStruct((1, 8192, 32, 64), jnp.bfloat16, sharding=sh)
@@ -393,7 +409,7 @@ def test_flash_kernels_compile_for_v5e_at_head_dim_64(topo):
                        .astype(jnp.float32))
 
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
-    assert text.count("tpu_custom_call") >= 3
+    assert _flash_kernels(text) == {"flash_fwd", "flash_dq_dkv"}
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk_s512"])

@@ -8,10 +8,14 @@ cheap in chip time, and its numbers justify (or refute) the
 512x512 default the models use (`ops/flash_attention.py` block_q/block_k).
 ``--window W`` times the sliding-window band, ``--non-causal`` the whole
 square.  On a TPU each line also gives ``kernel_us``: the device-clock time
-of ONE call of ``flash_fwd``, ``flash_dq`` and ``flash_dkv`` each (their
-events in a profiler trace of the fwd+bwd program), beside
-``live_of_stepped``: the block pairs a (batch, head) whose body runs and the
-steps the kernel's grid makes for them (`ops.flash_attention.band_blocks`).
+of ONE call of ``flash_fwd`` and of the backward's kernels (their events in
+a profiler trace of the fwd+bwd program) — ``flash_dq_dkv``, the one call a
+sequence under the kernel's VMEM budget takes, and beside it the pair it
+replaces, ``flash_dq`` and ``flash_dkv``, from a second program held to the
+split path (``fwd_bwd_split_ms``; past the budget the pair is all there is
+and ``flash_dq_dkv`` reads None) — beside ``live_of_stepped``: the block
+pairs a (batch, head) whose body runs and the steps the kernel's grid makes
+for them (`ops.flash_attention.band_blocks`).
 
 ``--paged`` instead times the paged-attention kernel
 (`ops/paged_attention.py`) at one serving shape with every live slot at
@@ -48,7 +52,7 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-FLASH_KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+FLASH_KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "flash_dq_dkv")
 
 
 def _pct_roofline(flops: float, bytes_accessed: float, seconds: float):
@@ -140,7 +144,8 @@ def _time_chain(paged_call, chain, steps, q, *rest):
 def _kernel_us(call, steps, *xs, kernels=("paged_attention",)):
     """Device-clock microseconds of a program's Pallas kernels ALONE: for
     each name in ``kernels`` the median duration of the events whose HLO
-    name starts with it, in a profiler trace of ``steps`` calls (a name
+    name starts with it (and with no longer one of them: ``flash_dq_dkv`` is
+    not a ``flash_dq``), in a profiler trace of ``steps`` calls (a name
     without events reads None).  One name gives a number, several a dict.
     None where there is no TPU to trace."""
     import glob
@@ -168,7 +173,9 @@ def _kernel_us(call, steps, *xs, kernels=("paged_attention",)):
               for e in line.events]
     found = {}
     for kernel in kernels:
-        durations = [d for name, d in events if name.startswith(kernel)]
+        longer = tuple(k for k in kernels if k != kernel and k.startswith(kernel))
+        durations = [d for name, d in events if name.startswith(kernel)
+                     and not name.startswith(longer)]
         found[kernel] = (round(statistics.median(durations) / 1e3, 1)
                          if durations else None)
     return found if len(kernels) > 1 else found[kernels[0]]
@@ -342,11 +349,12 @@ def main() -> int:
         jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
 
-    from neuronx_distributed_tpu.ops.flash_attention import (
-        _block_sizes,
-        band_blocks,
-        flash_attention,
-    )
+    import importlib
+
+    # the module: ``ops`` exports the function under the same name
+    fa = importlib.import_module("neuronx_distributed_tpu.ops.flash_attention")
+    _block_sizes, band_blocks = fa._block_sizes, fa.band_blocks
+    flash_attention = fa.flash_attention
 
     if args.paged:
         return run_paged_walk(args) if args.walk else run_paged(args)
@@ -375,15 +383,32 @@ def main() -> int:
         attend = lambda a, b_, c, bq=bq, bk=bk: flash_attention(  # noqa: E731
             a, b_, c, causal, None, bq, bk, None, window)
         fwd = jax.jit(attend)
-        grad = jax.jit(jax.grad(
-            lambda a, b_, c: attend(a, b_, c).astype(jnp.float32).sum(),
-            (0, 1, 2)))
 
+        def grad():  # traced anew a call: the backward reads the budget then
+            return jax.jit(jax.grad(
+                lambda a, b_, c: attend(a, b_, c).astype(jnp.float32).sum(),
+                (0, 1, 2)))
+
+        fused = fa._dq_rows_vmem(S, D) <= fa._FUSED_DQ_BYTES
         try:
             t_fwd = _time_fn(fwd, args.steps, q, k, v)
-            t_bwd = _time_fn(grad, args.steps, q, k, v)
-            kernel_us = _kernel_us(grad, args.steps, q, k, v,
+            bwd = grad()
+            t_bwd = _time_fn(bwd, args.steps, q, k, v)
+            kernel_us = _kernel_us(bwd, args.steps, q, k, v,
                                    kernels=FLASH_KERNELS)
+            t_split = None
+            if fused:  # the pair it replaces, timed beside it
+                budget, fa._FUSED_DQ_BYTES = fa._FUSED_DQ_BYTES, 0
+                try:
+                    split = grad()
+                    t_split = _time_fn(split, args.steps, q, k, v)
+                    split_us = _kernel_us(split, args.steps, q, k, v,
+                                          kernels=FLASH_KERNELS)
+                finally:
+                    fa._FUSED_DQ_BYTES = budget
+                if kernel_us is not None:
+                    kernel_us.update(flash_dq=split_us["flash_dq"],
+                                     flash_dkv=split_us["flash_dkv"])
         except Exception as e:  # noqa: BLE001 — report and continue sweeping
             rec = {"block_q": bq, "block_k": bk, "error": str(e)[:200]}
             results.append(rec)
@@ -392,14 +417,15 @@ def main() -> int:
         # block pairs a (batch, head) whose body runs, of the grid's steps
         fitted = _block_sizes(S, S, bq, bk)
         by_q = band_blocks(S, S, *fitted, causal, window)
-        bands = (by_q, by_q, band_blocks(S, S, *fitted, causal, window,
-                                         by_kv=True))
+        by_kv = band_blocks(S, S, *fitted, causal, window, by_kv=True)
+        bands = (by_q, by_q, by_kv, by_kv)  # flash_dq_dkv walks flash_dkv's
         rec = {
             "shape": {"batch": B, "heads": HQ, "kv_heads": HKV, "seq": S,
                       "head_dim": D, "causal": causal, "window": window},
             "block_q": bq, "block_k": bk,
             "fwd_ms": round(t_fwd * 1e3, 3),
             "fwd_bwd_ms": round(t_bwd * 1e3, 3),
+            "fwd_bwd_split_ms": t_split and round(t_split * 1e3, 3),
             "kernel_us": kernel_us,
             "live_of_stepped": {kernel: [band.live, band.stepped]
                                 for kernel, band in zip(FLASH_KERNELS, bands)},
