@@ -35,8 +35,10 @@ from neuronx_distributed_tpu.kvcache.allocator import (
 )
 from neuronx_distributed_tpu.kvcache.pool import (
     GATHER_BYTES_TOTAL,
+    PageKinds,
     PagePool,
     init_page_pool_caches,
+    page_kinds,
 )
 from neuronx_distributed_tpu.kvcache.prefix import (
     PAD,
@@ -61,7 +63,9 @@ __all__ = [
     "PAD",
     "PAGES_EXPORTED_TOTAL",
     "PAGES_IMPORTED_TOTAL",
+    "PageKinds",
     "PagePool",
+    "page_kinds",
     "PoolExhausted",
     "PrefixIndex",
     "TransferError",

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import numbers
 from typing import Any, List, Optional, Tuple
 
 import jax
@@ -55,6 +56,73 @@ GATHER_BYTES_TOTAL = "kvcache/gather_bytes_total"
 # ``[NP, page, latent_dim]``, ``ops.latent_attention``); nothing (a layer
 # without a mixer)
 CACHE_KINDS = ("pages", "selected_pages", "state", "latent", "none")
+
+
+@dataclasses.dataclass(frozen=True)
+class PageKinds:
+    """The KINDS of K/V page a model's layers keep: layers with the same
+    causal window (or none) are one kind — one page-id space, one block
+    table a slot, one page count of the pool — because their pages live and
+    die together: a layer that attends everything keeps a sequence's whole
+    history, a layer with a window only the keys a row can still be asked
+    for.  ``windows[k]`` is kind ``k``'s window (None: everything), kinds in
+    the order their first layer appears; ``of_layer[i]`` layer ``i``'s kind.
+    A model without windows, or with one window for every layer, has ONE
+    kind."""
+
+    windows: Tuple[Optional[int], ...] = (None,)
+    of_layer: Tuple[int, ...] = ()
+
+    def __len__(self) -> int:
+        return len(self.windows)
+
+    def layers(self, kind: int) -> int:
+        """How many layers keep pages of ``kind``."""
+        return sum(k == kind for k in self.of_layer)
+
+    def weights(self) -> Tuple[int, ...]:
+        """Each kind's bytes a page in the smallest whole units: its layer
+        count over the counts' greatest common divisor (1 for one kind) —
+        what a page count of several kinds is summed by."""
+        counts = [max(self.layers(k), 1) for k in range(len(self))]
+        g = math.gcd(*counts)
+        return tuple(c // g for c in counts)
+
+    def window_pages(self, max_total_len: int, chunk_tokens: int,
+                     page_size: int) -> Tuple[Optional[int], ...]:
+        """The most pages of each kind a slot can hold at once where the
+        band gives pages back: the window, the widest prefill chunk and a
+        page of misalignment.  None: the kind keeps a row's whole history
+        (no window, or one no row of ``max_total_len`` outgrows) and never
+        frees before release."""
+        return tuple(
+            None if w is None or w >= max_total_len
+            else math.ceil((w + chunk_tokens) / page_size) + 1
+            for w in self.windows)
+
+
+def page_kinds(cfg) -> PageKinds:
+    """The page kinds of a model config, from its layers' windows
+    (``layer_windows`` and ``page_kind_of_layer``:
+    ``models.llama.LlamaConfig``); a config that does not say has one kind
+    that keeps everything."""
+    by_layer = getattr(cfg, "layer_windows", None)
+    if not by_layer:
+        return PageKinds()
+    return PageKinds(tuple(dict.fromkeys(by_layer)),
+                     tuple(cfg.page_kind_of_layer))
+
+
+def pages_of_kinds(num_pages, kinds: Optional[PageKinds]) -> Tuple[int, ...]:
+    """``num_pages`` as one count a kind: an int is every kind's."""
+    n = len(kinds) if kinds is not None else 1
+    counts = ((int(num_pages),) * n if isinstance(num_pages, numbers.Integral)
+              else tuple(int(p) for p in num_pages))
+    if len(counts) != n:
+        raise ValueError(
+            f"num_pages {num_pages!r} names {len(counts)} page counts for a "
+            f"model of {n} page kind(s)")
+    return counts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -133,17 +201,25 @@ def init_page_pool_caches(
     dtype: Any = jnp.bfloat16,
     quant: Optional[str] = None,
     layers: Optional[LayerStates] = None,
+    kinds: Optional[PageKinds] = None,
 ) -> List[Tuple[jax.Array, ...]]:
     """Zero page-pool caches ``[NP, NKV, page, D]`` per layer, kv-heads
     sharded over tp when divisible (the same policy as the contiguous
     ``init_kv_caches``); the page axis is unsharded — it is a global pool.
+    With ``kinds`` of more than one kind, ``num_pages`` is a count a kind
+    and a layer's arrays have ITS kind's.
 
     ``quant="int8"`` switches each layer's entry from the fp pair
     ``(k, v)`` to the six-tuple ``(k int8, v int8, k_scale, k_zero,
     v_scale, v_zero)`` with one fp32 scale/zero per physical page (see
     :mod:`.quant`) — the structural marker the model's block-table
     scatter/gather keys its dequantize-in-the-gather path on."""
-    shape = (num_pages, num_kv_heads, page_size, head_dim)
+    counts = pages_of_kinds(num_pages, kinds)
+    if len(counts) > 1 and (quant is not None or layers is not None):
+        raise ValueError("pages of several kinds (layers of different "
+                         "windows) are carried through neither an int8 "
+                         "pool nor a layer list")
+    num_pages = counts[0]
     if quant not in (None, "int8"):
         raise ValueError(f"unknown KV quantization {quant!r} "
                          "(supported: 'int8')")
@@ -161,8 +237,9 @@ def init_page_pool_caches(
         page_sh = named_sharding(None, kv_axes, None, None)
         scale_sh = named_sharding(None)  # per-page params: replicated
 
-    def pages(dt):
-        return jnp.zeros(shape, dt, device=page_sh)
+    def pages(dt, n=num_pages):
+        return jnp.zeros((n, num_kv_heads, page_size, head_dim), dt,
+                         device=page_sh)
 
     def params():
         return jnp.zeros((num_pages,), jnp.float32, device=scale_sh)
@@ -194,7 +271,10 @@ def init_page_pool_caches(
 
         return [entry(k) for k in layers.kinds]
     if quant is None:
-        return [(pages(dtype), pages(dtype)) for _ in range(num_layers)]
+        of_layer = (kinds.of_layer if len(counts) > 1
+                    else (0,) * num_layers)
+        return [(pages(dtype, counts[k]), pages(dtype, counts[k]))
+                for k in of_layer]
     return [(pages(jnp.int8), pages(jnp.int8),
              params(), params(), params(), params())
             for _ in range(num_layers)]
@@ -220,11 +300,17 @@ class PagePool:
         dtype: Any = jnp.bfloat16,
         quant: Optional[str] = None,
         layers: Optional[LayerStates] = None,
+        kinds: Optional[PageKinds] = None,
     ):
-        if num_pages < 2:
+        self.kinds = kinds if kinds is not None else PageKinds()
+        # one count a kind; ``num_pages`` stays the first kind's (the only
+        # one's, for a model of one kind)
+        self.pages_by_kind = pages_of_kinds(num_pages, self.kinds)
+        num_pages = self.pages_by_kind[0]
+        if min(self.pages_by_kind) < 2:
             raise ValueError(
                 f"num_pages must be >= 2 (page 0 is the NULL page), "
-                f"got {num_pages}")
+                f"got {self.pages_by_kind}")
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         self.num_layers = num_layers
@@ -236,16 +322,27 @@ class PagePool:
         self.quant = quant
         self.layers = layers
         self.caches = init_page_pool_caches(
-            num_layers, num_pages, page_size, num_kv_heads, head_dim, dtype,
-            quant=quant, layers=layers)
+            num_layers, self.pages_by_kind, page_size, num_kv_heads,
+            head_dim, dtype, quant=quant, layers=layers, kinds=self.kinds)
 
     @property
     def page_bytes(self) -> int:
         """HBM bytes one page costs across all layers (k + v, plus the
         per-page scale/zero params under int8 quantization — honest
-        accounting: the quantized pool pays for its metadata)."""
-        return _page_bytes(self.num_layers, self.page_size, self.num_kv_heads,
-                           self.head_dim, self.dtype, self.quant, self.layers)
+        accounting: the quantized pool pays for its metadata).  With several
+        page kinds: the first kind's (:attr:`page_bytes_by_kind` has each)."""
+        return self.page_bytes_by_kind[0]
+
+    @property
+    def page_bytes_by_kind(self) -> Tuple[int, ...]:
+        """Bytes a page of each kind costs across that kind's layers."""
+        if len(self.kinds) == 1:
+            return (_page_bytes(self.num_layers, self.page_size,
+                                self.num_kv_heads, self.head_dim, self.dtype,
+                                self.quant, self.layers),)
+        return tuple(_page_bytes(self.kinds.layers(k), self.page_size,
+                                 self.num_kv_heads, self.head_dim, self.dtype,
+                                 None, None) for k in range(len(self.kinds)))
 
     @property
     def state_bytes(self) -> int:
@@ -255,7 +352,8 @@ class PagePool:
 
     @property
     def total_bytes(self) -> int:
-        return self.num_pages * self.page_bytes + self.state_bytes
+        return sum(n * b for n, b in zip(
+            self.pages_by_kind, self.page_bytes_by_kind)) + self.state_bytes
 
     @staticmethod
     def pages_for_budget(budget_bytes: int, num_layers: int, page_size: int,

@@ -100,7 +100,11 @@ class LlamaConfig:
     # Composes with cp: ulysses at any degree, and the contiguous ring when
     # sliding_window <= S/cp — there ONE ppermute (the left neighbor)
     # replaces the whole rotation, the long-context Mistral schedule.
-    sliding_window: Optional[int] = None
+    # An int (or None) is every layer's; a sequence names each layer's own
+    # (None: that layer attends everything) — a model of window and global
+    # layers, whose serving pages then come in one kind a window
+    # (``layer_windows``, ``kvcache.pool.page_kinds``).
+    sliding_window: Any = None
     remat: str = "selective"  # none | selective | full
     # "dense": GSPMD einsum core (CPU-friendly; always used for cached decode).
     # "flash": pallas flash kernel under shard_map; rings KV over the cp axis
@@ -187,8 +191,9 @@ class LlamaConfig:
     # "none" a layer.  None: every layer has the one num_experts implies.
     # A layer with one of the two "none" is ONE sublayer, x + f(norm(x))
     ffn_types: Optional[Tuple[str, ...]] = None
-    # RoPE on the "attention" mixer's q and k (Nemotron-H's has none)
-    attn_rope: bool = True
+    # RoPE on the "attention" mixer's q and k (Nemotron-H's has none); a
+    # sequence names each layer's own (SmallThinker: none on global layers)
+    attn_rope: Any = True
     # mamba2: heads and their size P, groups sharing B and C, state size N,
     # convolution taps, rows of a block of the chunked scan; the last three
     # are how a SEEDED dt_bias is drawn (Mamba-2's own initialisation)
@@ -260,6 +265,11 @@ class LlamaConfig:
     qk_norm_per_head: bool = False
     tie_word_embeddings: bool = False
     moe_aux_loss: bool = True
+    # what the routed block's ROUTER reads: "ffn" — the rows its experts
+    # compute on (the post-attention norm's output) — or "attn": the
+    # attention's normed input of the same layer (SmallThinker routes before
+    # attention, so that experts can be fetched while attention runs)
+    moe_router_input: str = "ffn"
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
@@ -292,6 +302,21 @@ class LlamaConfig:
                     self.mixer_types or (), self.ffn_types)):
                 raise ValueError("a layer with no mixer and no "
                                  "feed-forward part is no layer")
+        for name in ("sliding_window", "attn_rope"):
+            v = getattr(self, name)
+            if isinstance(v, (list, tuple)):
+                # a JSON list: hashable, one entry a layer
+                object.__setattr__(self, name, tuple(v))
+                if len(v) != self.num_layers:
+                    raise ValueError(
+                        f"{name} names each of the {self.num_layers} "
+                        f"layers' own, got {len(v)} entries")
+        if self.per_layer_attention and self.scan_layers:
+            raise ValueError("scan_layers traces ONE block: a window or a "
+                             "RoPE switch a layer makes several")
+        if self.moe_router_input not in ("ffn", "attn"):
+            raise ValueError(
+                f"moe_router_input {self.moe_router_input!r} (ffn | attn)")
         object.__setattr__(self, "hc_res_clamp", tuple(self.hc_res_clamp))
         if self.hc_mult > 1 and self.scan_layers:
             raise ValueError("hc_mult > 1 is not carried through scan_layers")
@@ -324,6 +349,39 @@ class LlamaConfig:
         if self.ffn_types is not None:
             return self.ffn_types[layer]
         return "moe" if self.num_experts > 1 else "mlp"
+
+    @property
+    def per_layer_attention(self) -> bool:
+        """Whether a layer's window or RoPE switch is its own."""
+        return isinstance(self.sliding_window, tuple) \
+            or isinstance(self.attn_rope, tuple)
+
+    @property
+    def layer_windows(self) -> Tuple[Optional[int], ...]:
+        """Each layer's causal window (None: the layer attends everything):
+        what sorts the serving pool's pages into kinds."""
+        w = self.sliding_window
+        return w if isinstance(w, tuple) else (w,) * self.num_layers
+
+    @property
+    def page_kind_of_layer(self) -> Tuple[int, ...]:
+        """Each layer's page KIND in a serving pool: layers of one window
+        (or of none) keep pages of one kind, kinds numbered in the order
+        their first layer appears.  A ``[K, B, PP]`` block table holds one
+        table a kind, and ``kvcache.pool.page_kinds`` reads its kinds off
+        this and ``layer_windows``."""
+        windows = list(dict.fromkeys(self.layer_windows))
+        return tuple(windows.index(w) for w in self.layer_windows)
+
+    def layer_config(self, layer: int) -> "LlamaConfig":
+        """The config layer ``layer``'s block is built from: this one, with
+        the layer's own window and RoPE switch where they differ by layer."""
+        if not self.per_layer_attention:
+            return self
+        rope = self.attn_rope
+        return dataclasses.replace(
+            self, sliding_window=self.layer_windows[layer],
+            attn_rope=rope[layer] if isinstance(rope, tuple) else rope)
 
     @property
     def moe_layers(self) -> Tuple[int, ...]:
@@ -1134,12 +1192,14 @@ class LlamaBlock(nn.Module):
         # reads them through its own maps and writes them back (the default,
         # one stream, leaves the block as it was built before there were any)
         hc = cfg.hc_mult > 1
+        attn_in = None
         if self.mixer != "none":
             u = x
             if hc:
                 u, post, res = HyperConnection(cfg, name="attn_hc")(x)
-            normed = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
-                             param_dtype=cfg.param_dtype, name="input_norm")(u)
+            normed = attn_in = RMSNorm(
+                eps=cfg.rms_eps, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, name="input_norm")(u)
             if self.mixer == "attention":
                 h, new_cache = LlamaAttention(cfg, name="attn")(
                     normed, positions, kv_cache, cache_offset, kv_valid,
@@ -1179,8 +1239,9 @@ class LlamaBlock(nn.Module):
                 ("router_scores", cfg.moe_router_scores, "softmax"),
                 ("router_bias", cfg.moe_router_bias, False),
                 ("route_scale", cfg.moe_route_scale, 1.0),
-                ("activation", "relu2" if cfg.mlp_activation == "relu2"
-                 else "silu", "silu"),
+                ("activation", cfg.mlp_activation
+                 if cfg.mlp_activation in ("relu", "relu2") else "silu",
+                 "silu"),
                 ("shared_intermediate_size",
                  cfg.moe_shared_intermediate_size, 0),
                 ("n_group", cfg.moe_n_group, 1),
@@ -1211,11 +1272,19 @@ class LlamaBlock(nn.Module):
                 name="moe_mlp",
                 **family,
             )
+            routed_on = {}
+            if cfg.moe_router_input == "attn":
+                if attn_in is None or not dropless:
+                    raise ValueError(
+                        "moe_router_input='attn': the router reads the "
+                        "layer's attention input, on the dropless path")
+                routed_on = {"router_input": attn_in}
             # the expert block is the layer's MLP in a device trace too
             with jax.named_scope("mlp"):
                 h, aux = (moe(normed, row_validity(
                     kv_valid, cache_offset, normed.shape[1],
-                    kv_cache is not None)) if dropless else moe(normed))
+                    kv_cache is not None), **routed_on)
+                    if dropless else moe(normed))
             # collected by losses-mutable apply (causal_lm_loss adds the
             # load-balancing term); silently dropped when not collected
             if cfg.moe_aux_loss:
@@ -1327,6 +1396,7 @@ class LlamaModel(nn.Module):
                 h, positions, None, 0, kv_valid, segment_ids
             )
         else:
+            page_kind_of = cfg.page_kind_of_layer
             new_caches = []
             for i in range(cfg.num_layers):
                 cache = kv_caches[i] if kv_caches is not None else None
@@ -1336,16 +1406,25 @@ class LlamaModel(nn.Module):
                            else {"mixer": cfg.mixer(i)}),
                         **({} if cfg.ffn_types is None
                            else {"ffn": cfg.ffn(i)})}
+                # ... and a layer whose window or RoPE switch is its own is
+                # built from the config that says so (the same otherwise)
+                lcfg = cfg.layer_config(i)
                 if kv_caches is not None:
-                    h, c = LlamaBlock(cfg, name=f"layer_{i}", **kind)(
+                    # a [K, B, PP] block table holds one table a page KIND
+                    # (several windows); a model of one kind is handed the
+                    # [B, PP] table it always was (PagedKVManager.tables)
+                    table = block_table
+                    if block_table is not None and jnp.ndim(block_table) == 3:
+                        table = block_table[page_kind_of[i]]
+                    h, c = LlamaBlock(lcfg, name=f"layer_{i}", **kind)(
                         h, positions, cache, cache_offset, kv_valid, segment_ids,
-                        block_table,
+                        table,
                         adapters[i] if adapters is not None else None,
                         paged_kernel,
                         **({} if state_rows is None
                            else {"state_rows": state_rows}))
                 else:
-                    h, c = block_cls(cfg, name=f"layer_{i}", **kind)(
+                    h, c = block_cls(lcfg, name=f"layer_{i}", **kind)(
                         h, positions, None, 0, kv_valid, segment_ids)
                 new_caches.append(c)
         if cfg.hc_mult > 1:
@@ -1485,6 +1564,9 @@ def build_pipelined_llama(
         dtype=cfg.dtype,
         param_dtype=cfg.param_dtype,
     )
+    if cfg.per_layer_attention:
+        raise ValueError("the pipelined stack is ONE block: a window or a "
+                         "RoPE switch a layer is not carried through it")
     block_mod = LlamaBlock(cfg)  # init: declares GLOBAL expert shapes
     head_mod = LlamaHead(cfg)
     moe = cfg.num_experts > 1

@@ -526,6 +526,10 @@ def held_rows_slab(rows: int, held: int, routed: int) -> int:
     return slab if slab < rows else 0
 
 
+# a gated expert is ``down(f(gate x) * up x)``: SwiGLU's and ReGLU's ``f``
+GATE_FN = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def _expert_activation(activation: str):
     """``pre -> h``: the experts' activation over their pre-activations, the
     pair ``(gate, up)`` or, of relu2 experts, ``(up,)``."""
@@ -535,7 +539,7 @@ def _expert_activation(activation: str):
         # on where a fusion's edge fell
         wide = [p.astype(jnp.float32) for p in pre]
         h = (jnp.square(jax.nn.relu(wide[0])) if activation == "relu2"
-             else jax.nn.silu(wide[0]) * wide[1])
+             else GATE_FN[activation](wide[0]) * wide[1])
         return shard_activation(h.astype(pre[0].dtype),
                                 _auto_spec(None, TENSOR_AXES))
     return act
@@ -792,7 +796,8 @@ class ExpertParallelMLP(nn.Module):
     # the dropless path's sigmoid-routed family (module docstring):
     # "softmax" | "sigmoid" scores; a correction bias ``router_bias [Eg]``
     # added for the choice only; the gates' scale; "silu" (SwiGLU experts,
-    # gate/up/down) | "relu2" (up/down); a shared expert's width (0: none)
+    # gate/up/down) | "relu" (ReGLU: the same three matmuls, relu for silu)
+    # | "relu2" (up/down); a shared expert's width (0: none)
     router_scores: str = "softmax"
     router_bias: bool = False
     route_scale: float = 1.0
@@ -810,10 +815,14 @@ class ExpertParallelMLP(nn.Module):
     kernel_init: Initializer = nn.initializers.lecun_normal()
 
     @nn.compact
-    def __call__(self, x: jax.Array, valid=None) -> Tuple[jax.Array, jax.Array]:
+    def __call__(self, x: jax.Array, valid=None,
+                 router_input=None) -> Tuple[jax.Array, jax.Array]:
         """``valid [...]`` (the lead dims of ``x``; dropless path only)
         marks the rows that are tokens: the others are routed nowhere and
-        come out zero."""
+        come out zero.  ``router_input`` (shaped as ``x``; dropless path
+        only): the rows the ROUTER scores, where they are not the rows the
+        experts compute on — a router placed before the layer's attention
+        reads that attention's input."""
         from jax import lax
 
         dropless = self.dispatch == "dropless"
@@ -851,9 +860,9 @@ class ExpertParallelMLP(nn.Module):
                   or self.shared_intermediate_size or self.n_group > 1)
         if family and not dropless:
             raise ValueError(
-                "sigmoid scores, a router bias, a route scale, relu2 "
-                "experts, a shared expert and a group-limited choice are "
-                "the dropless path's")
+                "sigmoid scores, a router bias, a route scale, relu or "
+                "relu2 experts, a shared expert and a group-limited choice "
+                "are the dropless path's")
         if self.n_group > 1 and not (
                 Eg % self.n_group == 0
                 and 1 <= self.topk_group <= self.n_group
@@ -863,12 +872,14 @@ class ExpertParallelMLP(nn.Module):
                 f"groups of which topk_group={self.topk_group} hold at "
                 f"least top_k={self.top_k}")
         if self.router_scores not in ("softmax", "sigmoid") \
-                or self.activation not in ("silu", "relu2"):
+                or self.activation not in ("silu", "relu", "relu2"):
             raise ValueError(
                 f"unknown router_scores {self.router_scores!r} (softmax | "
-                f"sigmoid) or activation {self.activation!r} (silu | relu2)")
-        if valid is not None and not dropless:
-            raise ValueError("row validity is the dropless path's argument")
+                f"sigmoid) or activation {self.activation!r} (silu | relu | "
+                "relu2)")
+        if (valid is not None or router_input is not None) and not dropless:
+            raise ValueError("row validity and a router input of its own are "
+                             "the dropless path's arguments")
         if self.router_type not in ("topk", "expert_choice"):
             raise ValueError(
                 f"unknown router_type {self.router_type!r} "
@@ -889,7 +900,7 @@ class ExpertParallelMLP(nn.Module):
             "router", nn.with_partitioning(self.kernel_init, (None, None)),
             (H, Eg), self.param_dtype,
         )
-        gated = self.activation == "silu"
+        gated = self.activation in GATE_FN
         if not gated:
             # ``up [E, I, H]``, each expert's matrix out-major (as a Linear
             # stores it): the kernel takes it transposed.  Stored ``[E, H,
@@ -941,7 +952,9 @@ class ExpertParallelMLP(nn.Module):
                     (Eg,), jnp.float32)))
             y, aux = self._dropless(
                 xt, None if valid is None else valid.reshape(-1),
-                jnp.asarray(router), wi, jnp.asarray(wo), bias)
+                jnp.asarray(router), wi, jnp.asarray(wo), bias,
+                **({} if router_input is None
+                   else {"scored": router_input.reshape(-1, H)}))
             if self.shared_intermediate_size:
                 # the shared expert: every row, the experts' activation at
                 # its own width
@@ -958,7 +971,7 @@ class ExpertParallelMLP(nn.Module):
                     xs = xt.astype(self.dtype)
                     up = ColumnParallelLinear(features=F, name="shared_up",
                                               **lin)(xs)
-                    h = (jax.nn.silu(ColumnParallelLinear(
+                    h = (GATE_FN[self.activation](ColumnParallelLinear(
                         features=F, name="shared_gate", **lin)(xs)) * up
                          if gated else jnp.square(jax.nn.relu(up)))
                     y = y + RowParallelLinear(features=H, name="shared_down",
@@ -1087,8 +1100,9 @@ class ExpertParallelMLP(nn.Module):
         y = shard_activation(y, _auto_spec(BATCH_AXES, None))
         return y.reshape(*lead, H).astype(self.dtype), aux.astype(jnp.float32)
 
-    def _dropless(self, xt, valid, router, wi, wo, bias=None):
-        """``xt [N, H]`` -> ``(y [N, H], aux)`` with no capacity; ``wi`` is
+    def _dropless(self, xt, valid, router, wi, wo, bias=None, scored=None):
+        """``xt [N, H]`` -> ``(y [N, H], aux)`` with no capacity, routed on
+        ``scored [N, H]`` where given (else on ``xt`` itself); ``wi`` is
         the pair ``(gate, up)``, each ``[E, H, I]`` (``(up,)`` for relu2
         experts, ``[E, I, H]``).  Sown into ``moe_stats`` (kept only by an apply that
         makes it mutable): ``load [E]``, the valid assignments each expert
@@ -1103,8 +1117,10 @@ class ExpertParallelMLP(nn.Module):
         E, I, K = self.num_experts, self.intermediate_size, self.top_k
         Eg = self.num_experts_global or E
         with jax.named_scope("moe_router"):
-            logits = jnp.einsum("nh,he->ne", xt.astype(jnp.float32),
-                                router.astype(jnp.float32))
+            logits = jnp.einsum(
+                "nh,he->ne",
+                (xt if scored is None else scored).astype(jnp.float32),
+                router.astype(jnp.float32))
             if self.router_scores == "sigmoid":
                 probs = jax.nn.sigmoid(logits)
             else:
@@ -1188,7 +1204,7 @@ class ExpertParallelMLP(nn.Module):
                 else:
                     gate, up = (grouped_matmul(xs, w.astype(self.dtype),
                                                load, self.dtype) for w in wi)
-                    h = jax.nn.silu(gate) * up
+                    h = GATE_FN[self.activation](gate) * up
                 h = shard_activation(h, _auto_spec(None, TENSOR_AXES))
                 ys = grouped_matmul(h, wo.astype(self.dtype), load,
                                     self.dtype)

@@ -93,7 +93,7 @@ from neuronx_distributed_tpu.serving.request import (
     RequestState,
 )
 from neuronx_distributed_tpu.kvcache.allocator import NULL_PAGE, PoolExhausted
-from neuronx_distributed_tpu.kvcache.pool import GATHER_BYTES_TOTAL
+from neuronx_distributed_tpu.kvcache.pool import GATHER_BYTES_TOTAL, page_kinds
 from neuronx_distributed_tpu.kvcache.quant import QUANT_PAGES_TOTAL
 from neuronx_distributed_tpu.kvcache.transfer import (
     ChainExport,
@@ -462,10 +462,26 @@ class ServingEngine:
     unset, ``num_pages`` is the pool in which every slot can hold
     ``max_total_len`` (``B * T / page_size`` pages and the NULL page).
     Admission gates on *pages free*, every terminal state reclaims its
-    pages, and ``prefix_cache`` (default True) shares page-aligned prompt
+    pages, and ``prefix_cache`` (on unless pages come back, below) shares
+    page-aligned prompt
     prefixes across requests (an exact repeated prompt skips prefill
     compute entirely).  ``kvcache/*`` metrics (pool occupancy, prefix
-    hit/miss, evictions) export through the registry.
+    hit/miss, evictions) export through the registry.  Pages come in KINDS,
+    one a causal window of the model's layers (``kvcache.pool.page_kinds``;
+    most models have one): each kind has its allocator, its block table a
+    slot and its page count — ``num_pages`` may name one a kind — and a kind
+    whose window a row can outgrow takes pages as the writes reach them and
+    gives them back, from the step's ``tail``, once the oldest row that can
+    still be queried has moved past them.  A chain with holes is no prefix
+    and nothing to rewind, requantize or hand to another replica, so pages
+    come back only where nothing asked for whole chains: a model of ONE kind
+    (Mistral) built with ``prefix_cache=True``, ``spec_k``, ``kv_quant`` or
+    ``adapter_store`` keeps every page and its window only masks, as before
+    there were kinds; left to the default (``prefix_cache=None``) its pages
+    come back and it runs without the prefix index — a preempted request
+    then prefills again from its prompt, and KV migration raises.  A model
+    of SEVERAL kinds always gives its window pages back, and refuses all
+    five.
 
     Speculative decoding (spec PR): ``draft=`` (a second
     ``ParallelInferenceModel`` sharing the target's tokenizer and serving
@@ -574,7 +590,7 @@ class ServingEngine:
         transfer_guard: str = "off",
         page_size: int,
         num_pages: Optional[int] = None,
-        prefix_cache: bool = True,
+        prefix_cache: Optional[bool] = None,
         draft: Any = None,
         spec_k: int = 0,
         adapter_store: Any = None,
@@ -669,6 +685,47 @@ class ServingEngine:
                     "latent layers yet: " + "; ".join(refused))
             if self._recurrent or self._sparse_spec is not None:
                 prefix_cache = False
+        # pages by layer KIND (kvcache.pool.page_kinds): layers of one
+        # window, or of none, share a page-id space and a block table a
+        # slot.  A kind whose window a row can outgrow gives its pages back
+        # as the band moves on (serving/paged.py) — unless something needs
+        # WHOLE chains: a verify round rewinds rows whose band would have
+        # been returned; an int8 page requantizes the whole page its
+        # neighbours were freed around; LoRA pages, the KV hand-off between
+        # replicas and the resume pin of a preempted request move chains
+        # the prefix index vouches for.  A model of ONE kind asked for any
+        # of them keeps every page (the window only masks: what it did
+        # before there were kinds); left to the default its pages come back
+        # and the index is off.  A model of SEVERAL kinds has no pool to
+        # fall back on — with a mask alone its pages are what the chip
+        # cannot hold, and the index is one kind's — so there they raise
+        self._page_kinds = page_kinds(mcfg)
+        several = len(self._page_kinds) > 1
+        whole_chains = [what for what, on in (
+            ("speculative decoding (spec_k): a rejected tail rewinds rows "
+             "whose band was given back", spec_k),
+            ("an int8 page pool (kv_quant): one array layout a layer, one "
+             "page-id space", kv_quant is not None),
+            ("LoRA adapter pages (adapter_store)", adapter_store is not None),
+            ("the prefix index (prefix_cache=True): a chain with holes is "
+             "no prefix, and the index holds pages of one kind",
+             prefix_cache)) if on]
+        if several and whole_chains:
+            raise ValueError(
+                "not carried through pages of several kinds (layers of "
+                "different windows), whose window layers give pages back, "
+                "yet: " + "; ".join(whole_chains))
+        free_behind = several or not whole_chains
+        if prefix_cache is None:
+            outgrown = any(w is not None and w < self.T
+                           for w in self._page_kinds.windows)
+            prefix_cache = not several and not (free_behind and outgrown)
+            if outgrown and not prefix_cache:
+                logger.info(
+                    "serving: window pages come back as the band moves on, "
+                    "so the prefix index is off%s", "" if several else
+                    " (prefix_cache=True keeps whole chains and the index; "
+                    "the window then only masks)")
         if spec_k:
             # the draft keeps a contiguous [B, T] row a slot (see
             # _prefill_draft_row): the one user of these phase functions
@@ -739,17 +796,6 @@ class ServingEngine:
         # reads) and books any growth as a compile event.
         self._jit_sizes = (_module_jit_sizes()
                           if compile_ledger is not None else None)
-        # the KV cache (kvcache/ subsystem): a global page pool sized by
-        # `num_pages` — left unset, the pool in which every slot can hold
-        # max_total_len, and the NULL page — slots carry int32 block tables,
-        # admission gates on pages free, repeated prompts share prefix pages
-        num_pages = (self.B * self.T // page_size + 1 if num_pages is None
-                     else num_pages)
-        self._kv = PagedKVManager(
-            num_slots=self.B, context_len=self.C, max_total_len=self.T,
-            page_size=page_size, num_pages=num_pages,
-            registry=self.registry, prefix_cache=prefix_cache,
-            spec_overshoot=self._spec_k, state_rows=self._recurrent)
         # chunked prefill (Sarathi-style stall-free batching): a prompt's
         # fresh pages trickle into the pool a chunk a step — a PREFILLING
         # slot co-exists with decoding slots, and the chunk width bounds how
@@ -764,6 +810,22 @@ class ServingEngine:
                 f"positive multiple of page_size ({page_size}) — chunks "
                 "are page-aligned so cached prefix pages can be skipped "
                 "whole")
+        # the KV cache (kvcache/ subsystem): a global page pool sized by
+        # `num_pages` — left unset, the pool in which every slot can hold
+        # max_total_len, and the NULL page — slots carry int32 block tables,
+        # admission gates on pages free, repeated prompts share prefix pages
+        # ... of each kind: a kind that gives pages back holds at most its
+        # window, a chunk and a page of misalignment a slot
+        # (``PagedKVManager.window_pages``)
+        self._kv = PagedKVManager(
+            num_slots=self.B, context_len=self.C, max_total_len=self.T,
+            page_size=page_size, num_pages=num_pages,
+            registry=self.registry, prefix_cache=prefix_cache,
+            spec_overshoot=self._spec_k, state_rows=self._recurrent,
+            kinds=self._page_kinds, chunk_tokens=self._chunk_tokens,
+            free_behind=free_behind)
+        num_pages = self._kv.num_pages
+        self._pages_freed = self._kv.frees
         self._chunking: dict = {}   # slot -> _ChunkPrefill in progress
         self._chunk_rr = 0          # budget-rotation cursor (fairness)
         # block-table-native paged decode (ops.paged_attention): "auto"
@@ -781,9 +843,11 @@ class ServingEngine:
 
             self._paged_kernel = resolve_paged_kernel(paged_kernel)
         # the model's sliding window, for the count of pages a decode walks
-        self._attn_window = getattr(
-            getattr(getattr(model, "module", None), "config", None),
-            "sliding_window", None)
+        # (of a model of window and global layers: its window layers')
+        self._attn_window = getattr(mcfg, "sliding_window", None)
+        if isinstance(self._attn_window, tuple):
+            self._attn_window = min(
+                (w for w in self._attn_window if w is not None), default=None)
         # bytes ONE gather-path step spends on the contiguous clone: k + v,
         # every layer, the full padded [B, T] view in the compute dtype
         # (an int8 pool dequantizes into the same-sized fp clone)
@@ -867,14 +931,17 @@ class ServingEngine:
         # the pool's page_bytes-derived logical size: what the memory
         # ledger accounts and what the fleet's headroom view is sized
         # from (pages_free * page_bytes)
-        self._page_bytes = pool.page_bytes
+        # (of the kinds the page gate counts: ``PagedKVManager.gating``)
+        self._page_bytes = sum(
+            pool.page_bytes_by_kind[k] for k in self._kv.gating)
         logger.info(
-            "serving: paged KV pool: %d pages x %d tokens%s "
+            "serving: paged KV pool: %s pages x %d tokens%s "
             "(%.1f MiB; [B=%d, T=%d] rows would be %.1f MiB)",
-            num_pages, page_size,
+            pool.pages_by_kind, page_size,
             f" ({self._kv_quant} quantized)" if self._kv_quant else "",
-            num_pages * pool.page_bytes / 2**20, self.B,
-            self.T, pool.page_bytes * self.B * self.T / page_size / 2**20)
+            (pool.total_bytes - pool.state_bytes) / 2**20, self.B,
+            self.T, sum(pool.page_bytes_by_kind) * self.B * self.T
+            / page_size / 2**20)
         self.valid = jnp.zeros((self.B, self.T), jnp.int32)
         # the draft's KV state stays CONTIGUOUS [B, T]: its rollback is free
         # (rejected slots sit past the rewound offset, index-based causal
@@ -947,7 +1014,7 @@ class ServingEngine:
         ml = self.memory_ledger
         if ml is not None:
             ml.account_tree("params", model.params)
-            ml.set("kv_pool", num_pages * self._page_bytes)
+            ml.set("kv_pool", pool.total_bytes - pool.state_bytes)
             if self._spec_k:
                 from neuronx_distributed_tpu.obs.memory_ledger import (
                     tree_bytes,
@@ -1135,6 +1202,12 @@ class ServingEngine:
             raise TransferError(
                 "KV migration moves K/V page chains: chains of latent pages "
                 "are not carried through export and import yet")
+        if self._pages_freed or len(self._page_kinds) > 1:
+            raise TransferError(
+                "KV migration moves whole page chains of ONE kind: a model "
+                "whose window layers give pages back, or whose pages come "
+                "in several kinds, has none to move (a model of one kind "
+                "keeps whole chains under prefix_cache=True)")
 
     @property
     def has_work(self) -> bool:
@@ -1328,6 +1401,21 @@ class ServingEngine:
         rules, and the compile ledger's poll of the shared sampler jits."""
         self.registry.gauge("serving/queue_depth").set(self.scheduler.queue_depth)
         self.registry.gauge("serving/slots_active").set(self.scheduler.active_count)
+        if self._pages_freed:
+            # window layers give back the pages every row that can still be
+            # queried has moved past: a prefilling slot's next chunk starts
+            # where its last one ended, a decoding slot's oldest row not yet
+            # collected sits at its committed offset
+            page = self._kv.page_size
+            for slot, req in self.scheduler.active():
+                st = self._chunking.get(slot)
+                if st is not None:
+                    oldest = st.fresh[st.next_i][0] * page
+                elif req.state is RequestState.DECODE:
+                    oldest = int(self._offsets[slot])
+                else:
+                    continue
+                self._kv.release_behind(slot, min(oldest, self.T))
         self._kv.export_gauges()
         if self._adapters is not None:
             self._adapters.export_gauges()
@@ -1405,10 +1493,14 @@ class ServingEngine:
         self._count_decode_write(active, offs)
         lens = np.asarray([int(offs[slot]) - self.C + req.prompt_len + 1
                            for slot, req in active])
+        if self._pages_freed:
+            for slot, _ in active:
+                self._kv.extend_window(slot, int(offs[slot]))
         self._account.rows = len(active)
         self._count_latents("decode_pages", int(lens.sum()), len(active))
         with self._phase("dispatch", active=len(active),
                          ctx_tokens=int(lens.sum()) - len(active),
+                         **self._window_tokens(lens, 1),
                          **self._count_selection("decode_pages", lens - 1,
                                                  lens)):
             if self._spec_k:
@@ -1828,6 +1920,7 @@ class ServingEngine:
                 with self._phase(
                         "prefill_chunk", request_id=req.request_id,
                         tok_start=off, width=n_pages * page, ctx_tokens=ctx,
+                        **self._window_tokens([ctx], n_pages * page),
                         **self._count_selection(
                             "prefill_chunk_pages",
                             np.arange(max(off - (self.C - req.prompt_len),
@@ -1872,6 +1965,8 @@ class ServingEngine:
         last = n_pages == st.pages_remaining
         ids_chunk = np.zeros((1, self._chunk_tokens), np.int32)
         ids_chunk[0, :width] = st.ids_row[off:off + width]
+        if self._pages_freed:
+            self._kv.extend_window(slot, off + width - 1)
         # the chunk commits its valid cells (a first page may lead with pads)
         cells = st.valid_row[off:off + width].reshape(n_pages, page) > 0
         self._count_kv_write(int(cells.sum()), int(cells.any(axis=1).sum()))
@@ -1894,7 +1989,8 @@ class ServingEngine:
                   if self._adapters is not None else (None, None))
             logits, self.caches = self.model.prefill_chunk_pages(
                 jnp.asarray(ids_chunk), off,
-                self._kv.tables[slot][None, :].copy(), self.caches,
+                self._kv.tables[..., slot, :][..., None, :].copy(),
+                self.caches,
                 st.valid_row[None, :].copy(), apool=ad[0], atables=ad[1],
                 paged_kernel=self._paged_kernel, last_row=width - 1,
                 want_logits=last,
@@ -1973,6 +2069,18 @@ class ServingEngine:
         self.registry.counter("serving/expired_before_prefill_total").inc()
         self.registry.counter("serving/timed_out_total").inc()
         outputs.append(self._emit(req, now))
+
+    def _window_tokens(self, lens, rows: int) -> dict:
+        """The span's ``window_tokens``: the keys the coming program's
+        WINDOW layers attend, where ``ctx_tokens`` is what its global layers
+        do — each row's keys capped at the window (a chunk of ``rows`` rows:
+        its last row's, and the ``rows - 1`` its first row sees before
+        them).  Empty for a model without a window."""
+        if self._attn_window is None:
+            return {}
+        cap = self._attn_window + rows - 1
+        return {"window_tokens": int(sum(
+            min(int(n), cap) for n in lens)) - (len(lens) if rows == 1 else 0)}
 
     def _count_paged_walk(self, active: list, offs) -> None:
         """What the traffic lets the paged kernel skip, from the write

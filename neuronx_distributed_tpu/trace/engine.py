@@ -1014,22 +1014,30 @@ class ParallelInferenceModel(_ServingBase):
 
     # -- paged-KV phase fns (kvcache/ subsystem; serving paged mode) --------
 
-    def make_page_pool(self, num_pages: int, page_size: int,
+    def make_page_pool(self, num_pages, page_size: int,
                        quant: Optional[str] = None):
         """A :class:`~..kvcache.pool.PagePool` shaped/sharded for this
         model's layers and cache dtype — the device half of the paged
         serving engine's KV state.  ``quant="int8"`` builds the quantized
         layout (int8 pages + per-page fp32 scale/zero; see
-        :mod:`~..kvcache.quant`) — roughly 2x the pages per HBM byte."""
-        from neuronx_distributed_tpu.kvcache.pool import LayerStates, PagePool
+        :mod:`~..kvcache.quant`) — roughly 2x the pages per HBM byte.
+        ``num_pages`` is one count, or — for a model whose layers keep pages
+        of several kinds (``kvcache.pool.page_kinds``: window layers beside
+        global ones) — one a kind: each layer's arrays have its kind's."""
+        from neuronx_distributed_tpu.kvcache.pool import (
+            LayerStates,
+            PagePool,
+            page_kinds,
+        )
 
+        mcfg = getattr(self.module, "config", None)
         # a state row a slot: a recurrent layer's batch row b continues row b
         layers = LayerStates.for_config(
-            getattr(self.module, "config", None), page_size,
-            state_rows=self.config.batch_size)
+            mcfg, page_size, state_rows=self.config.batch_size)
         return PagePool(self.num_layers, num_pages, page_size,
                         self.num_kv_heads, self.head_dim,
                         self.config.kv_cache_dtype, quant=quant,
+                        kinds=page_kinds(mcfg),
                         **({} if layers is None else {"layers": layers}))
 
     @staticmethod
@@ -1400,11 +1408,15 @@ class ParallelInferenceModel(_ServingBase):
         """Compiled pool-internal page copy (pool donated) — the device half
         of the allocator's copy-on-write: duplicate a shared page before
         writing the copy."""
-        if self.recurrent or self._sparse:
+        from neuronx_distributed_tpu.kvcache.pool import page_kinds
+
+        if self.recurrent or self._sparse or len(page_kinds(
+                getattr(self.module, "config", None))) > 1:
             raise ValueError(
                 "copy_page duplicates a page of every layer: a layer list "
-                "with state rows or compressed keys has no shared pages to "
-                "copy (prefix sharing is off for it)")
+                "with state rows or compressed keys, or layers whose pages "
+                "come in several kinds, has no shared pages to copy (prefix "
+                "sharing is off for it)")
         self._serving_lru()
         key = ("copy_page", self._pool_tag(caches))
         fn = self._serving_cache.get(key)

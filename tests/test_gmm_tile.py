@@ -47,8 +47,9 @@ def _routed():
     return cases
 
 
-def test_the_benchmark_has_four_routed_configurations():
-    assert len(_routed()) == 4 * 2 * 2
+def test_the_benchmark_has_five_routed_configurations():
+    # OLMoE, Nemotron, Xing4.0, DeepSeek-V2 and, since PR 48, SmallThinker
+    assert len(_routed()) == 5 * 2 * 2
 
 
 @pytest.mark.parametrize("m", [128, 4096], ids=["decode", "chunk"])
