@@ -10,10 +10,23 @@ reduce-scatter calls (``layers.py:208-334``), each module:
   (column-parallel → sharded on the output dim, row-parallel → input dim,
   embedding → vocab dim), and
 - constrains its activations with ``with_sharding_constraint`` so GSPMD
-  inserts exactly the Megatron collectives — including the backward-pass
-  conjugates and the async overlap the reference implements by hand
-  (``layers.py:270-305``), which XLA's latency-hiding scheduler recovers
-  automatically.
+  inserts exactly the Megatron collectives, including the backward-pass
+  conjugates.  The overlap the reference implements by hand
+  (``layers.py:270-305``) the compiler recovers only in part: on the 2x2 host
+  94% of the collective time of the tp = 4 training step stood alone (ledger,
+  PR 48: ``collective_exposed_share`` 7.39 of ``collective_time_share``
+  7.86).  The scatters and the backward's gathers it does put under matmuls
+  (windowed einsums, async collective fusions); the FORWARD gather before a
+  sequence-parallel column projection it emits synchronous, because the
+  gather's only consumer is the matmul.  ``parallel/collective_matmul.py``
+  gives it something to put beside the gather — the projection cut in pieces
+  along the batch, piece ``i + 1``'s gather under piece ``i``'s matmul — and
+  the q/k/v projection (``parallel/qkv.py``) is cut so where its local
+  columns reach ``GATHER_MIN_WIDTH`` (the chip's peak over the measured gather
+  speed, derived there).  The layers of this file are NOT cut: gate-up's
+  products come out fused-axis-major, joining the pieces is a copy of 224 MiB,
+  and the cut lost 0.2% of the tp4 step where q/k/v's won 1.8% (``PERF.md``
+  §6, PR 49).
 
 Sequence parallelism (Megatron-SP, reference ``mappings.py:198-250`` +
 ``layers.py:230-238,311-324``) is an activation-sharding choice here: SP

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from neuronx_distributed_tpu.parallel import collective_matmul
 from neuronx_distributed_tpu.parallel.layers import shard_activation, trailing_spec
 from neuronx_distributed_tpu.parallel.mesh import (
     KV_REPLICA_AXIS,
@@ -120,11 +121,16 @@ class GQAQKVColumnParallelLinear(nn.Module):
         if self.sequence_parallel:
             x = shard_activation(x, trailing_spec(x.ndim, seq=SEQUENCE_AXES))
 
-        def proj(w, head_axes, name):
-            y = jnp.einsum("...h,hnd->...nd", x, jnp.asarray(w, self.dtype),
-                           preferred_element_type=self.dtype)
+        dtype = self.dtype
+
+        def base(x, w, head_axes):
+            y = jnp.einsum("...h,hnd->...nd", x, jnp.asarray(w, dtype),
+                           preferred_element_type=dtype)
             # head dim sits at -2 ([..., n_heads, head_dim])
-            y = shard_activation(y, trailing_spec(y.ndim, seq=head_axes))
+            return shard_activation(y, trailing_spec(y.ndim, seq=head_axes))
+
+        def proj(w, head_axes, name):
+            y = base(x, w, head_axes)
             if self.lora_rank > 0 and name in self.lora_targets:
                 r = self.lora_rank
                 n_heads = w.shape[1]
@@ -146,9 +152,21 @@ class GQAQKVColumnParallelLinear(nn.Module):
                 y = y + (self.lora_alpha / r) * delta
             return y
 
-        q = proj(wq, Q_HEAD_AXES, "q")
-        k = proj(wk, KV_HEAD_AXES, "k")
-        v = proj(wv, KV_HEAD_AXES, "v")
+        pieces = 1
+        if self.sequence_parallel and self.lora_rank == 0:
+            pieces = collective_matmul.gather_pieces(
+                x, (self.num_heads + 2 * self.num_kv_heads) * self.head_dim)
+        if pieces == 1:
+            q = proj(wq, Q_HEAD_AXES, "q")
+            k = proj(wk, KV_HEAD_AXES, "k")
+            v = proj(wv, KV_HEAD_AXES, "v")
+        else:
+            # a piece's gather rides under its neighbour's three matmuls
+            q, k, v = collective_matmul.in_pieces(
+                lambda x, wq, wk, wv: (base(x, wq, Q_HEAD_AXES),
+                                       base(x, wk, KV_HEAD_AXES),
+                                       base(x, wv, KV_HEAD_AXES)),
+                pieces, x, wq, wk, wv)
 
         if self.use_bias:
             bq = self.param(

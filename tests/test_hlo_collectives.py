@@ -10,6 +10,11 @@ legitimately lowers reduce-scatter as all-reduce+slice, so op MIX is not
 pinned), tight enough that a per-layer replication blow-up (which
 multiplies counts) fails loudly.
 
+The tiny model's projections stay under ``collective_matmul``'s rule
+(``GATHER_MIN_WIDTH``), so its two budgets below are those of GSPMD's own
+text and stand as they were; the last case widens q/k/v past the rule and
+counts what the cut adds.
+
 Reference counterpart: none — the reference has no compile-time collective
 accounting; its perf regressions surface only on Trn1 metrics dashboards.
 """
@@ -40,13 +45,14 @@ def _collective_counts(txt: str):
     return {op: len(re.findall(rf"{op}(?:-start)?\(", txt)) for op in _OPS}
 
 
-def _compiled_step_text(num_layers: int):
+def _compiled_step_text(num_layers: int, **overrides):
     nxd.destroy_model_parallel()
     nxd.initialize_model_parallel(tensor_parallel_size=8)
     config = nxd.training_config(tensor_parallel_size=8, compute_dtype="float32")
     cfg = LlamaConfig.tiny(
         num_layers=num_layers, sequence_parallel=True, remat="none",
-        dtype=jnp.float32, param_dtype=jnp.float32, max_seq_len=64)
+        dtype=jnp.float32, param_dtype=jnp.float32, max_seq_len=64,
+        **overrides)
     model = initialize_parallel_model(
         config, lambda: LlamaForCausalLM(cfg), (jnp.zeros((1, 64), jnp.int32),))
     opt = initialize_parallel_optimizer(config, model)
@@ -80,3 +86,25 @@ def test_collectives_scale_linearly_with_depth(devices8):
         # fixed part (loss/optimizer) + per-layer part: c4 <= c2 * 2 holds
         # whenever the per-layer share doesn't grow
         assert c4[op] <= 2 * c2[op] + 4, (op, c2, c4)
+
+
+def test_a_cut_gather_adds_its_pieces_and_nothing_else(devices8, monkeypatch):
+    """q/k/v widened past the rule (local columns (8 + 2 x 8) x 512 / 8 =
+    1536): cut in pieces, a layer holds (pieces - 1) more forward all-gathers
+    and, this step keeping no remat, the backward's own gather of the input
+    where the whole form reused the forward's — linear in depth, and not one
+    all-reduce, permute or all-to-all more."""
+    from neuronx_distributed_tpu.parallel import collective_matmul as cm
+
+    counts = {}
+    for form, width in (("cut", cm.GATHER_MIN_WIDTH), ("whole", 1 << 30)):
+        monkeypatch.setattr(cm, "GATHER_MIN_WIDTH", width)
+        for layers in (2, 4):
+            counts[form, layers] = _collective_counts(
+                _compiled_step_text(layers, head_dim=512))
+    for layers in (2, 4):
+        cut, whole = counts["cut", layers], counts["whole", layers]
+        assert cut["all-gather"] - whole["all-gather"] == layers * cm.GATHER_PIECES, counts
+        for op in ("all-reduce", "collective-permute", "all-to-all",
+                   "reduce-scatter"):
+            assert cut[op] == whole[op], (op, counts)

@@ -608,3 +608,74 @@ def test_xing4_serve_programs_fit_a_v5e_and_copy_no_pool(
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert weights + pool < total < 14.5 * 2 ** 30
     assert weights > 10.3 * 2 ** 30 and pool > 2.2 * 2 ** 30
+
+
+# -- a sequence-parallel gather under its neighbour's matmul (tp = 4) ---------
+
+
+def _matmuls_inside_async_collectives(text):
+    """For every asynchronous collective of a compiled program's ENTRY
+    computation (``async-collective-start``, ``collective-permute-start``,
+    ``all-gather-start``), in its scheduled order: how many matmul fusions
+    stand between the start and its done."""
+    import re
+
+    comps = re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \([^\n]*\) -> [^\n]*\{\n)",
+                     text)
+    called = {c.split(" ")[0]: c for c in comps if not c.startswith("ENTRY")}
+    entry = [c for c in comps if c.startswith("ENTRY")][0].splitlines()
+    order = [(m.group(1), ln) for ln in entry
+             if (m := re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = ", ln))]
+    at = {name: i for i, (name, _) in enumerate(order)}
+
+    def is_matmul(ln):
+        call = re.search(r"calls=(%[\w.\-]+)", ln)
+        return (" fusion(" in ln and call is not None
+                and "convolution(" in called.get(call.group(1), ""))
+    found = {}
+    for name, _ in order:
+        m = re.match(r"%(async-collective|collective-permute|all-gather)"
+                     r"-start(\.\d+)?$", name)
+        done = m and f"%{m.group(1)}-done{m.group(2) or ''}"
+        if done in at:
+            found[name] = sum(is_matmul(ln)
+                              for _, ln in order[at[name] + 1: at[done]])
+    return found
+
+
+def _abstract_layer(layer, mesh, x):
+    from flax import linen as nn
+
+    boxed = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    return jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        nn.unbox(boxed), nn.get_partition_spec(boxed))
+
+
+@pytest.mark.parametrize("form", ["cut", "whole"])
+def test_qkv_gather_rides_under_a_matmul_for_v5e(topo, monkeypatch, form):
+    """Mistral-7B's q/k/v projection on tp = 4 with sequence parallel at the
+    tp4 cell's shapes (batch 2 x 8192): left whole, the compiled forward
+    holds no asynchronous collective at all — the gather stands alone before
+    its matmuls; cut in pieces (``parallel/collective_matmul.py``), a piece's
+    gather is started before its neighbour's matmuls and waited for after."""
+    from neuronx_distributed_tpu.parallel import collective_matmul as cm
+    from neuronx_distributed_tpu.parallel.mesh import SEQUENCE_AXES
+    from neuronx_distributed_tpu.parallel.qkv import GQAQKVColumnParallelLinear
+
+    if form == "whole":
+        monkeypatch.setattr(cm, "GATHER_MIN_WIDTH", 1 << 30)
+    mesh = _mesh(topo, tp=4)
+    layer = GQAQKVColumnParallelLinear(
+        num_heads=NQ, num_kv_heads=NKV, head_dim=D, sequence_parallel=True)
+    x = jax.ShapeDtypeStruct(
+        (2, 8192, 4096), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(None, SEQUENCE_AXES, None)))
+    params = _abstract_layer(layer, mesh, x)
+    text = _compiled_text(layer.apply, params, x)
+    over = _matmuls_inside_async_collectives(text)
+    if form == "cut":
+        assert any(n > 0 for n in over.values()), over
+    else:
+        assert not over, over
