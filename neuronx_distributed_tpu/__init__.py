@@ -7,7 +7,13 @@ Public API mirrors the reference's top-level exports
 (``src/neuronx_distributed/__init__.py:1-7``).
 """
 
-from neuronx_distributed_tpu.version import __version__
+import time as _time
+
+# the top of the start-up account's ``import`` phase (obs.startup): before
+# anything heavy — jax and flax come in below unless the caller had them
+_IMPORT_T0 = _time.perf_counter()
+
+from neuronx_distributed_tpu.version import __version__  # noqa: E402
 from neuronx_distributed_tpu.config import (
     ActivationCheckpointConfig,
     OptimizerConfig,
@@ -25,6 +31,9 @@ from neuronx_distributed_tpu.parallel.mesh import (
     initialize_model_parallel,
     model_parallel_is_initialized,
 )
+from neuronx_distributed_tpu.obs import startup as _startup
+
+_startup.account().imported(_IMPORT_T0, _time.perf_counter())
 
 __all__ = [
     "__version__",

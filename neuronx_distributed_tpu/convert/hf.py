@@ -35,6 +35,8 @@ from typing import Any, Dict, Mapping
 import jax
 import numpy as np
 
+from neuronx_distributed_tpu.obs import startup
+
 
 def _np(x) -> np.ndarray:
     if hasattr(x, "detach"):  # torch tensor
@@ -114,6 +116,7 @@ def _decoder_layer_from_hf(sd: Mapping[str, np.ndarray], p: str, cfg,
     }
 
 
+@startup.phased("weights")
 def llama_params_from_hf(state_dict: Mapping[str, Any], cfg) -> Dict[str, Any]:
     """HF ``LlamaForCausalLM.state_dict()`` → framework param tree for
     :class:`~..models.llama.LlamaForCausalLM` with config ``cfg`` (scanned
@@ -176,6 +179,7 @@ def llama_params_to_hf(params: Mapping[str, Any], cfg) -> Dict[str, np.ndarray]:
 # ---------------------------------------------------------------------------
 
 
+@startup.phased("weights")
 def olmoe_params_from_hf(state_dict: Mapping[str, Any], cfg) -> Dict[str, Any]:
     """HF ``OlmoeForCausalLM.state_dict()`` -> the param tree of
     :class:`~..models.llama.LlamaForCausalLM` under an OLMoE config
@@ -321,6 +325,7 @@ def _sala_layer_dims(cfg, i):
     return cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
 
 
+@startup.phased("weights")
 def minicpm_sala_params_from_hf(state_dict: Mapping[str, Any], cfg
                                 ) -> Dict[str, Any]:
     """A ``minicpm_sala`` state dict -> the param tree of
@@ -471,6 +476,7 @@ def _nemotron_h_held(cfg):
     return cfg.moe_experts_held or (0, cfg.num_experts)
 
 
+@startup.phased("weights")
 def nemotron_h_params_from_hf(state_dict: Mapping[str, Any], cfg
                               ) -> Dict[str, Any]:
     """A ``nemotron_h`` state dict (:data:`NEMOTRON_H_MIXER_NAMES`) -> the
@@ -679,6 +685,7 @@ def _xing4_hc_names(p: str, hc: str):
     return [p + n.format(hc=hc) for n in XING4_NAMES["streams"]]
 
 
+@startup.phased("weights")
 def _latent_params_from_hf(state_dict: Mapping[str, Any], cfg
                            ) -> Dict[str, Any]:
     """The state dict of a latent-attention decoder with one leading run of
@@ -808,6 +815,7 @@ def _latent_params_to_hf(params: Mapping[str, Any], cfg
     return sd
 
 
+@startup.phased("weights")
 def xing4_params_from_hf(state_dict: Mapping[str, Any], cfg
                          ) -> Dict[str, Any]:
     """A ``xing4_0`` state dict (:data:`XING4_NAMES`, assumed) -> the param
@@ -895,6 +903,7 @@ def deepseek_v2_config_from_hf(hf_config: Mapping[str, Any], **overrides):
     ), **overrides})
 
 
+@startup.phased("weights")
 def deepseek_v2_params_from_hf(state_dict: Mapping[str, Any], cfg
                                ) -> Dict[str, Any]:
     """A ``deepseek_v2`` state dict (:data:`DEEPSEEK_V2_NAMES`) -> the param
@@ -958,6 +967,7 @@ def lfm2_moe_config_from_hf(hf_config: Mapping[str, Any], **overrides):
     ), **overrides})
 
 
+@startup.phased("weights")
 def lfm2_moe_params_from_hf(state_dict: Mapping[str, Any], cfg
                             ) -> Dict[str, Any]:
     """An ``lfm2_moe`` state dict (names as assumed above) -> the param tree
@@ -1075,6 +1085,7 @@ def _neox_interleave(w: np.ndarray, b: np.ndarray, num_heads: int, head_dim: int
     return wq, bq
 
 
+@startup.phased("weights")
 def gpt_neox_params_from_hf(state_dict: Mapping[str, Any], cfg) -> Dict[str, Any]:
     """HF ``GPTNeoXForCausalLM.state_dict()`` → framework param tree."""
     sd = {k: _np(v) for k, v in state_dict.items()}
@@ -1161,6 +1172,7 @@ def gpt_neox_params_to_hf(params: Mapping[str, Any], cfg) -> Dict[str, np.ndarra
 # ---------------------------------------------------------------------------
 
 
+@startup.phased("weights")
 def bert_params_from_hf(state_dict: Mapping[str, Any], cfg) -> Dict[str, Any]:
     """HF ``BertForPreTraining.state_dict()`` → framework param tree for
     :class:`~..models.bert.BertForPreTraining` (separate HF q/k/v linears
@@ -1336,6 +1348,7 @@ mistral_params_to_hf = llama_params_to_hf
 # ---------------------------------------------------------------------------
 
 
+@startup.phased("weights")
 def gemma_params_from_hf(state_dict: Mapping[str, Any], cfg) -> Dict[str, Any]:
     """HF ``GemmaForCausalLM.state_dict()`` → framework param tree for
     :class:`~..models.gemma.GemmaForCausalLM`.
@@ -1385,6 +1398,7 @@ def gemma_params_to_hf(params: Mapping[str, Any], cfg) -> Dict[str, np.ndarray]:
     return out
 
 
+@startup.phased("weights")
 def gemma2_params_from_hf(state_dict: Mapping[str, Any], cfg) -> Dict[str, Any]:
     """HF ``Gemma2ForCausalLM.state_dict()`` → framework param tree for
     :class:`~..models.gemma.Gemma2ForCausalLM` (tied head; every RMSNorm —

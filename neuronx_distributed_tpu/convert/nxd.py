@@ -34,6 +34,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from neuronx_distributed_tpu.obs import startup
 from neuronx_distributed_tpu.utils.logger import get_logger
 
 logger = get_logger(__name__)
@@ -114,6 +115,7 @@ def rule_for(name: str, rules: Sequence[Tuple[str, Tuple[int, int]]]):
     return None
 
 
+@startup.phased("weights")
 def load_nxd_checkpoint(
     model_dir: str,
     tp_rules: Sequence[Tuple[str, Tuple[int, int]]] = LLAMA_TP_RULES,

@@ -148,14 +148,23 @@ class DevicePrefetcher:
     def _restart(self, step: int) -> None:
         """(Re)start staging at ``step``: bump the generation (the old
         worker sees it and exits), drop staged batches, spawn a worker."""
+        old = self._thread
         with self._lock:
-            was_running = self._thread is not None
+            was_running = old is not None
             self._gen += 1
             gen = self._gen
             self._next_out = step
             self._staged_to = step
         self._drain()
         if was_running:
+            # the retired worker sees the generation and exits (a put it was
+            # blocked in has room now): wait for it, so that two workers
+            # never call the source at once and close() — which joins only
+            # the newest — leaves no thread behind
+            self._join(old)
+            self._drain()  # what it put on its way out
+            with self._lock:
+                self._staged_to = step
             self.rewinds += 1
             if self._registry is not None:
                 self._registry.counter(REWINDS_TOTAL).inc()
@@ -165,6 +174,12 @@ class DevicePrefetcher:
             target=self._worker, args=(gen, step),
             name=f"{self._name}-prefetch", daemon=True)
         self._thread.start()
+
+    def _join(self, worker: threading.Thread, timeout: float = 5.0) -> None:
+        worker.join(timeout=timeout)
+        if worker.is_alive():  # pragma: no cover - source wedged in user code
+            logger.warning("prefetch[%s]: worker did not stop in %.1fs",
+                           self._name, timeout)
 
     def _drain(self) -> None:
         while True:
@@ -227,12 +242,8 @@ class DevicePrefetcher:
             self._closed = True
             self._gen += 1
         self._drain()  # unblock a worker stuck in put
-        t = self._thread
-        if t is not None:
-            t.join(timeout=timeout)
-            if t.is_alive():  # pragma: no cover - source wedged in user code
-                logger.warning("prefetch[%s]: worker did not stop in %.1fs",
-                               self._name, timeout)
+        if self._thread is not None:
+            self._join(self._thread, timeout)
             self._thread = None
         self._drain()  # whatever the worker put while we were joining
         if self._registry is not None:

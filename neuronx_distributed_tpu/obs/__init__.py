@@ -18,6 +18,11 @@ This package correlates them:
   ``StepAccount``, a stepping loop's own books (host time by phase, CPU
   against off-CPU time, the stall rule), one flat record a step into the
   same ring;
+- :mod:`.startup` — the process's account of its own start-up, always on:
+  wall time by phase from the process's start to ``ready`` (import, mesh,
+  weights, optimizer, engine, warm-up, first step) on the profiler's clock,
+  JAX's compile path by stage inside them, and the package's one pair of
+  ``jax.monitoring`` listeners;
 - :mod:`.hlo_audit` — compile-time collective-op counts and byte volumes
   walked out of a compiled program's HLO (the reusable form of the
   assertions in ``tests/test_hlo_collectives.py``), one audit record per

@@ -23,10 +23,11 @@ it.  :class:`CompileLedger` is the one accounting surface:
   (``trace/compile_storms_total``), surfaced in the flight recorder's
   warnings, and traced as a ``compile`` span so the stall shows up in
   request waterfalls;
-- **a recompile inside jit dispatch is seen too**: one process-wide
-  ``jax.monitoring`` listener, installed when the first ledger is built,
-  hears every request JAX makes to its compiler (served from the persistent
-  cache or not) and counts it into ``trace/compile_requests_total``.  After
+- **a recompile inside jit dispatch is seen too**: the process's one
+  ``jax.monitoring`` duration listener (``obs.startup``, installed when the
+  package is imported; a ledger joins the set it feeds) hears every request
+  JAX makes to its compiler (served from the persistent cache or not) and
+  counts it into ``trace/compile_requests_total``.  After
   warm-up, a request that no explicit ``record_compile`` accounts for — a
   cached jit whose argument came back placed otherwise, say — becomes a row
   of family ``jit_dispatch`` at the next :meth:`reconcile`, and a storm.
@@ -47,10 +48,10 @@ import json
 import os
 import threading
 import time
-import weakref
 from contextlib import contextmanager
 from typing import Any, Dict, Iterable, List, Optional
 
+from neuronx_distributed_tpu.obs import startup
 from neuronx_distributed_tpu.utils.logger import get_logger
 
 logger = get_logger(__name__)
@@ -76,35 +77,9 @@ _COST_KEYS = ("flops", "bytes_accessed", "argument_size_in_bytes",
               "output_size_in_bytes", "temp_size_in_bytes")
 
 
-# JAX times every call into its compiler under this event, cache hit or
-# miss, and names the program (jax._src.dispatch.BACKEND_COMPILE_EVENT)
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # a request this long before an explicit row's own timed stretch began is
 # not that row's (clock granularity between the two stamps)
 _COVER_SLACK_S = 0.05
-_live_ledgers: "weakref.WeakSet[CompileLedger]" = weakref.WeakSet()
-_listening = False
-
-
-def _on_compile_request(event: str, duration_secs: float, **kw) -> None:
-    if event == _COMPILE_EVENT:
-        for led in list(_live_ledgers):
-            led._compile_requested(str(kw.get("fun_name", "?")),
-                                   duration_secs * 1e3)
-
-
-def _listen(ledger: "CompileLedger") -> None:
-    """Feed ``ledger`` from the one process-wide listener (JAX offers no
-    public way to take a listener out again, so there is one for good and
-    dead ledgers fall out of the weak set)."""
-    global _listening
-    _live_ledgers.add(ledger)
-    if not _listening:
-        import jax
-
-        jax.monitoring.register_event_duration_secs_listener(
-            _on_compile_request)
-        _listening = True
 
 
 def jit_cache_size(fn: Any) -> Optional[int]:
@@ -180,7 +155,7 @@ class CompileLedger:
         # compiler requests heard since warm-up that no row accounts for
         # yet: (program name, wall ms, clock at the request's end)
         self._unaccounted: List[tuple] = []
-        _listen(self)
+        startup.LEDGERS.add(self)
 
     # -- wiring ------------------------------------------------------------
 

@@ -396,6 +396,33 @@ REGISTRY_METRICS: Dict[str, str] = {
     "weights/swap_failures_total": "counter",
     "weights/swap_ms": "histogram",
     "weights/weights_version": "gauge",
+    # the start-up account (obs.startup.StartupAccount), copied in ONCE by
+    # whoever declares the process ready (the first engine's
+    # declare_warmup_done, fit()'s first fetched loss): seconds from the
+    # process's start to that moment; self wall time by phase
+    # (STARTUP_PHASES; they add up to ready_s); inside them, what JAX
+    # reported of its compile path by stage (COMPILE_STAGES, made disjoint),
+    # the compile time the persistent cache says it saved, and its
+    # requests, hits and misses
+    "startup/ready_s": "gauge",
+    "startup/ms_total/process": "counter",
+    "startup/ms_total/import": "counter",
+    "startup/ms_total/backend": "counter",
+    "startup/ms_total/mesh": "counter",
+    "startup/ms_total/weights": "counter",
+    "startup/ms_total/optimizer": "counter",
+    "startup/ms_total/engine": "counter",
+    "startup/ms_total/warmup": "counter",
+    "startup/ms_total/step0": "counter",
+    "startup/ms_total/audit": "counter",
+    "startup/compile_ms_total/trace": "counter",
+    "startup/compile_ms_total/lower": "counter",
+    "startup/compile_ms_total/backend_compile": "counter",
+    "startup/compile_ms_total/cache_read": "counter",
+    "startup/compile_saved_ms_total": "counter",
+    "startup/compile_requests_total": "counter",
+    "startup/cache_hits_total": "counter",
+    "startup/cache_misses_total": "counter",
 }
 
 

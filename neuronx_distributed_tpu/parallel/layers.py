@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from neuronx_distributed_tpu.obs import startup
 from neuronx_distributed_tpu.parallel.mesh import (
     SEQUENCE_AXES,
     TENSOR_AXES,
@@ -63,6 +64,7 @@ Initializer = Callable[..., jax.Array]
 _U = P.UNCONSTRAINED
 
 
+@startup.phased("weights")
 def init_sharded_params(module: nn.Module, rng: jax.Array, *example_inputs,
                         spec_map: Callable[[Any, Any], Any] | None = None):
     """``module.init`` with every parameter BORN sharded over the global
@@ -81,8 +83,10 @@ def init_sharded_params(module: nn.Module, rng: jax.Array, *example_inputs,
     shardings = jax.tree.map(
         lambda s: NamedSharding(mesh, s), specs,
         is_leaf=lambda x: isinstance(x, P))
-    init = jax.jit(lambda r, *a: nn.unbox(module.init(r, *a)),
-                   out_shardings=shardings)
+    def init_sharded(r, *a):    # named: the start-up account lists programs
+        return nn.unbox(module.init(r, *a))
+
+    init = jax.jit(init_sharded, out_shardings=shardings)
     return init(rng, *example_inputs), specs
 
 

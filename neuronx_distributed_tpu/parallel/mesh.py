@@ -43,6 +43,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from neuronx_distributed_tpu.obs import startup
 from neuronx_distributed_tpu.utils.logger import get_logger
 
 logger = get_logger(__name__)
@@ -164,6 +165,7 @@ def _build_device_array(devices: Sequence[jax.Device], shape: Sequence[int]) -> 
     return np.asarray(devices).reshape(tuple(shape))
 
 
+@startup.phased("mesh")
 def initialize_model_parallel(
     tensor_parallel_size: int = 1,
     pipeline_parallel_size: int = 1,
@@ -195,7 +197,12 @@ def initialize_model_parallel(
     # choices (tests/test_rng_dropout.py).
     jax.config.update("jax_threefry_partitionable", True)
 
-    devices = list(devices if devices is not None else jax.devices())
+    if devices is None:
+        # the program itself brings the client up (a caller that hands the
+        # devices in has done it, on its own time)
+        with startup.account().phase("backend"):
+            devices = jax.devices()
+    devices = list(devices)
     n = len(devices)
     cfg = MeshConfig(
         tensor_parallel_size=tensor_parallel_size,

@@ -79,7 +79,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from neuronx_distributed_tpu.obs import MS_BUCKETS, MetricRegistry
+from neuronx_distributed_tpu.obs import MS_BUCKETS, MetricRegistry, startup
 from neuronx_distributed_tpu.obs.flight import FlightRecorder, StepAccount
 from neuronx_distributed_tpu.obs.tracing import phase
 from neuronx_distributed_tpu.obs.transfer_audit import TransferAudit
@@ -575,6 +575,7 @@ class ServingEngine:
     ``obs.health.ALERTS_EVALUATED`` counter.
     """
 
+    @startup.phased("engine")
     def __init__(
         self,
         model: Any,
@@ -1224,10 +1225,17 @@ class ServingEngine:
         ledger sees from here on is a ``compile_storm`` (counted, flight-
         warned, traced).  Benches call this between their warm pass and the
         measured pass.  The step account starts over too (totals, longest
-        step, trailing median): a warm-up's compiles are not stalls."""
+        step, trailing median): a warm-up's compiles are not stalls.  The
+        first engine of a process to get here also declares the process
+        ``ready`` (``obs.startup``): the steps so far were its ``warmup``,
+        and the start-up totals land in this engine's registry."""
+        started = startup.account()
+        started.move("warmup", self.registry.counter(
+            "serving/step_ms_total").value / 1e3)
         self._account.reset()
         if self.compile_ledger is not None:
             self.compile_ledger.declare_warmup_done("engine")
+        started.ready("engine", self.registry)
 
     def install_params(self, params: Any, version: int) -> None:
         """Commit point of a live weight swap (``weights.WeightSwapper``):

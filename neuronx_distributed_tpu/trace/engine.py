@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from neuronx_distributed_tpu.obs import startup
 from neuronx_distributed_tpu.parallel.mesh import (
     BATCH_AXES,
     TENSOR_AXIS,
@@ -662,6 +663,7 @@ class ParallelInferenceModel(_ServingBase):
     cache_offset) -> (logits, new_caches)``.
     """
 
+    @startup.phased("engine")
     def __init__(
         self,
         module,

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import orbax.checkpoint as ocp
 from jax import export as jax_export
 
+from neuronx_distributed_tpu.obs import startup
 from neuronx_distributed_tpu.trace.engine import (
     InferenceConfig,
     ParallelInferenceModel,
@@ -90,6 +91,7 @@ class LoadedInferenceModel(_ServingBase):
         return self._decode_exp.call(params, tok, offset, caches, valid)
 
 
+@startup.phased("weights")
 def parallel_model_load(path: str) -> LoadedInferenceModel:
     """Load a traced model saved by :func:`parallel_model_save` (reference
     ``parallel_model_load``, ``trace/trace.py:195-200``)."""

@@ -58,6 +58,7 @@ import orbax.checkpoint as ocp
 from jax.experimental import multihost_utils
 from jax.sharding import NamedSharding
 
+from neuronx_distributed_tpu.obs import startup
 from neuronx_distributed_tpu.resilience.faults import fault_point
 from neuronx_distributed_tpu.utils.distributed import is_primary as _is_primary
 from neuronx_distributed_tpu.utils.logger import get_logger
@@ -261,6 +262,7 @@ def _abstract_like(template: Any):
     return jax.tree.map(one, template)
 
 
+@startup.phased("weights")
 def load_checkpoint(
     ckpt_dir: str,
     tag: Optional[str] = None,

@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from neuronx_distributed_tpu.config import TrainingConfig
+from neuronx_distributed_tpu.obs import startup
 from neuronx_distributed_tpu.resilience.faults import fault_point, perturb
 from neuronx_distributed_tpu.trainer.checkpoint import (
     load_checkpoint,
@@ -125,6 +126,7 @@ class Callback:
         """Called once with the final :class:`FitResult`."""
 
 
+@startup.phased("step0")
 def fit(
     config: TrainingConfig,
     model: Any,
@@ -443,6 +445,16 @@ def fit(
     pending_eval: list = []  # [(eval_step, ev)]
 
     moe_running = None       # [L, E] loads summed since the run began
+    starting = True          # until a step's loss has reached the host
+
+    def _started() -> None:
+        """The first loss is here: the process is ``ready`` (``obs.startup``;
+        once a process, so a later ``fit()`` changes nothing), and the
+        ``step0`` phase this call opened ends."""
+        nonlocal starting
+        starting = False
+        startup.account().ready(
+            "fit", obs_rt.registry if obs_rt is not None else None)
 
     def _book_moe(fetched: dict) -> None:
         """A routed model's step: its expert loads into the registry's
@@ -472,6 +484,8 @@ def fit(
         # expert loads ride it as they ride the token fetch in serving)
         fetched = audit.fetch(pm, label="train")
         wait_s = time.perf_counter() - t_w
+        if starting:
+            _started()
         ploss = perturb("fit/loss", float(fetched["loss"]), step=pstep)
         pgrad = float(fetched["grad_norm"])
         loss = ploss
@@ -575,8 +589,9 @@ def fit(
                 # compilation cache (when enabled) dedupes the XLA work
                 try:
                     t_aot = time.perf_counter()
-                    compiled = step_fn.lower(
-                        params, opt_state, batch, rng).compile()
+                    with startup.account().phase("audit"):
+                        compiled = step_fn.lower(
+                            params, opt_state, batch, rng).compile()
                     if compile_led is not None:
                         compile_led.record_compile(
                             "train_step", "aot_audit",
@@ -619,6 +634,8 @@ def fit(
                     grad_norm = float(m["grad_norm"])
                     t_done = time.perf_counter()
                     _book_moe(m)
+            if starting and not deferred:
+                _started()      # deferred: the flush that fetches a loss
             if compile_led is not None:
                 n = jit_cache_size(step_fn)
                 if step == start_step:

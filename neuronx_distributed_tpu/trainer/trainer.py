@@ -22,6 +22,7 @@ from flax import linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from neuronx_distributed_tpu.config import TrainingConfig
+from neuronx_distributed_tpu.obs import startup
 from neuronx_distributed_tpu.optimizer.adamw_fp32 import adamw_fp32, build_lr_schedule
 from neuronx_distributed_tpu.optimizer.zero1 import optimizer_state_specs
 from neuronx_distributed_tpu.parallel.grads import clip_grad_norm
@@ -142,6 +143,7 @@ def _align_module_with_config(module: nn.Module, config: TrainingConfig) -> nn.M
     return rebuilt
 
 
+@startup.phased("weights")
 def initialize_parallel_model(
     config: TrainingConfig,
     model_fn: Callable[[], nn.Module],
@@ -239,6 +241,7 @@ def _is_state_leaf(path: str) -> bool:
     return any(f"'{name}'" in path for name in NON_TRAINABLE_LEAVES)
 
 
+@startup.phased("optimizer")
 def initialize_parallel_optimizer(
     config: TrainingConfig,
     model: ParallelModel,

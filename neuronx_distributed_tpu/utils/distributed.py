@@ -53,12 +53,16 @@ def initialize_distributed(
         # args must still be able to bring the job up
         logger.info("single-process run; skipping jax.distributed.initialize")
         return
-    jax.distributed.initialize(
-        coordinator_address=coordinator_address,
-        num_processes=num_processes,
-        process_id=process_id,
-        local_device_ids=local_device_ids,
-    )
+    # here and not at the top: obs reaches this package for its logger
+    from neuronx_distributed_tpu.obs import startup
+
+    with startup.account().phase("backend"):
+        jax.distributed.initialize(
+            coordinator_address=coordinator_address,
+            num_processes=num_processes,
+            process_id=process_id,
+            local_device_ids=local_device_ids,
+        )
     _INITIALIZED = True
     logger.info(
         "jax.distributed up: process %d/%d", jax.process_index(), jax.process_count()

@@ -110,6 +110,55 @@ def test_prefetcher_rewind_restages_at_requested_step():
     assert _live_prefetch_threads() == []
 
 
+def test_a_rewind_retires_its_worker_before_the_next_one_starts():
+    """The worker a rewind retires used to be left to notice on its own
+    time: under load it outlived ``close()`` (which joins the newest worker
+    only) and could call the source beside its successor.  More threads
+    than cores and a short switch interval, up to 200 rewinds in 6 s: never
+    two calls of the source at once, never a thread behind."""
+    import sys
+    import time
+
+    busy = threading.Event()
+    in_source = []
+    overlaps = []
+
+    def source(step):
+        in_source.append(step)
+        if len(in_source) > 1:
+            overlaps.append(tuple(in_source))
+        time.sleep(0)                  # give another worker its chance
+        in_source.pop()
+        return np.full((1,), step, np.int32)
+
+    def hog():
+        while not busy.is_set():
+            sum(range(500))
+
+    hogs = [threading.Thread(target=hog, daemon=True) for _ in range(12)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for h in hogs:
+            h.start()
+        deadline = time.monotonic() + 6.0
+        for _ in range(100):
+            with DevicePrefetcher(source, depth=2) as pf:
+                for step in (0, 1, 2, 1, 2, 0):
+                    assert int(np.asarray(pf.get(step))[0]) == step
+                assert pf.rewinds == 2
+            assert _live_prefetch_threads() == []
+            if time.monotonic() > deadline:
+                break
+    finally:
+        busy.set()
+        sys.setswitchinterval(interval)
+        for h in hogs:
+            h.join(timeout=5.0)
+    assert not any(h.is_alive() for h in hogs)
+    assert overlaps == []
+
+
 def test_prefetcher_iterator_source_exhausts_and_cannot_rewind():
     pf = DevicePrefetcher(iter([{"x": np.zeros(1)} for _ in range(3)]), depth=2)
     for step in range(3):
