@@ -17,7 +17,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-import orbax.checkpoint as ocp
 from jax import export as jax_export
 
 from neuronx_distributed_tpu.obs import startup
@@ -26,6 +25,7 @@ from neuronx_distributed_tpu.trace.engine import (
     ParallelInferenceModel,
     _ServingBase,
 )
+from neuronx_distributed_tpu.utils.checkpoint_library import checkpoint_library
 from neuronx_distributed_tpu.utils.logger import get_logger
 
 logger = get_logger(__name__)
@@ -53,6 +53,7 @@ def parallel_model_save(path: str, model: ParallelInferenceModel) -> str:
     with open(os.path.join(path, _DECODE), "wb") as f:
         f.write(dec_exp.serialize())
 
+    ocp = checkpoint_library()
     ocp.Checkpointer(ocp.StandardCheckpointHandler()).save(
         os.path.join(path, _PARAMS), args=ocp.args.StandardSave(model.params),
         force=True,
@@ -99,6 +100,7 @@ def parallel_model_load(path: str) -> LoadedInferenceModel:
         ctx_exp = jax_export.deserialize(f.read())
     with open(os.path.join(path, _DECODE), "rb") as f:
         dec_exp = jax_export.deserialize(f.read())
+    ocp = checkpoint_library()
     params = ocp.Checkpointer(ocp.StandardCheckpointHandler()).restore(
         os.path.join(path, _PARAMS)
     )

@@ -54,12 +54,12 @@ import shutil
 from typing import Any, Callable, List, Optional, Tuple
 
 import jax
-import orbax.checkpoint as ocp
 from jax.experimental import multihost_utils
 from jax.sharding import NamedSharding
 
 from neuronx_distributed_tpu.obs import startup
 from neuronx_distributed_tpu.resilience.faults import fault_point
+from neuronx_distributed_tpu.utils.checkpoint_library import checkpoint_library
 from neuronx_distributed_tpu.utils.distributed import is_primary as _is_primary
 from neuronx_distributed_tpu.utils.logger import get_logger
 
@@ -96,9 +96,10 @@ def _atomic_write(path: str, text: str) -> None:
 
 
 class _PendingSave:
-    """Finalization state of an in-flight async save."""
+    """Finalization state of an in-flight async save (``checkpointers``:
+    its ``ocp.AsyncCheckpointer``s)."""
 
-    def __init__(self, checkpointers: List[ocp.AsyncCheckpointer], finalize: Callable[[], None]):
+    def __init__(self, checkpointers: List[Any], finalize: Callable[[], None]):
         self._checkpointers = checkpointers
         self._finalize = finalize
         self.done = False
@@ -170,6 +171,7 @@ def save_checkpoint(
     template's dtype, so an fp32 template upcasts the stored bf16 values
     (precision truncated once at save, as with the reference)."""
     wait_for_checkpoint()  # at most one in-flight async save
+    ocp = checkpoint_library()
 
     if save_dtype is not None:
         from neuronx_distributed_tpu.utils.dtypes import cast_floating
@@ -277,6 +279,7 @@ def load_checkpoint(
     if tag is None:
         raise FileNotFoundError(f"no completed checkpoints under {ckpt_dir}")
     path = _tag_dir(ckpt_dir, tag)
+    ocp = checkpoint_library()
     ckptr = ocp.Checkpointer(ocp.StandardCheckpointHandler())
 
     model_state = None

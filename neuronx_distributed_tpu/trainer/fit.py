@@ -61,6 +61,7 @@ from neuronx_distributed_tpu.trainer.trainer import (
     make_eval_step,
     make_train_step,
 )
+from neuronx_distributed_tpu.utils.checkpoint_library import checkpoint_library
 from neuronx_distributed_tpu.utils.logger import get_logger
 
 logger = get_logger(__name__)
@@ -281,6 +282,10 @@ def fit(
     params, opt_state = model.params, optimizer.state
     start_step = 0
     resumed_user: dict = {}
+    if ckpt_dir:
+        # a run that names a checkpoint pays for the library here (the
+        # start-up account's ``import``), not inside step ``ckpt_every``
+        checkpoint_library()
     if resume and ckpt_dir and newest_tag(ckpt_dir):
         params, opt_state, _, user = load_checkpoint(
             ckpt_dir, model_template=params, optimizer_template=opt_state

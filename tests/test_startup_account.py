@@ -10,7 +10,9 @@ start to ``ready`` has a phase, the compile path has its stages, and after
 - a tiny ``ServingEngine`` warmed up and a tiny ``fit()`` each leave the
   declared names in the registry of whoever declared, and the spans
   ``nxd/startup/*`` in a profile taken over set-up;
-- after ``ready`` no phase opens over serve steps and train steps.
+- after ``ready`` no phase opens over serve steps and train steps;
+- a ``fit()`` that names a checkpoint directory loads the checkpoint library
+  before its first step, under ``import``; one that names none loads nothing.
 
 Each test gets an account of its own in place of the process's.
 """
@@ -46,6 +48,7 @@ from neuronx_distributed_tpu.trainer import (
     initialize_parallel_model,
     initialize_parallel_optimizer,
 )
+from neuronx_distributed_tpu.utils import checkpoint_library as library
 from test_device_names import _host_spans
 
 TICK = 1.0 / 1024        # a scripted clock's grain: sums of it are exact
@@ -70,6 +73,14 @@ class ScriptedClock:
 
     def jump(self, ticks):
         self.t += ticks * TICK
+
+
+class TickingClock(ScriptedClock):
+    """Moves one tick each time it is read."""
+
+    def __call__(self):
+        self.t += TICK
+        return self.t
 
 
 @pytest.fixture
@@ -462,3 +473,32 @@ def test_a_fit_is_ready_at_its_first_loss(devices8, live, tmp_path,
     assert startup.PHASES_OPENED == before
     assert len(ready_lines) == 1
     assert hub.registry.snapshot()["startup/ready_s"] == live.ready_s
+
+
+@pytest.mark.parametrize("named", [True, False])
+def test_a_fit_that_names_a_checkpoint_loads_the_library_under_import(
+        devices8, acct, monkeypatch, tmp_path, named):
+    # every reading of the clock is one tick, so a phase's self time is the
+    # boundaries read while it was the innermost: the load's is ONE
+    acct.clock = TickingClock()
+    monkeypatch.setattr(library, "_OCP", None)      # a fresh process
+    opened = []
+    phase = acct.phase
+
+    def listed(name, **kw):
+        opened.append(name)
+        return phase(name, **kw)
+
+    monkeypatch.setattr(acct, "phase", listed)
+    _tiny_fit(2, ckpt_dir=str(tmp_path / "ckpt") if named else None)
+    got = acct.phases_s()
+    assert acct.label == "fit" and sum(got.values()) == acct.ready_s
+    if named:
+        # before the first step (inside fit(), which is step0), and not in
+        # step0's or the weights' seconds
+        assert opened.index("import") == opened.index("step0") + 1
+        assert got["import"] == TICK
+        assert library._OCP is library.checkpoint_library()
+    else:
+        assert "import" not in opened and got["import"] == 0.0
+        assert library._OCP is None
