@@ -139,7 +139,10 @@ class LayerStates:
     page, latent_dim],)``: pages as any other to the allocator, the block
     tables and the prefix index, ``latent_dim`` columns a token in place of
     a K/V pair a kv head.  A ``"none"`` layer's entry of the pool is
-    ``()``."""
+    ``()``.  A model whose every layer is recurrent keeps NO page
+    (:attr:`paged` is 0): its pool is its state rows alone, a page costs
+    nothing, and the serving engine admits by state rows
+    (``serving.paged.PagedKVManager(pageless=True)``)."""
 
     kinds: Tuple[str, ...]
     comp_slots: int = 0
@@ -372,6 +375,8 @@ class PagePool:
             # the state rows come off the budget first: they are there
             # whatever the pages hold
             budget_bytes -= layers.state_rows * layers.state_row_bytes
+        if not per_page:
+            return 0    # no layer keeps a page: none to buy
         return max(int(budget_bytes // per_page), 0)
 
 

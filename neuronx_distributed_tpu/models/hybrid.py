@@ -28,6 +28,16 @@ DeepSeek-V2's latent attention and LFM2's gated short convolution:
   and a prefill chunk EXPANDED (keys and values up-projected a page inside
   the walk).
 
+- ``"power-retention"``: power retention of degree 2 (Brumby's layer,
+  ``ops.power_retention``) — Qwen3's attention block (grouped key/value
+  heads, per-head RMSNorm of q and k, RoPE) with the softmax replaced by
+  ``(q . k / sqrt d)^2`` under a per-token decay a key/value head,
+  ``log_sigmoid(W_g h + b_g)``.  Its per-sequence state is a state row of
+  TWO float32 arrays a layer: ``[kv heads, d, D]`` — the values against
+  the symmetric square ``phi`` of the keys, ``D = 9,216`` at ``d = 128`` —
+  and the normaliser ``[kv heads, d, d]``.  A decode steps the rows where
+  they lie (``retention_step``, by row id); a chunk slices its row out and
+  writes it back.  A model of such layers alone keeps NO page.
 - ``"conv"``: LFM2's gated short convolution — ``B, C, x = split3(in_proj
   h)``, a causal depthwise convolution of ``conv_L_cache`` taps over ``B *
   x`` with NO activation and no bias, ``out_proj(C * taps)``.  Scopes
@@ -42,8 +52,9 @@ and has no cached call (its gradients are tested against the float32
 reference, ``tests/test_lfm2_moe.py``; the serving engine refuses it by
 name); ``"lightning-attn"``, ``"minicpm4"``, ``"mamba2"`` and ``"mla"``
 SERVE and have no tested backward (their uncached call differentiates as
-plain XLA operations, unmeasured and unchecked).  Tensor parallelism over
-the heads of the five is not carried through (the engine refuses tp > 1).
+plain XLA operations, unmeasured and unchecked), and so does
+``"power-retention"``.  Tensor parallelism over the heads of the six is not
+carried through (the engine refuses tp > 1).
 """
 
 from __future__ import annotations
@@ -63,13 +74,14 @@ from neuronx_distributed_tpu.parallel.qkv import (
 )
 
 MIXERS = ("attention", "minicpm4", "lightning-attn", "mamba2", "mla", "conv",
-          "none")
+          "power-retention", "none")
 # what each mixer keeps for a live sequence, in the page pool's terms
 # (``kvcache.pool.CACHE_KINDS``): the one place a mixer's name decides it —
 # ``LlamaConfig.layer_caches`` hands it on, and the pool and the engines
 # read the config
 CACHE_OF = {"attention": "pages", "minicpm4": "selected_pages",
             "lightning-attn": "state", "mamba2": "state", "mla": "latent",
+            "power-retention": "state",
             # no cached call (trace/engine.py refuses the mixer by name)
             "conv": "none", "none": "none"}
 # the standard deviation a SEEDED embedding table of a layer-list model is
@@ -110,6 +122,11 @@ def state_arrays(cfg, kind: str):
     if kind == "lightning-attn":
         nh, d = lightning_dims(cfg)
         return (((nh, d, d), "float32"),)
+    if kind == "power-retention":
+        from neuronx_distributed_tpu.ops.power_retention import phi_dim
+
+        nkv, d = cfg.num_kv_heads, cfg.head_dim_
+        return (((nkv, d, phi_dim(d)), "float32"), ((nkv, d, d), "float32"))
     nh, p, g, n, k = ssm_dims(cfg)
     return (((nh, p, n), "float32"),
             ((k - 1, nh * p + 2 * g * n), jnp.dtype(cfg.dtype).name))
@@ -418,6 +435,139 @@ class Mamba2Mixer(nn.Module):
             name="out_proj")(y), new_cache
 
 
+# a SEEDED decay bias is drawn so that a head's half-life lies between these
+# many tokens, log-uniform: at a bias of 0 a seeded gate sits near 0.5, the
+# state forgets in ten tokens and no check sees what a chunk hands the next
+RETENTION_HALF_LIFE = (64.0, 8192.0)
+
+
+def _retention_gate_bias(key, shape, dtype):
+    import math
+
+    lo, hi = RETENTION_HALF_LIFE
+    half = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
+                   * (math.log(hi) - math.log(lo)) + math.log(lo))
+    g = jnp.exp(-math.log(2.0) / half)          # the decay a token
+    return (jnp.log(g) - jnp.log1p(-g)).astype(dtype)
+
+
+def _log_decay(gate, bias):
+    """A token's log decay a key/value head, float32, ``<= 0``."""
+    return jax.nn.log_sigmoid(gate.astype(jnp.float32) + bias)
+
+
+def phi(u, dtype=None):
+    """The symmetric square of ``u [..., d]`` as the power-retention state
+    lays it out (``ops.power_retention.phi``): ``phi(q) . phi(k) = (q .
+    k)^2``."""
+    from neuronx_distributed_tpu.ops.power_retention import phi as _phi
+
+    return _phi(u, dtype)
+
+
+class PowerRetentionMixer(nn.Module):
+    config: object
+
+    @nn.compact
+    def __call__(self, x, positions, kv_cache=None, cache_offset=0,
+                 kv_valid=None, block_table=None, paged_kernel=False,
+                 state_rows=None):
+        from neuronx_distributed_tpu.models.llama import (
+            apply_rope,
+            rope_sin_cos,
+            row_validity,
+        )
+        from neuronx_distributed_tpu.ops import power_retention as pr
+
+        cfg = self.config
+        NQ, NKV, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+        B, S = x.shape[0], x.shape[1]
+        f32 = jnp.float32
+        with jax.named_scope("retention_proj"):
+            q, k, v = GQAQKVColumnParallelLinear(
+                num_heads=NQ, num_kv_heads=NKV, head_dim=d,
+                use_bias=cfg.qkv_bias,
+                sequence_parallel=cfg.sequence_parallel, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, name="qkv")(x)
+            # the decay's logit leaves its matmul in float32: rounded to
+            # bfloat16 at ~5-9 it would move a fast head's decay by 3%
+            gate = ColumnParallelLinear(
+                features=NKV, use_bias=False,
+                sequence_parallel=cfg.sequence_parallel, dtype=jnp.float32,
+                param_dtype=cfg.param_dtype, name="gate")(x)
+        # the decay's own scalar a head stays float32 whatever the weights
+        bias = jnp.asarray(self.param(
+            "gate_bias", nn.with_partitioning(_retention_gate_bias, (None,)),
+            (NKV,), f32))
+        with jax.named_scope("retention_norm"):
+            norm = lambda name: RMSNorm(  # noqa: E731
+                eps=cfg.rms_eps, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, name=name)
+            sin, cos = rope_sin_cos(positions, d, cfg.rope_theta,
+                                    cfg.rope_scaling_)
+            q = apply_rope(norm("q_norm")(q), sin, cos)
+            k = apply_rope(norm("k_norm")(k), sin, cos)
+            lg = _log_decay(gate, bias)                        # [B, S, NKV]
+        live = row_validity(kv_valid, cache_offset, S, kv_cache is not None)
+        new_cache = None
+        if kv_cache is None:
+            o, _, _ = pr.power_retention(
+                q, k, v, lg, live,
+                jnp.zeros((B, NKV, d, pr.phi_dim(d)), f32),
+                jnp.zeros((B, NKV, d, d), f32))
+        else:
+            if state_rows is None:
+                raise ValueError(
+                    "a recurrent layer's cached call needs state_rows: "
+                    "which row of the state arrays each batch row continues")
+            states, zs = kv_cache
+            # a call that holds position 0 begins its sequence
+            fresh = _fresh(positions, live)
+            if S == 1:
+                o, new_cache = self._step(q, k, v, lg, live, fresh, states,
+                                          zs, state_rows, paged_kernel)
+            else:
+                o, states, zs = pr.retention_chunk(
+                    q, k, v, lg, live, fresh, states, zs, state_rows,
+                    kernel=paged_kernel)
+                new_cache = (states, zs)
+        with jax.named_scope("retention_proj"):
+            return RowParallelLinear(
+                features=cfg.hidden_size, use_bias=False,
+                sequence_parallel=cfg.sequence_parallel,
+                input_partition_axes=Q_HEAD_AXES, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, name="o_proj")(
+                o.reshape(B, S, NQ * d)), new_cache
+
+    @staticmethod
+    def _step(q, k, v, lg, live, fresh, states, zs, state_rows, kernel):
+        """One token a row (a decode): the state rows stepped where they
+        lie, by row id; the small normaliser as a gather and a scatter."""
+        from neuronx_distributed_tpu.ops import power_retention as pr
+
+        B, _, NQ, d = q.shape
+        NKV = k.shape[2]
+        f32 = jnp.float32
+        hi = jax.lax.Precision.HIGHEST
+        with jax.named_scope("retention_step"):
+            m = (jnp.ones((B,), f32) if live is None
+                 else live[:, 0].astype(f32))
+            qf = q[:, 0].astype(f32).reshape(B, NKV, NQ // NKV, d)
+            kf, vf = k[:, 0].astype(f32), v[:, 0].astype(f32)
+            g = jnp.exp(lg[:, 0] * m[:, None])                 # [B, NKV]
+            keep = jnp.where(fresh[:, None], 0.0, g)
+            z = zs[state_rows] * keep[..., None, None] \
+                + (kf * m[:, None, None])[..., :, None] * kf[..., None, :]
+            zs = zs.at[state_rows].set(z)
+            den = jnp.einsum("bkgi,bkij,bkgj->bkg", qf, z, qf, precision=hi)
+        states, num = pr.retention_step(
+            states, state_rows, keep, pr.phi(kf) * m[:, None, None],
+            pr.phi(qf), vf, kernel=kernel)
+        with jax.named_scope("retention_step"):
+            o = pr._normalise(num, den, d)
+        return o.reshape(B, 1, NQ, d).astype(q.dtype), (states, zs)
+
+
 class ConvMixer(nn.Module):
     """LFM2's gated short convolution (module docstring).  The gates and
     the taps' sum are float32, each rounded once to the activations' dtype."""
@@ -621,6 +771,8 @@ def hybrid_mixer(cfg, kind: str):
         return MLAMixer(cfg, name="attn")
     if kind == "mamba2":
         return Mamba2Mixer(cfg, name="attn")
+    if kind == "power-retention":
+        return PowerRetentionMixer(cfg, name="attn")
     if kind == "lightning-attn":
         return LightningMixer(cfg, name="attn")
     if kind == "minicpm4":

@@ -184,7 +184,8 @@ class LlamaConfig:
     # (block-sparse softmax attention, no RoPE, output gate),
     # "lightning-attn" (decayed linear attention with a recurrent state),
     # "mamba2" (the Mamba-2 selective scan, a scan state and a convolution
-    # state) — models/hybrid.py — or "none": the layer has no mixer.  None:
+    # state), "power-retention" (Brumby's degree-2 power retention, a state
+    # row of two arrays) — models/hybrid.py — or "none": no mixer.  None:
     # every layer is "attention".
     mixer_types: Optional[Tuple[str, ...]] = None
     # its feed-forward parts, likewise: "mlp", "moe" (num_experts > 1) or
@@ -284,7 +285,8 @@ class LlamaConfig:
                 raise ValueError(
                     f"mixer_types names one of {MIXERS} for each of the "
                     f"{self.num_layers} layers, got {self.mixer_types}")
-            if {"lightning-attn", "mamba2"} <= set(self.mixer_types):
+            if len({"lightning-attn", "mamba2", "power-retention"}
+                   & set(self.mixer_types)) > 1:
                 raise ValueError(
                     "one kind of recurrent layer a model: a state row is "
                     "one tuple of arrays")

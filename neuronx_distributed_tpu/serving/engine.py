@@ -658,6 +658,14 @@ class ServingEngine:
         self._sparse_spec = getattr(mcfg, "selection_spec", None)
         # Mamba-2 layers: a decode steps one state row a live slot a layer
         self._ssm = "mamba2" in (getattr(mcfg, "mixer_types", None) or ())
+        # power-retention layers: their tokens are counted by the program
+        # that ran them, and a model of such layers ALONE keeps no page —
+        # it is admitted, finished and freed by its state row
+        self._retention = "power-retention" in (
+            getattr(mcfg, "mixer_types", None) or ())
+        self._pageless = self._recurrent and not any(
+            c in ("pages", "selected_pages", "latent")
+            for c in getattr(mcfg, "layer_caches", None) or ("pages",))
         # latent layers (MLA) keep pages of ONE latent row a token: pages
         # as any other to the allocator and the prefix index, which STAYS
         # on (a shared page holds the same rows whoever wrote it), but the
@@ -680,10 +688,15 @@ class ServingEngine:
                 ("tensor parallelism (tp > 1)",
                  model_parallel_is_initialized()
                  and get_mesh().shape[TENSOR_AXIS] > 1)) if on]
+            if self._pageless and prefix_cache:
+                refused.append("the prefix index (prefix_cache=True): a "
+                               "model that keeps no page has no chain to "
+                               "share")
             if refused:
                 raise ValueError(
-                    "not carried through recurrent, page-selecting or "
-                    "latent layers yet: " + "; ".join(refused))
+                    "not carried through recurrent (lightning-attn, mamba2, "
+                    "power-retention), page-selecting or latent layers yet: "
+                    + "; ".join(refused))
             if self._recurrent or self._sparse_spec is not None:
                 prefix_cache = False
         # pages by layer KIND (kvcache.pool.page_kinds): layers of one
@@ -824,7 +837,7 @@ class ServingEngine:
             registry=self.registry, prefix_cache=prefix_cache,
             spec_overshoot=self._spec_k, state_rows=self._recurrent,
             kinds=self._page_kinds, chunk_tokens=self._chunk_tokens,
-            free_behind=free_behind)
+            free_behind=free_behind, pageless=self._pageless)
         num_pages = self._kv.num_pages
         self._pages_freed = self._kv.frees
         self._chunking: dict = {}   # slot -> _ChunkPrefill in progress
@@ -852,7 +865,8 @@ class ServingEngine:
         # bytes ONE gather-path step spends on the contiguous clone: k + v,
         # every layer, the full padded [B, T] view in the compute dtype
         # (an int8 pool dequantizes into the same-sized fp clone)
-        self._gather_bytes_step = (
+        # (nothing for a model that keeps no page: there is no K/V to clone)
+        self._gather_bytes_step = 0 if self._pageless else (
             getattr(model, "num_layers", 0) * 2 * self.B * self.T
             * getattr(model, "num_kv_heads", 0) * getattr(model, "head_dim", 0)
             * jnp.dtype(cfg.kv_cache_dtype).itemsize)
@@ -935,6 +949,14 @@ class ServingEngine:
         # (of the kinds the page gate counts: ``PagedKVManager.gating``)
         self._page_bytes = sum(
             pool.page_bytes_by_kind[k] for k in self._kv.gating)
+        if self._recurrent:
+            # what the recurrent layers' state rows hold of the device,
+            # whatever the slots are doing
+            self.registry.gauge("kvcache/state_bytes").set(pool.state_bytes)
+        if self._retention:
+            for family in ("chunk", "step"):
+                self.registry.counter(
+                    f"serving/retention_tokens_total/{family}")
         logger.info(
             "serving: paged KV pool: %s pages x %d tokens%s "
             "(%.1f MiB; [B=%d, T=%d] rows would be %.1f MiB)",
@@ -1508,6 +1530,8 @@ class ServingEngine:
         self._count_latents("decode_pages", int(lens.sum()), len(active))
         with self._phase("dispatch", active=len(active),
                          ctx_tokens=int(lens.sum()) - len(active),
+                         **({"state_rows": len(active)}
+                            if self._recurrent else {}),
                          **self._window_tokens(lens, 1),
                          **self._count_selection("decode_pages", lens - 1,
                                                  lens)):
@@ -1921,6 +1945,12 @@ class ServingEngine:
                 # ctx_tokens: the keys the chunk's last row attends — its
                 # end in the left-padded row less the pad
                 ctx = off + n_pages * page - (self.C - req.prompt_len)
+                # the chunk's own tokens: its rows less a first page's pads
+                chunk_tokens = ctx - max(off - (self.C - req.prompt_len), 0)
+                if self._retention:
+                    self.registry.counter(
+                        "serving/retention_tokens_total/chunk").inc(
+                        chunk_tokens)
                 self._account.chunk = n_pages * page
                 self._count_latents(
                     "prefill_chunk_pages", ctx,
@@ -1928,6 +1958,7 @@ class ServingEngine:
                 with self._phase(
                         "prefill_chunk", request_id=req.request_id,
                         tok_start=off, width=n_pages * page, ctx_tokens=ctx,
+                        chunk_tokens=chunk_tokens,
                         **self._window_tokens([ctx], n_pages * page),
                         **self._count_selection(
                             "prefill_chunk_pages",
@@ -2100,7 +2131,7 @@ class ServingEngine:
         pages; a hybrid model's global layers walk the whole band, this is
         its windowed layers') beside the pages its block tables could hold
         (``serving/paged_pages_tabled_total``: slots x pages a slot)."""
-        if not self._paged_kernel:
+        if not self._paged_kernel or self._pageless:
             return
         page = self._kv.page_size
         rows = self._spec_k + 1 if self._spec_k else 1
@@ -2179,6 +2210,8 @@ class ServingEngine:
         K/V rows (``serving/kv_rows_written_total``) and the pool pages they
         land in (``serving/kv_pages_touched_total`` — what the page-granular
         writer, ``ops.kv_pool_write``, reads and writes back)."""
+        if self._pageless:
+            return      # no layer keeps a page: nothing is committed to one
         self.registry.counter("serving/kv_rows_written_total").inc(rows)
         self.registry.counter("serving/kv_pages_touched_total").inc(pages)
 
@@ -2427,6 +2460,9 @@ class ServingEngine:
         if self._ssm:
             self.registry.counter(
                 "serving/ssm_state_rows_stepped_total").inc(len(active))
+        if self._retention:
+            self.registry.counter(
+                "serving/retention_tokens_total/step").inc(len(active))
         if self._kv_quant is not None:
             # every active slot's decode write requantized its page
             self.registry.counter(QUANT_PAGES_TOTAL).inc(len(active))

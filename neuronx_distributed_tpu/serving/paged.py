@@ -120,7 +120,12 @@ class PagedKVManager:
                  state_rows: bool = False,
                  kinds: Optional[PageKinds] = None,
                  chunk_tokens: Optional[int] = None,
-                 free_behind: bool = True):
+                 free_behind: bool = True, pageless: bool = False):
+        if pageless and (prefix_cache or not state_rows):
+            raise ValueError(
+                "a model that keeps no page has state rows and no prefix "
+                "index (there is no chain to share)")
+        self.pageless = pageless
         if context_len % page_size != 0 or max_total_len % page_size != 0:
             raise ValueError(
                 f"page_size {page_size} must divide context_len "
@@ -147,6 +152,10 @@ class PagedKVManager:
             max_total_len,
             context_len if chunk_tokens is None else chunk_tokens, page_size
         ) if free_behind else (None,) * K
+        if pageless:
+            free_behind = False
+        if num_pages is None and pageless:
+            num_pages = 2       # the NULL page and one nobody takes
         if num_pages is None:
             num_pages = tuple(
                 num_slots * (self.pages_per_slot if cap is None else cap) + 1
@@ -258,11 +267,15 @@ class PagedKVManager:
         return whole if cap is None else min(whole, cap)
 
     def _row_pages(self, req: Request) -> int:
+        if self.pageless:
+            return 0
         L = min(req.prompt_len, self.C)
         n_ctx = self.ctx_pages - (self.C - L) // self.page_size
         return n_ctx + self._decode_pages_needed(req)
 
     def _decode_pages_needed(self, req: Request) -> int:
+        if self.pageless:
+            return 0
         return math.ceil(
             (req.max_new_tokens + self.spec_overshoot) / self.page_size)
 
@@ -337,8 +350,8 @@ class PagedKVManager:
             matched, payload = self.index.lookup(keys)
         # refs we now hold, (kind, page): the index is the first kind's
         taken = [(0, p) for p in matched if p != NULL_PAGE]
-        keeping = [k for k, cap in enumerate(self.window_pages)
-                   if cap is None]
+        keeping = [] if self.pageless else [
+            k for k, cap in enumerate(self.window_pages) if cap is None]
         try:
             table = np.full(self._tables.shape[::2], NULL_PAGE, np.int32)
             for lp, p in enumerate(matched):
@@ -365,6 +378,7 @@ class PagedKVManager:
             if not keeping:
                 # every kind gives pages back: the chunk loop walks the
                 # same logical run, its pages taken as its writes reach them
+                # (none at all for a model that keeps no page)
                 fresh = [(lp, NULL_PAGE) for lp in todo]
             # chaos hook: a crash between the prompt-page and decode-page
             # allocations must leak nothing (tests/test_kvcache.py)
@@ -657,7 +671,10 @@ class PagedKVManager:
                 "chain carries no state")
             for slot, rid in enumerate(self.state_rows):
                 # a state row is held exactly while its slot holds pages
-                assert (rid is not None) == bool(self._slot_pages[slot]), (
+                # (its admission's keys where the model keeps no page)
+                held = (self._slot_keys[slot] is not None if self.pageless
+                        else bool(self._slot_pages[slot]))
+                assert (rid is not None) == held, (
                     f"slot {slot}: state row held by {rid}, pages "
                     f"{len(self._slot_pages[slot])}")
             live = [r for r in self.state_rows if r is not None]

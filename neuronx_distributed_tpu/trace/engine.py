@@ -1200,7 +1200,8 @@ class ParallelInferenceModel(_ServingBase):
             if lora or not last_only:
                 raise ValueError(
                     "LoRA pages and speculative verification are not "
-                    "carried through the recurrent (lightning-attn, mamba2) "
+                    "carried through the recurrent (lightning-attn, mamba2, "
+                    "power-retention) "
                     "layers")
             if state_rows is None:
                 if int(toks.shape[0]) != self.config.batch_size:

@@ -679,3 +679,76 @@ def test_qkv_gather_rides_under_a_matmul_for_v5e(topo, monkeypatch, form):
         assert any(n > 0 for n in over.values()), over
     else:
         assert not over, over
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_s512"])
+def test_retention_programs_compile_for_v5e_and_step_the_state_in_place(
+        topo, program):
+    """Brumby-14B's paged programs at its PUBLISHED widths and the cell's
+    serving geometry (16 slots of 17,408 tokens, a 512-row chunk), cut to
+    one layer: the decode steps 16 state rows of 36 MiB by row id in the
+    Mosaic call ``retention_step``, the chunk continues one in
+    ``retention_chunk``; the state arrays are donated, aliased to their
+    outputs and never copied — a second copy of the cell's 4.5 GiB of state
+    would not fit the chip."""
+    import functools
+    import re
+
+    from neuronx_distributed_tpu.kvcache.pool import LayerStates
+    from neuronx_distributed_tpu.models.llama import (
+        LlamaConfig,
+        LlamaForCausalLM,
+    )
+    from neuronx_distributed_tpu.trace import (
+        InferenceConfig,
+        ParallelInferenceModel,
+    )
+    from flax import linen as nn
+
+    mesh = _mesh(topo)
+    B, T, page, W = 16, 17408, 64, 512
+    cfg = LlamaConfig(
+        vocab_size=151936, hidden_size=5120, intermediate_size=17408,
+        num_layers=1, num_heads=40, num_kv_heads=8, head_dim=128,
+        max_seq_len=T, rope_theta=1e6, rms_eps=1e-6,
+        mixer_types=("power-retention",), sequence_parallel=False,
+        remat="none", dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    module = LlamaForCausalLM(cfg)
+    rep = NamedSharding(mesh, P())
+    boxed = jax.eval_shape(module.init, jax.random.PRNGKey(0),
+                           jnp.zeros((1, page), jnp.int32))
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep),
+        nn.unbox(boxed))
+    model = ParallelInferenceModel(
+        module, params,
+        InferenceConfig(batch_size=B, context_len=16384, max_total_len=T,
+                        kv_cache_dtype=jnp.bfloat16))
+    layers = LayerStates.for_config(cfg, page, state_rows=B)
+    assert layers.paged == 0 and layers.state_shape == (8, 128, 9216)
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    caches = (tuple(sds((B,) + shape, jnp.dtype(dt))
+                    for shape, dt in layers.state_arrays),)
+    decode = program == "decode"
+    rows, S = (B, 1) if decode else (1, W)
+    fn = jax.jit(functools.partial(
+        model._paged_step_fn, paged_kernel=True, update_valid=decode,
+        last_only=True), donate_argnums=(4,))
+    compiled = fn.lower(
+        params, sds((rows, S)), sds((rows,)), sds((rows, T // page)), caches,
+        sds((rows, T)), state_rows=sds((rows,)),
+        **({} if decode else {"last_row": sds(())})).compile()
+    text = compiled.as_text()
+    assert ("%retention_step" if decode else "%retention_chunk") in text
+    copied = [ln.strip()[:120] for ln in text.splitlines()
+              if re.search(r"= f32\[16,8,128,9216\]\S* (copy|transpose)\(",
+                           ln)]
+    assert not copied, f"the state array is copied: {copied}"
+    memory = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(caches))
+    assert memory.alias_size_in_bytes >= held
+    # ... and beside the one copy the program keeps well under a state row
+    assert memory.temp_size_in_bytes < 8 * 128 * 9216 * 4 * 8
