@@ -561,8 +561,8 @@ class PowerRetentionMixer(nn.Module):
             zs = zs.at[state_rows].set(z)
             den = jnp.einsum("bkgi,bkij,bkgj->bkg", qf, z, qf, precision=hi)
         states, num = pr.retention_step(
-            states, state_rows, keep, pr.phi(kf) * m[:, None, None],
-            pr.phi(qf), vf, kernel=kernel)
+            states, state_rows, keep, kf * m[:, None, None], qf, vf,
+            kernel=kernel)
         with jax.named_scope("retention_step"):
             o = pr._normalise(num, den, d)
         return o.reshape(B, 1, NQ, d).astype(q.dtype), (states, zs)

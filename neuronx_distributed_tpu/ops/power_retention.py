@@ -52,12 +52,21 @@ Two forms:
   the rows are sliced out, continued by :func:`power_retention` and written
   back where they lay.
 - :func:`retention_step` — one row a slot (a decode): the state rows are
-  stepped IN PLACE by row id.  A Pallas call named ``retention_step`` where
-  the paged kernels run (the state array stays in HBM, aliased input to
-  output; the row ids are scalar-prefetched; a program reads a ``[d, D /
-  n]`` block of one head's state, scales it, adds ``v phi(k)^T``, writes it
-  back and accumulates ``S phi(q)``), else the same arithmetic as a gather,
-  a step and a scatter.  Scope ``retention_step``.
+  stepped IN PLACE by row id, from the token's own ``q``, ``k``, ``v``.  A
+  Pallas call named ``retention_step`` where the paged kernels run (the
+  state array stays in HBM, aliased input to output; the row ids are
+  scalar-prefetched; a head's first program lays ``q`` and ``k`` out as
+  :func:`_column_operands` does — a 0/1 matmul, exact — and forms every
+  column of ``phi(q)`` and ``phi(k)`` in VMEM with :func:`_column`, the
+  chunk kernel's helper; each program reads a ``[d, D / n]`` block of the
+  head's state, scales it, adds ``v phi(k)^T``, writes it back and
+  accumulates ``S phi(q)``), else the same arithmetic as a gather,
+  :func:`phi`, a step and a scatter.  No ``[.., D]`` array but the state
+  exists in a decode.  Scope ``retention_step``.  The call runs at the
+  speed of its two copies (81% of a v5e's HBM peak read + written; one
+  stream alone reads 92%): ``tools/retention_step_probe.py`` knocks the
+  read out, changes the blocking and moves the read to the vector unit,
+  and none of them moves it (PERF.md §6, PR 54).
 """
 
 from __future__ import annotations
@@ -129,6 +138,16 @@ def _column_operands(u):
     lay = jnp.tile(u.reshape(*u.shape[:-1], d // TILE, 1, TILE),
                    (1,) * (u.ndim - 1) + (1, 8, 1))
     return jnp.repeat(u, TILE, axis=-1), lay.reshape(*u.shape[:-1], 8 * d)
+
+
+def _column(rep, lay, col, dtype):
+    """One 128-lane column of ``phi`` from the two operands (arrays, or
+    what a kernel read of its refs), ``col`` an entry of :func:`_columns`:
+    the product in float32, rounded once to ``dtype``."""
+    a, r, w = col
+    f32 = jnp.float32
+    return (rep[..., 128 * r:128 * (r + 1)].astype(f32)
+            * lay[..., 128 * a:128 * (a + 1)].astype(f32) * w).astype(dtype)
 
 
 def _normalise(num, den, d: int):
@@ -275,26 +294,20 @@ def _chunk_kernel(rows_ref, s_in, ku_ref, qrep, qlay, krep, klay, vwt, s_out,
     one HBM buffer (aliased); a column of ``phi`` lives in VMEM only."""
     del rows_ref                       # the index maps read it
     f32 = jnp.float32
-
-    def column(rep, lay, a, r, w):
-        return (rep[:, 128 * r:128 * (r + 1)].astype(f32)
-                * lay[:, 128 * a:128 * (a + 1)].astype(f32)
-                * w).astype(op_dtype)
-
     num_ref[0, 0, 0] = jnp.zeros(num_ref.shape[3:], f32)
-    for c, (a, r, w) in enumerate(cols):
+    for c, col in enumerate(cols):
         num_ref[0, 0, 0] += jax.lax.dot_general(
-            column(qrep[0, 0, 0], qlay[0, 0, 0], a, r, w),
+            _column(qrep[0, 0, 0], qlay[0, 0, 0], col, op_dtype),
             s_in[0, 0, :, 128 * c:128 * (c + 1)].astype(op_dtype),
             (((1,), (1,)), ((), ())), preferred_element_type=f32)
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         ku = ku_ref[0, 0][:, :1]                               # [1, 1]
-        for c, (a, r, w) in enumerate(cols):
+        for c, col in enumerate(cols):
             lanes = slice(128 * c, 128 * (c + 1))
             s_out[0, 0, :, lanes] = s_in[0, 0, :, lanes] * ku + jnp.dot(
-                vwt[0, 0], column(krep[0, 0], klay[0, 0], a, r, w),
+                vwt[0, 0], _column(krep[0, 0], klay[0, 0], col, op_dtype),
                 preferred_element_type=f32)
 
 
@@ -419,19 +432,50 @@ def _step_block(d: int, D: int) -> int:
     return best
 
 
-def _step_kernel(rows_ref, s_in, keep_ref, pk_ref, pq_ref, v_ref, s_out,
-                 o_ref):
+@functools.lru_cache(maxsize=None)
+def _expansion(d: int) -> np.ndarray:
+    """``E [d, 24 d]`` of zeros and ones with ``u @ E`` = the two operands
+    of :func:`_column_operands` side by side (``rep`` then ``lay``): every
+    entry of the product is ONE entry of ``u``, so a float32 matmul at
+    ``HIGHEST`` (whose three-way split of ``u`` adds back to ``u``) is
+    exact."""
+    eye = np.eye(d, dtype=np.float32)
+    lay = np.tile(eye.reshape(d, d // TILE, 1, TILE), (1, 1, 8, 1))
+    return np.concatenate([np.repeat(eye, TILE, axis=1),
+                           lay.reshape(d, 8 * d)], axis=1)
+
+
+def _step_kernel(rows_ref, s_in, keep_ref, u_ref, e_ref, v_ref, s_out, o_ref,
+                 phi_scr, v_scr, *, cols):
     """One program: a ``[d, blk]`` block of one head's state of one row.
-    ``s_in`` and ``s_out`` are blocks of the one HBM buffer (aliased)."""
+    ``s_in`` and ``s_out`` are blocks of the one HBM buffer (aliased).  A
+    head's first program forms the token's ``phi(q)`` (``G`` rows) and
+    ``phi(k)`` (the last row) a column at a time into VMEM, where they stay
+    for the head's other programs: ``phi`` is in no HBM buffer."""
     del rows_ref                       # the index maps read it
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
     j = pl.program_id(2)
+    G, d = o_ref.shape[2:]
+    per = s_in.shape[3] // 128         # columns of phi a block
+
+    @pl.when(j == 0)
+    def _():
+        both = jnp.dot(u_ref[0, 0], e_ref[...], precision=hi,
+                       preferred_element_type=f32)             # [G + 1, 24 d]
+        rep, lay = both[:, :16 * d], both[:, 16 * d:]
+        for c, col in enumerate(cols):
+            lanes = slice(128 * (c % per), 128 * (c % per + 1))
+            phi_scr[c // per, :, lanes] = _column(rep, lay, col, f32)
+        # v as a column: its row laid down the sublanes, turned
+        v_scr[...] = jnp.broadcast_to(v_ref[0, 0], (128, d)).T
+
     keep = keep_ref[0, 0][:, :1]                               # [1, 1]
-    s = s_in[0, 0] * keep + v_ref[0, 0][:, :1] * pk_ref[0, 0]   # [d, blk]
-    s_out[0, 0] = s
+    s = s_in[0, 0] * keep + v_scr[:, :1] * phi_scr[j, G:G + 1, :]
+    s_out[0, 0] = s                                            # [d, blk]
     part = jax.lax.dot_general(
-        pq_ref[0, 0], s, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST)                   # [G, d]
+        phi_scr[j, :G, :], s, (((1,), (1,)), ((), ())),
+        preferred_element_type=f32, precision=hi)              # [G, d]
 
     @pl.when(j == 0)
     def _():
@@ -442,77 +486,82 @@ def _step_kernel(rows_ref, s_in, keep_ref, pk_ref, pq_ref, v_ref, s_out,
         o_ref[0, 0] += part
 
 
-def _step_call(state, rows, keep, pk, pq, vcol, interpret):
+def _step_call(state, rows, keep, k, q, v, interpret):
     R, NKV, d, D = state.shape
-    B, _, G, _ = pq.shape
+    B, _, G, _ = q.shape
     blk = _step_block(d, D)
+    per_head = lambda b, h, j, rows: (b, h, 0, 0)  # noqa: E731
+    block = lambda b, h, j, rows: (rows[b], h, 0, j)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, NKV, D // blk),
         in_specs=[
-            pl.BlockSpec((1, 1, d, blk), lambda b, h, j, rows: (rows[b], h, 0, j)),
-            pl.BlockSpec((1, 1, 1, 128), lambda b, h, j, rows: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, 1, blk), lambda b, h, j, rows: (b, h, 0, j)),
-            pl.BlockSpec((1, 1, G, blk), lambda b, h, j, rows: (b, h, 0, j)),
-            pl.BlockSpec((1, 1, d, 128), lambda b, h, j, rows: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, d, blk), block),
+            pl.BlockSpec((1, 1, 1, 128), per_head),
+            pl.BlockSpec((1, 1, G + 1, d), per_head),
+            pl.BlockSpec((d, 24 * d), lambda b, h, j, rows: (0, 0)),
+            pl.BlockSpec((1, 1, 1, d), per_head),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, d, blk), lambda b, h, j, rows: (rows[b], h, 0, j)),
-            pl.BlockSpec((1, 1, G, d), lambda b, h, j, rows: (b, h, 0, 0)),
-        ],
+        out_specs=[pl.BlockSpec((1, 1, d, blk), block),
+                   pl.BlockSpec((1, 1, G, d), per_head)],
+        scratch_shapes=[pltpu.VMEM((D // blk, G + 1, blk), jnp.float32),
+                        pltpu.VMEM((d, 128), jnp.float32)],
     )
 
     def call(interp):
         return pl.pallas_call(
-            _step_kernel,
+            functools.partial(_step_kernel, cols=_columns(d)),
             grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
                        jax.ShapeDtypeStruct((B, NKV, G, d), jnp.float32)],
             # operands count the scalar-prefetched row ids: the state is 2nd
             input_output_aliases={1: 0},
-            # a row is one slot's; the last axis accumulates the read
+            # a row is one slot's; the last axis accumulates the read and
+            # finds the head's phi where its first program left it
             compiler_params=_compiler_params(
                 ("arbitrary", "arbitrary", "arbitrary"), interp),
             interpret=interp,
             name="retention_step",
         )
 
-    return run_kernel(call, interpret, rows, state, keep, pk, pq, vcol)
+    return run_kernel(
+        call, interpret, rows, state,
+        # the decay reaches the kernel laid along 128 lanes
+        jnp.broadcast_to(keep[..., None, None], (B, NKV, 1, 128)),
+        jnp.concatenate([q, k[:, :, None]], axis=2), _expansion(d),
+        v[:, :, None])
 
 
 @functools.partial(jax.jit, static_argnames=("kernel", "interpret"))
-def _retention_step_impl(state, rows, keep, pk, pq, v, kernel=False,
+def _retention_step_impl(state, rows, keep, k, q, v, kernel=False,
                          interpret=None):
     if kernel:
-        # a scalar and a column reach the kernel laid along 128 lanes
-        lanes = lambda a: jnp.broadcast_to(  # noqa: E731
-            a[..., None], a.shape + (128,))
-        return _step_call(state, rows, lanes(keep)[:, :, None],
-                          pk[:, :, None], pq, lanes(v), interpret)
+        return _step_call(state, rows, keep, k, q, v, interpret)
     s = state[rows] * keep[..., None, None] \
-        + v[..., :, None] * pk[..., None, :]
-    o = jnp.einsum("bkgr,bker->bkge", pq, s,
+        + v[..., :, None] * phi(k)[..., None, :]
+    o = jnp.einsum("bkgr,bker->bkge", phi(q), s,
                    precision=jax.lax.Precision.HIGHEST)
     return state.at[rows].set(s), o
 
 
 def retention_step(state: jax.Array, rows: jax.Array, keep: jax.Array,
-                   pk: jax.Array, pq: jax.Array, v: jax.Array, *,
+                   k: jax.Array, q: jax.Array, v: jax.Array, *,
                    kernel: bool = False, interpret: Optional[bool] = None):
     """One token a row: ``state[rows[b]] = keep[b] * state[rows[b]] + v[b]
     phi(k[b])^T`` and the read ``state[rows[b]] phi(q[b])`` of what that
     left.  ``state [R, NKV, d, D]`` float32; ``rows [B]`` distinct row ids;
     ``keep [B, NKV]`` the decay (1 for a row that is no token, 0 for one that
-    begins its sequence); ``pk [B, NKV, D]`` (zeros for a row that is no
-    token), ``pq [B, NKV, G, D]``, ``v [B, NKV, d]`` float32.  Returns
-    ``(state, num [B, NKV, G, d])``.  Every other row keeps its bits; given
-    the state donated, the step is in place.
+    begins its sequence); ``k [B, NKV, d]`` (zeros for a row that is no
+    token), ``q [B, NKV, G, d]``, ``v [B, NKV, d]``, the token's own, taken
+    as float32: ``phi`` of them is formed inside.  Returns ``(state, num [B,
+    NKV, G, d])``.  Every other row keeps its bits; given the state donated,
+    the step is in place.
 
     ``kernel`` takes the Pallas call (the caller's resolved
-    ``paged_kernel``), else the XLA form; ``interpret`` as in
-    ``ops.paged_attention``."""
+    ``paged_kernel``), else the XLA form — a gather, :func:`phi`, a step, a
+    scatter; ``interpret`` as in ``ops.paged_attention``."""
     with jax.named_scope("retention_step"):
         return _retention_step_impl(
             state, rows.astype(jnp.int32), keep.astype(jnp.float32),
-            pk.astype(jnp.float32), pq.astype(jnp.float32),
+            k.astype(jnp.float32), q.astype(jnp.float32),
             v.astype(jnp.float32), kernel=kernel, interpret=interpret)

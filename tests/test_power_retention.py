@@ -143,36 +143,95 @@ def _array(seed, R, NKV, d):
     return states, jnp.einsum("rkti,rktj->rkij", kk, kk)
 
 
-@pytest.mark.parametrize("d", [16, 32])
-def test_decode_step_is_in_place_by_row_id(d):
-    """One token a row: rows 2 and 0 of a four-row state array stepped by
-    the Pallas call (interpreted) and by the XLA form; rows 1 and 3 keep
-    their bits; the read is of what the step LEFT."""
-    NKV, G = 2, 2
-    states, _ = _array(d, 4, NKV, d)
-    rs = np.random.RandomState(1)
-    rows = jnp.asarray([2, 0])
-    keep = jnp.asarray(rs.uniform(0.5, 1.0, (2, NKV)), jnp.float32)
-    k, v = (jnp.asarray(rs.randn(2, NKV, d), jnp.float32) for _ in range(2))
-    q = jnp.asarray(rs.randn(2, NKV, G, d), jnp.float32)
-    got = {kernel: pr.retention_step(states, rows, keep, pr.phi(k), pr.phi(q),
-                                     v, kernel=kernel)
-           for kernel in (False, True)}
-    want = np.asarray(states)[[2, 0]] * np.asarray(keep)[..., None, None] \
-        + np.asarray(v)[..., :, None] * np.asarray(pr.phi(k))[..., None, :]
+@pytest.mark.parametrize("d,G", [(16, 1), (16, 2), (16, 5), (32, 1), (32, 2),
+                                 (32, 5), (128, 5)])
+def test_decode_step_is_in_place_by_row_id(d, G):
+    """One token a row, handed over as the token's own ``q``, ``k``, ``v``:
+    of a five-row state array row 2 carries on, row 0 begins its sequence
+    (keep 0: whatever it held is gone) and row 4 is parked (no token: keep
+    1, ``k`` zero); rows 1 and 3 are not named.  The Pallas call
+    (interpreted; ``phi`` formed inside, 5 query heads a group is the
+    cell's and no power of two) and the XLA form against the token
+    recurrence; the read is of what the step LEFT.  At d 128, the cell's,
+    one row of two."""
+    B, R, NKV = (1, 2, 1) if d == 128 else (3, 5, 2)
+    rows = jnp.asarray([2, 0, 4][:B]) % R
+    states, zs = _array(d, R, NKV, d)
+    q, k, v, lg = _inputs(G, B, 1, NKV * G, NKV, d)
+    live = np.asarray([1, 1, 0][:B])
+    fresh = np.asarray([False, True, False][:B])
+    m = jnp.asarray(live, jnp.float32)
+    keep = jnp.where(fresh[:, None], 0.0, jnp.exp(lg[:, 0] * m[:, None]))
+    qg = q[:, 0].reshape(B, NKV, G, d)
+    got = {kernel: pr.retention_step(
+        states, rows, keep, k[:, 0] * m[:, None, None], qg, v[:, 0],
+        kernel=kernel) for kernel in (False, True)}
+    begins = fresh[:, None, None, None]
+    o_ref, s_ref, z_ref = pr.retention_scan_reference(
+        q, k, v, lg, live[:, None], jnp.where(begins, 0.0, states[rows]),
+        jnp.where(begins, 0.0, zs[rows]))
+    den = np.einsum("bkgi,bkij,bkgj->bkg", qg, z_ref, qg)
+    others = [r for r in range(R) if r not in np.asarray(rows)]
     for kernel, (st, num) in got.items():
-        np.testing.assert_allclose(np.asarray(st)[[2, 0]], want, rtol=1e-6,
-                                   atol=1e-6)
-        np.testing.assert_array_equal(np.asarray(st)[[1, 3]],
-                                      np.asarray(states)[[1, 3]])
+        np.testing.assert_allclose(np.asarray(st)[rows], s_ref, rtol=1e-6,
+                                   atol=1e-6, err_msg=f"kernel={kernel}")
+        np.testing.assert_array_equal(np.asarray(st)[others],
+                                      np.asarray(states)[others])
+        # the parked row keeps its BITS
+        np.testing.assert_array_equal(np.asarray(st)[rows][live == 0],
+                                      np.asarray(states)[rows][live == 0])
+        want = np.einsum("bkgr,bker->bkge", np.asarray(pr.phi(qg), np.float64),
+                         np.asarray(s_ref, np.float64))
+        np.testing.assert_allclose(num, want, rtol=1e-5,
+                                   atol=1e-5 * np.abs(want).max())
+        o = np.asarray(pr._normalise(num, den, d)).reshape(B, 1, NKV * G, d)
+        np.testing.assert_allclose(o[live > 0], np.asarray(o_ref)[live > 0],
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_both_kernels_form_phi_by_the_one_column_helper(monkeypatch):
+    """The step's and the chunk's Pallas calls take a column of ``phi`` from
+    the SAME function, ``_column`` — which is ``phi()``'s, column by column —
+    and the step's 0/1 matrix lays ``u`` out as ``_column_operands`` does,
+    entry for entry."""
+    d, NKV, G = 32, 1, 2
+    u = jnp.asarray(np.random.RandomState(0).randn(3, d), jnp.float32)
+    rep, lay = pr._column_operands(u)
+    for c, col in enumerate(pr._columns(d)):
         np.testing.assert_allclose(
-            num, np.einsum("bkgr,bker->bkge", np.asarray(pr.phi(q)), want),
-            rtol=1e-4, atol=1e-4)
-    # a row that is no token: decay 1, phi(k) zero — its bits stay
-    st, _ = pr.retention_step(states, rows, jnp.ones((2, NKV)),
-                              jnp.zeros_like(pr.phi(k)), pr.phi(q), v,
-                              kernel=True)
-    np.testing.assert_array_equal(st, states)
+            pr._column(rep, lay, col, jnp.float32),
+            pr.phi(u)[:, 128 * c:128 * (c + 1)], rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(u) @ pr._expansion(d),
+                                  np.concatenate([rep, lay], axis=-1))
+    seen, column = [], pr._column
+
+    def counted(rep, lay, col, dtype):
+        seen.append(jnp.dtype(dtype))
+        return column(rep, lay, col, dtype)
+
+    monkeypatch.setattr(pr, "_column", counted)
+    states, zs = _array(1, 2, NKV, d)
+    q, k, v, lg = _inputs(3, 1, 8, NKV * G, NKV, d)
+    ids = jnp.asarray([1])
+    # the ops' own jits keep what they traced: a patch is seen by a new trace
+    for fn in (pr._retention_chunk_impl, pr._retention_step_impl):
+        fn.clear_cache()
+    try:
+        pr.retention_step(states, ids, jnp.ones((1, NKV)), k[:, 0],
+                          q[:, 0].reshape(1, NKV, G, d), v[:, 0], kernel=True)
+        step = len(seen)
+        pr.retention_chunk(q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
+                           v.astype(jnp.bfloat16), lg, None,
+                           jnp.asarray([False]), states, zs, ids, kernel=True)
+    finally:
+        for fn in (pr._retention_chunk_impl, pr._retention_step_impl):
+            fn.clear_cache()
+    # whole passes over the columns (a traced branch may be traced again)
+    cols = len(pr._columns(d))
+    assert step and step % cols == 0
+    assert len(seen) > step and (len(seen) - step) % cols == 0
+    assert set(seen[:step]) == {jnp.dtype(jnp.float32)}
+    assert set(seen[step:]) == {jnp.dtype(jnp.bfloat16)}
 
 
 @pytest.mark.parametrize("d", [16, 32])
@@ -224,3 +283,28 @@ def test_the_step_of_a_call_of_many_rows_takes_the_blocks_path():
     o2, s2, _ = pr.retention_chunk(*args, kernel=False)
     np.testing.assert_array_equal(o1, o2)
     np.testing.assert_array_equal(s1, s2)
+
+
+def test_retention_step_probe_prints_a_line_a_variant():
+    """`tools/retention_step_probe.py --cpu --tiny`: the library's call and
+    the tool's knock-outs (the step as it stood with ``phi`` in HBM, the read
+    on the MXU, knocked out, on the vector unit; one stream alone) through
+    the interpreter, each a line with no device number off the chip and, where
+    there is a read, the XLA form's state and read."""
+    import json
+
+    from conftest import run_cli
+
+    proc = run_cli("tools/retention_step_probe.py", "--cpu", "--tiny")
+    rows = [json.loads(ln) for ln in proc.stdout.splitlines()
+            if ln.startswith("{")]
+    assert [r["variant"].split(":")[0].split("@")[0] for r in rows] == [
+        "mxu", "none", "load", "store", "turns", "vpu", "library", "library"]
+    for r in rows:
+        assert "error" not in r, r
+        assert r["kernel_us"] is None and "gb_per_s" not in r
+        if r["variant"].startswith(("load", "store")):
+            continue
+        assert r["state_rel"] < 1e-6
+        assert r["variant"].startswith(("none", "turns")) or r["read_rel"] < 1e-5
+    assert rows[-2]["block"] == [32, 768] and rows[-1]["block"] == [32, 384]

@@ -687,10 +687,11 @@ def test_retention_programs_compile_for_v5e_and_step_the_state_in_place(
     """Brumby-14B's paged programs at its PUBLISHED widths and the cell's
     serving geometry (16 slots of 17,408 tokens, a 512-row chunk), cut to
     one layer: the decode steps 16 state rows of 36 MiB by row id in the
-    Mosaic call ``retention_step``, the chunk continues one in
-    ``retention_chunk``; the state arrays are donated, aliased to their
-    outputs and never copied — a second copy of the cell's 4.5 GiB of state
-    would not fit the chip."""
+    Mosaic call ``retention_step`` (which takes the token's own q, k, v:
+    the decode program holds no ``[.., 9216]`` array but the state), the
+    chunk continues one in ``retention_chunk``; the state arrays are
+    donated, aliased to their outputs and never copied — a second copy of
+    the cell's 4.5 GiB of state would not fit the chip."""
     import functools
     import re
 
@@ -747,6 +748,12 @@ def test_retention_programs_compile_for_v5e_and_step_the_state_in_place(
               if re.search(r"= f32\[16,8,128,9216\]\S* (copy|transpose)\(",
                            ln)]
     assert not copied, f"the state array is copied: {copied}"
+    if decode:
+        # the token's phi(q) and phi(k) are formed inside the call, a column
+        # at a time in VMEM: in no HBM buffer (PR 54)
+        formed = [ln.strip()[:120] for ln in text.splitlines()
+                  if re.search(r"f32\[16,8,(5,)?9216\]", ln)]
+        assert not formed, f"phi is a value of the decode program: {formed}"
     memory = compiled.memory_analysis()
     held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(caches))
     assert memory.alias_size_in_bytes >= held
