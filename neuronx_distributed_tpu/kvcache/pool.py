@@ -8,7 +8,10 @@ preallocated HEAD-MAJOR ``[num_pages, kv_heads, page_size, head_dim]`` pair
 per layer (one page of one kv head is a whole ``(page, head_dim)`` trailing
 slab — the block the paged-attention kernel DMAs, ``ops.paged_attention``),
 and requests hold integer *block tables* mapping their logical cache pages
-to physical pages.  Left-padding pages and unwritten decode tail pages
+to physical pages.  Heads of HALF a lane row (``head_dim`` 64) are kept two
+to a row, ``[num_pages, kv_heads / 2, page_size, 128]`` (:func:`page_layout`):
+the device lays a minor dimension out on 128 lanes, so a 64-wide row would
+take twice its bytes there.  Left-padding pages and unwritten decode tail pages
 back onto the shared NULL page (index 0, content never written), and prompt
 pages shared through the :class:`~.prefix.PrefixIndex` exist once.
 
@@ -48,6 +51,36 @@ logger = get_logger(__name__)
 # ZERO on the kernel path (the int8 acceptance gate: quantized serving
 # with the kernel never materializes a dequantized history).
 GATHER_BYTES_TOTAL = "kvcache/gather_bytes_total"
+
+
+# lanes of the device's tiles: an array's minor dimension is laid out on
+# whole lane rows, its second minor on whole sublane tiles of its dtype
+LANES = 128
+_SUBLANES = 8
+
+
+def page_layout(num_kv_heads: int, head_dim: int) -> Tuple[int, int]:
+    """``(heads, width)`` of a K/V page as the pool keeps it, from the
+    shapes alone.  A head of half a lane row shares its row with its
+    neighbour — kv heads ``2j`` and ``2j + 1`` are lanes ``[0, D)`` and
+    ``[D, 2D)`` of pool head ``j``, which is the plain reshape of ``[...,
+    NKV, D]`` to ``[..., NKV / 2, 2D]`` — so that a page costs the bytes of
+    what it holds.  Every other shape, an odd head count included, is kept
+    as it is (a 64-wide head alone in its row is laid out on 128 lanes)."""
+    if 2 * head_dim == LANES and num_kv_heads % 2 == 0:
+        return num_kv_heads // 2, LANES
+    return num_kv_heads, head_dim
+
+
+def laid_out_bytes(shape, dtype) -> int:
+    """Bytes an array of ``shape`` takes on the device, whose tiles pad the
+    minor dimension to :data:`LANES` and the second minor to the sublanes
+    of ``dtype`` (8 of 4 bytes, 16 of 2, 32 of 1)."""
+    item = jnp.dtype(dtype).itemsize
+    tile = _SUBLANES * max(4 // item, 1)
+    *lead, rows, cols = shape
+    return (math.prod(lead) * -(-rows // tile) * tile
+            * -(-cols // LANES) * LANES * item)
 
 
 # what a layer keeps for a live sequence: K/V pages; K/V pages it chooses
@@ -206,9 +239,11 @@ def init_page_pool_caches(
     layers: Optional[LayerStates] = None,
     kinds: Optional[PageKinds] = None,
 ) -> List[Tuple[jax.Array, ...]]:
-    """Zero page-pool caches ``[NP, NKV, page, D]`` per layer, kv-heads
+    """Zero page-pool caches ``[NP, NKV, page, D]`` per layer (``[NP, NKV /
+    2, page, 2D]`` where :func:`page_layout` pairs the heads), kv-heads
     sharded over tp when divisible (the same policy as the contiguous
-    ``init_kv_caches``); the page axis is unsharded — it is a global pool.
+    ``init_kv_caches``; paired heads by whole pairs); the page axis is
+    unsharded — it is a global pool.
     With ``kinds`` of more than one kind, ``num_pages`` is a count a kind
     and a layer's arrays have ITS kind's.
 
@@ -229,20 +264,23 @@ def init_page_pool_caches(
     # every leaf is BORN with its sharding (never staged whole on the
     # default device: a pool sized for a tp mesh does not fit one chip)
     page_sh = scale_sh = None
+    pool_heads, pool_width = page_layout(num_kv_heads, head_dim)
     if model_parallel_is_initialized():
         mesh = get_mesh()
         kv_axes = (TENSOR_AXIS
-                   if num_kv_heads % mesh.shape[TENSOR_AXIS] == 0 else None)
+                   if pool_heads % mesh.shape[TENSOR_AXIS] == 0 else None)
         if kv_axes is None and mesh.shape[TENSOR_AXIS] > 1:
             logger.warning(
-                "page pool kv head dim (%d) not divisible by tp (%d); "
-                "replicating", num_kv_heads, mesh.shape[TENSOR_AXIS])
+                "page pool kv head dim (%d, %d to a lane row) not divisible "
+                "by tp (%d); replicating", num_kv_heads,
+                num_kv_heads // pool_heads, mesh.shape[TENSOR_AXIS])
         page_sh = named_sharding(None, kv_axes, None, None)
         scale_sh = named_sharding(None)  # per-page params: replicated
 
-    def pages(dt, n=num_pages):
-        return jnp.zeros((n, num_kv_heads, page_size, head_dim), dt,
-                         device=page_sh)
+    def pages(dt, n=num_pages, paired=True):
+        shape = ((pool_heads, page_size, pool_width) if paired
+                 else (num_kv_heads, page_size, head_dim))
+        return jnp.zeros((n,) + shape, dt, device=page_sh)
 
     def params():
         return jnp.zeros((num_pages,), jnp.float32, device=scale_sh)
@@ -269,7 +307,10 @@ def init_page_pool_caches(
             if kind == "selected_pages":
                 comp = jnp.zeros((num_pages, layers.comp_slots, num_kv_heads,
                                   head_dim), dtype, device=scale_sh)
-                return (pages(dtype), pages(dtype), comp)
+                # a selecting layer reads its pages head by head beside
+                # the compressed keys: kept as they are
+                return (pages(dtype, paired=False),
+                        pages(dtype, paired=False), comp)
             return (pages(dtype), pages(dtype))
 
         return [entry(k) for k in layers.kinds]
@@ -327,6 +368,17 @@ class PagePool:
         self.caches = init_page_pool_caches(
             num_layers, self.pages_by_kind, page_size, num_kv_heads,
             head_dim, dtype, quant=quant, layers=layers, kinds=self.kinds)
+        # bytes ONE token's cells take of the device across the layers that
+        # keep pages, as the device lays those arrays out
+        # (:func:`laid_out_bytes`; a page's scalar parameters as they are):
+        # what :attr:`page_bytes` counts by shape, a token, where no lane
+        # of a page is padding
+        entries = [e for k, e in zip(
+            layers.kinds if layers is not None else ("pages",) * num_layers,
+            self.caches) if k not in ("state", "none")]
+        self.page_bytes_per_token = sum(
+            laid_out_bytes(x.shape[1:], x.dtype) if x.ndim > 2
+            else x.dtype.itemsize for e in entries for x in e) / page_size
 
     @property
     def page_bytes(self) -> int:

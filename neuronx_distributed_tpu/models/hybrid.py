@@ -346,6 +346,16 @@ def _mamba_dt_bias(cfg):
     return init
 
 
+def gated_group_norm(y, z, groups: int, eps: float):
+    """Mamba-2's gated norm, float32: RMSNorm over each group's channels of
+    ``y * silu(z)`` (``y, z [..., d_inner]``; ONE group is the whole
+    width)."""
+    y = y * jax.nn.silu(z.astype(jnp.float32))
+    yg = y.reshape(*y.shape[:-1], groups, y.shape[-1] // groups)
+    yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, axis=-1, keepdims=True) + eps)
+    return yg.reshape(y.shape)
+
+
 class Mamba2Mixer(nn.Module):
     config: object
 
@@ -419,14 +429,10 @@ class Mamba2Mixer(nn.Module):
                 new_cache = (state, taps) if whole else (
                     states.at[state_rows].set(state),
                     all_taps.at[state_rows].set(taps))
-        # the gated norm: RMSNorm over each group's channels of y * silu(z)
-        y = y.reshape(B, S, d_inner) * jax.nn.silu(z.astype(f32))
-        yg = y.reshape(B, S, G, d_inner // G)
-        yg = yg * jax.lax.rsqrt(
-            jnp.mean(yg * yg, axis=-1, keepdims=True) + cfg.rms_eps)
         norm_w = small("norm_weight", nn.initializers.ones, (d_inner,),
                        cfg.param_dtype)
-        y = (yg.reshape(B, S, d_inner) * norm_w.astype(f32)).astype(cfg.dtype)
+        y = (gated_group_norm(y.reshape(B, S, d_inner), z, G, cfg.rms_eps)
+             * norm_w.astype(f32)).astype(cfg.dtype)
         return RowParallelLinear(
             features=cfg.hidden_size, use_bias=False,
             sequence_parallel=cfg.sequence_parallel,

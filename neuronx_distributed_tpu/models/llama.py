@@ -919,8 +919,13 @@ class LlamaAttention(nn.Module):
                                 zp = zp.at[pj].set(z2, mode="drop")
                             return cq, sc, zp
 
-                        ck, ks, kz = requant_pages(ck, ks, kz, k)
-                        cv, vs, vz = requant_pages(cv, vs, vz, v)
+                        # (the rows as the pool keeps them: heads of half
+                        # a lane row two to a row, kvcache.pool.page_layout)
+                        as_kept = ck.shape[1::2]
+                        ck, ks, kz = requant_pages(
+                            ck, ks, kz, k.reshape(*k.shape[:2], *as_kept))
+                        cv, vs, vz = requant_pages(
+                            cv, vs, vz, v.reshape(*v.shape[:2], *as_kept))
                     else:
                         # cell (phys, :, in_off) of the head-major pool, in
                         # place; a kernel where the paged kernel is one
@@ -960,7 +965,7 @@ class LlamaAttention(nn.Module):
                     gather_page_chain,
                 )
 
-                k, v = gather_page_chain(new_cache, block_table, q.dtype)
+                k, v = gather_page_chain(new_cache, block_table, q.dtype, D)
             elif block_table is None:
                 k, v = ck, cv
 

@@ -257,6 +257,15 @@ REGISTRY_METRICS: Dict[str, str] = {
     # contiguous [B, T] K/V views from the page pool — stays ZERO when the
     # block-table-native kernel (ops.paged_attention) serves decode
     "kvcache/gather_bytes_total": "counter",
+    # what one token's K/V cells take of the device as it lays the pool's
+    # arrays out (kvcache.pool.laid_out_bytes), and what the recurrent
+    # layers' state rows hold of it
+    "kvcache/page_bytes_per_token": "gauge",
+    "kvcache/state_bytes": "gauge",
+    # tokens through the Mamba-2 layers by the program that ran them: a
+    # prefill chunk's own tokens, a decode's live rows
+    "serving/ssm_tokens_total/chunk": "counter",
+    "serving/ssm_tokens_total/step": "counter",
     # KV chain transfer (kvcache.transfer, disagg PR): pages serialized
     # out of / admitted into page pools by migration and fleet-prefix
     # fills; the fleet_prefix counters split directory consultations by

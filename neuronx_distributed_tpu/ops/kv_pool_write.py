@@ -1,7 +1,8 @@
 """The page pool's one writer: a step's new K/V rows, committed IN PLACE.
 
 The pool is head-major ``[NP, NKV, page, D]`` (``kvcache.pool``: the layout
-the paged kernel's copies read).  A scatter of single rows whose two indices
+the paged kernel's copies read; heads of 64 lie two to a 128-lane row,
+``[NP, NKV / 2, page, 128]``, and the writer is the same at that shape).  A scatter of single rows whose two indices
 (page, cell) are split by the head axis made the chip's compiler relay the
 whole pool out for the scatter and back for the kernel — two pool-sized
 copies a pool a layer a program, for a write of a few rows (PERF.md, PR 28).
@@ -175,11 +176,16 @@ def write_pool_rows(pool: jax.Array, new: jax.Array, phys: jax.Array,
     CONSECUTIVE cells of its chain (:func:`touched_pages`), and no page is
     written by two slots (decode pages are a slot's own).  Every other cell
     of the pool keeps its bits; given the pool donated, the write is in
-    place.
+    place.  Where the pool is ``[NP, NKV / 2, page, 2D]`` (heads of 64, two
+    to a lane row) the rows are laid the same way first.
 
     ``kernel`` takes the Pallas call (the caller's resolved ``paged_kernel``:
     where the paged kernel runs, so does this), else the XLA form; both
     leave the same bits.  ``interpret`` as in ``ops.paged_attention``."""
+    # a pool that keeps heads of half a lane row two to a row
+    # (``kvcache.pool.page_layout``) takes the rows' heads side by side too:
+    # the plain reshape, and then the same write at the pool's width
+    new = new.reshape(*new.shape[:2], pool.shape[1], pool.shape[3])
     write = functools.partial(_write_pool_rows_impl, kernel=kernel,
                               interpret=interpret)
     mesh = kv_head_tp_mesh(pool.shape[1]) if kernel else None

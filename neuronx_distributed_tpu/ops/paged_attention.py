@@ -34,6 +34,16 @@ with this pool's layout and the band, softcap and int8 it lacks):
   speculative verification chunk), padded to a sublane tile in the wrapper,
   so grouped queries cost no extra KV traffic.  The heads of a program are
   the batch dim of its two matmuls;
+- a head of HALF a lane row (``D`` 64) is walked two to a row: the pool
+  keeps kv heads ``2j`` and ``2j + 1`` as lanes ``[0, D)`` and ``[D, 2D)``
+  of its head ``j`` (``kvcache.pool.page_layout``: no padded lane in HBM,
+  in the copies or in VMEM), and the wrapper hands the kernel the pair's
+  ``2G`` query heads as rows ``[q | 0]`` and ``[0 | q]`` — ``QK^T`` over
+  the 128 lanes is then each head's own score (the other head's lanes meet
+  exact zeros) and of ``P V [rows, 2D]`` each row keeps its own half.  The
+  kernel below is the same program at ``D`` 128 with twice the group: the
+  bytes are exact and the MXU's work doubled, where the call is bound by
+  the pool's bytes (a decode) or walks in more parts (a chunk);
 - int8 six-tuple pools dequantize IN-KERNEL: the slot's per-page fp32
   ``(scale, zero)`` pairs are gathered through the block table OUTSIDE
   (``[B, PP]`` floats, zeroed outside the band) into per-key-column rows,
@@ -365,6 +375,12 @@ def _paged_attention_impl(q, kv_pages, block_table, cache_offset, kv_start,
                           name=None):
     quantized = len(kv_pages) == 6
     k_pages, v_pages = kv_pages[:2]
+    scale = (q.shape[3] ** -0.5) if sm_scale is None else sm_scale
+    # heads of half a lane row, two to a row of the pool: the pair's query
+    # heads become rows of the pool's width (their own half, zeros beside)
+    paired = k_pages.shape[3] == 2 * q.shape[3]
+    if paired:
+        q = _pair_queries(q, k_pages.shape[1])
     B, S, NQ, D = q.shape
     _, NKV, page, _ = k_pages.shape
     PP = block_table.shape[1]
@@ -372,7 +388,6 @@ def _paged_attention_impl(q, kv_pages, block_table, cache_offset, kv_start,
     G = NQ // NKV
     rows = G * S
     rows_p = -(-rows // _SUBLANES) * _SUBLANES
-    scale = (D ** -0.5) if sm_scale is None else sm_scale
     heads, bp = walk_shape(page, NKV, D, rows, PP, q.dtype.itemsize,
                            k_pages.dtype.itemsize)
     if block_pages is not None:
@@ -448,8 +463,33 @@ def _paged_attention_impl(q, kv_pages, block_table, cache_offset, kv_start,
         )
 
     o = run_kernel(call, interpret, bt, off, start, *operands)
-    return o[:, :, :rows].reshape(B, NKV, S, G, D).transpose(
+    o = o[:, :, :rows].reshape(B, NKV, S, G, D).transpose(
         0, 2, 1, 3, 4).reshape(B, S, NQ, D)
+    return _own_halves(o, NKV) if paired else o
+
+
+def _pair_queries(q, pairs: int):
+    """``q [B, S, NQ, D]`` over a pool of ``pairs`` heads that each hold
+    TWO kv heads side by side -> ``[B, S, NQ, 2D]``: a query head of a
+    pair's first kv head is ``[q | 0]``, of its second ``[0 | q]`` (the
+    query heads group over the kv heads in order, so a pair's ``2G`` are
+    contiguous and keep their places)."""
+    B, S, NQ, D = q.shape
+    qp = q.reshape(B, S, pairs, 2, NQ // (2 * pairs), D)
+    first, second = qp[:, :, :, 0], qp[:, :, :, 1]
+    none = jnp.zeros_like(first)
+    return jnp.stack([jnp.concatenate([first, none], axis=-1),
+                      jnp.concatenate([none, second], axis=-1)],
+                     axis=3).reshape(B, S, NQ, 2 * D)
+
+
+def _own_halves(o, pairs: int):
+    """The inverse of :func:`_pair_queries` on the output ``[B, S, NQ,
+    2D]``: each query head keeps the half that is its own kv head's."""
+    B, S, NQ, D2 = o.shape
+    op = o.reshape(B, S, pairs, 2, NQ // (2 * pairs), 2, D2 // 2)
+    return jnp.stack([op[:, :, :, 0, :, 0], op[:, :, :, 1, :, 1]],
+                     axis=3).reshape(B, S, NQ, D2 // 2)
 
 
 def paged_attention(
@@ -470,7 +510,9 @@ def paged_attention(
     ``q [B, S, NQ, D]`` (post-RoPE, model layout; ``S = 1`` is the serving
     decode step, ``S = k+1`` the speculative verification chunk);
     ``kv_pages`` is ONE layer's pool entry — the fp pair
-    ``(k [NP, NKV, page, D], v)`` or the int8 six-tuple ``(k, v, k_scale,
+    ``(k [NP, NKV, page, D], v)`` (``[NP, NKV / 2, page, 2D]`` where the
+    pool keeps heads of 64 two to a lane row, ``kvcache.pool.page_layout``:
+    told from the shapes) or the int8 six-tuple ``(k, v, k_scale,
     k_zero, v_scale, v_zero)`` (``kvcache.pool`` layout, dequantized
     in-kernel); ``block_table [B, PP]`` maps each slot's logical pages to
     physical ones; ``cache_offset [B]`` is the cache index of query row 0
@@ -490,8 +532,11 @@ def paged_attention(
     and the sweep's handle, not a model's.  ``interpret`` auto (compiled
     where the program lowers for a TPU, the pallas interpreter elsewhere),
     matching ``ops.flash_attention``.  The compiled kernel needs ``page``
-    to be a multiple of 8 (one fp32 sublane tile) and ``D`` of 128; the
-    interpreter takes any shape.
+    to be a multiple of 8 (one fp32 sublane tile) and the POOL's rows a
+    multiple of 128 wide — ``D`` of 128, or heads of 64 paired by the pool
+    (an even kv-head count; an odd count of 64-wide heads stays one to a
+    row and is the interpreter's and the gather path's); the interpreter
+    takes any shape.
 
     On a live tp > 1 mesh the kernel runs under a ``shard_map`` over the
     kv-head axis: heads shard naturally (each ``(slot, kv-head block)``
@@ -594,12 +639,14 @@ def _tp_shard_mapped(nq: int, nkv: int):
     return wrap
 
 
-def gather_page_chain(kv_pages, block_table, dtype):
+def gather_page_chain(kv_pages, block_table, dtype, head_dim=None):
     """One layer's pool entry -> the slots' contiguous ``(k, v)`` views
     ``[B, T, NKV, D]`` through ``block_table [B, PP]`` — the gather path's
     (and the oracle's) O(T) clone, in the layout the dense attention core
     attends over.  An int8 six-tuple dequantizes in the gather (page params
-    gather alongside the pages) into ``dtype``."""
+    gather alongside the pages) into ``dtype``.  ``head_dim``: the model's
+    ``D`` where the pool may keep two heads to a row (the view's rows are
+    then cut back into heads: the plain reshape)."""
     B, PP = block_table.shape
 
     def view(pages, scale=None, zero=None):
@@ -610,7 +657,8 @@ def gather_page_chain(kv_pages, block_table, dtype):
             g = dequantize_page(g, scale[block_table], zero[block_table],
                                 dtype=dtype)
         _, _, NKV, page, D = g.shape
-        return g.transpose(0, 1, 3, 2, 4).reshape(B, PP * page, NKV, D)
+        return g.transpose(0, 1, 3, 2, 4).reshape(
+            B, PP * page, -1, head_dim or D)
 
     if len(kv_pages) == 6:
         ck, cv, ks, kz, vs, vz = kv_pages
@@ -625,7 +673,7 @@ def paged_attention_reference(q, kv_pages, block_table, cache_offset,
     dequantize) the chain into the contiguous ``[B, T]`` view, band-mask,
     softmax — except parked rows (``offset >= T``) are zeroed to match the
     kernel's contract.  The parity tests pin the kernel against this."""
-    k, v = gather_page_chain(kv_pages, block_table, q.dtype)
+    k, v = gather_page_chain(kv_pages, block_table, q.dtype, q.shape[3])
     B, T = k.shape[0], k.shape[1]
     S, NQ, D = q.shape[1], q.shape[2], q.shape[3]
     NKV = k.shape[2]

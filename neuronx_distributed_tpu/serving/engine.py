@@ -663,6 +663,11 @@ class ServingEngine:
         # it is admitted, finished and freed by its state row
         self._retention = "power-retention" in (
             getattr(mcfg, "mixer_types", None) or ())
+        # ... and both count their tokens by the program that ran them:
+        # ``serving/<name>_tokens_total/{chunk,step}``
+        self._token_counts = tuple(
+            name for on, name in ((self._retention, "retention"),
+                                  (self._ssm, "ssm")) if on)
         self._pageless = self._recurrent and not any(
             c in ("pages", "selected_pages", "latent")
             for c in getattr(mcfg, "layer_caches", None) or ("pages",))
@@ -953,10 +958,14 @@ class ServingEngine:
             # what the recurrent layers' state rows hold of the device,
             # whatever the slots are doing
             self.registry.gauge("kvcache/state_bytes").set(pool.state_bytes)
-        if self._retention:
+        # what ONE token's K/V cells take of the device as it lays the
+        # pool's arrays out (a 64-wide head alone in a 128-lane row reads
+        # twice its bytes here, whatever a share of the pool's bytes says)
+        self.registry.gauge("kvcache/page_bytes_per_token").set(
+            pool.page_bytes_per_token)
+        for name in self._token_counts:
             for family in ("chunk", "step"):
-                self.registry.counter(
-                    f"serving/retention_tokens_total/{family}")
+                self.registry.counter(f"serving/{name}_tokens_total/{family}")
         logger.info(
             "serving: paged KV pool: %s pages x %d tokens%s "
             "(%.1f MiB; [B=%d, T=%d] rows would be %.1f MiB)",
@@ -1947,9 +1956,9 @@ class ServingEngine:
                 ctx = off + n_pages * page - (self.C - req.prompt_len)
                 # the chunk's own tokens: its rows less a first page's pads
                 chunk_tokens = ctx - max(off - (self.C - req.prompt_len), 0)
-                if self._retention:
+                for name in self._token_counts:
                     self.registry.counter(
-                        "serving/retention_tokens_total/chunk").inc(
+                        f"serving/{name}_tokens_total/chunk").inc(
                         chunk_tokens)
                 self._account.chunk = n_pages * page
                 self._count_latents(
@@ -2460,9 +2469,9 @@ class ServingEngine:
         if self._ssm:
             self.registry.counter(
                 "serving/ssm_state_rows_stepped_total").inc(len(active))
-        if self._retention:
+        for name in self._token_counts:
             self.registry.counter(
-                "serving/retention_tokens_total/step").inc(len(active))
+                f"serving/{name}_tokens_total/step").inc(len(active))
         if self._kv_quant is not None:
             # every active slot's decode write requantized its page
             self.registry.counter(QUANT_PAGES_TOTAL).inc(len(active))

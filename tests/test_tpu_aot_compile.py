@@ -552,6 +552,144 @@ def test_nemotron_serve_programs_fit_a_v5e_and_copy_no_state(
     assert weights + pool < total < 14 * 2 ** 30
 
 
+# Granite-4.0-H-Micro's attention layers at the cell's pool: 32 slots, 8 kv
+# heads of 64 (group 4), pages of 64, 528 a slot, 8,193 in the pool
+GRANITE = dict(slots=32, nkv=8, group=4, d=64, page=64, pp=528, pages=8193)
+
+
+@pytest.mark.parametrize("S", [1, 512], ids=["decode", "chunk_s512"])
+def test_heads_of_64_are_written_and_walked_in_place_at_their_true_bytes(
+        topo, S):
+    """Heads of 64 for a v5e: the pool keeps two to a 128-lane row
+    (``kvcache.pool.page_layout``), so the writer and the walk are the
+    ``D`` 128 Mosaic calls under their own names, each takes the K and the V
+    pool once, whole, and the two pools are donated, aliased and EXACTLY
+    ``tokens x kv heads x 64 x 2`` bytes each — where ``[pages, 8, 64, 64]``
+    does not lower at all (a slice of 64 lanes of a 128-lane tiling)."""
+    import re
+
+    from neuronx_distributed_tpu.kvcache.pool import page_layout
+    from neuronx_distributed_tpu.ops.kv_pool_write import write_pool_rows
+
+    g = GRANITE
+    nkv, d, page, pp, num_pages = g["nkv"], g["d"], g["page"], g["pp"], g["pages"]
+    mesh = _mesh(topo)
+    B = g["slots"] if S == 1 else 1
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P()))
+
+    def step(q, k, v, pool, bt, off, start):
+        idx = off[:, None] + jnp.arange(S)[None, :]
+        phys = jnp.take_along_axis(bt, jnp.clip(idx // page, 0, pp - 1), axis=1)
+        phys = jnp.where(idx < pp * page, phys, num_pages)
+        with jax.named_scope("kv_write"):
+            pool = tuple(write_pool_rows(c, x, phys, idx % page, kernel=True)
+                         for c, x in zip(pool, (k, v)))
+        return paged_attention(q, pool, bt, off, start,
+                               sm_scale=0.015625), pool
+
+    heads, width = page_layout(nkv, d)
+    assert (heads, width) == (4, 128)
+    pages = sds((num_pages, heads, page, width), jnp.bfloat16)
+    new = sds((B, S, nkv, d), jnp.bfloat16)
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        sds((B, S, nkv * g["group"], d), jnp.bfloat16), new, new,
+        (pages, pages), sds((B, pp), jnp.int32), sds((B,), jnp.int32),
+        sds((B,), jnp.int32)).compile()
+    text = compiled.as_text()
+    name = "paged_attention_decode" if S == 1 else "paged_attention_chunk"
+    assert text.count("%kv_pool_write") >= 2 and f"%{name}" in text
+    pool_shape = f"bf16[{num_pages},{heads},{page},{width}]"
+    walks = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and f"%{name}" in ln]
+    # a 512-row chunk of a pair's 8 query heads is walked in two parts
+    assert len(walks) == (1 if S == 1 else 2)
+    for call in walks:
+        [operands] = re.findall(r"operand_layout_constraints=\{(.*?)\}\}, ",
+                                call)
+        assert operands.count(pool_shape) == 2
+    relaid = [ln.strip()[:120] for ln in text.splitlines()
+              if re.search(rf"= {re.escape(pool_shape)}\S* (copy|transpose)\(",
+                           ln)]
+    assert not relaid, f"a pool is copied: {relaid}"
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 24 * 2 ** 20
+    tokens = num_pages * page
+    assert memory.alias_size_in_bytes == 2 * tokens * nkv * d * 2
+
+
+@pytest.fixture(scope="module")
+def granite_programs(topo):
+    """Both serve programs of the benchmark's Granite-4.0-H-Micro
+    configuration (``benchmarks/tools/granite_aot.py``: all 40 layers, every
+    published width, the whole tied table, 32 slots), compiled once for the
+    two cases below."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from benchmarks.harness import manifest
+    from benchmarks.tools import granite_aot
+
+    prev = jax.config.jax_enable_compilation_cache
+    try:
+        cell = manifest.Cell("granite-4.0-h-micro.serve-sessions")
+        programs, weights, pool, shapes, _ = \
+            granite_aot.compile_serve_programs(cell)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+    return dict(programs), weights, pool, shapes, cell.config["serving"]
+
+
+@pytest.mark.parametrize("program", ["paged decode", "paged chunk prefill"])
+def test_granite_serve_programs_fit_a_v5e_whole_and_copy_no_state(
+        granite_programs, program):
+    """The WHOLE model at the published widths — 36 Mamba-2 layers (64 heads
+    x 64, ONE group, state 128, blocks of 256 rows) beside 4 attention
+    layers of 32 q / 8 kv heads of 64, a SwiGLU of 8,192 in each, the tied
+    100,352-row table — 32 slots of 33,792 tokens, a 512-row chunk: the
+    paged walk and the pool writer are the named Mosaic calls; the K/V pages
+    are laid out at exactly ``tokens x 8,192`` bytes; the pages, the float32
+    scan states ``[32, 64, 64, 128]`` and the taps are donated and aliased,
+    and no copy of any of them is in the text; all of it under 13.5 GiB of
+    the chip's 15.75."""
+    import re
+
+    from benchmarks.tools import granite_aot
+
+    programs, weights, pool, shapes, s = granite_programs
+    compiled = programs[program]
+    text = compiled.as_text()
+    kernel = ("paged_attention_decode" if program == "paged decode"
+              else "paged_attention_chunk")
+    assert f"%{kernel}" in text
+    assert text.count("%kv_pool_write") >= 2 * 4
+    page, state, taps = shapes
+    assert page.shape == (s["num_pages"], 4, s["page_size"], 128)
+    assert state.shape == (32, 64, 64, 128) and state.dtype == jnp.float32
+    laid, layout = granite_aot.laid_out_bytes(text, page)
+    tokens = s["num_pages"] * s["page_size"]
+    assert 2 * 4 * laid == tokens * 8192, layout
+    for sds in shapes:
+        shape = (f"{'f32' if sds.dtype == jnp.float32 else 'bf16'}"
+                 f"[{','.join(map(str, sds.shape))}]")
+        copied = [ln.strip()[:120] for ln in text.splitlines()
+                  if re.search(rf"= {re.escape(shape)}\S* (copy|transpose)\(",
+                               ln) and "fused_computation" not in ln]
+        assert not copied, f"{shape} is copied: {copied}"
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= pool
+    assert pool == tokens * 8192 + 32 * 36 * (2097152 + 26112)
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert weights + pool < total < 13.5 * 2 ** 30
+    # what a step keeps beside the resident bytes: a decode next to nothing,
+    # a chunk its block arrays and activations
+    assert m.temp_size_in_bytes < (0.15 if program == "paged decode"
+                                   else 0.5) * 2 ** 30
+
+
 @pytest.fixture(scope="module")
 def xing4_programs(topo):
     """Both serve programs of the benchmark's Xing4.0 configuration
