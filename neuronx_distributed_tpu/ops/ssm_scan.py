@@ -28,6 +28,19 @@ reduction over ``N``): nothing of it is rounded below float32, which is what
 the benchmark's state check reads (``after - a * before`` is one outer
 product across the heads of a group).  Scopes: ``ssm_conv``,
 ``ssm_scan_chunk`` (blocks of a prefill chunk), ``ssm_step`` (a decode).
+
+How the blocks are walked: in a Python loop at trace time while a call has
+at most ``UNROLLED_BLOCKS`` of them (the static ``ceil(S / c)``: 2 and 4 in
+the served chunks), each block's ``y`` produced once and joined along the
+row axis, which the compiler fuses into the reader.  NOT through
+``lax.scan``'s outputs: the TPU compiler lays a loop's stacked ``ys`` out
+with the BLOCK INDEX in the tiled second-minor dimension
+(``f32[2,1,256,64,64]{2,0,4,3,1:T(2,128)}``), so every trip re-lays its
+block and stores it one sublane a tile, and a physical reshape follows the
+loop — 268 of a Granite layer's 385 us on the v5e, for two trips
+(PERF.md, PR 56).  Past the constant the same ``_block`` is rolled into a
+``lax.scan`` (thousands of uncached rows: program size wins there); the
+two walks run the same blocks to the same bits.
 """
 
 from __future__ import annotations
@@ -38,6 +51,17 @@ import jax.numpy as jnp
 # rows of one block of the chunked form (the published ``chunk_size``): the
 # [c, c] decay mask a head and the [c, P] x [P, N] products
 CHUNK_ROWS = 128
+# a call of at most this many blocks walks them in a loop written out at
+# trace time, a longer one in a ``lax.scan`` (the module's docstring says
+# why).  Read from compiles of one layer's core for a described v5e at 64
+# heads x 64 (PERF.md, PR 56, step 0): written out, a block adds ~36 KB of
+# text and ~0.2 s of compile to every layer that holds it and its least
+# cycles stay level (67-88k a block of 256 rows from 2 blocks to 32), and
+# through 8 blocks the temporaries stay under 1 MiB — at 16 they are 68 MiB
+# a layer and the rolled form's text is 7 times smaller.  The benchmark's
+# chunks are 2 blocks (Granite: 512 rows in 256s) and 4 (Nemotron: 128s); 8
+# is a 1,024-row chunk of 128s.
+UNROLLED_BLOCKS = 8
 
 
 def causal_conv(x, taps, weight, bias, valid, silu=True, scope="ssm_conv"):
@@ -155,8 +179,19 @@ def ssm_scan(x, Bm, Cm, dt, A, D, valid, state, chunk_rows: int = CHUNK_ROWS):
         dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
     nb = (S + pad) // c
     with jax.named_scope("ssm_scan_chunk"):
-        if nb == 1:
-            state, y = _block(state, x, Bm, Cm, dt, A)
+        if nb <= UNROLLED_BLOCKS:
+            # the incoming state is materialised once, as a loop's carry
+            # would be: left free, the compiler fuses the caller's read of
+            # the state row into every block that touches it, and then
+            # copies a layer's WHOLE donated state array to write one row
+            # of it (Granite's layer 39, 64 MiB twice a chunk; AOT, PR 56)
+            state = jax.lax.optimization_barrier(state)
+            ys = []
+            for lo in range(0, nb * c, c):
+                state, y = _block(state, x[:, lo:lo + c], Bm[:, lo:lo + c],
+                                  Cm[:, lo:lo + c], dt[:, lo:lo + c], A)
+                ys.append(y)
+            y = jnp.concatenate(ys, axis=1)
         else:
             def blocks(a):
                 return a.reshape(Bsz, nb, c, *a.shape[2:]).swapaxes(0, 1)

@@ -177,6 +177,34 @@ def test_the_program_scan_is_the_references(toy):
         np.testing.assert_allclose(s1[0], s0, rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("rows", [48, 64, 41])
+def test_a_chunk_of_two_blocks_is_walked_without_a_loop(rows, monkeypatch):
+    """Granite's chunk is two blocks at ONE group: the jitted call holds no
+    ``while`` (nothing is carried through a loop's stacked output), the
+    rolled walk does, both leave the reference's rows and state, and with
+    every operation dispatched alone they agree to the bit.  41 rows: the
+    second block is short."""
+    x, Bm, Cm, dt, A, D = (a[None] if a.ndim > 1 else a
+                           for a in scan_inputs(rows, seed=rows))
+    y0, s0 = ref.selective_scan(x[0], Bm[0], Cm[0], dt[0], A, D)
+    st0 = jnp.zeros((1, 8, 8, 16))
+    c = 32 if rows > 41 else 24
+    out = {}
+    for walk, unrolled in (("written_out", ssm.UNROLLED_BLOCKS),
+                           ("rolled", 1)):
+        monkeypatch.setattr(ssm, "UNROLLED_BLOCKS", unrolled)
+        fn = jax.jit(lambda *a: ssm.ssm_scan(*a, None, st0, c))
+        text = fn.lower(x, Bm, Cm, dt, A, D).as_text()
+        assert ("stablehlo.while" in text) == (walk == "rolled")
+        y1, s1 = fn(x, Bm, Cm, dt, A, D)
+        np.testing.assert_allclose(y1[0], y0, rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(s1[0], s0, rtol=1e-4, atol=1e-4)
+        with jax.disable_jit():
+            out[walk] = jax.tree.map(np.asarray, fn(x, Bm, Cm, dt, A, D))
+    for a, b in zip(out["written_out"], out["rolled"]):
+        np.testing.assert_array_equal(a, b)
+
+
 def _stepped(seed, NH=8, P=8, N=16, G=1, tokens=30):
     rs = np.random.RandomState(seed)
     S = np.zeros((NH, P, N), np.float32)

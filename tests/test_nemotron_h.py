@@ -154,6 +154,61 @@ def test_chunked_scan_is_the_token_scan(chunk, holes):
         np.testing.assert_array_equal(np.asarray(st)[1], np.asarray(st0)[1])
 
 
+WALK_HOLES = {
+    "all_tokens": lambda S: np.ones((3, S), np.int32),
+    # row 0 with pads inside, row 1 parked, row 2 with pads at its end
+    "pads_inside_and_at_the_end": lambda S: np.stack([
+        (np.arange(S) % 5 != 2).astype(np.int32), np.zeros(S, np.int32),
+        (np.arange(S) < S - 3).astype(np.int32)]),
+}
+
+
+@pytest.mark.parametrize("groups", [1, 8])
+@pytest.mark.parametrize("holes", sorted(WALK_HOLES))
+@pytest.mark.parametrize("ragged", [False, True], ids=["whole", "ragged"])
+@pytest.mark.parametrize("nb", [1, 2, 4, ssm.UNROLLED_BLOCKS + 1])
+def test_the_blocks_are_walked_written_out_or_rolled_to_the_same_bits(
+        nb, ragged, holes, groups, monkeypatch):
+    """``nb`` blocks of 8 rows (the last one short by three where ragged):
+    the call is the token scan, whichever way it walks its blocks — written
+    out up to ``UNROLLED_BLOCKS``, rolled into a ``lax.scan`` past it — and
+    the two walks run the same blocks to the same bits.  The bits are read
+    with every operation dispatched alone: compiled whole, the CPU backend's
+    LLVM passes leave the last bit of some ``y`` rows different between the
+    two programs where the last block is short (at its optimisation level 0
+    they do not), which is the compiler's doing and not the walk's."""
+    c = 8
+    S = nb * c - (3 if ragged else 0)
+    args = scan_inputs(3, S, seed=nb, G=groups)
+    valid = WALK_HOLES[holes](S)
+    st0 = jax.random.normal(jax.random.PRNGKey(9), (3, 8, 8, 16))
+
+    def walked(unrolled, compiled):
+        monkeypatch.setattr(ssm, "UNROLLED_BLOCKS", unrolled)
+        fn = jax.jit(lambda *a: ssm.ssm_scan(*a, valid, st0, c))
+        if compiled:
+            assert ("stablehlo.while" in fn.lower(*args).as_text()) \
+                == (nb > unrolled)
+            return jax.tree.map(np.asarray, fn(*args))
+        with jax.disable_jit():
+            return jax.tree.map(np.asarray, fn(*args))
+
+    library = ssm.UNROLLED_BLOCKS
+    with jax.default_matmul_precision("highest"):
+        y, st = walked(library, True)
+        written_out, rolled = walked(nb, False), walked(nb - 1, False)
+        yr, sr = ssm.ssm_scan_reference(*args, valid, st0)
+    for a, b in zip(written_out, rolled):
+        np.testing.assert_array_equal(a, b)
+    live = valid.astype(bool)
+    for got in (y, written_out[0]):
+        assert np.max(np.abs(got[live] - np.asarray(yr)[live])) < 2e-4
+    for got in (st, written_out[1]):
+        np.testing.assert_allclose(got, sr, rtol=1e-4, atol=1e-5)
+    if holes != "all_tokens":
+        np.testing.assert_array_equal(st[1], np.asarray(st0)[1])
+
+
 def test_the_one_token_step_is_the_scan_and_leaves_one_outer_product():
     x, Bm, Cm, dt, A, D = scan_inputs(3, 1, seed=3)
     st0 = jax.random.normal(jax.random.PRNGKey(5), (3, 8, 8, 16))

@@ -690,6 +690,48 @@ def test_granite_serve_programs_fit_a_v5e_whole_and_copy_no_state(
                                    else 0.5) * 2 ** 30
 
 
+# (cell's fixture, rows x heads x width of one BLOCK of the scan's float32
+# ``y``, lines of the decode text under ``ssm_step``: 44 a Mamba-2 layer of
+# Granite's 36, 45 of Nemotron's 6 — what PR 55's programs held)
+SCAN_WALKS = {"granite": ("granite_programs", 256 * 64 * 64, 36 * 44),
+              "nemotron": ("nemotron_programs", 128 * 64 * 64, 6 * 45)}
+
+
+@pytest.mark.parametrize("cell", sorted(SCAN_WALKS))
+def test_a_chunks_scan_blocks_reach_their_rows_without_a_loops_output(
+        cell, request):
+    """The chunk program walks its 2 (Granite) or 4 (Nemotron) blocks
+    written out (``ops/ssm_scan.py::UNROLLED_BLOCKS``): under the scope
+    ``ssm_scan_chunk`` the text holds no ``while``, no dynamic update of a
+    slice (``lax.scan``'s stacked ``ys``, which the v5e stored a sublane a
+    tile: 268 of a layer's 385 us, PERF.md PR 56) and no float32 copy or
+    reshape as large as a block's rows; the decode program holds what it
+    held under ``ssm_step``."""
+    import math
+    import re
+
+    fixture, block, step_lines = SCAN_WALKS[cell]
+    programs = request.getfixturevalue(fixture)[0]
+    chunk = [ln for ln in programs["paged chunk prefill"].as_text()
+             .splitlines() if re.search(r'op_name="[^"]*/ssm_scan_chunk/', ln)]
+    assert len(chunk) > 100
+    moved = []
+    for ln in chunk:
+        op = re.match(r"\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(", ln)
+        if op is None:      # a tuple's result
+            assert not re.search(r" while\(", ln), ln[:200]
+            continue
+        dtype, dims, opcode = op.groups()
+        assert opcode not in ("while", "dynamic-update-slice"), ln[:200]
+        if opcode in ("copy", "reshape") and dtype == "f32" and math.prod(
+                int(d) for d in dims.split(",") if d) >= block:
+            moved.append(ln.strip()[:200])
+    assert not moved, moved
+    decode = programs["paged decode"].as_text()
+    assert "/ssm_scan_chunk/" not in decode
+    assert sum("/ssm_step/" in ln for ln in decode.splitlines()) == step_lines
+
+
 @pytest.fixture(scope="module")
 def xing4_programs(topo):
     """Both serve programs of the benchmark's Xing4.0 configuration
