@@ -89,6 +89,36 @@ def laid_out_bytes(shape, dtype) -> int:
 # ``[NP, page, latent_dim]``, ``ops.latent_attention``); nothing (a layer
 # without a mixer)
 CACHE_KINDS = ("pages", "selected_pages", "state", "latent", "none")
+# ... those of them whose entries are pages the allocator counts
+PAGED_KINDS = ("pages", "selected_pages", "latent")
+# what each kind's entries are carried through beside plain serving: a verify
+# round's rewind, int8 rows, LoRA deltas, a head axis to shard, a chain
+# another prompt may share (a selecting layer writes a row's pages at a shift
+# of its own, a state row is no chain; a latent page holds the same rows
+# whoever wrote it), export and import of chains
+_ALL = ("spec_k", "kv_quant", "adapter_store", "tp", "prefix_cache",
+        "migration")
+CARRIES = {"pages": _ALL, "selected_pages": (), "state": (),
+           "latent": ("prefix_cache",), "none": _ALL}
+# ... how a refusal names each, and why a layer of another kind, or pages of
+# several kinds (None: never their matter), do not carry it
+_ASKS = (
+    ("spec_k", "speculative decoding (spec_k)", ": no state roll-back",
+     ": a rejected tail rewinds rows whose band was given back"),
+    ("kv_quant", "an int8 page pool (kv_quant)", "",
+     ": one array layout a layer, one page-id space"),
+    ("adapter_store", "LoRA adapter pages (adapter_store)", "", ""),
+    ("tp", "tensor parallelism (tp > 1)", "", None),
+    ("prefix_cache", "the prefix index (prefix_cache=True)",
+     ": a model that keeps no page has no chain to share",
+     ": a chain with holes is no prefix, and the index holds pages of one "
+     "kind"))
+_NO_CHAINS = ("KV migration moves page chains: a model with recurrent state "
+              "rows or block-sparse page layouts has none to move")
+_NOT_MOVED = {
+    "selected_pages": _NO_CHAINS, "state": _NO_CHAINS,
+    "latent": "KV migration moves K/V page chains: chains of latent pages "
+              "are not carried through export and import yet"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,6 +174,103 @@ def page_kinds(cfg) -> PageKinds:
         return PageKinds()
     return PageKinds(tuple(dict.fromkeys(by_layer)),
                      tuple(cfg.page_kind_of_layer))
+
+
+@dataclasses.dataclass(frozen=True)
+class CachePlan:
+    """What :func:`cache_plan` resolved: some layer keeps a state row
+    (``recurrent``) and none a page (``pageless``), the page kinds, whether
+    window kinds give pages back (``free_behind``), whether the prefix index
+    is on, and why the layers' chains are not migrated (None: they are)."""
+
+    recurrent: bool
+    pageless: bool
+    page_kinds: PageKinds
+    free_behind: bool
+    prefix_cache: bool
+    not_moved: Optional[str]
+
+    def refuses_migration(self, frees: bool) -> Optional[str]:
+        """Why no chain is exported or imported, None where they are:
+        ``frees`` says that the manager gives pages back."""
+        if self.not_moved is None and (frees or len(self.page_kinds) > 1):
+            return ("KV migration moves whole page chains of ONE kind: a "
+                    "model whose window layers give pages back, or whose "
+                    "pages come in several kinds, has none to move (a model "
+                    "of one kind keeps whole chains under prefix_cache=True)")
+        return self.not_moved
+
+
+def cache_plan(cfg, *, spec_k: int, kv_quant: Optional[str], adapters: bool,
+               prefix_cache: Optional[bool], tp: int,
+               max_total_len: int) -> CachePlan:
+    """What a model's caches may be combined with: from its config (None, or
+    no layer list: every layer keeps pages) and what the caller asked for,
+    the :class:`CachePlan` — or the ``ValueError`` that names what is not
+    carried (:data:`CARRIES`) rather than run wrong.  Where a layer's pages
+    cannot be shared the prefix index is OFF, whatever was passed; a model of
+    recurrent layers ALONE keeps no page and is admitted, finished and freed
+    by its state row.
+
+    Pages by layer KIND (:func:`page_kinds`): a kind whose window a row can
+    outgrow gives its pages back as the band moves on (serving/paged.py) —
+    unless something needs WHOLE chains: a verify round rewinds rows whose
+    band would have been returned; an int8 page requantizes the whole page
+    its neighbours were freed around; LoRA pages, the KV hand-off between
+    replicas and a preempted request's resume pin move chains the prefix
+    index vouches for.  A model of ONE kind asked for any of them keeps every
+    page (the window only masks); left to the default (``prefix_cache``
+    None) its pages come back and the index is off.  A model of SEVERAL
+    kinds has no pool to fall back on — with a mask alone its pages are what
+    the chip cannot hold, and the index is one kind's — so there they
+    raise."""
+    kept = set(getattr(cfg, "layer_caches", None) or ("pages",))
+
+    def lacking(what):
+        return any(what not in CARRIES[k] for k in kept)
+
+    recurrent = "state" in kept
+    pageless = recurrent and not kept & set(PAGED_KINDS)
+    asked = {"spec_k": spec_k, "kv_quant": kv_quant is not None,
+             "adapter_store": adapters, "tp": tp > 1,
+             # an index asked of a hybrid of state rows and pages is off,
+             # unsaid; asked of a model that keeps no page it is refused
+             "prefix_cache": prefix_cache and pageless}
+    refused = [what + why for name, what, why, _ in _ASKS
+               if asked[name] and lacking(name)]
+    if refused:
+        from neuronx_distributed_tpu.models.hybrid import RECURRENT_NAMES
+
+        raise ValueError(
+            f"not carried through recurrent ({RECURRENT_NAMES}), "
+            "page-selecting or latent layers yet: " + "; ".join(refused))
+    if lacking("prefix_cache"):
+        prefix_cache = False
+    asked["prefix_cache"] = prefix_cache
+    kinds = page_kinds(cfg)
+    several = len(kinds) > 1
+    whole_chains = [what + why for name, what, _, why in _ASKS
+                    if asked[name] and why is not None]
+    if several and whole_chains:
+        raise ValueError(
+            "not carried through pages of several kinds (layers of "
+            "different windows), whose window layers give pages back, "
+            "yet: " + "; ".join(whole_chains))
+    free_behind = several or not whole_chains
+    if prefix_cache is None:
+        outgrown = any(w is not None and w < max_total_len
+                       for w in kinds.windows)
+        prefix_cache = not several and not (free_behind and outgrown)
+        if outgrown and not prefix_cache:
+            logger.info(
+                "serving: window pages come back as the band moves on, "
+                "so the prefix index is off%s", "" if several else
+                " (prefix_cache=True keeps whole chains and the index; "
+                "the window then only masks)")
+    return CachePlan(
+        recurrent, pageless, kinds, free_behind, bool(prefix_cache),
+        next((_NOT_MOVED[k] for k in CACHE_KINDS
+              if k in kept and "migration" not in CARRIES[k]), None))
 
 
 def pages_of_kinds(num_pages, kinds: Optional[PageKinds]) -> Tuple[int, ...]:
@@ -212,8 +339,7 @@ class LayerStates:
 
     @property
     def paged(self) -> int:
-        return sum(k in ("pages", "selected_pages", "latent")
-                   for k in self.kinds)
+        return sum(k in PAGED_KINDS for k in self.kinds)
 
     @property
     def state_shape(self) -> Tuple[int, ...]:
@@ -375,7 +501,7 @@ class PagePool:
         # of a page is padding
         entries = [e for k, e in zip(
             layers.kinds if layers is not None else ("pages",) * num_layers,
-            self.caches) if k not in ("state", "none")]
+            self.caches) if k in PAGED_KINDS]
         self.page_bytes_per_token = sum(
             laid_out_bytes(x.shape[1:], x.dtype) if x.ndim > 2
             else x.dtype.itemsize for e in entries for x in e) / page_size

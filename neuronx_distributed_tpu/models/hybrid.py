@@ -46,18 +46,22 @@ DeepSeek-V2's latent attention and LFM2's gated short convolution:
 Projections, norms and the cache protocol are the block's own
 (``GQAQKVColumnParallelLinear``, ``RowParallelLinear``, ``RMSNorm``): a
 mixer is called like ``LlamaAttention`` and returns ``(out, new cache)``.
-What each can do, mixer by mixer: ``"attention"`` trains (flash kernels
-with their backward, every parallel layout) and serves; ``"conv"`` TRAINS
-and has no cached call (its gradients are tested against the float32
-reference, ``tests/test_lfm2_moe.py``; the serving engine refuses it by
-name); ``"lightning-attn"``, ``"minicpm4"``, ``"mamba2"`` and ``"mla"``
-SERVE and have no tested backward (their uncached call differentiates as
-plain XLA operations, unmeasured and unchecked), and so does
-``"power-retention"``.  Tensor parallelism over the heads of the six is not
-carried through (the engine refuses tp > 1).
+What the rest of the package asks about a mixer is ONE record of
+:data:`MIXER_KINDS`, at the end of this file.  ``"attention"`` trains (flash
+kernels with their backward, every parallel layout) and serves; ``"conv"``
+TRAINS and has no cached call (its gradients are tested against the float32
+reference, ``tests/test_lfm2_moe.py``; its record says why it is not
+served); the others SERVE and have no tested backward (their uncached call
+differentiates as plain XLA operations, unmeasured and unchecked), and
+tensor parallelism over their heads is not carried through
+(``kvcache.pool.cache_plan`` refuses tp > 1).
 """
 
 from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -73,23 +77,26 @@ from neuronx_distributed_tpu.parallel.qkv import (
     Q_HEAD_AXES,
 )
 
-MIXERS = ("attention", "minicpm4", "lightning-attn", "mamba2", "mla", "conv",
-          "power-retention", "none")
-# what each mixer keeps for a live sequence, in the page pool's terms
-# (``kvcache.pool.CACHE_KINDS``): the one place a mixer's name decides it —
-# ``LlamaConfig.layer_caches`` hands it on, and the pool and the engines
-# read the config
-CACHE_OF = {"attention": "pages", "minicpm4": "selected_pages",
-            "lightning-attn": "state", "mamba2": "state", "mla": "latent",
-            "power-retention": "state",
-            # no cached call (trace/engine.py refuses the mixer by name)
-            "conv": "none", "none": "none"}
 # the standard deviation a SEEDED embedding table of a layer-list model is
 # drawn with: the MiniCPM family's ``initializer_range``.  With muP's 12 x
 # embedding the table then leads the residual stream, as in a trained model;
 # at flax's 0.02 the layers' additions outgrow it and a seeded network
 # amplifies rounding (PERF.md, PR 29)
 SEEDED_EMBED_STD = 0.1
+
+
+class Launch(NamedTuple):
+    """One paged program as the host knows it before it runs (what the
+    serving engine hands :func:`launch_counters`): its queries' positions,
+    each in its own row; the rows' lengths, one a query or one for all; the
+    keys attended (a decode's contexts, a chunk's last row's); the rows
+    computed (a decode's live slots, a chunk's own tokens)."""
+
+    family: str         # "decode_pages" | "prefill_chunk_pages"
+    positions: Any
+    lengths: Any
+    visible: int
+    rows: int
 
 
 def sparse_spec(cfg):
@@ -116,17 +123,19 @@ def ssm_dims(cfg):
             cfg.ssm_state_size, cfg.ssm_conv_kernel)
 
 
-def state_arrays(cfg, kind: str):
-    """``((shape, dtype name), ...)``: the arrays of ONE recurrent layer's
-    state of one sequence, for the mixer ``kind``."""
-    if kind == "lightning-attn":
-        nh, d = lightning_dims(cfg)
-        return (((nh, d, d), "float32"),)
-    if kind == "power-retention":
-        from neuronx_distributed_tpu.ops.power_retention import phi_dim
+def _lightning_state(cfg):
+    nh, d = lightning_dims(cfg)
+    return (((nh, d, d), "float32"),)
 
-        nkv, d = cfg.num_kv_heads, cfg.head_dim_
-        return (((nkv, d, phi_dim(d)), "float32"), ((nkv, d, d), "float32"))
+
+def _retention_state(cfg):
+    from neuronx_distributed_tpu.ops.power_retention import phi_dim
+
+    nkv, d = cfg.num_kv_heads, cfg.head_dim_
+    return (((nkv, d, phi_dim(d)), "float32"), ((nkv, d, d), "float32"))
+
+
+def _ssm_state(cfg):
     nh, p, g, n, k = ssm_dims(cfg)
     return (((nh, p, n), "float32"),
             ((k - 1, nh * p + 2 * g * n), jnp.dtype(cfg.dtype).name))
@@ -639,6 +648,24 @@ def mla_softmax_scale(cfg) -> float:
 MLA_EXPANDED_MIN_ROWS = 64
 
 
+def count_latents(reg, chunk_tokens: int, launch: Launch) -> None:
+    """What the coming program's latent layers read and write, a layer,
+    from the host offsets: the latent rows its queries attend
+    (``launch.visible``: ``serving/latent_tokens_read_total``, also by
+    program family), those of them a chunk up-projects to keys and values
+    (the expanded path expands what it reads, once a chunk; a decode,
+    absorbed, none: ``serving/latent_tokens_expanded_total``) and the rows
+    it commits (``kvcache/latent_rows_written_total``, also by family)."""
+    for name, n in (("serving/latent_tokens_read_total", launch.visible),
+                    ("kvcache/latent_rows_written_total", launch.rows)):
+        reg.counter(name).inc(n)
+        reg.counter(f"{name}/{launch.family}").inc(n)
+    if launch.family == "prefill_chunk_pages" \
+            and chunk_tokens >= MLA_EXPANDED_MIN_ROWS:
+        reg.counter("serving/latent_tokens_expanded_total").inc(
+            launch.visible)
+
+
 def _latent_cells(cache_offset, block_table, kv_valid, rows, page, num_pages):
     """``(phys [B, rows], in_off)``: the pool cell of each new row, as the
     K/V write of ``models.llama`` finds it — row ``s`` of slot ``b`` is
@@ -770,17 +797,104 @@ class MLAMixer(nn.Module):
             out.reshape(B, S, NH * dv)), new_cache
 
 
+@dataclasses.dataclass(frozen=True)
+class MixerKind:
+    """Everything the rest of the package asks about one mixer a layer list
+    may name, the ONE place its name decides anything: what it keeps for a
+    live sequence (``cache``, of ``kvcache.pool.CACHE_KINDS``); the flax
+    ``module`` that builds it (None: the block's own attention, or no
+    mixer); ``state``, ``cfg -> ((shape, dtype name), ...)``, the arrays of
+    ONE layer's state of one sequence (recurrent kinds only); the stem its
+    tokens are ``counted`` under by the program that ran them
+    (``serving/<stem>_tokens_total/{chunk,step}``) and the counter of the
+    state rows its decodes have ``stepped``; whether a decode over every slot
+    steps those rows where they lie (``rows_in_place``: the program is then
+    told no rows); and why a model with such layers is ``unserved`` (None: it
+    has a cached call)."""
+
+    name: str
+    cache: str
+    module: Optional[type] = None
+    state: Optional[Callable] = None
+    counted: Optional[str] = None
+    stepped: Optional[str] = None
+    rows_in_place: bool = False
+    unserved: Optional[str] = None
+
+
+MIXER_KINDS = {kind.name: kind for kind in (
+    MixerKind("attention", "pages"),
+    MixerKind("minicpm4", "selected_pages", SparseMixer),
+    MixerKind("lightning-attn", "state", LightningMixer, _lightning_state),
+    MixerKind("mamba2", "state", Mamba2Mixer, _ssm_state, counted="ssm",
+              stepped="serving/ssm_state_rows_stepped_total",
+              rows_in_place=True),
+    MixerKind("mla", "latent", MLAMixer),
+    MixerKind("conv", "none", ConvMixer, unserved=(
+        "the 'conv' mixer (LFM2's gated short convolution) has no cached "
+        "call: a model with such layers trains and is not served "
+        "(models/hybrid.py)")),
+    MixerKind("power-retention", "state", PowerRetentionMixer,
+              _retention_state, counted="retention"),
+    MixerKind("none", "none"),
+)}
+MIXERS = tuple(MIXER_KINDS)
+CACHE_OF = {name: kind.cache for name, kind in MIXER_KINDS.items()}
+# the recurrent kinds by name, as a refusal spells them
+RECURRENT_NAMES = ", ".join(
+    name for name, kind in MIXER_KINDS.items() if kind.state is not None)
+
+
+def kinds_of(cfg) -> tuple:
+    """The records of the mixers ``cfg``'s layer list names, each once, in
+    the table's order; () for a config without a layer list."""
+    named = set(getattr(cfg, "mixer_types", None) or ())
+    return tuple(k for k in MIXER_KINDS.values() if k.name in named)
+
+
+def state_arrays(cfg, kind: str):
+    """``((shape, dtype name), ...)``: the arrays of ONE recurrent layer's
+    state of one sequence, for the mixer ``kind``."""
+    return MIXER_KINDS[kind].state(cfg)
+
+
 def hybrid_mixer(cfg, kind: str):
-    if kind == "conv":
-        return ConvMixer(cfg, name="attn")
-    if kind == "mla":
-        return MLAMixer(cfg, name="attn")
-    if kind == "mamba2":
-        return Mamba2Mixer(cfg, name="attn")
-    if kind == "power-retention":
-        return PowerRetentionMixer(cfg, name="attn")
-    if kind == "lightning-attn":
-        return LightningMixer(cfg, name="attn")
-    if kind == "minicpm4":
-        return SparseMixer(cfg, name="attn")
-    raise ValueError(f"unknown mixer {kind!r} (known: {MIXERS})")
+    module = MIXER_KINDS[kind].module if kind in MIXER_KINDS else None
+    if module is None:
+        raise ValueError(f"unknown mixer {kind!r} (known: {MIXERS})")
+    return module(cfg, name="attn")
+
+
+def _count_rows(reg, names: dict, launch: Launch) -> None:
+    for name in names[launch.family]:
+        reg.counter(name).inc(launch.rows)
+
+
+def launch_counters(cfg, reg, chunk_tokens: int) -> tuple:
+    """What the host counts into ``reg`` of each paged program of a model of
+    ``cfg`` before it runs, one ``count(launch) -> span keys or None`` a
+    kind that counts anything: the ``counted`` kinds' tokens by the program
+    that runs them (a chunk's own tokens, a decode's live rows — which are
+    the state rows it steps, where the kind counts those; the token counters
+    are created here, at zero), the latent layers' rows, the selecting
+    layers' blocks (whose ``selected_tokens`` goes on the launch's span).
+    () without a layer list: such a model's launches build no
+    :class:`Launch`."""
+    counters = []
+    for kind in kinds_of(cfg):
+        if kind.counted is not None:
+            chunk, step = (f"serving/{kind.counted}_tokens_total/{family}"
+                           for family in ("chunk", "step"))
+            reg.counter(chunk), reg.counter(step)
+            counters.append(functools.partial(_count_rows, reg, {
+                "prefill_chunk_pages": (chunk,),
+                "decode_pages": (step,) + (
+                    (kind.stepped,) if kind.stepped is not None else ())}))
+    if getattr(cfg, "latent_layers", ()):
+        counters.append(functools.partial(count_latents, reg, chunk_tokens))
+    spec = getattr(cfg, "selection_spec", None)
+    if spec is not None:
+        from neuronx_distributed_tpu.ops.block_select import count_selection
+
+        counters.append(functools.partial(count_selection, reg, spec))
+    return tuple(counters)

@@ -179,14 +179,9 @@ class LlamaConfig:
     # alone.  1 group: no limit, and no such code in the program
     moe_n_group: int = 1
     moe_topk_group: int = 1
-    # the layer list as DATA (HF ``mixer_types``): one mixer name a layer —
-    # "attention" (this file's GQA softmax attention), "minicpm4"
-    # (block-sparse softmax attention, no RoPE, output gate),
-    # "lightning-attn" (decayed linear attention with a recurrent state),
-    # "mamba2" (the Mamba-2 selective scan, a scan state and a convolution
-    # state), "power-retention" (Brumby's degree-2 power retention, a state
-    # row of two arrays) — models/hybrid.py — or "none": no mixer.  None:
-    # every layer is "attention".
+    # the layer list as DATA (HF ``mixer_types``): one mixer name a layer, of
+    # ``models.hybrid.MIXER_KINDS`` — "attention" is this file's GQA softmax
+    # attention, "none" no mixer.  None: every layer is "attention".
     mixer_types: Optional[Tuple[str, ...]] = None
     # its feed-forward parts, likewise: "mlp", "moe" (num_experts > 1) or
     # "none" a layer.  None: every layer has the one num_experts implies.
@@ -278,15 +273,17 @@ class LlamaConfig:
         if self.mixer_types is not None:
             # a JSON list: the frozen config must stay hashable for flax
             object.__setattr__(self, "mixer_types", tuple(self.mixer_types))
-            from neuronx_distributed_tpu.models.hybrid import MIXERS
+            from neuronx_distributed_tpu.models.hybrid import (
+                MIXERS,
+                kinds_of,
+            )
 
             bad = sorted(set(self.mixer_types) - set(MIXERS))
             if bad or len(self.mixer_types) != self.num_layers:
                 raise ValueError(
                     f"mixer_types names one of {MIXERS} for each of the "
                     f"{self.num_layers} layers, got {self.mixer_types}")
-            if len({"lightning-attn", "mamba2", "power-retention"}
-                   & set(self.mixer_types)) > 1:
+            if sum(k.state is not None for k in kinds_of(self)) > 1:
                 raise ValueError(
                     "one kind of recurrent layer a model: a state row is "
                     "one tuple of arrays")
@@ -397,12 +394,8 @@ class LlamaConfig:
 
     @property
     def layer_caches(self) -> Optional[Tuple[str, ...]]:
-        """``"pages"`` (K/V pages), ``"selected_pages"`` (K/V pages the
-        layer chooses among, with compressed keys beside them), ``"state"``
-        (a fixed-size recurrent state row), ``"latent"`` (pages of one
-        latent row a token) or ``"none"`` (a layer without a mixer keeps
-        nothing) a layer; None without a layer list: every layer keeps
-        pages."""
+        """One of ``kvcache.pool.CACHE_KINDS`` a layer; None without a
+        layer list: every layer keeps pages."""
         if self.mixer_types is None:
             return None
         from neuronx_distributed_tpu.models.hybrid import CACHE_OF

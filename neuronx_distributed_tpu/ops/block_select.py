@@ -446,3 +446,34 @@ def selection_counts(positions: np.ndarray, n_row: np.ndarray,
     dense = np.asarray(n_row, np.int64) < spec.dense_len
     chosen = np.where(dense, visible, np.minimum(visible, spec.topk))
     return int(chosen.sum()), int(visible.sum()), int(dense.sum())
+
+
+def count_selection(reg, spec: SparseSpec, launch) -> dict:
+    """What the coming program's block-sparse layers choose, from the host
+    offsets (no device fetch; ``launch``: ``models.hybrid.Launch``): for
+    queries at ``launch.positions`` of rows ``launch.lengths`` long, the
+    blocks chosen and the blocks visible, a kv head a layer
+    (``serving/sparse_blocks_selected_total`` and ``..._visible_total``,
+    also by program family) and the queries under the dense rule
+    (``serving/sparse_dense_queries_total``).  Returns the span's
+    ``selected_tokens`` — the keys the program's LAST query attends in a
+    layer: what a selected walk reads, where ``ctx_tokens`` is what a dense
+    one would."""
+    family, lengths = launch.family, launch.lengths
+    chosen, visible, dense = selection_counts(launch.positions, lengths, spec)
+    for name, n in (("selected", chosen), ("visible", visible)):
+        reg.counter(f"serving/sparse_blocks_{name}_total").inc(n)
+        reg.counter(f"serving/sparse_blocks_{name}_total/{family}").inc(n)
+    reg.counter("serving/sparse_dense_queries_total").inc(dense)
+    positions = np.atleast_1d(launch.positions)
+    if family == "decode_pages":
+        # every live slot's one query: blocks before its own are whole
+        tokens = (chosen - len(positions)) * spec.block_size + int(
+            (positions % spec.block_size + 1).sum())
+    elif len(positions):
+        last = int(positions[-1])
+        sel = selection_counts(last, lengths, spec)[0]
+        tokens = (sel - 1) * spec.block_size + last % spec.block_size + 1
+    else:
+        tokens = 0
+    return {"selected_tokens": int(tokens)}

@@ -230,16 +230,26 @@ def take_gmm_lowered() -> dict:
 
 
 def book_expert_loads(reg, program: str, stats: dict, running):
-    """Book one program's fetched expert loads — ``stats["load"] [L, E]``,
-    with ``"assigned" [L]`` where the layers hold a share of their experts,
-    ``"reached" [L, 2]`` where a group limit lets a row reach none of them
-    and ``"computed" [L]`` where a share passes over the rows it holds (a
-    missing key and ``None`` are alike) — into the registry ``reg`` under
-    the family name ``program`` (a serve program's, or ``train_step``);
-    returns ``running``, the loads
-    summed since the caller began (``None`` at first), with this program's
-    added (:func:`set_expert_load_gauge` reads it).  The counters are those
-    ``ServingEngine._count_moe`` documents."""
+    """Book one program's fetched expert loads into the registry ``reg``
+    under the family name ``program`` (a serve program's, or ``train_step``)
+    and return ``running``, the loads summed since the caller began (``None``
+    at first), with this program's added (:func:`set_expert_load_gauge`
+    reads it).  From ``stats["load"] [L, E]``: ``moe/assignments_total``
+    (valid rows x experts a token x layers), ``moe/rows_computed_total``
+    (the assignment rows the blocks passed over: every one made, or
+    ``"computed" [L]``, those of the spans that ran where a held share of a
+    long array computes over the rows it holds), ``moe/layer_calls_total``
+    (expert blocks that ran with a token) and ``moe/experts_hit_total``
+    (experts with a row, summed over those calls: a decode's few rows leave
+    experts unread, a chunk's hundreds do not) — the last three also by
+    program family.  With ``"assigned" [L]``, where the layers hold a share
+    of their experts, ``moe/assignments_held_total`` (those that went to an
+    expert this program holds; it and ``moe/assignments_total`` then also by
+    family).  With ``"reached" [L, 2]``, where a group limit lets a row
+    reach none of them, ``moe/rows_routed_total`` (valid rows x layers) and
+    ``moe/rows_reaching_held_total`` (those with at least one held
+    assignment), both also by family.  A missing key and ``None`` are
+    alike."""
     load = np.asarray(stats["load"], np.int64)
     assigned = stats.get("assigned")
     calls, hit = int((load.sum(axis=1) > 0).sum()), int((load > 0).sum())
@@ -281,6 +291,49 @@ def set_expert_load_gauge(reg, running) -> None:
     if (mean > 0).any():
         reg.gauge("moe/expert_load_max_over_mean").set(float(np.mean(
             running.max(axis=1)[mean > 0] / mean[mean > 0])))
+
+
+class ExpertLoadBook:
+    """What ONE serving engine has booked of a routed model's expert loads:
+    the loads summed since the engine began (``[L, E]``) and the program
+    families whose first loads it has seen.  What the model ran before
+    (another engine, a check) is taken here and dropped."""
+
+    def __init__(self, model, reg):
+        self._take = model.take_moe_stats
+        self._reg = reg
+        self._load = None
+        self._programs: set = set()
+        self.take()
+
+    def take(self, upto=None):
+        """``(program families, device loads)`` of the paged programs the
+        model ran since the last call (``take_moe_stats``: ``"load"``,
+        ``"assigned"`` and ``"reached"`` where the program has them), those
+        launched after ``upto`` (a ``moe_seq``) left for the next call."""
+        stats = self._take(upto)
+        return [s["program"] for s in stats], [
+            {k: s[k] for k in ("load", "assigned", "reached") if k in s}
+            for s in stats]
+
+    def book(self, programs, loads) -> None:
+        """Book the fetched ``loads`` of ``programs``
+        (:func:`book_expert_loads`) and set the gauge
+        ``moe/expert_load_max_over_mean`` (per layer, the busiest expert's
+        assignments over the mean expert's since the engine began; the mean
+        over layers).  With a family's FIRST loads — its program has been
+        traced by then — also ``moe/gmm_lowered_total/{whole_k,masked_k}``:
+        the grouped matmuls lowered in this process since the last booking,
+        by whether the k-tile divides the contraction
+        (:func:`take_gmm_lowered`)."""
+        reg = self._reg
+        for program, stats in zip(programs, loads):
+            if program not in self._programs:
+                self._programs.add(program)
+                for how, n in take_gmm_lowered().items():
+                    reg.counter("moe/gmm_lowered_total/" + how).inc(n)
+            self._load = book_expert_loads(reg, program, stats, self._load)
+        set_expert_load_gauge(reg, self._load)
 
 
 def per_expert_lecun(key, shape, dtype=jnp.float32):

@@ -93,7 +93,7 @@ from neuronx_distributed_tpu.serving.request import (
     RequestState,
 )
 from neuronx_distributed_tpu.kvcache.allocator import NULL_PAGE, PoolExhausted
-from neuronx_distributed_tpu.kvcache.pool import GATHER_BYTES_TOTAL, page_kinds
+from neuronx_distributed_tpu.kvcache.pool import GATHER_BYTES_TOTAL, cache_plan
 from neuronx_distributed_tpu.kvcache.quant import QUANT_PAGES_TOTAL
 from neuronx_distributed_tpu.kvcache.transfer import (
     ChainExport,
@@ -101,11 +101,13 @@ from neuronx_distributed_tpu.kvcache.transfer import (
     export_chain,
     import_chain,
 )
-from neuronx_distributed_tpu.parallel.moe import (
-    book_expert_loads,
-    set_expert_load_gauge,
-    take_gmm_lowered,
+from neuronx_distributed_tpu.models.hybrid import Launch, launch_counters
+from neuronx_distributed_tpu.parallel.mesh import (
+    TENSOR_AXIS,
+    get_mesh,
+    model_parallel_is_initialized,
 )
+from neuronx_distributed_tpu.parallel.moe import ExpertLoadBook
 from neuronx_distributed_tpu.serving.paged import PagedKVManager
 from neuronx_distributed_tpu.serving.scheduler import (
     DEFAULT_MAX_BATCH_WAIT_S,
@@ -472,16 +474,10 @@ class ServingEngine:
     slot and its page count — ``num_pages`` may name one a kind — and a kind
     whose window a row can outgrow takes pages as the writes reach them and
     gives them back, from the step's ``tail``, once the oldest row that can
-    still be queried has moved past them.  A chain with holes is no prefix
-    and nothing to rewind, requantize or hand to another replica, so pages
-    come back only where nothing asked for whole chains: a model of ONE kind
-    (Mistral) built with ``prefix_cache=True``, ``spec_k``, ``kv_quant`` or
-    ``adapter_store`` keeps every page and its window only masks, as before
-    there were kinds; left to the default (``prefix_cache=None``) its pages
-    come back and it runs without the prefix index — a preempted request
-    then prefills again from its prompt, and KV migration raises.  A model
-    of SEVERAL kinds always gives its window pages back, and refuses all
-    five.
+    still be queried has moved past them — unless ``prefix_cache=True``,
+    ``spec_k``, ``kv_quant`` or ``adapter_store`` asked for whole chains.
+    What a model's layers keep, what that may be combined with and what is
+    refused is ``kvcache.pool.cache_plan``'s to say.
 
     Speculative decoding (spec PR): ``draft=`` (a second
     ``ParallelInferenceModel`` sharing the target's tokenizer and serving
@@ -646,105 +642,17 @@ class ServingEngine:
                 f"kv_quant must be 'int8' or None, got {kv_quant!r}")
         self._adapters = adapter_store
         self._kv_quant = kv_quant
-        # a model with a layer LIST whose layers keep more than K/V pages
-        # (models/hybrid.py): recurrent layers hold a state row a slot, and
-        # block-sparse layers write a row's pages at a shift of its own.  A
-        # page chain then carries neither a recurrent state nor a layout
-        # another prompt could share: prefix sharing is OFF for such a
-        # model (derived here, no argument), and what is not carried
-        # through these layers raises now rather than run wrong
+        # what the model's layers keep, and what that may be combined with
+        # (kvcache.pool.cache_plan: it raises what is not carried through)
         mcfg = getattr(getattr(model, "module", None), "config", None)
-        self._recurrent = bool(getattr(mcfg, "recurrent_layers", ()))
-        self._sparse_spec = getattr(mcfg, "selection_spec", None)
-        # Mamba-2 layers: a decode steps one state row a live slot a layer
-        self._ssm = "mamba2" in (getattr(mcfg, "mixer_types", None) or ())
-        # power-retention layers: their tokens are counted by the program
-        # that ran them, and a model of such layers ALONE keeps no page —
-        # it is admitted, finished and freed by its state row
-        self._retention = "power-retention" in (
-            getattr(mcfg, "mixer_types", None) or ())
-        # ... and both count their tokens by the program that ran them:
-        # ``serving/<name>_tokens_total/{chunk,step}``
-        self._token_counts = tuple(
-            name for on, name in ((self._retention, "retention"),
-                                  (self._ssm, "ssm")) if on)
-        self._pageless = self._recurrent and not any(
-            c in ("pages", "selected_pages", "latent")
-            for c in getattr(mcfg, "layer_caches", None) or ("pages",))
-        # latent layers (MLA) keep pages of ONE latent row a token: pages
-        # as any other to the allocator and the prefix index, which STAYS
-        # on (a shared page holds the same rows whoever wrote it), but the
-        # programs carry neither a verify chunk, an int8 row, LoRA deltas
-        # nor a head axis to shard through them yet
-        self._latent_layers = len(getattr(mcfg, "latent_layers", ()))
-        if self._recurrent or self._sparse_spec is not None \
-                or self._latent_layers:
-            from neuronx_distributed_tpu.parallel.mesh import (
-                TENSOR_AXIS,
-                get_mesh,
-                model_parallel_is_initialized,
-            )
-
-            refused = [what for what, on in (
-                ("speculative decoding (spec_k): no state roll-back", spec_k),
-                ("an int8 page pool (kv_quant)", kv_quant is not None),
-                ("LoRA adapter pages (adapter_store)",
-                 adapter_store is not None),
-                ("tensor parallelism (tp > 1)",
-                 model_parallel_is_initialized()
-                 and get_mesh().shape[TENSOR_AXIS] > 1)) if on]
-            if self._pageless and prefix_cache:
-                refused.append("the prefix index (prefix_cache=True): a "
-                               "model that keeps no page has no chain to "
-                               "share")
-            if refused:
-                raise ValueError(
-                    "not carried through recurrent (lightning-attn, mamba2, "
-                    "power-retention), page-selecting or latent layers yet: "
-                    + "; ".join(refused))
-            if self._recurrent or self._sparse_spec is not None:
-                prefix_cache = False
-        # pages by layer KIND (kvcache.pool.page_kinds): layers of one
-        # window, or of none, share a page-id space and a block table a
-        # slot.  A kind whose window a row can outgrow gives its pages back
-        # as the band moves on (serving/paged.py) — unless something needs
-        # WHOLE chains: a verify round rewinds rows whose band would have
-        # been returned; an int8 page requantizes the whole page its
-        # neighbours were freed around; LoRA pages, the KV hand-off between
-        # replicas and the resume pin of a preempted request move chains
-        # the prefix index vouches for.  A model of ONE kind asked for any
-        # of them keeps every page (the window only masks: what it did
-        # before there were kinds); left to the default its pages come back
-        # and the index is off.  A model of SEVERAL kinds has no pool to
-        # fall back on — with a mask alone its pages are what the chip
-        # cannot hold, and the index is one kind's — so there they raise
-        self._page_kinds = page_kinds(mcfg)
-        several = len(self._page_kinds) > 1
-        whole_chains = [what for what, on in (
-            ("speculative decoding (spec_k): a rejected tail rewinds rows "
-             "whose band was given back", spec_k),
-            ("an int8 page pool (kv_quant): one array layout a layer, one "
-             "page-id space", kv_quant is not None),
-            ("LoRA adapter pages (adapter_store)", adapter_store is not None),
-            ("the prefix index (prefix_cache=True): a chain with holes is "
-             "no prefix, and the index holds pages of one kind",
-             prefix_cache)) if on]
-        if several and whole_chains:
-            raise ValueError(
-                "not carried through pages of several kinds (layers of "
-                "different windows), whose window layers give pages back, "
-                "yet: " + "; ".join(whole_chains))
-        free_behind = several or not whole_chains
-        if prefix_cache is None:
-            outgrown = any(w is not None and w < self.T
-                           for w in self._page_kinds.windows)
-            prefix_cache = not several and not (free_behind and outgrown)
-            if outgrown and not prefix_cache:
-                logger.info(
-                    "serving: window pages come back as the band moves on, "
-                    "so the prefix index is off%s", "" if several else
-                    " (prefix_cache=True keeps whole chains and the index; "
-                    "the window then only masks)")
+        self._cache_plan = plan = cache_plan(
+            mcfg, spec_k=spec_k, kv_quant=kv_quant,
+            adapters=adapter_store is not None, prefix_cache=prefix_cache,
+            tp=(get_mesh().shape[TENSOR_AXIS]
+                if model_parallel_is_initialized() else 1),
+            max_total_len=self.T)
+        self._recurrent = plan.recurrent
+        self._pageless = plan.pageless
         if spec_k:
             # the draft keeps a contiguous [B, T] row a slot (see
             # _prefill_draft_row): the one user of these phase functions
@@ -761,24 +669,24 @@ class ServingEngine:
                     raise ValueError(
                         f"target/draft serving shapes differ on {f}: "
                         f"{getattr(cfg, f)} vs {getattr(dcfg, f)}")
-            tv = getattr(getattr(model, "module", None), "config", None)
             dv = getattr(getattr(draft, "module", None), "config", None)
-            if (tv is not None and dv is not None
-                    and getattr(tv, "vocab_size", None)
+            if (mcfg is not None and dv is not None
+                    and getattr(mcfg, "vocab_size", None)
                     != getattr(dv, "vocab_size", None)):
                 raise ValueError(
-                    f"target/draft vocab_size differ ({tv.vocab_size} vs "
+                    f"target/draft vocab_size differ ({mcfg.vocab_size} vs "
                     f"{dv.vocab_size}): speculative decoding needs one "
                     "shared tokenizer")
         self.obs = obs
         if registry is None and obs is not None:
             registry = obs.registry
         self.registry = registry if registry is not None else MetricRegistry()
-        # expert loads summed since this engine began, [L, E]; what the
-        # model ran before (another engine, a check) is not this engine's
-        self._moe_load = None
-        self._moe_programs: set = set()    # families whose loads were booked
-        self._take_moe_loads()
+        # a routed model's expert loads, booked as they ride the step's
+        # fetch (parallel.moe.ExpertLoadBook); None: the model is dense
+        self._moe_book = (
+            ExpertLoadBook(model, self.registry)
+            if getattr(mcfg, "num_experts", 1) > 1
+            and hasattr(model, "take_moe_stats") else None)
         # resource ledgers (obs.compile_ledger / obs.memory_ledger).  An
         # explicit compile ledger is attached to the MODEL (and the draft)
         # so the AOT phase-fn wrappers and every _CompiledLRU family report
@@ -839,10 +747,10 @@ class ServingEngine:
         self._kv = PagedKVManager(
             num_slots=self.B, context_len=self.C, max_total_len=self.T,
             page_size=page_size, num_pages=num_pages,
-            registry=self.registry, prefix_cache=prefix_cache,
+            registry=self.registry, prefix_cache=plan.prefix_cache,
             spec_overshoot=self._spec_k, state_rows=self._recurrent,
-            kinds=self._page_kinds, chunk_tokens=self._chunk_tokens,
-            free_behind=free_behind, pageless=self._pageless)
+            kinds=plan.page_kinds, chunk_tokens=self._chunk_tokens,
+            free_behind=plan.free_behind, pageless=self._pageless)
         num_pages = self._kv.num_pages
         self._pages_freed = self._kv.frees
         self._chunking: dict = {}   # slot -> _ChunkPrefill in progress
@@ -963,9 +871,11 @@ class ServingEngine:
         # twice its bytes here, whatever a share of the pool's bytes says)
         self.registry.gauge("kvcache/page_bytes_per_token").set(
             pool.page_bytes_per_token)
-        for name in self._token_counts:
-            for family in ("chunk", "step"):
-                self.registry.counter(f"serving/{name}_tokens_total/{family}")
+        # what the host counts of each launch, by what the model's layers
+        # are (models.hybrid.launch_counters); () for a model of attention
+        # layers alone, whose launches then describe nothing
+        self._counters = launch_counters(mcfg, self.registry,
+                                         self._chunk_tokens)
         logger.info(
             "serving: paged KV pool: %s pages x %d tokens%s "
             "(%.1f MiB; [B=%d, T=%d] rows would be %.1f MiB)",
@@ -1226,20 +1136,9 @@ class ServingEngine:
         return export.n_pages - already
 
     def _refuse_migration(self) -> None:
-        if self._recurrent or self._sparse_spec is not None:
-            raise TransferError(
-                "KV migration moves page chains: a model with recurrent "
-                "state rows or block-sparse page layouts has none to move")
-        if self._latent_layers:
-            raise TransferError(
-                "KV migration moves K/V page chains: chains of latent pages "
-                "are not carried through export and import yet")
-        if self._pages_freed or len(self._page_kinds) > 1:
-            raise TransferError(
-                "KV migration moves whole page chains of ONE kind: a model "
-                "whose window layers give pages back, or whose pages come "
-                "in several kinds, has none to move (a model of one kind "
-                "keeps whole chains under prefix_cache=True)")
+        why = self._cache_plan.refuses_migration(self._pages_freed)
+        if why is not None:
+            raise TransferError(why)
 
     @property
     def has_work(self) -> bool:
@@ -1536,14 +1435,15 @@ class ServingEngine:
             for slot, _ in active:
                 self._kv.extend_window(slot, int(offs[slot]))
         self._account.rows = len(active)
-        self._count_latents("decode_pages", int(lens.sum()), len(active))
+        visible = int(lens.sum())
+        counted = self._count_launch(
+            "decode_pages", lens - 1, lens, visible, len(active)
+        ) if self._counters else {}
         with self._phase("dispatch", active=len(active),
-                         ctx_tokens=int(lens.sum()) - len(active),
+                         ctx_tokens=visible - len(active),
                          **({"state_rows": len(active)}
                             if self._recurrent else {}),
-                         **self._window_tokens(lens, 1),
-                         **self._count_selection("decode_pages", lens - 1,
-                                                 lens)):
+                         **self._window_tokens(lens, 1), **counted):
             if self._spec_k:
                 self._spec_dispatch(active)
             else:
@@ -1956,24 +1856,17 @@ class ServingEngine:
                 ctx = off + n_pages * page - (self.C - req.prompt_len)
                 # the chunk's own tokens: its rows less a first page's pads
                 chunk_tokens = ctx - max(off - (self.C - req.prompt_len), 0)
-                for name in self._token_counts:
-                    self.registry.counter(
-                        f"serving/{name}_tokens_total/chunk").inc(
-                        chunk_tokens)
                 self._account.chunk = n_pages * page
-                self._count_latents(
-                    "prefill_chunk_pages", ctx,
-                    ctx - max(off - (self.C - req.prompt_len), 0))
+                counted = self._count_launch(
+                    "prefill_chunk_pages",
+                    np.arange(ctx - chunk_tokens, ctx), req.prompt_len,
+                    ctx, chunk_tokens) if self._counters else {}
                 with self._phase(
                         "prefill_chunk", request_id=req.request_id,
                         tok_start=off, width=n_pages * page, ctx_tokens=ctx,
                         chunk_tokens=chunk_tokens,
                         **self._window_tokens([ctx], n_pages * page),
-                        **self._count_selection(
-                            "prefill_chunk_pages",
-                            np.arange(max(off - (self.C - req.prompt_len),
-                                          0), ctx),
-                            req.prompt_len)):
+                        **counted):
                     self._dispatch_chunk(slot, st, n_pages)
             except BaseException as e:
                 # transactional like the admission path: the one request
@@ -2155,64 +2048,16 @@ class ServingEngine:
         self.registry.counter("serving/paged_pages_tabled_total").inc(
             self.B * self._kv.pages_per_slot)
 
-    def _count_selection(self, family: str, positions, lengths) -> dict:
-        """What the coming program's block-sparse layers choose, from the
-        host offsets (no device fetch): for queries at ``positions`` of rows
-        ``lengths`` long, the blocks chosen and the blocks visible, a kv
-        head a layer (``serving/sparse_blocks_selected_total`` and
-        ``..._visible_total``, also by program family) and the queries under
-        the dense rule (``serving/sparse_dense_queries_total``).  Returns
-        the span's ``selected_tokens`` — the keys the program's LAST query
-        attends in a layer: what a selected walk reads, where ``ctx_tokens``
-        is what a dense one would.  Empty for a model that selects nothing."""
-        if self._sparse_spec is None:
-            return {}
-        from neuronx_distributed_tpu.ops.block_select import selection_counts
-
-        spec = self._sparse_spec
-        chosen, visible, dense = selection_counts(positions, lengths, spec)
-        reg = self.registry
-        for name, n in (("selected", chosen), ("visible", visible)):
-            reg.counter(f"serving/sparse_blocks_{name}_total").inc(n)
-            reg.counter(f"serving/sparse_blocks_{name}_total/{family}").inc(n)
-        reg.counter("serving/sparse_dense_queries_total").inc(dense)
-        positions = np.atleast_1d(positions)
-        if family == "decode_pages":
-            # every live slot's one query: blocks before its own are whole
-            tokens = (chosen - len(positions)) * spec.block_size + int(
-                (positions % spec.block_size + 1).sum())
-        elif len(positions):
-            last = int(positions[-1])
-            sel = selection_counts(last, lengths, spec)[0]
-            tokens = (sel - 1) * spec.block_size + last % spec.block_size + 1
-        else:
-            tokens = 0
-        return {"selected_tokens": int(tokens)}
-
-    def _count_latents(self, family: str, visible: int, rows: int) -> None:
-        """What the coming program's latent layers read and write, a layer,
-        from the host offsets: the latent rows its queries attend
-        (``visible``: each live slot's context for a decode, the keys the
-        last row sees for a chunk: ``serving/latent_tokens_read_total``,
-        also by program family), those of them a chunk up-projects to keys
-        and values (the expanded path expands what it reads, once a chunk;
-        a decode, absorbed, none: ``serving/latent_tokens_expanded_total``)
-        and the rows it commits (``kvcache/latent_rows_written_total``,
-        also by family).  Nothing for a model without latent layers."""
-        if not self._latent_layers:
-            return
-        from neuronx_distributed_tpu.models.hybrid import (
-            MLA_EXPANDED_MIN_ROWS,
-        )
-
-        reg = self.registry
-        for name, n in (("serving/latent_tokens_read_total", visible),
-                        ("kvcache/latent_rows_written_total", rows)):
-            reg.counter(name).inc(n)
-            reg.counter(f"{name}/{family}").inc(n)
-        if family == "prefill_chunk_pages" \
-                and self._chunk_tokens >= MLA_EXPANDED_MIN_ROWS:
-            reg.counter("serving/latent_tokens_expanded_total").inc(visible)
+    def _count_launch(self, family: str, positions, lengths, visible: int,
+                      rows: int) -> dict:
+        """Describe the coming paged program ONCE (``models.hybrid.Launch``)
+        to each of the model's launch counters; the span keys they return
+        (``selected_tokens``).  Called only where the model has any."""
+        launch = Launch(family, positions, lengths, visible, rows)
+        keys: dict = {}
+        for count in self._counters:
+            keys.update(count(launch) or ())
+        return keys
 
     def _count_kv_write(self, rows: int, pages: int) -> None:
         """What the coming program commits to the page pool, a layer: the
@@ -2254,65 +2099,16 @@ class ServingEngine:
         ``upto`` (a decode's ``moe_seq``) leaves the loads of programs
         launched AFTER the one whose tokens are read — this step's prefill
         chunk — for the next fetch, which would otherwise wait for them."""
-        programs, loads = self._take_moe_loads(upto)
         self._account.fetches += 1
+        book = self._moe_book
+        programs, loads = book.take(upto) if book is not None else ((), ())
         with self._phase("fetch"):
             if not loads:
                 return self._audit.fetch(packed_dev, label="serving")
             packed, loads = self._audit.fetch((packed_dev, loads),
                                               label="serving")
-        self._count_moe(programs, loads)
+        book.book(programs, loads)
         return packed
-
-    def _take_moe_loads(self, upto=None):
-        """``(program families, device loads)`` of the paged programs a
-        routed model ran since the last call: ``{"load": [L, E]}`` a
-        program, with ``"assigned" [L]`` beside it where the layers hold a
-        share of their experts and ``"reached" [L, 2]`` where a group limit
-        lets a row reach none of them."""
-        take = getattr(self.model, "take_moe_stats", None)
-        stats = take(upto) if take is not None else []
-        return [s["program"] for s in stats], [
-            {k: s[k] for k in ("load", "assigned", "reached") if k in s}
-            for s in stats]
-
-    def _count_moe(self, programs, loads) -> None:
-        """Book the expert loads of the paged programs just fetched:
-        ``moe/assignments_total`` (valid rows x experts a token x layers),
-        where the layers hold a share of their experts
-        ``moe/assignments_held_total`` (those that went to an expert this
-        program holds; it and ``moe/assignments_total`` then also by program
-        family), ``moe/rows_computed_total`` (the assignment rows the
-        blocks passed over: every one made, or those of the spans that ran
-        where a held share of a long array computes over the rows it holds;
-        also by program family), where the
-        router is group-limited besides
-        ``moe/rows_routed_total`` (valid rows x layers) and
-        ``moe/rows_reaching_held_total`` (those with at least one held
-        assignment), both also by program family,
-        ``moe/layer_calls_total`` (expert blocks that ran with a token),
-        ``moe/experts_hit_total`` (experts with a row, summed over those
-        calls) — the last two also by program family, ``.../decode_pages``
-        and ``.../prefill_chunk_pages``: a decode's few rows leave experts
-        unread, a chunk's hundreds do not — and the gauge
-        ``moe/expert_load_max_over_mean`` (per layer, the busiest expert's
-        assignments over the mean expert's since the engine began; the mean
-        over layers).  With a family's FIRST loads — its program has been
-        traced by then — also ``moe/gmm_lowered_total/{whole_k,masked_k}``:
-        the grouped matmuls lowered in this process since the last booking,
-        by whether the k-tile divides the contraction
-        (``parallel.moe.take_gmm_lowered``)."""
-        if not loads:
-            return
-        reg = self.registry
-        for program, stats in zip(programs, loads):
-            if program not in self._moe_programs:
-                self._moe_programs.add(program)
-                for how, n in take_gmm_lowered().items():
-                    reg.counter("moe/gmm_lowered_total/" + how).inc(n)
-            self._moe_load = book_expert_loads(reg, program, stats,
-                                               self._moe_load)
-        set_expert_load_gauge(reg, self._moe_load)
 
     def _count_sampler_step(self) -> None:
         """Book which branch of ``_sample_rows`` the coming decode takes,
@@ -2466,12 +2262,6 @@ class ServingEngine:
         self._count_gather_step()
         self.registry.counter(
             "serving/head_rows_total/decode_pages").inc(self.B)
-        if self._ssm:
-            self.registry.counter(
-                "serving/ssm_state_rows_stepped_total").inc(len(active))
-        for name in self._token_counts:
-            self.registry.counter(
-                f"serving/{name}_tokens_total/step").inc(len(active))
         if self._kv_quant is not None:
             # every active slot's decode write requantized its page
             self.registry.counter(QUANT_PAGES_TOTAL).inc(len(active))

@@ -110,8 +110,8 @@ class PagedKVManager:
     chunk, which a window kind's reservation holds beside the window.
     ``free_behind=False`` makes every window a mask only — each kind keeps a
     row's whole history, as a prefix index, a speculative tail, int8 pages
-    and adapter pages need (``ServingEngine`` derives it for a model of one
-    kind from what it was asked for).
+    and adapter pages need (``kvcache.pool.cache_plan`` derives it for a model
+    of one kind from what the engine was asked for).
     """
 
     def __init__(self, *, num_slots: int, context_len: int, max_total_len: int,
@@ -152,8 +152,6 @@ class PagedKVManager:
             max_total_len,
             context_len if chunk_tokens is None else chunk_tokens, page_size
         ) if free_behind else (None,) * K
-        if pageless:
-            free_behind = False
         if num_pages is None and pageless:
             num_pages = 2       # the NULL page and one nobody takes
         if num_pages is None:

@@ -714,16 +714,16 @@ class ParallelInferenceModel(_ServingBase):
         # keep a recurrent state row a sequence and which choose the pages
         # they attend (the paged programs then also return that choice)
         self.recurrent = bool(getattr(mcfg, "recurrent_layers", ()))
-        if "conv" in (getattr(mcfg, "mixer_types", None) or ()):
-            raise ValueError(
-                "the 'conv' mixer (LFM2's gated short convolution) has no "
-                "cached call: a model with such layers trains and is not "
-                "served (models/hybrid.py)")
-        # Mamba-2 layers step their state arrays where they lie when every
+        from neuronx_distributed_tpu.models.hybrid import kinds_of
+
+        mixers = kinds_of(mcfg)
+        for kind in mixers:
+            if kind.unserved is not None:
+                raise ValueError(kind.unserved)
+        # layers that step their state arrays where they lie when every
         # slot is a batch row (``models.hybrid.Mamba2Mixer``): a decode is
         # then told no rows
-        self._rows_in_place = "mamba2" in (
-            getattr(mcfg, "mixer_types", None) or ())
+        self._rows_in_place = any(kind.rows_in_place for kind in mixers)
         self._sparse = tuple(getattr(mcfg, "selecting_layers", ()))
         self._sparse_stats: collections.deque = collections.deque(maxlen=256)
         self._build()
@@ -1198,10 +1198,13 @@ class ParallelInferenceModel(_ServingBase):
             # which state row each batch row continues: its slot — a
             # decode's rows are the slots, a one-row chunk names its own
             if lora or not last_only:
+                from neuronx_distributed_tpu.models.hybrid import (
+                    RECURRENT_NAMES,
+                )
+
                 raise ValueError(
                     "LoRA pages and speculative verification are not "
-                    "carried through the recurrent (lightning-attn, mamba2, "
-                    "power-retention) "
+                    f"carried through the recurrent ({RECURRENT_NAMES}) "
                     "layers")
             if state_rows is None:
                 if int(toks.shape[0]) != self.config.batch_size:
