@@ -956,8 +956,9 @@ def phase_serve_hybrid(size, seed, devices, on_tpu, kind="hybrid"):
             or engine._kv.alloc.in_use:
         raise AssertionError(f"state not returned: {snap}")
     log(f"  {len(outs)} requests finished; " + (
-        f"state rows stepped "
-        f"{snap['serving/ssm_state_rows_stepped_total']:.0f}, assignments "
+        f"state rows stepped / skipped "
+        f"{snap['serving/ssm_state_rows_stepped_total']:.0f} / "
+        f"{snap['serving/ssm_state_rows_skipped_total']:.0f}, assignments "
         f"held / made {snap['moe/assignments_held_total']:.0f} / "
         f"{snap['moe/assignments_total']:.0f}" if kind == "ssm" else
         f"blocks chosen / visible "

@@ -19,7 +19,12 @@ DeepSeek-V2's latent attention and LFM2's gated short convolution:
   ``x B C``, the scan with its data-dependent decay, a grouped RMSNorm gated
   by ``silu(z)``.  Its per-sequence state is a state row of TWO arrays: the
   float32 scan state ``[heads, P, N]`` and the convolution's last ``K - 1``
-  inputs ``[K - 1, channels]`` in the activations' dtype.
+  inputs ``[K - 1, channels]`` in the activations' dtype.  A decode where
+  the paged kernels run steps the scan state in ONE Pallas call on the array
+  where it lies (``ops.ssm_scan.ssm_step``): the rows that are tokens by
+  their ids, no byte of any other row, ``y`` of a skipped row exactly 0;
+  elsewhere (the gather path, the CPU, a tp axis) the XLA step passes over
+  every row it is handed.  A chunk slices its row out and writes it back.
 - ``"mla"``: latent attention (DeepSeek-V2's MLA, ``ops.latent_attention``)
   — queries through a normed bottleneck, keys and values through ONE normed
   latent a token beside one RoPE key all heads share; the pool keeps pages
@@ -90,13 +95,16 @@ class Launch(NamedTuple):
     serving engine hands :func:`launch_counters`): its queries' positions,
     each in its own row; the rows' lengths, one a query or one for all; the
     keys attended (a decode's contexts, a chunk's last row's); the rows
-    computed (a decode's live slots, a chunk's own tokens)."""
+    computed (a decode's live slots, a chunk's own tokens); the rows the
+    program is launched over (a decode's slots, a chunk's rows with its
+    pads)."""
 
     family: str         # "decode_pages" | "prefill_chunk_pages"
     positions: Any
     lengths: Any
     visible: int
     rows: int
+    width: int
 
 
 def sparse_spec(cfg):
@@ -366,6 +374,20 @@ def gated_group_norm(y, z, groups: int, eps: float):
 
 
 class Mamba2Mixer(nn.Module):
+    """The Mamba-2 layer (module docstring).  What a cached call reads and
+    writes of the layer's state arrays ``(scan state [R, NH, P, N] float32,
+    taps [R, K - 1, channels])``: a chunk (``S > 1``) slices its one row out
+    and writes it back; a decode (``S == 1``) where the paged kernels run
+    (``paged_kernel`` resolved true, tp = 1) hands the WHOLE scan-state
+    array to ``ops.ssm_scan.ssm_step``, which reads and writes the rows
+    that are tokens (``row_validity``), by ``state_rows`` or their own
+    index, and no byte of any other — such a row keeps its bits and its
+    ``y`` is exactly 0, so what leaves the layer for it is the out
+    projection of zeros; elsewhere the XLA step passes over every row it is
+    handed (all of them when ``state_rows`` is None, a gather and a scatter
+    of whole rows when it is given), an identity step for a row that is no
+    token.  The taps are small and go the XLA way on every path."""
+
     config: object
 
     @nn.compact
@@ -373,7 +395,15 @@ class Mamba2Mixer(nn.Module):
                  kv_valid=None, block_table=None, paged_kernel=False,
                  state_rows=None):
         from neuronx_distributed_tpu.models.llama import row_validity
-        from neuronx_distributed_tpu.ops.ssm_scan import causal_conv, ssm_scan
+        from neuronx_distributed_tpu.ops.ssm_scan import (
+            causal_conv,
+            ssm_scan,
+            ssm_step,
+        )
+        from neuronx_distributed_tpu.parallel.mesh import (
+            get_tensor_parallel_size,
+            model_parallel_is_initialized,
+        )
 
         cfg = self.config
         NH, P, G, N, K = ssm_dims(cfg)
@@ -404,16 +434,25 @@ class Mamba2Mixer(nn.Module):
         D = small("D", nn.initializers.ones, (NH,), f32)
         live = row_validity(kv_valid, cache_offset, S, kv_cache is not None)
         new_cache = None
+        # a decode where the paged kernels run steps the state rows that are
+        # tokens, where they lie and by id, in ONE Pallas call
+        # (``ops.ssm_scan.ssm_step``): a row that is no token moves no byte.
+        # Elsewhere — the gather path, the CPU, heads split over a tp axis
+        # (a Mosaic call is not partitioned) — the XLA step below
+        in_kernel = (
+            paged_kernel and S == 1 and kv_cache is not None
+            and not (model_parallel_is_initialized()
+                     and get_tensor_parallel_size() > 1))
         if kv_cache is None:
             state = jnp.zeros((B, NH, P, N), f32)
             taps = jnp.zeros((B, K - 1, conv_ch), cfg.dtype)
         else:
             states, all_taps = kv_cache
             # ``state_rows`` None: batch row b continues state row b (a
-            # decode of every slot).  The arrays are then stepped where
-            # they lie: gathering 64 rows of 2 MiB at traced ids, stepping
-            # and scattering them back took 1.62 ms a layer on the v5e,
-            # the step on the array itself 0.51 (PERF.md, PR 32, step 0)
+            # decode of every slot).  The XLA step then runs on the arrays
+            # where they lie: gathering 64 rows of 2 MiB at traced ids,
+            # stepping and scattering them back took 1.62 ms a layer on the
+            # v5e, the step on the array itself 0.51 (PERF.md, PR 32, step 0)
             whole = state_rows is None
             if whole and states.shape[0] != B:
                 raise ValueError(
@@ -422,22 +461,31 @@ class Mamba2Mixer(nn.Module):
                     "arrays each batch row continues")
             with jax.named_scope("state_read"):
                 fresh = _fresh(positions, live)
-                state = jnp.where(fresh[:, None, None, None], 0.0,
-                                  states if whole else states[state_rows])
+                if not in_kernel:
+                    state = jnp.where(
+                        fresh[:, None, None, None], 0.0,
+                        states if whole else states[state_rows])
                 taps = jnp.where(fresh[:, None, None], 0,
                                  all_taps if whole else all_taps[state_rows])
         xbc, taps = causal_conv(xbc, taps, conv_w, conv_b, live)
         xs, Bm, Cm = jnp.split(xbc, [d_inner, d_inner + G * N], axis=-1)
         dt = jax.nn.softplus(dt.astype(f32) + dt_bias)
-        y, state = ssm_scan(
-            xs.reshape(B, S, NH, P), Bm.reshape(B, S, G, N),
-            Cm.reshape(B, S, G, N), dt, -jnp.exp(A_log), D, live, state,
-            cfg.ssm_chunk_rows)
+        if in_kernel:
+            y, state = ssm_step(
+                states, xs.reshape(B, NH, P), Bm.reshape(B, G, N),
+                Cm.reshape(B, G, N), dt[:, 0], -jnp.exp(A_log), D,
+                None if live is None else live[:, 0], fresh, state_rows)
+        else:
+            y, state = ssm_scan(
+                xs.reshape(B, S, NH, P), Bm.reshape(B, S, G, N),
+                Cm.reshape(B, S, G, N), dt, -jnp.exp(A_log), D, live, state,
+                cfg.ssm_chunk_rows)
         if kv_cache is not None:
             with jax.named_scope("state_write"):
-                new_cache = (state, taps) if whole else (
-                    states.at[state_rows].set(state),
-                    all_taps.at[state_rows].set(taps))
+                new_cache = (
+                    state if whole or in_kernel
+                    else states.at[state_rows].set(state),
+                    taps if whole else all_taps.at[state_rows].set(taps))
         norm_w = small("norm_weight", nn.initializers.ones, (d_inner,),
                        cfg.param_dtype)
         y = (gated_group_norm(y.reshape(B, S, d_inner), z, G, cfg.rms_eps)
@@ -806,10 +854,13 @@ class MixerKind:
     mixer); ``state``, ``cfg -> ((shape, dtype name), ...)``, the arrays of
     ONE layer's state of one sequence (recurrent kinds only); the stem its
     tokens are ``counted`` under by the program that ran them
-    (``serving/<stem>_tokens_total/{chunk,step}``) and the counter of the
-    state rows its decodes have ``stepped``; whether a decode over every slot
-    steps those rows where they lie (``rows_in_place``: the program is then
-    told no rows); and why a model with such layers is ``unserved`` (None: it
+    (``serving/<stem>_tokens_total/{chunk,step}``), the counter of the
+    state rows its decodes have ``stepped`` and the one of the rows those
+    decodes were launched over and did not step (``skipped``: slots that
+    wait for a chunk's turn or hold no request — what a step that visits
+    live rows alone does not move); whether a decode over every slot steps
+    those rows where they lie (``rows_in_place``: the program is then told
+    no rows); and why a model with such layers is ``unserved`` (None: it
     has a cached call)."""
 
     name: str
@@ -818,6 +869,7 @@ class MixerKind:
     state: Optional[Callable] = None
     counted: Optional[str] = None
     stepped: Optional[str] = None
+    skipped: Optional[str] = None
     rows_in_place: bool = False
     unserved: Optional[str] = None
 
@@ -828,6 +880,7 @@ MIXER_KINDS = {kind.name: kind for kind in (
     MixerKind("lightning-attn", "state", LightningMixer, _lightning_state),
     MixerKind("mamba2", "state", Mamba2Mixer, _ssm_state, counted="ssm",
               stepped="serving/ssm_state_rows_stepped_total",
+              skipped="serving/ssm_state_rows_skipped_total",
               rows_in_place=True),
     MixerKind("mla", "latent", MLAMixer),
     MixerKind("conv", "none", ConvMixer, unserved=(
@@ -865,9 +918,11 @@ def hybrid_mixer(cfg, kind: str):
     return module(cfg, name="attn")
 
 
-def _count_rows(reg, names: dict, launch: Launch) -> None:
+def _count_rows(reg, names: dict, skipped, launch: Launch) -> None:
     for name in names[launch.family]:
         reg.counter(name).inc(launch.rows)
+    if skipped is not None and launch.family == "decode_pages":
+        reg.counter(skipped).inc(launch.width - launch.rows)
 
 
 def launch_counters(cfg, reg, chunk_tokens: int) -> tuple:
@@ -875,8 +930,9 @@ def launch_counters(cfg, reg, chunk_tokens: int) -> tuple:
     ``cfg`` before it runs, one ``count(launch) -> span keys or None`` a
     kind that counts anything: the ``counted`` kinds' tokens by the program
     that runs them (a chunk's own tokens, a decode's live rows — which are
-    the state rows it steps, where the kind counts those; the token counters
-    are created here, at zero), the latent layers' rows, the selecting
+    the state rows it steps, where the kind counts those, beside the rows
+    of its width it does not; the token counters are created here, at
+    zero), the latent layers' rows, the selecting
     layers' blocks (whose ``selected_tokens`` goes on the launch's span).
     () without a layer list: such a model's launches build no
     :class:`Launch`."""
@@ -889,7 +945,8 @@ def launch_counters(cfg, reg, chunk_tokens: int) -> tuple:
             counters.append(functools.partial(_count_rows, reg, {
                 "prefill_chunk_pages": (chunk,),
                 "decode_pages": (step,) + (
-                    (kind.stepped,) if kind.stepped is not None else ())}))
+                    (kind.stepped,) if kind.stepped is not None else ())},
+                kind.skipped))
     if getattr(cfg, "latent_layers", ()):
         counters.append(functools.partial(count_latents, reg, chunk_tokens))
     spec = getattr(cfg, "selection_spec", None)

@@ -1,5 +1,7 @@
 """Mamba-2 (SSD) selective scan: the causal depthwise convolution with its
-carried taps, the chunked form of the scan, and the one-token step.
+carried taps, the chunked form of the scan, and the one-token step — as XLA
+operations on the rows a call holds (:func:`ssm_scan`) and as one Pallas
+call on a layer's state array where it lies (:func:`ssm_step`).
 
 Per head ``h`` (``P`` channels) of group ``g = h // (NH // G)`` the state
 ``S_h [P, N]`` follows, with ``dt`` already ``softplus(dt + dt_bias)`` and
@@ -29,6 +31,31 @@ the benchmark's state check reads (``after - a * before`` is one outer
 product across the heads of a group).  Scopes: ``ssm_conv``,
 ``ssm_scan_chunk`` (blocks of a prefill chunk), ``ssm_step`` (a decode).
 
+What a decode reads and writes.  The XLA step (the ``S == 1`` branch of
+:func:`ssm_scan`) passes over EVERY row it is handed: a decode launched
+over all the slots reads and writes 2 MiB a slot a layer at Granite's and
+Nemotron's sizes whether the slot decodes or waits for its chunk's turn
+(``dt`` masked to 0: the row is rewritten with the bits it held) — 7.74 ms
+a Granite decode whatever its live rows (PERF.md, PR 56).  :func:`ssm_step`
+is the same arithmetic as a Mosaic call named ``ssm_step`` over the state
+array ``[R, NH, P, N]`` itself, aliased input to output: the live rows are
+compacted inside the program (:func:`live_rows_first`, no sort; once a
+program, the layers' copies of it are one after CSE), their ids and their
+count scalar-prefetched, the grid (compacted row, head block).  A program
+past the count maps its blocks to the ones the program before it held —
+an unchanged block index is no fetch and no write-back, the trick
+``flash_attention``'s band uses — and runs no body: a row that is no token
+keeps its BITS, costs no HBM traffic, and its ``y`` is exactly 0 (the first
+program zeroes the resident ``y``).  A count of 0 and a count of ``B`` run
+the same code.  The tokens' ``x``, ``B``, ``C`` and ``y`` stay in VMEM for
+the whole call, the per-head scalars (decay, ``dt``, ``D``) in SMEM; a
+pair of heads' ``dt x`` becomes a column by a ``[128, 128]`` transpose, the
+update is three float32 vector operations an element, and the read ``S C``
+a float32 ``dot_general`` at ``HIGHEST`` with the state stationary (as
+``retention_step``'s).  ``models.hybrid.Mamba2Mixer`` takes the kernel for
+a decode where the paged kernels run and tp = 1, and the XLA step — the
+oracle the tests hold the kernel to — elsewhere.
+
 How the blocks are walked: in a Python loop at trace time while a call has
 at most ``UNROLLED_BLOCKS`` of them (the static ``ceil(S / c)``: 2 and 4 in
 the served chunks), each block's ``y`` produced once and joined along the
@@ -45,8 +72,18 @@ two walks run the same blocks to the same bits.
 
 from __future__ import annotations
 
+import functools
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from neuronx_distributed_tpu.ops.flash_attention import (
+    _compiler_params,
+    run_kernel,
+)
 
 # rows of one block of the chunked form (the published ``chunk_size``): the
 # [c, c] decay mask a head and the [c, P] x [P, N] products
@@ -202,6 +239,215 @@ def ssm_scan(x, Bm, Cm, dt, A, D, valid, state, chunk_rows: int = CHUNK_ROWS):
             y = y.swapaxes(0, 1).reshape(Bsz, nb * c, NH, P)
         y = y[:, :S] + D[None, None, :, None] * x[:, :S].astype(f32)
     return y, state
+
+
+# -- the one-token step on the state arrays where they lie -------------------
+
+# heads of one program's block of a state row: a whole row where it fits (a
+# grid step a row: what a skipped row costs is one step), else the largest
+# divisor of the heads inside this many bytes
+_STEP_BLOCK_BYTES = 2 << 20
+# Mosaic's scoped VMEM for the call: a block in and out, each double
+# buffered (8 MiB), beside the tokens' x, y, B and C (64 rows: 5 MiB)
+_STEP_VMEM_BYTES = 32 << 20
+
+
+def _step_heads(NH: int, P: int, N: int) -> int:
+    best = 1
+    for hb in range(1, NH + 1):
+        if NH % hb == 0 and hb * P * N * 4 <= _STEP_BLOCK_BYTES:
+            best = hb
+    return best
+
+
+def _tile_heads(HB: int, R: int, P: int) -> int:
+    """Heads of one ``[T, N]`` tile of a block (``T = heads x P`` rows, 128
+    where ``P`` divides it): whole heads of ONE group."""
+    t = max(1, 128 // P)
+    while HB % t or R % t:
+        t -= 1
+    return t
+
+
+def _as_column(row, N: int):
+    """``[1, T] -> [T, N]``, every lane of row ``r`` the entry ``r``: the
+    row laid down the sublanes, turned (one ``[128, 128]`` transpose)."""
+    return jnp.broadcast_to(row, (N, row.shape[1])).T
+
+
+def _read(c, s):
+    """``s [T, N] . c [1, N] -> [1, T]`` along the lanes: a float32 matmul at
+    ``HIGHEST`` with the state as the stationary operand."""
+    return jax.lax.dot_general(
+        jnp.broadcast_to(c, (8, c.shape[1])), s, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)[:1]
+
+
+def _step_kernel(src_ref, cnt_ref, rows_ref, fresh_ref, a_ref, dt_ref, d_ref,
+                 s_in, x_ref, b_ref, c_ref, s_out, y_ref, *, R, NJ):
+    """One program: ``HB`` heads of one LIVE row's state, ``s_in`` and
+    ``s_out`` blocks of the one HBM buffer (aliased); the tokens' ``x``,
+    ``B``, ``C`` and the ``y`` they leave stay in VMEM for the whole call,
+    the scalars a head in SMEM.  A program past the count holds the block
+    the last live one held and does nothing; of a call with no live row the
+    first program hands its block back as it came."""
+    del rows_ref                       # the index maps read the row ids
+    f32 = jnp.float32
+    i, j = pl.program_id(0), pl.program_id(1)
+    cnt = cnt_ref[0]
+    _, HB, P, N = s_in.shape
+    hpt = _tile_heads(HB, R, P)
+    T = hpt * P
+    base = 0 if NJ == 1 else j * HB    # the block's first head
+
+    @pl.when((i == 0) & (j == 0))
+    def _():
+        # a row no program visits reads exactly 0
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when(i < cnt)
+    def _():
+        b = src_ref[i]
+        keep = fresh_ref[b] == 0
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+        # row b of x and y: a dynamic row is reached through its aligned
+        # eight (Mosaic loads no single sublane at a traced index)
+        eight = pl.ds(pl.multiple_of(b // 8 * 8, 8), 8)
+        mine = jax.lax.broadcasted_iota(jnp.int32, (8, T), 0) == b % 8
+
+        def along(ref, *at):
+            """A scalar a head laid along its ``P`` lanes of the tile."""
+            row = jnp.full((1, T), ref[at], f32)
+            for hl in range(1, hpt):
+                row = jnp.where(lane >= hl * P,
+                                ref[at[:-1] + (at[-1] + hl,)], row)
+            return row
+
+        for t in range(HB // hpt):
+            h0 = base + t * hpt                        # the tile's first head
+            g = h0 // R                                # ... and its group
+            lanes = pl.ds(h0 * P if NJ == 1 else pl.multiple_of(h0 * P, T), T)
+            x = jnp.sum(jnp.where(mine, x_ref[eight, lanes], 0.0), axis=0,
+                        keepdims=True)                             # [1, T]
+            col = _as_column(x * along(dt_ref, b, h0), N)          # [T, N]
+            brow = b_ref[b, pl.ds(g, 1), :]                        # [1, N]
+            for hl in range(hpt):
+                h = t * hpt + hl
+                s = jnp.where(keep, s_in[0, h], 0.0)               # [P, N]
+                s_out[0, h] = a_ref[b, base + h] * s \
+                    + col[hl * P:(hl + 1) * P] * brow
+            y = _read(c_ref[b, pl.ds(g, 1), :],
+                      s_out[0, t * hpt:(t + 1) * hpt].reshape(T, N))
+            y_ref[eight, lanes] = jnp.where(
+                mine, y + along(d_ref, h0) * x, y_ref[eight, lanes])
+
+    @pl.when((cnt == 0) & (i == 0) & (j == 0))
+    def _():
+        s_out[...] = s_in[...]
+
+
+def _step_call(state, src, cnt, rows, fresh, a, dt, D, x, Bm, Cm, interpret):
+    _, NH, P, N = state.shape
+    HB = _step_heads(NH, P, N)
+    NJ = NH // HB
+    B, G = Bm.shape[:2]
+
+    def block(i, j, src, cnt, rows, *_):
+        # a program past the count: the last live program's block again,
+        # which is no fetch and no write-back
+        live = i < cnt[0]
+        i = jnp.where(live, i, jnp.maximum(cnt[0] - 1, 0))
+        return (rows[src[i]], jnp.where(live, j, NJ - 1), 0, 0)
+
+    whole = lambda shape: pl.BlockSpec(  # noqa: E731
+        shape, lambda i, j, *_: (0,) * len(shape))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=7,
+        grid=(B, NJ),
+        in_specs=[pl.BlockSpec((1, HB, P, N), block), whole(x.shape),
+                  whole(Bm.shape), whole(Cm.shape)],
+        out_specs=[pl.BlockSpec((1, HB, P, N), block), whole(x.shape)],
+    )
+
+    def call(interp):
+        return pl.pallas_call(
+            functools.partial(_step_kernel, R=NH // G, NJ=NJ),
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                       jax.ShapeDtypeStruct(x.shape, jnp.float32)],
+            # operands count the seven scalar-prefetched ones: the state is
+            # the 8th
+            input_output_aliases={7: 0},
+            # y is one block the whole call, zeroed by the first program
+            compiler_params=_compiler_params(
+                ("arbitrary", "arbitrary"), interp, _STEP_VMEM_BYTES),
+            interpret=interp,
+            name="ssm_step",
+        )
+
+    state, y = run_kernel(call, interpret, src, cnt, rows, fresh, a, dt, D,
+                          state, x, Bm, Cm)
+    return y, state
+
+
+def live_rows_first(live):
+    """``(order [B] int32, count [1] int32)``: the rows that are tokens
+    first, in their order, the others behind them.  No sort: a row's place
+    is a running count, and the inverse one comparison a pair."""
+    B = live.shape[0]
+    n = jnp.cumsum(live.astype(jnp.int32))
+    place = jnp.where(live, n - 1, n[-1] + jnp.arange(B) - n)
+    at = jnp.arange(B, dtype=jnp.int32)
+    order = jnp.sum(jnp.where(place[None, :] == at[:, None], at[None, :], 0),
+                    axis=1)
+    return order.astype(jnp.int32), n[-1:].astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _ssm_step_impl(state, x, Bm, Cm, dt, A, D, live, fresh, rows,
+                   interpret=None):
+    f32 = jnp.float32
+    B, NH, P = x.shape
+    order, cnt = live_rows_first(live)
+    # (x and y in whole eights of rows: the kernel reaches a row by its eight)
+    y, state = _step_call(
+        state, order, cnt, rows, fresh.astype(jnp.int32),
+        jnp.exp(dt * A[None, :]), dt, D,
+        jnp.pad(x.astype(f32).reshape(B, NH * P), ((0, -B % 8), (0, 0))),
+        Bm.astype(f32), Cm.astype(f32), interpret)
+    return y[:B].reshape(B, NH, P), state
+
+
+def ssm_step(state, x, Bm, Cm, dt, A, D, live=None, fresh=None, rows=None, *,
+             interpret: Optional[bool] = None):
+    """The one-token step as ONE Pallas call (``ssm_step``) on the state
+    array where it lies, visiting the rows that are tokens and no others.
+
+    ``state [R, NH, P, N]`` float32 (the layer's whole array; given
+    donated, the step is in place); ``x [B, NH, P]``, ``Bm, Cm [B, G, N]``,
+    ``dt [B, NH]`` float32 after its softplus, ``A, D [NH]``; ``live [B]``
+    which batch rows are tokens (``None``: all), ``fresh [B]`` which begin
+    their sequence (their row counts as zeros; ``None``: none), ``rows [B]``
+    the state row each batch row continues (``None``: row ``b``; distinct
+    among the live).  Returns ``(y [B, NH, P] float32, state)``: for a live
+    row ``S = a S + (dt x) (x) B`` and ``y = S C + D x`` as the ``S == 1``
+    branch of :func:`ssm_scan` computes them (float32 throughout, the read a
+    float32 matmul at ``HIGHEST``); a row that is no token keeps its state's
+    BITS, costs no HBM traffic and yields ``y`` exactly 0.
+
+    The grid is (compacted row, head block): the live rows' ids come first
+    in a scalar-prefetched list with their count, and a program past the
+    count maps every operand to the block the program before it held."""
+    B = x.shape[0]
+    f32 = jnp.float32
+    with jax.named_scope("ssm_step"):
+        return _ssm_step_impl(
+            state, x, Bm, Cm, dt.astype(f32), A.astype(f32), D.astype(f32),
+            jnp.ones((B,), bool) if live is None else jnp.asarray(live) > 0,
+            jnp.zeros((B,), bool) if fresh is None else jnp.asarray(fresh),
+            jnp.arange(B, dtype=jnp.int32) if rows is None
+            else jnp.asarray(rows, jnp.int32), interpret=interpret)
 
 
 def ssm_scan_reference(x, Bm, Cm, dt, A, D, valid, state):

@@ -1437,7 +1437,7 @@ class ServingEngine:
         self._account.rows = len(active)
         visible = int(lens.sum())
         counted = self._count_launch(
-            "decode_pages", lens - 1, lens, visible, len(active)
+            "decode_pages", lens - 1, lens, visible, len(active), self.B
         ) if self._counters else {}
         with self._phase("dispatch", active=len(active),
                          ctx_tokens=visible - len(active),
@@ -1860,7 +1860,8 @@ class ServingEngine:
                 counted = self._count_launch(
                     "prefill_chunk_pages",
                     np.arange(ctx - chunk_tokens, ctx), req.prompt_len,
-                    ctx, chunk_tokens) if self._counters else {}
+                    ctx, chunk_tokens, n_pages * page
+                ) if self._counters else {}
                 with self._phase(
                         "prefill_chunk", request_id=req.request_id,
                         tok_start=off, width=n_pages * page, ctx_tokens=ctx,
@@ -2049,11 +2050,11 @@ class ServingEngine:
             self.B * self._kv.pages_per_slot)
 
     def _count_launch(self, family: str, positions, lengths, visible: int,
-                      rows: int) -> dict:
+                      rows: int, width: int) -> dict:
         """Describe the coming paged program ONCE (``models.hybrid.Launch``)
         to each of the model's launch counters; the span keys they return
         (``selected_tokens``).  Called only where the model has any."""
-        launch = Launch(family, positions, lengths, visible, rows)
+        launch = Launch(family, positions, lengths, visible, rows, width)
         keys: dict = {}
         for count in self._counters:
             keys.update(count(launch) or ())

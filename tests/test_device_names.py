@@ -204,6 +204,49 @@ def test_paged_programs_name_their_kernel_and_their_cache_writes(
     assert _has(stacks, "kv_valid")
 
 
+def test_a_mamba2_decode_names_its_step_kernel_under_its_scope(devices8):
+    """A Mamba-2 decode program where the paged kernels run holds the Pallas
+    call ``ssm_step`` under the scope ``ssm_step`` (what ``ssm_time_share``,
+    ``ssm_roofline`` and ``ssm_step_roofline`` book by), inside the layer's
+    ``attn`` module; its chunk program holds no such call."""
+    initialize_model_parallel(tensor_parallel_size=1,
+                              devices=jax.devices()[:1])
+    cfg = LlamaConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=48, num_layers=2,
+        num_heads=2, num_kv_heads=2, head_dim=16, max_seq_len=32,
+        sequence_parallel=False, remat="none", dtype=jnp.float32,
+        param_dtype=jnp.float32, mixer_types=["mamba2", "attention"],
+        ffn_types=["mlp", "mlp"], ssm_heads=8, ssm_head_dim=8, ssm_groups=2,
+        ssm_state_size=16, ssm_conv_kernel=4, ssm_chunk_rows=4)
+    module = LlamaForCausalLM(cfg)
+    params = sharded_params(module.init(jax.random.PRNGKey(0),
+                                        jnp.zeros((3, 8), jnp.int32)))
+    pool = ParallelInferenceModel(
+        module, params,
+        InferenceConfig(batch_size=3, context_len=8, max_total_len=16,
+                        kv_cache_dtype=jnp.float32))
+    caches = pool.make_page_pool(16, 4).caches
+    for rows, width, update_valid, kernels in (
+            (3, 1, True, {"ssm_step", "paged_attention_decode",
+                          "kv_pool_write"}),
+            (1, 4, False, {"paged_attention_chunk", "kv_pool_write"})):
+        fn = functools.partial(pool._paged_step_fn, paged_kernel=True,
+                               update_valid=update_valid, last_only=True)
+        names = [(prim, stack, prm) for prim, stack, prm in _walk(
+            jax.make_jaxpr(fn)(
+                pool.params, jnp.zeros((rows, width), jnp.int32),
+                jnp.full((rows,), 8, jnp.int32),
+                jnp.zeros((rows, 4), jnp.int32), caches,
+                jnp.zeros((rows, 16), jnp.int32),
+                state_rows=jnp.arange(rows, dtype=jnp.int32)).jaxpr)]
+        calls = {prm["name"]: stack for prim, stack, prm in names
+                 if prim == "pallas_call"}
+        assert set(calls) == kernels
+        if width == 1:
+            assert {"ssm_step", "attn", "layer_0"} <= set(
+                tracing_components(calls["ssm_step"]))
+
+
 # -- (1) the serve loop's phases in a profile ---------------------------------
 
 def _host_spans(trace_dir, prefix):

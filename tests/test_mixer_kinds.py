@@ -68,6 +68,7 @@ def test_a_kind_is_one_whole_record(name):
     # what only a recurrent kind may say of itself
     if kind.cache != "state":
         assert not kind.rows_in_place and kind.stepped is None
+        assert kind.skipped is None
     # no cached call: nothing kept, and the sentence that says so
     if kind.unserved is not None:
         assert kind.cache == "none" and "no cached call" in kind.unserved
@@ -231,8 +232,8 @@ def test_whole_chains_or_pages_given_back(model, ask):
 # ---------------------------------------------------------------------------
 
 DECODE = hybrid.Launch("decode_pages", np.array([6, 17, 2]),
-                       np.array([7, 18, 3]), 28, 3)
-CHUNK = hybrid.Launch("prefill_chunk_pages", np.arange(8, 16), 20, 16, 8)
+                       np.array([7, 18, 3]), 28, 3, 5)
+CHUNK = hybrid.Launch("prefill_chunk_pages", np.arange(8, 16), 20, 16, 8, 12)
 COUNTED = {
     "attention": {},
     "lightning-attn": {},
@@ -240,7 +241,10 @@ COUNTED = {
     "mamba2": {
         "serving/ssm_tokens_total/step": 3,
         "serving/ssm_tokens_total/chunk": 8,
-        "serving/ssm_state_rows_stepped_total": 3},
+        "serving/ssm_state_rows_stepped_total": 3,
+        # a decode launched over 5 slots of which 3 decode; a chunk's pads
+        # are no state rows
+        "serving/ssm_state_rows_skipped_total": 2},
     "power-retention": {
         "serving/retention_tokens_total/step": 3,
         "serving/retention_tokens_total/chunk": 8},

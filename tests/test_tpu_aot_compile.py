@@ -21,7 +21,11 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import (
+    NamedSharding,
+    PartitionSpec as P,
+    SingleDeviceSharding,
+)
 
 from neuronx_distributed_tpu.ops.paged_attention import paged_attention
 from neuronx_distributed_tpu.ops.ring_attention import ring_attention
@@ -496,6 +500,43 @@ def test_hybrid_paged_programs_compile_for_v5e_and_copy_no_state(topo, program):
     assert memory.alias_size_in_bytes >= held
 
 
+@pytest.mark.parametrize("rows,groups", [(32, 1), (64, 8)],
+                         ids=["granite_32_rows", "nemotron_64_rows"])
+def test_the_scan_step_kernel_compiles_for_v5e_on_the_state_where_it_lies(
+        topo, rows, groups):
+    """``ops.ssm_scan.ssm_step`` at the cells' shapes — 32 rows of ``[64,
+    64, 128]`` float32 in ONE group (Granite), 64 in 8 (Nemotron), a whole
+    2 MiB row a program: the Mosaic call ``ssm_step`` with the state array
+    donated, aliased to its output and never copied, and nothing as large as
+    a state row beside it."""
+    import re
+
+    from neuronx_distributed_tpu.ops import ssm_scan as ssm
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    NH, HP, N = 64, 64, 128
+    assert ssm._step_heads(NH, HP, N) == NH
+    compiled = jax.jit(ssm.ssm_step, donate_argnums=(0,)).lower(
+        sds((rows, NH, HP, N)), sds((rows, NH, HP), jnp.bfloat16),
+        sds((rows, groups, N), jnp.bfloat16),
+        sds((rows, groups, N), jnp.bfloat16), sds((rows, NH)), sds((NH,)),
+        sds((NH,)), sds((rows,), jnp.bool_), sds((rows,), jnp.bool_),
+        sds((rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert re.search(r"%ssm_step[.\d]* = [^\n]*tpu_custom_call", text)
+    copied = [ln.strip()[:120] for ln in text.splitlines()
+              if re.search(rf"= f32\[{rows},64,64,128\]\S* (copy|transpose)\(",
+                           ln)]
+    assert not copied, f"the state array is copied: {copied}"
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == rows * NH * HP * N * 4
+    assert m.temp_size_in_bytes < NH * HP * N * 4
+
+
 @pytest.fixture(scope="module")
 def nemotron_programs(topo):
     """Both serve programs of the benchmark's Nemotron-3-Nano configuration
@@ -691,10 +732,11 @@ def test_granite_serve_programs_fit_a_v5e_whole_and_copy_no_state(
 
 
 # (cell's fixture, rows x heads x width of one BLOCK of the scan's float32
-# ``y``, lines of the decode text under ``ssm_step``: 44 a Mamba-2 layer of
-# Granite's 36, 45 of Nemotron's 6 — what PR 55's programs held)
-SCAN_WALKS = {"granite": ("granite_programs", 256 * 64 * 64, 36 * 44),
-              "nemotron": ("nemotron_programs", 128 * 64 * 64, 6 * 45)}
+# ``y``, the Mamba-2 layers — each ONE Mosaic call ``ssm_step`` in the decode
+# text since PR 58 — and the state array a layer)
+SCAN_WALKS = {
+    "granite": ("granite_programs", 256 * 64 * 64, 36, "f32[32,64,64,128]"),
+    "nemotron": ("nemotron_programs", 128 * 64 * 64, 6, "f32[64,64,64,128]")}
 
 
 @pytest.mark.parametrize("cell", sorted(SCAN_WALKS))
@@ -710,7 +752,7 @@ def test_a_chunks_scan_blocks_reach_their_rows_without_a_loops_output(
     import math
     import re
 
-    fixture, block, step_lines = SCAN_WALKS[cell]
+    fixture, block, layers, state = SCAN_WALKS[cell]
     programs = request.getfixturevalue(fixture)[0]
     chunk = [ln for ln in programs["paged chunk prefill"].as_text()
              .splitlines() if re.search(r'op_name="[^"]*/ssm_scan_chunk/', ln)]
@@ -729,7 +771,15 @@ def test_a_chunks_scan_blocks_reach_their_rows_without_a_loops_output(
     assert not moved, moved
     decode = programs["paged decode"].as_text()
     assert "/ssm_scan_chunk/" not in decode
-    assert sum("/ssm_step/" in ln for ln in decode.splitlines()) == step_lines
+    step = [ln for ln in decode.splitlines()
+            if re.search(r'op_name="[^"]*/ssm_step/', ln)]
+    calls = [ln for ln in step if re.search(r"%ssm_step[.\d]* = ", ln)]
+    assert len(calls) == layers and all("custom-call(" in ln for ln in calls)
+    made = [ln.strip()[:160] for ln in step
+            if re.match(rf"\s*%\S+ = {re.escape(state)}", ln)
+            and "get-tuple-element(%ssm_step" not in ln]
+    assert not made, made
+    assert sum(" reduce-window(" in ln for ln in step) == 1
 
 
 @pytest.fixture(scope="module")
