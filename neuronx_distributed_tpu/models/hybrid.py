@@ -1,7 +1,8 @@
 """The mixers a ``LlamaConfig.mixer_types`` layer list may name beside the
 block's own ``"attention"`` (and ``"none"``: a layer that is its
 feed-forward part alone) — MiniCPM-SALA's two, Nemotron-H's Mamba-2,
-DeepSeek-V2's latent attention and LFM2's gated short convolution:
+DeepSeek-V2's latent attention, Brumby's power retention, Qwen3-Next's gated
+delta rule and LFM2's gated short convolution:
 
 - ``"lightning-attn"``: decayed linear attention (``ops.lightning_attention``)
   — per-head RMSNorm of q and k, RoPE, an RMSNorm over the concatenated
@@ -43,6 +44,24 @@ DeepSeek-V2's latent attention and LFM2's gated short convolution:
   and the normaliser ``[kv heads, d, d]``.  A decode steps the rows where
   they lie (``retention_step``, by row id); a chunk slices its row out and
   writes it back.  A model of such layers alone keeps NO page.
+- ``"gated-delta"``: the gated delta rule (Qwen3-Next's linear-attention
+  layers, ``ops.gated_delta``) — ``[q, k, v, z] = x W_qkvz`` and ``[b, a] =
+  x W_ba`` laid out a KEY head (``gdn_key_heads`` of them; a key head serves
+  ``gdn_value_heads / gdn_key_heads`` value heads), a causal depthwise
+  convolution with ``silu`` over ``[q | k | v]``, ``beta = sigmoid(b)``,
+  the log decay ``g = -exp(A_log) softplus(a + dt_bias)`` a value head, q
+  and k L2-normalised a head (q scaled by ``Dk ** -0.5``), the rule ``S <-
+  e^g S; S <- S + k (beta (v - S^T k))^T; o = S^T q``, then ``o <- w
+  rms_norm(o) silu(z)`` a head (ONE plain weight ``[Dv]``) and the output
+  projection.  Its per-sequence state is a state row of TWO arrays: the
+  float32 state ``[value heads, Dk, Dv]`` and the convolution's last ``K -
+  1`` inputs ``[K - 1, channels]`` in the activations' dtype.  Where the
+  paged kernels run a decode steps the rows that are tokens where they lie
+  (``gdn_step``, by row id, no byte of any other row), elsewhere as XLA
+  operations on rows gathered and scattered; a chunk walks its blocks as
+  XLA operations on its one row, sliced out and written back (scope
+  ``gdn_chunk``: no kernel).  Scopes ``gdn_proj``,
+  ``gdn_conv``, ``gdn_gates``, ``gdn_chunk``, ``gdn_step``, ``gdn_norm``.
 - ``"conv"``: LFM2's gated short convolution — ``B, C, x = split3(in_proj
   h)``, a causal depthwise convolution of ``conv_L_cache`` taps over ``B *
   x`` with NO activation and no bias, ``out_proj(C * taps)``.  Scopes
@@ -141,6 +160,19 @@ def _retention_state(cfg):
 
     nkv, d = cfg.num_kv_heads, cfg.head_dim_
     return (((nkv, d, phi_dim(d)), "float32"), ((nkv, d, d), "float32"))
+
+
+def gdn_dims(cfg):
+    """``(key heads, value heads, Dk, Dv, convolution taps K)`` of the
+    gated-delta layers."""
+    return (cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_head_dim,
+            cfg.gdn_value_head_dim, cfg.gdn_conv_kernel)
+
+
+def _gdn_state(cfg):
+    hk, hv, dk, dv, k = gdn_dims(cfg)
+    return (((hv, dk, dv), "float32"),
+            ((k - 1, 2 * hk * dk + hv * dv), jnp.dtype(cfg.dtype).name))
 
 
 def _ssm_state(cfg):
@@ -496,6 +528,136 @@ class Mamba2Mixer(nn.Module):
             input_partition_axes=Q_HEAD_AXES, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, kernel_init=per_expert_lecun,
             name="out_proj")(y), new_cache
+
+
+class GatedDeltaMixer(nn.Module):
+    """The gated-delta layer (module docstring).  What a cached call reads
+    and writes of the layer's state arrays ``(state [R, HV, Dk, Dv] float32,
+    taps [R, K - 1, channels])``: the state goes to ``ops.gated_delta`` as
+    the WHOLE array with the rows' ids (``state_rows``, or their own index)
+    — a chunk's ``gdn_chunk`` slices its one row out and writes it back, a
+    decode's ``gdn_step`` steps the live rows in place where the paged
+    kernels run (``paged_kernel`` resolved true); the taps are small and go
+    the XLA way (a gather and a scatter of rows) on every path."""
+
+    config: object
+
+    @nn.compact
+    def __call__(self, x, positions, kv_cache=None, cache_offset=0,
+                 kv_valid=None, block_table=None, paged_kernel=False,
+                 state_rows=None):
+        from neuronx_distributed_tpu.models.llama import row_validity
+        from neuronx_distributed_tpu.ops import gated_delta as gd
+        from neuronx_distributed_tpu.ops.ssm_scan import causal_conv
+        from neuronx_distributed_tpu.parallel.moe import per_expert_lecun
+
+        cfg = self.config
+        HK, HV, Dk, Dv, K = gdn_dims(cfg)
+        if not (HK and HV % HK == 0 and Dk and Dv):
+            raise ValueError(
+                "the gated-delta mixer needs gdn_key_heads, gdn_value_heads "
+                "(a multiple of them), gdn_key_head_dim and "
+                f"gdn_value_head_dim, got {gdn_dims(cfg)}")
+        R = HV // HK
+        key_w, val_w = HK * Dk, HV * Dv
+        conv_ch = 2 * key_w + val_w
+        B, S = x.shape[0], x.shape[1]
+        f32 = jnp.float32
+        small = lambda name, init, shape, dtype: jnp.asarray(self.param(  # noqa: E731
+            name, nn.with_partitioning(init, (None,) * len(shape)), shape,
+            dtype))
+        # (seeded weights are drawn in float32 and rounded: per_expert_lecun)
+        lin = dict(use_bias=False, sequence_parallel=cfg.sequence_parallel,
+                   param_dtype=cfg.param_dtype, kernel_init=per_expert_lecun)
+        with jax.named_scope("gdn_proj"):
+            qkvz = ColumnParallelLinear(
+                features=2 * key_w + 2 * val_w, dtype=cfg.dtype,
+                name="in_proj_qkvz", **lin)(x)
+            # the decay's and beta's logits leave their matmul in float32
+            ba = ColumnParallelLinear(
+                features=2 * HV, dtype=f32, name="in_proj_ba", **lin)(x)
+            # a key head's (q Dk | k Dk | v R Dv | z R Dv) and (b R | a R)
+            qkvz = qkvz.reshape(B, S, HK, 2 * Dk + 2 * R * Dv)
+            q, k, v, z = jnp.split(
+                qkvz, [Dk, 2 * Dk, 2 * Dk + R * Dv], axis=-1)
+            ba = ba.reshape(B, S, HK, 2 * R)
+            b, a = ba[..., :R].reshape(B, S, HV), ba[..., R:].reshape(B, S, HV)
+            mixed = jnp.concatenate(
+                [q.reshape(B, S, key_w), k.reshape(B, S, key_w),
+                 v.reshape(B, S, val_w)], axis=-1)
+            z = z.reshape(B, S, HV, Dv)
+        bound = K ** -0.5       # torch's Conv1d draw at a fan-in of K
+        conv_w = small("conv_weight", lambda key, shape, dtype:
+                       jax.random.uniform(key, shape, f32, -bound, bound
+                                          ).astype(dtype),
+                       (K, conv_ch), cfg.param_dtype)
+        # the rule's own scalars a head stay float32 whatever the weights
+        # are; a SEEDED dt_bias is Mamba-2's draw (flash-linear-attention's:
+        # the checkpoint's ones would give e^g near e^-10 a token for most
+        # heads, and a state that forgets within a token carries nothing a
+        # check could read)
+        dt_bias = small("dt_bias", _mamba_dt_bias(cfg), (HV,), f32)
+        A_log = small("A_log", lambda key, shape, dtype: jnp.log(
+            jax.random.uniform(key, shape, dtype, 1e-3, 16.0)), (HV,), f32)
+        live = row_validity(kv_valid, cache_offset, S, kv_cache is not None)
+        new_cache = None
+        if kv_cache is None:
+            taps = jnp.zeros((B, K - 1, conv_ch), cfg.dtype)
+        else:
+            states, all_taps = kv_cache
+            whole = state_rows is None
+            if whole and states.shape[0] != B:
+                raise ValueError(
+                    "a recurrent layer's cached call over fewer rows than "
+                    "state rows needs state_rows: which row of the state "
+                    "arrays each batch row continues")
+            with jax.named_scope("state_read"):
+                fresh = _fresh(positions, live)
+                taps = jnp.where(fresh[:, None, None], 0,
+                                 all_taps if whole else all_taps[state_rows])
+        mixed, taps = causal_conv(mixed, taps, conv_w, None, live,
+                                  scope="gdn_conv")
+        with jax.named_scope("gdn_gates"):
+            q, k, v = jnp.split(mixed, [key_w, 2 * key_w], axis=-1)
+            heads = lambda t, d: jnp.repeat(  # noqa: E731
+                t.reshape(B, S, HK, d), R, axis=2)
+            q = gd.l2_normalise(heads(q, Dk)) * Dk ** -0.5
+            k = gd.l2_normalise(heads(k, Dk))
+            v = v.reshape(B, S, HV, Dv)
+            beta = jax.nn.sigmoid(b)
+            g = -jnp.exp(A_log) * jax.nn.softplus(a + dt_bias)
+        if kv_cache is None:
+            o, _ = gd.gdn_scan(q, k, v, g, beta, live,
+                               jnp.zeros((B, HV, Dk, Dv), f32))
+        else:
+            rows = (jnp.arange(B, dtype=jnp.int32) if whole
+                    else jnp.asarray(state_rows, jnp.int32))
+            if S == 1:
+                o, states = gd.gdn_step(
+                    states, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                    None if live is None else live[:, 0], fresh, rows,
+                    kernel=paged_kernel)
+                o = o[:, None]
+            else:
+                o, states = gd.gdn_chunk(q, k, v, g, beta, live, fresh,
+                                         states, rows)
+            with jax.named_scope("state_write"):
+                new_cache = (states, taps if whole
+                             else all_taps.at[state_rows].set(taps))
+        norm_w = small("norm_weight", nn.initializers.ones, (Dv,),
+                       cfg.param_dtype)
+        with jax.named_scope("gdn_norm"):
+            # the published gated norm: the head's RMSNorm under its plain
+            # weight, THEN silu(z) — float32, rounded once
+            o = o * jax.lax.rsqrt(
+                jnp.mean(o * o, axis=-1, keepdims=True) + cfg.rms_eps)
+            y = (o * norm_w.astype(f32) * jax.nn.silu(z.astype(f32))
+                 ).astype(cfg.dtype).reshape(B, S, val_w)
+        with jax.named_scope("gdn_proj"):
+            return RowParallelLinear(
+                features=cfg.hidden_size, dtype=cfg.dtype,
+                input_partition_axes=Q_HEAD_AXES, name="o_proj", **lin)(
+                y), new_cache
 
 
 # a SEEDED decay bias is drawn so that a head's half-life lies between these
@@ -860,8 +1022,14 @@ class MixerKind:
     wait for a chunk's turn or hold no request — what a step that visits
     live rows alone does not move); whether a decode over every slot steps
     those rows where they lie (``rows_in_place``: the program is then told
-    no rows); and why a model with such layers is ``unserved`` (None: it
-    has a cached call)."""
+    no rows); why a model with such layers is ``unserved`` (None: it has a
+    cached call); and which of the block's switches it carries —
+    ``partial_rotary`` (``LlamaConfig.partial_rotary_factor`` reaches every
+    channel it rotates, or it rotates none) and ``zero_centered``
+    (``norm_zero_centered`` reaches its norms, or it has none that store a
+    weight about 1 where the switch wants one about 0).  A new kind carries
+    neither until its record says so: a model that sets the switch beside
+    it is refused (:func:`refuse_block_switches`)."""
 
     name: str
     cache: str
@@ -872,30 +1040,59 @@ class MixerKind:
     skipped: Optional[str] = None
     rows_in_place: bool = False
     unserved: Optional[str] = None
+    partial_rotary: bool = False
+    zero_centered: bool = False
 
 
 MIXER_KINDS = {kind.name: kind for kind in (
-    MixerKind("attention", "pages"),
-    MixerKind("minicpm4", "selected_pages", SparseMixer),
+    MixerKind("attention", "pages", partial_rotary=True, zero_centered=True),
+    # (no RoPE on the block-sparse layers: encode_positions)
+    MixerKind("minicpm4", "selected_pages", SparseMixer, partial_rotary=True),
     MixerKind("lightning-attn", "state", LightningMixer, _lightning_state),
     MixerKind("mamba2", "state", Mamba2Mixer, _ssm_state, counted="ssm",
               stepped="serving/ssm_state_rows_stepped_total",
               skipped="serving/ssm_state_rows_skipped_total",
-              rows_in_place=True),
+              rows_in_place=True, partial_rotary=True),
     MixerKind("mla", "latent", MLAMixer),
     MixerKind("conv", "none", ConvMixer, unserved=(
         "the 'conv' mixer (LFM2's gated short convolution) has no cached "
         "call: a model with such layers trains and is not served "
-        "(models/hybrid.py)")),
+        "(models/hybrid.py)"), partial_rotary=True),
     MixerKind("power-retention", "state", PowerRetentionMixer,
               _retention_state, counted="retention"),
-    MixerKind("none", "none"),
+    # (its one norm is published with a PLAIN weight beside the model's
+    # zero-centred ones, and stored so)
+    MixerKind("gated-delta", "state", GatedDeltaMixer, _gdn_state,
+              counted="gdn",
+              stepped="serving/gdn_state_rows_stepped_total",
+              skipped="serving/gdn_state_rows_skipped_total",
+              rows_in_place=True, partial_rotary=True, zero_centered=True),
+    MixerKind("none", "none", partial_rotary=True, zero_centered=True),
 )}
 MIXERS = tuple(MIXER_KINDS)
 CACHE_OF = {name: kind.cache for name, kind in MIXER_KINDS.items()}
 # the recurrent kinds by name, as a refusal spells them
 RECURRENT_NAMES = ", ".join(
     name for name, kind in MIXER_KINDS.items() if kind.state is not None)
+
+
+def refuse_block_switches(cfg) -> None:
+    """``LlamaConfig.partial_rotary_factor`` and ``norm_zero_centered`` are
+    the block's and the ``"attention"`` mixer's: refused, in one sentence
+    each, beside a mixer whose record does not carry them."""
+    kinds = kinds_of(cfg)
+    whole = [k.name for k in kinds if not k.partial_rotary]
+    if cfg.partial_rotary_factor != 1.0 and whole:
+        raise ValueError(
+            "partial_rotary_factor turns a part of the 'attention' mixer's "
+            f"heads: the {', '.join(whole)} mixers rotate the channels they "
+            "always did")
+    plain = [k.name for k in kinds if not k.zero_centered]
+    if cfg.norm_zero_centered and plain:
+        raise ValueError(
+            "norm_zero_centered is the block's norms' and the 'attention' "
+            f"mixer's: the other mixers' own norms ({plain}) store plain "
+            "weights")
 
 
 def kinds_of(cfg) -> tuple:

@@ -266,6 +266,28 @@ class LlamaConfig:
     # attention's normed input of the same layer (SmallThinker routes before
     # attention, so that experts can be fetched while attention runs)
     moe_router_input: str = "ffn"
+    # Qwen3-Next's departures from the block (HF ``qwen3_next``), each off by
+    # default.  attn_output_gate: a second projection as wide as q whose
+    # sigmoid multiplies the attention's output before o_proj (the
+    # checkpoint's q_proj holds both, a head (q | gate); here "gate" is a
+    # parameter of its own).  partial_rotary_factor: RoPE turns the first
+    # factor x head_dim channels of a head (rotate-half among themselves),
+    # the rest pass.  norm_zero_centered: every RMSNorm of the block — the
+    # layers', the final one, the per-head q/k norms — stores its weight
+    # zero-centred, ``x_hat (1 + w)``.  moe_shared_gate: the shared expert's
+    # output times ``sigmoid(x w_s)``, one scalar a row
+    attn_output_gate: bool = False
+    partial_rotary_factor: float = 1.0
+    norm_zero_centered: bool = False
+    moe_shared_gate: bool = False
+    # gated-delta (models/hybrid.py, ops/gated_delta.py): key heads (q and
+    # k) and value heads (v, z, beta, the decay; a multiple of the key
+    # heads, which are repeated over them), their sizes, convolution taps
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_head_dim: int = 0
+    gdn_value_head_dim: int = 0
+    gdn_conv_kernel: int = 4
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
@@ -276,6 +298,7 @@ class LlamaConfig:
             from neuronx_distributed_tpu.models.hybrid import (
                 MIXERS,
                 kinds_of,
+                refuse_block_switches,
             )
 
             bad = sorted(set(self.mixer_types) - set(MIXERS))
@@ -287,6 +310,7 @@ class LlamaConfig:
                 raise ValueError(
                     "one kind of recurrent layer a model: a state row is "
                     "one tuple of arrays")
+            refuse_block_switches(self)
         if self.ffn_types is not None:
             object.__setattr__(self, "ffn_types", tuple(self.ffn_types))
             kinds = ("mlp", "moe", "none")
@@ -313,6 +337,16 @@ class LlamaConfig:
         if self.per_layer_attention and self.scan_layers:
             raise ValueError("scan_layers traces ONE block: a window or a "
                              "RoPE switch a layer makes several")
+        if self.partial_rotary_factor != 1.0 and (
+                not 0.0 < self.partial_rotary_factor < 1.0 or int(
+                    self.head_dim_ * self.partial_rotary_factor) % 2):
+            raise ValueError(
+                f"partial_rotary_factor {self.partial_rotary_factor} of a "
+                f"head of {self.head_dim_}: an even number of channels, at "
+                "most the head")
+        if self.moe_shared_gate and not self.moe_shared_intermediate_size:
+            raise ValueError("moe_shared_gate gates a shared expert: "
+                             "moe_shared_intermediate_size > 0")
         if self.moe_router_input not in ("ffn", "attn"):
             raise ValueError(
                 f"moe_router_input {self.moe_router_input!r} (ffn | attn)")
@@ -649,8 +683,15 @@ def rope_sin_cos(positions: jax.Array, head_dim: int, theta: float,
 
 def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:
     """Rotate-half RoPE (HF Llama convention) in fp32; ``x`` is
-    ``[B, S, n, d]``, sin/cos ``[B, S, d/2]``."""
-    d2 = x.shape[-1] // 2
+    ``[B, S, n, d]``, sin/cos ``[B, S, d/2]`` — or of fewer channels, which
+    are then the head's first."""
+    d2 = sin.shape[-1]
+    if 2 * d2 != x.shape[-1]:
+        # tables of fewer channels than the head has (``LlamaConfig.
+        # partial_rotary_factor``): the first ``2 d2`` turn among
+        # themselves, the rest pass as they are
+        return jnp.concatenate([apply_rope(x[..., :2 * d2], sin, cos),
+                                x[..., 2 * d2:]], axis=-1)
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., :d2], xf[..., d2:]
     sin = sin[..., None, :]  # broadcast over heads
@@ -784,7 +825,8 @@ class LlamaAttention(nn.Module):
             # projection, which the fused QKV hands over split into heads
             def full_width_norm(t, name):
                 flat = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
-                               param_dtype=cfg.param_dtype, name=name)(
+                               param_dtype=cfg.param_dtype, name=name,
+                               zero_centered=cfg.norm_zero_centered)(
                     t.reshape(*t.shape[:-2], -1))
                 return flat.reshape(t.shape)
 
@@ -793,10 +835,12 @@ class LlamaAttention(nn.Module):
         elif cfg.qk_norm_per_head:
             # each head's own statistic, one weight [head_dim] for all
             q, k = (RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
-                            param_dtype=cfg.param_dtype, name=name)(t)
+                            param_dtype=cfg.param_dtype, name=name,
+                            zero_centered=cfg.norm_zero_centered)(t)
                     for name, t in (("q_norm", q), ("k_norm", k)))
         if cfg.attn_rope:
-            sin, cos = rope_sin_cos(positions, D, cfg.rope_theta,
+            rot = int(D * cfg.partial_rotary_factor)
+            sin, cos = rope_sin_cos(positions, rot, cfg.rope_theta,
                                     cfg.rope_scaling_)
             q = apply_rope(q, sin, cos)
             k = apply_rope(k, sin, cos)
@@ -997,6 +1041,27 @@ class LlamaAttention(nn.Module):
 
         B, S = x.shape[0], q.shape[1]
         out = out.reshape(B, S, cfg.num_heads * D)
+        if cfg.attn_output_gate:
+            from neuronx_distributed_tpu.parallel.mesh import (
+                get_tensor_parallel_size,
+                model_parallel_is_initialized,
+            )
+
+            if model_parallel_is_initialized() \
+                    and get_tensor_parallel_size() > 1:
+                raise ValueError(
+                    "attn_output_gate is not carried over tp > 1: the "
+                    "gate's projection is not laid out over the q heads' "
+                    "axes")
+            # the sigmoid gate, float32: it multiplies the attention's
+            # output before that is rounded to the activations' dtype, once
+            with jax.named_scope("attn_gate"):
+                gate = jax.nn.sigmoid(ColumnParallelLinear(
+                    features=cfg.num_heads * D, use_bias=False,
+                    sequence_parallel=cfg.sequence_parallel, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype, name="gate")(x).astype(
+                        jnp.float32))
+                out = (out.astype(jnp.float32) * gate).astype(cfg.dtype)
         out = RowParallelLinear(
             features=cfg.hidden_size,
             use_bias=False,
@@ -1199,7 +1264,8 @@ class LlamaBlock(nn.Module):
                 u, post, res = HyperConnection(cfg, name="attn_hc")(x)
             normed = attn_in = RMSNorm(
                 eps=cfg.rms_eps, dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype, name="input_norm")(u)
+                param_dtype=cfg.param_dtype, name="input_norm",
+                zero_centered=cfg.norm_zero_centered)(u)
             if self.mixer == "attention":
                 h, new_cache = LlamaAttention(cfg, name="attn")(
                     normed, positions, kv_cache, cache_offset, kv_valid,
@@ -1223,7 +1289,8 @@ class LlamaBlock(nn.Module):
         if hc:
             u, post, res = HyperConnection(cfg, name="ffn_hc")(x)
         normed = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                         name="post_attn_norm")(u)
+                         name="post_attn_norm",
+                         zero_centered=cfg.norm_zero_centered)(u)
         if ffn == "moe":
             from neuronx_distributed_tpu.parallel.moe import (
                 ExpertParallelMLP,
@@ -1244,6 +1311,7 @@ class LlamaBlock(nn.Module):
                  "silu"),
                 ("shared_intermediate_size",
                  cfg.moe_shared_intermediate_size, 0),
+                ("shared_gate", cfg.moe_shared_gate, False),
                 ("n_group", cfg.moe_n_group, 1),
                 ("topk_group", cfg.moe_topk_group, 1))
                 if v != default}
@@ -1431,7 +1499,8 @@ class LlamaModel(nn.Module):
             # the read-out: the streams' sum, float32, rounded once
             with jax.named_scope("hc_mix"):
                 h = jnp.sum(h.astype(jnp.float32), axis=1).astype(h.dtype)
-        h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="final_norm")(h)
+        h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="final_norm",
+                    zero_centered=cfg.norm_zero_centered)(h)
         if cfg.logit_scale != 1.0:
             h = h * jnp.asarray(cfg.logit_scale, h.dtype)
         return (h, new_caches) if kv_caches is not None else (h, None)
@@ -1531,7 +1600,8 @@ class LlamaHead(nn.Module):
     def __call__(self, h):
         cfg = self.config
         h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                    name="final_norm")(h)
+                    name="final_norm",
+                    zero_centered=cfg.norm_zero_centered)(h)
         if cfg.sequence_parallel:
             h = shard_activation(h, trailing_spec(h.ndim, seq=None, last=None))
         return ColumnParallelLinear(

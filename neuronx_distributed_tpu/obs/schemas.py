@@ -262,13 +262,15 @@ REGISTRY_METRICS: Dict[str, str] = {
     # layers' state rows hold of it
     "kvcache/page_bytes_per_token": "gauge",
     "kvcache/state_bytes": "gauge",
-    # tokens through the Mamba-2 layers, and through the power-retention
-    # layers, by the program that ran them: a prefill chunk's own tokens, a
+    # tokens through the Mamba-2 layers, through the power-retention layers
+    # and through the gated-delta layers, by the program that ran them: a prefill chunk's own tokens, a
     # decode's live rows (the stems of ``models.hybrid.MIXER_KINDS``)
     "serving/ssm_tokens_total/chunk": "counter",
     "serving/ssm_tokens_total/step": "counter",
     "serving/retention_tokens_total/chunk": "counter",
     "serving/retention_tokens_total/step": "counter",
+    "serving/gdn_tokens_total/chunk": "counter",
+    "serving/gdn_tokens_total/step": "counter",
     # KV chain transfer (kvcache.transfer, disagg PR): pages serialized
     # out of / admitted into page pools by migration and fleet-prefix
     # fills; the fleet_prefix counters split directory consultations by

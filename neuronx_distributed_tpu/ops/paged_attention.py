@@ -533,7 +533,11 @@ def paged_attention(
     where the program lowers for a TPU, the pallas interpreter elsewhere),
     matching ``ops.flash_attention``.  The compiled kernel needs ``page``
     to be a multiple of 8 (one fp32 sublane tile) and the POOL's rows a
-    multiple of 128 wide — ``D`` of 128, or heads of 64 paired by the pool
+    multiple of 128 wide — ``D`` of 128, ``D`` of 256 (a page row of two
+    lane tiles: the q, accumulator and page blocks are ``D`` wide, the m
+    and l scratch 128; at a group of 8 a 512-row chunk is walked in two
+    parts of 2,048 query rows a kv head and fits the 16 MiB of scoped VMEM —
+    AOT for a v5e and the chip, PR 59), or heads of 64 paired by the pool
     (an even kv-head count; an odd count of 64-wide heads stays one to a
     row and is the interpreter's and the gather path's); the interpreter
     takes any shape.

@@ -41,7 +41,8 @@ learned correction bias joins the scores for the CHOICE only, the chosen
 UNBIASED scores are renormalised and multiplied by ``route_scale``;
 ``activation="relu2"`` makes an expert ``down(relu(up x)^2)``, two matmuls
 and no gate; ``shared_intermediate_size`` adds one shared expert that every
-row passes (scope ``moe_shared``).  And it can HOLD a share of the experts:
+row passes (scope ``moe_shared``), its output under ``shared_gate`` times
+``sigmoid(x w_s)``, a scalar a row.  And it can HOLD a share of the experts:
 with ``num_experts_global`` routed experts of which this program holds
 ``num_experts``, from ``first_expert`` on, the router, its top-k and its
 normalisation run over all of them and the sum over the chosen ones that
@@ -856,6 +857,9 @@ class ExpertParallelMLP(nn.Module):
     route_scale: float = 1.0
     activation: str = "silu"
     shared_intermediate_size: int = 0
+    # the shared expert's output times ``sigmoid(x w_s)``, a scalar a row
+    # (Qwen2-MoE's and Qwen3-Next's ``shared_expert_gate``)
+    shared_gate: bool = False
     # dropless with ``num_experts_global != num_experts``: the first of
     # the ``num_experts`` routed experts this program holds
     first_expert: int = 0
@@ -911,6 +915,9 @@ class ExpertParallelMLP(nn.Module):
         family = (self.router_scores != "softmax" or self.router_bias
                   or self.route_scale != 1.0 or self.activation != "silu"
                   or self.shared_intermediate_size or self.n_group > 1)
+        if self.shared_gate and not self.shared_intermediate_size:
+            raise ValueError("shared_gate gates a shared expert: "
+                             "shared_intermediate_size > 0")
         if family and not dropless:
             raise ValueError(
                 "sigmoid scores, a router bias, a route scale, relu or "
@@ -1027,8 +1034,20 @@ class ExpertParallelMLP(nn.Module):
                     h = (GATE_FN[self.activation](ColumnParallelLinear(
                         features=F, name="shared_gate", **lin)(xs)) * up
                          if gated else jnp.square(jax.nn.relu(up)))
-                    y = y + RowParallelLinear(features=H, name="shared_down",
-                                              **lin)(h)
+                    shared = RowParallelLinear(
+                        features=H, name="shared_down", **lin)(h)
+                    if self.shared_gate:
+                        # float32 from its matmul to the product, rounded
+                        # once
+                        w_s = jnp.asarray(self.param(
+                            "shared_expert_gate", nn.with_partitioning(
+                                per_expert_lecun, (None, None)), (H, 1),
+                            self.param_dtype))
+                        shared = (shared.astype(jnp.float32) * jax.nn.sigmoid(
+                            jnp.dot(xs, w_s.astype(self.dtype),
+                                    preferred_element_type=jnp.float32))
+                                  ).astype(shared.dtype)
+                    y = y + shared
             return y.reshape(*lead, H), aux
 
         # -- routing (fp32), over the GLOBAL expert space ---------------------

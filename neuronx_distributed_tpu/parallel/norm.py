@@ -23,14 +23,22 @@ class RMSNorm(nn.Module):
     eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    # the weight is stored ZERO-CENTRED: ``x_hat (1 + w)``, drawn at zeros
+    # (Gemma's and Qwen3-Next's checkpoints; the sum is float32)
+    zero_centered: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        weight = self.param("weight", nn.initializers.ones_init(), (x.shape[-1],), self.param_dtype)
+        init = (nn.initializers.zeros_init() if self.zero_centered
+                else nn.initializers.ones_init())
+        weight = self.param("weight", init, (x.shape[-1],), self.param_dtype)
         xf = x.astype(jnp.float32)
         var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
         y = xf * jax.lax.rsqrt(var + self.eps)
-        return (y * weight.astype(jnp.float32)).astype(self.dtype)
+        scale = weight.astype(jnp.float32)
+        if self.zero_centered:
+            scale = 1.0 + scale
+        return (y * scale).astype(self.dtype)
 
 
 class LayerNorm(nn.Module):

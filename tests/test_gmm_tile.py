@@ -47,9 +47,10 @@ def _routed():
     return cases
 
 
-def test_the_benchmark_has_five_routed_configurations():
-    # OLMoE, Nemotron, Xing4.0, DeepSeek-V2 and, since PR 48, SmallThinker
-    assert len(_routed()) == 5 * 2 * 2
+def test_the_benchmark_has_six_routed_configurations():
+    # OLMoE, Nemotron, Xing4.0, DeepSeek-V2, since PR 48 SmallThinker and,
+    # since PR 59, Qwen3-Next
+    assert len(_routed()) == 6 * 2 * 2
 
 
 @pytest.mark.parametrize("m", [128, 4096], ids=["decode", "chunk"])

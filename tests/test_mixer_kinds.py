@@ -31,6 +31,8 @@ def config(*mixers, **kw):
     return LlamaConfig.tiny(
         num_layers=len(mixers), mixer_types=mixers, ssm_heads=4,
         ssm_head_dim=8, kv_lora_rank=16, num_heads=4, num_kv_heads=2,
+        gdn_key_heads=2, gdn_value_heads=4, gdn_key_head_dim=8,
+        gdn_value_head_dim=8,
         sparse_block_size=4, sparse_kernel_size=4, sparse_kernel_stride=2,
         sparse_window_size=4, sparse_topk=3, sparse_dense_len=8, **kw)
 
@@ -146,7 +148,8 @@ def test_what_a_cache_kind_carries(kind, ask):
             plan(caches(*kept), **asked)
         assert str(e.value).startswith(
             "not carried through recurrent (lightning-attn, mamba2, "
-            "power-retention), page-selecting or latent layers yet: ")
+            "power-retention, gated-delta), page-selecting or latent "
+            "layers yet: ")
         return
     got = plan(caches(*kept), **asked)
     assert got.recurrent == (kind == "state") and got.pageless == pageless

@@ -989,3 +989,89 @@ def test_retention_programs_compile_for_v5e_and_step_the_state_in_place(
     assert memory.alias_size_in_bytes >= held
     # ... and beside the one copy the program keeps well under a state row
     assert memory.temp_size_in_bytes < 8 * 128 * 9216 * 4 * 8
+
+
+# -- Qwen3-Next: the delta rule's two calls, and heads of 256 in the pool -----
+
+
+@pytest.mark.parametrize("call", ["gdn_chunk", "gdn_step"])
+def test_the_delta_rule_compiles_for_v5e_on_the_state_where_it_lies(
+        topo, call):
+    """``ops.gated_delta`` at Qwen3-Next's shapes — 8 rows of ``[32, 128,
+    128]`` float32, a 512-row chunk continuing ONE row in eight blocks
+    written out as XLA, a decode over all eight in the Mosaic call
+    ``gdn_step``: the state array donated, aliased to its output and never
+    copied (a walk without its barrier copied the array twice a chunk:
+    ``ops/ssm_scan.py``)."""
+    import re
+
+    from neuronx_distributed_tpu.ops import gated_delta as gd
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    R, NH, Dh = 8, 32, 128
+    if call == "gdn_chunk":
+        fn = lambda st, q, k, v, g, b, rows: gd.gdn_chunk(  # noqa: E731
+            q, k, v, g, b, None, jnp.zeros((1,), bool), st, rows)
+        args = (sds((R, NH, Dh, Dh)), sds((1, 512, NH, Dh)),
+                sds((1, 512, NH, Dh)), sds((1, 512, NH, Dh), jnp.bfloat16),
+                sds((1, 512, NH)), sds((1, 512, NH)), sds((1,), jnp.int32))
+    else:
+        fn = lambda st, q, k, v, g, b, live: gd.gdn_step(  # noqa: E731
+            st, q, k, v, g, b, live, None, None, kernel=True)
+        args = (sds((R, NH, Dh, Dh)), sds((R, NH, Dh)), sds((R, NH, Dh)),
+                sds((R, NH, Dh)), sds((R, NH)), sds((R, NH)),
+                sds((R,), jnp.bool_))
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    mosaic = re.search(r"%gdn_\w+?[.\d]* = [^\n]*tpu_custom_call", text)
+    assert bool(mosaic) == (call == "gdn_step")
+    copied = [ln.strip()[:120] for ln in text.splitlines()
+              if re.search(rf"= f32\[{R},32,128,128\]\S* (copy|transpose)\(",
+                           ln)]
+    assert not copied, f"the state array is copied: {copied}"
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= R * NH * Dh * Dh * 4
+
+
+@pytest.mark.parametrize("S", [1, 512], ids=["decode", "chunk_s512"])
+def test_heads_of_256_are_written_and_walked_for_v5e(topo, S):
+    """Qwen3-Next's attention geometry — 16 query heads over 2 kv heads of
+    256 (a group of eight, a page row of two lane tiles), pages of 64, 520 a
+    slot: the pool write and the walk lower and fit VMEM; a decode is one
+    walk, a 512-row chunk (4,096 query rows a kv head) two."""
+    from neuronx_distributed_tpu.ops.kv_pool_write import write_pool_rows
+
+    mesh = _mesh(topo)
+    B = 8 if S == 1 else 1
+    NQ_, NKV_, D_, page, num_pages, pp = 16, 2, 256, 64, 4161, 520
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P()))
+
+    pages = sds((num_pages, NKV_, page, D_), jnp.bfloat16)
+
+    def write_then_attend(q, new, pool, bt, off, start, phys, cell):
+        pool = tuple(write_pool_rows(p, new, phys, cell, kernel=True)
+                     for p in pool)
+        return paged_attention(q, pool, bt, off, start), pool
+
+    compiled = jax.jit(write_then_attend, donate_argnums=(2,)).lower(
+        sds((B, S, NQ_, D_), jnp.bfloat16), sds((B, S, NKV_, D_), jnp.bfloat16),
+        (pages, pages), sds((B, pp), jnp.int32), sds((B,), jnp.int32),
+        sds((B,), jnp.int32), sds((B, S), jnp.int32),
+        sds((B, S), jnp.int32)).compile()
+    text = compiled.as_text()
+    import re
+
+    name = "paged_attention_decode" if S == 1 else "paged_attention_chunk"
+    made = lambda call: len(re.findall(  # noqa: E731
+        rf"%{call}[.\d]* = [^\n]*tpu_custom_call", text))
+    assert made(name) == (1 if S == 1 else 2)
+    assert made("kv_pool_write") == 2
+    held = 2 * num_pages * NKV_ * page * D_ * 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
